@@ -64,7 +64,7 @@ class WorkerSpec:
     redirect_output: Optional[str] = None  # dir for per-worker logs
     # Keep a pre-spawned interpreter (python + framework imports
     # already paid) and adopt it as the next incarnation on restart —
-    # cuts restart latency by the ~4s import cost (agent/standby.py).
+    # cuts restart latency by the import cost (agent/standby.py).
     # Honored for nproc_per_node == 1.
     warm_standby: bool = False
 
@@ -264,7 +264,7 @@ class ElasticAgent:
 
     def _spawn_standby(self, spec):
         """Pre-spawn the NEXT incarnation's interpreter so a restart
-        skips the ~4s python + framework import cost (agent/standby.py).
+        skips the python + framework import cost (agent/standby.py).
         The standby blocks on stdin; it never touches the accelerator
         until adopted."""
         if self._standby is not None and self._standby.poll() is None:

@@ -158,6 +158,24 @@ def tiny_config(**overrides) -> TpuLMConfig:
     return TpuLMConfig(**defaults)
 
 
+def flagship_config(**overrides) -> TpuLMConfig:
+    """The 334M-param dense model the bench, the example and
+    chip_smoke.py all mean by "flagship": the largest whose train state
+    (f32 weights + Adam, ~4 GB) and activations fit one 16 GB chip."""
+    defaults = dict(
+        vocab_size=32000,
+        embed_dim=1024,
+        n_layers=16,
+        n_heads=8,
+        n_kv_heads=8,
+        head_dim=128,
+        mlp_dim=4096,
+        dtype="bfloat16",
+    )
+    defaults.update(overrides)
+    return TpuLMConfig(**defaults)
+
+
 # ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
@@ -476,22 +494,20 @@ def _attn_block_lite(config, p, x, positions):
 
 
 def _attn_block_lite_fwd(config, p, x, positions):
-    from dlrover_tpu.ops.pallas_attention import _flash_forward
+    from dlrover_tpu.ops.pallas_attention import flash_forward
 
     q, k, v = attention_qkv(config, p, x, positions)
     interpret = jax.default_backend() != "tpu"
-    out, lse = _flash_forward(q, k, v, True, None, interpret)
-    # lse compact [b*h, sq]: the lane-broadcast layout would pin 128x
-    # the bytes (same trade as pallas_attention._fwd).
-    return out, (p, x, positions, out, lse[:, :, 0])
+    out, lse_c = flash_forward(q, k, v, True, None, interpret)
+    return out, (p, x, positions, out, lse_c)
 
 
 def _attn_block_lite_bwd(config, res, g):
     import numpy as np
 
-    from dlrover_tpu.ops.pallas_attention import LANES, _flash_backward
+    from dlrover_tpu.ops.pallas_attention import flash_backward
 
-    p, x, positions, out, lse2d = res
+    p, x, positions, out, lse_c = res
     (q, k, v), qkv_vjp = jax.vjp(
         lambda p_, x_: attention_qkv(config, p_, x_, positions), p, x
     )
@@ -509,12 +525,9 @@ def _attn_block_lite_bwd(config, res, g):
         )
         dq, dk, dv = attn_vjp(g)
     else:
-        lse = jnp.broadcast_to(
-            lse2d[:, :, None], lse2d.shape + (LANES,)
-        )
         interpret = jax.default_backend() != "tpu"
-        dq, dk, dv = _flash_backward(
-            q, k, v, out, lse, g, True, None, interpret
+        dq, dk, dv = flash_backward(
+            q, k, v, out, lse_c, g, True, None, interpret
         )
     dp, dx = qkv_vjp((dq, dk, dv))
     dpos = np.zeros(positions.shape, jax.dtypes.float0)

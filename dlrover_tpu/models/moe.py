@@ -226,14 +226,17 @@ _gather_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 def _dispatch_impl() -> str:
-    """"fused" (ops/moe_dispatch grouped kernel, the default) | "gmm"
-    (megablox grouped matmuls around XLA gathers — the A/B baseline)
-    for the dropless expert compute. DLROVER_TPU_MOE_DISPATCH picks;
-    typos warn once and fall back to "fused"."""
+    """"gmm" (megablox grouped matmuls around XLA gathers, the default)
+    | "fused" (ops/moe_dispatch grouped kernel) for the dropless expert
+    compute. DLROVER_TPU_MOE_DISPATCH picks; typos warn once and fall
+    back to "gmm". "fused" was the default from PR 14 to PR 21 without
+    ever having compiled for a chip; repaired, its first on-chip
+    reading is 1.4x SLOWER than gmm (ops/moe_dispatch.py STATUS), so
+    gmm is the default until a benchmark says otherwise."""
     from dlrover_tpu.common.env_utils import resolve_env_choice
 
     return resolve_env_choice(
-        "DLROVER_TPU_MOE_DISPATCH", ("fused", "gmm"), "fused"
+        "DLROVER_TPU_MOE_DISPATCH", ("fused", "gmm"), "gmm"
     )
 
 
@@ -242,9 +245,9 @@ def _dropless_core(
 ):
     """Sorted grouped-matmul expert compute over flat tokens [n, d] ->
     out [n, d] f32. Local to one device (all experts resident).
-    ``dispatch``: "fused" routes through the ops/moe_dispatch Pallas
-    kernel (gather→GEMM→scatter in one pass, custom VJP on the same
-    permutation); "gmm" keeps the megablox path with XLA gathers."""
+    ``dispatch``: "gmm" is the megablox path with XLA gathers; "fused"
+    routes through the ops/moe_dispatch Pallas kernel (gather→GEMM→
+    scatter in one pass, custom VJP on the same permutation)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     n, d = xf.shape
@@ -387,7 +390,6 @@ def moe_mlp_dropless_sharded(
     batch axes with replicated weights. (The global-argsort single-
     device path has data-dependent group sizes GSPMD cannot lower
     soundly; this per-shard form sidesteps that entirely.)"""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from dlrover_tpu.parallel.sharding import logical_to_spec
@@ -405,12 +407,12 @@ def moe_mlp_dropless_sharded(
         return out.astype(xl.dtype).reshape(bl, sl, d)
 
     xspec = logical_to_spec(("batch", None, None))
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(xspec, P(), P(), P(), P()),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w_gate, w_up, w_down)
     out = with_logical_constraint(out, ("batch", "seq", "embed"))
     return out, _global_router_metrics(x, router_w)
@@ -500,7 +502,6 @@ def moe_mlp_dropless_ep(
     routed to one shard) — the price of true droplessness; the gshard
     path bounds memory with capacity instead (and drops).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -651,12 +652,12 @@ def moe_mlp_dropless_ep(
         return out.astype(x.dtype).reshape(bl, sl, d)
 
     wspec = P(axis_name)
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(xspec, P(), wspec, wspec, wspec),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w_gate, w_up, w_down)
     out = with_logical_constraint(out, ("batch", "seq", "embed"))
 

@@ -38,6 +38,14 @@ most one tile), padding slots carry ``row_id = -1`` and are masked to
 zero rows / skipped scatters. Group sizes are data-dependent VALUES,
 never shapes — the whole thing jits once.
 
+STATUS (PR 21): compiles for a v5e (tests/test_tpu_compile.py) and
+runs on one with gmm-level agreement, but is NOT the default dispatch:
+the one on-chip reading so far has it at 20.3 ms fwd+bwd against gmm's
+14.1 ms at the bench shape (a smoke reading; ROADMAP S3 owns the real
+A/B). The >40% MFU figure above is the design's target, never measured.
+Rows move as f32 through a [rows, 1, d] view (see ``_dma_rows``), which
+doubles the gather/scatter bytes of a bf16 model.
+
 VMEM budget note: the kernels hold one expert's weights (w_gu
 ``[d, 2f]``, w_down ``[f, d]``) plus ``tile_m``-row tiles in VMEM; at
 the bench shape (d = f = 1024, bf16, tile_m = 128) the worst kernel
@@ -51,6 +59,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# One expert's double-buffered weights, the f32 row tiles and (in the dw
+# kernels) an f32 [d, 2f] accumulator block outgrow Mosaic's default
+# 16 MiB scoped-VMEM budget at d = f = 1024; v5e has 128 MiB.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def default_tile_m(m: int) -> int:
@@ -119,6 +133,18 @@ def build_dispatch_layout(
     return row_ids, dest_ids, tile_expert
 
 
+def _dma_rows(x):
+    """``x`` [rows, d] as the f32 [rows, 1, d] array the row DMAs move.
+
+    Mosaic only slices a memref along its two minor dims in whole
+    tiles, and a [rows, d] array's tile spans 8 rows (16 packed, for
+    bf16): one row is not addressable. With a unit second-minor dim the
+    row index is an UNTILED leading dim instead — for 32-bit types a
+    free reshape with (1, 128) tiles (bf16 would pad every row to two).
+    So rows travel as f32; the MXU still sees the compute dtype."""
+    return x.astype(jnp.float32).reshape(x.shape[0], 1, x.shape[1])
+
+
 def _gather_rows(src_hbm, ids_ref, base, dst_ref, sems, tile_m):
     """DMA ``tile_m`` rows ``src_hbm[ids[base + r]]`` into ``dst_ref``;
     all copies start before the first wait so the DMA engine pipelines
@@ -172,8 +198,18 @@ def _scatter_rows(src_ref, ids_ref, base, dst_hbm, sems, tile_m):
 
 
 def _valid_mask(ids_ref, base, tile_m):
-    ids = jax.lax.dynamic_slice(ids_ref[:], (base,), (tile_m,))
-    return (ids >= 0)[:, None]
+    """[tile_m, 1] mask of the tile's non-padding rows. The ids live in
+    SMEM (scalar prefetch), which Mosaic reads one scalar at a time, so
+    the valid rows are COUNTED with scalar loads and the mask is an
+    iota compare: ``build_dispatch_layout`` packs each group's copies
+    at the front of its tiles, so a tile's valid rows are a prefix."""
+    n_valid = jax.lax.fori_loop(
+        0, tile_m,
+        lambda r, c: c + (ids_ref[base + r] >= 0).astype(jnp.int32),
+        jnp.int32(0),
+    )
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_m, 1), 0)
+    return rows < n_valid
 
 
 def _silu_bwd(hg, hu, da):
@@ -193,17 +229,17 @@ def _fwd_kernel(
     base = i * tile_m
     _gather_rows(x_hbm, row_ids, base, xt, gsem, tile_m)
     mask = _valid_mask(row_ids, base, tile_m)
-    xm = jnp.where(mask, xt[:], 0)
+    xm = jnp.where(mask, xt[:, 0, :], 0).astype(wgu_ref.dtype)
     h = jax.lax.dot_general(
         xm, wgu_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     f = h.shape[-1] // 2
-    a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(xt.dtype)
-    yt[:] = jax.lax.dot_general(
+    a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(wdn_ref.dtype)
+    yt[:, 0, :] = jax.lax.dot_general(
         a, wdn_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).astype(yt.dtype)
+    )
     _scatter_rows(yt, dest_ids, base, y_hbm, ssem, tile_m)
 
 
@@ -217,8 +253,8 @@ def _dx_kernel(
     _gather_rows(x_hbm, row_ids, base, xt, gsem, tile_m)
     _gather_rows(dy_hbm, dest_ids, base, dyt, dsem, tile_m)
     mask = _valid_mask(row_ids, base, tile_m)
-    xm = jnp.where(mask, xt[:], 0)
-    dy = jnp.where(mask, dyt[:], 0).astype(jnp.float32)
+    xm = jnp.where(mask, xt[:, 0, :], 0).astype(wgu_ref.dtype)
+    dy = jnp.where(mask, dyt[:, 0, :], 0)
     h = jax.lax.dot_general(
         xm, wgu_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -237,11 +273,11 @@ def _dx_kernel(
     dh = jnp.concatenate([dhg, dhu], axis=-1)
     dh_ref[:] = dh.astype(dh_ref.dtype)
     # dx = dh @ w_guᵀ  (contract the 2f axis)
-    dxt[:] = jax.lax.dot_general(
+    dxt[:, 0, :] = jax.lax.dot_general(
         dh.astype(wgu_ref.dtype), wgu_ref[0],
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).astype(dxt.dtype)
+    )
     _scatter_rows(dxt, dest_ids, base, dx_hbm, ssem, tile_m)
 
 
@@ -256,7 +292,7 @@ def _dw_accum_kernel(
     base = i * tile_m
     _gather_rows(lhs_hbm, gather_ids, base, lt, gsem, tile_m)
     mask = _valid_mask(gather_ids, base, tile_m)
-    lhs = jnp.where(mask, lt[:], 0)
+    lhs = jnp.where(mask, lt[:, 0, :], 0).astype(rhs_ref.dtype)
     contrib = jax.lax.dot_general(
         lhs, rhs_ref[:], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -278,7 +314,7 @@ def _dw_sorted_lhs_kernel(
     base = i * tile_m
     _gather_rows(rhs_hbm, gather_ids, base, rt, gsem, tile_m)
     mask = _valid_mask(gather_ids, base, tile_m)
-    rhs = jnp.where(mask, rt[:], 0)
+    rhs = jnp.where(mask, rt[:, 0, :], 0).astype(lhs_ref.dtype)
     contrib = jax.lax.dot_general(
         lhs_ref[:], rhs, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -325,13 +361,14 @@ def _grouped_ffn_fwd(
     T = tile_expert.shape[0]
     d = x.shape[-1]
     two_f = w_gu.shape[-1]
+    row_tile = pltpu.VMEM((tile_m, 1, d), jnp.float32)
     y = pl.pallas_call(
         functools.partial(_fwd_kernel, tile_m=tile_m),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(T,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(
                     (1, d, two_f), lambda i, ri, di, te: (te[i], 0, 0)
                 ),
@@ -340,17 +377,19 @@ def _grouped_ffn_fwd(
                     lambda i, ri, di, te: (te[i], 0, 0),
                 ),
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
-                pltpu.VMEM((tile_m, d), x.dtype),
-                pltpu.VMEM((tile_m, d), x.dtype),
+                row_tile,
+                row_tile,
                 pltpu.SemaphoreType.DMA((tile_m,)),
                 pltpu.SemaphoreType.DMA((tile_m,)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_out, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_out, 1, d), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(row_ids, dest_ids, tile_expert, x, w_gu, w_down)
+    )(row_ids, dest_ids, tile_expert, _dma_rows(x), w_gu, w_down)
+    y = y.reshape(n_out, d).astype(x.dtype)
     return y, (x, w_gu, w_down, row_ids, dest_ids, tile_expert)
 
 
@@ -363,15 +402,16 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
     n_src, d = x.shape
     two_f = w_gu.shape[-1]
     f = two_f // 2
-    g = g.astype(x.dtype)
+    x3, g3 = _dma_rows(x), _dma_rows(g)
+    row_tile = pltpu.VMEM((tile_m, 1, d), jnp.float32)
     dx_c, dh_sorted, a_sorted = pl.pallas_call(
         functools.partial(_dx_kernel, tile_m=tile_m),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(T,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(
                     (1, d, two_f), lambda i, ri, di, te: (te[i], 0, 0)
                 ),
@@ -380,7 +420,7 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
                 ),
             ],
             out_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(
                     (tile_m, two_f), lambda i, ri, di, te: (i, 0)
                 ),
@@ -389,21 +429,22 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
                 ),
             ],
             scratch_shapes=[
-                pltpu.VMEM((tile_m, d), x.dtype),
-                pltpu.VMEM((tile_m, d), x.dtype),
-                pltpu.VMEM((tile_m, d), x.dtype),
+                row_tile,
+                row_tile,
+                row_tile,
                 pltpu.SemaphoreType.DMA((tile_m,)),
                 pltpu.SemaphoreType.DMA((tile_m,)),
                 pltpu.SemaphoreType.DMA((tile_m,)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n_out, d), x.dtype),
+            jax.ShapeDtypeStruct((n_out, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((m_pad, two_f), x.dtype),
             jax.ShapeDtypeStruct((m_pad, f), x.dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(row_ids, dest_ids, tile_expert, x, g, w_gu, w_down)
+    )(row_ids, dest_ids, tile_expert, x3, g3, w_gu, w_down)
     e = w_gu.shape[0]
     dwgu = pl.pallas_call(
         functools.partial(_dw_accum_kernel, tile_m=tile_m),
@@ -411,7 +452,7 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
             num_scalar_prefetch=2,
             grid=(T,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(
                     (tile_m, two_f), lambda i, ri, te: (i, 0)
                 ),
@@ -420,13 +461,14 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
                 (1, d, two_f), lambda i, ri, te: (te[i], 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((tile_m, d), x.dtype),
+                row_tile,
                 pltpu.SemaphoreType.DMA((tile_m,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((e, d, two_f), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(row_ids, tile_expert, x, dh_sorted)
+    )(row_ids, tile_expert, x3, dh_sorted)
     dwdn = pl.pallas_call(
         functools.partial(_dw_sorted_lhs_kernel, tile_m=tile_m),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -436,24 +478,24 @@ def _grouped_ffn_bwd(n_out, copies_per_src, tile_m, interpret, res, g):
                 pl.BlockSpec(
                     (tile_m, f), lambda i, di, te: (i, 0)
                 ),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
                 (1, f, d), lambda i, di, te: (te[i], 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((tile_m, d), x.dtype),
+                row_tile,
                 pltpu.SemaphoreType.DMA((tile_m,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((e, f, d), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(dest_ids, tile_expert, a_sorted, g)
+    )(dest_ids, tile_expert, a_sorted, g3)
     # Per-copy dx reduces densely over the k copies of each source row
     # (the row_ids == dest_ids // copies invariant): no scatter.
     dx = jnp.sum(
-        dx_c.reshape(n_src, copies_per_src, d).astype(jnp.float32),
-        axis=1,
+        dx_c.reshape(n_src, copies_per_src, d), axis=1
     ).astype(x.dtype)
     return (
         dx,

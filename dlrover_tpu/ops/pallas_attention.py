@@ -25,6 +25,7 @@ its elastic machinery would supervise.
 """
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -32,8 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.ops.attention import NEG_INF, dot_product_attention
+from dlrover_tpu.parallel.sharding import current_mesh, logical_to_spec
 
 LANES = 128  # lane-broadcast width for per-row stats (lse, delta)
 
@@ -502,6 +505,106 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
 
 
 # ---------------------------------------------------------------------------
+# Mesh island
+# ---------------------------------------------------------------------------
+#
+# GSPMD cannot partition a Mosaic kernel, so on a multi-device mesh the
+# kernels run per shard inside a ``shard_map`` over the batch and head
+# axes (attention is independent across both; the sequence stays whole —
+# a sequence-sharded mesh uses ops/ring_attention.py instead). The row
+# lse crosses the island compact, [b, h, sq]: the kernel's
+# lane-broadcast [b*h, sq, LANES] layout would pin 128x the bytes
+# (64MB/layer at the flagship shape) across the whole backward, and its
+# merged b*h axis has no partition spec.
+
+
+def _island_specs(q, k):
+    """(mesh, q_spec, kv_spec, lse_spec) when a multi-device mesh is in
+    scope, else None. A dim its mesh axes do not divide stays whole."""
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    batch_ax, q_ax = logical_to_spec(("batch", "heads"))
+    kv_ax = logical_to_spec(("kv_heads",))[0]
+
+    def n_shards(axes):
+        if axes is None:
+            return 1
+        names = (axes,) if isinstance(axes, str) else axes
+        return math.prod(mesh.shape[a] for a in names)
+
+    b, _, h, _ = q.shape
+    hkv = k.shape[2]
+    if b % n_shards(batch_ax):
+        batch_ax = None
+    # q heads and kv heads split together or not at all: a shard's
+    # query heads must find their kv head on the same shard.
+    if q_ax != kv_ax or h % n_shards(q_ax) or hkv % n_shards(kv_ax):
+        q_ax = kv_ax = None
+    return (
+        mesh,
+        P(batch_ax, None, q_ax, None),
+        P(batch_ax, None, kv_ax, None),
+        P(batch_ax, q_ax, None),
+    )
+
+
+def flash_forward_local(q, k, v, causal, softmax_scale, interpret):
+    """The forward on this shard's arrays (also one ring-attention
+    hop): (out, compact lse [b, h, sq])."""
+    b, sq, h, _ = q.shape
+    out, lse = _flash_forward(q, k, v, causal, softmax_scale, interpret)
+    return out, lse[:, :, 0].reshape(b, h, sq)
+
+
+def _backward_local(
+    q, k, v, out, lse_c, g, causal, softmax_scale, interpret
+):
+    b, sq, h, _ = q.shape
+    lse = jnp.broadcast_to(
+        lse_c.reshape(b * h, sq, 1), (b * h, sq, LANES)
+    )
+    return _flash_backward(
+        q, k, v, out, lse, g, causal, softmax_scale, interpret
+    )
+
+
+def flash_forward(q, k, v, causal, softmax_scale, interpret):
+    """(out [b, sq, h, d], compact lse [b, h, sq]) — per shard under the
+    mesh in scope, directly on a single device."""
+    local = functools.partial(
+        flash_forward_local, causal=causal, softmax_scale=softmax_scale,
+        interpret=interpret,
+    )
+    specs = _island_specs(q, k)
+    if specs is None:
+        return local(q, k, v)
+    mesh, qs, kvs, ls = specs
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(qs, kvs, kvs), out_specs=(qs, ls),
+        check_vma=False,
+    )(q, k, v)
+
+
+def flash_backward(
+    q, k, v, out, lse_c, g, causal, softmax_scale, interpret
+):
+    """Grad wrt (q, k, v) from ``flash_forward``'s residuals."""
+    local = functools.partial(
+        _backward_local, causal=causal, softmax_scale=softmax_scale,
+        interpret=interpret,
+    )
+    specs = _island_specs(q, k)
+    if specs is None:
+        return local(q, k, v, out, lse_c, g)
+    mesh, qs, kvs, ls = specs
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(qs, kvs, kvs, qs, ls, qs),
+        out_specs=(qs, kvs, kvs), check_vma=False,
+    )(q, k, v, out, lse_c, g)
+
+
+# ---------------------------------------------------------------------------
 # Public op
 # ---------------------------------------------------------------------------
 
@@ -520,23 +623,19 @@ def flash_attention(
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    out, _ = _flash_forward(q, k, v, causal, softmax_scale, interpret)
+    out, _ = flash_forward(q, k, v, causal, softmax_scale, interpret)
     return out
 
 
 def _fwd(q, k, v, causal, softmax_scale, interpret):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    out, lse = _flash_forward(q, k, v, causal, softmax_scale, interpret)
-    # Residual lse is stored COMPACT [b*h, sq] — the kernel's
-    # lane-broadcast [b*h, sq, LANES] layout would pin 128x the bytes
-    # (64MB/layer at the flagship shape) across the whole backward.
-    return out, (q, k, v, out, lse[:, :, 0])
+    out, lse_c = flash_forward(q, k, v, causal, softmax_scale, interpret)
+    return out, (q, k, v, out, lse_c)
 
 
 def _bwd(causal, softmax_scale, interpret, res, g):
-    q, k, v, out, lse2d = res
-    lse = jnp.broadcast_to(lse2d[:, :, None], lse2d.shape + (LANES,))
+    q, k, v, out, lse_c = res
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if os.environ.get("DLROVER_TPU_FLASH_BWD", "pallas").lower() == "xla":
@@ -548,8 +647,8 @@ def _bwd(causal, softmax_scale, interpret, res, g):
             q, k, v,
         )
         return vjp(g)
-    return _flash_backward(
-        q, k, v, out, lse, g, causal, softmax_scale, interpret
+    return flash_backward(
+        q, k, v, out, lse_c, g, causal, softmax_scale, interpret
     )
 
 

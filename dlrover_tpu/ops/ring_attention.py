@@ -98,7 +98,7 @@ def ring_attention_local(
     b, sq, h, d = q.shape
     hkv = k.shape[2]
     groups = h // hkv
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
 
     o0 = jnp.zeros((b, hkv, groups, sq, d), jnp.float32)
@@ -181,12 +181,10 @@ def ring_attention_local(
 def _flash_block(q, k, v, causal, scale):
     """One ring hop through the Pallas forward. Returns (out [b,sq,h,d]
     in q.dtype, lse [b, h, sq] f32)."""
-    from dlrover_tpu.ops.pallas_attention import _flash_forward
+    from dlrover_tpu.ops.pallas_attention import flash_forward_local
 
     interpret = jax.default_backend() != "tpu"
-    out, lse = _flash_forward(q, k, v, causal, scale, interpret)
-    b, sq, h, d = q.shape
-    return out, lse[:, :, 0].reshape(b, h, sq)
+    return flash_forward_local(q, k, v, causal, scale, interpret)
 
 
 def _merge(o, lse, out_b, lse_b):
@@ -235,7 +233,7 @@ def _contiguity_poison(q_pos, kv_pos):
 
 def _ring_flash_fwd(q, k, v, q_pos, kv_pos, axis_name, causal, scale):
     b, sq, h, d = q.shape
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = scale if scale is not None else d ** -0.5
     q_off = q_pos[0, 0]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -314,7 +312,7 @@ def _ring_bwd_rule(axis_name, causal, scale, res, g):
 
     q, k, v, q_pos, kv_pos, out, lse = res
     b, sq, h, d = q.shape
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale_v = scale if scale is not None else d ** -0.5
     interpret = jax.default_backend() != "tpu"
     q_off = q_pos[0, 0]
@@ -443,33 +441,6 @@ def _ring_impl(impl: Optional[str]) -> str:
     return impl
 
 
-def _axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map: jax.lax.axis_size where
-    it exists, the psum-of-unit idiom (resolved to a Python int at
-    trace time) on older releases."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _shard_map_compat(body, mesh, in_specs, out_specs):
-    """jax.shard_map(check_vma=False) where the public API exists,
-    jax.experimental.shard_map.shard_map(check_rep=False) on older
-    releases (the replication/VMA check was renamed across versions —
-    both forms disable it, which the ring's manual collectives need)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def make_ring_attention(
     mesh: Mesh,
     rules=DEFAULT_RULES,
@@ -510,11 +481,14 @@ def make_ring_attention(
                 q, k, v, qp, kp, axis_name, causal, softmax_scale
             )
 
-        return _shard_map_compat(
+        # check_vma off: the ring's manual collectives (ppermute hops)
+        # carry no replication annotations.
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(q_spec, kv_spec, kv_spec, pos_spec, pos_spec),
             out_specs=q_spec,
+            check_vma=False,
         )(q, k, v, q_positions, kv_positions)
 
     # The pallas path's ring-level custom VJP keeps O(s*d) residuals

@@ -47,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.common.compile_cache import enable_compile_cache
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.fault import fault_point
 from dlrover_tpu.models import generate as gen_lib
@@ -351,6 +352,7 @@ class ServingEngine:
                 "serving runs on the flat layer stack; merge pipeline "
                 "stages for inference"
             )
+        enable_compile_cache()  # before the step programs compile
         if max_len % 8:
             raise ValueError("max_len must be a multiple of 8")
         if max_len % prefill_chunk:

@@ -7,16 +7,18 @@ NCCL: spans are fed explicitly from Python at the natural sync points
 probes), while everything that must survive a wedged Python runtime —
 trace ring, aggregation, Prometheus daemon, hang watchdog — is native.
 
-The library is built on first use if missing (one g++ invocation, no
-third-party deps) and cached next to the sources.
+The library is built from its sources on first use and cached next to
+them (see ``ensure_native_built``).
 """
 
 import ctypes
 import fcntl
+import hashlib
 import os
 import subprocess
 import tempfile
 import threading
+import time
 from contextlib import contextmanager
 from typing import Optional
 
@@ -28,7 +30,6 @@ _NATIVE_DIR = os.path.join(
     "native",
     "tpu_timer",
 )
-_SO_PATH = os.path.join(_NATIVE_DIR, "libtpu_timer.so")
 
 
 class SpanKind:
@@ -57,32 +58,94 @@ def publish_port(local_rank: int, port: int):
     os.rename(tmp, path)
 
 
-def _ensure_built() -> str:
-    if os.path.exists(_SO_PATH):
-        return _SO_PATH
-    # Serialize concurrent first-use builds across worker processes: make
-    # writes the .so in place, and a sibling must not dlopen a half-
-    # written ELF.
+def _sources_digest() -> str:
+    """Hash of everything ``make`` reads in the native dir."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(_NATIVE_DIR)):
+        if name == "Makefile" or name.endswith((".cc", ".h")):
+            h.update(name.encode())
+            with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def ensure_native_built(target: str, timeout: float = 120.0) -> str:
+    """Path of ``native/tpu_timer/<target>``, built with make unless it
+    was built from the sources now on disk.
+
+    The binaries are git-ignored and built on first use (one g++
+    invocation, no third-party deps). "Up to date" is a digest of the
+    sources stamped next to the binary, not an mtime: a copied tree
+    keeps prebuilt binaries next to sources that changed since and no
+    trustworthy timestamps, and a stale binary silently preferred over
+    its sources is the worst outcome.
+
+    Everything is BOUNDED: this also runs on the agent's hang-recovery
+    path (native_stack), where an unbounded flock or make would let the
+    hang diagnostic hang the recovery itself. A lock held past the
+    deadline raises TimeoutError; so does a failed or wedged build
+    (CalledProcessError / TimeoutExpired / OSError) when no binary is
+    there — one that is there is then loaded with a warning."""
+    path = os.path.join(_NATIVE_DIR, target)
+    stamp = path + ".srcdigest"
+    want = _sources_digest()
+
+    def fresh() -> bool:
+        try:
+            with open(stamp) as f:
+                return os.path.exists(path) and f.read() == want
+        except OSError:
+            return False
+
+    if fresh():
+        return path
+    # Serialize builds across worker processes: make writes the binary
+    # in place, and a sibling must not load a half-written ELF.
     lock_path = os.path.join(
         tempfile.gettempdir(), "dlrover_tpu_timer_build.lock"
     )
+    deadline = time.time() + timeout
     with open(lock_path, "w") as lock:
-        fcntl.flock(lock, fcntl.LOCK_EX)
+        while True:
+            try:
+                fcntl.flock(lock, fcntl.LOCK_EX | fcntl.LOCK_NB)
+                break
+            except OSError:
+                if time.time() > deadline:
+                    raise TimeoutError(
+                        f"build lock {lock_path} held past {timeout}s"
+                    )
+                time.sleep(0.2)
         try:
-            if not os.path.exists(_SO_PATH):
-                logger.info("building libtpu_timer.so (first use)")
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
-                    check=True,
-                    capture_output=True,
-                )
+            if not fresh():
+                logger.info("building %s from its sources", target)
+                try:
+                    subprocess.run(
+                        ["make", "-B", "-C", _NATIVE_DIR, target],
+                        check=True,
+                        capture_output=True,
+                        timeout=max(deadline - time.time(), 10.0),
+                    )
+                    with open(stamp, "w") as f:
+                        f.write(want)
+                except (OSError, subprocess.SubprocessError) as e:
+                    # A read-only install, or no compiler: a binary
+                    # that is there is used, LOUDLY — it cannot be
+                    # shown to match the sources.
+                    if not os.path.exists(path):
+                        raise
+                    logger.warning(
+                        "could not rebuild or stamp %s (%s); loading "
+                        "the binary found there, which may not match "
+                        "its sources", target, e,
+                    )
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)
-    return _SO_PATH
+    return path
 
 
 def _load_lib() -> ctypes.CDLL:
-    lib = ctypes.CDLL(_ensure_built())
+    lib = ctypes.CDLL(ensure_native_built("libtpu_timer.so"))
     lib.tt_init.argtypes = [ctypes.c_int64]
     lib.tt_start_server.argtypes = [ctypes.c_int]
     lib.tt_start_server.restype = ctypes.c_int

@@ -18,63 +18,21 @@ output to the worker's log, right next to the SIGUSR2 faulthandler
 dump; ``analysis.py stacks`` folds both into one histogram.
 """
 
-import fcntl
-import os
 import re
 import subprocess
-import tempfile
-import time
 from typing import List, Optional
 
 from dlrover_tpu.common.log import logger
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "native",
-    "tpu_timer",
-)
-_SAMPLER_PATH = os.path.join(_NATIVE_DIR, "stack_sampler")
-
 
 def ensure_built(timeout: float = 120.0) -> str:
-    """Build stack_sampler on first use (one g++ invocation), with the
-    same cross-process build lock as the timer runtime.
+    """The stack_sampler binary, built from its sources on first use;
+    bounded, raises when it cannot be had (see
+    ``bridge.ensure_native_built``) and the caller degrades to the
+    Python-only dump."""
+    from dlrover_tpu.tpu_timer.bridge import ensure_native_built
 
-    Everything here is BOUNDED: this runs on the agent's hang-recovery
-    path (_stop_workers post-mortem), where an unbounded flock or make
-    would let the hang diagnostic hang the recovery itself. A lock held
-    past the deadline or a wedged compiler raises (TimeoutError /
-    CalledProcessError) and the caller degrades to the Python-only
-    dump."""
-    if os.path.exists(_SAMPLER_PATH):
-        return _SAMPLER_PATH
-    lock_path = os.path.join(
-        tempfile.gettempdir(), "dlrover_tpu_timer_build.lock"
-    )
-    deadline = time.time() + timeout
-    with open(lock_path, "w") as lock:
-        while True:
-            try:
-                fcntl.flock(lock, fcntl.LOCK_EX | fcntl.LOCK_NB)
-                break
-            except OSError:
-                if time.time() > deadline:
-                    raise TimeoutError(
-                        f"build lock {lock_path} held past {timeout}s"
-                    )
-                time.sleep(0.2)
-        try:
-            if not os.path.exists(_SAMPLER_PATH):
-                logger.info("building stack_sampler (first use)")
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, "stack_sampler"],
-                    check=True,
-                    capture_output=True,
-                    timeout=max(deadline - time.time(), 10.0),
-                )
-        finally:
-            fcntl.flock(lock, fcntl.LOCK_UN)
-    return _SAMPLER_PATH
+    return ensure_native_built("stack_sampler", timeout)
 
 
 def sample_native_stacks(

@@ -15,6 +15,7 @@ import time
 from dataclasses import dataclass
 from typing import Optional
 
+from dlrover_tpu.common.compile_cache import enable_compile_cache
 from dlrover_tpu.common.constants import NodeEnv, WorkerEnv
 from dlrover_tpu.common.log import logger
 
@@ -69,6 +70,7 @@ def init_distributed(timeout_secs: int = 300) -> DistributedContext:
     if _context is not None:
         return _context
     ctx = read_worker_env()
+    enable_compile_cache()
     if ctx.num_processes > 1 and ctx.coordinator_address:
         import jax
 

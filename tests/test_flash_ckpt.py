@@ -384,23 +384,24 @@ def test_async_writer_does_not_pollute_block_cost(tmp_path):
         engine.close()
 
 
-def test_fetch_barrier_touches_every_leaf():
-    """The restore-timing barrier must fetch through every leaf (it is
-    the honest replacement for block_until_ready, which can return
-    early on async-dispatch backends) and tolerate mixed dtypes."""
+def test_sharded_restore_counts_the_batched_branch():
+    """A sharded restore on one host takes the batched device_put and
+    says so: the per-leaf fallback only logs a warning, and
+    chip_smoke.py fails the run if the count shows it ran."""
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from dlrover_tpu.flash_ckpt.engine import fetch_barrier
+    from dlrover_tpu.flash_ckpt import engine as engine_lib
 
-    tree = {
-        "params": {"w": jnp.ones((4, 4)), "b": jnp.arange(3)},
-        "step": jnp.asarray(7, jnp.int32),
-        "flag": jnp.asarray(True),
-        "meta": "not-an-array",  # non-array leaves are skipped
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+    tree = {"w": np.arange(32.0).reshape(8, 4), "step": np.int32(7)}
+    shardings = {
+        "w": NamedSharding(mesh, P("dp")), "step": NamedSharding(mesh, P()),
     }
-    total = fetch_barrier(tree)
-    # 1.0 (w[0,0]) + 0 (b[0]) + 7 (step) + 1 (flag)
-    assert total == 9.0
-    # Second call reuses the cached jitted probe (same avals).
-    assert fetch_barrier(tree) == 9.0
+    before = dict(engine_lib.RESTORE_BRANCH_COUNTS)
+    state = to_device_state(tree, shardings)
+    assert engine_lib.RESTORE_BRANCH_COUNTS == {
+        "batched": before["batched"] + 1, "per_leaf": before["per_leaf"],
+    }
+    assert state["w"].sharding == shardings["w"]
+    np.testing.assert_array_equal(np.asarray(state["w"]), tree["w"])

@@ -409,7 +409,7 @@ def tiny():
     return cfg, params
 
 
-def _paged_factory(tiny, slots=4, **kw):
+def _paged_factory(tiny, slots=4, max_len=48, **kw):
     from dlrover_tpu.serving.kvpool import PagedServingEngine
 
     cfg, params = tiny
@@ -418,7 +418,7 @@ def _paged_factory(tiny, slots=4, **kw):
         # Enough slots that a burst of concurrent migrations is never
         # refused for want of a destination slot.
         eng = PagedServingEngine(
-            cfg, params, slots=slots, max_len=48, prefill_chunk=4,
+            cfg, params, slots=slots, max_len=max_len, prefill_chunk=4,
             block_size=8, **kw,
         )
         eng.warmup()
@@ -427,12 +427,13 @@ def _paged_factory(tiny, slots=4, **kw):
     return factory
 
 
-def _reference_tokens(tiny, prompts, max_new):
+def _reference_tokens(tiny, prompts, max_new, max_len=48):
     from dlrover_tpu.serving.kvpool import PagedServingEngine
 
     cfg, params = tiny
     eng = PagedServingEngine(
-        cfg, params, slots=2, max_len=48, prefill_chunk=4, block_size=8,
+        cfg, params, slots=2, max_len=max_len, prefill_chunk=4,
+        block_size=8,
     )
     eng.warmup()
     out = []
@@ -491,18 +492,27 @@ def test_thread_fleet_live_drain_token_exact(tiny):
         ).tolist()
         for s in (7, 8)
     ]
-    expected = _reference_tokens(tiny, prompts, 24)
+    # Long decodes (hundreds of ms on the CPU): the drain below lands
+    # mid-decode however fast the machine is. At 24 tokens a request
+    # could finish inside the 50 ms settle sleep, leaving nothing to
+    # migrate.
+    new_tokens, max_len = 400, 416
+    expected = _reference_tokens(tiny, prompts, new_tokens, max_len)
     router = FleetRouter(
         [
-            ThreadReplica("m0", _paged_factory(tiny), role="mixed"),
-            ThreadReplica("m1", _paged_factory(tiny), role="mixed"),
+            ThreadReplica(
+                "m0", _paged_factory(tiny, max_len=max_len), role="mixed"
+            ),
+            ThreadReplica(
+                "m1", _paged_factory(tiny, max_len=max_len), role="mixed"
+            ),
         ],
         RouterConfig(),
         registry=MetricsRegistry(),
     )
     router.start()
     try:
-        reqs = [router.submit(p, 24) for p in prompts]
+        reqs = [router.submit(p, new_tokens) for p in prompts]
         # Let both replicas admit and start decoding.
         deadline = time.monotonic() + 30.0
         while time.monotonic() < deadline:

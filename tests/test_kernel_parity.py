@@ -155,7 +155,7 @@ def test_dispatch_env_knob_round_trip():
         os.environ["DLROVER_TPU_MOE_DISPATCH"] = "gmm"
         assert moe_lib._dispatch_impl() == "gmm"
         os.environ["DLROVER_TPU_MOE_DISPATCH"] = "not-a-dispatch"
-        assert moe_lib._dispatch_impl() == "fused"  # loud fallback
+        assert moe_lib._dispatch_impl() == "gmm"  # loud fallback
     finally:
         if old is None:
             os.environ.pop("DLROVER_TPU_MOE_DISPATCH", None)

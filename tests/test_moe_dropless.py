@@ -212,3 +212,22 @@ def _step(cfg, opt, params, ostate, batch):
     import optax
 
     return optax.apply_updates(params, upd), ostate, loss
+
+
+def test_dispatch_ab_probe_runs_and_agrees():
+    """tools/bench_moe_dispatch.py (the on-chip gmm-vs-fused A/B that
+    ROADMAP S3 decides from) at a tiny shape in interpret mode: both
+    dispatches run fwd+bwd and agree."""
+    import os
+    import sys
+
+    sys.path.insert(
+        0, os.path.join(os.path.dirname(__file__), "..", "tools")
+    )
+    import bench_moe_dispatch
+
+    out = bench_moe_dispatch.run(
+        batch=2, seq=64, d=128, f=128, experts=4, repeats=1
+    )
+    assert out["ok"], out
+    assert out["fused_over_gmm"] > 0

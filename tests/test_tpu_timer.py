@@ -3,6 +3,7 @@ timeline dump, and the agent-side Prometheus collector."""
 
 import http.client
 import json
+import os
 import threading
 import time
 
@@ -223,3 +224,60 @@ def test_sigusr2_dumps_and_does_not_kill(tmp_path):
     proc.terminate()
     _, err = proc.communicate(timeout=10)
     assert b"Thread" in err or b"File" in err  # traceback was dumped
+
+
+def test_native_binary_is_rebuilt_when_its_sources_change(
+    tmp_path, monkeypatch
+):
+    """The binaries are git-ignored: a fresh checkout has none and must
+    build them on first use, and a copied tree may hold one built from
+    OTHER sources — that one must be rebuilt, whatever its mtime."""
+    import shutil
+
+    from dlrover_tpu.tpu_timer import bridge
+
+    native = tmp_path / "tpu_timer"
+    native.mkdir()
+    for name in os.listdir(bridge._NATIVE_DIR):
+        if name == "Makefile" or name.endswith((".cc", ".h")):
+            shutil.copy(os.path.join(bridge._NATIVE_DIR, name), native)
+    monkeypatch.setattr(bridge, "_NATIVE_DIR", str(native))
+
+    path = bridge.ensure_native_built("stack_sampler")
+    assert os.access(path, os.X_OK)  # built from nothing
+    built = os.stat(path).st_mtime_ns
+    assert bridge.ensure_native_built("stack_sampler") == path
+    assert os.stat(path).st_mtime_ns == built  # up to date: untouched
+
+    with open(native / "stack_sampler.cc", "a") as f:
+        f.write("\n// changed\n")
+    future = time.time() + 3600
+    os.utime(path, (future, future))  # a "newer" stale binary
+    bridge.ensure_native_built("stack_sampler")
+    assert os.stat(path).st_mtime_ns != int(future * 1e9)  # rebuilt
+
+
+def test_unbuildable_native_binary_loads_if_present_else_raises(
+    tmp_path, monkeypatch
+):
+    """A read-only install or a host without a compiler: a binary that
+    is there is used with a warning (it cannot be shown to match its
+    sources); with none there, the failure is raised."""
+    import subprocess
+
+    from dlrover_tpu.tpu_timer import bridge
+
+    native = tmp_path / "tpu_timer"
+    native.mkdir()
+    (native / "Makefile").write_text("libx.so:\n\tfalse\n")
+    monkeypatch.setattr(bridge, "_NATIVE_DIR", str(native))
+
+    with pytest.raises(subprocess.CalledProcessError):
+        bridge.ensure_native_built("libx.so")
+    (native / "libx.so").write_bytes(b"prebuilt, unstamped")
+    warned = []
+    monkeypatch.setattr(
+        bridge.logger, "warning", lambda msg, *a: warned.append(msg % a)
+    )
+    assert bridge.ensure_native_built("libx.so") == str(native / "libx.so")
+    assert len(warned) == 1 and "may not match" in warned[0]

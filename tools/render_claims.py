@@ -122,8 +122,7 @@ def render_block(path: str) -> str:
         ("Measured SIGKILL recovery (detect+restart+restore+replay)",
          g("measured_recovery_s"),
          f"{fmt(g('measured_recovery_s'))} s"),
-        ("— of which recovery machinery (excl. wire-bound state "
-         "transfer)",
+        ("— of which recovery machinery (excl. the state transfer)",
          g("e2e_machinery_recovery_s"),
          f"{fmt(g('e2e_machinery_recovery_s'))} s"),
         ("End-to-end goodput @ MTBF 3600s, autotuned cadence",
@@ -179,8 +178,9 @@ def render_block(path: str) -> str:
         else "driver-captured"
     )
     lines = [
-        f"Measured on real v5e hardware — source: `{name}` "
-        f"({origin}).",
+        f"A DATED RECORD, not the state of this tree: measured on a "
+        f"v5e on 2026-08-01, before PRs 1-20, and not re-measured "
+        f"since — source: `{name}` ({origin}).",
         "",
         "| Metric | Measured |",
         "|---|---|",
