@@ -429,9 +429,10 @@ def transformer_layer(
     attn_fn = attention_fn or dot_product_attention
 
     residual = x
-    # named_scope: the scope lands in every op's trace metadata (tf_op),
-    # forward AND backward — the basis of the bench's mfu_breakdown
-    # (tpu_timer/xla_capture.bucket_by_scope).
+    # named_scope: the scope lands in the compiled HLO's op_name of
+    # every op, forward AND backward; benchmark/trace_reduce.py buckets
+    # device time by it (scopes_from_hlo — this JAX's trace events
+    # carry no tf_op).
     with jax.named_scope("attn"):
         q, k, v = attention_qkv(config, p, x, positions)
         attn = attn_fn(q, k, v, causal=True,

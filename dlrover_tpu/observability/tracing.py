@@ -18,14 +18,42 @@ Design rules, same discipline as :func:`dlrover_tpu.fault.fault_point`:
   returns a shared no-op object. No locks, no allocation, no branches
   beyond the one check.
 - **Armed is cheap.** A finished span is one dict append into a bounded
-  ring plus (when a sink is configured) one buffered JSONL line. The
-  serving bench A/Bs the armed cost (<2% tokens/s budget).
+  ring plus (when a sink is configured) one buffered JSONL line.
+  On a TPU v5e serving Mistral-NeMo widths (PERF.md, PR 24) the armed
+  engine — request spans plus one step span and a dozen clock reads per
+  ~30 ms iteration — completed 580.5 tokens/s against 583.1 with
+  request spans alone when armed with a ring only (-0.4 %, inside
+  those runs' own 0.3-0.9 % spread), and 581.1 against 580.6 when both
+  also wrote a JSONL sink, as ``DLROVER_TPU_TRACE_FILE`` arms them.
+  Emitting one step span takes ~0.09 ms there into the ring, ~0.15 ms
+  with the sink (its ``json.dumps``), and the sink then grows four
+  times as fast (32.6 against 7.7 KB a second).
 - **Hot loops emit retrospectively.** The engine/trainer never open
-  spans inside their step loops — they already record the timestamps
-  they need (submit/admit/first-token/finish), and emit the whole
-  phase tree in one :meth:`Tracer.record_span` burst at completion.
-  A disarmed process pays the one global check per completion, zero
-  per-iteration.
+  live spans inside their step loops — they keep plain floats
+  (submit/admit/first-token/finish per request; armed, a mark at each
+  phase boundary of an engine step) and emit whole phase trees through
+  :meth:`Tracer.record_span`: a request's at its completion, one
+  ``serving.step`` span per engine iteration. A disarmed process pays
+  the one global check per completion and per iteration, and no clock
+  read.
+- **Step spans stay local.** ``record_span(..., local=True)`` reaches
+  the ring and the sink, never the export buffer or ``on_finish``:
+  ~33 ``serving.step`` spans a second would evict every request trace
+  from the master's aggregator (256 traces) and fill the 1,024-deep
+  export buffer in half a minute. A local record is not flushed on its
+  own (72 -> 54 us a record on the v5e's host); the next request span
+  or ``close()`` flushes it. Steps share the ring with request spans:
+  the default 4,096 then reach back 75 s where requests alone had
+  3 minutes (docs/DESIGN.md §29).
+- **One clock with the device trace.** A record's ``ts`` is epoch
+  seconds (``time.time()`` back-dated by the monotonic distance),
+  ``mono`` is ``time.monotonic()``. The JAX profiler's host plane
+  (``TraceAnnotation``) counts nanoseconds from ITS SESSION'S start on
+  that same epoch clock: with the session's start added back (the
+  xplane's "Task Environment" plane, ``profile_start_time``), ``ts``
+  is on the profiler's clock to within 0.01 ms (median lead of a
+  bracketing annotation 1.8 us, quartile spread 0.4 us, worst of 183
+  pairs 10 us; TPU v5e host, PERF.md, PR 24).
 
 Cross-process arming mirrors the fault plane: ``DLROVER_TPU_TRACE_FILE``
 names the JSONL sink; a subprocess calls :func:`arm_from_env` early in
@@ -270,12 +298,17 @@ class Tracer:
         parent=None,
         attrs: Optional[Dict] = None,
         status: str = "ok",
+        local: bool = False,
     ) -> Span:
         """Retrospective span from already-recorded monotonic
         timestamps — the hot-loop pattern: the engine/trainer keeps
         plain floats during the loop and emits the whole phase tree in
         one burst at completion. Returns the finished span so children
-        can parent to it."""
+        can parent to it.
+
+        ``local`` spans stay in this process: ring and JSONL sink, but
+        neither the export buffer nor ``on_finish`` (the module
+        docstring says why ``serving.step`` is one)."""
         trace_id, parent_id = self._resolve_parent(parent)
         now_mono = time.monotonic()
         start_wall = time.time() - (now_mono - start_mono)
@@ -284,7 +317,8 @@ class Tracer:
             start_mono=start_mono, start_wall=start_wall,
         )
         sp.status = status
-        sp.end(end_mono=max(end_mono, start_mono))
+        sp.end_mono = max(end_mono, start_mono)
+        self._finish(sp, local=local)
         return sp
 
     def _resolve_parent(self, parent):
@@ -315,24 +349,25 @@ class Tracer:
 
     # ---- finish path -------------------------------------------------------
 
-    def _finish(self, span: Span):
+    def _finish(self, span: Span, local: bool = False):
         record = span.to_dict()
         if self.service:
             record["service"] = self.service
         record["pid"] = os.getpid()
         with self._lock:
-            if len(self._exports) == self._exports.maxlen:
-                self._dropped += 1
             self._ring.append(record)
-            self._exports.append(record)
-            self._write_locked(record)
-        if self._on_finish is not None:
+            self._write_locked(record, flush=not local)
+            if not local:
+                if len(self._exports) == self._exports.maxlen:
+                    self._dropped += 1
+                self._exports.append(record)
+        if self._on_finish is not None and not local:
             try:
                 self._on_finish(record)
             except Exception:  # noqa: BLE001 — observer must not break sites
                 logger.debug("trace on_finish hook failed", exc_info=True)
 
-    def _write_locked(self, record: Dict):
+    def _write_locked(self, record: Dict, flush: bool = True):
         if not self._sink_path:
             return
         try:
@@ -342,7 +377,10 @@ class Tracer:
                 )
                 self._sink_file = open(self._sink_path, "a")
             self._sink_file.write(json.dumps(record) + "\n")
-            self._sink_file.flush()
+            if flush:
+                # A local (per-iteration) record rides in the file's
+                # buffer until the next request span or close() flushes.
+                self._sink_file.flush()
         except OSError:
             # A full/vanished disk must not take down the traced job.
             self._sink_path = None

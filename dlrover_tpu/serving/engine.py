@@ -71,6 +71,37 @@ class _CompiledSteps(NamedTuple):
 _NGRAM_WINDOW = 128
 
 
+# Phase vocabulary of a ``serving.step`` span (docs/DESIGN.md §29), in
+# the order one iteration can pass them. A phase is named by the mark
+# that CLOSES it, so the host time between two marks always belongs to
+# the later one and the phases tile the span whatever path a step takes.
+STEP_PHASES = (
+    "admit", "prefill_prep", "prefill_launch", "prefill_fetch",
+    "decode_prep", "decode_launch", "decode_fetch",
+    "spec_draft", "spec_verify", "commit", "account",
+)
+
+
+class _StepTrace:
+    """Marks and counts of ONE armed ``step()``: plain floats and ints
+    while the iteration runs, one retrospective span at its end."""
+
+    __slots__ = ("t0", "last", "phases", "counts")
+
+    def __init__(self, t0: float):
+        self.t0 = self.last = t0
+        self.phases: List[list] = []
+        self.counts = {"n_admitted": 0, "n_decoding": 0,
+                       "prefill_tokens": 0}
+
+    def mark(self, phase: str, at: Optional[float] = None) -> None:
+        """Close ``phase`` now (or at an already-taken clock read)."""
+        if at is None:
+            at = time.monotonic()
+        self.phases.append([phase, self.last - self.t0, at - self.last])
+        self.last = at
+
+
 class _SpecSteps(NamedTuple):
     """Speculative-decoding programs, compiled SEPARATELY from the
     base prefill/decode pair: a spec-on and a spec-off engine with the
@@ -422,6 +453,8 @@ class ServingEngine:
         # (a verify step that commits 4 tokens is 4 cheap tokens, not
         # one slow one).
         self._iter_advance: List[int] = []
+        # The armed step's phase marks; None whenever no Tracer is armed.
+        self._step_trace: Optional[_StepTrace] = None
         self._trace_snapshot = self._all_trace_counts()
         self._rng = rng if rng is not None else jax.random.key(0)
         self._step_idx = 0
@@ -535,8 +568,17 @@ class ServingEngine:
     def step(self) -> List[Request]:
         """One scheduler iteration: admissions, at most one prefill
         chunk, one ragged decode step. Returns requests finished THIS
-        iteration (tokens fully populated)."""
+        iteration (tokens fully populated).
+
+        With a Tracer armed the iteration also times itself: one
+        ``local`` span ``serving.step`` whose ``phases`` (STEP_PHASES)
+        tile it, with the counts taken where the work happens.
+        Disarmed, the ``is not None`` checks are the whole cost."""
         t0 = time.monotonic()
+        tracer = tracing.active_tracer()
+        st = self._step_trace = (
+            _StepTrace(t0) if tracer is not None else None
+        )
         sch = self.scheduler
         finished: List[Request] = []
         self._iter_advance = []
@@ -544,7 +586,8 @@ class ServingEngine:
             # Past-deadline queued work is an explicit terminal outcome,
             # surfaced through step()'s return like any completion.
             self._report_shed(req, finished)
-        for req in sch.admit(t0):
+        admitted = sch.admit(t0)
+        for req in admitted:
             self._admit_slot(req)
             if req.requeues == 0:
                 # Re-admission after a step-error requeue is not a new
@@ -559,6 +602,8 @@ class ServingEngine:
             # Deadline lapsed while waiting for a free slot: shed at
             # the admission decision, same terminal surface.
             self._report_shed(req, finished)
+        self._mark("admit", "n_admitted", len(admitted))
+        status = "ok"
         try:
             fault_point("serving.step.error", step_idx=self._step_idx)
             pf = sch.pick_prefill()
@@ -570,6 +615,8 @@ class ServingEngine:
         except Exception as e:  # noqa: BLE001 — device/XLA errors vary
             self._recover_from_step_error(e, finished)
             self._iter_advance = []
+            status = "error"
+        idx = self._step_idx
         self._step_idx += 1
         self.metrics.iterations.inc()
         self.metrics.queue_depth.set(len(sch.queue))
@@ -577,7 +624,7 @@ class ServingEngine:
             self.metrics.class_queue_depth.set(depth, slo_class=name)
         self.metrics.active_slots.set(len(sch.active()))
         self._sync_pool_metrics()
-        self._sync_retrace_metric()
+        retraces = self._sync_retrace_metric()
         if self._iter_advance:
             # One observation PER EMITTED TOKEN at the per-token cost,
             # not one per iteration at the full wall time — a verify
@@ -589,7 +636,28 @@ class ServingEngine:
             for adv in self._iter_advance:
                 for _ in range(adv):
                     self.metrics.token_latency.observe(per_tok)
+        if st is not None:
+            self._step_trace = None
+            st.mark("account")
+            attrs = dict(st.counts, idx=idx, phases=st.phases,
+                         n_finished=len(finished))
+            if retraces:
+                attrs["retraces"] = retraces  # THIS step recompiled
+            tracer.record_span(
+                "serving.step", st.t0, st.last, attrs=attrs,
+                status=status, local=True,
+            )
         return finished
+
+    def _mark(self, phase: str, count: Optional[str] = None,
+              value: int = 0, at: Optional[float] = None) -> None:
+        """Armed steps only: close step phase ``phase`` here (``at``: a
+        clock read the site already took) and note a count with it."""
+        st = self._step_trace
+        if st is not None:
+            st.mark(phase, at)
+            if count is not None:
+                st.counts[count] = value
 
     def run_until_idle(self, max_iters: int = 100000) -> List[Request]:
         """Drive step() until nothing is pending; returns all finished."""
@@ -700,19 +768,28 @@ class ServingEngine:
         n_valid = min(c, req.prompt_len - start)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
+        self._mark("prefill_prep", "prefill_tokens", n_valid)
         self._k, self._v, first = self._steps.prefill(
             self._k, self._v, self._params, jnp.asarray(chunk),
             np.int32(req.slot), np.int32(start), np.int32(n_valid),
             np.float32(req.temperature), self._rng,
             np.int32(self._step_idx),
         )
+        self._mark("prefill_launch")
         req.prefill_pos += n_valid
         self._lengths[req.slot] = req.prefill_pos
         self.metrics.tokens.inc(n_valid, kind="prefill")
         if req.prefill_pos < req.prompt_len:
             return  # more chunks to come; `first` is discarded unfetched
+        self._commit_first_token(req, first, finished)
+
+    def _commit_first_token(self, req: Request, first,
+                            finished: List[Request]):
+        """Tail of a prompt's FINAL chunk: fetch the sampled token
+        (blocks on the device) and move the request to DECODE."""
         tok = int(jax.device_get(first))
         req.first_token_ts = time.monotonic()
+        self._mark("prefill_fetch", at=req.first_token_ts)
         if req.requeues == 0:
             # A re-run after a step-error requeue would re-observe an
             # inflated first-token latency for the same request.
@@ -724,6 +801,7 @@ class ServingEngine:
             self._finish(req, finished)
         else:
             req.state = DECODE
+        self._mark("commit")
 
     def _run_decode(self, decoding: List[Request],
                     finished: List[Request]):
@@ -733,13 +811,22 @@ class ServingEngine:
         active = np.zeros(self.slots, bool)
         for r in decoding:
             active[r.slot] = True
+        self._mark("decode_prep", "n_decoding", len(decoding))
         self._k, self._v, nxt = self._steps.decode(
             self._k, self._v, self._params,
             jnp.asarray(self._lengths), jnp.asarray(self._tokens),
             jnp.asarray(active), jnp.asarray(self._temps),
             self._rng, np.int32(self._step_idx),
         )
+        self._mark("decode_launch")
+        self._commit_decode(decoding, nxt, finished)
+
+    def _commit_decode(self, decoding: List[Request], nxt,
+                       finished: List[Request]):
+        """Fetch the step's tokens (blocks on the device) and hand one
+        to every decoding request."""
         nxt = np.asarray(jax.device_get(nxt))
+        self._mark("decode_fetch")
         for r in decoding:
             self._lengths[r.slot] += 1   # the fed token's KV landed
             tok = int(nxt[r.slot])
@@ -753,6 +840,7 @@ class ServingEngine:
                 # No room to feed the token just sampled.
                 r.truncated = True
                 self._finish(r, finished)
+        self._mark("commit")
 
     # ---- speculative decode (§35) ------------------------------------------
 
@@ -772,13 +860,16 @@ class ServingEngine:
         for r in decoding:
             active[r.slot] = True
         t_d = time.monotonic()
+        self._mark("decode_prep", "n_decoding", len(decoding), at=t_d)
         drafts, draft_len = self._spec_draft(decoding, active)
         t_v = time.monotonic()
+        self._mark("spec_draft", at=t_v)
         emitted, acc = self._spec_verify_device(active, drafts,
                                                 draft_len)
         emitted = np.asarray(jax.device_get(emitted))
         acc = np.asarray(jax.device_get(acc))
         t_e = time.monotonic()
+        self._mark("spec_verify", at=t_e)
         n_dec = len(decoding)
         d_dt = (t_v - t_d) / n_dec
         v_dt = (t_e - t_v) / n_dec
@@ -819,6 +910,7 @@ class ServingEngine:
         self.metrics.spec_tokens_per_step.set(
             self._spec_emitted / self._spec_slot_steps
         )
+        self._mark("commit")
 
     def _spec_prepare_rows(self, decoding: List[Request]):
         """Make rows fill..fill+spec_k writable for every decoding
@@ -1002,9 +1094,12 @@ class ServingEngine:
                 attrs={"spec_accepted": req.spec_accepted},
             )
 
-    def _sync_retrace_metric(self):
+    def _sync_retrace_metric(self) -> int:
+        """Count programs traced since the last call; returns that
+        delta so an armed step can say it is the one that recompiled."""
         now = self._all_trace_counts()
         delta = sum(now.values()) - sum(self._trace_snapshot.values())
         if delta > 0:
             self.metrics.retraces.inc(delta)
             self._trace_snapshot = dict(now)
+        return delta

@@ -48,7 +48,6 @@ slot's logical cache is the pool rows its BLOCK TABLE names:
 """
 
 import functools
-import time
 from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
@@ -1077,6 +1076,7 @@ class PagedServingEngine(ServingEngine):
             self._privatize(req, idx)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
+        self._mark("prefill_prep", "prefill_tokens", n_valid)
         *pools, first = self._steps.prefill(
             *self._pools(), self._params, jnp.asarray(chunk),
             jnp.asarray(self._tables[req.slot]),
@@ -1085,6 +1085,7 @@ class PagedServingEngine(ServingEngine):
             np.int32(self._step_idx),
         )
         self._set_pools(pools)
+        self._mark("prefill_launch")
         req.prefill_pos += n_valid
         self._lengths[req.slot] = req.prefill_pos
         self.metrics.tokens.inc(n_valid, kind="prefill")
@@ -1097,17 +1098,7 @@ class PagedServingEngine(ServingEngine):
             self._cache.insert(
                 req.prompt, self._slot_blocks[req.slot][:n_full]
             )
-        tok = int(jax.device_get(first))
-        req.first_token_ts = time.monotonic()
-        if req.requeues == 0:
-            self.metrics.ttft.observe(req.ttft_s)
-        req.tokens.append(tok)
-        self._tokens[req.slot] = tok
-        self.metrics.tokens.inc(kind="decode")
-        if len(req.tokens) >= req.max_new_tokens:
-            self._finish(req, finished)
-        else:
-            req.state = DECODE
+        self._commit_first_token(req, first, finished)
 
     def _run_decode(self, decoding: List[Request],
                     finished: List[Request]):
@@ -1129,6 +1120,7 @@ class PagedServingEngine(ServingEngine):
         active = np.zeros(self.slots, bool)
         for r in decoding:
             active[r.slot] = True
+        self._mark("decode_prep", "n_decoding", len(decoding))
         *pools, nxt = self._steps.decode(
             *self._pools(), self._params, jnp.asarray(self._tables),
             jnp.asarray(self._lengths), jnp.asarray(self._tokens),
@@ -1136,19 +1128,8 @@ class PagedServingEngine(ServingEngine):
             self._rng, np.int32(self._step_idx),
         )
         self._set_pools(pools)
-        nxt = np.asarray(jax.device_get(nxt))
-        for r in decoding:
-            self._lengths[r.slot] += 1
-            tok = int(nxt[r.slot])
-            r.tokens.append(tok)
-            self._tokens[r.slot] = tok
-            self.metrics.tokens.inc(kind="decode")
-            self._iter_advance.append(1)
-            if len(r.tokens) >= r.max_new_tokens:
-                self._finish(r, finished)
-            elif self._lengths[r.slot] + 1 > self.max_len:
-                r.truncated = True
-                self._finish(r, finished)
+        self._mark("decode_launch")
+        self._commit_decode(decoding, nxt, finished)
 
     # ---- speculative decode hooks (§35) ------------------------------------
 

@@ -196,8 +196,8 @@ def make_train_step(
         else:
             loss, _, grads = single_grad(params, {"tokens": tokens})
 
-        # named_scope: lands in trace metadata (tf_op) for the bench's
-        # mfu_breakdown (tpu_timer/xla_capture.bucket_by_scope).
+        # named_scope: lands in the compiled HLO's op_name, which
+        # benchmark/trace_reduce.py buckets device time by.
         with jax.named_scope("optimizer"):
             updates, new_opt = optimizer.update(
                 grads, state["opt_state"], params

@@ -1,0 +1,280 @@
+"""The serving engine times its own step (docs/DESIGN.md §29): armed,
+every ``step()`` emits one ``local`` span ``serving.step`` whose phases
+tile it and whose counts add up to the work done; disarmed, nothing is
+emitted and no clock read is added."""
+
+import json
+import os
+import sys
+import time
+
+import pytest
+
+import jax
+
+from dlrover_tpu.fault import FaultRule, FaultSchedule
+from dlrover_tpu.fault import arm as arm_faults
+from dlrover_tpu.fault import disarm as disarm_faults
+from dlrover_tpu.models import llama
+from dlrover_tpu.observability import tracing
+from dlrover_tpu.observability.registry import MetricsRegistry
+from dlrover_tpu.observability.tracing import Tracer
+from dlrover_tpu.serving import engine as engine_mod
+from dlrover_tpu.serving.engine import STEP_PHASES, ServingEngine
+from dlrover_tpu.serving.kvpool import PagedServingEngine
+
+pytestmark = pytest.mark.trace
+
+KINDS = ("flat", "paged", "speculative")
+# (prompt length, new tokens): three requests over two slots, so one
+# waits for a slot, and prompts of one, two and three 8-token chunks.
+PLAN = ((5, 6), (11, 4), (19, 5))
+
+
+@pytest.fixture(scope="module")
+def parts():
+    cfg = llama.tiny_config()
+    params, _ = llama.init_params(cfg, jax.random.key(0))
+    return cfg, params
+
+
+def build(kind, parts):
+    cfg, params = parts
+    kw = dict(slots=2, max_len=64, prefill_chunk=8,
+              registry=MetricsRegistry())
+    if kind == "paged":
+        return PagedServingEngine(cfg, params, block_size=8, **kw)
+    return ServingEngine(
+        cfg, params, spec_k=3 if kind == "speculative" else 0, **kw
+    )
+
+
+def serve(eng):
+    """The PLAN through ``eng``; returns the requests, all finished."""
+    reqs = [
+        eng.submit([(7 * i + j) % 50 + 1 for j in range(n)], new)
+        for i, (n, new) in enumerate(PLAN)
+    ]
+    eng.run_until_idle(max_iters=500)
+    assert all(r.state == "done" for r in reqs)
+    return reqs
+
+
+@pytest.fixture()
+def tracer(tmp_path):
+    t = tracing.arm(
+        Tracer(service="test", sink_path=str(tmp_path / "spans.jsonl"))
+    )
+    yield t
+    tracing.disarm()
+
+
+def step_spans(tracer):
+    return [s for s in tracer.finished() if s["name"] == "serving.step"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_phases_tile_every_step_span(kind, parts, tracer):
+    eng = build(kind, parts)
+    serve(eng)
+    spans = step_spans(tracer)
+    assert len(spans) == eng._step_idx > 0
+    assert [s["attrs"]["idx"] for s in spans] == list(range(len(spans)))
+    seen = set()
+    for s in spans:
+        assert s["parent_id"] is None and s["status"] == "ok"
+        phases = s["attrs"]["phases"]
+        cursor = 0.0
+        for name, offset, dur in phases:
+            assert name in STEP_PHASES
+            assert offset == pytest.approx(cursor, abs=1e-9)
+            assert dur >= 0.0
+            cursor = offset + dur
+            seen.add(name)
+        assert phases[0][0] == "admit" and phases[-1][0] == "account"
+        assert sum(p[2] for p in phases) == pytest.approx(
+            s["dur_s"], abs=1e-6
+        )
+    launches = (
+        {"spec_draft", "spec_verify"} if kind == "speculative"
+        else {"decode_launch", "decode_fetch"}
+    )
+    assert seen == {
+        "admit", "prefill_prep", "prefill_launch", "prefill_fetch",
+        "decode_prep", "commit", "account",
+    } | launches
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_counts_add_up_to_the_work_done(kind, parts, tracer):
+    reqs = serve(build(kind, parts))
+    attrs = [s["attrs"] for s in step_spans(tracer)]
+    assert sum(a["prefill_tokens"] for a in attrs) == sum(
+        n for n, _ in PLAN
+    )
+    assert sum(a["n_admitted"] for a in attrs) == len(PLAN)
+    assert sum(a["n_finished"] for a in attrs) == len(PLAN)
+    decoded = sum(len(r.tokens) - 1 for r in reqs)
+    slot_steps = sum(a["n_decoding"] for a in attrs)
+    if kind == "speculative":
+        # A verify step hands a slot one token or more.
+        assert 0 < slot_steps <= decoded
+    else:
+        assert slot_steps == decoded
+    assert all(
+        set(a) - {"retraces"} == {
+            "idx", "phases", "n_admitted", "n_decoding",
+            "prefill_tokens", "n_finished",
+        } for a in attrs
+    )
+    assert all("retraces" not in a for a in attrs[3:])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_tokens_are_identical_armed_and_disarmed(kind, parts):
+    plain = [r.tokens for r in serve(build(kind, parts))]
+    tracing.arm(Tracer(service="test"))
+    try:
+        armed = [r.tokens for r in serve(build(kind, parts))]
+    finally:
+        tracing.disarm()
+    assert armed == plain
+    assert [len(t) for t in plain] == [new for _, new in PLAN]
+
+
+class CountingClock:
+    """``time`` for the engine module, counting its monotonic reads."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def monotonic(self):
+        self.reads += 1
+        return time.monotonic()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_disarmed_no_span_and_no_added_clock_read(
+    kind, parts, monkeypatch
+):
+    clock = CountingClock()
+    monkeypatch.setattr(engine_mod, "time", clock)
+    tracer = tracing.arm(Tracer(service="test"))
+    try:
+        serve(build(kind, parts))
+        steps = [s["attrs"] for s in step_spans(tracer)]
+    finally:
+        tracing.disarm()
+    armed_reads, clock.reads = clock.reads, 0
+    n_spans = len(tracer.finished())
+    eng = build(kind, parts)
+    serve(eng)
+    assert len(tracer.finished()) == n_spans and eng._step_trace is None
+    # What step() read before it timed itself: its own start, the
+    # token-latency observation of a step that emitted tokens, every
+    # request's first-token stamp, and the speculative path's three
+    # draft / verify marks.
+    decoding = sum(1 for a in steps if a["n_decoding"])
+    expected = len(steps) + decoding + len(PLAN)
+    if kind == "speculative":
+        expected += 3 * decoding
+    assert clock.reads == expected
+    assert armed_reads > expected
+
+
+def test_a_local_span_stays_in_the_process(tmp_path):
+    seen = []
+    sink = tmp_path / "spans.jsonl"
+    tracer = Tracer(service="t", sink_path=str(sink),
+                    on_finish=seen.append)
+    tracer.record_span("serving.step", 1.0, 2.0, local=True)
+    # A local record rides in the sink's buffer; the next span that is
+    # not local flushes both, in order.
+    assert sink.read_text() == ""
+    tracer.record_span("serving.request", 1.0, 3.0)
+    assert len(sink.read_text().splitlines()) == 2
+    tracer.close()
+    assert [s["name"] for s in tracer.finished()] == [
+        "serving.step", "serving.request",
+    ]
+    on_disk = [json.loads(line) for line in sink.read_text().splitlines()]
+    assert [s["name"] for s in on_disk] == [
+        "serving.step", "serving.request",
+    ]
+    assert [s["name"] for s in seen] == ["serving.request"]
+    assert [s["name"] for s in tracer.drain_exports()] == [
+        "serving.request"
+    ]
+
+
+def test_the_engine_exports_requests_and_never_steps(parts, tracer):
+    serve(build("paged", parts))
+    exported = {s["name"] for s in tracer.drain_exports(10 ** 6)}
+    assert "serving.request" in exported
+    assert "serving.step" not in exported
+    assert step_spans(tracer)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_an_injected_step_error_yields_an_error_span(kind, parts, tracer):
+    eng = build(kind, parts)
+    arm_faults(FaultSchedule(
+        [FaultRule("serving.step.error", nth=3)], seed=0
+    ))
+    try:
+        reqs = serve(eng)
+    finally:
+        disarm_faults()
+    spans = step_spans(tracer)
+    assert [s["status"] for s in spans].count("error") == 1
+    bad = next(s for s in spans if s["status"] == "error")
+    assert bad["attrs"]["idx"] == 2
+    assert [p[0] for p in bad["attrs"]["phases"]] == ["admit", "account"]
+    assert sum(p[2] for p in bad["attrs"]["phases"]) == pytest.approx(
+        bad["dur_s"], abs=1e-6
+    )
+    # The requeued requests restart: their prompts are prefilled twice.
+    assert sum(s["attrs"]["prefill_tokens"] for s in spans) > sum(
+        n for n, _ in PLAN
+    )
+    assert [len(r.tokens) for r in reqs] == [new for _, new in PLAN]
+
+
+def test_trace_query_renders_a_step_span(parts, tracer, tmp_path, capsys):
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools",
+    ))
+    import trace_query
+
+    serve(build("flat", parts))
+    tracer.close()
+    sink = str(tmp_path / "spans.jsonl")
+    step = step_spans(tracer)[1]
+    assert trace_query.main(["--trace", step["trace_id"], sink]) == 0
+    out = capsys.readouterr().out
+    assert "serving.step" in out and "account" in out
+    assert trace_query.main(["--serving", "--json", sink]) == 0
+    rows = {r["name"] for r in json.loads(capsys.readouterr().out)}
+    assert "step" not in rows and "decode" in rows
+    assert trace_query.main(["--steps", "--json", sink]) == 0
+    table = json.loads(capsys.readouterr().out)
+    assert {r["name"] for r in table["phases"]} <= set(STEP_PHASES)
+    assert sum(r["share_pct"] for r in table["phases"]) == pytest.approx(
+        100.0, abs=0.1
+    )
+    spans = step_spans(tracer)
+    assert table["counts"] == {
+        "steps": len(spans), "errors": 0, "admitted": len(PLAN),
+        "finished": len(PLAN),
+        "prefill_tokens": sum(n for n, _ in PLAN),
+        "decode_batch_mean": pytest.approx(
+            table["counts"]["decode_batch_mean"]
+        ),
+        "retraced_steps": [
+            s["attrs"]["idx"] for s in spans if "retraces" in s["attrs"]
+        ],
+    }
+    assert 1.0 <= table["counts"]["decode_batch_mean"] <= 2.0
+    assert trace_query.main(["--steps", sink]) == 0
+    assert "retraced_steps=" in capsys.readouterr().out

@@ -23,7 +23,13 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # split, §35) plus each phase's share of serving.request time
     python tools/trace_query.py --serving spans_engine.jsonl
 
-    # one trace's tree + critical path
+    # engine iterations: the phases of the serving.step spans folded
+    # into one table with each phase's share of the summed step time,
+    # then the steps' counts (admitted / finished requests, prompt
+    # tokens, mean decode batch, which steps recompiled)
+    python tools/trace_query.py --steps spans_engine.jsonl
+
+    # one trace's tree + critical path (a serving.step: its phases)
     python tools/trace_query.py --trace 7f3a... spans_*.jsonl
 
 Plain stdlib + the tracing module's own loaders — usable on any box
@@ -97,7 +103,8 @@ def serving_summary(spans: List[Dict]) -> List[Dict]:
         {**s, "name": s.get("name", "")[len("serving."):]}
         for s in spans
         if s.get("name", "").startswith("serving.")
-        and s.get("name") != "serving.request"
+        # serving.step times an engine iteration, not a request phase.
+        and s.get("name") not in ("serving.request", "serving.step")
     ])
     total = sum(
         s.get("dur_s") or 0.0
@@ -108,6 +115,43 @@ def serving_summary(spans: List[Dict]) -> List[Dict]:
         summed = r["mean_s"] * r["count"]
         r["share_pct"] = round(100.0 * summed / total, 2) if total else 0.0
     return rows
+
+
+def step_summary(spans: List[Dict]) -> Dict:
+    """Where an engine iteration's time goes, from the engine's own
+    ``serving.step`` spans (§29). ``phases``: one row per step phase,
+    :func:`summarize`'s columns over the steps that passed through it,
+    plus ``share_pct`` of the summed step time (phases tile a step, so
+    the shares sum to 100). ``counts``: what the steps carried —
+    ``n_admitted`` explains a long ``admit`` (a prefix lookup per
+    admission), ``n_finished`` a long ``commit`` (a finished request
+    emits its span tree there), ``retraced_steps`` names the
+    iterations that recompiled a program."""
+    steps = [
+        s for s in spans
+        if s.get("name") == "serving.step" and s.get("dur_s") is not None
+    ]
+    rows = summarize([
+        {"name": name, "dur_s": dur, "status": s.get("status")}
+        for s in steps for name, _offset, dur in s["attrs"]["phases"]
+    ])
+    total = sum(s["dur_s"] for s in steps)
+    for r in rows:
+        summed = r["mean_s"] * r["count"]
+        r["share_pct"] = round(100.0 * summed / total, 2) if total else 0.0
+    attrs = [s["attrs"] for s in steps]
+    decoding = [a["n_decoding"] for a in attrs if a["n_decoding"]]
+    return {"phases": rows, "counts": {
+        "steps": len(steps),
+        "errors": sum(s.get("status") != "ok" for s in steps),
+        "admitted": sum(a["n_admitted"] for a in attrs),
+        "finished": sum(a["n_finished"] for a in attrs),
+        "prefill_tokens": sum(a["prefill_tokens"] for a in attrs),
+        "decode_batch_mean": (
+            sum(decoding) / len(decoding) if decoding else 0.0
+        ),
+        "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
+    }}
 
 
 def summarize(spans: List[Dict]) -> List[Dict]:
@@ -179,6 +223,13 @@ def render_tree(node: Dict, indent: int = 0) -> List[str]:
     ]
     for child in node.get("children", []):
         lines.extend(render_tree(child, indent + 1))
+    # A serving.step span has no children; its phases tile it.
+    for name, _offset, phase_s in (node.get("attrs") or {}).get(
+        "phases", ()
+    ):
+        lines.append(
+            f"{phase_s * 1e3:9.3f}ms  {'  ' * (indent + 1)}{name}"
+        )
     return lines
 
 
@@ -197,6 +248,9 @@ def main(argv=None) -> int:
                     help="per-phase latency table from serving.* "
                     "request spans (queue/prefill/migrate/decode + "
                     "draft/verify split, with request-time share)")
+    ap.add_argument("--steps", action="store_true",
+                    help="per-phase latency table from serving.step "
+                    "spans (share of step time) + the steps' counts")
     ap.add_argument("--trace",
                     help="print one trace's tree + critical path")
     ap.add_argument("--json", action="store_true",
@@ -225,35 +279,39 @@ def main(argv=None) -> int:
             )
         return 0
 
-    if ns.summary or ns.verbs or ns.serving:
+    if ns.summary or ns.verbs or ns.serving or ns.steps:
+        counts = None
         if ns.verbs:
             rows = verb_summary(spans)
         elif ns.serving:
             rows = serving_summary(spans)
+        elif ns.steps:
+            table = step_summary(spans)
+            rows, counts = table["phases"], table["counts"]
         else:
             rows = summarize(spans)
         if ns.verbs and not rows:
             print("no master.<verb> server spans found", file=sys.stderr)
             return 1
-        if ns.serving and not rows:
+        if (ns.serving or ns.steps) and not rows:
             print("no serving.* spans found", file=sys.stderr)
             return 1
         if ns.json:
-            print(json.dumps(rows))
+            print(json.dumps(table if ns.steps else rows))
             return 0
-        share_hdr = f"{'share%':>8}" if ns.serving else ""
+        share_hdr = f"{'share%':>8}" if ns.serving or ns.steps else ""
         print(f"{'name':<28}{'count':>7}{'err':>5}{'mean_ms':>10}"
               f"{'p50_ms':>10}{'p95_ms':>10}{'max_ms':>10}{share_hdr}")
         for r in rows:
-            share = (
-                f"{r['share_pct']:>8.2f}" if ns.serving else ""
-            )
+            share = f"{r['share_pct']:>8.2f}" if share_hdr else ""
             print(
                 f"{r['name']:<28}{r['count']:>7}{r['errors']:>5}"
                 f"{r['mean_s'] * 1e3:>10.3f}{r['p50_s'] * 1e3:>10.3f}"
                 f"{r['p95_s'] * 1e3:>10.3f}{r['max_s'] * 1e3:>10.3f}"
                 f"{share}"
             )
+        if counts is not None:
+            print("  ".join(f"{k}={v}" for k, v in counts.items()))
         return 0
 
     rows = slowest(spans, top=ns.top, name=ns.name)
