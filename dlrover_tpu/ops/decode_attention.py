@@ -22,13 +22,24 @@ bandwidth proportional to the raggedness, while this grid clamps each
 slot to its own fill.
 
 Parity note: the reference delegates decode to vLLM/torch kernels
-(paged attention); :func:`decode_attention` is the TPU-native analogue
-for this repo's single-slab cache, and :func:`paged_decode_attention`
-is the block-table generalization for the paged KV pool
-(serving/kvpool): the per-row block table rides as a SECOND
-scalar-prefetch operand and the kv index map dereferences it, so grid
-step ``j`` of row ``ib`` DMAs pool block ``table[ib, j]`` — gather
-through the table with zero extra HBM traffic for the indirection.
+(paged attention). Three kernels live here:
+
+- :func:`decode_attention`, for this repo's single-slab cache. Its
+  ``(batch, kv_head, block)`` grid runs sequentially on a TPU and lost
+  to the append-free XLA step at 334 M parameters and a <= 384-row
+  cache (3.675 vs 1.35 ms/token, BENCH_r05), where KV is no part of
+  the bytes; opt-in by ``DLROVER_TPU_DECODE_ATTN``.
+- :func:`paged_decode_attention`, the same grid over ONE layer's pool
+  with the block table as a second scalar-prefetch operand. At a real
+  serving shape (16 slots x 144 pages x 8 KV heads) that grid is
+  18,432 steps of 4 KB a layer, and fed ``k[layer]`` it would keep
+  the copy of the layer's pool: called by parity tests only.
+- :func:`pool_decode_attention`, what ``PagedServingEngine``'s decode
+  program runs on a TPU: the STACKED pool read in place, one call a
+  layer, a page of all KV heads per DMA, only each decoding slot's
+  filled pages, chunks of pages double-buffered across slots. The
+  trace that ROADMAP S4 waited for is PERF.md §5 (PR 25): the XLA
+  gather moved the cache at full capacity four times a layer.
 """
 
 import functools
@@ -479,3 +490,315 @@ def decode_attention(
         interpret=interpret,
     )(*operands)
     return out[:, :, :g, :].reshape(b, h, d)
+
+
+# ---- paged decode attention over the STACKED pool, in place ---------------
+
+# Bytes of cache one inner step of the pool kernel copies (K and V each,
+# double-buffered: four such buffers in VMEM). 1 MB is 512 rows of 8 KV
+# heads x 128, 32 pages of 16 rows. On the v5e at 16 slots x 12 layers
+# of that shape (``tools/bench_paged_decode.py``, my chip runs, PR 25):
+# 256 KB / 512 KB / 1 MB / 2 MB take 2.45 / 2.14 / 2.03 / 2.11 ms at chat
+# fills (mean ~900 rows), 4.7 / 3.9 / 3.7 / 3.8 ms with every slot full,
+# 0.92 / 0.99 / 1.09 / 1.52 ms at 128 rows a slot; at 32 KV heads 512 KB /
+# 1 MB / 2 MB take 3.52 / 3.48 / 3.52, 7.6 / 7.2 / 7.3, 1.06 / 1.06 / 1.19. Sized in bytes, not rows: the buffers grow with
+# ``kv_heads * head_dim``, and the kernel's scoped VMEM (16 MB on the
+# v5e) holds four of them beside the score tiles — at 3 MB a buffer the
+# compiler still takes the kernel, at 3.5 MB it refuses it.
+_POOL_CHUNK_BYTES = 1 << 20
+
+
+def _pool_chunk_pages(block_size: int, kv_heads: int, head_dim: int,
+                      max_blocks: int) -> int:
+    """Pages of a bf16 pool in one VMEM chunk: as many whole pages as
+    ``_POOL_CHUNK_BYTES`` holds, at most a slot's table; 0 where not
+    even one fits."""
+    page_bytes = block_size * kv_heads * head_dim * 2
+    return min(_POOL_CHUNK_BYTES // page_bytes, max_blocks)
+
+
+def pool_kernel_supported(pool_dtype, block_size: int, kv_heads: int,
+                          head_dim: int) -> bool:
+    """Shapes the in-place pool kernel lowers for on a TPU: a bf16 pool
+    whose page ``[block_size * kv_heads, head_dim]`` is a whole number
+    of (16, 128) bf16 tiles, so that one page is one contiguous DMA and
+    the collapse of ``[block_size, kv_heads]`` into rows moves no byte,
+    and no larger than one VMEM chunk (``_POOL_CHUNK_BYTES``: a page is
+    the unit of copy, so a larger one would size the four buffers past
+    what the kernel's VMEM holds). ``kv_heads`` a multiple of 8 is what
+    makes that collapse a bitcast of XLA's ``T(8,128)(2,1)`` layout of
+    ``[..., kv_heads, head_dim]``."""
+    return (
+        jnp.dtype(pool_dtype) == jnp.bfloat16
+        and head_dim % 128 == 0
+        and kv_heads % 8 == 0
+        and (block_size * kv_heads) % 16 == 0
+        and _pool_chunk_pages(block_size, kv_heads, head_dim, 1) == 1
+    )
+
+
+def _split_bf16(x):
+    """``x`` (f32) as three bf16 arrays whose sum is ``x`` exactly (8 +
+    8 + 8 mantissa bits). A bf16 x bf16 product is exact in f32, so one
+    MXU pass over the three stacked as extra rows gives the f32 product
+    without rounding ``x`` (the MXU is bound by loading the OTHER
+    operand's tiles; a few more rows ride free)."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return [hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _dot_f32_by_stored(a, b, dims):
+    """``a`` (f32, [rows, n]) contracted with ``b`` as it is stored.
+    A bf16 ``b`` takes ``a`` as three bf16 addends stacked along rows
+    (see :func:`_split_bf16`); any other dtype (interpret mode on f32
+    pools) is a plain f32 contraction."""
+    if b.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            a, b.astype(jnp.float32), dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    rows = a.shape[0]
+    out = jax.lax.dot_general(
+        jnp.concatenate(_split_bf16(a), 0), b, dims,
+        preferred_element_type=jnp.float32,
+    )
+    return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
+
+
+def _pool_kernel(
+    layer_ref, pages_ref, len_ref, tbl_ref,       # scalar prefetch
+    q_ref, s_new_ref, v_new_ref, k_hbm, v_hbm,    # inputs
+    o_ref,                                        # output
+    kbuf, vbuf, sem,                              # scratch
+    *, chunk_pages: int, page_rows: int, kv_heads: int, group: int,
+    max_blocks: int,
+):
+    """One call = one layer's decode attention for every slot. The
+    pools stay in HBM; each slot's filled pages are copied page by page
+    (one contiguous DMA each) into a double-buffered VMEM chunk of
+    ``chunk_pages`` pages, the next chunk — of this slot or of the next
+    — in flight while this one is computed.
+
+    A chunk is ``[chunk_pages * block_size * kv_heads, head_dim]``: row
+    ``c`` is cache row ``c // kv_heads`` of KV head ``c % kv_heads``,
+    exactly as the page lies in the pool. All query heads are scored
+    against all rows in one matmul and a constant mask keeps each
+    head's own KV head, so no byte of a page is moved to separate the
+    heads: the MXU time is set by streaming the chunk through it once
+    either way, the wasted columns cost only VPU time — ``kv_heads``
+    times the useful softmax work, which per byte of cache copied goes
+    by query heads / ``head_dim``. Measured on the v5e at 16 slots x
+    2,304 rows (``tools/bench_paged_decode.py``, PR 25): at 32 query
+    heads x 128 the kernel is bound by its page DMAs, with 8 KV heads
+    and with 32 (MHA) alike; 64 query heads cost a quarter more time.
+    A layout that scores each KV head apart has not been measured. The
+    call is one sequential program (no grid): one TensorCore, which is
+    all a v5e has."""
+    slots, hp, _ = o_ref.shape
+    layer = layer_ref[0]
+    cols = chunk_pages * page_rows
+    block_size = page_rows // kv_heads
+
+    # What a page copy has not filled must still be FINITE: a masked
+    # column's probability is exactly 0, and 0 x NaN would poison p.V.
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    def pages_in(slot, chunk):
+        return jnp.clip(pages_ref[slot] - chunk * chunk_pages,
+                        0, chunk_pages)
+
+    def page_copies(slot, chunk, buf, i):
+        blk = tbl_ref[slot * max_blocks + chunk * chunk_pages + i]
+        dst = pl.ds(pl.multiple_of(i * page_rows, page_rows), page_rows)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, blk], kbuf.at[buf, dst], sem.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, blk], vbuf.at[buf, dst], sem.at[1, buf]
+            ),
+        )
+
+    def start(slot, chunk, buf):
+        def body(i, carry):
+            for cp in page_copies(slot, chunk, buf, i):
+                cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in(slot, chunk), body, 0)
+
+    def wait(slot, chunk, buf):
+        def body(i, carry):
+            for cp in page_copies(slot, chunk, buf, i):
+                cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in(slot, chunk), body, 0)
+
+    # Column c of a chunk belongs to KV head c % kv_heads; query head r
+    # reads KV head r // group.
+    col = jax.lax.broadcasted_iota(jnp.int32, (hp, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, cols), 0)
+    own_head = (col % kv_heads) == (row // group)
+    nt = (((1,), (1,)), ((), ()))      # [m, d] x [n, d] -> [m, n]
+    nn = (((1,), (0,)), ((), ()))      # [m, n] x [n, d] -> [m, d]
+
+    def attend(slot, chunk, buf, m, l, acc):
+        s = _dot_f32_by_stored(q_ref[slot], kbuf[buf], nt)
+        # Visibility: cache row < the slot's fill, i.e. column <
+        # (fill - first row of the chunk) * kv_heads.
+        limit = (
+            len_ref[slot] - chunk * chunk_pages * block_size
+        ) * kv_heads
+        s = jnp.where(own_head & (col < limit), s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)          # masked: exp(-1e30 - m) == 0
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + _dot_f32_by_stored(p, vbuf[buf], nn)
+        return m_new, l, acc
+
+    start(0, 0, 0)
+
+    def slot_body(slot, buf):
+        # Every slot is visited for one chunk at least; one with no
+        # page to read (free, mid-prefill, or an empty cache) copies
+        # and computes nothing and answers with its new token alone.
+        n_chunks = jnp.maximum(
+            (pages_ref[slot] + chunk_pages - 1) // chunk_pages, 1
+        )
+
+        def chunk_body(chunk, carry):
+            buf, m, l, acc = carry
+            last = chunk + 1 >= n_chunks
+            nxt_slot = jnp.where(last, slot + 1, slot)
+            nxt_chunk = jnp.where(last, 0, chunk + 1)
+
+            @pl.when(nxt_slot < slots)
+            def _():
+                start(nxt_slot, nxt_chunk, 1 - buf)
+
+            wait(slot, chunk, buf)
+            m, l, acc = jax.lax.cond(
+                pages_in(slot, chunk) > 0,
+                lambda: attend(slot, chunk, buf, m, l, acc),
+                lambda: (m, l, acc),
+            )
+            return 1 - buf, m, l, acc
+
+        # The new token's own K/V open the online softmax: the running
+        # max is a real logit from the start, so no -inf arithmetic.
+        buf, _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, chunk_body,
+            (buf, s_new_ref[slot], jnp.ones((hp, 1), jnp.float32),
+             v_new_ref[slot]),
+        )
+        o_ref[slot] = acc / l
+        return buf
+
+    jax.lax.fori_loop(0, slots, slot_body, 0)
+
+
+def pool_decode_attention(
+    q,             # [b, n_heads, d] — ONE query token per slot
+    k_new,         # [b, kv_heads, d] — that token's own K/V, not yet
+    v_new,         #   in the pool (the append-free step)
+    k_pool,        # [layers, num_blocks, block_size, kv_heads, d]
+    v_pool,
+    layer,         # [] int32 — which layer of the stacked pool
+    block_tables,  # [b, max_blocks] int32
+    length,        # [b] int32 — filled logical rows per slot
+    active,        # [b] bool — a slot that is not active reads nothing
+    interpret=None,
+):
+    """The paged decode step's attention, read from the stacked pool IN
+    PLACE: ``_append_free_attention`` over ``k_pool[layer][tables]``
+    without the slice, without the gathered ``[slots, max_len]`` view,
+    and without the rows past each slot's fill.
+
+    The pools go into the kernel whole (``memory_space=ANY``); the
+    layer index, the per-slot page counts and fills and the flattened
+    block tables ride as scalar prefetch and pick the pages, each one
+    contiguous DMA of ``block_size x kv_heads x d``. A slot stops at
+    its last filled page and an inactive one copies nothing; rows of
+    that page past the fill are masked (visibility: row < length).
+
+    Arithmetic is the reference's: K and V are used as stored, the
+    scaled query, logits, running max, sum, probabilities and
+    accumulator are f32, and the f32 operands meet the bf16 ones
+    unrounded (:func:`_split_bf16`). What differs is the order of
+    summation: an online softmax over chunks of
+    ``_POOL_CHUNK_BYTES`` of pages, opened by the new token's own term. (On
+    a TPU XLA runs the reference's f32 einsums at default matmul
+    precision, one bf16 pass: there the gather path is ~3e-3 off the
+    exact softmax and this kernel ~5e-7, ``tools/bench_paged_decode.py``
+    on the v5e, PR 25.)
+    Returns ``[b, n_heads, d]`` in ``q.dtype``; an inactive slot's row
+    is its own ``v_new`` (finite, and discarded by the caller)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, d = q.shape
+    n_layers, nb_pool, block_size, kh, _ = k_pool.shape
+    _, max_blocks = block_tables.shape
+    if h % kh:
+        raise ValueError(f"n_heads {h} not divisible by kv_heads {kh}")
+    g = h // kh
+    page_rows = block_size * kh
+    # Off the chip (interpret mode, f32 pools) any page goes; on it the
+    # caller asked pool_kernel_supported, so at least one page fits.
+    chunk_pages = max(1, _pool_chunk_pages(block_size, kh, d, max_blocks))
+    # _append_free_attention's operands, to the letter: the query is
+    # scaled in its own dtype, then everything is f32.
+    q32 = (q * d ** -0.5).astype(jnp.float32)
+    s_new = jnp.einsum(
+        "bkgd,bkd->bkg", q32.reshape(b, kh, g, d),
+        k_new.astype(jnp.float32),
+    ).reshape(b, h, 1)
+    v_rows = jnp.repeat(v_new.astype(jnp.float32), g, axis=1)
+    # Query heads padded to whole bf16 tiles, so that the addends of
+    # _split_bf16 stack on tile edges.
+    hp = -(-h // 16) * 16
+    pad = ((0, 0), (0, hp - h), (0, 0))
+    q32, s_new, v_rows = (jnp.pad(x, pad) for x in (q32, s_new, v_rows))
+    fill = jnp.where(active, jnp.asarray(length, jnp.int32), 0)
+    fill = jnp.minimum(fill, max_blocks * block_size)
+    scalars = (
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        (fill + block_size - 1) // block_size,
+        fill,
+        jnp.asarray(block_tables, jnp.int32).reshape(-1),
+    )
+    pooled = (n_layers, nb_pool, page_rows, d)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(
+            _pool_kernel, chunk_pages=chunk_pages, page_rows=page_rows,
+            kv_heads=kh, group=g, max_blocks=max_blocks,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(),
+            in_specs=[
+                vmem, vmem, vmem,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk_pages * page_rows, d), k_pool.dtype),
+                pltpu.VMEM((2, chunk_pages * page_rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hp, d), jnp.float32),
+        interpret=interpret,
+        name="paged_pool_decode_attention",
+    )(
+        *scalars, q32, s_new, v_rows,
+        k_pool.reshape(pooled), v_pool.reshape(pooled),
+    )
+    return out[:, :h].astype(q.dtype)

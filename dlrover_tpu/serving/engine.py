@@ -659,6 +659,20 @@ class ServingEngine:
             if count is not None:
                 st.counts[count] = value
 
+    def _mark_decode_prep(self, decoding: List[Request],
+                          at: Optional[float] = None) -> None:
+        """Close ``decode_prep`` with what the launch will carry: the
+        slots in it and ``kv_rows``, the cache rows visible to them
+        (over ``n_decoding * max_len`` it is the share of the logical
+        view that attention has any use for)."""
+        st = self._step_trace
+        if st is not None:
+            st.mark("decode_prep", at)
+            st.counts["n_decoding"] = len(decoding)
+            st.counts["kv_rows"] = int(
+                sum(self._lengths[r.slot] for r in decoding)
+            )
+
     def run_until_idle(self, max_iters: int = 100000) -> List[Request]:
         """Drive step() until nothing is pending; returns all finished."""
         done: List[Request] = []
@@ -811,7 +825,7 @@ class ServingEngine:
         active = np.zeros(self.slots, bool)
         for r in decoding:
             active[r.slot] = True
-        self._mark("decode_prep", "n_decoding", len(decoding))
+        self._mark_decode_prep(decoding)
         self._k, self._v, nxt = self._steps.decode(
             self._k, self._v, self._params,
             jnp.asarray(self._lengths), jnp.asarray(self._tokens),
@@ -860,7 +874,7 @@ class ServingEngine:
         for r in decoding:
             active[r.slot] = True
         t_d = time.monotonic()
-        self._mark("decode_prep", "n_decoding", len(decoding), at=t_d)
+        self._mark_decode_prep(decoding, at=t_d)
         drafts, draft_len = self._spec_draft(decoding, active)
         t_v = time.monotonic()
         self._mark("spec_draft", at=t_v)

@@ -9,23 +9,31 @@ slot's logical cache is the pool rows its BLOCK TABLE names:
 - **Block tables as traced args.** The ``[slots, max_blocks]`` int32
   tables ride into the compiled steps exactly like the fill vector:
   every admission/allocation/COW changes table VALUES, never shapes, so
-  the no-retrace-across-admissions property survives paging. Inside
-  the decode step each layer gathers its per-slot logical view through
-  the table and runs the SAME append-free ragged attention as the flat
-  engine (``models/generate._layer_decode_read_only``) — token-exact by
-  construction. The append is a per-slot scatter at ``(table[cursor //
-  bs], cursor % bs)``; non-active slots are redirected to the reserved
-  SENTINEL block 0 so their masked-garbage writes can never land in a
-  block another slot shares (the flat engine's own-row trick does not
-  survive sharing). Note on the hot path: the XLA gather reads the
-  same ``[slots, max_len]`` logical view per layer the FLAT engine's
-  append-free step already reads — paging's win here is CAPACITY
-  (blocks per admitted token), not per-step bandwidth. The
-  length-clamped Pallas variant (``ops.decode_attention.
-  paged_decode_attention``, parity-tested) is the TPU-targeted
-  alternative, deliberately not the default for the same measured
-  reason as the flat engine's (§21): the per-(batch, kv-head) grid
-  serializes on TPU and loses to the XLA step at serving shapes.
+  the no-retrace-across-admissions property survives paging. The
+  append is a per-slot scatter at ``(table[cursor // bs], cursor %
+  bs)``; non-active slots are redirected to the reserved SENTINEL block
+  0 so their masked-garbage writes can never land in a block another
+  slot shares (the flat engine's own-row trick does not survive
+  sharing).
+- **Decode attention reads the pool in place** (``decode_attention``:
+  ``"paged_kernel"``). On a TPU with a bf16 pool whose page tiles and
+  fits a VMEM chunk (``ops.decode_attention.pool_kernel_supported``;
+  anything else takes the gather, nothing fails to build) each layer's
+  attention is one Pallas call over the WHOLE stacked pool: the layer
+  index, the tables and the fills pick the pages, only each decoding
+  slot's filled pages are copied (one contiguous DMA a page), and the
+  new token's own K/V open the online softmax, so the step stays
+  append-free. The alternative (``"xla_gather"``: everywhere else,
+  and the plain reference of the parity tests) gathers each slot's
+  logical ``[max_len]`` view through the table and runs the flat
+  engine's ``models/generate._layer_decode_read_only`` on it. On the
+  chip that path moved the cache at its full CAPACITY four times a
+  layer — the scan's slice of the layer's pool, the gathered view,
+  and one read each for K and V — as many bytes as the weights at
+  ``nemo12b-serve-chat`` (PERF.md §5, PR 25). Which one a decode
+  program was built with follows from the platform and the pool, not
+  from a knob; the engine logs it once at construction. The verify /
+  draft programs and int8 pools still gather (no cell runs them).
 - **Visibility invariant, unchanged.** A logical row is read iff
   ``row < fill``; stale or foreign content beyond a slot's fill —
   including the longer tail of a SHARED prefix block — is masked out
@@ -79,6 +87,7 @@ class _PagedSteps(NamedTuple):
     imp: object          # migration import: host block rows -> pool[dst]
     exp: object          # migration export: pool[src] -> one block's rows
     trace_counts: Dict[str, int]
+    decode_attention: str = "xla_gather"   # see decode_attention_kind
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -92,17 +101,70 @@ class _PagedSpecSteps(NamedTuple):
     trace_counts: Dict[str, int]
 
 
+def _on_tpu() -> bool:
+    """The platform probe of :func:`decode_attention_kind` (tests patch
+    it to take the kernel path in interpret mode)."""
+    return jax.default_backend() == "tpu"
+
+
+def decode_attention_kind(config, block_size: int, kv_dtype: str) -> str:
+    """Which attention the plain decode program is built with:
+    ``"paged_kernel"`` (the pool read in place, filled pages only) where
+    the kernel lowers — a TPU, a bf16 pool, a page that tiles and fits
+    a VMEM chunk — and ``"xla_gather"`` otherwise. Decided by what the
+    code can see; there is no option for it, and nothing falls back
+    after it, so what it admits has to compile
+    (``tests/test_tpu_compile.py`` holds it to that over GQA, MHA, wide
+    heads and short caches). The cache's size is no part of it: on the
+    v5e the kernel was ahead of the gather down to 4 slots x 576 rows
+    and 16 slots x 128 rows (PERF.md §6, PR 25)."""
+    if kv_dtype != "fp" or not _on_tpu():
+        return "xla_gather"
+    # Pallas costs ~1.2 s to import: only a process that may run the
+    # kernel pays it (the repo's idiom for ops/ kernels).
+    from dlrover_tpu.ops.decode_attention import pool_kernel_supported
+
+    if pool_kernel_supported(
+        config.compute_dtype, block_size, config.n_kv_heads,
+        config.head_dim,
+    ):
+        return "paged_kernel"
+    return "xla_gather"
+
+
+def _layer_decode_pool(config, p, x, positions, attend):
+    """``generate._layer_decode_read_only`` with the cache behind
+    ``attend(q, k_new, v_new)`` (``[slots, heads, d]`` each) in place of
+    a ``[slots, max_len]`` slab: the paged decode's memory is a pool
+    and tables, so its attention shares no logic with the slab's."""
+    residual = x
+    if "wqkv" in p:
+        q, k, v = gen_lib._fused_qkv(config, p, x, positions)
+    else:
+        q, k, v = llama.attention_qkv(config, p, x, positions)
+    attn = attend(q[:, 0], k[:, 0], v[:, 0])[:, None]
+    x = llama.attention_out(config, p, attn, residual)
+    if "w_gu" in p:
+        x = gen_lib._fused_mlp(config, p, x)
+    else:
+        x, _ = llama.mlp_block(config, p, x)
+    return x, k, v
+
+
 def _build_paged_decode(config, slots: int, max_blocks: int,
                         block_size: int, counts,
-                        quantized: bool = False):
-    """[slots] tokens -> one decoded token per slot, ragged lengths,
-    cache gathered per layer through the block tables. ``quantized``:
-    int8 pools + per-(row, head) scale pools — the gather streams half
-    the KV bytes and the append quantizes each new row (ops/kv_quant);
+                        quantized: bool = False,
+                        attn: str = "xla_gather"):
+    """[slots] tokens -> one decoded token per slot, ragged lengths.
+    ``attn`` (:func:`decode_attention_kind`): ``"paged_kernel"`` reads
+    each layer's K/V straight from the stacked pool through the block
+    tables, filled pages only; ``"xla_gather"`` gathers the cache per
+    layer into a ``[slots, max_len]`` view. ``quantized``: int8 pools +
+    per-(row, head) scale pools — the gather streams half the KV bytes
+    and the append quantizes each new row (ops/kv_quant);
     dequantization folds into the attention math."""
     max_len = max_blocks * block_size
     kh, hd = config.n_kv_heads, config.head_dim
-
     def _append_coords(tables, lengths, active):
         # Per-slot append through the table. Non-active slots are
         # redirected to the sentinel block: their garbage must never
@@ -138,9 +200,34 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
             )
             return y, (k_new, v_new)
 
-        x, (k_news, v_news) = jax.lax.scan(
-            body, x, (params["layers"], k, v)
-        )
+        def body_in_place(carry, layer_in):
+            # The pools are closed over WHOLE: as scanned inputs the
+            # loop would slice a layer's pool out (a copy of all of
+            # it) before the kernel could pick its pages.
+            from dlrover_tpu.ops.decode_attention import (
+                pool_decode_attention,
+            )
+
+            pl, layer = layer_in
+            y, k_new, v_new = _layer_decode_pool(
+                config, pl, carry, positions,
+                lambda q, k_new, v_new: pool_decode_attention(
+                    q, k_new, v_new, k, v, layer, tables, lengths,
+                    active,
+                ),
+            )
+            return y, (k_new, v_new)
+
+        if attn == "paged_kernel":
+            x, (k_news, v_news) = jax.lax.scan(
+                body_in_place, x,
+                (params["layers"],
+                 jnp.arange(config.n_layers, dtype=jnp.int32)),
+            )
+        else:
+            x, (k_news, v_news) = jax.lax.scan(
+                body, x, (params["layers"], k, v)
+            )
         blk, off = _append_coords(tables, lengths, active)
         k = k.at[:, blk, off].set(k_news[:, :, 0].astype(k.dtype))
         v = v.at[:, blk, off].set(v_news[:, :, 0].astype(v.dtype))
@@ -588,7 +675,6 @@ def _paged_spec_steps(
                            trace_counts=counts)
 
 
-@functools.lru_cache(maxsize=16)
 def _paged_steps(
     config: llama.TpuLMConfig, slots: int, num_blocks: int,
     max_blocks: int, block_size: int, chunk: int,
@@ -597,13 +683,26 @@ def _paged_steps(
     """Compiled once per shape key, shared across engines (the flat
     engine's lru_cache discipline). Pools donated; tables/lengths/ids
     all plain traced arguments. ``kv_dtype`` "int8" programs also
-    donate the scale pools."""
+    donate the scale pools. The decode program's attention
+    (:func:`decode_attention_kind`) is part of the key."""
+    return _paged_steps_for(
+        config, slots, num_blocks, max_blocks, block_size, chunk,
+        kv_dtype, decode_attention_kind(config, block_size, kv_dtype),
+    )
+
+
+@functools.lru_cache(maxsize=16)
+def _paged_steps_for(
+    config: llama.TpuLMConfig, slots: int, num_blocks: int,
+    max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
+    attn: str,
+) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
     pool_args = (0, 1, 2, 3) if quantized else (0, 1)
     decode = jax.jit(
         _build_paged_decode(config, slots, max_blocks, block_size,
-                            counts, quantized=quantized),
+                            counts, quantized=quantized, attn=attn),
         donate_argnums=pool_args,
     )
     prefill = jax.jit(
@@ -623,7 +722,8 @@ def _paged_steps(
     # from them until the importer acks.
     exp = jax.jit(_build_export_gather(counts, quantized=quantized))
     return _PagedSteps(prefill=prefill, decode=decode, cow=cow,
-                       imp=imp, exp=exp, trace_counts=counts)
+                       imp=imp, exp=exp, trace_counts=counts,
+                       decode_attention=attn)
 
 
 class PagedServingEngine(ServingEngine):
@@ -735,6 +835,16 @@ class PagedServingEngine(ServingEngine):
             config, slots, self.num_blocks, self.max_blocks,
             block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
         )
+        logger.info(
+            "paged engine: %d slots x %d rows, %d blocks of %d "
+            "(%s KV), decode attention %s",
+            slots, max_len, self.num_blocks, block_size,
+            kv_cache_dtype, self.decode_attention,
+        )
+        self.metrics.annotate(
+            "serving_engine_built", slots=slots, max_len=max_len,
+            decode_attention=self.decode_attention,
+        )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
             # __init__ bound were never traced — jit is lazy).
@@ -763,6 +873,12 @@ class PagedServingEngine(ServingEngine):
     @property
     def _quantized(self) -> bool:
         return self.kv_cache_dtype == "int8"
+
+    @property
+    def decode_attention(self) -> str:
+        """``"paged_kernel"`` or ``"xla_gather"``: what the plain decode
+        program was built with (:func:`decode_attention_kind`)."""
+        return self._steps.decode_attention
 
     def _fresh_pool(self):
         shape = (
@@ -1120,7 +1236,7 @@ class PagedServingEngine(ServingEngine):
         active = np.zeros(self.slots, bool)
         for r in decoding:
             active[r.slot] = True
-        self._mark("decode_prep", "n_decoding", len(decoding))
+        self._mark_decode_prep(decoding)
         *pools, nxt = self._steps.decode(
             *self._pools(), self._params, jnp.asarray(self._tables),
             jnp.asarray(self._lengths), jnp.asarray(self._tokens),

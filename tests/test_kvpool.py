@@ -5,6 +5,9 @@ zero retraces across admissions with varying block tables, SLO-class
 weighted-fair admission + admission-time deadline sheds, and the paged
 Pallas decode kernel's parity through a shuffled block table."""
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -479,6 +482,207 @@ def test_paged_decode_attention_matches_flat_through_shuffled_table():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
+
+
+# Each case: per-slot fills, which slots decode, and what its table
+# rows are made of, for a 5-slot pool of 16-row pages with 32 query
+# heads on 8 KV heads and a 320-row cache, read in 128-row chunks.
+_POOL_CASES = {
+    # Nothing cached, one row, exactly a page, mid-page, the whole cache.
+    "fills_on_every_edge": dict(fills=(0, 1, 16, 23, 320)),
+    # Chunk edges: one row short of a chunk, exactly one, one more;
+    # exactly two, and one more.
+    "fills_around_a_chunk_edge": dict(fills=(127, 128, 129, 256, 257)),
+    # Slot 1 is free and slot 3 mid-prefill: their table rows name
+    # pages of NaNs, which an inactive slot must never read.
+    "inactive_slots_with_stale_tables": dict(
+        fills=(40, 33, 7, 200, 90), active=(1, 0, 1, 0, 1),
+        poisoned=(1, 3),
+    ),
+    # Slots 0 and 1 share their first three pages (a prefix-cache hit)
+    # and read them to different depths; slot 1 goes on in its own.
+    "two_slots_share_prefix_blocks": dict(
+        fills=(37, 70, 5, 48, 120), shared=((0, 1), 3),
+    ),
+}
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_POOL_CASES))
+def test_pool_decode_attention_matches_gather(
+    case, pool_dtype, monkeypatch
+):
+    """The in-place pool kernel (interpret mode on CPU) against what
+    the paged decode step computed before it: the layer's pool gathered
+    through a shuffled table into ``[slots, max_len]`` views, then
+    ``_append_free_attention``. Layer 2 of a three-layer stacked pool;
+    f32 queries, so the only difference left is the order of
+    summation."""
+    from dlrover_tpu.models.generate import _append_free_attention
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.ops.decode_attention import pool_decode_attention
+
+    spec = _POOL_CASES[case]
+    b, h, kh, d, bs, mb, n_layers, layer = 5, 32, 8, 128, 16, 20, 3, 2
+    monkeypatch.setattr(da, "_POOL_CHUNK_BYTES", 128 * kh * d * 2)
+    fills = np.asarray(spec["fills"], np.int32)
+    active = np.asarray(spec.get("active", (1,) * b), bool)
+    rs = np.random.RandomState(0)
+    tables = (rs.permutation(b * mb) + 1).reshape(b, mb).astype(np.int32)
+    if "shared" in spec:
+        (first, second), n = spec["shared"]
+        tables[second, :n] = tables[first, :n]
+    ks = jax.random.split(jax.random.key(1), 5)
+    pool_shape = (n_layers, b * mb + 1, bs, kh, d)
+    k_pool = jax.random.normal(ks[0], pool_shape).astype(pool_dtype)
+    v_pool = jax.random.normal(ks[1], pool_shape).astype(pool_dtype)
+    for slot in spec.get("poisoned", ()):
+        k_pool = k_pool.at[:, tables[slot]].set(jnp.nan)
+        v_pool = v_pool.at[:, tables[slot]].set(jnp.nan)
+    q = jax.random.normal(ks[2], (b, h, d), jnp.float32)
+    k_new = jax.random.normal(ks[3], (b, kh, d), jnp.float32)
+    v_new = jax.random.normal(ks[4], (b, kh, d), jnp.float32)
+
+    got = np.asarray(pool_decode_attention(
+        q, k_new, v_new, k_pool, v_pool, jnp.int32(layer),
+        jnp.asarray(tables), jnp.asarray(fills), jnp.asarray(active),
+    ))
+    views = [
+        pool[layer][tables].reshape(b, mb * bs, kh, d)
+        for pool in (k_pool, v_pool)
+    ]
+    want = np.asarray(_append_free_attention(
+        q[:, None], *views, k_new[:, None], v_new[:, None],
+        jnp.asarray(fills),
+    ))[:, 0]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got[active], want[active], rtol=2e-5, atol=2e-6
+    )
+    # A slot that reads nothing answers with its own new token.
+    idle = ~active | (fills == 0)
+    np.testing.assert_allclose(
+        got[idle], np.repeat(np.asarray(v_new), h // kh, axis=1)[idle],
+        rtol=1e-6,
+    )
+    # Another layer of the same pool is another answer.
+    other = np.asarray(pool_decode_attention(
+        q, k_new, v_new, k_pool, v_pool, jnp.int32(0),
+        jnp.asarray(tables), jnp.asarray(fills), jnp.asarray(active),
+    ))
+    busy = active & (fills > 0)
+    assert np.abs(other[busy] - got[busy]).max() > 1e-2
+
+
+def test_paged_engine_tokens_are_the_same_through_the_pool_kernel(
+    monkeypatch,
+):
+    """One run of mixed prompts over a pool too small for them (slots
+    admitted and finished mid-run, the youngest preempted once): the
+    decode program built with the pool kernel — the platform probe
+    patched, the kernel in interpret mode — emits the greedy tokens of
+    the gather program, and retraces nothing across admissions."""
+    from dlrover_tpu.observability.registry import MetricsRegistry
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    cfg = llama.tiny_config(
+        n_layers=2, n_heads=16, n_kv_heads=8, head_dim=128,
+        dtype="bfloat16",
+    )
+    params, _ = llama.init_params(cfg, jax.random.key(0))
+    prompts = make_prompts(cfg, (12, 5, 19, 12, 9, 3), seed=7)
+    new = (16, 9, 12, 16, 5, 11)
+
+    def run():
+        eng = PagedServingEngine(
+            cfg, params, slots=4, max_len=32, prefill_chunk=8,
+            block_size=8, num_blocks=10, prefix_cache=False,
+            registry=MetricsRegistry(),
+        )
+        eng.warmup()
+        base = dict(eng.trace_counts)
+        reqs = [eng.submit(p, n) for p, n in zip(prompts, new)]
+        eng.run_until_idle()
+        assert all(r.state == "done" and not r.failed for r in reqs)
+        assert eng.metrics.kv_preemptions.value() >= 1
+        assert eng.trace_counts == base
+        eng.check_block_invariants()
+        return eng.decode_attention, [r.tokens for r in reqs]
+
+    kind, want = run()
+    assert kind == "xla_gather"
+    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    kind, got = run()
+    assert kind == "paged_kernel"
+    assert got == want
+    assert [len(t) for t in got] == list(new)
+
+
+# What decode_attention_kind sees -> what it builds. Defaults: a TPU, a
+# bf16 model of 32 heads on 8 KV heads x 128, 16-row pages (the
+# ``nemo12b-serve-chat`` engine), a bf16 pool.
+_KIND_CASES = {
+    "the_cell": (dict(), "paged_kernel"),
+    "mha": (dict(n_kv_heads=32), "paged_kernel"),
+    "not_on_a_tpu": (dict(on_tpu=False), "xla_gather"),
+    "int8_pool": (dict(kv_dtype="int8"), "xla_gather"),
+    "f32_model": (dict(dtype="float32"), "xla_gather"),
+    "head_dim_64": (dict(head_dim=64), "xla_gather"),
+    "four_kv_heads": (dict(n_kv_heads=4), "xla_gather"),
+    # 1,024 rows x 8 x 128 x 2 B = 2 MB: one page outgrows a VMEM chunk.
+    "page_larger_than_a_chunk": (dict(block_size=1024), "xla_gather"),
+    "page_of_one_chunk": (dict(block_size=512), "paged_kernel"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KIND_CASES))
+def test_decode_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    seen, want = _KIND_CASES[case]
+    seen = dict(seen)
+    on_tpu = seen.pop("on_tpu", True)
+    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    block_size = seen.pop("block_size", 16)
+    kv_dtype = seen.pop("kv_dtype", "fp")
+    cfg = llama.tiny_config(**{
+        **dict(n_heads=32, n_kv_heads=8, head_dim=128, dtype="bfloat16"),
+        **seen,
+    })
+    assert paged.decode_attention_kind(cfg, block_size, kv_dtype) == want
+
+
+@pytest.mark.parametrize("tiny", [False, True])
+def test_bench_paged_decode_times_nothing_off_a_tpu(tiny):
+    """The on-chip A/B tool's times mean something on a TPU only: off
+    one it refuses to run, and its ``--tiny`` rehearsal (interpret
+    mode) goes through every part and prints no time."""
+    import subprocess
+    import sys
+
+    tool = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "bench_paged_decode.py",
+    )
+    shape = ["--preset", "flagship334m", "--slots", "2", "--layers", "1",
+             "--max-blocks", "8", "--chunk-kb", "1024"]
+    out = subprocess.run(
+        [sys.executable, tool, *shape, *(["--tiny"] if tiny else [])],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, text=True,
+        capture_output=True, timeout=300,
+    )
+    if not tiny:
+        assert out.returncode == 2 and "no TPU here" in out.stderr
+        assert out.stdout == ""
+        return
+    assert out.returncode == 0, out.stderr[-2000:]
+    rows = [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
+    assert [r.get("part") for r in rows] == [
+        "shape", "parity", "attention", "attention", "attention",
+        "decode", None,
+    ]
+    assert rows[-1]["ok"] and rows[-2]["same_tokens"] == [2, 2]
+    assert not [k for r in rows for k in r if k.endswith(("_ms", "_s"))]
 
 
 # ---- autoscaler signal source -----------------------------------------------

@@ -122,12 +122,42 @@ def test_counts_add_up_to_the_work_done(kind, parts, tracer):
     else:
         assert slot_steps == decoded
     assert all(
-        set(a) - {"retraces"} == {
+        set(a) - {"retraces", "kv_rows"} == {
             "idx", "phases", "n_admitted", "n_decoding",
             "prefill_tokens", "n_finished",
         } for a in attrs
     )
     assert all("retraces" not in a for a in attrs[3:])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_kv_rows_is_the_cache_visible_to_each_decode_launch(
+    kind, parts, tracer
+):
+    """``kv_rows`` rides on exactly the steps that launched a decode
+    and is the sum of the decoding slots' fills: a request's first
+    decode step sees its prompt, every later one a row more."""
+    reqs = serve(build(kind, parts))
+    attrs = [s["attrs"] for s in step_spans(tracer)]
+    assert all(("kv_rows" in a) == (a["n_decoding"] > 0) for a in attrs)
+    launched = [a for a in attrs if a["n_decoding"]]
+    assert launched
+    assert all(a["kv_rows"] >= a["n_decoding"] * PLAN[0][0]
+               for a in launched)
+    fed = [len(r.tokens) - 1 for r in reqs]
+    by_request = sum(
+        n * plen + n * (n - 1) // 2
+        for n, (plen, _) in zip(fed, PLAN)
+    )
+    total = sum(a["kv_rows"] for a in launched)
+    if kind == "speculative":
+        # A verify step may commit several tokens: fewer launches see
+        # the same prompts, so fewer rows in all.
+        assert sum(
+            a["n_decoding"] for a in launched
+        ) * PLAN[0][0] <= total <= by_request
+    else:
+        assert total == by_request
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -271,10 +301,15 @@ def test_trace_query_renders_a_step_span(parts, tracer, tmp_path, capsys):
         "decode_batch_mean": pytest.approx(
             table["counts"]["decode_batch_mean"]
         ),
+        "kv_rows_mean": pytest.approx(
+            sum(s["attrs"].get("kv_rows", 0) for s in spans)
+            / sum("kv_rows" in s["attrs"] for s in spans)
+        ),
         "retraced_steps": [
             s["attrs"]["idx"] for s in spans if "retraces" in s["attrs"]
         ],
     }
     assert 1.0 <= table["counts"]["decode_batch_mean"] <= 2.0
     assert trace_query.main(["--steps", sink]) == 0
-    assert "retraced_steps=" in capsys.readouterr().out
+    printed = capsys.readouterr().out
+    assert "retraced_steps=" in printed and "kv_rows_mean=" in printed

@@ -172,3 +172,98 @@ def test_flagship_step_compiles_for_four_chips(topo, mesh_config):
     assert _n_kernels(c) >= 3
     mem = c.memory_analysis()
     assert 0.8e9 < mem.argument_size_in_bytes < 1.3e9
+
+
+# Widths, engine shape and what the decode program must be built with.
+_PAGED_DECODE_CASES = {
+    # ``nemo12b-serve-chat``: GQA 32 / 8, a 2,304-row cache.
+    "nemo12b_cell": dict(
+        vocab_size=131072, embed_dim=5120, mlp_dim=14336, n_heads=32,
+        n_kv_heads=8, head_dim=128, want="paged_kernel",
+    ),
+    # MHA (Llama-2-7B): a page is 4x the bytes, so a VMEM chunk holds
+    # a quarter of the rows; sized in rows it overflowed the VMEM.
+    "llama2_7b_mha": dict(
+        vocab_size=32000, embed_dim=4096, mlp_dim=11008, n_heads=32,
+        n_kv_heads=32, head_dim=128, want="paged_kernel",
+    ),
+    # Narrow on purpose: the temporaries are held under 32 MB below,
+    # and a wide layer's sliced-out ``wqkv`` alone would pass that.
+    "kv16_head256": dict(
+        vocab_size=32000, embed_dim=512, mlp_dim=2048, n_heads=32,
+        n_kv_heads=16, head_dim=256, want="paged_kernel",
+    ),
+    # ``chip_smoke.py``'s engine: 8 query heads (padded to one bf16
+    # tile of 16), 4 slots, a 576-row cache.
+    "flagship_short_cache": dict(
+        vocab_size=32000, embed_dim=1024, mlp_dim=4096, n_heads=8,
+        n_kv_heads=8, head_dim=128, slots=4, max_blocks=36,
+        want="paged_kernel",
+    ),
+    # One page is larger than a VMEM chunk: the kernel cannot hold it.
+    "page_larger_than_a_chunk": dict(
+        vocab_size=32000, embed_dim=1024, mlp_dim=4096, n_heads=8,
+        n_kv_heads=8, head_dim=128, max_blocks=4, block_size=1024,
+        want="xla_gather",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_DECODE_CASES))
+def test_paged_decode_reads_the_pool_in_place(one_chip, case):
+    """The paged decode program (2 layers) compiles for the described
+    v5e with the attention ``decode_attention_kind`` chose for it —
+    nothing falls back after that choice, so what it admits has to
+    lower. Built with the pool kernel it has one call in the layer
+    body and NO copy of the cache — neither the layer's pool sliced
+    out of the stacked arrays nor the gathered ``[slots, max_len]``
+    view, which were 153 MB of temporaries and 43 % of the decode
+    program's time at ``nemo12b-serve-chat`` (PERF.md §5, PR 25)."""
+    from dlrover_tpu.models import generate as gen_lib
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    spec = dict(_PAGED_DECODE_CASES[case])
+    want = spec.pop("want")
+    slots, max_blocks = spec.pop("slots", 16), spec.pop("max_blocks", 144)
+    bs = spec.pop("block_size", 16)
+    cfg = llama.TpuLMConfig(n_layers=2, dtype="bfloat16", **spec)
+    num_blocks = slots * max_blocks + 1
+    assert paged.decode_attention_kind(cfg, bs, "fp") == want
+    assert paged.decode_attention_kind(cfg, bs, "int8") == "xla_gather"
+    steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, 256)
+    assert steps.decode_attention == want
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: arr(x.shape, x.dtype), tree
+        )
+
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    params = on_chip(jax.eval_shape(
+        lambda k: gen_lib.prepare_decode_params(
+            cfg, llama.init_params(cfg, k)[0]
+        ), key,
+    ))
+    pool = arr(
+        (cfg.n_layers, num_blocks, bs, cfg.n_kv_heads, cfg.head_dim),
+        jnp.bfloat16,
+    )
+    i32 = jnp.int32
+    c = steps.decode.lower(
+        pool, pool, params, arr((slots, max_blocks), i32),
+        arr((slots,), i32), arr((slots,), i32), arr((slots,), bool),
+        arr((slots,), jnp.float32), key, arr((), i32),
+    ).compile()
+    text = c.as_text()
+    if want == "xla_gather":
+        assert _n_kernels(c) == 0
+        return
+    assert _n_kernels(c) == 1  # in the scan's body, once for all layers
+    assert "paged_pool_decode_attention" in text
+    kv = f"{cfg.n_kv_heads},{cfg.head_dim}]"
+    for rows in (num_blocks, num_blocks - 1):   # a layer's pool, a view
+        assert f"[{rows},{bs},{kv}" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 32e6

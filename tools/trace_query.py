@@ -26,7 +26,8 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # engine iterations: the phases of the serving.step spans folded
     # into one table with each phase's share of the summed step time,
     # then the steps' counts (admitted / finished requests, prompt
-    # tokens, mean decode batch, which steps recompiled)
+    # tokens, mean decode batch and cache rows visible to it, which
+    # steps recompiled)
     python tools/trace_query.py --steps spans_engine.jsonl
 
     # one trace's tree + critical path (a serving.step: its phases)
@@ -125,8 +126,11 @@ def step_summary(spans: List[Dict]) -> Dict:
     the shares sum to 100). ``counts``: what the steps carried —
     ``n_admitted`` explains a long ``admit`` (a prefix lookup per
     admission), ``n_finished`` a long ``commit`` (a finished request
-    emits its span tree there), ``retraced_steps`` names the
-    iterations that recompiled a program."""
+    emits its span tree there), ``kv_rows_mean`` is the cache rows
+    visible to a decode launch (over ``decode_batch_mean`` x the
+    engine's ``max_len``: the share of the logical view attention has
+    any use for), ``retraced_steps`` names the iterations that
+    recompiled a program."""
     steps = [
         s for s in spans
         if s.get("name") == "serving.step" and s.get("dur_s") is not None
@@ -141,6 +145,7 @@ def step_summary(spans: List[Dict]) -> Dict:
         r["share_pct"] = round(100.0 * summed / total, 2) if total else 0.0
     attrs = [s["attrs"] for s in steps]
     decoding = [a["n_decoding"] for a in attrs if a["n_decoding"]]
+    kv_rows = [a["kv_rows"] for a in attrs if "kv_rows" in a]
     return {"phases": rows, "counts": {
         "steps": len(steps),
         "errors": sum(s.get("status") != "ok" for s in steps),
@@ -150,6 +155,7 @@ def step_summary(spans: List[Dict]) -> Dict:
         "decode_batch_mean": (
             sum(decoding) / len(decoding) if decoding else 0.0
         ),
+        "kv_rows_mean": sum(kv_rows) / len(kv_rows) if kv_rows else 0.0,
         "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
     }}
 
