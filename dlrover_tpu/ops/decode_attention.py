@@ -802,3 +802,384 @@ def pool_decode_attention(
         k_pool.reshape(pooled), v_pool.reshape(pooled),
     )
     return out[:, :h].astype(q.dtype)
+
+
+# ---- a prefill chunk's attention over the STACKED pool, in place ----------
+
+# Query rows (tokens x query heads) one grid step of the chunk kernel
+# holds: their f32 accumulator and the pipelined query and output
+# blocks come to ~1.5 KB a row at 128-wide heads, 6 MB at 4,096 rows
+# beside the 4 MB of page buffers and the score tiles. Every grid step
+# re-reads the prefix's pages and pays its own set-up, so fewer, larger
+# steps are faster: on the v5e at the ``nemo12b-serve-chat`` shape
+# 2,048 / 4,096 / 8,192 rows took 2.01 / 1.59 / 1.44 ms over 12 layers
+# with 512 rows below the chunk, 3.89 / 2.83 / 2.54 with 2,048
+# (``tools/bench_paged_decode.py --parts prefill``, PR 28); 8,192 would
+# want ~32 MB of VMEM for a tenth of a millisecond a chunk.
+_CHUNK_QUERY_ROWS = 4096
+# What the chunk kernel may use of the core's VMEM (128 MiB on a v5e; the
+# compiler's own default scope is 16 MB).
+_CHUNK_VMEM_BYTES = 32 << 20
+
+
+def _chunk_token_tile(chunk: int, n_heads: int) -> int:
+    """Tokens of a chunk in one grid step of the chunk kernel: the
+    largest power-of-two share of ``chunk`` whose query rows number at
+    most ``_CHUNK_QUERY_ROWS``; 0 where no share of 8 tokens or more
+    divides the chunk."""
+    tile = chunk
+    while tile * n_heads > _CHUNK_QUERY_ROWS and tile % 2 == 0:
+        tile //= 2
+    fits = tile * n_heads <= _CHUNK_QUERY_ROWS and tile % 8 == 0
+    return tile if fits else 0
+
+
+def _chunk_vmem_bytes(block_size, n_heads, kv_heads, head_dim, chunk,
+                      tile, itemsize) -> int:
+    """An upper reckoning of the chunk kernel's VMEM: page buffers,
+    per-row state, pipelined blocks and the live score tiles."""
+    rows = tile * n_heads
+    group_rows = tile * (n_heads // kv_heads)
+    page = block_size * kv_heads * head_dim * itemsize
+    pages = max(1, _POOL_CHUNK_BYTES // page)
+    cols = max(pages * block_size, chunk)
+    return (
+        4 * pages * page                          # K, V double-buffered
+        + rows * (2 * 8 * 4 + head_dim * 4)       # max, sum, accumulator
+        + 2 * rows * head_dim * 2 * itemsize      # q in, out: 2 buffers
+        + 2 * 2 * chunk * kv_heads * head_dim * itemsize   # own K, V
+        + 6 * group_rows * cols * 4               # scores, probabilities
+    )
+
+
+def chunk_kernel_supported(pool_dtype, block_size: int, n_heads: int,
+                           kv_heads: int, head_dim: int,
+                           chunk: int) -> bool:
+    """Shapes :func:`pool_chunk_attention` lowers for on a TPU: what
+    :func:`pool_kernel_supported` admits (the page is the same unit of
+    copy), a chunk that splits into token tiles of whole sublanes, and
+    buffers — sized in bytes from the shapes — that fit the VMEM the
+    kernel asks for."""
+    tile = _chunk_token_tile(chunk, n_heads)
+    if not tile or not pool_kernel_supported(
+        pool_dtype, block_size, kv_heads, head_dim
+    ):
+        return False
+    return _chunk_vmem_bytes(
+        block_size, n_heads, kv_heads, head_dim, chunk, tile, 2
+    ) <= _CHUNK_VMEM_BYTES
+
+
+def _head_rows(ref, head, kv_heads: int, rows: int):
+    """Rows of one KV head out of a chunk that lies as its pages do:
+    ``ref`` is ``[d // 128, rows * kv_heads, 128]`` (Mosaic's strided
+    read wants a 128-lane buffer, so wider heads lie in lane groups),
+    row ``c`` = cache row ``c // kv_heads`` of head ``c % kv_heads``.
+    bf16 rows are packed in pairs into 32-bit sublanes, so there the
+    read is of the words that hold the head (stride ``kv_heads // 2``)
+    and a shift picks the half — exact. Returns ``[rows, d]``."""
+
+    def lanes(ref):
+        if ref.dtype != jnp.bfloat16:
+            return ref[pl.ds(head, rows, stride=kv_heads), :]
+        words = ref.bitcast(jnp.uint32)[
+            pl.ds(head // 2, rows, stride=kv_heads // 2), :
+        ]
+        half = jnp.where(
+            head % 2 == 0, words << 16, words & jnp.uint32(0xFFFF0000)
+        )
+        return pltpu.bitcast(half, jnp.float32).astype(jnp.bfloat16)
+
+    return jnp.concatenate(
+        [lanes(ref.at[j]) for j in range(ref.shape[0])], axis=-1
+    )
+
+
+def _chunk_kernel(
+    layer_ref, start_ref, tbl_ref,                # scalar prefetch
+    q_ref, kn_ref, vn_ref, k_hbm, v_hbm,          # inputs
+    o_ref,                                        # output
+    kbuf, vbuf, sem, m_ref, l_ref, acc_ref,       # scratch
+    *, chunk_pages: int, page_rows: int, kv_heads: int, group: int,
+    tile: int, exact: bool,
+):
+    """One call = one layer's attention for one slot's prefill chunk;
+    one grid step = ``tile`` of its tokens, all heads.
+
+    Two key groups in one online softmax per (KV head, query row):
+
+    - the slot's cache rows ``[0, start)``, copied from the pool page
+      by page as :func:`_pool_kernel` copies them (the next chunk of
+      pages in flight while this one is computed), every row visible
+      to every query, the last page masked at ``start``;
+    - the chunk's own K/V, from VMEM, causally.
+
+    Query rows lie ``[kv_heads, tile * group, d]``, row ``r`` of a head
+    is token ``r // group``: a KV head's rows are read out of the page
+    layout by :func:`_head_rows` and meet only their own queries, so
+    nothing is scored to be masked away (the decode kernel's one
+    matmul over all heads costs ``kv_heads`` x the softmax work, which
+    for 32 query rows is nothing and for 8,192 would be the kernel's
+    time)."""
+    step = pl.program_id(0)
+    layer = layer_ref[0]
+    start = start_ref[0]
+    block_size = page_rows // kv_heads
+    chunk_rows = chunk_pages * block_size
+    n_pages = (start + block_size - 1) // block_size
+    n_chunks = (n_pages + chunk_pages - 1) // chunk_pages
+    t_own = kn_ref.shape[1]
+    rows = tile * group
+    _, lane_groups, _, lanes = kbuf.shape
+
+    @pl.when(step == 0)
+    def _():
+        # Finite wherever a page copy has not written (0 x NaN).
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def pages_in(chunk):
+        return jnp.clip(n_pages - chunk * chunk_pages, 0, chunk_pages)
+
+    def page_copies(chunk, buf, i):
+        blk = tbl_ref[chunk * chunk_pages + i]
+        dst = pl.ds(pl.multiple_of(i * page_rows, page_rows), page_rows)
+        return [
+            pltpu.make_async_copy(
+                hbm.at[layer, blk, :, pl.ds(j * lanes, lanes)],
+                vmem.at[buf, j, dst], sem.at[which, buf],
+            )
+            for which, (hbm, vmem) in enumerate(
+                ((k_hbm, kbuf), (v_hbm, vbuf))
+            )
+            for j in range(lane_groups)
+        ]
+
+    def start_copies(chunk, buf):
+        def body(i, carry):
+            for cp in page_copies(chunk, buf, i):
+                cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in(chunk), body, 0)
+
+    def wait_copies(chunk, buf):
+        def body(i, carry):
+            for cp in page_copies(chunk, buf, i):
+                cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in(chunk), body, 0)
+
+    nt = (((1,), (1,)), ((), ()))      # [n, d] x [m, d] -> [n, m]
+    tn = (((0,), (0,)), ((), ()))      # [n, d] x [n, m] -> [d, m]
+
+    def dot(a, b, dims):
+        # bf16 by bf16 is exact in f32: one MXU pass. Anything else
+        # (interpret mode on f32 pools, f32 queries from the parity
+        # tool) is an f32 contraction.
+        if a.dtype == b.dtype == jnp.bfloat16:
+            return jax.lax.dot_general(
+                a, b, dims, preferred_element_type=jnp.float32
+            )
+        return jax.lax.dot_general(
+            a.astype(jnp.float32), b.astype(jnp.float32), dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+
+    def attend(head, k, v, visible=None):
+        """One online-softmax step of ``head``'s query rows over keys
+        ``k`` / values ``v`` ``[n, d]`` as stored; ``visible`` ``[n,
+        rows]``, or None where every key is. Scores lie KEYS x QUERIES:
+        the softmax's reductions run down the sublanes (elementwise
+        over vregs, one short reduce at the end) and its per-query
+        statistics are lane-dense ``[1, rows]`` rows, where queries x
+        keys would reduce along the lanes and keep a ``[rows, 128]``
+        tile a statistic. Every call shows each query a key at least
+        (a chunk of pages is attended only if it holds a row below
+        ``start``, and a token sees itself), so the running max is a
+        real logit after it and a masked key's probability is
+        exp(NEG_INF - m) == 0 with no second select."""
+        s = dot(k, q_ref[head], nt)
+        if visible is not None:
+            s = jnp.where(visible, s, NEG_INF)
+        m_prev = m_ref[head]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[head] = alpha * l_ref[head] + jnp.sum(
+            p, axis=0, keepdims=True
+        )
+        if v.dtype != jnp.bfloat16:
+            pv = dot(v, p, tn)
+        elif exact:
+            # The f32 probabilities as three bf16 addends side by side
+            # (:func:`_split_bf16`): exact products in one MXU pass.
+            pv = dot(v, jnp.concatenate(_split_bf16(p), 1), tn)
+            pv = pv[:, :rows] + pv[:, rows:2 * rows] + pv[:, 2 * rows:]
+        else:
+            pv = dot(v, p.astype(v.dtype), tn)
+        acc_ref[head] = acc_ref[head] * alpha + pv
+        m_ref[head] = m_new
+
+    @pl.when(n_chunks > 0)
+    def _():
+        start_copies(0, 0)
+
+    def chunk_body(chunk, buf):
+        @pl.when(chunk + 1 < n_chunks)
+        def _():
+            start_copies(chunk + 1, 1 - buf)
+
+        wait_copies(chunk, buf)
+        filled = start - chunk * chunk_rows     # rows of it below start
+
+        def heads(visible):
+            def head_body(head, carry):
+                attend(
+                    head,
+                    _head_rows(kbuf.at[buf], head, kv_heads, chunk_rows),
+                    _head_rows(vbuf.at[buf], head, kv_heads, chunk_rows),
+                    visible,
+                )
+                return carry
+
+            jax.lax.fori_loop(0, kv_heads, head_body, 0)
+
+        # Only the prefix's last chunk has rows to hide (what its page
+        # copies left of an earlier chunk, and a last page's rows past
+        # ``start``): every other one skips the mask's passes.
+        @pl.when(filled >= chunk_rows)
+        def _():
+            heads(None)
+
+        @pl.when(filled < chunk_rows)
+        def _():
+            key = jax.lax.broadcasted_iota(
+                jnp.int32, (chunk_rows, rows), 0
+            )
+            heads(key < filled)
+
+        return 1 - buf
+
+    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+
+    # The chunk's own keys: token u is visible to token t iff u <= t.
+    token = step * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (t_own, rows), 1
+    ) // group
+    causal = jax.lax.broadcasted_iota(
+        jnp.int32, (t_own, rows), 0
+    ) <= token
+
+    def own_body(head, carry):
+        attend(head, kn_ref[head], vn_ref[head], causal)
+        o_ref[head] = (acc_ref[head] / l_ref[head]).T.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, kv_heads, own_body, 0)
+
+
+def pool_chunk_attention(
+    q,            # [T, n_heads, d] — one slot's prefill chunk
+    k_new,        # [T, kv_heads, d] — the chunk's own K/V, not yet in
+    v_new,        #   the pool
+    k_pool,       # [layers, num_blocks, block_size, kv_heads, d]
+    v_pool,
+    layer,        # [] int32
+    table_row,    # [max_blocks] int32 — the slot's pages
+    start,        # [] int32 — cache rows already filled: [0, start)
+    interpret=None,
+    exact: bool = True,
+):
+    """A paged prefill chunk's attention with the pool read IN PLACE:
+    ``dot_product_attention`` of the chunk's queries (positions ``start
+    + t``) over the slot's logical cache with the chunk written at
+    ``start``, without that view: rows below ``start`` come straight
+    from the stacked pool through ``table_row``, only the pages that
+    hold them, and the chunk's own K/V from the caller's hands. Cost
+    goes by ``start + T``, not by the table's length.
+
+    Arithmetic is the reference's: the query is scaled in its own
+    dtype, K and V are used as stored, logits, running max, sum and
+    accumulator are f32 and no row is dropped; the order of summation
+    differs (an online softmax over chunks of ``_POOL_CHUNK_BYTES`` of
+    pages, then the chunk itself). ``exact`` says how the f32
+    probabilities meet a bf16 V: unrounded (:func:`_split_bf16`, three
+    MXU passes' worth of rows) or rounded to bf16 once, which is what
+    XLA's default matmul precision makes of the reference on a TPU.
+    Returns ``[T, n_heads, d]`` in ``q.dtype``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t, h, d = q.shape
+    n_layers, nb_pool, block_size, kh, _ = k_pool.shape
+    if h % kh:
+        raise ValueError(f"n_heads {h} not divisible by kv_heads {kh}")
+    g = h // kh
+    page_rows = block_size * kh
+    max_blocks = table_row.shape[0]
+    chunk_pages = max(1, _pool_chunk_pages(block_size, kh, d, max_blocks))
+    # Off the chip (interpret mode) any chunk goes in one tile; on it
+    # the caller asked chunk_kernel_supported.
+    tile = _chunk_token_tile(t, h) or t
+    rows = tile * g
+    # [T, kh, g, d] -> [kh, T * g, d]: a KV head's query rows together,
+    # token-major, so that a token tile is a run of rows.
+    qs = (q * d ** -0.5).reshape(t, kh, g, d).transpose(1, 0, 2, 3)
+    qs = qs.reshape(kh, t * g, d)
+    own = [x.astype(k_pool.dtype).transpose(1, 0, 2) for x in (k_new, v_new)]
+    scalars = (
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.minimum(
+            jnp.asarray(start, jnp.int32), max_blocks * block_size
+        ).reshape(1),
+        jnp.asarray(table_row, jnp.int32),
+    )
+    pooled = (n_layers, nb_pool, page_rows, d)
+    # 128-lane groups of the head (one, off the chip, for a narrow one).
+    lane_groups = max(d // 128, 1)
+    chunk_buf = (
+        2, lane_groups, chunk_pages * page_rows, d // lane_groups
+    )
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    tiled = pl.BlockSpec((kh, rows, d), lambda i, *_: (0, i, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_kernel, chunk_pages=chunk_pages, page_rows=page_rows,
+            kv_heads=kh, group=g, tile=tile, exact=exact,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(t // tile,),
+            in_specs=[
+                tiled, whole, whole,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=tiled,
+            scratch_shapes=[
+                pltpu.VMEM(chunk_buf, k_pool.dtype),
+                pltpu.VMEM(chunk_buf, v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kh, 1, rows), jnp.float32),
+                pltpu.VMEM((kh, 1, rows), jnp.float32),
+                pltpu.VMEM((kh, d, rows), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kh, t * g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_CHUNK_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_pool_chunk_attention",
+    )(
+        *scalars, qs, *own,
+        k_pool.reshape(pooled), v_pool.reshape(pooled),
+    )
+    return out.reshape(kh, t, g, d).transpose(1, 0, 2, 3).reshape(t, h, d)

@@ -659,6 +659,18 @@ class ServingEngine:
             if count is not None:
                 st.counts[count] = value
 
+    def _mark_prefill_prep(self, n_valid: int, kv_rows: int) -> None:
+        """Close ``prefill_prep`` with what the chunk launch carries:
+        its ``prefill_tokens`` and ``prefill_kv_rows``, the cache rows
+        its attention has a use for (the slot's fill below the chunk
+        plus the chunk; over ``max_len`` the share of the logical view,
+        as ``kv_rows`` is for the decode launch)."""
+        st = self._step_trace
+        if st is not None:
+            st.mark("prefill_prep")
+            st.counts["prefill_tokens"] = n_valid
+            st.counts["prefill_kv_rows"] = kv_rows
+
     def _mark_decode_prep(self, decoding: List[Request],
                           at: Optional[float] = None) -> None:
         """Close ``decode_prep`` with what the launch will carry: the
@@ -782,7 +794,7 @@ class ServingEngine:
         n_valid = min(c, req.prompt_len - start)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
-        self._mark("prefill_prep", "prefill_tokens", n_valid)
+        self._mark_prefill_prep(n_valid, start + n_valid)
         self._k, self._v, first = self._steps.prefill(
             self._k, self._v, self._params, jnp.asarray(chunk),
             np.int32(req.slot), np.int32(start), np.int32(n_valid),

@@ -15,24 +15,30 @@ slot's logical cache is the pool rows its BLOCK TABLE names:
   0 so their masked-garbage writes can never land in a block another
   slot shares (the flat engine's own-row trick does not survive
   sharing).
-- **Decode attention reads the pool in place** (``decode_attention``:
-  ``"paged_kernel"``). On a TPU with a bf16 pool whose page tiles and
-  fits a VMEM chunk (``ops.decode_attention.pool_kernel_supported``;
-  anything else takes the gather, nothing fails to build) each layer's
-  attention is one Pallas call over the WHOLE stacked pool: the layer
-  index, the tables and the fills pick the pages, only each decoding
-  slot's filled pages are copied (one contiguous DMA a page), and the
-  new token's own K/V open the online softmax, so the step stays
-  append-free. The alternative (``"xla_gather"``: everywhere else,
-  and the plain reference of the parity tests) gathers each slot's
+- **Attention reads the pool in place** (``pool_attention``:
+  ``"paged_kernel"``), in the decode step and the prefill chunk alike.
+  On a TPU with a bf16 pool whose page tiles and fits a VMEM chunk
+  (``ops.decode_attention.chunk_kernel_supported``; anything else
+  takes the gather, nothing fails to build) each layer's attention is
+  one Pallas call over the WHOLE stacked pool: the layer index, the
+  tables and the fills pick the pages, only the filled pages are
+  copied (one contiguous DMA a page), and the new tokens' own K/V
+  come from the layer's hands, so both programs are append-free: the
+  decode step lands one row a slot after its layer scan, the prefill
+  chunk its ``[layers, chunk]`` rows (PERF.md §5, PR 28: the chunk
+  program used to gather one slot's whole ``[max_len]`` view, carry
+  it through the scan and score all of it). The alternative
+  (``"xla_gather"``: everywhere else, and the plain reference of
+  the parity tests) gathers each slot's
   logical ``[max_len]`` view through the table and runs the flat
   engine's ``models/generate._layer_decode_read_only`` on it. On the
   chip that path moved the cache at its full CAPACITY four times a
   layer — the scan's slice of the layer's pool, the gathered view,
   and one read each for K and V — as many bytes as the weights at
-  ``nemo12b-serve-chat`` (PERF.md §5, PR 25). Which one a decode
-  program was built with follows from the platform and the pool, not
-  from a knob; the engine logs it once at construction. The verify /
+  ``nemo12b-serve-chat`` (PERF.md §5, PR 25). Which one an engine's
+  programs were built with follows from the platform and the pool,
+  not from a knob (:func:`pool_attention_kind`); the engine logs it
+  once at construction and reports it in ``kv_stats()``. The verify /
   draft programs and int8 pools still gather (no cell runs them).
 - **Visibility invariant, unchanged.** A logical row is read iff
   ``row < fill``; stale or foreign content beyond a slot's fill —
@@ -87,7 +93,7 @@ class _PagedSteps(NamedTuple):
     imp: object          # migration import: host block rows -> pool[dst]
     exp: object          # migration export: pool[src] -> one block's rows
     trace_counts: Dict[str, int]
-    decode_attention: str = "xla_gather"   # see decode_attention_kind
+    pool_attention: str = "xla_gather"   # see pool_attention_kind
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -102,47 +108,53 @@ class _PagedSpecSteps(NamedTuple):
 
 
 def _on_tpu() -> bool:
-    """The platform probe of :func:`decode_attention_kind` (tests patch
+    """The platform probe of :func:`pool_attention_kind` (tests patch
     it to take the kernel path in interpret mode)."""
     return jax.default_backend() == "tpu"
 
 
-def decode_attention_kind(config, block_size: int, kv_dtype: str) -> str:
-    """Which attention the plain decode program is built with:
-    ``"paged_kernel"`` (the pool read in place, filled pages only) where
-    the kernel lowers — a TPU, a bf16 pool, a page that tiles and fits
-    a VMEM chunk — and ``"xla_gather"`` otherwise. Decided by what the
-    code can see; there is no option for it, and nothing falls back
-    after it, so what it admits has to compile
-    (``tests/test_tpu_compile.py`` holds it to that over GQA, MHA, wide
-    heads and short caches). The cache's size is no part of it: on the
-    v5e the kernel was ahead of the gather down to 4 slots x 576 rows
-    and 16 slots x 128 rows (PERF.md §6, PR 25)."""
+def pool_attention_kind(config, block_size: int, kv_dtype: str,
+                        chunk: int) -> str:
+    """Which attention the plain decode program AND the prefill program
+    are built with, one answer for both: ``"paged_kernel"`` (the pool
+    read in place, filled pages only) where both kernels lower — a TPU,
+    a bf16 pool, a page that tiles and fits a VMEM chunk, a prefill
+    chunk whose query tile and buffers fit the VMEM the kernel asks for
+    — and ``"xla_gather"`` otherwise. Decided by what the code can see;
+    there is no option for it, and nothing falls back after it, so what
+    it admits has to compile (``tests/test_tpu_compile.py`` holds it to
+    that over GQA, MHA, wide heads and short caches). The cache's size
+    is no part of it: on the v5e the decode kernel was ahead of the
+    gather down to 4 slots x 576 rows and 16 slots x 128 rows (PERF.md
+    §6, PR 25), the chunk kernel at every ``start`` (PR 28)."""
     if kv_dtype != "fp" or not _on_tpu():
         return "xla_gather"
     # Pallas costs ~1.2 s to import: only a process that may run the
-    # kernel pays it (the repo's idiom for ops/ kernels).
-    from dlrover_tpu.ops.decode_attention import pool_kernel_supported
+    # kernels pays it (the repo's idiom for ops/ kernels).
+    from dlrover_tpu.ops.decode_attention import chunk_kernel_supported
 
-    if pool_kernel_supported(
-        config.compute_dtype, block_size, config.n_kv_heads,
-        config.head_dim,
+    if chunk_kernel_supported(
+        config.compute_dtype, block_size, config.n_heads,
+        config.n_kv_heads, config.head_dim, chunk,
     ):
         return "paged_kernel"
     return "xla_gather"
 
 
-def _layer_decode_pool(config, p, x, positions, attend):
+def _layer_over_pool(config, p, x, positions, attend):
     """``generate._layer_decode_read_only`` with the cache behind
-    ``attend(q, k_new, v_new)`` (``[slots, heads, d]`` each) in place of
-    a ``[slots, max_len]`` slab: the paged decode's memory is a pool
-    and tables, so its attention shares no logic with the slab's."""
+    ``attend(q, k_new, v_new)`` (``[b, s, heads, d]`` each) in place of
+    a ``[b, max_len]`` slab: the paged programs' memory is a pool and
+    tables, so their attention shares no logic with the slab's. Serves
+    the decode step (``[slots, 1]``) and the prefill chunk (``[1,
+    chunk]``) alike; the caller lands ``k_new`` / ``v_new`` in their
+    pages after the layer scan."""
     residual = x
     if "wqkv" in p:
         q, k, v = gen_lib._fused_qkv(config, p, x, positions)
     else:
         q, k, v = llama.attention_qkv(config, p, x, positions)
-    attn = attend(q[:, 0], k[:, 0], v[:, 0])[:, None]
+    attn = attend(q, k, v)
     x = llama.attention_out(config, p, attn, residual)
     if "w_gu" in p:
         x = gen_lib._fused_mlp(config, p, x)
@@ -156,7 +168,7 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
                         quantized: bool = False,
                         attn: str = "xla_gather"):
     """[slots] tokens -> one decoded token per slot, ragged lengths.
-    ``attn`` (:func:`decode_attention_kind`): ``"paged_kernel"`` reads
+    ``attn`` (:func:`pool_attention_kind`): ``"paged_kernel"`` reads
     each layer's K/V straight from the stacked pool through the block
     tables, filled pages only; ``"xla_gather"`` gathers the cache per
     layer into a ``[slots, max_len]`` view. ``quantized``: int8 pools +
@@ -209,12 +221,12 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
             )
 
             pl, layer = layer_in
-            y, k_new, v_new = _layer_decode_pool(
+            y, k_new, v_new = _layer_over_pool(
                 config, pl, carry, positions,
                 lambda q, k_new, v_new: pool_decode_attention(
-                    q, k_new, v_new, k, v, layer, tables, lengths,
-                    active,
-                ),
+                    q[:, 0], k_new[:, 0], v_new[:, 0], k, v, layer,
+                    tables, lengths, active,
+                )[:, None],
             )
             return y, (k_new, v_new)
 
@@ -271,16 +283,32 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
 
 
 def _build_paged_prefill(config, max_blocks: int, block_size: int,
-                         chunk: int, counts, quantized: bool = False):
-    """One prompt chunk into ONE slot's blocks: gather the slot's
-    logical cache through its table row, run the flat prefill body,
-    scatter back only the touched blocks (shared untouched blocks are
-    never rewritten — the COW invariant). ``quantized``: the slot view
-    is dequantized for the (compute-bound) chunk forward and the
-    touched span re-quantized on the way out — per-(row, head)
-    round-to-nearest is IDEMPOTENT (the amax element always maps to
-    ±127), so rows below the chunk inside a touched block keep their
-    exact stored values."""
+                         chunk: int, counts, quantized: bool = False,
+                         attn: str = "xla_gather"):
+    """One prompt chunk into ONE slot's blocks. ``attn``
+    (:func:`pool_attention_kind`):
+
+    - ``"paged_kernel"``: the chunk does only its chunk's work. Each
+      layer's attention reads the slot's rows below ``start`` straight
+      from the stacked pool through ``table_row``
+      (``ops.decode_attention.pool_chunk_attention``) and the chunk's
+      own K/V from the layer's hands; the pools are closed over whole,
+      the layer scan carries nothing of the cache, and after it the
+      chunk's rows of all layers land in their pages with one write.
+    - ``"xla_gather"`` (everywhere else, and the reference): gather the
+      slot's logical ``[max_len]`` cache through its table row, run the
+      flat prefill body over it, scatter back only the touched blocks.
+
+    Either way shared untouched blocks are never rewritten (the COW
+    invariant), rows at or past ``n_valid`` are written, invisible and
+    overwritten later, and the head runs under ``last`` only: the host
+    reads the sampled token on a prompt's LAST chunk alone, so every
+    other chunk skips the ``[d, vocab]`` matmul and returns token 0.
+    ``quantized``: the slot view is dequantized for the
+    (compute-bound) chunk forward and the touched span re-quantized on
+    the way out — per-(row, head) round-to-nearest is IDEMPOTENT (the
+    amax element always maps to ±127), so rows below the chunk inside a
+    touched block keep their exact stored values."""
     L = config.n_layers
     kh, hd = config.n_kv_heads, config.head_dim
     max_len = max_blocks * block_size
@@ -289,10 +317,11 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
     # (init enforces one of chunk % bs == 0 / bs % chunk == 0).
     n_touch = max(chunk // block_size, 1)
 
+    def _positions(start):
+        return (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
+
     def _run_chunk(k_slot, v_slot, params, tokens, start):
-        positions = (
-            start + jnp.arange(chunk, dtype=jnp.int32)
-        )[None, :]
+        positions = _positions(start)
         x = llama.embed_tokens(config, params, tokens)
 
         def body(carry, layer_in):
@@ -319,14 +348,65 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
         ).reshape((L, n_touch, block_size) + head_shape)
         return seg, touched0
 
-    def _first_token(x, params, n_valid, temp, rng, step_idx):
-        h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-        logits = llama.unembed(config, params, h)[0, 0]    # [V]
-        sub = jax.random.fold_in(rng, step_idx * 2 + 1)
-        return gen_lib.sample_token(logits, sub, temp)
+    def _first_token(x, params, n_valid, temp, rng, step_idx, last):
+        def head():
+            h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+            logits = llama.unembed(config, params, h)[0, 0]    # [V]
+            sub = jax.random.fold_in(rng, step_idx * 2 + 1)
+            return gen_lib.sample_token(logits, sub, temp)
 
-    def prefill(k, v, params, tokens, table_row, start, n_valid, temp,
-                rng, step_idx):
+        return jax.lax.cond(last, head, lambda: jnp.zeros((), jnp.int32))
+
+    def _land_chunk(pool, rows, table_row, start):
+        # ``rows`` [L, chunk, kh, hd] into their pages: whole blocks
+        # when a chunk is a multiple of a block, else the chunk's span
+        # inside the one block that holds it.
+        rows = rows.astype(pool.dtype)
+        if chunk % block_size == 0:
+            ids = jax.lax.dynamic_slice(
+                table_row, (start // block_size,), (n_touch,)
+            )
+            return pool.at[:, ids].set(
+                rows.reshape(L, n_touch, block_size, kh, hd)
+            )
+        return jax.lax.dynamic_update_slice(
+            pool, rows[:, None],
+            (0, table_row[start // block_size], start % block_size, 0, 0),
+        )
+
+    def prefill_in_place(k, v, params, tokens, table_row, start, n_valid,
+                         temp, rng, step_idx, last=True):
+        from dlrover_tpu.ops.decode_attention import pool_chunk_attention
+
+        counts["prefill"] += 1  # traces only
+        positions = _positions(start)
+        x = llama.embed_tokens(config, params, tokens)
+
+        def body(carry, layer_in):
+            # The pools are closed over WHOLE (see the decode step's
+            # body_in_place); what the scan stacks is the chunk's own
+            # K/V, [chunk, kh, hd] a layer.
+            pl, layer = layer_in
+            y, k_new, v_new = _layer_over_pool(
+                config, pl, carry, positions,
+                lambda q, k_new, v_new: pool_chunk_attention(
+                    q[0], k_new[0], v_new[0], k, v, layer, table_row,
+                    start,
+                )[None],
+            )
+            return y, (k_new[0], v_new[0])
+
+        x, (k_news, v_news) = jax.lax.scan(
+            body, x,
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)),
+        )
+        k = _land_chunk(k, k_news, table_row, start)
+        v = _land_chunk(v, v_news, table_row, start)
+        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
+        return k, v, first
+
+    def prefill_gather(k, v, params, tokens, table_row, start, n_valid,
+                       temp, rng, step_idx, last=True):
         counts["prefill"] += 1  # traces only
         k_slot = k[:, table_row].reshape(L, 1, max_len, kh, hd)
         v_slot = v[:, table_row].reshape(L, 1, max_len, kh, hd)
@@ -338,11 +418,11 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
         ids = jax.lax.dynamic_slice(table_row, (touched0,), (n_touch,))
         k = k.at[:, ids].set(seg_k.astype(k.dtype))
         v = v.at[:, ids].set(seg_v.astype(v.dtype))
-        first = _first_token(x, params, n_valid, temp, rng, step_idx)
+        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
         return k, v, first
 
     def prefill_q8(k, v, ks, vs, params, tokens, table_row, start,
-                   n_valid, temp, rng, step_idx):
+                   n_valid, temp, rng, step_idx, last=True):
         from dlrover_tpu.ops.kv_quant import dequantize_kv, quantize_kv
 
         counts["prefill"] += 1  # traces only
@@ -369,10 +449,17 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
         v = v.at[:, ids].set(seg_v)
         ks = ks.at[:, ids].set(seg_ks)
         vs = vs.at[:, ids].set(seg_vs)
-        first = _first_token(x, params, n_valid, temp, rng, step_idx)
+        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
         return k, v, ks, vs, first
 
-    return prefill_q8 if quantized else prefill
+    if quantized:
+        return prefill_q8
+    # Both plain programs go by ``prefill``: a trace names a device op
+    # by its program (``jit_prefill:...``), and readers of traces find
+    # the chunk program by that name whichever it is.
+    prefill = prefill_in_place if attn == "paged_kernel" else prefill_gather
+    prefill.__name__ = prefill.__qualname__ = "prefill"
+    return prefill
 
 
 def _build_cow_copy(counts, quantized: bool = False):
@@ -683,11 +770,12 @@ def _paged_steps(
     """Compiled once per shape key, shared across engines (the flat
     engine's lru_cache discipline). Pools donated; tables/lengths/ids
     all plain traced arguments. ``kv_dtype`` "int8" programs also
-    donate the scale pools. The decode program's attention
-    (:func:`decode_attention_kind`) is part of the key."""
+    donate the scale pools. The decode and prefill programs' attention
+    (:func:`pool_attention_kind`) is part of the key."""
     return _paged_steps_for(
         config, slots, num_blocks, max_blocks, block_size, chunk,
-        kv_dtype, decode_attention_kind(config, block_size, kv_dtype),
+        kv_dtype,
+        pool_attention_kind(config, block_size, kv_dtype, chunk),
     )
 
 
@@ -707,7 +795,7 @@ def _paged_steps_for(
     )
     prefill = jax.jit(
         _build_paged_prefill(config, max_blocks, block_size, chunk,
-                             counts, quantized=quantized),
+                             counts, quantized=quantized, attn=attn),
         donate_argnums=pool_args,
     )
     cow = jax.jit(
@@ -723,7 +811,7 @@ def _paged_steps_for(
     exp = jax.jit(_build_export_gather(counts, quantized=quantized))
     return _PagedSteps(prefill=prefill, decode=decode, cow=cow,
                        imp=imp, exp=exp, trace_counts=counts,
-                       decode_attention=attn)
+                       pool_attention=attn)
 
 
 class PagedServingEngine(ServingEngine):
@@ -837,13 +925,13 @@ class PagedServingEngine(ServingEngine):
         )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
-            "(%s KV), decode attention %s",
+            "(%s KV), decode and prefill attention %s",
             slots, max_len, self.num_blocks, block_size,
-            kv_cache_dtype, self.decode_attention,
+            kv_cache_dtype, self.pool_attention,
         )
         self.metrics.annotate(
             "serving_engine_built", slots=slots, max_len=max_len,
-            decode_attention=self.decode_attention,
+            pool_attention=self.pool_attention,
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -875,10 +963,11 @@ class PagedServingEngine(ServingEngine):
         return self.kv_cache_dtype == "int8"
 
     @property
-    def decode_attention(self) -> str:
+    def pool_attention(self) -> str:
         """``"paged_kernel"`` or ``"xla_gather"``: what the plain decode
-        program was built with (:func:`decode_attention_kind`)."""
-        return self._steps.decode_attention
+        and prefill programs were built with
+        (:func:`pool_attention_kind`)."""
+        return self._steps.pool_attention
 
     def _fresh_pool(self):
         shape = (
@@ -933,7 +1022,7 @@ class PagedServingEngine(ServingEngine):
             *pools, self._params, jnp.asarray(chunk),
             jnp.zeros(self.max_blocks, jnp.int32),
             np.int32(0), np.int32(1), np.float32(0.0),
-            self._rng, np.int32(0),
+            self._rng, np.int32(0), np.bool_(True),
         )
         *pools, nxt = self._steps.decode(
             *pools, self._params,
@@ -1192,13 +1281,14 @@ class PagedServingEngine(ServingEngine):
             self._privatize(req, idx)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
-        self._mark("prefill_prep", "prefill_tokens", n_valid)
+        self._mark_prefill_prep(n_valid, start + n_valid)
         *pools, first = self._steps.prefill(
             *self._pools(), self._params, jnp.asarray(chunk),
             jnp.asarray(self._tables[req.slot]),
             np.int32(start), np.int32(n_valid),
             np.float32(req.temperature), self._rng,
             np.int32(self._step_idx),
+            np.bool_(start + n_valid == req.prompt_len),
         )
         self._set_pools(pools)
         self._mark("prefill_launch")
@@ -1302,6 +1392,7 @@ class PagedServingEngine(ServingEngine):
             (stats["used"] + stats["cached"]) * self._block_bytes
         )
         stats["cow_copies"] = self._allocator.cow_copies_total
+        stats["pool_attention"] = self.pool_attention
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value

@@ -574,20 +574,131 @@ def test_pool_decode_attention_matches_gather(
     assert np.abs(other[busy] - got[busy]).max() > 1e-2
 
 
-def test_paged_engine_tokens_are_the_same_through_the_pool_kernel(
-    monkeypatch,
+# Each case: one slot's prefill chunk of ``chunk`` tokens over a pool of
+# ``bs``-row pages behind a shuffled 12-page table, ``start`` cache rows
+# below it; 32 query heads on 8 KV heads unless said. A VMEM chunk
+# holds 4 pages of the bf16 pool, a grid step 8 tokens.
+_CHUNK_CASES = {
+    # A prompt's first chunk: nothing below it, no page read.
+    "first_chunk": dict(chunk=16, bs=16, start=0),
+    # Mid-prompt, whole pages below the chunk, one VMEM chunk of them.
+    "mid_prompt": dict(chunk=16, bs=16, start=48),
+    # Two whole VMEM chunks of prefix: no row of them is masked.
+    "prefix_of_whole_vmem_chunks": dict(chunk=16, bs=16, start=128),
+    # The prefix spans three VMEM chunks, the last one a page short.
+    "prefix_of_three_vmem_chunks": dict(chunk=16, bs=8, start=80),
+    # A chunk is a share of a block: the prefix ends mid-page, the
+    # rows of that page past ``start`` (the chunk's own, not written
+    # yet) are masked.
+    "chunk_smaller_than_a_block": dict(chunk=8, bs=16, start=40),
+    "mha": dict(chunk=8, bs=8, start=24, heads=16, kv_heads=16),
+    "one_query_head_a_kv_head_pair": dict(
+        chunk=8, bs=8, start=16, heads=16, kv_heads=8,
+    ),
+}
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+def test_pool_chunk_attention_matches_gather(case, pool_dtype, monkeypatch):
+    """The in-place chunk kernel (interpret mode on CPU) against what
+    the paged prefill computed before it: the slot's pages gathered
+    into a ``[max_len]`` view, the chunk written at ``start``, plain
+    ``dot_product_attention`` over every row of it. Layer 1 of a
+    two-layer pool; pages the table does not reach below ``start`` hold
+    NaNs, which the kernel must never copy."""
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.ops.attention import dot_product_attention
+
+    spec = dict(_CHUNK_CASES[case])
+    t, bs, start = spec["chunk"], spec["bs"], spec["start"]
+    h, kh = spec.get("heads", 32), spec.get("kv_heads", 8)
+    d, mb, n_layers, layer = 128, 12, 2, 1
+    monkeypatch.setattr(da, "_POOL_CHUNK_BYTES", 4 * bs * kh * d * 2)
+    monkeypatch.setattr(da, "_CHUNK_QUERY_ROWS", 8 * h)
+    rs = np.random.RandomState(0)
+    table = (rs.permutation(2 * mb)[:mb] + 1).astype(np.int32)
+    ks = jax.random.split(jax.random.key(2), 5)
+    pool_shape = (n_layers, 2 * mb + 1, bs, kh, d)
+    k_pool = jax.random.normal(ks[0], pool_shape).astype(pool_dtype)
+    v_pool = jax.random.normal(ks[1], pool_shape).astype(pool_dtype)
+    q = jax.random.normal(ks[2], (t, h, d)).astype(pool_dtype)
+    k_new = jax.random.normal(ks[3], (t, kh, d)).astype(pool_dtype)
+    v_new = jax.random.normal(ks[4], (t, kh, d)).astype(pool_dtype)
+
+    def view(pool, new):
+        rows = pool[layer][table].reshape(mb * bs, kh, d)
+        return jax.lax.dynamic_update_slice(rows, new, (start, 0, 0))[None]
+
+    want = np.asarray(dot_product_attention(
+        q[None], view(k_pool, k_new), view(v_pool, v_new), causal=True,
+        q_positions=(start + jnp.arange(t))[None],
+        kv_positions=jnp.arange(mb * bs),
+    )[0], np.float32)
+    unread = table[-(-start // bs):]
+    k_pool = k_pool.at[:, unread].set(jnp.nan)
+    v_pool = v_pool.at[:, unread].set(jnp.nan)
+    args = (k_pool, v_pool, jnp.int32(layer), jnp.asarray(table),
+            jnp.int32(start))
+    got = np.asarray(
+        da.pool_chunk_attention(q, k_new, v_new, *args), np.float32
+    )
+    assert np.isfinite(got).all()
+    # f32: the order of summation; bf16: the output's last rounding.
+    tol = dict(rtol=2e-5, atol=2e-6) if pool_dtype == "float32" else dict(
+        rtol=2 ** -7, atol=2 ** -9
+    )
+    np.testing.assert_allclose(got, want, **tol)
+    # Probabilities rounded to the pool's dtype once (what XLA's default
+    # matmul precision makes of the reference on a TPU): the same
+    # answer to that rounding.
+    rounded = np.asarray(da.pool_chunk_attention(
+        q, k_new, v_new, *args, exact=False
+    ), np.float32)
+    np.testing.assert_allclose(rounded, want, rtol=2 ** -6, atol=2 ** -7)
+    # Another layer of the same pool is another answer (below start).
+    if start:
+        other = np.asarray(da.pool_chunk_attention(
+            q, k_new, v_new, *args[:2], jnp.int32(0), *args[3:]
+        ), np.float32)
+        assert not np.isfinite(other).all() or (
+            np.abs(other - got).max() > 1e-2
+        )
+
+
+# (prefill_chunk, block_size): a chunk of one block, of two, and half
+# of one (the divisibility contract's two sides).
+_CHUNK_BLOCK = {"chunk_is_a_block": (8, 8), "chunk_of_two_blocks": (16, 8),
+                "chunk_is_half_a_block": (8, 16)}
+
+
+def _admit_f32_pools(monkeypatch):
+    """Take the kernel path on the CPU: the platform probe says TPU
+    (the kernels then run in interpret mode) and the page predicate
+    admits the f32 pools of the tiny models here, where XLA's f32 is
+    f32 and the two paths differ by the order of summation alone."""
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(da, "pool_kernel_supported", lambda *a: True)
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_BLOCK))
+def test_paged_engine_tokens_are_the_same_through_the_pool_kernels(
+    case, monkeypatch,
 ):
     """One run of mixed prompts over a pool too small for them (slots
     admitted and finished mid-run, the youngest preempted once): the
-    decode program built with the pool kernel — the platform probe
-    patched, the kernel in interpret mode — emits the greedy tokens of
-    the gather program, and retraces nothing across admissions."""
+    decode and prefill programs built with the pool kernels — the
+    platform probe patched, the kernels in interpret mode — emit the
+    greedy tokens of the gather programs, and retrace nothing across
+    admissions."""
     from dlrover_tpu.observability.registry import MetricsRegistry
-    from dlrover_tpu.serving.kvpool import engine as paged
 
+    chunk, bs = _CHUNK_BLOCK[case]
     cfg = llama.tiny_config(
         n_layers=2, n_heads=16, n_kv_heads=8, head_dim=128,
-        dtype="bfloat16",
     )
     params, _ = llama.init_params(cfg, jax.random.key(0))
     prompts = make_prompts(cfg, (12, 5, 19, 12, 9, 3), seed=7)
@@ -595,8 +706,8 @@ def test_paged_engine_tokens_are_the_same_through_the_pool_kernel(
 
     def run():
         eng = PagedServingEngine(
-            cfg, params, slots=4, max_len=32, prefill_chunk=8,
-            block_size=8, num_blocks=10, prefix_cache=False,
+            cfg, params, slots=4, max_len=32, prefill_chunk=chunk,
+            block_size=bs, num_blocks=80 // bs, prefix_cache=False,
             registry=MetricsRegistry(),
         )
         eng.warmup()
@@ -607,20 +718,128 @@ def test_paged_engine_tokens_are_the_same_through_the_pool_kernel(
         assert eng.metrics.kv_preemptions.value() >= 1
         assert eng.trace_counts == base
         eng.check_block_invariants()
-        return eng.decode_attention, [r.tokens for r in reqs]
+        assert eng.kv_stats()["pool_attention"] == eng.pool_attention
+        return eng.pool_attention, [r.tokens for r in reqs]
 
     kind, want = run()
     assert kind == "xla_gather"
-    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    _admit_f32_pools(monkeypatch)
     kind, got = run()
     assert kind == "paged_kernel"
     assert got == want
     assert [len(t) for t in got] == list(new)
 
 
-# What decode_attention_kind sees -> what it builds. Defaults: a TPU, a
-# bf16 model of 32 heads on 8 KV heads x 128, 16-row pages (the
-# ``nemo12b-serve-chat`` engine), a bf16 pool.
+@pytest.mark.parametrize("case", sorted(_CHUNK_BLOCK))
+def test_in_place_prefill_never_rewrites_a_shared_block(case, monkeypatch):
+    """A prefix-cache hit resumes a prompt mid-way (``start > 0`` on
+    its first chunk): the in-place prefill reads the shared pages and
+    writes its own, so every block the cache holds keeps its bytes, the
+    tokens are the gather engine's, and the books balance."""
+    from dlrover_tpu.observability.registry import MetricsRegistry
+
+    chunk, bs = _CHUNK_BLOCK[case]
+    cfg = llama.tiny_config(
+        n_layers=2, n_heads=16, n_kv_heads=8, head_dim=128,
+    )
+    params, _ = llama.init_params(cfg, jax.random.key(0))
+    shared = make_prompts(cfg, (32,), seed=3)[0]
+    tails = make_prompts(cfg, (5, 9, 3), seed=4)
+    prompts = [np.concatenate([shared, t]) for t in tails]
+
+    def run():
+        eng = PagedServingEngine(
+            cfg, params, slots=2, max_len=64, prefill_chunk=chunk,
+            block_size=bs, registry=MetricsRegistry(),
+        )
+        eng.warmup()
+        first = eng.submit(prompts[0], 4)
+        eng.run_until_idle()
+        held = sorted(
+            b for b in range(1, eng.num_blocks)
+            if eng._allocator.refcount(b) > 0
+        )
+        assert held  # the cache keeps the finished prompt's full blocks
+        before = [np.asarray(p[:, held]) for p in eng._pools()]
+        rest = [eng.submit(p, 4) for p in prompts[1:]]
+        eng.run_until_idle()
+        assert eng.kv_stats()["prefix_hits"] == 2
+        assert all(r.prefix_hit_blocks > 0 for r in rest)
+        for was, pool in zip(before, eng._pools()):
+            np.testing.assert_array_equal(was, np.asarray(pool[:, held]))
+        eng.check_block_invariants()
+        return eng.pool_attention, [r.tokens for r in [first] + rest]
+
+    kind, want = run()
+    assert kind == "xla_gather"
+    _admit_f32_pools(monkeypatch)
+    kind, got = run()
+    assert kind == "paged_kernel"
+    assert got == want
+
+
+@pytest.mark.parametrize(
+    "kv_dtype,in_place",
+    [("fp", False), ("int8", False), ("fp", True)],
+    ids=["fp_gather", "int8_gather", "fp_in_place"],
+)
+def test_prefill_runs_its_head_on_a_last_chunk_only(
+    kv_dtype, in_place, tiny, monkeypatch
+):
+    """The chunk program under its ``last`` flag: the pools it returns
+    are bit for bit the same with the head on, off, and called with
+    ten arguments as before the flag (the head then always on); the
+    first token of a last chunk is that call's, any other chunk's is
+    the placeholder 0 — and one compiled program serves both."""
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    cfg, params = tiny
+    if in_place:
+        cfg = llama.tiny_config(
+            n_layers=2, n_heads=16, n_kv_heads=8, head_dim=128,
+        )
+        params, _ = llama.init_params(cfg, jax.random.key(0))
+        _admit_f32_pools(monkeypatch)
+    eng = PagedServingEngine(
+        cfg, params, slots=2, max_len=32, prefill_chunk=8,
+        block_size=8, kv_cache_dtype=kv_dtype,
+    )
+    assert eng.pool_attention == (
+        "paged_kernel" if in_place else "xla_gather"
+    )
+    eng.warmup()
+    traced = dict(eng.trace_counts)
+    prompt = make_prompts(cfg, (8,), seed=5)[0]
+    table = jnp.asarray(np.arange(1, 5, dtype=np.int32))
+    args = (
+        eng._params, jnp.asarray(prompt[None]), table, np.int32(0),
+        np.int32(8), np.float32(0.0), eng._rng, np.int32(3),
+    )
+
+    def chunk(*flag):
+        # The programs donate their pools: a fresh set a call.
+        pools = eng._fresh_pool()
+        if kv_dtype == "int8":
+            pools += eng._fresh_scales()
+        *pools, first = eng._steps.prefill(*pools, *args, *flag)
+        return [np.asarray(p) for p in pools], int(first)
+
+    pools_old, first_old = chunk()
+    pools_on, first_on = chunk(np.bool_(True))
+    pools_off, first_off = chunk(np.bool_(False))
+    for old, on, off in zip(pools_old, pools_on, pools_off):
+        np.testing.assert_array_equal(old, on)
+        np.testing.assert_array_equal(old, off)
+    assert np.abs(pools_old[0]).max() > 0
+    assert first_on == first_old and first_off == 0
+    # The traced flag is one program (the ten-argument call, whose
+    # flag is a Python constant, is the other).
+    assert eng.trace_counts["prefill"] == traced["prefill"] + 1
+
+
+# What pool_attention_kind sees -> what it builds. Defaults: a TPU, a
+# bf16 model of 32 heads on 8 KV heads x 128, 16-row pages, chunks of
+# 256 tokens (the ``nemo12b-serve-chat`` engine), a bf16 pool.
 _KIND_CASES = {
     "the_cell": (dict(), "paged_kernel"),
     "mha": (dict(n_kv_heads=32), "paged_kernel"),
@@ -632,11 +851,19 @@ _KIND_CASES = {
     # 1,024 rows x 8 x 128 x 2 B = 2 MB: one page outgrows a VMEM chunk.
     "page_larger_than_a_chunk": (dict(block_size=1024), "xla_gather"),
     "page_of_one_chunk": (dict(block_size=512), "paged_kernel"),
+    # chip_smoke's engine: 8 / 8 heads, chunks of 64.
+    "short_chunks": (dict(n_heads=8, chunk=64), "paged_kernel"),
+    # No tile of whole sublanes of tokens divides the chunk.
+    "chunk_of_12_tokens": (dict(chunk=12, block_size=4), "xla_gather"),
+    # 1,024 tokens x 32 KV heads of own K/V outgrow the kernel's VMEM.
+    "long_chunks_of_mha": (
+        dict(n_kv_heads=32, chunk=1024), "xla_gather"
+    ),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_KIND_CASES))
-def test_decode_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
+def test_pool_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
     from dlrover_tpu.serving.kvpool import engine as paged
 
     seen, want = _KIND_CASES[case]
@@ -645,11 +872,14 @@ def test_decode_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
     monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
     block_size = seen.pop("block_size", 16)
     kv_dtype = seen.pop("kv_dtype", "fp")
+    chunk = seen.pop("chunk", 256)
     cfg = llama.tiny_config(**{
         **dict(n_heads=32, n_kv_heads=8, head_dim=128, dtype="bfloat16"),
         **seen,
     })
-    assert paged.decode_attention_kind(cfg, block_size, kv_dtype) == want
+    assert paged.pool_attention_kind(
+        cfg, block_size, kv_dtype, chunk
+    ) == want
 
 
 @pytest.mark.parametrize("tiny", [False, True])
@@ -679,9 +909,17 @@ def test_bench_paged_decode_times_nothing_off_a_tpu(tiny):
     rows = [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
     assert [r.get("part") for r in rows] == [
         "shape", "parity", "attention", "attention", "attention",
-        "decode", None,
+        "decode", "prefill_parity", "prefill_parity",
+        "prefill_attention", "prefill_attention", "prefill", "prefill",
+        None,
     ]
-    assert rows[-1]["ok"] and rows[-2]["same_tokens"] == [2, 2]
+    assert rows[-1]["ok"] and rows[5]["same_tokens"] == [2, 2]
+    assert [r["start"] for r in rows[6:8]] == [0, 64]
+    assert all(r["same_first_token"] for r in rows[-3:-1])
+    assert all(
+        r["finite"] and r["kernel_f32_probs_vs_exact"] < 1e-5
+        for r in rows[6:8]
+    )
     assert not [k for r in rows for k in r if k.endswith(("_ms", "_s"))]
 
 

@@ -122,12 +122,40 @@ def test_counts_add_up_to_the_work_done(kind, parts, tracer):
     else:
         assert slot_steps == decoded
     assert all(
-        set(a) - {"retraces", "kv_rows"} == {
+        set(a) - {"retraces", "kv_rows", "prefill_kv_rows"} == {
             "idx", "phases", "n_admitted", "n_decoding",
             "prefill_tokens", "n_finished",
         } for a in attrs
     )
     assert all("retraces" not in a for a in attrs[3:])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_prefill_kv_rows_is_the_cache_a_chunk_launch_attends_to(
+    kind, parts, tracer
+):
+    """``prefill_kv_rows`` rides on exactly the steps that launched a
+    prefill chunk and is the slot's fill below the chunk plus the
+    chunk's own valid tokens: over a prompt's chunks it climbs by the
+    chunk to the prompt's length."""
+    serve(build(kind, parts))
+    attrs = [s["attrs"] for s in step_spans(tracer)]
+    assert all(
+        ("prefill_kv_rows" in a) == (a["prefill_tokens"] > 0)
+        for a in attrs
+    )
+    chunk = 8
+    want = sorted(
+        min(start + chunk, n)
+        for n, _ in PLAN for start in range(0, n, chunk)
+    )
+    assert sorted(
+        a["prefill_kv_rows"] for a in attrs if a["prefill_tokens"]
+    ) == want
+    # The last chunk of a prompt sees all of it.
+    assert {n for n, _ in PLAN} <= {
+        a["prefill_kv_rows"] for a in attrs if a["prefill_tokens"]
+    }
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -305,11 +333,17 @@ def test_trace_query_renders_a_step_span(parts, tracer, tmp_path, capsys):
             sum(s["attrs"].get("kv_rows", 0) for s in spans)
             / sum("kv_rows" in s["attrs"] for s in spans)
         ),
+        "prefill_kv_rows_mean": pytest.approx(
+            sum(s["attrs"].get("prefill_kv_rows", 0) for s in spans)
+            / sum("prefill_kv_rows" in s["attrs"] for s in spans)
+        ),
         "retraced_steps": [
             s["attrs"]["idx"] for s in spans if "retraces" in s["attrs"]
         ],
     }
     assert 1.0 <= table["counts"]["decode_batch_mean"] <= 2.0
+    assert table["counts"]["prefill_kv_rows_mean"] > 8
     assert trace_query.main(["--steps", sink]) == 0
     printed = capsys.readouterr().out
     assert "retraced_steps=" in printed and "kv_rows_mean=" in printed
+    assert " prefill_kv_rows_mean=" in printed

@@ -174,8 +174,9 @@ def test_flagship_step_compiles_for_four_chips(topo, mesh_config):
     assert 0.8e9 < mem.argument_size_in_bytes < 1.3e9
 
 
-# Widths, engine shape and what the decode program must be built with.
-_PAGED_DECODE_CASES = {
+# Widths, engine shape and what the decode and prefill programs must be
+# built with (prefill chunks of 256 tokens unless said).
+_PAGED_CASES = {
     # ``nemo12b-serve-chat``: GQA 32 / 8, a 2,304-row cache.
     "nemo12b_cell": dict(
         vocab_size=131072, embed_dim=5120, mlp_dim=14336, n_heads=32,
@@ -197,7 +198,7 @@ _PAGED_DECODE_CASES = {
     # tile of 16), 4 slots, a 576-row cache.
     "flagship_short_cache": dict(
         vocab_size=32000, embed_dim=1024, mlp_dim=4096, n_heads=8,
-        n_kv_heads=8, head_dim=128, slots=4, max_blocks=36,
+        n_kv_heads=8, head_dim=128, slots=4, max_blocks=36, chunk=64,
         want="paged_kernel",
     ),
     # One page is larger than a VMEM chunk: the kernel cannot hold it.
@@ -209,29 +210,22 @@ _PAGED_DECODE_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_PAGED_DECODE_CASES))
-def test_paged_decode_reads_the_pool_in_place(one_chip, case):
-    """The paged decode program (2 layers) compiles for the described
-    v5e with the attention ``decode_attention_kind`` chose for it —
-    nothing falls back after that choice, so what it admits has to
-    lower. Built with the pool kernel it has one call in the layer
-    body and NO copy of the cache — neither the layer's pool sliced
-    out of the stacked arrays nor the gathered ``[slots, max_len]``
-    view, which were 153 MB of temporaries and 43 % of the decode
-    program's time at ``nemo12b-serve-chat`` (PERF.md §5, PR 25)."""
+def _paged_programs(case, one_chip):
+    """The case's config and paged step programs, and the arguments
+    both programs start with, as shapes on the described chip."""
     from dlrover_tpu.models import generate as gen_lib
     from dlrover_tpu.serving.kvpool import engine as paged
 
-    spec = dict(_PAGED_DECODE_CASES[case])
+    spec = dict(_PAGED_CASES[case])
     want = spec.pop("want")
     slots, max_blocks = spec.pop("slots", 16), spec.pop("max_blocks", 144)
-    bs = spec.pop("block_size", 16)
+    bs, chunk = spec.pop("block_size", 16), spec.pop("chunk", 256)
     cfg = llama.TpuLMConfig(n_layers=2, dtype="bfloat16", **spec)
     num_blocks = slots * max_blocks + 1
-    assert paged.decode_attention_kind(cfg, bs, "fp") == want
-    assert paged.decode_attention_kind(cfg, bs, "int8") == "xla_gather"
-    steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, 256)
-    assert steps.decode_attention == want
+    assert paged.pool_attention_kind(cfg, bs, "fp", chunk) == want
+    assert paged.pool_attention_kind(cfg, bs, "int8", chunk) == "xla_gather"
+    steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, chunk)
+    assert steps.pool_attention == want
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -251,9 +245,29 @@ def test_paged_decode_reads_the_pool_in_place(one_chip, case):
         (cfg.n_layers, num_blocks, bs, cfg.n_kv_heads, cfg.head_dim),
         jnp.bfloat16,
     )
+    shape = dict(slots=slots, max_blocks=max_blocks, bs=bs, chunk=chunk,
+                 num_blocks=num_blocks)
+    return cfg, steps, want, shape, arr, (pool, pool, params), key
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_CASES))
+def test_paged_decode_reads_the_pool_in_place(one_chip, case):
+    """The paged decode program (2 layers) compiles for the described
+    v5e with the attention ``pool_attention_kind`` chose for it —
+    nothing falls back after that choice, so what it admits has to
+    lower. Built with the pool kernel it has one call in the layer
+    body and NO copy of the cache — neither the layer's pool sliced
+    out of the stacked arrays nor the gathered ``[slots, max_len]``
+    view, which were 153 MB of temporaries and 43 % of the decode
+    program's time at ``nemo12b-serve-chat`` (PERF.md §5, PR 25)."""
+    cfg, steps, want, shape, arr, lead, key = _paged_programs(
+        case, one_chip
+    )
+    slots, max_blocks = shape["slots"], shape["max_blocks"]
+    bs, num_blocks = shape["bs"], shape["num_blocks"]
     i32 = jnp.int32
     c = steps.decode.lower(
-        pool, pool, params, arr((slots, max_blocks), i32),
+        *lead, arr((slots, max_blocks), i32),
         arr((slots,), i32), arr((slots,), i32), arr((slots,), bool),
         arr((slots,), jnp.float32), key, arr((), i32),
     ).compile()
@@ -267,3 +281,40 @@ def test_paged_decode_reads_the_pool_in_place(one_chip, case):
     for rows in (num_blocks, num_blocks - 1):   # a layer's pool, a view
         assert f"[{rows},{bs},{kv}" not in text
     assert c.memory_analysis().temp_size_in_bytes < 32e6
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_CASES))
+def test_paged_prefill_does_only_its_chunks_work(one_chip, case):
+    """The paged prefill program (2 layers) compiles for the described
+    v5e with the same choice. Built with the chunk kernel it has one
+    call in the layer body, NO array with a ``max_len`` axis — the
+    gather program carries one slot's ``[layers, 1, max_len]`` view
+    through the layer scan, in and out, and scores all of it whatever
+    ``start`` is (PERF.md §5, PR 28) — and the pools alias their
+    outputs. The flag that turns the head off is a traced argument:
+    one program for a prompt's every chunk."""
+    import re
+
+    cfg, steps, want, shape, arr, lead, key = _paged_programs(
+        case, one_chip
+    )
+    max_blocks, bs, chunk = shape["max_blocks"], shape["bs"], shape["chunk"]
+    i32 = jnp.int32
+    c = steps.prefill.lower(
+        *lead, arr((1, chunk), i32), arr((max_blocks,), i32),
+        arr((), i32), arr((), i32), arr((), jnp.float32), key,
+        arr((), i32), arr((), bool),
+    ).compile()
+    text = c.as_text()
+    assert "jit_prefill," in text.splitlines()[0]   # what traces name it
+    assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in text
+    max_len_axes = re.findall(rf"[\[,]{max_blocks * bs}[,\]]", text)
+    if want == "xla_gather":
+        assert _n_kernels(c) == 0 and max_len_axes
+        return
+    assert _n_kernels(c) == 1  # in the scan's body, once for all layers
+    assert "paged_pool_chunk_attention" in text
+    assert not max_len_axes
+    # 0.40 GB of temporaries in the gather program at the cell's shape
+    # (12 layers); what is left is the layer's sliced-out weights.
+    assert c.memory_analysis().temp_size_in_bytes < 0.2e9

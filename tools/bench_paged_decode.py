@@ -1,11 +1,14 @@
-"""Paged decode attention A/B on the device at hand: pool kernel vs gather.
+"""Paged attention A/B on the device at hand: pool kernels vs gather.
 
-``serving/kvpool/engine.py`` builds its plain decode program with one of
-two attentions (``decode_attention_kind``): ``paged_kernel`` reads each
-layer's K/V from the stacked pool in place, filled pages only
-(``ops.decode_attention.pool_decode_attention``); ``xla_gather`` slices
-the layer's pool, gathers a ``[slots, max_len]`` view through the tables
-and runs ``_append_free_attention`` on it. This probe holds the two
+``serving/kvpool/engine.py`` builds its plain decode program and its
+prefill program with one of two attentions (``pool_attention_kind``):
+``paged_kernel`` reads each layer's K/V from the stacked pool in place,
+filled pages only (``ops.decode_attention.pool_decode_attention`` for
+the decode step, ``pool_chunk_attention`` for a prefill chunk);
+``xla_gather`` slices the layer's pool, gathers a ``[slots, max_len]``
+view through the tables and runs ``_append_free_attention`` on it (the
+prefill chunk: one slot's view, the chunk written into it, plain
+attention over all of it). This probe holds the two
 against each other at the shapes of ``--preset``: ``nemo12b``, the
 ``nemo12b-serve-chat`` cell's (16 slots x 2,304 rows in 16-row pages, 32
 heads / 8 KV x 128, 12 layers); ``flagship334m``, ``chip_smoke.py``'s
@@ -15,13 +18,18 @@ MHA model at the cell's cache (32 / 32 x 128). ``--slots`` and
 
     python tools/bench_paged_decode.py          # on the chip: chiprun -- ...
 
-Three parts (``--parts``), one JSON line each: ``parity`` (the kernel
+Parts (``--parts``), one JSON line each: ``parity`` (the decode kernel
 against the gather reference on one layer of a random pool, fills on
 every edge), ``attention`` (all layers' attention alone, at the chat
 traffic's fills, a full and a short cache, and over four chunk sizes),
-``decode`` (the whole decode program both ways, random weights). Exits 1
-if the kernel is not finite or, fed f32 queries, more than 1e-5 off the
-reference at the highest matmul precision. A smoke reading, not a
+``decode`` (the whole decode program both ways, random weights), and
+the prefill mode, ``prefill``: at ``--starts`` (cache rows below the
+chunk) the chunk kernel against the exact softmax — probabilities kept
+f32 and rounded to bf16 once, beside the gather's error — all layers'
+chunk attention alone over ``--query-rows`` tile sizes, and the whole
+prefill program both ways with the head on and off. Exits 1 if a kernel
+is not finite or, fed f32 queries, more than 1e-5 off the reference at
+the highest matmul precision. A smoke reading, not a
 benchmark: one process, host-clock timing around ``block_until_ready``.
 Times mean something on a TPU only: anywhere else the tool refuses to
 run, unless ``--tiny`` rehearses it (interpret mode, nothing timed).
@@ -43,17 +51,21 @@ PRESETS = {
     "nemo12b": dict(
         slots=16, max_blocks=144, heads=32, kv_heads=8, head_dim=128,
         layers=12, vocab_size=131072, embed_dim=5120, mlp_dim=14336,
+        chunk=256,
     ),
     "flagship334m": dict(
         slots=4, max_blocks=36, heads=8, kv_heads=8, head_dim=128,
         layers=16, vocab_size=32000, embed_dim=1024, mlp_dim=4096,
+        chunk=64,
     ),
     "llama2-7b": dict(
         slots=16, max_blocks=144, heads=32, kv_heads=32, head_dim=128,
         layers=8, vocab_size=32000, embed_dim=4096, mlp_dim=11008,
+        chunk=256,
     ),
 }
-TINY = dict(max_blocks=40, vocab_size=512, embed_dim=256, mlp_dim=512)
+TINY = dict(max_blocks=40, vocab_size=512, embed_dim=256, mlp_dim=512,
+            chunk=64)
 
 
 def _timed(fn, repeats):
@@ -71,6 +83,34 @@ def _timed(fn, repeats):
 def _put(row, key, value):
     if value is not None:
         row[key] = value
+
+
+def _normal(key, dims):
+    """bf16 standard normals of ``dims``, made on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(
+        lambda k: jax.random.normal(k, dims, jnp.bfloat16)
+    )(key)
+
+
+def _weights(cfg, key):
+    """Random bf16 weights of ``cfg`` as the engines hold them."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import generate as gen_lib
+    from dlrover_tpu.models import llama
+
+    def make(key):
+        params, _ = llama.init_params(cfg, key)
+        cast = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params
+        )
+        return gen_lib.prepare_decode_params(cfg, cast)
+
+    return jax.jit(make)(key)
 
 
 def _fills(kind, rng, slots, max_len):
@@ -104,7 +144,7 @@ def _reference(q, k_new, v_new, k_pool, v_pool, layer, tables, fills):
     )[:, 0]
 
 
-def run(shape, parts, chunk_kb, repeats, seed):
+def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -134,20 +174,15 @@ def run(shape, parts, chunk_kb, repeats, seed):
         "view_mb": round(
             2 * slots * max_len * kv_heads * head_dim * 2 / 1e6, 1
         ),
-        "engine_would_build": paged.decode_attention_kind(
-            cfg, BLOCK, "fp"
+        "engine_would_build": paged.pool_attention_kind(
+            cfg, BLOCK, "fp", shape.chunk
         ),
     }), flush=True)
 
-    def normal(key, shape):
-        return jax.jit(
-            lambda k: jax.random.normal(k, shape, jnp.bfloat16)
-        )(key)
-
-    k_pool, v_pool = normal(keys[0], pool_shape), normal(keys[1], pool_shape)
-    q = normal(keys[2], (slots, heads, head_dim))
-    k_new = normal(keys[3], (slots, kv_heads, head_dim))
-    v_new = normal(keys[4], (slots, kv_heads, head_dim))
+    k_pool, v_pool = _normal(keys[0], pool_shape), _normal(keys[1], pool_shape)
+    q = _normal(keys[2], (slots, heads, head_dim))
+    k_new = _normal(keys[3], (slots, kv_heads, head_dim))
+    v_new = _normal(keys[4], (slots, kv_heads, head_dim))
     tables = jnp.asarray(
         (rng.permutation(slots * max_blocks) + 1)
         .reshape(slots, max_blocks).astype(np.int32)
@@ -250,14 +285,7 @@ def run(shape, parts, chunk_kb, repeats, seed):
 
     # ---- the whole decode program ------------------------------------------
     if "decode" in parts:
-        def weights(key):
-            params, _ = llama.init_params(cfg, key)
-            cast = jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.bfloat16), params
-            )
-            return gen_lib.prepare_decode_params(cfg, cast)
-
-        params = jax.jit(weights)(keys[5])
+        params = _weights(cfg, keys[5])
         fills = _fills("chat", rng, slots, max_len)
         host = (
             tables, jnp.asarray(fills), jnp.zeros(slots, jnp.int32),
@@ -286,10 +314,174 @@ def run(shape, parts, chunk_kb, repeats, seed):
             slots,
         ]
         print(json.dumps(row), flush=True)
+    if "prefill" in parts:
+        ok = _prefill(
+            cfg, shape, starts, query_rows, repeats, rng, keys, k_pool,
+            v_pool,
+        ) and ok
     print(json.dumps({
         "ok": ok,
         "device": {"platform": dev.platform, "kind": dev.device_kind},
     }), flush=True)
+    return ok
+
+
+def _chunk_reference(q, k_new, v_new, k_pool, v_pool, layer, table_row,
+                     start):
+    """What the gather prefill computes a layer: the slot's logical
+    view, the chunk written at ``start``, plain causal attention over
+    every row of it."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.ops.attention import dot_product_attention
+
+    rows = table_row.shape[0] * BLOCK
+    views = [
+        jax.lax.dynamic_update_slice(
+            pool[layer][table_row].reshape((rows,) + pool.shape[-2:]),
+            new.astype(pool.dtype), (start, 0, 0),
+        )
+        for pool, new in ((k_pool, k_new), (v_pool, v_new))
+    ]
+    t = q.shape[0]
+    return dot_product_attention(
+        q[None], views[0][None], views[1][None], causal=True,
+        q_positions=(start + jnp.arange(t))[None],
+        kv_positions=jnp.arange(rows),
+    )[0]
+
+
+def _prefill(cfg, shape, starts, query_rows, repeats, rng, keys, k_pool,
+             v_pool):
+    """The prefill mode: one slot's chunk of ``shape.chunk`` tokens at
+    each of ``starts``."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    chunk, layers, max_blocks = shape.chunk, shape.layers, shape.max_blocks
+    max_len = max_blocks * BLOCK
+    starts = sorted({
+        min(s, max_len - chunk) // chunk * chunk for s in starts
+    })
+    table_row = jnp.asarray(
+        (rng.permutation(shape.slots * max_blocks)[:max_blocks] + 1)
+        .astype(np.int32)
+    )
+
+    q = _normal(keys[2], (chunk, shape.heads, shape.head_dim))
+    k_new = _normal(keys[3], (chunk, shape.kv_heads, shape.head_dim))
+    v_new = _normal(keys[4], (chunk, shape.kv_heads, shape.head_dim))
+    ok = True
+
+    # ---- the kernel's arithmetic against the exact softmax -----------------
+    # bf16 values as f32 inputs, so that the outputs are f32 and what is
+    # compared is the arithmetic, not the last rounding to bf16.
+    wide = [x.astype(jnp.float32) for x in (q, k_new, v_new)]
+    for start in starts:
+        args = (k_pool, v_pool, jnp.int32(layers - 1), table_row,
+                jnp.int32(start))
+        with jax.default_matmul_precision("highest"):
+            exact = np.asarray(jax.jit(_chunk_reference)(*wide, *args))
+        row = {"part": "prefill_parity", "start": start}
+        for name, fn in (
+            ("kernel_f32_probs", da.pool_chunk_attention),
+            ("kernel_bf16_probs", functools.partial(
+                da.pool_chunk_attention, exact=False)),
+            ("gather", _chunk_reference),
+        ):
+            got = np.asarray(jax.jit(fn)(*wide, *args))
+            row[f"{name}_vs_exact"] = float(np.abs(got - exact).max())
+            row["finite"] = row.get("finite", True) and bool(
+                np.isfinite(got).all()
+            )
+        print(json.dumps(row), flush=True)
+        ok = ok and row["finite"] and row["kernel_f32_probs_vs_exact"] <= 1e-5
+
+    # ---- all layers' chunk attention alone ---------------------------------
+    def all_layers(attend):
+        def f(q, k_new, v_new, k_pool, v_pool, table_row, start):
+            def body(carry, layer):
+                out = attend(
+                    q, k_new, v_new, k_pool, v_pool, layer, table_row,
+                    start,
+                )
+                return carry + out.astype(jnp.float32), None
+
+            total, _ = jax.lax.scan(
+                body, jnp.zeros(q.shape, jnp.float32),
+                jnp.arange(layers, dtype=jnp.int32),
+            )
+            return total
+
+        return jax.jit(f)
+
+    shipped = da._CHUNK_QUERY_ROWS, da._CHUNK_VMEM_BYTES
+    for start in starts:
+        row = {"part": "prefill_attention", "start": start,
+               "kv_rows": start + chunk}
+        timed = (q, k_new, v_new, k_pool, v_pool, table_row,
+                 jnp.int32(start))
+        for rows in query_rows or shipped[:1]:
+            # A larger tile than the shipped one wants more VMEM.
+            da._CHUNK_QUERY_ROWS = rows
+            da._CHUNK_VMEM_BYTES = shipped[1] * max(1, rows // shipped[0])
+            for name, exact in (("f32_probs", True), ("bf16_probs", False)):
+                fn = all_layers(functools.partial(
+                    da.pool_chunk_attention, exact=exact
+                ))
+                _put(row, f"kernel_{rows}rows_{name}_ms", _timed(
+                    lambda: jax.block_until_ready(fn(*timed)), repeats
+                ))
+        da._CHUNK_QUERY_ROWS, da._CHUNK_VMEM_BYTES = shipped
+        fn = all_layers(_chunk_reference)
+        _put(row, "gather_ms", _timed(
+            lambda: jax.block_until_ready(fn(*timed)), repeats
+        ))
+        print(json.dumps(row), flush=True)
+    del q, k_new, v_new
+
+    # ---- the whole prefill program -----------------------------------------
+    params = _weights(cfg, keys[5])
+    tokens = jnp.asarray(
+        rng.randint(1, shape.vocab_size, (1, chunk)).astype(np.int32)
+    )
+    programs = {
+        attn: jax.jit(
+            paged._build_paged_prefill(
+                cfg, max_blocks, BLOCK, chunk, {"prefill": 0}, attn=attn
+            ),
+            donate_argnums=(0, 1),
+        )
+        for attn in ("xla_gather", "paged_kernel")
+    }
+    for start in starts:
+        row = {"part": "prefill", "start": start}
+        first = {}
+        for attn, program in programs.items():
+            for head in (True, False):
+                def once():
+                    nonlocal k_pool, v_pool
+                    k_pool, v_pool, tok = program(
+                        k_pool, v_pool, params, tokens, table_row,
+                        np.int32(start), np.int32(chunk), np.float32(0.0),
+                        jax.random.key(0), np.int32(0), np.bool_(head),
+                    )
+                    return jax.block_until_ready(tok)
+
+                tok = int(once())
+                if head:
+                    first[attn] = tok
+                _put(row, f"{attn}_{'head' if head else 'no_head'}_ms",
+                     _timed(once, repeats))
+        row["same_first_token"] = first["xla_gather"] == first["paged_kernel"]
+        print(json.dumps(row), flush=True)
     return ok
 
 
@@ -304,7 +496,16 @@ def main():
                     help=f"pages of {BLOCK} rows in a slot's table")
     ap.add_argument("--heads", type=int, help="query heads")
     ap.add_argument("--layers", type=int)
-    ap.add_argument("--parts", default="parity,attention,decode")
+    ap.add_argument("--chunk", type=int, help="tokens of a prefill chunk")
+    ap.add_argument("--parts", default="parity,attention,decode,prefill")
+    ap.add_argument("--starts", default="0,512,2048",
+                    help="prefill: cache rows below the chunk (brought "
+                    "down to the last chunk the cache holds)")
+    ap.add_argument("--query-rows",
+                    help="prefill: query rows (tokens x heads) a grid "
+                    "step of the chunk kernel, to time it at (default, "
+                    "the one shipped: "
+                    "ops.decode_attention._CHUNK_QUERY_ROWS)")
     ap.add_argument("--chunk-kb", default="256,512,1024,2048",
                     help="VMEM chunk sizes to time the kernel at (the "
                     "one shipped: ops.decode_attention._POOL_CHUNK_BYTES)")
@@ -318,7 +519,7 @@ def main():
     if ns.tiny:
         shape.update(TINY)
     shape.update({
-        k: v for k in ("slots", "max_blocks", "heads", "layers")
+        k: v for k in ("slots", "max_blocks", "heads", "layers", "chunk")
         if (v := getattr(ns, k)) is not None
     })
     import jax
@@ -333,6 +534,9 @@ def main():
         argparse.Namespace(**shape), ns.parts.split(","),
         [int(kb) for kb in ns.chunk_kb.split(",")],
         0 if ns.tiny else ns.repeats, ns.seed,
+        [int(x) for x in ns.starts.split(",")],
+        [int(x) for x in ns.query_rows.split(",")] if ns.query_rows
+        else None,
     )
     raise SystemExit(0 if ok else 1)
 

@@ -129,7 +129,9 @@ def step_summary(spans: List[Dict]) -> Dict:
     emits its span tree there), ``kv_rows_mean`` is the cache rows
     visible to a decode launch (over ``decode_batch_mean`` x the
     engine's ``max_len``: the share of the logical view attention has
-    any use for), ``retraced_steps`` names the iterations that
+    any use for), ``prefill_kv_rows_mean`` the same for a prefill
+    chunk launch (the slot's fill below the chunk plus the chunk, over
+    ``max_len``), ``retraced_steps`` names the iterations that
     recompiled a program."""
     steps = [
         s for s in spans
@@ -146,6 +148,9 @@ def step_summary(spans: List[Dict]) -> Dict:
     attrs = [s["attrs"] for s in steps]
     decoding = [a["n_decoding"] for a in attrs if a["n_decoding"]]
     kv_rows = [a["kv_rows"] for a in attrs if "kv_rows" in a]
+    chunk_rows = [
+        a["prefill_kv_rows"] for a in attrs if "prefill_kv_rows" in a
+    ]
     return {"phases": rows, "counts": {
         "steps": len(steps),
         "errors": sum(s.get("status") != "ok" for s in steps),
@@ -156,6 +161,9 @@ def step_summary(spans: List[Dict]) -> Dict:
             sum(decoding) / len(decoding) if decoding else 0.0
         ),
         "kv_rows_mean": sum(kv_rows) / len(kv_rows) if kv_rows else 0.0,
+        "prefill_kv_rows_mean": (
+            sum(chunk_rows) / len(chunk_rows) if chunk_rows else 0.0
+        ),
         "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
     }}
 
