@@ -231,21 +231,16 @@ def _mfu_breakdown(step_fn, state, batch_d, step_s):
 
 
 # ---------------------------------------------------------------------------
-# Phase 1b: fused-CE A/B (pallas blockwise vs dense XLA) on hardware
+# Phase 1b: fused-CE A/B (chunked vs dense XLA) on hardware
 # ---------------------------------------------------------------------------
 
 
 def ce_ab_phase(out=None):
     """Loss fwd+bwd at the flagship head shape: dense XLA logits vs the
-    two fused CE paths. The chunked path (gradients computed in the
-    forward — same three matmuls as dense) is the production long-context
-    path and must stay within ~1.1x of dense; the Pallas blockwise path
-    (5 matmul passes, strictly O(block) memory) is the record of the
-    flash-style alternative it replaced. Results land in the
-    scheduler's sink incrementally: the dense/chunked pair is the
-    headline and must survive a slice abort during the pallas tail
-    (observed: cold compiles pushed the phase past its slice and lost
-    everything)."""
+    chunked fused CE (gradients computed in the forward — same three
+    matmuls as dense), the production long-context path, which must
+    stay within ~1.1x of dense. Results land in the scheduler's sink
+    incrementally."""
     import jax
     import jax.numpy as jnp
 
@@ -270,9 +265,6 @@ def ce_ab_phase(out=None):
 
     def chunked(x, w):
         return fused_cross_entropy(x, w, tgt, impl="chunked")
-
-    def pallas(x, w):
-        return fused_cross_entropy(x, w, tgt, impl="pallas")
 
     def grad_chain(loss_fn):
         # Fold loss + dw into the dx output so _timed_op's carry chain
@@ -312,8 +304,6 @@ def ce_ab_phase(out=None):
     out["ce_auto_pin_consistent"] = int(
         (tc / td >= 1.0) == _fce.auto_prefers_dense(n, v)
     )
-    tf = _timed_op(grad_chain(pallas), x, 30, overhead)
-    out["ce_fused_pallas_ms"] = round(tf * 1e3, 2)
     return out
 
 
@@ -1017,26 +1007,6 @@ def decode_phase():
             out[f"decode_vs_roofline{suffix}"] = round(
                 ms_tok / roofline_ms(batch, kv_dtype), 2
             )
-    # A/B: the length-aware Pallas decode attention (opt-in) vs the
-    # default padded-cache XLA path, at the headline batch. The pallas
-    # kernel's sequential (batch, kv_head, block) grid loses here —
-    # the record keeps the evidence behind the XLA default. The env
-    # toggle is restored in a finally (a mid-A/B failure must not leak
-    # pallas into later phases); the impl is part of
-    # _compiled_generate's cache key, so no cache_clear is needed.
-    if time_left() > RESERVE_S + 60:
-        prev = os.environ.get("DLROVER_TPU_DECODE_ATTN")
-        try:
-            os.environ["DLROVER_TPU_DECODE_ATTN"] = "pallas"
-            dec_s = run_once(8)
-            out["decode_ms_per_token_pallas_attn"] = round(
-                dec_s / new * 1e3, 3
-            )
-        finally:
-            if prev is None:
-                os.environ.pop("DLROVER_TPU_DECODE_ATTN", None)
-            else:
-                os.environ["DLROVER_TPU_DECODE_ATTN"] = prev
     return out
 
 

@@ -52,32 +52,22 @@ class DecodeCache(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None
 
 
-def _kv_cache_dtype() -> str:
-    """"fp" (cache in compute_dtype, the default) | "int8"
-    (ops/kv_quant per-(row, head) scales — half the decode KV bytes).
-    DLROVER_TPU_KV_DTYPE picks; typos warn once and fall back to
-    "fp"."""
-    from dlrover_tpu.common.env_utils import resolve_env_choice
-
-    return resolve_env_choice(
-        "DLROVER_TPU_KV_DTYPE", ("fp", "int8"), "fp"
-    )
-
-
 def init_cache(
     config: llama.TpuLMConfig, batch: int, max_len: int,
     kv_dtype: Optional[str] = None,
 ) -> DecodeCache:
+    """``kv_dtype``: "fp" (cache in compute_dtype; what None means) |
+    "int8" (ops/kv_quant per-(row, head) scales — half the decode KV
+    bytes)."""
     if config.pp_stages > 1:
         raise NotImplementedError(
             "decode runs on the flat layer stack; merge pipeline stages "
             "for inference"
         )
-    kv_dtype = kv_dtype or _kv_cache_dtype()
+    kv_dtype = kv_dtype or "fp"
     if kv_dtype not in ("fp", "int8"):
-        # An explicit argument bypasses the env resolver's vocabulary
-        # check; silently building an fp cache would make an intended
-        # int8 A/B measure the wrong path.
+        # Silently building an fp cache would make an intended int8
+        # A/B measure the wrong path.
         raise ValueError(
             f"kv_dtype {kv_dtype!r} not in ('fp', 'int8')"
         )
@@ -112,27 +102,6 @@ def _uniform_cursor(cache_len):
     with a per-row scatter instead."""
     cl = jnp.asarray(cache_len)
     return cl if cl.ndim == 0 else cl[0]
-
-
-def _decode_attn_impl() -> str:
-    """"pallas" | "xla" for the single-token decode step's attention.
-
-    Auto is XLA: the length-aware Pallas kernel
-    (ops/decode_attention.py) reads only the filled cache blocks, but
-    its (batch, kv_head, block) grid runs SEQUENTIALLY on TPU — at the
-    flagship decode shape the serialization costs more than the padded
-    reads it saves (measured v5e b=8: 3.58 vs 1.26 ms/token against
-    the append-free XLA step; the bench A/B keeps both on record). DLROVER_TPU_DECODE_ATTN=pallas opts in
-    (wins would need batch*kv_heads small or caches much longer than
-    the fill). Typos warn once and fall back to auto → xla
-    (env_utils.resolve_env_choice: a silent "palas"→xla would make an
-    intended kernel A/B measure the wrong path)."""
-    from dlrover_tpu.common.env_utils import resolve_env_choice
-
-    raw = resolve_env_choice(
-        "DLROVER_TPU_DECODE_ATTN", ("pallas", "xla", "auto"), "auto"
-    )
-    return "xla" if raw == "auto" else raw
 
 
 def _fuse_decode_params(config, layers):
@@ -185,18 +154,17 @@ def _fused_mlp(config, p, x):
 
 def _layer_decode(
     config, p, x, positions, k_cache, v_cache, cache_len,
-    attn_impl=None, k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None,
 ):
-    """One decoder block over [b, sq] new tokens with cache append.
+    """The PREFILL body (``sq > 1``): one decoder block over [b, sq]
+    new tokens with cache append. A one-token step never comes here:
+    it takes :func:`_layer_decode_read_only`, which rebuilds no cache.
     Returns (x, new_k_cache, new_v_cache) — plus (new_k_scale,
     new_v_scale) when the cache is int8 (``k_scale`` given: the append
-    quantizes per ops/kv_quant; the single-token Pallas path
-    dequantizes in-kernel, the full-cache XLA path materializes the
-    dequantized view — it only serves compute-bound prefill).
-    ``attn_impl`` ("pallas" | "xla") is resolved by the caller; None
-    falls back to the env knob (direct callers / tests). ``cache_len``
-    may be scalar or a UNIFORM [b] vector — the append writes at the
-    shared cursor."""
+    quantizes per ops/kv_quant and the attention materializes the
+    dequantized view — prefill is compute-bound). ``cache_len`` may be
+    scalar or a UNIFORM [b] vector — the append writes at the shared
+    cursor."""
     residual = x
     quantized = k_scale is not None
     if "wqkv" in p:
@@ -229,51 +197,29 @@ def _layer_decode(
         v_cache = jax.lax.dynamic_update_slice(
             v_cache, v.astype(v_cache.dtype), (0, cursor, 0, 0)
         )
-    max_len = k_cache.shape[1]
-    block_k = next(
-        (c for c in (128, 64, 32, 16) if max_len % c == 0), None
-    )
-    if (
-        q.shape[1] == 1
-        and block_k is not None
-        and (attn_impl or _decode_attn_impl()) == "pallas"
-    ):
-        # Single-token step: the length-aware kernel reads only the
-        # filled cache blocks (ops/decode_attention.py); int8 caches
-        # dequantize in-kernel.
-        from dlrover_tpu.ops.decode_attention import decode_attention
+    # Plain attention over the full pre-allocated cache; with
+    # contiguous query positions the causal mask already excludes
+    # every unfilled slot. Rejected for the one-token step, which the
+    # append-free path serves (v5e, b=8, 334M): a Pallas kernel on a
+    # sequential (batch, kv_head, block) grid (3.6 vs 1.3 ms/token)
+    # and lax.switch-bucketed static prefixes (no gain at b>=8, b=1
+    # 0.92 -> 1.39 ms/token).
+    if quantized:
+        from dlrover_tpu.ops.kv_quant import dequantize_kv
 
-        attn = decode_attention(
-            q[:, 0], k_cache, v_cache, cache_len + 1, block_k=block_k,
-            k_scale=k_scale, v_scale=v_scale,
-        )[:, None]
+        cdt = config.compute_dtype
+        k_attn = dequantize_kv(k_cache, k_scale, cdt)
+        v_attn = dequantize_kv(v_cache, v_scale, cdt)
     else:
-        # Plain attention over the full pre-allocated cache; with
-        # contiguous query positions the causal mask already excludes
-        # every unfilled slot. This path now serves PREFILL (sq > 1)
-        # and the opt-in Pallas A/B only — the single-token hot loop
-        # uses the append-free step (_layer_decode_read_only), which
-        # removed the per-token cache rebuild that dominated this
-        # path's profile. Other rejected alternatives (v5e, b=8,
-        # 334M): the sequential-grid Pallas kernel (3.6 vs 1.3
-        # ms/token) and lax.switch-bucketed static prefixes (no gain
-        # at b>=8, b=1 0.92 -> 1.39 ms/token).
-        if quantized:
-            from dlrover_tpu.ops.kv_quant import dequantize_kv
-
-            cdt = config.compute_dtype
-            k_attn = dequantize_kv(k_cache, k_scale, cdt)
-            v_attn = dequantize_kv(v_cache, v_scale, cdt)
-        else:
-            k_attn, v_attn = k_cache, v_cache
-        attn = dot_product_attention(
-            q,
-            k_attn,
-            v_attn,
-            causal=True,
-            q_positions=positions,
-            kv_positions=jnp.arange(max_len),
-        )
+        k_attn, v_attn = k_cache, v_cache
+    attn = dot_product_attention(
+        q,
+        k_attn,
+        v_attn,
+        causal=True,
+        q_positions=positions,
+        kv_positions=jnp.arange(k_cache.shape[1]),
+    )
     x = llama.attention_out(config, p, attn, residual)
     if "w_gu" in p:
         x = _fused_mlp(config, p, x)
@@ -426,30 +372,17 @@ def _layer_verify_read_only(
     return x, k, v
 
 
-def _layer_scan_unroll(n_layers: int) -> int:
-    """Unroll factor for the decode-time layer scan. ROLLED is the
-    measured winner: with the append-free step the rolled scan lets
-    XLA alias the cache append in place (measured v5e, 334M, b=8:
-    1.38 ms/token, zero per-token cache copies in the op profile),
-    while unrolling reintroduces 100-200MB/token of cache copy
-    traffic (1.47-1.74 ms/token) — the unrolled straight-line code
-    defeats the buffer aliasing that the loop structure makes
-    provable. DLROVER_TPU_DECODE_UNROLL overrides for experiments."""
-    import os
-
-    raw = os.environ.get("DLROVER_TPU_DECODE_UNROLL", "")
-    if raw:
-        try:
-            return max(1, min(int(raw), n_layers))
-        except ValueError:
-            pass
-    return 1
+# Unroll factor of the decode-time layer scan: ROLLED. With the
+# append-free step the rolled scan lets XLA alias the cache append in
+# place (measured v5e, 334M, b=8: 1.38 ms/token, zero per-token cache
+# copies in the op profile), while unrolling reintroduces
+# 100-200MB/token of cache copy traffic (1.47-1.74 ms/token) — the
+# unrolled straight-line code defeats the buffer aliasing that the loop
+# structure makes provable.
+_LAYER_SCAN_UNROLL = 1
 
 
-def _forward_with_cache(
-    config, params, tokens, cache: DecodeCache, attn_impl=None,
-    unroll=None,
-):
+def _forward_with_cache(config, params, tokens, cache: DecodeCache):
     """Run [b, sq] tokens through all layers, appending to the cache.
     Returns (logits of the LAST position [b, vocab], new cache).
     Uniform-fill contract: every row of ``cache.length`` holds the same
@@ -460,11 +393,10 @@ def _forward_with_cache(
         None, :
     ]
     x = llama.embed_tokens(config, params, tokens)
-    unroll = unroll or _layer_scan_unroll(config.n_layers)
     quantized = cache.k_scale is not None
     new_ks = new_vs = None
 
-    if sq == 1 and (attn_impl or _decode_attn_impl()) != "pallas":
+    if sq == 1:
         # Append-free single-token step (the decode hot loop): the
         # layer scan READS the cache; each layer returns only its new
         # token's K/V, and one small dynamic-update-slice appends all
@@ -486,7 +418,7 @@ def _forward_with_cache(
                 body1, x,
                 (params["layers"], cache.k, cache.v,
                  cache.k_scale, cache.v_scale),
-                unroll=unroll,
+                unroll=_LAYER_SCAN_UNROLL,
             )
         else:
             def body1(carry, layer_in):
@@ -499,7 +431,7 @@ def _forward_with_cache(
 
             x, (k_news, v_news) = jax.lax.scan(
                 body1, x, (params["layers"], cache.k, cache.v),
-                unroll=unroll,
+                unroll=_LAYER_SCAN_UNROLL,
             )
         cursor = _uniform_cursor(cache.length)
         if quantized:
@@ -533,7 +465,7 @@ def _forward_with_cache(
             pl, k_c, v_c, ks, vs = layer_in
             y, k_c, v_c, ks, vs = _layer_decode(
                 config, pl, carry, positions, k_c, v_c, cache.length,
-                attn_impl=attn_impl, k_scale=ks, v_scale=vs,
+                k_scale=ks, v_scale=vs,
             )
             return y, (k_c, v_c, ks, vs)
 
@@ -541,20 +473,19 @@ def _forward_with_cache(
             body_q, x,
             (params["layers"], cache.k, cache.v,
              cache.k_scale, cache.v_scale),
-            unroll=unroll,
+            unroll=_LAYER_SCAN_UNROLL,
         )
     else:
         def body(carry, layer_in):
             pl, k_c, v_c = layer_in
             y, k_c, v_c = _layer_decode(
-                config, pl, carry, positions, k_c, v_c, cache.length,
-                attn_impl=attn_impl,
+                config, pl, carry, positions, k_c, v_c, cache.length
             )
             return y, (k_c, v_c)
 
         x, (new_k, new_v) = jax.lax.scan(
             body, x, (params["layers"], cache.k, cache.v),
-            unroll=unroll,
+            unroll=_LAYER_SCAN_UNROLL,
         )
     logits = llama.unembed(config, params, x[:, -1:, :])[:, 0, :]
     new_cache = DecodeCache(
@@ -660,29 +591,21 @@ def _compiled_generate(
     batch: int,
     max_new_tokens: int,
     max_len: int,
-    attn_impl: str = "xla",
-    unroll: int = 0,
     kv_dtype: str = "fp",
 ):
-    """One compiled program per (config, shapes, attn_impl, unroll) —
-    repeat generate() calls reuse it (jit caches key on the function
-    object, which must therefore be cached itself). Temperature is a
-    TRACED scalar argument, NOT a cache key: per-request temperatures
-    (a serving workload's normal case) previously forced a full
-    retrace each time the value changed. The decode-attention impl and
-    the layer-scan unroll are EXPLICIT cache-key arguments: generate()
-    resolves their env knobs per call, so toggling them takes effect
-    without cache_clear() (advisor r4)."""
+    """One compiled program per (config, shapes, kv_dtype) — repeat
+    generate() calls reuse it (jit caches key on the function object,
+    which must therefore be cached itself). Temperature is a TRACED
+    scalar argument, NOT a cache key: per-request temperatures (a
+    serving workload's normal case) previously forced a full retrace
+    each time the value changed."""
 
     pick = sample_token
 
     def run(params, prompt, rng, temperature):
         params = prepare_decode_params(config, params)
         cache = init_cache(config, batch, max_len, kv_dtype=kv_dtype)
-        logits, cache = _forward_with_cache(
-            config, params, prompt, cache, attn_impl=attn_impl,
-            unroll=unroll or None,
-        )
+        logits, cache = _forward_with_cache(config, params, prompt, cache)
         rng, first_key = jax.random.split(rng)
         first = pick(logits, first_key, temperature)
 
@@ -690,8 +613,7 @@ def _compiled_generate(
             cache, tok, rng = carry
             rng, sub = jax.random.split(rng)
             logits, cache = _forward_with_cache(
-                config, params, tok[:, None], cache,
-                attn_impl=attn_impl, unroll=unroll or None,
+                config, params, tok[:, None], cache
             )
             nxt = pick(logits, sub, temperature)
             return (cache, nxt, rng), tok
@@ -720,8 +642,8 @@ def generate(
     """Greedy (temperature=0) or sampled decoding. The prefill and the
     whole decode loop are one jit-compiled program with static shapes.
     ``kv_cache_dtype``: "fp" (default) | "int8" — int8 halves the KV
-    bytes every decode step streams (DLROVER_TPU_KV_DTYPE sets the
-    default; the dtype is a compile-cache key, not a retrace)."""
+    bytes every decode step streams (the dtype is a compile-cache key,
+    not a retrace)."""
     if max_new_tokens < 1:
         raise ValueError("max_new_tokens must be >= 1")
     b, prompt_len = prompt.shape
@@ -735,9 +657,7 @@ def generate(
     rng = rng if rng is not None else jax.random.key(0)
     run = _compiled_generate(
         config, b, max_new_tokens, max_len,
-        attn_impl=_decode_attn_impl(),
-        unroll=_layer_scan_unroll(config.n_layers),
-        kv_dtype=kv_cache_dtype or _kv_cache_dtype(),
+        kv_dtype=kv_cache_dtype or "fp",
     )
     # np.float32, not a Python float: a weakly-typed scalar would give
     # the traced argument a different avals key and retrace once.

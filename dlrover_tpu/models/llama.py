@@ -288,23 +288,18 @@ def default_attention_fn():
     """Best attention impl for contiguous-position causal attention on the
     current backend: the Pallas flash kernel (ops/pallas_attention.py) on
     TPU, the XLA reference op elsewhere (``None`` → transformer_layer's
-    ``dot_product_attention`` fallback).
-
-    Override with ``DLROVER_TPU_ATTN=xla|pallas`` (``pallas`` off-TPU runs
-    the kernel in interpret mode — for tests/debugging only).
+    ``dot_product_attention`` fallback). Decided once, by the backend
+    alone; a caller that wants another attention passes
+    ``attention_fn=``.
     """
-    choice = os.environ.get("DLROVER_TPU_ATTN", "auto").lower()
-    if choice not in _ATTN_CACHE:
-        use_pallas = choice == "pallas" or (
-            choice == "auto" and jax.default_backend() == "tpu"
-        )
-        if use_pallas:
+    if "fn" not in _ATTN_CACHE:
+        if jax.default_backend() == "tpu":
             from dlrover_tpu.ops.pallas_attention import make_flash_attention
 
-            _ATTN_CACHE[choice] = make_flash_attention()
+            _ATTN_CACHE["fn"] = make_flash_attention()
         else:
-            _ATTN_CACHE[choice] = None
-    return _ATTN_CACHE[choice]
+            _ATTN_CACHE["fn"] = None
+    return _ATTN_CACHE["fn"]
 
 
 def attention_qkv(config: TpuLMConfig, p, x, positions):
@@ -512,24 +507,10 @@ def _attn_block_lite_bwd(config, res, g):
     (q, k, v), qkv_vjp = jax.vjp(
         lambda p_, x_: attention_qkv(config, p_, x_, positions), p, x
     )
-    if os.environ.get(
-        "DLROVER_TPU_FLASH_BWD", "pallas"
-    ).lower() == "xla":
-        # Same debug fallback as pallas_attention._bwd: rebuild the
-        # attention grads through the XLA reference op so the knob
-        # keeps working under the lite path too.
-        _, attn_vjp = jax.vjp(
-            lambda q_, k_, v_: dot_product_attention(
-                q_, k_, v_, causal=True
-            ),
-            q, k, v,
-        )
-        dq, dk, dv = attn_vjp(g)
-    else:
-        interpret = jax.default_backend() != "tpu"
-        dq, dk, dv = flash_backward(
-            q, k, v, out, lse_c, g, True, None, interpret
-        )
+    interpret = jax.default_backend() != "tpu"
+    dq, dk, dv = flash_backward(
+        q, k, v, out, lse_c, g, True, None, interpret
+    )
     dp, dx = qkv_vjp((dq, dk, dv))
     dpos = np.zeros(positions.shape, jax.dtypes.float0)
     return dp, dx, dpos

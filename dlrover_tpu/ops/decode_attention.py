@@ -1,45 +1,39 @@
-"""Single-query (decode-step) attention over a KV cache, Pallas.
+"""Attention over a KV cache that is READ, not rewritten: what serving
+runs over its paged pool, and the reference for what it runs next.
 
-The generate loop's per-step attention previously ran the plain XLA
-``dot_product_attention`` over the FULL pre-allocated cache — every
-step reads ``max_len`` KV rows even when only ``length`` are filled,
-and the masked softmax touches the padding too. Decode is HBM-bound,
-so those wasted reads are wasted milliseconds.
+Three functions, and the predicates that say where the two kernels
+lower:
 
-This kernel is length-aware PER ROW: the fill lengths ride as a [b]
-scalar-prefetch vector (a scalar is broadcast, so uniform-fill callers
-are unchanged), the KV block index map CLAMPS past-the-end blocks to
-the row's own last valid block (Mosaic skips the HBM copy when a block
-index repeats), and ``pl.when`` skips their compute. Per (batch,
-kv-head) grid cell the query group (GQA: n_heads // n_kv_heads rows,
-padded to the 8-sublane minimum) runs an online-softmax sweep over KV
-blocks — flash attention with a 1-token query.
-
-Ragged fills are where the kernel earns its keep: the continuous-
-batching serving engine (serving/engine.py) holds slots at wildly
-different fill lengths, and a padded whole-cache XLA read wastes HBM
-bandwidth proportional to the raggedness, while this grid clamps each
-slot to its own fill.
-
-Parity note: the reference delegates decode to vLLM/torch kernels
-(paged attention). Three kernels live here:
-
-- :func:`decode_attention`, for this repo's single-slab cache. Its
-  ``(batch, kv_head, block)`` grid runs sequentially on a TPU and lost
-  to the append-free XLA step at 334 M parameters and a <= 384-row
-  cache (3.675 vs 1.35 ms/token, BENCH_r05), where KV is no part of
-  the bytes; opt-in by ``DLROVER_TPU_DECODE_ATTN``.
-- :func:`paged_decode_attention`, the same grid over ONE layer's pool
-  with the block table as a second scalar-prefetch operand. At a real
-  serving shape (16 slots x 144 pages x 8 KV heads) that grid is
-  18,432 steps of 4 KB a layer, and fed ``k[layer]`` it would keep
-  the copy of the layer's pool: called by parity tests only.
-- :func:`pool_decode_attention`, what ``PagedServingEngine``'s decode
-  program runs on a TPU: the STACKED pool read in place, one call a
+- :func:`pool_decode_attention` (Pallas; :func:`pool_kernel_supported`),
+  the attention of ``PagedServingEngine``'s decode program on a TPU: one
+  query token a slot against the STACKED pool read in place, one call a
   layer, a page of all KV heads per DMA, only each decoding slot's
-  filled pages, chunks of pages double-buffered across slots. The
-  trace that ROADMAP S4 waited for is PERF.md §5 (PR 25): the XLA
-  gather moved the cache at full capacity four times a layer.
+  filled pages, chunks of pages double-buffered across slots. The XLA
+  gather it replaced moved the cache at full capacity four times a
+  layer (PERF.md §5, PR 25).
+- :func:`pool_chunk_attention` (Pallas;
+  :func:`chunk_kernel_supported`), the attention of its prefill
+  program: one slot's chunk of T tokens against the rows below the
+  chunk, read from the same pool in place, then the chunk's own K/V
+  causally, in one online softmax (PERF.md §5, PR 28).
+- :func:`spec_verify_attention` (plain XLA), T query tokens a row
+  against a read-only cache: the speculative verify step's math, and
+  the reference for a T-query pool kernel over several slots
+  (ROADMAP S4(c)).
+
+``serving/kvpool/engine.pool_attention_kind`` picks between the two
+kernels and the engine's XLA gather from what it can see (platform,
+pool dtype, page and chunk shapes); nothing here reads the environment.
+
+A one-token step over a SLAB cache (``generate()``, the flat
+``ServingEngine``) has no kernel here: it runs
+``models/generate._append_free_attention``, plain XLA. The Pallas
+kernel this module once held for it — a sequential ``(batch, kv_head,
+block)`` grid, also over one layer's pool through a block table — took
+3.58-3.675 ms/token where the append-free step takes 1.26-1.35 (v5e,
+334 M parameters, <= 384-row cache, BENCH_r05), and over a layer's
+pool it would have kept the 2.44 ms per-layer pool slice that PR 25
+removed: deleted in PR 29, not to be rebuilt on that grid.
 """
 
 import functools
@@ -159,337 +153,6 @@ def spec_verify_attention(
     return out.transpose(0, 3, 1, 2, 4).reshape(b, T, h, d).astype(
         q.dtype
     )
-
-
-def _decode_body(
-    len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-    o_ref, m_ref, l_ref, acc_ref, *, block_k: int, scale: float,
-):
-    """Online-softmax sweep shared by the fp and int8 kernels. With
-    scale refs present the KV blocks are int8 and dequantization is
-    folded into the math IN-KERNEL: the per-(row, head) K scales
-    multiply the raw q·k logits and the V scales fold into the
-    probability rows before the p·v matmul — the dequantized cache is
-    never materialized, and HBM moves half the bytes. Scale blocks
-    carry ALL kv heads ([1, bk, kh] — a full minor dim, which Mosaic
-    pads, unlike a 1-wide lane slice it could reject) and the kernel
-    selects its own head's column by the grid index."""
-    ib = pl.program_id(0)
-    ih = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[ib]
-    base = j * block_k
-
-    @pl.when(base < length)
-    def _():
-        q = q_ref[0, 0]                                 # [gp, d]
-        k = k_ref[0]                                    # [bk, d]
-        v = v_ref[0]
-        if ks_ref is not None:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-            q = q.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [gp, bk]
-        if ks_ref is not None:
-            # Dequantized logits: s_true = (q · k_q) * scale * k_scale
-            ks = jax.lax.dynamic_slice_in_dim(
-                ks_ref[0], ih, 1, axis=1
-            )[:, 0]
-            s = s * ks[None, :]
-        cols = base + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(cols < length, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(p, -1, keepdims=True),
-            l_ref.shape,
-        )
-        # V dequant folds into the probability rows (l above keeps the
-        # UNSCALED p — it is the softmax denominator).
-        if vs_ref is None:
-            pv = p
-        else:
-            vs = jax.lax.dynamic_slice_in_dim(
-                vs_ref[0], ih, 1, axis=1
-            )[:, 0]
-            pv = p * vs[None, :]
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nj - 1)
-    def _():
-        o_ref[0, 0] = (
-            acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _kernel(
-    len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, block_k: int, scale: float,
-):
-    _decode_body(
-        len_ref, q_ref, k_ref, v_ref, None, None,
-        o_ref, m_ref, l_ref, acc_ref, block_k=block_k, scale=scale,
-    )
-
-
-def _kernel_q8(
-    len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-    o_ref, m_ref, l_ref, acc_ref, *, block_k: int, scale: float,
-):
-    _decode_body(
-        len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-        o_ref, m_ref, l_ref, acc_ref, block_k=block_k, scale=scale,
-    )
-
-
-def _paged_kernel(
-    len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, block_k: int, scale: float,
-):
-    # The block table is consumed entirely by the kv index maps; the
-    # compute body is the flat kernel's online-softmax sweep unchanged.
-    del bt_ref
-    _decode_body(
-        len_ref, q_ref, k_ref, v_ref, None, None,
-        o_ref, m_ref, l_ref, acc_ref, block_k=block_k, scale=scale,
-    )
-
-
-def _paged_kernel_q8(
-    len_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-    o_ref, m_ref, l_ref, acc_ref, *, block_k: int, scale: float,
-):
-    del bt_ref
-    _decode_body(
-        len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-        o_ref, m_ref, l_ref, acc_ref, block_k=block_k, scale=scale,
-    )
-
-
-def paged_decode_attention(
-    q,             # [b, n_heads, d] — ONE query token per sequence
-    k_pool,        # [num_blocks, block_size, kv_heads, d]
-    v_pool,
-    block_tables,  # [b, max_blocks] int32 — pool rows per sequence
-    length,        # [b] int32 — filled LOGICAL rows per sequence
-    interpret=None,
-    k_scale=None,  # [num_blocks, block_size, kv_heads] f32 — int8 pools
-    v_scale=None,
-):
-    """Single-query attention straight through a block table.
-
-    The paged generalization of :func:`decode_attention`: the KV pool
-    is block-granular (``[num_blocks, block_size, kh, d]``) and each
-    sequence's logical cache is the concatenation of the pool rows its
-    ``block_tables`` row names. Both the fill vector AND the tables
-    ride as scalar-prefetch operands, so the kv index map dereferences
-    the table on the host side of the DMA: grid step ``j`` of row
-    ``ib`` copies pool block ``block_tables[ib, j]``, clamped past the
-    fill to the row's last valid table entry (repeat index = skipped
-    copy, the same Mosaic trick as the flat kernel). Visibility is the
-    engine invariant — a logical row is read iff ``< length[ib]`` —
-    so stale ids beyond the fill in a table row are never dereferenced
-    into the softmax. With ``k_scale``/``v_scale`` the pools are int8
-    (ops/kv_quant per-(row, head) scheme) and dequantization happens
-    in-kernel — half the KV bytes per step. Returns
-    ``[b, n_heads, d]``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, h, d = q.shape
-    nb_pool, block_size, kh, _ = k_pool.shape
-    _, max_blocks = block_tables.shape
-    if h % kh:
-        raise ValueError(f"n_heads {h} not divisible by kv_heads {kh}")
-    g = h // kh
-    gp = max(g, 8)  # sublane minimum
-    scale = d ** -0.5
-    qg = q.reshape(b, kh, g, d)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    length = jnp.broadcast_to(
-        jnp.asarray(length, jnp.int32).reshape(-1), (b,)
-    )
-    tables = jnp.asarray(block_tables, jnp.int32)
-
-    def kv_index(ib, ih, j, len_ref, bt_ref):
-        # Clamp to the row's last FILLED logical block, then map the
-        # logical block through the row's table to a pool row.
-        last = jnp.maximum((len_ref[ib] - 1) // block_size, 0)
-        return (bt_ref[ib, jnp.minimum(j, last)], 0, ih)
-
-    kf = k_pool.reshape(nb_pool, block_size, kh * d)
-    vf = v_pool.reshape(nb_pool, block_size, kh * d)
-
-    quantized = k_scale is not None
-    kernel = _paged_kernel_q8 if quantized else _paged_kernel
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, gp, d),
-            lambda ib, ih, j, ln, bt: (ib, ih, 0, 0),
-        ),
-        pl.BlockSpec((1, block_size, d), kv_index),
-        pl.BlockSpec((1, block_size, d), kv_index),
-    ]
-    operands = [length, tables, qg, kf, vf]
-    if quantized:
-        # Per-(row, head) scale blocks ride the SAME table-deref row
-        # clamp as their KV blocks but carry ALL kh heads (full minor
-        # dim — Mosaic pads it; the kernel picks its head's column).
-        def scale_index(ib, ih, j, len_ref, bt_ref):
-            last = jnp.maximum((len_ref[ib] - 1) // block_size, 0)
-            return (bt_ref[ib, jnp.minimum(j, last)], 0, 0)
-
-        in_specs += [
-            pl.BlockSpec((1, block_size, kh), scale_index),
-            pl.BlockSpec((1, block_size, kh), scale_index),
-        ]
-        operands += [
-            jnp.asarray(k_scale, jnp.float32),
-            jnp.asarray(v_scale, jnp.float32),
-        ]
-
-    out = pl.pallas_call(
-        functools.partial(kernel, block_k=block_size, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, kh, max_blocks),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, gp, d),
-                lambda ib, ih, j, ln, bt: (ib, ih, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, kh, gp, d), q.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:, :, :g, :].reshape(b, h, d)
-
-
-def decode_attention(
-    q,            # [b, n_heads, d] — ONE query token per sequence
-    k_cache,      # [b, max_len, kv_heads, d]
-    v_cache,
-    length,       # [] or [b] int32 — filled cache rows per sequence
-    block_k: int = 128,
-    interpret=None,
-    k_scale=None,  # [b, max_len, kv_heads] f32 — int8 caches only
-    v_scale=None,
-):
-    """Length-masked single-query attention; returns [b, n_heads, d].
-
-    ``length`` may be a scalar (uniform fill — every row clamps to the
-    same block range, the original generate() contract) or a [b] vector
-    of per-row fills (ragged slots — the serving engine's case, where
-    each (batch, kv-head) grid cell reads only its own row's filled
-    blocks). Rows with length 0 produce zero output. With
-    ``k_scale``/``v_scale`` the caches are int8 (ops/kv_quant) and the
-    kernel dequantizes in-kernel — the HBM stream the decode roofline
-    is judged against halves."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, h, d = q.shape
-    _, max_len, kh, _ = k_cache.shape
-    if h % kh:
-        raise ValueError(f"n_heads {h} not divisible by kv_heads {kh}")
-    g = h // kh
-    gp = max(g, 8)  # sublane minimum
-    scale = d ** -0.5
-    # [b, kh, gp, d] query groups, zero-padded rows.
-    qg = q.reshape(b, kh, g, d)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    length = jnp.broadcast_to(
-        jnp.asarray(length, jnp.int32).reshape(-1), (b,)
-    )
-    nj = max_len // block_k
-    if max_len % block_k:
-        raise ValueError(
-            f"max_len {max_len} not a multiple of block_k {block_k}"
-        )
-
-    def kv_index(ib, ih, j, len_ref):
-        # Clamp past-the-fill blocks to THIS ROW's last valid one:
-        # Mosaic skips the HBM copy when the index repeats, so unfilled
-        # cache rows are never read — per sequence, not per batch.
-        last = jnp.maximum((len_ref[ib] - 1) // block_k, 0)
-        return (ib, jnp.minimum(j, last), ih)
-
-    # Mosaic wants the trailing two block dims (8, 128)-divisible: view
-    # the cache [b, L, kh, d] as [b, L, kh*d] (free — contiguous) and
-    # block the lane dim per kv head.
-    kf = k_cache.reshape(b, max_len, kh * d)
-    vf = v_cache.reshape(b, max_len, kh * d)
-
-    quantized = k_scale is not None
-    kernel = _kernel_q8 if quantized else _kernel
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, gp, d), lambda ib, ih, j, s: (ib, ih, 0, 0)
-        ),
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-    ]
-    operands = [length, qg, kf, vf]
-    if quantized:
-        # Full-kh scale blocks (see paged variant for the Mosaic
-        # minor-dim rationale); same per-row fill clamp as K/V.
-        def scale_index(ib, ih, j, len_ref):
-            last = jnp.maximum((len_ref[ib] - 1) // block_k, 0)
-            return (ib, jnp.minimum(j, last), 0)
-
-        in_specs += [
-            pl.BlockSpec((1, block_k, kh), scale_index),
-            pl.BlockSpec((1, block_k, kh), scale_index),
-        ]
-        operands += [
-            jnp.asarray(k_scale, jnp.float32),
-            jnp.asarray(v_scale, jnp.float32),
-        ]
-
-    out = pl.pallas_call(
-        functools.partial(kernel, block_k=block_k, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, kh, nj),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, gp, d), lambda ib, ih, j, s: (ib, ih, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, kh, gp, d), q.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:, :, :g, :].reshape(b, h, d)
 
 
 # ---- paged decode attention over the STACKED pool, in place ---------------

@@ -5,8 +5,9 @@ f32 logits — at the flagship bench shape that is a 2 GB HBM round-trip
 per pass (forward write, logsumexp read, softmax write/read in the
 backward, plus the 2 GB value_and_grad residual). This op computes the
 identical token-mean ``nll + z_weight * logz^2`` loss by streaming the
-vocab in blocks with an online logsumexp, so only [block_n, block_v]
-tiles ever exist:
+vocab or the rows in blocks, so only a [block_rows, V] or an
+[N, block_v] tile ever exists. Two paths, chosen by the mesh
+(``resolve_impl``), both plain XLA:
 
 - **Chunked path** (default): ``lax.scan`` over ROW chunks with exact
   per-chunk softmax, computing loss AND unit-cotangent gradients in the
@@ -15,21 +16,17 @@ tiles ever exist:
   the dense path's three (logits, dx, dw): no flash-style recompute.
   Peak memory is one [block_rows, V] f32 logits tile plus the [d, V]
   f32 dw accumulator — residuals are (dx_unit, dw_unit), both small.
-- **Pallas path** (opt-in): forward kernel with grid (n_tiles, v_tiles),
-  v innermost; running (m, l, target_logit) live in VMEM scratch across
-  v iterations (same sequential-grid trick as ops/pallas_attention.py).
-  Backward recomputes the logits tile from (x, w, logz) flash-style and
-  runs two kernels — one accumulating dx over v blocks, one accumulating
-  dw over n blocks. Strictly lowest memory (no [block, V] tile in HBM),
-  but pays 5 logits-sized matmuls vs the chunked path's 3 — measured
-  slower on v5e; kept for the truly HBM-starved corner.
 - **XLA path** (sharded meshes): the same math as a ``lax.scan`` over
-  vocab blocks, keeping the [N, d] activations un-rechunked so GSPMD
-  sharding over batch/seq axes passes through untouched.
+  vocab blocks with an online logsumexp, keeping the [N, d]
+  activations un-rechunked so GSPMD sharding over batch/seq axes
+  passes through untouched. Its custom VJP keeps residuals to (x, w,
+  targets, weights, logz) — logz is [N], everything else is an input —
+  and recomputes the logits tile in the backward.
 
-Per-row integers/stats ride lane-broadcast [N, LANES] like the attention
-kernel's lse. Custom VJP keeps residuals to (x, w, targets, weights,
-logz) — logz is [N], everything else is an input.
+A Pallas version of the vocab scan (forward kernel, dx and dw kernels
+recomputing the logits tile flash-style) lost on the chip: 30.61 ms
+against the dense loss's 20.56 at the flagship head shape (v5e, r05) —
+5 logits-sized matmuls where the chunked path pays 3. Deleted in PR 29.
 
 Parity note: the reference has no loss kernels at all (torch frameworks
 own the compute path, SURVEY.md §2.9); this is the TPU-native analogue
@@ -42,11 +39,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-LANES = 128
 
 # Measured dense/fused crossover in N*V elements (f32-logits bytes / 4).
 # Evidence trail (the §33 kernel campaign re-measured after the MoE /
@@ -82,15 +76,8 @@ def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _pick_bn(n: int, target: int) -> int:
-    for cand in (target, 512, 256, 128, 64, 32, 16, 8):
-        if cand <= n and n % cand == 0:
-            return cand
-    return n
-
-
 # ---------------------------------------------------------------------------
-# XLA (lax.scan) implementation — CPU fallback and sharded-mesh path
+# XLA (lax.scan over vocab blocks) implementation — the sharded-mesh path
 # ---------------------------------------------------------------------------
 
 
@@ -303,279 +290,27 @@ _chunked_ce_core.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels
-# ---------------------------------------------------------------------------
-
-
-def _fwd_kernel(
-    x_ref, w_ref, tgt_ref, ptok_ref, logz_ref, m_ref, l_ref, tl_ref,
-    *, v: int, block_v: int, z_weight: float,
-):
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        tl_ref[:] = jnp.zeros_like(tl_ref)
-
-    x = x_ref[...]
-    w = w_ref[...]
-    tgt = tgt_ref[...][:, :1]                       # [bn, 1] int32
-    bn = x.shape[0]
-    logits = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [bn, block_v]
-    cols = j * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (bn, block_v), 1
-    )
-    logits = jnp.where(cols < v, logits, NEG_INF)
-
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_blk = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_blk)
-    p_sum = jnp.sum(jnp.exp(logits - m_new), axis=-1, keepdims=True)
-    l_new = l_prev * jnp.exp(m_prev - m_new) + p_sum
-    tl_new = tl_ref[:, :1] + jnp.sum(
-        jnp.where(cols == tgt, logits, 0.0), axis=-1, keepdims=True
-    )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-    tl_ref[:] = jnp.broadcast_to(tl_new, tl_ref.shape)
-
-    @pl.when(j == nj - 1)
-    def _():
-        logz = m_new + jnp.log(jnp.maximum(l_new, 1e-30))
-        per_tok = logz - tl_new + z_weight * jnp.square(logz)
-        logz_ref[...] = jnp.broadcast_to(logz, logz_ref.shape)
-        ptok_ref[...] = jnp.broadcast_to(per_tok, ptok_ref.shape)
-
-
-def _bwd_dx_kernel(
-    x_ref, w_ref, tgt_ref, logz_ref, a_ref, b_ref, dx_ref, acc_ref,
-    *, v: int, block_v: int,
-):
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]
-    w = w_ref[...]
-    tgt = tgt_ref[...][:, :1]
-    logz = logz_ref[...][:, :1]
-    a = a_ref[...][:, :1]
-    b = b_ref[...][:, :1]
-    bn = x.shape[0]
-    logits = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    cols = j * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (bn, block_v), 1
-    )
-    logits = jnp.where(cols < v, logits, NEG_INF)
-    p = jnp.exp(logits - logz)
-    g = (a * p - jnp.where(cols == tgt, b, 0.0)).astype(x.dtype)
-    acc_ref[:] += jax.lax.dot_general(
-        g, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(j == nj - 1)
-    def _():
-        dx_ref[...] = acc_ref[:].astype(dx_ref.dtype)
-
-
-def _bwd_dw_kernel(
-    x_ref, w_ref, tgt_ref, logz_ref, a_ref, b_ref, dw_ref, acc_ref,
-    *, v: int, block_v: int,
-):
-    i = pl.program_id(1)
-    ni = pl.num_programs(1)
-    j = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]
-    w = w_ref[...]
-    tgt = tgt_ref[...][:, :1]
-    logz = logz_ref[...][:, :1]
-    a = a_ref[...][:, :1]
-    b = b_ref[...][:, :1]
-    bn = x.shape[0]
-    logits = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    cols = j * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (bn, block_v), 1
-    )
-    logits = jnp.where(cols < v, logits, NEG_INF)
-    p = jnp.exp(logits - logz)
-    g = (a * p - jnp.where(cols == tgt, b, 0.0)).astype(x.dtype)
-    acc_ref[:] += jax.lax.dot_general(
-        x, g, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(i == ni - 1)
-    def _():
-        dw_ref[...] = acc_ref[:].astype(dw_ref.dtype)
-
-
-def _lane(arr, dtype):
-    """[n] -> lane-broadcast [n, LANES] (the stats layout)."""
-    return jnp.broadcast_to(arr.astype(dtype)[:, None],
-                            (arr.shape[0], LANES))
-
-
-def _pallas_forward(x, w, tgt, z_weight, block_n, block_v, interpret):
-    n, d = x.shape
-    v = w.shape[1]
-    vp = _ceil_to(v, block_v)
-    bn = _pick_bn(n, block_n)
-    wp = jnp.pad(w, ((0, 0), (0, vp - v))).astype(x.dtype)
-    grid = (n // bn, vp // block_v)
-
-    ptok, logz = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, v=v, block_v=block_v, z_weight=z_weight
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((n, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n, LANES), jnp.float32),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, block_v), lambda i, j: (0, j)),
-            pl.BlockSpec((bn, LANES), lambda i, j: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bn, LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, LANES), lambda i, j: (i, 0)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bn, LANES), jnp.float32),
-            pltpu.VMEM((bn, LANES), jnp.float32),
-            pltpu.VMEM((bn, LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, wp, _lane(tgt, jnp.int32))
-    return ptok[:, 0], logz[:, 0]
-
-
-def _pallas_backward(
-    x, w, tgt, logz, coef_a, coef_b, block_n, block_v, interpret
-):
-    n, d = x.shape
-    v = w.shape[1]
-    vp = _ceil_to(v, block_v)
-    bn = _pick_bn(n, block_n)
-    wp = jnp.pad(w, ((0, 0), (0, vp - v))).astype(x.dtype)
-    tgt_l = _lane(tgt, jnp.int32)
-    logz_l = _lane(logz, jnp.float32)
-    a_l = _lane(coef_a, jnp.float32)
-    b_l = _lane(coef_b, jnp.float32)
-
-    # Mosaic's scoped-VMEM budget tightens slightly at very large row
-    # counts (measured: the 1024-wide vocab block fits at n<=32k and
-    # overflows by ~170KB at n=64k) — halve the block there.
-    bv_dx = block_v if n <= 32768 else min(block_v, 512)
-    vp_dx = _ceil_to(v, bv_dx)
-    wp_dx = wp[:, :vp_dx] if vp_dx <= wp.shape[1] else jnp.pad(
-        w, ((0, 0), (0, vp_dx - v))
-    ).astype(x.dtype)
-    stat = pl.BlockSpec((bn, LANES), lambda i, j: (i, 0))
-    dx = pl.pallas_call(
-        functools.partial(_bwd_dx_kernel, v=v, block_v=bv_dx),
-        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        grid=(n // bn, vp_dx // bv_dx),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, bv_dx), lambda i, j: (0, j)),
-            stat, stat, stat, stat,
-        ],
-        out_specs=pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
-        interpret=interpret,
-    )(x, wp_dx, tgt_l, logz_l, a_l, b_l)
-
-    # The dw kernel holds a [d, block_v] f32 accumulator on top of the
-    # streamed tiles — at d=1024, block_v=1024 that exceeds the 16 MB
-    # scoped-VMEM budget (measured on v5e), so it runs at half the vocab
-    # block. Re-pad for its own block size.
-    bv_dw = min(block_v, 512)
-    vp_dw = _ceil_to(v, bv_dw)
-    wp_dw = wp[:, :vp_dw] if vp_dw <= vp else jnp.pad(
-        w, ((0, 0), (0, vp_dw - v))
-    ).astype(x.dtype)
-    stat2 = pl.BlockSpec((bn, LANES), lambda j, i: (i, 0))
-    dw = pl.pallas_call(
-        functools.partial(_bwd_dw_kernel, v=v, block_v=bv_dw),
-        out_shape=jax.ShapeDtypeStruct((d, vp_dw), jnp.float32),
-        grid=(vp_dw // bv_dw, n // bn),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((d, bv_dw), lambda j, i: (0, j)),
-            stat2, stat2, stat2, stat2,
-        ],
-        out_specs=pl.BlockSpec((d, bv_dw), lambda j, i: (0, j)),
-        scratch_shapes=[pltpu.VMEM((d, bv_dw), jnp.float32)],
-        interpret=interpret,
-    )(x, wp_dw, tgt_l, logz_l, a_l, b_l)
-    return dx, dw[:, :v]
-
-
-# ---------------------------------------------------------------------------
 # Custom-VJP core and public op
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_ce_core(x, w, tgt, wgt, z_weight, block_n, block_v, use_pallas):
-    per_tok, _ = (
-        _pallas_forward(x, w, tgt, z_weight, block_n, block_v,
-                        interpret=jax.default_backend() != "tpu")
-        if use_pallas
-        else _xla_forward(x, w, tgt, z_weight, block_v)
-    )
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused_ce_core(x, w, tgt, wgt, z_weight, block_v):
+    per_tok, _ = _xla_forward(x, w, tgt, z_weight, block_v)
     return jnp.sum(per_tok * wgt)
 
 
-def _core_fwd(x, w, tgt, wgt, z_weight, block_n, block_v, use_pallas):
-    if use_pallas:
-        per_tok, logz = _pallas_forward(
-            x, w, tgt, z_weight, block_n, block_v,
-            interpret=jax.default_backend() != "tpu",
-        )
-    else:
-        per_tok, logz = _xla_forward(x, w, tgt, z_weight, block_v)
+def _core_fwd(x, w, tgt, wgt, z_weight, block_v):
+    per_tok, logz = _xla_forward(x, w, tgt, z_weight, block_v)
     return jnp.sum(per_tok * wgt), (x, w, tgt, wgt, logz)
 
 
-def _core_bwd(z_weight, block_n, block_v, use_pallas, res, gbar):
+def _core_bwd(z_weight, block_v, res, gbar):
     x, w, tgt, wgt, logz = res
     scaled = gbar * wgt                                   # [n] f32
     coef_a = scaled * (1.0 + 2.0 * z_weight * logz)
     coef_b = scaled
-    if use_pallas:
-        dx, dw = _pallas_backward(
-            x, w, tgt, logz, coef_a, coef_b, block_n, block_v,
-            interpret=jax.default_backend() != "tpu",
-        )
-    else:
-        dx, dw = _xla_backward(
-            x, w, tgt, logz, coef_a, coef_b, block_v
-        )
+    dx, dw = _xla_backward(x, w, tgt, logz, coef_a, coef_b, block_v)
     return (
         dx.astype(x.dtype),
         dw.astype(w.dtype),
@@ -616,7 +351,6 @@ def fused_cross_entropy(
     targets,
     mask=None,
     z_weight: float = 1e-4,
-    block_n: int = 512,
     block_v: int = 1024,
     block_rows: Optional[int] = None,
     impl: Optional[str] = None,
@@ -629,14 +363,15 @@ def fused_cross_entropy(
     targets int [...]; mask optional [...] — tokens with mask 0 contribute
     nothing.
 
-    impl: "chunked" | "pallas" | "xla" | None. Auto picks "chunked"
-    (dense-speed, O(block_rows*V) memory) except under a multi-device
-    mesh, where the vocab-scan "xla" path keeps GSPMD shardings intact
-    (``resolve_impl`` is the selection, shared with the driver dryrun's
-    per-mesh CE logging).
+    impl: "chunked" | "xla" | None. Auto picks "chunked" (dense-speed,
+    O(block_rows*V) memory) except under a multi-device mesh, where the
+    vocab-scan "xla" path keeps GSPMD shardings intact (``resolve_impl``
+    is the selection, shared with the driver dryrun's per-mesh CE
+    logging). Anything else raises ``ValueError``.
     """
-    if impl is None:
-        impl = resolve_impl()
+    impl = resolve_impl(impl)
+    if impl not in ("chunked", "xla"):
+        raise ValueError(f"impl {impl!r} not in ('chunked', 'xla')")
     d = x.shape[-1]
     n = int(np.prod(x.shape[:-1]))
     x2 = x.reshape(n, d)
@@ -660,6 +395,4 @@ def fused_cross_entropy(
         wgt = jnp.pad(wgt, (0, n_pad - n))
     if impl == "chunked":
         return _chunked_ce_core(x2, w, tgt, wgt, z_weight, chunk)
-    return _fused_ce_core(
-        x2, w, tgt, wgt, z_weight, block_n, block_v, impl == "pallas"
-    )
+    return _fused_ce_core(x2, w, tgt, wgt, z_weight, block_v)

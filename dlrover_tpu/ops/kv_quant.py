@@ -14,8 +14,9 @@ updated, so the scale is computed at append time and immutable after;
 d=128 int8 values + one f32 scale = 132 bytes/head/row vs 256 for
 bf16 (1.94×). Dequantization happens at the READ site — folded into
 the attention math (scales applied to logits / probabilities, never
-materializing a dequantized cache) in the XLA append-free step, and
-in-kernel in the Pallas decode kernels (ops/decode_attention.py).
+materializing a dequantized cache) in the XLA append-free step
+(models/generate._append_free_attention), which every int8 decode
+program runs.
 
 The quantizer is round-to-nearest (deterministic — the cache must be
 bit-stable across replays); clipping is impossible by construction

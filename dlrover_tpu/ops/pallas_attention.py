@@ -26,7 +26,6 @@ its elastic machinery would supervise.
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -35,7 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from dlrover_tpu.ops.attention import NEG_INF, dot_product_attention
+from dlrover_tpu.ops.attention import NEG_INF
 from dlrover_tpu.parallel.sharding import current_mesh, logical_to_spec
 
 LANES = 128  # lane-broadcast width for per-row stats (lse, delta)
@@ -638,15 +637,6 @@ def _bwd(causal, softmax_scale, interpret, res, g):
     q, k, v, out, lse_c = res
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if os.environ.get("DLROVER_TPU_FLASH_BWD", "pallas").lower() == "xla":
-        # Debug fallback: rebuild grads through the XLA reference op.
-        _, vjp = jax.vjp(
-            lambda q, k, v: dot_product_attention(
-                q, k, v, causal=causal, softmax_scale=softmax_scale
-            ),
-            q, k, v,
-        )
-        return vjp(g)
     return flash_backward(
         q, k, v, out, lse_c, g, causal, softmax_scale, interpret
     )

@@ -184,8 +184,7 @@ def _build_prefill_chunk(config, slots: int, max_len: int, chunk: int,
         def body(carry, layer_in):
             pl, k_c, v_c = layer_in
             y, k_c, v_c = gen_lib._layer_decode(
-                config, pl, carry, positions, k_c, v_c, start,
-                attn_impl="xla",
+                config, pl, carry, positions, k_c, v_c, start
             )
             return y, (k_c, v_c)
 

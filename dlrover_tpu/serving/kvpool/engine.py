@@ -327,8 +327,7 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
         def body(carry, layer_in):
             pl, k_c, v_c = layer_in
             y, k_c, v_c = gen_lib._layer_decode(
-                config, pl, carry, positions, k_c, v_c, start,
-                attn_impl="xla",
+                config, pl, carry, positions, k_c, v_c, start
             )
             return y, (k_c, v_c)
 

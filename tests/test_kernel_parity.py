@@ -11,8 +11,8 @@ Four surfaces:
   tolerance vs fp, token-exact greedy on the pinned bench prompts,
   and the fused gumbel-max sampler's equivalence to the categorical
   + argmax + select it collapsed;
-- paged int8 decode-attention kernel vs the flat int8 kernel through
-  a shuffled pool;
+- the int8 paged decode program vs the flat int8 slab its pool was
+  shuffled from;
 - zero retraces across admissions with the quantized paged cache.
 """
 
@@ -317,62 +317,107 @@ def test_fused_sampler_matches_categorical_reference():
 
 
 # ---------------------------------------------------------------------------
-# Paged int8 kernel parity
+# Paged int8 decode program vs the flat int8 slab
 # ---------------------------------------------------------------------------
 
 
-def test_paged_int8_kernel_parity_vs_flat():
-    """paged_decode_attention over an int8 pool through a SHUFFLED
-    block table == the flat int8 kernel == the dequantized fp kernel,
-    at ragged fills."""
-    from dlrover_tpu.ops.decode_attention import (
-        decode_attention,
-        paged_decode_attention,
-    )
-    from dlrover_tpu.ops.kv_quant import dequantize_kv, quantize_kv
+def test_paged_int8_decode_step_parity_vs_flat(monkeypatch):
+    """What an int8 ``PagedServingEngine`` runs a decode step with —
+    ``_build_paged_decode(quantized=True)``: per layer the int8 pool
+    and its scale pool gathered through the block tables, dequant
+    folded into ``_append_free_attention`` — against
+    ``_layer_decode_read_only(k_scale=, v_scale=)`` on the FLAT int8
+    slab the tables were shuffled from, at ragged fills. Two layers,
+    so the second layer's appended rows carry the first's attention.
+    Both stay within the pinned int8 decode tolerance of the same step
+    over the unquantized slab."""
+    from dlrover_tpu.models import generate as gen_lib
+    from dlrover_tpu.ops.kv_quant import quantize_kv
+    from dlrover_tpu.serving.kvpool.engine import _build_paged_decode
 
-    b, h, kh, d, L, bs = 4, 8, 4, 32, 256, 32
-    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(kq, (b, h, d), jnp.float32)
-    k = jax.random.normal(kk, (b, L, kh, d), jnp.float32)
-    v = jax.random.normal(kv, (b, L, kh, d), jnp.float32)
-    lens = jnp.array([5, 64, 129, 256], jnp.int32)
-    kq8, ks = quantize_kv(k)
-    vq8, vs = quantize_kv(v)
-    # Reference: fp kernel over the dequantized cache.
-    ref = decode_attention(
-        q, dequantize_kv(kq8, ks), dequantize_kv(vq8, vs), lens,
-        block_k=bs,
-    )
-    flat = decode_attention(
-        q, kq8, vq8, lens, block_k=bs, k_scale=ks, v_scale=vs
-    )
-    np.testing.assert_allclose(
-        np.asarray(flat), np.asarray(ref), rtol=2e-5, atol=2e-5
-    )
-    # Paged pool: blocks shuffled through the table.
-    nb = b * (L // bs) + 1
+    cfg = llama.tiny_config(n_layers=2)
+    params, _ = llama.init_params(cfg, jax.random.key(0))
+    dec = gen_lib.prepare_decode_params(cfg, params)
+    L, kh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    b, bs, mb = 4, 8, 8
+    S = bs * mb
+    lens = jnp.array([5, 16, 33, 63], jnp.int32)
+    kk, kv, kt = jax.random.split(jax.random.key(1), 3)
+    k_fp = jax.random.normal(kk, (L, b, S, kh, hd), jnp.float32)
+    v_fp = jax.random.normal(kv, (L, b, S, kh, hd), jnp.float32)
+    tokens = jax.random.randint(kt, (b,), 0, cfg.vocab_size, jnp.int32)
+    k8, ks = quantize_kv(k_fp)
+    v8, vs = quantize_kv(v_fp)
+
+    def flat_step(k, v, k_scale=None, v_scale=None):
+        x = llama.embed_tokens(cfg, dec, tokens[:, None])
+        news = []
+        for i in range(L):
+            layer = jax.tree.map(lambda a: a[i], dec["layers"])
+            scales = {} if k_scale is None else dict(
+                k_scale=k_scale[i], v_scale=v_scale[i]
+            )
+            x, k_new, v_new = gen_lib._layer_decode_read_only(
+                cfg, layer, x, lens[:, None], k[i], v[i], lens, **scales
+            )
+            news.append((k_new[:, 0], v_new[:, 0]))
+        return llama.unembed(cfg, dec, x)[:, 0], news
+
+    logits_fp, _ = flat_step(k_fp.astype(cfg.compute_dtype),
+                             v_fp.astype(cfg.compute_dtype))
+    logits_flat, news = flat_step(k8, v8, ks, vs)
+
+    # The same slab behind shuffled tables (block 0 is the sentinel).
     rs = np.random.RandomState(0)
-    ids = rs.permutation(nb - 1) + 1
-    pool_k = np.zeros((nb, bs, kh, d), np.float32)
-    pool_v = np.zeros((nb, bs, kh, d), np.float32)
-    tables = np.zeros((b, L // bs), np.int32)
-    n = 0
-    for i in range(b):
-        for j in range(L // bs):
-            blk = int(ids[n]); n += 1
-            tables[i, j] = blk
-            pool_k[blk] = np.asarray(k)[i, j * bs:(j + 1) * bs]
-            pool_v[blk] = np.asarray(v)[i, j * bs:(j + 1) * bs]
-    pk8, pks = quantize_kv(jnp.asarray(pool_k))
-    pv8, pvs = quantize_kv(jnp.asarray(pool_v))
-    paged = paged_decode_attention(
-        q, pk8, pv8, jnp.asarray(tables), lens,
-        k_scale=pks, v_scale=pvs,
+    tables = (rs.permutation(b * mb) + 1).reshape(b, mb).astype(np.int32)
+
+    def pooled(slab):
+        slab = np.asarray(slab)
+        pool = np.zeros((L, b * mb + 1, bs) + slab.shape[3:], slab.dtype)
+        pool[:, tables.reshape(-1)] = slab.reshape(
+            (L, b * mb, bs) + slab.shape[3:]
+        )
+        return jnp.asarray(pool)
+
+    seen = {}
+    pick = gen_lib.sample_token
+
+    def spy(logits, rng, temps):
+        seen["logits"] = logits
+        return pick(logits, rng, temps)
+
+    monkeypatch.setattr(gen_lib, "sample_token", spy)
+    step_q8 = _build_paged_decode(
+        cfg, b, mb, bs, {"decode": 0}, quantized=True
+    )
+    pk, pv, pks, pvs, nxt = step_q8(
+        pooled(k8), pooled(v8), pooled(ks), pooled(vs), dec,
+        jnp.asarray(tables), lens, tokens, jnp.ones((b,), bool),
+        jnp.zeros((b,), jnp.float32), jax.random.key(2), jnp.int32(0),
     )
     np.testing.assert_allclose(
-        np.asarray(paged), np.asarray(flat), rtol=2e-5, atol=2e-5
+        np.asarray(seen["logits"]), np.asarray(logits_flat),
+        rtol=2e-5, atol=2e-5,
     )
+    np.testing.assert_array_equal(
+        np.asarray(nxt), np.asarray(jnp.argmax(logits_flat, axis=-1))
+    )
+    # Each layer's new row landed, quantized, at its slot's cursor.
+    blk = tables[np.arange(b), np.asarray(lens) // bs]
+    off = np.asarray(lens) % bs
+    for i, (k_new, v_new) in enumerate(news):
+        for pool, scale_pool, new in ((pk, pks, k_new), (pv, pvs, v_new)):
+            want_q, want_s = quantize_kv(new)
+            np.testing.assert_array_equal(
+                np.asarray(pool[i, blk, off]), np.asarray(want_q)
+            )
+            np.testing.assert_allclose(
+                np.asarray(scale_pool[i, blk, off]), np.asarray(want_s),
+                rtol=1e-6,
+            )
+    for name, logits in (("flat", logits_flat), ("paged", seen["logits"])):
+        err = float(jnp.max(jnp.abs(logits - logits_fp)))
+        assert err < 0.2, f"{name} int8 logit error {err} above bound"
 
 
 # ---------------------------------------------------------------------------

@@ -439,46 +439,50 @@ def test_engine_shed_metrics_carry_slo_class(tiny):
     ) == 0
 
 
-# ---- paged Pallas kernel ----------------------------------------------------
+# ---- paged Pallas kernels --------------------------------------------------
 
 
-def test_paged_decode_attention_matches_flat_through_shuffled_table():
-    """The block-table kernel (interpret mode on CPU) must equal the
-    flat length-aware kernel when the pool holds the same logical rows
-    scattered through a shuffled table."""
-    from dlrover_tpu.ops.decode_attention import (
-        decode_attention,
-        paged_decode_attention,
-    )
+def test_pool_decode_attention_matches_flat_slab_through_shuffled_table():
+    """A second, independent reference for the kernel the decode
+    program runs: the FLAT slab is made first and scattered into the
+    pool through a shuffled table, and the kernel over the pool
+    (interpret mode on CPU) must equal ``_append_free_attention`` on
+    the slab itself — no gather through the table on the reference's
+    side. GQA 8 / 4, fills 1 / 23 / 40 / 64 of 64."""
+    from dlrover_tpu.models.generate import _append_free_attention
+    from dlrover_tpu.ops.decode_attention import pool_decode_attention
 
     b, S, h, kh, d = 4, 64, 8, 4, 32
     bs = 16
     mb = S // bs
     lens = jnp.array([1, 23, 40, 64], jnp.int32)
-    ks = jax.random.split(jax.random.key(0), 3)
+    ks = jax.random.split(jax.random.key(0), 5)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
     k_cache = jax.random.normal(ks[1], (b, S, kh, d), jnp.float32)
     v_cache = jax.random.normal(ks[2], (b, S, kh, d), jnp.float32)
+    k_new = jax.random.normal(ks[3], (b, kh, d), jnp.float32)
+    v_new = jax.random.normal(ks[4], (b, kh, d), jnp.float32)
 
     rs = np.random.RandomState(0)
     tables = (rs.permutation(b * mb) + 1).reshape(b, mb).astype(np.int32)
-    nb_pool = b * mb + 1
-    k_pool = np.zeros((nb_pool, bs, kh, d), np.float32)
-    v_pool = np.zeros((nb_pool, bs, kh, d), np.float32)
+    # Layer 1 of a two-layer pool holds the slab; layer 0 holds NaNs.
+    pool_shape = (2, b * mb + 1, bs, kh, d)
+    k_pool = np.full(pool_shape, np.nan, np.float32)
+    v_pool = np.full(pool_shape, np.nan, np.float32)
+    k_pool[1], v_pool[1] = 0.0, 0.0
     for i in range(b):
         for j in range(mb):
-            k_pool[tables[i, j]] = np.asarray(
-                k_cache[i, j * bs:(j + 1) * bs]
-            )
-            v_pool[tables[i, j]] = np.asarray(
-                v_cache[i, j * bs:(j + 1) * bs]
-            )
+            rows = slice(j * bs, (j + 1) * bs)
+            k_pool[1, tables[i, j]] = np.asarray(k_cache[i, rows])
+            v_pool[1, tables[i, j]] = np.asarray(v_cache[i, rows])
 
-    got = paged_decode_attention(
-        q, jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(tables), lens,
+    got = pool_decode_attention(
+        q, k_new, v_new, jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.int32(1), jnp.asarray(tables), lens, jnp.ones((b,), bool),
     )
-    ref = decode_attention(q, k_cache, v_cache, lens, block_k=bs)
+    ref = _append_free_attention(
+        q[:, None], k_cache, v_cache, k_new[:, None], v_new[:, None], lens
+    )[:, 0]
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
     )

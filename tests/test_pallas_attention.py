@@ -77,6 +77,24 @@ def test_flash_in_model():
     )
 
 
+def test_default_attention_goes_by_the_backend_alone(monkeypatch):
+    """The model's default attention is decided once, from the backend:
+    the XLA reference (None) off a TPU, the flash kernel on one — the
+    same function object on every call, since jit caches key on it. A
+    caller that wants another attention passes ``attention_fn=``, as
+    the test above does."""
+    from dlrover_tpu.models import llama
+
+    monkeypatch.setattr(llama, "_ATTN_CACHE", {})
+    assert llama.default_attention_fn() is None
+    assert len(llama._ATTN_CACHE) == 1
+    monkeypatch.setattr(llama, "_ATTN_CACHE", {})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = llama.default_attention_fn()
+    assert callable(fn) and llama.default_attention_fn() is fn
+    assert len(llama._ATTN_CACHE) == 1
+
+
 def test_mlp_only_remat_matches_dots():
     """The mlp_only scan body (attention exempt from remat) must produce
     the same loss and grads as the dots policy, and must silently demote

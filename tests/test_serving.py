@@ -197,56 +197,6 @@ def test_scheduler_drain_mode_admits_only_empty():
     assert len(sch.admit()) == 1                # empty pool -> refill
 
 
-# ---- ragged Pallas decode kernel -------------------------------------------
-
-
-def test_decode_attention_per_row_lengths_match_reference():
-    """The per-row scalar-prefetch variant (interpret mode on CPU):
-    each (batch, kv-head) grid cell clamps to its OWN fill; parity vs
-    the masked XLA reference at every row."""
-    from dlrover_tpu.ops.attention import dot_product_attention
-    from dlrover_tpu.ops.decode_attention import decode_attention
-
-    b, S, h, kh, d = 4, 64, 8, 4, 32
-    lens = jnp.array([1, 23, 40, 64], jnp.int32)
-    ks = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    k_cache = jax.random.normal(ks[1], (b, S, kh, d), jnp.float32)
-    v_cache = jax.random.normal(ks[2], (b, S, kh, d), jnp.float32)
-
-    got = decode_attention(q, k_cache, v_cache, lens, block_k=16)
-    # Reference: per-row masking via positions (query at its row's
-    # last filled position sees exactly rows < len).
-    ref = dot_product_attention(
-        q[:, None], k_cache, v_cache, causal=True,
-        q_positions=(lens - 1)[:, None],
-        kv_positions=jnp.arange(S),
-    )[:, 0]
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
-    )
-
-
-def test_decode_attention_scalar_length_still_uniform():
-    """Scalar length keeps the original uniform-fill contract."""
-    from dlrover_tpu.ops.decode_attention import decode_attention
-
-    b, S, h, kh, d = 2, 32, 4, 2, 16
-    ks = jax.random.split(jax.random.key(1), 3)
-    q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    k_cache = jax.random.normal(ks[1], (b, S, kh, d), jnp.float32)
-    v_cache = jax.random.normal(ks[2], (b, S, kh, d), jnp.float32)
-    got_scalar = decode_attention(
-        q, k_cache, v_cache, jnp.int32(17), block_k=16
-    )
-    got_vec = decode_attention(
-        q, k_cache, v_cache, jnp.full((b,), 17, jnp.int32), block_k=16
-    )
-    np.testing.assert_allclose(
-        np.asarray(got_scalar), np.asarray(got_vec), rtol=1e-6, atol=1e-6
-    )
-
-
 # ---- metrics wiring ---------------------------------------------------------
 
 

@@ -54,7 +54,6 @@ def _as_if_on_tpu(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(llama, "_ATTN_CACHE", {})
-    monkeypatch.delenv("DLROVER_TPU_ATTN", raising=False)
     monkeypatch.delenv("DLROVER_TPU_MOE_DISPATCH", raising=False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -210,22 +209,11 @@ _PAGED_CASES = {
 }
 
 
-def _paged_programs(case, one_chip):
-    """The case's config and paged step programs, and the arguments
-    both programs start with, as shapes on the described chip."""
+def _decode_args(cfg, one_chip):
+    """What a decode program starts from, as shapes on the described
+    chip: ``arr`` (any shape there), a PRNG key, the decode-ready
+    params of ``cfg``."""
     from dlrover_tpu.models import generate as gen_lib
-    from dlrover_tpu.serving.kvpool import engine as paged
-
-    spec = dict(_PAGED_CASES[case])
-    want = spec.pop("want")
-    slots, max_blocks = spec.pop("slots", 16), spec.pop("max_blocks", 144)
-    bs, chunk = spec.pop("block_size", 16), spec.pop("chunk", 256)
-    cfg = llama.TpuLMConfig(n_layers=2, dtype="bfloat16", **spec)
-    num_blocks = slots * max_blocks + 1
-    assert paged.pool_attention_kind(cfg, bs, "fp", chunk) == want
-    assert paged.pool_attention_kind(cfg, bs, "int8", chunk) == "xla_gather"
-    steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, chunk)
-    assert steps.pool_attention == want
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -241,6 +229,25 @@ def _paged_programs(case, one_chip):
             cfg, llama.init_params(cfg, k)[0]
         ), key,
     ))
+    return arr, key, params
+
+
+def _paged_programs(case, one_chip):
+    """The case's config and paged step programs, and the arguments
+    both programs start with, as shapes on the described chip."""
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    spec = dict(_PAGED_CASES[case])
+    want = spec.pop("want")
+    slots, max_blocks = spec.pop("slots", 16), spec.pop("max_blocks", 144)
+    bs, chunk = spec.pop("block_size", 16), spec.pop("chunk", 256)
+    cfg = llama.TpuLMConfig(n_layers=2, dtype="bfloat16", **spec)
+    num_blocks = slots * max_blocks + 1
+    assert paged.pool_attention_kind(cfg, bs, "fp", chunk) == want
+    assert paged.pool_attention_kind(cfg, bs, "int8", chunk) == "xla_gather"
+    steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, chunk)
+    assert steps.pool_attention == want
+    arr, key, params = _decode_args(cfg, one_chip)
     pool = arr(
         (cfg.n_layers, num_blocks, bs, cfg.n_kv_heads, cfg.head_dim),
         jnp.bfloat16,
@@ -318,3 +325,68 @@ def test_paged_prefill_does_only_its_chunks_work(one_chip, case):
     # 0.40 GB of temporaries in the gather program at the cell's shape
     # (12 layers); what is left is the layer's sliced-out weights.
     assert c.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def _one_token_step(kind, cfg, slots, max_len):
+    """A slab cache's one-token step as the two callers build it: the
+    flat ``ServingEngine``'s decode program, and the step ``generate()``
+    scans over. Returns the function (both caches donated) and what
+    follows the caches and the params among its arguments, as (shape,
+    dtype) pairs; ``"key"`` stands for a PRNG key."""
+    from dlrover_tpu.models import generate as gen_lib
+    from dlrover_tpu.serving import engine as flat
+
+    i32 = jnp.int32
+    if kind == "flat_engine":
+        step = flat._build_decode_step(cfg, slots, max_len, {"decode": 0})
+        rest = [
+            ((slots,), i32), ((slots,), i32), ((slots,), bool),
+            ((slots,), jnp.float32), "key", ((), i32),
+        ]
+        return jax.jit(step, donate_argnums=(0, 1)), rest
+
+    def step(k, v, params, lengths, tokens):
+        logits, cache = gen_lib._forward_with_cache(
+            cfg, params, tokens[:, None], gen_lib.DecodeCache(k, v, lengths)
+        )
+        return cache.k, cache.v, logits
+
+    return (
+        jax.jit(step, donate_argnums=(0, 1)),
+        [((slots,), i32), ((slots,), i32)],
+    )
+
+
+@pytest.mark.parametrize("kind", ["flat_engine", "generate"])
+def test_slab_decode_step_is_plain_xla_and_one_rolled_loop(one_chip, kind):
+    """A one-token step over a slab cache at the flagship width (334 M,
+    8 rows x 384 cache rows: the shape its readings were taken at)
+    compiles for the described v5e with NO Pallas kernel and ONE loop
+    over the layers, the cache written in place. Nothing else can be
+    chosen any more: the sequential-grid Pallas kernel took 3.6
+    ms/token against 1.3, an unrolled scan 1.47-1.74 against 1.38 with
+    100-200 MB/token of cache copies (v5e, BENCH_r05)."""
+    cfg = llama.flagship_config(dtype="bfloat16")
+    slots, max_len = 8, 384
+    arr, key, params = _decode_args(cfg, one_chip)
+    cache = arr(
+        (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim),
+        jnp.bfloat16,
+    )
+    step, rest = _one_token_step(kind, cfg, slots, max_len)
+    c = step.lower(
+        cache, cache, params,
+        *(key if a == "key" else arr(*a) for a in rest),
+    ).compile()
+    text = c.as_text()
+    assert _n_kernels(c) == 0
+    assert text.count(" while(") == 1
+    # Both caches alias their outputs, and no second copy of one is
+    # among the temporaries (a side is 100.7 MB; unrolled, the scanned
+    # step holds 91.7 MB of them and no loop).
+    cache_bytes = 2 * cfg.n_layers * slots * max_len * (
+        cfg.n_kv_heads * cfg.head_dim
+    )
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes
