@@ -28,6 +28,12 @@ blocks, one slot = one sequence's [max_len] slab):
   index, cursor, lengths, occupancy, temperatures, sampling step — is a
   traced argument. ``trace_counts`` exposes the compile counter the
   no-retrace tests and the serving bench assert on.
+- **One step in flight.** ``step()`` enqueues its chunk and its decode
+  launch BEFORE it fetches the tokens the previous iteration's launches
+  sampled: the token vector goes from launch to launch on the device,
+  the host schedules on counts (there is no stop token, so who decodes
+  next is known before any token's value), and a finished request is
+  returned one ``step()`` after its last launch (docs/DESIGN.md §29).
 
 Typical use::
 
@@ -71,10 +77,15 @@ class _CompiledSteps(NamedTuple):
 _NGRAM_WINDOW = 128
 
 
-# Phase vocabulary of a ``serving.step`` span (docs/DESIGN.md §29), in
-# the order one iteration can pass them. A phase is named by the mark
-# that CLOSES it, so the host time between two marks always belongs to
-# the later one and the phases tile the span whatever path a step takes.
+# Phase vocabulary of a ``serving.step`` span (docs/DESIGN.md §29). A
+# phase is named by the mark that CLOSES it, so the host time between
+# two marks always belongs to the later one and the phases tile the span
+# whatever path a step takes. A steady iteration passes them as admit,
+# prefill_prep, prefill_launch, decode_prep, decode_launch, decode_fetch,
+# commit, account: it launches its own programs first and then fetches
+# and commits the tokens of the PREVIOUS iteration's (``prefill_fetch``
+# when that one launched a prompt's last chunk and no decode). A drain
+# puts a fetch and a commit before the launch that needed them.
 STEP_PHASES = (
     "admit", "prefill_prep", "prefill_launch", "prefill_fetch",
     "decode_prep", "decode_launch", "decode_fetch",
@@ -92,7 +103,7 @@ class _StepTrace:
         self.t0 = self.last = t0
         self.phases: List[list] = []
         self.counts = {"n_admitted": 0, "n_decoding": 0,
-                       "prefill_tokens": 0}
+                       "prefill_tokens": 0, "overlapped": 0}
 
     def mark(self, phase: str, at: Optional[float] = None) -> None:
         """Close ``phase`` now (or at an already-taken clock read)."""
@@ -100,6 +111,26 @@ class _StepTrace:
             at = time.monotonic()
         self.phases.append([phase, self.last - self.t0, at - self.last])
         self.last = at
+
+
+class _Flight:
+    """What ONE iteration launched and the host has not fetched: the
+    decode launch's token vector with a row per request in it, and the
+    first token of a prompt whose last chunk it ran. A row is ``(req,
+    slot, end)``; ``end`` is None while the request decodes on, else
+    how this token ends it (``"finished"`` / ``"truncated"``): the host
+    knows that by count when it launches, and gave the slot away."""
+
+    __slots__ = ("nxt", "rows", "first", "first_row")
+
+    def __init__(self):
+        self.nxt = None           # [slots] int32 on the device
+        self.rows: List[tuple] = []
+        self.first = None         # int32 scalar on the device
+        self.first_row: Optional[tuple] = None
+
+    def __bool__(self) -> bool:
+        return self.nxt is not None or self.first is not None
 
 
 class _SpecSteps(NamedTuple):
@@ -122,8 +153,10 @@ def _build_decode_step(config, slots: int, max_len: int, counts):
     own cursor — the ragged generalization of generate()'s single
     dynamic-update-slice."""
 
-    def step(k, v, params, lengths, tokens, active, temps, rng, step_idx):
+    def step(k, v, params, lengths, tokens, active, temps, rng, step_idx,
+             first=0, first_slot=-1):
         counts["decode"] += 1  # traces only; execution never reaches here
+        tokens = _place_first(tokens, first, first_slot)
         positions = lengths[:, None]                     # [slots, 1]
         x = llama.embed_tokens(config, params, tokens[:, None])
 
@@ -148,12 +181,30 @@ def _build_decode_step(config, slots: int, max_len: int, counts):
         logits = llama.unembed(config, params, x)[:, 0]   # [slots, V]
         sub = jax.random.fold_in(rng, step_idx * 2)
         nxt = gen_lib.sample_token(logits, sub, temps)
-        # Inactive slots keep their fed token (the host ignores them,
-        # but a stable value keeps replays deterministic).
+        # Inactive slots keep their fed token: the vector that comes out
+        # is the one the next launch takes in (the host fetches it a
+        # step later, and ignores them).
         nxt = jnp.where(active, nxt, tokens)
         return k, v, nxt
 
     return step
+
+
+def _h2d(mirror: np.ndarray):
+    """A host mirror as a launch argument. The launch gets a copy of
+    its own: the mirror is written again (``_lengths`` at once) while
+    the launch is still queued, and a backend may read a host buffer
+    after the call returns (the CPU client aliases an aligned one)."""
+    return jnp.asarray(mirror.copy())
+
+
+def _place_first(tokens, first, first_slot):
+    """The decode programs' first lines: the token vector fed back from
+    the previous launch, with the first token of the prompt whose last
+    chunk ran in this iteration put at its slot (``first_slot`` -1:
+    none). Both stay on the device between the launches (§29)."""
+    at = jnp.arange(tokens.shape[0], dtype=jnp.int32) == first_slot
+    return jnp.where(at, jnp.asarray(first, tokens.dtype), tokens)
 
 
 def _build_prefill_chunk(config, slots: int, max_len: int, chunk: int,
@@ -460,10 +511,23 @@ class ServingEngine:
         self._k, self._v = self._fresh_pool()
         # Host mirrors of the device-side per-slot state; passed into
         # every step call (tiny H2D) so host and device can never
-        # drift.
+        # drift. ``_lengths`` advances when a launch is enqueued;
+        # ``_tokens`` holds the COMMITTED tokens, and feeds a launch
+        # only when no decode launch is in flight (otherwise that
+        # launch's vector does, on the device).
         self._lengths = np.zeros(slots, np.int32)
         self._tokens = np.zeros(slots, np.int32)
         self._temps = np.zeros(slots, np.float32)
+        # One step in flight (docs/DESIGN.md §29): what the previous
+        # iteration launched and nobody fetched, what the running
+        # iteration has launched so far (None between steps), requests
+        # that gave their slot away at their last launch and wait for
+        # its tokens, and requests finished since step() last returned.
+        self._flight: Optional[_Flight] = None
+        self._cur: Optional[_Flight] = None
+        self._leaving: Dict[int, Request] = {}
+        self._done: List[Request] = []
+        self._no_first = jnp.zeros((), jnp.int32)
 
     def _fresh_pool(self):
         shape = (
@@ -506,7 +570,11 @@ class ServingEngine:
         return req
 
     def cancel(self, req: Request) -> None:
-        """Evict a live request; its slot is recycled immediately."""
+        """Evict a live request; its slot is recycled immediately. One
+        with a token in flight is committed first: if that token was
+        its last it has finished, and the next step() returns it."""
+        if req.inflight:
+            self._drain("cancel")
         if req.state == sched_lib.DONE:
             return
         if req.state == sched_lib.QUEUED:
@@ -522,8 +590,13 @@ class ServingEngine:
         self.metrics.annotate("serving_evict", rid=req.rid)
 
     def pending(self) -> int:
-        """Requests not yet DONE (queued + in a slot)."""
-        return len(self.scheduler.queue) + len(self.scheduler.active())
+        """Requests step() has yet to return: queued, in a slot, out of
+        their slot with their last tokens still on the device, or
+        finished by a drain between two steps."""
+        return (
+            len(self.scheduler.queue) + len(self.scheduler.active())
+            + len(self._leaving) + len(self._done)
+        )
 
     def warmup(self) -> None:
         """Compile both step programs on throwaway state, then reset the
@@ -535,14 +608,18 @@ class ServingEngine:
             np.int32(0), np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0),
         )
-        k, v, nxt = self._steps.decode(
-            k, v, self._params,
-            jnp.asarray(np.zeros(self.slots, np.int32)),
-            jnp.asarray(np.zeros(self.slots, np.int32)),
-            jnp.asarray(np.zeros(self.slots, bool)),
-            jnp.asarray(np.zeros(self.slots, np.float32)),
-            self._rng, np.int32(0),
-        )
+        # Both ways a launch is fed: the host's tokens and a chunk's
+        # first token, then the vector that launch returned.
+        fed = jnp.asarray(np.zeros(self.slots, np.int32))
+        for first, first_slot in ((first, 0), (self._no_first, -1)):
+            k, v, fed = self._steps.decode(
+                k, v, self._params,
+                jnp.asarray(np.zeros(self.slots, np.int32)), fed,
+                jnp.asarray(np.zeros(self.slots, bool)),
+                jnp.asarray(np.zeros(self.slots, np.float32)),
+                self._rng, np.int32(0), first, np.int32(first_slot),
+            )
+        nxt = fed
         if self._spec is not None:
             z_i = jnp.asarray(np.zeros(self.slots, np.int32))
             z_b = jnp.asarray(np.zeros(self.slots, bool))
@@ -566,8 +643,12 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """One scheduler iteration: admissions, at most one prefill
-        chunk, one ragged decode step. Returns requests finished THIS
-        iteration (tokens fully populated).
+        chunk and one ragged decode step LAUNCHED, then the tokens of
+        what the previous iteration launched fetched and handed out, so
+        the device holds its next programs while the host commits,
+        accounts and prepares (one step in flight, docs/DESIGN.md §29).
+        Returns the requests whose last token arrived in THIS iteration
+        (tokens fully populated).
 
         With a Tracer armed the iteration also times itself: one
         ``local`` span ``serving.step`` whose ``phases`` (STEP_PHASES)
@@ -579,7 +660,8 @@ class ServingEngine:
             _StepTrace(t0) if tracer is not None else None
         )
         sch = self.scheduler
-        finished: List[Request] = []
+        finished = self._done  # a drain between steps may have begun it
+        self._cur = _Flight()
         self._iter_advance = []
         for req in sch.shed_expired(t0):
             # Past-deadline queued work is an explicit terminal outcome,
@@ -611,10 +693,17 @@ class ServingEngine:
             decoding = sch.decoding()
             if decoding:
                 self._run_decode(decoding, finished)
+            if self._flight is not None:
+                self._fetch_commit(self._flight, finished)
+            self._flight = self._cur or None
         except Exception as e:  # noqa: BLE001 — device/XLA errors vary
+            # A launch's error may surface here or at its fetch, an
+            # iteration later: either way nothing on the device is kept.
             self._recover_from_step_error(e, finished)
             self._iter_advance = []
             status = "error"
+        self._cur = None
+        self._done = []
         idx = self._step_idx
         self._step_idx += 1
         self.metrics.iterations.inc()
@@ -737,7 +826,8 @@ class ServingEngine:
         """A compiled step raised (device fault, XLA error, injected
         chaos). The donated KV slabs may have been invalidated by the
         failed call, so NOTHING cached on device survives: rebuild the
-        pool and return every in-flight request to the front of the
+        pool and return every in-flight request (in a slot, or out of
+        it with its last tokens not yet fetched) to the front of the
         queue to restart from scratch. A request that keeps landing in
         a raising step is EXPLICITLY failed after ``max_requeues``
         restarts — admitted work is never silently lost, and a
@@ -746,10 +836,15 @@ class ServingEngine:
         # Progress about to be reset IS the wasted work: prompt rows
         # already prefilled and tokens already decoded replay from
         # scratch (§34 useful-token accounting).
-        active = self.scheduler.active()
+        leaving = list(self._leaving.values())
+        active = self.scheduler.active() + leaving
         wasted_prefill = sum(r.prefill_pos for r in active)
         wasted_decode = sum(len(r.tokens) for r in active)
-        requeued = self.scheduler.requeue_active()
+        requeued = self.scheduler.requeue_active(released=leaving)
+        # The step in flight goes with the pool it ran on.
+        self._flight = None
+        self._cur = _Flight() if self._cur is not None else None
+        self._leaving.clear()
         self._reset_pool()
         self._lengths[:] = 0
         self._tokens[:] = 0
@@ -806,31 +901,34 @@ class ServingEngine:
         self.metrics.tokens.inc(n_valid, kind="prefill")
         if req.prefill_pos < req.prompt_len:
             return  # more chunks to come; `first` is discarded unfetched
-        self._commit_first_token(req, first, finished)
+        self._launched_first(req, first)
 
-    def _commit_first_token(self, req: Request, first,
-                            finished: List[Request]):
-        """Tail of a prompt's FINAL chunk: fetch the sampled token
-        (blocks on the device) and move the request to DECODE."""
-        tok = int(jax.device_get(first))
-        req.first_token_ts = time.monotonic()
-        self._mark("prefill_fetch", at=req.first_token_ts)
-        if req.requeues == 0:
-            # A re-run after a step-error requeue would re-observe an
-            # inflated first-token latency for the same request.
-            self.metrics.ttft.observe(req.ttft_s)
-        req.tokens.append(tok)
-        self._tokens[req.slot] = tok
-        self.metrics.tokens.inc(kind="decode")
-        if len(req.tokens) >= req.max_new_tokens:
-            self._finish(req, finished)
-        else:
-            req.state = DECODE
-        self._mark("commit")
+    def _launched_first(self, req: Request, first) -> None:
+        """A prompt's LAST chunk is enqueued: its first token is in
+        flight, and by count the request decodes from this iteration's
+        launch on (the token goes there on the device), or, asked for
+        one token only, is done but for the fetch."""
+        req.inflight += 1
+        req.state = DECODE
+        end = "finished" if req.max_new_tokens <= 1 else None
+        self._cur.first = first
+        self._cur.first_row = (req, req.slot, end)
+        if end:
+            self._vacate(req)
+
+    def _vacate(self, req: Request) -> None:
+        """The request's last launch is enqueued: slot (and blocks) go
+        to the next request now, its completion waits for the fetch."""
+        slot = req.slot
+        self.scheduler.release(req)
+        self._release_slot(req, slot)
+        self._leaving[req.rid] = req
 
     def _run_decode(self, decoding: List[Request],
                     finished: List[Request]):
         if self.spec_k:
+            # The speculative path drafts from the committed tokens.
+            self._drain("spec_k")
             self._run_decode_spec(decoding, finished)
             return
         active = np.zeros(self.slots, bool)
@@ -839,33 +937,111 @@ class ServingEngine:
         self._mark_decode_prep(decoding)
         self._k, self._v, nxt = self._steps.decode(
             self._k, self._v, self._params,
-            jnp.asarray(self._lengths), jnp.asarray(self._tokens),
-            jnp.asarray(active), jnp.asarray(self._temps),
-            self._rng, np.int32(self._step_idx),
+            _h2d(self._lengths), self._fed_tokens(),
+            jnp.asarray(active), _h2d(self._temps),
+            self._rng, np.int32(self._step_idx), *self._fed_first(),
         )
         self._mark("decode_launch")
-        self._commit_decode(decoding, nxt, finished)
+        self._launched_decode(decoding, nxt)
 
-    def _commit_decode(self, decoding: List[Request], nxt,
-                       finished: List[Request]):
-        """Fetch the step's tokens (blocks on the device) and hand one
-        to every decoding request."""
-        nxt = np.asarray(jax.device_get(nxt))
-        self._mark("decode_fetch")
+    def _fed_tokens(self):
+        """The decode launch's token vector: the one the launch in
+        flight returns, still on the device, else the host's."""
+        prev = self._flight
+        if prev is None or prev.nxt is None:
+            return _h2d(self._tokens)
+        if self._step_trace is not None:
+            self._step_trace.counts["overlapped"] = 1
+        return prev.nxt
+
+    def _fed_first(self):
+        """``(first, first_slot)`` of the decode launch: this
+        iteration's last-chunk token and the slot that decodes it."""
+        cur = self._cur
+        if cur.first is None or cur.first_row[2]:
+            return self._no_first, np.int32(-1)
+        return cur.first, np.int32(cur.first_row[1])
+
+    def _launched_decode(self, decoding: List[Request], nxt) -> None:
+        """The decode launch is enqueued: advance every slot in it by
+        the row its fed token lands in, and settle BY COUNT who decodes
+        on. A request whose token in flight is its last (of
+        ``max_new_tokens``, or with no row left to feed it back) leaves
+        its slot here."""
+        cur = self._cur
+        cur.nxt = nxt
         for r in decoding:
-            self._lengths[r.slot] += 1   # the fed token's KV landed
-            tok = int(nxt[r.slot])
-            r.tokens.append(tok)
-            self._tokens[r.slot] = tok
-            self.metrics.tokens.inc(kind="decode")
+            slot = r.slot
+            self._lengths[slot] += 1   # the fed token's KV lands
+            r.inflight += 1
+            end = None
+            if len(r.tokens) + r.inflight >= r.max_new_tokens:
+                end = "finished"
+            elif self._lengths[slot] + 1 > self.max_len:
+                end = "truncated"      # no room to feed this token
+            cur.rows.append((r, slot, end))
+            if end:
+                self._vacate(r)
+
+    def _fetch_commit(self, flight: _Flight,
+                      finished: List[Request]) -> None:
+        """Fetch one iteration's tokens (blocks on the device) and hand
+        them out: the first token of the prompt it finished, one token
+        to every request its decode launch carried. First-token time is
+        stamped here, when the host holds the token."""
+        nxt, first = jax.device_get((flight.nxt, flight.first))
+        if flight.first_row is not None:
+            req, slot, end = flight.first_row
+            req.first_token_ts = time.monotonic()
+            self._mark(
+                "prefill_fetch" if nxt is None else "decode_fetch",
+                at=req.first_token_ts,
+            )
+            if req.requeues == 0:
+                # A re-run after a step-error requeue would re-observe
+                # an inflated first-token latency for the same request.
+                self.metrics.ttft.observe(req.ttft_s)
+            self._commit_token(req, slot, int(first), end, finished)
+        else:
+            self._mark("decode_fetch")
+        for r, slot, end in flight.rows:
+            self._commit_token(r, slot, int(nxt[slot]), end, finished)
             self._iter_advance.append(1)
-            if len(r.tokens) >= r.max_new_tokens:
-                self._finish(r, finished)
-            elif self._lengths[r.slot] + 1 > self.max_len:
-                # No room to feed the token just sampled.
-                r.truncated = True
-                self._finish(r, finished)
         self._mark("commit")
+
+    def _commit_token(self, req: Request, slot: int, tok: int,
+                      end: Optional[str], finished: List[Request]):
+        req.tokens.append(tok)
+        req.inflight -= 1
+        self.metrics.tokens.inc(kind="decode")
+        if end:
+            req.truncated = end == "truncated"
+            self._finish(req, finished, slot)
+        else:
+            self._tokens[slot] = tok
+
+    def _drain(self, reason: str) -> None:
+        """Fetch and commit every launch still in flight, so that the
+        host's state is the committed state: called where the code can
+        see it needs that (the speculative path, a preemption, a
+        cancellation, a migration). Inside step() a failed fetch is the
+        step's error; between steps it is recovered from here, and what
+        finishes waits in ``_done`` for the next step()."""
+        flights = [f for f in (self._flight, self._cur) if f]
+        if not flights:
+            return
+        self.metrics.pipeline_drains.inc(reason=reason)
+        try:
+            for f in flights:
+                self._fetch_commit(f, self._done)
+        except Exception as e:  # noqa: BLE001 — device/XLA errors vary
+            if self._cur is not None:
+                raise
+            self._recover_from_step_error(e, self._done)
+            return
+        self._flight = None
+        if self._cur is not None:
+            self._cur = _Flight()
 
     # ---- speculative decode (§35) ------------------------------------------
 
@@ -1010,11 +1186,15 @@ class ServingEngine:
         )
         return emitted, acc
 
-    def _finish(self, req: Request, finished: List[Request]):
-        slot = req.slot
+    def _finish(self, req: Request, finished: List[Request],
+                slot: Optional[int] = None):
+        """``slot``: the one the request held, if it left it at its
+        last launch (:meth:`_vacate`)."""
+        if self._leaving.pop(req.rid, None) is None:
+            slot = req.slot
+            if slot >= 0:
+                self._release_slot(req, slot)
         self.scheduler.finish(req)
-        if slot >= 0:
-            self._release_slot(req, slot)
         finished.append(req)
         self.metrics.requests.inc(
             outcome="truncated" if req.truncated else "finished"

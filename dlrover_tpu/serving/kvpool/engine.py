@@ -72,7 +72,11 @@ import jax.numpy as jnp
 from dlrover_tpu.common.log import logger
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import llama
-from dlrover_tpu.serving.engine import ServingEngine
+from dlrover_tpu.serving.engine import (
+    ServingEngine,
+    _h2d,
+    _place_first,
+)
 from dlrover_tpu.serving.kvpool.allocator import (
     BlockAllocator,
     BlockPoolExhausted,
@@ -167,7 +171,9 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
                         block_size: int, counts,
                         quantized: bool = False,
                         attn: str = "xla_gather"):
-    """[slots] tokens -> one decoded token per slot, ragged lengths.
+    """[slots] tokens -> one decoded token per slot, ragged lengths;
+    ``first`` / ``first_slot`` as the flat decode step takes them
+    (``serving.engine._place_first``).
     ``attn`` (:func:`pool_attention_kind`): ``"paged_kernel"`` reads
     each layer's K/V straight from the stacked pool through the block
     tables, filled pages only; ``"xla_gather"`` gathers the cache per
@@ -198,8 +204,9 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
         return jnp.where(active, nxt, tokens)
 
     def step(k, v, params, tables, lengths, tokens, active, temps,
-             rng, step_idx):
+             rng, step_idx, first=0, first_slot=-1):
         counts["decode"] += 1  # traces only
+        tokens = _place_first(tokens, first, first_slot)
         positions = lengths[:, None]                     # [slots, 1]
         x = llama.embed_tokens(config, params, tokens[:, None])
 
@@ -247,10 +254,11 @@ def _build_paged_decode(config, slots: int, max_blocks: int,
         return k, v, nxt
 
     def step_q8(k, v, ks, vs, params, tables, lengths, tokens, active,
-                temps, rng, step_idx):
+                temps, rng, step_idx, first=0, first_slot=-1):
         from dlrover_tpu.ops.kv_quant import quantize_kv
 
         counts["decode"] += 1  # traces only
+        tokens = _place_first(tokens, first, first_slot)
         positions = lengths[:, None]
         x = llama.embed_tokens(config, params, tokens[:, None])
 
@@ -1023,16 +1031,19 @@ class PagedServingEngine(ServingEngine):
             np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0), np.bool_(True),
         )
-        *pools, nxt = self._steps.decode(
-            *pools, self._params,
-            jnp.asarray(np.zeros((self.slots, self.max_blocks),
-                                 np.int32)),
-            jnp.asarray(np.zeros(self.slots, np.int32)),
-            jnp.asarray(np.zeros(self.slots, np.int32)),
-            jnp.asarray(np.zeros(self.slots, bool)),
-            jnp.asarray(np.zeros(self.slots, np.float32)),
-            self._rng, np.int32(0),
-        )
+        # Both ways a launch is fed: the host's tokens and a chunk's
+        # first token, then the vector that launch returned.
+        fed = jnp.asarray(np.zeros(self.slots, np.int32))
+        for first, first_slot in ((first, 0), (self._no_first, -1)):
+            *pools, fed = self._steps.decode(
+                *pools, self._params,
+                jnp.asarray(np.zeros((self.slots, self.max_blocks),
+                                     np.int32)),
+                jnp.asarray(np.zeros(self.slots, np.int32)), fed,
+                jnp.asarray(np.zeros(self.slots, bool)),
+                jnp.asarray(np.zeros(self.slots, np.float32)),
+                self._rng, np.int32(0), first, np.int32(first_slot),
+            )
         pools = self._steps.cow(*pools, np.int32(0), np.int32(0))
         blk_shape = (
             self.config.n_layers, self.block_size,
@@ -1117,6 +1128,9 @@ class PagedServingEngine(ServingEngine):
                 victim = self._pick_preemption_victim(requester)
                 if victim is None:
                     raise
+                # A preemption resets its victim: commit what the
+                # device still holds for it first.
+                self._drain("preempt")
                 self._preempt(victim)
 
     def _pick_preemption_victim(
@@ -1283,7 +1297,7 @@ class PagedServingEngine(ServingEngine):
         self._mark_prefill_prep(n_valid, start + n_valid)
         *pools, first = self._steps.prefill(
             *self._pools(), self._params, jnp.asarray(chunk),
-            jnp.asarray(self._tables[req.slot]),
+            _h2d(self._tables[req.slot]),
             np.int32(start), np.int32(n_valid),
             np.float32(req.temperature), self._rng,
             np.int32(self._step_idx),
@@ -1303,11 +1317,13 @@ class PagedServingEngine(ServingEngine):
             self._cache.insert(
                 req.prompt, self._slot_blocks[req.slot][:n_full]
             )
-        self._commit_first_token(req, first, finished)
+        self._launched_first(req, first)
 
     def _run_decode(self, decoding: List[Request],
                     finished: List[Request]):
         if self.spec_k:
+            # The speculative path drafts from the committed tokens.
+            self._drain("spec_k")
             self._run_decode_spec(decoding, finished)
             return
         # Block-budget pass FIRST: growing a cursor past a block edge
@@ -1327,14 +1343,14 @@ class PagedServingEngine(ServingEngine):
             active[r.slot] = True
         self._mark_decode_prep(decoding)
         *pools, nxt = self._steps.decode(
-            *self._pools(), self._params, jnp.asarray(self._tables),
-            jnp.asarray(self._lengths), jnp.asarray(self._tokens),
-            jnp.asarray(active), jnp.asarray(self._temps),
-            self._rng, np.int32(self._step_idx),
+            *self._pools(), self._params, _h2d(self._tables),
+            _h2d(self._lengths), self._fed_tokens(),
+            jnp.asarray(active), _h2d(self._temps),
+            self._rng, np.int32(self._step_idx), *self._fed_first(),
         )
         self._set_pools(pools)
         self._mark("decode_launch")
-        self._commit_decode(decoding, nxt, finished)
+        self._launched_decode(decoding, nxt)
 
     # ---- speculative decode hooks (§35) ------------------------------------
 

@@ -66,6 +66,9 @@ def export_request(engine, req: Request,
     """Serialize ``req``'s blocks + scheduler state on the source
     engine. The request stays LIVE on the source — pair with
     :func:`release_exported` once the importer acks."""
+    if req.inflight:
+        # Fill and tokens are exported as the host committed them.
+        engine._drain("migrate")
     if req.state != DECODE or not req.tokens:
         raise MigrationError(
             f"rid {req.rid} not migratable: state={req.state!r}, "
@@ -154,6 +157,8 @@ def release_exported(engine, req: Request,
     ref — conservation holds), and record a ``migrated`` outcome. The
     request's spans are emitted by the DESTINATION: the source emits
     nothing, or the request would double-report."""
+    if req.inflight:
+        engine._drain("migrate")  # it may finish here: the source won
     if req.state == DECODE and req.slot >= 0:
         slot = req.slot
         engine.scheduler.evict(req, now)
@@ -204,6 +209,9 @@ def import_request(engine, payload: bytes,
     fill = int(header["fill"])
     if fill > n * bs:
         raise MigrationError(f"fill {fill} exceeds {n} wire blocks")
+    # The import writes a slot's token on the host: the host's vector
+    # has to be the one the next launch is fed.
+    engine._drain("migrate")
     if not can_import(engine, n):
         raise MigrationRefused(
             f"destination full: {n} blocks + a slot needed"

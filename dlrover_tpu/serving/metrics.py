@@ -70,6 +70,13 @@ class ServingMetrics:
             "engine iterations that raised and re-queued their in-flight "
             "requests",
         )
+        self.pipeline_drains = reg.counter(
+            "serving_pipeline_drains_total",
+            "times the engine fetched its step in flight before the "
+            "next launch because it needed the committed state, by "
+            "reason (spec_k, preempt, cancel, migrate)",
+            labelnames=("reason",),
+        )
         self.shed = reg.counter(
             "serving_requests_shed_total",
             "queued requests dropped before admission, by reason "

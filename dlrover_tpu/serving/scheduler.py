@@ -160,6 +160,10 @@ class Request:
     # carried durations).
     migrate_start_ts: Optional[float] = None
     migrate_end_ts: Optional[float] = None
+    # Tokens sampled for this request on the device that the host has
+    # not fetched yet (the engine keeps one step in flight, §29): the
+    # engine schedules on ``len(tokens) + inflight``.
+    inflight: int = 0
 
     @property
     def prompt_len(self) -> int:
@@ -530,6 +534,13 @@ class Scheduler:
         overwritten before its fill cursor passes it."""
         req.state = DONE
         req.finish_ts = now if now is not None else time.monotonic()
+        self.release(req)
+
+    def release(self, req: Request) -> None:
+        """Recycle the slot alone. The engine calls this the moment a
+        request's LAST launch is enqueued: the device runs its queue in
+        order, so the next occupant's programs come after it, and only
+        the request's completion waits for its tokens (§29)."""
         if req.slot >= 0:
             self.by_slot[req.slot] = None
             self._free.append(req.slot)
@@ -547,28 +558,27 @@ class Scheduler:
         older request. Unlike a step-error requeue this does NOT count
         against the request's requeue budget — being the youngest when
         the pool runs dry is scheduling, not failure."""
-        if req.slot >= 0:
-            self.by_slot[req.slot] = None
-            self._free.append(req.slot)
-            req.slot = -1
+        self.release(req)
+        self._reset_progress(req)
+        req.preemptions += 1
+        self.queue.appendleft(req)
+
+    @staticmethod
+    def _reset_progress(req: Request) -> None:
+        """Progress resets (preemption, step-error requeue) restart a
+        request from scratch: queued, nothing prefilled, no token held
+        or in flight, and its speculative accounting restarts with it,
+        or replayed drafts would double-count."""
         req.state = QUEUED
         req.prefill_pos = 0
         req.tokens = []
+        req.inflight = 0
         req.truncated = False
         req.first_token_ts = None
         req.admit_ts = None
         req.prefix_hit_blocks = 0
         req.migrate_start_ts = None
         req.migrate_end_ts = None
-        self._reset_spec_progress(req)
-        req.preemptions += 1
-        self.queue.appendleft(req)
-
-    @staticmethod
-    def _reset_spec_progress(req: Request) -> None:
-        """Progress resets (preemption, step-error requeue) restart a
-        request from scratch — its speculative accounting restarts with
-        it, or replayed drafts would double-count."""
         req.spec_drafted = 0
         req.spec_accepted = 0
         req.draft_s = 0.0
@@ -576,30 +586,24 @@ class Scheduler:
 
     # ---- failure recovery --------------------------------------------------
 
-    def requeue_active(self) -> List[Request]:
+    def requeue_active(
+        self, released: Sequence[Request] = ()
+    ) -> List[Request]:
         """Return every in-slot request to the FRONT of the queue with
         its progress reset — the engine calls this when a step raises
         and the KV pool can no longer be trusted (donated buffers may be
         invalidated by the failed call). Requests restart from scratch:
         their sampled tokens depended on cache state that is gone.
+        ``released``: requests whose slot went at their last launch
+        (:meth:`release`) and whose tokens were lost with the step.
         Queue order preserves rid order (oldest first) so recovery does
         not reorder service. Returns the re-queued requests."""
-        victims = sorted(self.active(), key=lambda r: r.rid)
+        victims = sorted(
+            [*self.active(), *released], key=lambda r: r.rid
+        )
         for req in reversed(victims):
-            if req.slot >= 0:
-                self.by_slot[req.slot] = None
-                self._free.append(req.slot)
-                req.slot = -1
-            req.state = QUEUED
-            req.prefill_pos = 0
-            req.tokens = []
-            req.truncated = False
-            req.first_token_ts = None
-            req.admit_ts = None
-            req.prefix_hit_blocks = 0
-            req.migrate_start_ts = None
-            req.migrate_end_ts = None
-            self._reset_spec_progress(req)
+            self.release(req)
+            self._reset_progress(req)
             req.requeues += 1
             self.queue.appendleft(req)
         return victims

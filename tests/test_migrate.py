@@ -51,8 +51,8 @@ def make_prompt(cfg, n, seed=0):
 def drive_to_decode(eng, prompt, max_new, decode_steps=0, **kw):
     req = eng.submit(prompt, max_new, **kw)
     for _ in range(200):
-        if req.state == DECODE:
-            break
+        if req.tokens:
+            break  # the first token is fetched a step after its launch
         eng.step()
     assert req.state == DECODE and req.tokens
     for _ in range(decode_steps):

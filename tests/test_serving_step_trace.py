@@ -95,12 +95,16 @@ def test_phases_tile_every_step_span(kind, parts, tracer):
         assert sum(p[2] for p in phases) == pytest.approx(
             s["dur_s"], abs=1e-6
         )
+    # The plain engines fetch a prompt's first token with the decode
+    # launch it rides into, a step later; the speculative path drains
+    # it (``prefill_fetch``) before it drafts.
     launches = (
-        {"spec_draft", "spec_verify"} if kind == "speculative"
+        {"prefill_fetch", "spec_draft", "spec_verify"}
+        if kind == "speculative"
         else {"decode_launch", "decode_fetch"}
     )
     assert seen == {
-        "admit", "prefill_prep", "prefill_launch", "prefill_fetch",
+        "admit", "prefill_prep", "prefill_launch",
         "decode_prep", "commit", "account",
     } | launches
 
@@ -124,7 +128,7 @@ def test_counts_add_up_to_the_work_done(kind, parts, tracer):
     assert all(
         set(a) - {"retraces", "kv_rows", "prefill_kv_rows"} == {
             "idx", "phases", "n_admitted", "n_decoding",
-            "prefill_tokens", "n_finished",
+            "prefill_tokens", "n_finished", "overlapped",
         } for a in attrs
     )
     assert all("retraces" not in a for a in attrs[3:])
@@ -337,13 +341,21 @@ def test_trace_query_renders_a_step_span(parts, tracer, tmp_path, capsys):
             sum(s["attrs"].get("prefill_kv_rows", 0) for s in spans)
             / sum("prefill_kv_rows" in s["attrs"] for s in spans)
         ),
+        "overlapped_pct": pytest.approx(
+            100.0 * sum(s["attrs"]["overlapped"] for s in spans)
+            / sum(s["attrs"]["n_decoding"] > 0 for s in spans)
+        ),
         "retraced_steps": [
             s["attrs"]["idx"] for s in spans if "retraces" in s["attrs"]
         ],
     }
     assert 1.0 <= table["counts"]["decode_batch_mean"] <= 2.0
+    # A decode launch after one found it in flight (not the first, nor
+    # the one after a lull in which only a chunk ran).
+    assert 50.0 < table["counts"]["overlapped_pct"] < 100.0
     assert table["counts"]["prefill_kv_rows_mean"] > 8
     assert trace_query.main(["--steps", sink]) == 0
     printed = capsys.readouterr().out
     assert "retraced_steps=" in printed and "kv_rows_mean=" in printed
     assert " prefill_kv_rows_mean=" in printed
+    assert " overlapped_pct=" in printed

@@ -273,12 +273,21 @@ def test_paged_decode_reads_the_pool_in_place(one_chip, case):
     slots, max_blocks = shape["slots"], shape["max_blocks"]
     bs, num_blocks = shape["bs"], shape["num_blocks"]
     i32 = jnp.int32
+    # As the engine launches it: the token vector is the previous
+    # launch's output and the first token the chunk program's, both
+    # device arguments; nothing of the merge is a program of its own.
     c = steps.decode.lower(
         *lead, arr((slots, max_blocks), i32),
         arr((slots,), i32), arr((slots,), i32), arr((slots,), bool),
         arr((slots,), jnp.float32), key, arr((), i32),
+        arr((), i32), arr((), i32),
     ).compile()
     text = c.as_text()
+    assert "jit_step," in text.splitlines()[0]   # what traces name it
+    assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in text
+    n_params = len(jax.tree_util.tree_leaves(lead)) + 9
+    assert f"parameter({n_params - 1})" in text
+    assert f"parameter({n_params})" not in text
     if want == "xla_gather":
         assert _n_kernels(c) == 0
         return
@@ -342,6 +351,7 @@ def _one_token_step(kind, cfg, slots, max_len):
         rest = [
             ((slots,), i32), ((slots,), i32), ((slots,), bool),
             ((slots,), jnp.float32), "key", ((), i32),
+            ((), i32), ((), i32),   # the chunk's first token, its slot
         ]
         return jax.jit(step, donate_argnums=(0, 1)), rest
 

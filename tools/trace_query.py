@@ -26,7 +26,8 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # engine iterations: the phases of the serving.step spans folded
     # into one table with each phase's share of the summed step time,
     # then the steps' counts (admitted / finished requests, prompt
-    # tokens, mean decode batch and cache rows visible to it, which
+    # tokens, mean decode batch and cache rows visible to it, the share
+    # of decode launches enqueued with a step still in flight, which
     # steps recompiled)
     python tools/trace_query.py --steps spans_engine.jsonl
 
@@ -131,7 +132,10 @@ def step_summary(spans: List[Dict]) -> Dict:
     engine's ``max_len``: the share of the logical view attention has
     any use for), ``prefill_kv_rows_mean`` the same for a prefill
     chunk launch (the slot's fill below the chunk plus the chunk, over
-    ``max_len``), ``retraced_steps`` names the iterations that
+    ``max_len``), ``overlapped_pct`` the share of decode launches
+    enqueued while the previous launch's tokens were still on the
+    device (one step in flight: ~100 in steady state, 0 on a path that
+    drains every step), ``retraced_steps`` names the iterations that
     recompiled a program."""
     steps = [
         s for s in spans
@@ -163,6 +167,10 @@ def step_summary(spans: List[Dict]) -> Dict:
         "kv_rows_mean": sum(kv_rows) / len(kv_rows) if kv_rows else 0.0,
         "prefill_kv_rows_mean": (
             sum(chunk_rows) / len(chunk_rows) if chunk_rows else 0.0
+        ),
+        "overlapped_pct": (
+            100.0 * sum(a.get("overlapped", 0) for a in attrs)
+            / len(decoding) if decoding else 0.0
         ),
         "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
     }}
