@@ -12,3 +12,12 @@ from dlrover_tpu.models.llama import (  # noqa: F401
     forward,
     loss_fn,
 )
+
+
+def model_for(config):
+    """The module that implements ``config``'s kind of model: its
+    ``init_params`` / ``param_axes`` / ``loss_fn`` (and, where the model
+    has state that is not trained, ``init_buffers`` / ``buffer_axes``)."""
+    import importlib
+
+    return importlib.import_module(f"dlrover_tpu.models.{config.kind}")

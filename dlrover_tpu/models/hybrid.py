@@ -1,0 +1,529 @@
+"""Layer-pattern language models: a stack that is NOT one layer repeated.
+
+A model here is a few leading layers, unrolled, and then a period of
+``(mixer kind, ffn kind)`` pairs scanned over its repeats. A kind is one
+entry of ``MIXERS`` or ``FFNS``: how to make its parameters, their
+logical axes, and the function itself. What ships:
+
+- mixer ``kda``: gated delta-rule linear attention (short causal
+  convolutions, L2-normed q and k, a per-channel decay, the delta-rule
+  state of ``ops/kda.py``, a gated per-head norm on the way out);
+- mixer ``mla``: latent attention without rotary (NoPE): q of
+  ``qk_nope + qk_rope`` per head, a shared low-rank K/V latent, values of
+  their own head size, through the flash kernel;
+- ffn ``dense``: SwiGLU; ffn ``moe``: a shared expert plus this chip's
+  share of a sigmoid-routed expert layer (``moe.moe_mlp_share``).
+
+Every block is pre-norm residual: ``x += mixer(norm(x))``,
+``x += ffn(norm(x))``. Embedding, final norm, head, cross-entropy and
+the (1 + scale) RMSNorm are ``models/llama.py``'s. The dense model is
+the one-kind pattern by design but still runs ``llama.run_layer_stack``;
+so do ``generate.py`` and the serving engines (ROADMAP D1).
+
+Parameters: ``{"embed", "leading": [layer, ...], "period": [layer with a
+leading repeat axis, ...], "final_norm", "lm_head"}``, a layer being
+``{"mixer_norm", "mixer": {...}, "ffn_norm", "ffn": {...}}``. What is
+state but not trained (the routers' score-correction bias) lives in a
+twin tree of BUFFERS (``init_buffers``), which the train step carries
+beside the parameters and gives no gradient and no optimizer state.
+"""
+
+import dataclasses
+import functools
+import math
+from typing import Any, Callable, ClassVar, Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from dlrover_tpu.models import llama
+from dlrover_tpu.models import moe as moe_lib
+from dlrover_tpu.ops import kda as kda_ops
+from dlrover_tpu.ops.attention import dot_product_attention
+from dlrover_tpu.ops.norms import rms_norm
+from dlrover_tpu.parallel.sharding import with_logical_constraint
+
+Pattern = Tuple[Tuple[str, str], ...]
+COUNTERS = ("moe_rows_held", "moe_rows_max", "moe_rows_dropped")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridLMConfig:
+    kind: ClassVar[str] = "hybrid"
+    pp_stages: ClassVar[int] = 1         # llama's head asks
+    moe_aux_weight: ClassVar[float] = 0.0
+
+    vocab_size: int = 256                # rows of the vocabulary HELD
+    embed_dim: int = 64
+    leading: Pattern = (("kda", "dense"),)
+    period: Pattern = (
+        ("kda", "moe"), ("kda", "moe"), ("mla", "moe"), ("kda", "moe"),
+    )
+    n_periods: int = 1
+    # kda
+    kda_heads: int = 4
+    kda_head_dim: int = 16               # keys and values
+    kda_conv: int = 4
+    kda_gate_rank: int = 16
+    # mla
+    n_heads: int = 4
+    kv_lora_rank: int = 32
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    # ffn
+    mlp_dim: int = 128                   # the dense SwiGLU
+    moe_mlp_dim: int = 32                # each expert, and the shared one
+    n_experts: int = 16                  # the router's width
+    moe_top_k: int = 4
+    experts_held: Tuple[int, int] = (0, 16)   # (first, count) living here
+    n_shared_experts: int = 1
+    routed_scaling: float = 1.0
+    dtype: str = "bfloat16"              # compute dtype (params stay f32)
+
+    def __post_init__(self):
+        for mixer, ffn in self.leading + self.period:
+            if mixer not in MIXERS or ffn not in FFNS:
+                raise ValueError(f"no layer kind ({mixer!r}, {ffn!r})")
+        first, count = self.experts_held
+        if not (0 <= first and 1 <= count and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"block of the {self.n_experts} experts")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.leading) + self.n_periods * len(self.period)
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+
+def tiny_config(**overrides) -> HybridLMConfig:
+    """One dense-FFN KDA layer and one period, CPU-test sized."""
+    return HybridLMConfig(**dict({"dtype": "float32"}, **overrides))
+
+
+class Kind(NamedTuple):
+    init: Callable      # (config, key) -> params
+    axes: Callable      # (config) -> logical axes, same tree
+    apply: Callable     # mixer: (config, p, h) -> y
+    #                     ffn: (config, p, buffers, h) -> (y, counters)
+    buffers: Callable = lambda config, key: {}
+
+
+def _dense(key, shape, fan_in):
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+
+
+def _proj(spec, x, w):
+    return jnp.einsum(spec, x, w.astype(x.dtype))
+
+
+# -- mixer: KDA ---------------------------------------------------------------
+
+
+def _kda_init(config, key):
+    d, h, hd = config.embed_dim, config.kda_heads, config.kda_head_dim
+    r, width = config.kda_gate_rank, config.kda_conv
+    ks = jax.random.split(key, 14)
+    dt = jnp.exp(jax.random.uniform(
+        ks[11], (h, hd), jnp.float32, math.log(1e-3), math.log(1e-1)
+    ))
+    return {
+        "wq": _dense(ks[0], (d, h, hd), d),
+        "wk": _dense(ks[1], (d, h, hd), d),
+        "wv": _dense(ks[2], (d, h, hd), d),
+        "conv_q": _dense(ks[3], (width, h, hd), width),
+        "conv_k": _dense(ks[4], (width, h, hd), width),
+        "conv_v": _dense(ks[5], (width, h, hd), width),
+        "w_a1": _dense(ks[6], (d, r), d),
+        "w_a2": _dense(ks[7], (r, h, hd), r),
+        "w_g1": _dense(ks[8], (d, r), d),
+        "w_g2": _dense(ks[9], (r, h, hd), r),
+        "w_beta": _dense(ks[10], (d, h), d),
+        # decay rate exp(a_log) in [1, 16], softplus(dt_bias) in
+        # [1e-3, 1e-1]: the public implementation's start.
+        "a_log": jnp.log(jax.random.uniform(
+            ks[12], (h,), jnp.float32, 1.0, 16.0
+        )),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "o_norm": jnp.zeros((hd,), jnp.float32),
+        "wo": _dense(ks[13], (h, hd, d), h * hd),
+    }
+
+
+def _kda_axes(config):
+    head = ("heads", "head_dim")
+    return {
+        "wq": ("embed",) + head, "wk": ("embed",) + head,
+        "wv": ("embed",) + head,
+        "conv_q": (None,) + head, "conv_k": (None,) + head,
+        "conv_v": (None,) + head,
+        "w_a1": ("embed", None), "w_a2": (None,) + head,
+        "w_g1": ("embed", None), "w_g2": (None,) + head,
+        "w_beta": ("embed", "heads"),
+        "a_log": ("heads",), "dt_bias": head, "o_norm": ("norm",),
+        "wo": head + ("embed",),
+    }
+
+
+def _short_conv(x, w):
+    """Causal depthwise convolution over time, one filter a channel,
+    zeros to the left: ``y_t = sum_j w[j] x[t - (K-1) + j]``, float32.
+    x ``[b, h, s, k]``, w ``[K, h, k]``."""
+    width, s = w.shape[0], x.shape[2]
+    x = jnp.pad(
+        x.astype(jnp.float32), ((0, 0), (0, 0), (width - 1, 0), (0, 0))
+    )
+    return sum(
+        w[j][:, None, :] * x[:, :, j:j + s] for j in range(width)
+    )
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+def _kda_apply(config, p, h):
+    """Heads-major ``[b, heads, s, head_dim]`` from the projections on:
+    a chunk of the scan is then a reshape, and no transpose is paid."""
+    with jax.named_scope("kda"):
+        def branch(w, conv):
+            return jax.nn.silu(_short_conv(_proj("bsd,dhk->bhsk", h, w), conv))
+
+        q = _l2norm(branch(p["wq"], p["conv_q"])) * config.kda_head_dim ** -0.5
+        k = _l2norm(branch(p["wk"], p["conv_k"]))
+        v = branch(p["wv"], p["conv_v"])
+        low = _proj("bsd,dr->bsr", h, p["w_a1"])
+        g = -jnp.exp(p["a_log"])[:, None, None] * jax.nn.softplus(
+            _proj("bsr,rhk->bhsk", low, p["w_a2"]).astype(jnp.float32)
+            + p["dt_bias"][:, None, :]
+        )
+        beta = jax.nn.sigmoid(
+            _proj("bsd,dh->bhs", h, p["w_beta"]).astype(jnp.float32)
+        )
+        with jax.named_scope("kda_scan"):
+            o = kda_ops.kda_chunked(q, k, v, g, beta)
+        # Kept across the layer's rematerialisation (``run_pattern``'s
+        # policy): the backward then re-runs the scan once, for its own
+        # residuals, and not a second time for what follows it.
+        o = checkpoint_name(o, "kda_out")
+        gate = jax.nn.sigmoid(_proj(
+            "bsr,rhk->bhsk", _proj("bsd,dr->bsr", h, p["w_g1"]), p["w_g2"]
+        ).astype(jnp.float32))
+        o = (rms_norm(o, p["o_norm"]) * gate).astype(h.dtype)
+        return _proj("bhsk,hkd->bsd", o, p["wo"])
+
+
+# -- mixer: MLA without rotary ------------------------------------------------
+
+
+def _mla_init(config, key):
+    d, h, r = config.embed_dim, config.n_heads, config.kv_lora_rank
+    dq = config.qk_nope_dim + config.qk_rope_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": _dense(ks[0], (d, h, dq), d),
+        "w_kva": _dense(ks[1], (d, r + config.qk_rope_dim), d),
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "w_kvb": _dense(
+            ks[2], (r, h, config.qk_nope_dim + config.v_head_dim), r
+        ),
+        "wo": _dense(ks[3], (h, config.v_head_dim, d), h * config.v_head_dim),
+    }
+
+
+def _mla_axes(config):
+    return {
+        "wq": ("embed", "heads", "head_dim"),
+        "w_kva": ("embed", None),
+        "kv_norm": ("norm",),
+        "w_kvb": (None, "heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+
+
+def _mla_apply(config, p, h):
+    with jax.named_scope("mla"):
+        r, nope = config.kv_lora_rank, config.qk_nope_dim
+        q = _proj("bsd,dhk->bshk", h, p["wq"])
+        kva = _proj("bsd,dr->bsr", h, p["w_kva"])
+        latent = rms_norm(kva[..., :r], p["kv_norm"])
+        kvb = _proj("bsr,rhk->bshk", latent, p["w_kvb"])
+        # The positional slice of a key is one vector shared by the
+        # heads and, in this model, NOT rotated (mla_use_nope).
+        shared = jnp.broadcast_to(
+            kva[..., None, r:], kvb.shape[:3] + (config.qk_rope_dim,)
+        )
+        k = jnp.concatenate([kvb[..., :nope], shared], axis=-1)
+        v = kvb[..., nope:]
+        q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
+        attend = llama.default_attention_fn() or dot_product_attention
+        out = attend(q, k, v, causal=True)     # scaled by 1/sqrt(nope + rope)
+        return _proj("bshk,hkd->bsd", out, p["wo"])
+
+
+# -- ffns ---------------------------------------------------------------------
+
+
+def _swiglu_init(key, d, f):
+    ks = jax.random.split(key, 3)
+    return {
+        "w_gate": _dense(ks[0], (d, f), d),
+        "w_up": _dense(ks[1], (d, f), d),
+        "w_down": _dense(ks[2], (f, d), f),
+    }
+
+
+_SWIGLU_AXES = {
+    "w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
+    "w_down": ("mlp", "embed"),
+}
+
+
+def _swiglu(p, h):
+    g = _proj("bsd,df->bsf", h, p["w_gate"])
+    u = _proj("bsd,df->bsf", h, p["w_up"])
+    g = with_logical_constraint(g, ("batch", "seq", "mlp"))
+    return _proj("bsf,fd->bsd", jax.nn.silu(g) * u, p["w_down"])
+
+
+def _no_counters():
+    return jnp.zeros((len(COUNTERS),), jnp.int32)
+
+
+def _dense_apply(config, p, buffers, h):
+    with jax.named_scope("dense"):
+        return _swiglu(p, h), _no_counters()
+
+
+def _moe_init(config, key):
+    d, f, held = config.embed_dim, config.moe_mlp_dim, config.experts_held[1]
+    ks = jax.random.split(key, 5)
+    return {
+        "router": _dense(ks[0], (d, config.n_experts), d),
+        "w_gate": _dense(ks[1], (held, d, f), d),
+        "w_up": _dense(ks[2], (held, d, f), d),
+        "w_down": _dense(ks[3], (held, f, d), f),
+        "shared": _swiglu_init(ks[4], d, f * config.n_shared_experts),
+    }
+
+
+def _moe_axes(config):
+    return {
+        "router": ("embed", None),
+        "w_gate": ("expert", "embed", "mlp"),
+        "w_up": ("expert", "embed", "mlp"),
+        "w_down": ("expert", "mlp", "embed"),
+        "shared": dict(_SWIGLU_AXES),
+    }
+
+
+def _moe_buffers(config, key):
+    # Seeded and constant: nothing updates it while training. (A
+    # trainer that balances load would step it against each expert's
+    # load; the benchmark sets a balanced one before its first step.)
+    return {"router_bias": 0.01 * jax.random.normal(
+        key, (config.n_experts,), jnp.float32
+    )}
+
+
+def _moe_apply(config, p, buffers, h):
+    routed, c = moe_lib.moe_mlp_share(
+        h, p["router"], buffers["router_bias"],
+        p["w_gate"], p["w_up"], p["w_down"],
+        first=config.experts_held[0], top_k=config.moe_top_k,
+        scaling=config.routed_scaling,
+    )
+    with jax.named_scope("shared"):
+        out = routed + _swiglu(p["shared"], h)
+    return out, jnp.stack([c.rows_held, c.rows_max, c.rows_dropped])
+
+
+MIXERS: Dict[str, Kind] = {
+    "kda": Kind(_kda_init, _kda_axes, _kda_apply),
+    "mla": Kind(_mla_init, _mla_axes, _mla_apply),
+}
+FFNS: Dict[str, Kind] = {
+    "dense": Kind(
+        lambda c, k: _swiglu_init(k, c.embed_dim, c.mlp_dim),
+        lambda c: dict(_SWIGLU_AXES), _dense_apply,
+    ),
+    "moe": Kind(_moe_init, _moe_axes, _moe_apply, _moe_buffers),
+}
+
+
+# -- the stack ----------------------------------------------------------------
+
+
+def _layer_init(config, kinds, key):
+    mixer, ffn = kinds
+    k_mixer, k_ffn = jax.random.split(key)
+    d = config.embed_dim
+    return {
+        "mixer_norm": jnp.zeros((d,), jnp.float32),
+        "mixer": MIXERS[mixer].init(config, k_mixer),
+        "ffn_norm": jnp.zeros((d,), jnp.float32),
+        "ffn": FFNS[ffn].init(config, k_ffn),
+    }
+
+
+def _layer_axes(config, kinds, lead=()):
+    mixer, ffn = kinds
+    tree = {
+        "mixer_norm": ("norm",), "mixer": MIXERS[mixer].axes(config),
+        "ffn_norm": ("norm",), "ffn": FFNS[ffn].axes(config),
+    }
+    return jax.tree_util.tree_map(
+        lambda axes: lead + axes, tree, is_leaf=lambda x: isinstance(x, tuple)
+    )
+
+
+def _per_layer(config, key, make):
+    """``{"leading": [...], "period": [...]}`` of ``make(kinds, key)``,
+    a period position stacked over the repeats."""
+    keys = jax.random.split(key, config.n_layers)
+    n_lead, span = len(config.leading), len(config.period)
+    period = []
+    for i, kinds in enumerate(config.period):
+        repeats = [
+            make(kinds, keys[n_lead + r * span + i])
+            for r in range(config.n_periods)
+        ]
+        period.append(jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *repeats
+        ))
+    return {
+        "leading": [
+            make(kinds, keys[i]) for i, kinds in enumerate(config.leading)
+        ],
+        "period": period,
+    }
+
+
+def param_axes(config: HybridLMConfig) -> Dict[str, Any]:
+    return {
+        "embed": ("vocab", "embed"),
+        "leading": [_layer_axes(config, kinds) for kinds in config.leading],
+        "period": [
+            _layer_axes(config, kinds, ("layer",)) for kinds in config.period
+        ],
+        "final_norm": ("norm",),
+        "lm_head": ("embed", "vocab"),
+    }
+
+
+def init_params(config: HybridLMConfig, rng: jax.Array):
+    """(params, logical axes): normal(0, 1/sqrt(fan_in)) matrices, an
+    ~N(0, 1) embedding, zero norm scales (the identity under (1 + scale))."""
+    k_embed, k_head, k_layers = jax.random.split(rng, 3)
+    d, v = config.embed_dim, config.vocab_size
+    params = {
+        "embed": _dense(k_embed, (v, d), 1.0),
+        **_per_layer(config, k_layers, functools.partial(_layer_init, config)),
+        "final_norm": jnp.zeros((d,), jnp.float32),
+        "lm_head": _dense(k_head, (d, v), d),
+    }
+    return params, param_axes(config)
+
+
+def init_buffers(config: HybridLMConfig, rng: jax.Array):
+    """State that is not trained, shaped like ``params``' layers: the
+    expert layers' score-correction bias. Seeded from another stream
+    than the parameters."""
+    return _per_layer(
+        config, jax.random.fold_in(rng, 1),
+        lambda kinds, key: FFNS[kinds[1]].buffers(config, key),
+    )
+
+
+def buffer_axes(config: HybridLMConfig):
+    return jax.tree_util.tree_map(
+        lambda x: (None,) * x.ndim,
+        jax.eval_shape(lambda: init_buffers(config, jax.random.key(0))),
+    )
+
+
+def _mix(config, mixer, p, x):
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["mixer_norm"]).astype(config.compute_dtype)
+        x = x + MIXERS[mixer].apply(config, p["mixer"], h).astype(x.dtype)
+        return with_logical_constraint(x, ("batch", "seq", "embed"))
+
+
+def _feed(config, ffn, p, buffers, x):
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["ffn_norm"]).astype(config.compute_dtype)
+        y, counters = FFNS[ffn].apply(config, p["ffn"], buffers, h)
+        x = x + y.astype(x.dtype)
+        return with_logical_constraint(x, ("batch", "seq", "embed")), counters
+
+
+def _layer(config, kinds, p, buffers, x):
+    """One block. The scopes land in every op's ``op_name``, forward and
+    backward, and nest under the ``attn`` / ``mlp`` the dense model has,
+    so ``benchmark/trace_reduce.py`` buckets them as it does those."""
+    mixer, ffn = kinds
+    return _feed(config, ffn, p, buffers, _mix(config, mixer, p, x))
+
+
+def run_pattern(config: HybridLMConfig, params, buffers, x):
+    """Leading layers unrolled, then the period scanned over its
+    repeats. Returns (hidden, summed counters ``[len(COUNTERS)]``)."""
+
+    def block(kinds):
+        """The layer, rematerialised in the backward, keeping its
+        projection matmuls' outputs and a KDA scan's output. A policy
+        object a layer, not one for all: with a shared one the compiled
+        step read 12,398 tokens/s where this reads 12,470 (my chip runs,
+        PR 31; the layers' remat bodies are then laid out differently)."""
+        policies = jax.checkpoint_policies
+        keep = policies.save_from_both_policies(
+            policies.save_only_these_names("kda_out"),
+            policies.dots_with_no_batch_dims_saveable,
+        )
+        return jax.checkpoint(
+            functools.partial(_layer, config, kinds), policy=keep
+        )
+
+    counters = _no_counters()
+    for kinds, p, b in zip(
+        config.leading, params["leading"], buffers["leading"]
+    ):
+        x, c = block(kinds)(p, b, x)
+        counters += c
+    blocks = [block(kinds) for kinds in config.period]
+
+    def period(x, layers):
+        total = _no_counters()
+        for fn, (p, b) in zip(blocks, layers):
+            x, c = fn(p, b, x)
+            total += c
+        return x, total
+
+    x, per_repeat = jax.lax.scan(
+        period, x, list(zip(params["period"], buffers["period"]))
+    )
+    return x, counters + jnp.sum(per_repeat, axis=0)
+
+
+def forward_hidden(config, params, buffers, tokens):
+    x = llama.embed_tokens(config, params, tokens)
+    return run_pattern(config, params, buffers, x)
+
+
+def loss_fn(config, params, batch, buffers=None, attention_fn=None):
+    """batch: {"tokens": [b, s + 1]} -> (loss, {"ce", "aux", "counters"}).
+    No position enters but through KDA's recurrence and convolutions, so
+    there is no ``positions`` and no ``attention_fn`` to choose."""
+    del attention_fn
+    tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    x, counters = forward_hidden(config, params, buffers, tokens)
+    ce = llama.head_loss(config, params, x, targets, batch.get("mask"))
+    aux = jnp.zeros((), jnp.float32)
+    return ce, {
+        "ce": ce, "aux": aux,
+        "counters": dict(zip(COUNTERS, counters)),
+    }

@@ -19,7 +19,7 @@ import dataclasses
 import functools
 import math
 import os
-from typing import Any, Dict, Tuple
+from typing import Any, ClassVar, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,7 @@ from dlrover_tpu.parallel.sharding import with_logical_constraint
 
 @dataclasses.dataclass(frozen=True)
 class TpuLMConfig:
+    kind: ClassVar[str] = "llama"    # models.model_for: which module
     vocab_size: int = 32000
     embed_dim: int = 4096
     n_layers: int = 32
@@ -767,23 +768,15 @@ def resolve_ce_path(config, n_tokens: int) -> str:
     return "dense"
 
 
-def loss_fn(config, params, batch, attention_fn=None):
-    """batch: {"tokens": [b,s+1]} — next-token LM loss.
-
-    Uses the fused blockwise CE (ops/fused_ce.py) whenever applicable so
-    the [b, s, vocab] f32 logits never materialize; falls back to
-    ``forward`` + ``cross_entropy`` for pipelined or vocab-sharded runs
-    (see ``resolve_ce_path``). Set DLROVER_TPU_FUSED_CE=off to force
-    the unfused path.
-    """
-    tokens = batch["tokens"][:, :-1]
-    targets = batch["tokens"][:, 1:]
-    if resolve_ce_path(config, tokens.size) == "fused":
+def head_loss(config, params, x, targets, mask=None):
+    """Token-mean CE (+ z-loss) of the stack's output ``x [b, s, d]``:
+    final norm, then the fused blockwise CE (ops/fused_ce.py) whenever
+    applicable so the [b, s, vocab] f32 logits never materialize, else
+    the head and ``cross_entropy`` (see ``resolve_ce_path``). Shared by
+    every model whose stack ends in this head (models/hybrid.py)."""
+    if resolve_ce_path(config, targets.size) == "fused":
         from dlrover_tpu.ops.fused_ce import fused_cross_entropy
 
-        x, aux = forward_hidden(
-            config, params, tokens, attention_fn=attention_fn
-        )
         with jax.named_scope("vocab"):
             h = final_hidden(config, params, x)
             # Long sequences cap the CE row chunk at 4096: the 8192-row
@@ -793,18 +786,36 @@ def loss_fn(config, params, batch, attention_fn=None):
             # and times identically there, and at long context the CE is
             # ~2% of the step). Short-sequence large-batch runs keep the
             # measured-fastest auto chunk.
-            ce = fused_cross_entropy(
+            return fused_cross_entropy(
                 h,
                 params["lm_head"].astype(config.compute_dtype),
                 targets,
-                batch.get("mask"),
-                block_rows=4096 if tokens.shape[1] >= 32768 else None,
+                mask,
+                block_rows=4096 if targets.shape[1] >= 32768 else None,
             )
-    else:
+    logits = unembed(config, params, x)
+    with jax.named_scope("vocab"):
+        return cross_entropy(logits, targets, mask)
+
+
+def loss_fn(config, params, batch, attention_fn=None):
+    """batch: {"tokens": [b,s+1]} — next-token LM loss through
+    ``head_loss``; pipelined runs take ``forward`` + ``cross_entropy``
+    (the pipeline owns its unembed placement). Set
+    DLROVER_TPU_FUSED_CE=off to force the unfused path.
+    """
+    tokens = batch["tokens"][:, :-1]
+    targets = batch["tokens"][:, 1:]
+    if config.pp_stages > 1:
         logits, aux = forward(
             config, params, tokens, attention_fn=attention_fn
         )
         with jax.named_scope("vocab"):
             ce = cross_entropy(logits, targets, batch.get("mask"))
+    else:
+        x, aux = forward_hidden(
+            config, params, tokens, attention_fn=attention_fn
+        )
+        ce = head_loss(config, params, x, targets, batch.get("mask"))
     loss = ce + config.moe_aux_weight * aux
     return loss, {"ce": ce, "aux": aux}

@@ -667,3 +667,204 @@ def moe_mlp_dropless_ep(
         dropped_fraction=jnp.zeros((), jnp.float32),
     )
     return out, metrics
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert layer (sigmoid router, no capacity)
+# ---------------------------------------------------------------------------
+
+
+class ShareCounters(NamedTuple):
+    rows_held: jnp.ndarray     # (token, k) pairs the held experts computed
+    rows_max: jnp.ndarray      # the busiest held expert's rows
+    rows_dropped: jnp.ndarray  # 0: the row buffer holds any routing
+
+
+def router_scores(x, router_w):
+    """``sigmoid(x W)`` in float32, x ``[n, d]`` -> ``[n, experts]``."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+
+
+def sigmoid_route(x, router_w, router_bias, top_k: int, scaling: float):
+    """Sigmoid router with a score-correction bias: ``s = sigmoid(x W)``,
+    the ``top_k`` experts by ``s + bias`` (the bias selects and does not
+    weight), weights ``scaling * s_e / sum of the chosen s``. All in
+    float32. x ``[n, d]`` -> (experts ``[n, k]`` int32, weights ``[n, k]``)."""
+    scores = router_scores(x, router_w)
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(router_bias.astype(jnp.float32)),
+        top_k,
+    )
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def _take_or_zero(x, idx):
+    """``x[idx]``; an index of ``len(x)`` reads a row of zeros."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _gather_with_inverse(x, idx, back):
+    """``_take_or_zero(x, idx)`` whose transpose is a gather too:
+    ``back [len(x), m]`` names the up-to-``m`` output rows that read each
+    row of ``x`` (``len(idx)``: none), so the backward sums ``m`` gathers
+    where XLA's own transpose would scatter-add."""
+    return _take_or_zero(x, idx)
+
+
+def _gwi_fwd(x, idx, back):
+    return _take_or_zero(x, idx), (idx, back)
+
+
+def _gwi_bwd(res, g):
+    idx, back = res
+    dx = sum(
+        _take_or_zero(g, back[:, j]).astype(jnp.float32)
+        for j in range(back.shape[1])
+    ).astype(g.dtype)
+    return (
+        dx,
+        np.zeros(idx.shape, jax.dtypes.float0),
+        np.zeros(back.shape, jax.dtypes.float0),
+    )
+
+
+_gather_with_inverse.defvjp(_gwi_fwd, _gwi_bwd)
+
+
+def moe_mlp_share(
+    x,
+    router_w,      # [embed, all experts]
+    router_bias,   # [all experts], a buffer
+    w_gate,        # [held, embed, mlp]
+    w_up,          # [held, embed, mlp]
+    w_down,        # [held, mlp, embed]
+    first: int,
+    top_k: int,
+    scaling: float = 1.0,
+    interpret=None,
+):
+    """The part of a routed expert layer that the experts
+    ``first .. first + held - 1`` give: x ``[batch, seq, embed]`` ->
+    (out, :class:`ShareCounters`).
+
+    Every token is routed over ALL experts (the router's width and
+    ``top_k`` are the model's; the weights are normalised over all
+    ``top_k`` chosen experts, held or not). The (token, k) pairs whose
+    expert lives here are sorted to the front by expert and run through
+    one grouped matmul a projection (megablox ``gmm``, which visits the
+    tiles of the rows its groups name and zeroes the rest); what the
+    absent experts would have added is left out. No capacity and no
+    drop: a token picks distinct experts, so at most ``n * min(top_k,
+    held)`` pairs can land here, and a row buffer of that size holds any
+    routing. An evenly loaded share fills ``held / all`` of ``n *
+    top_k`` rows, so the step first asks whether the pairs fit a buffer
+    of four times that (``lax.cond`` on the count) and takes the full
+    one only when they do not: the same function either way, and never
+    a dropped row (on the chip, 8,192 tokens x top-8 of 256, 8 held, a
+    five-layer step: 12,465 tokens/s through the usual buffer, 9,352
+    with every pair sent here and the full one taken, loss and
+    gradients on the reference both ways; PERF.md, PR 31). This is
+    what expert parallelism asks of a shard
+    before and after its exchange; no exchange happens here.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, s, d = x.shape
+    held = w_gate.shape[0]
+    e_all = router_w.shape[-1]
+    n = b * s
+    xf = x.reshape(n, d)
+    with jax.named_scope("router"):
+        experts, weights = sigmoid_route(
+            xf, router_w, router_bias, top_k, scaling
+        )
+    with jax.named_scope("experts"):
+        local = experts.reshape(n * top_k) - first
+        is_held = (local >= 0) & (local < held)
+        local = jnp.where(is_held, local, held)        # absent: sorted last
+        order = jnp.argsort(local, stable=True)
+        inv_order = jnp.argsort(order)
+        group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
+            jnp.int32
+        )
+        n_held = jnp.sum(group_sizes)
+        # A row tile no larger than the rows an evenly loaded expert
+        # gets: a group pays for whole tiles.
+        even = n * top_k // e_all
+        tm = min(max(even, 128), 512)
+
+        def through(rows):
+            return _share_rows(
+                xf, weights, w_gate, w_up, w_down, order, inv_order,
+                is_held, group_sizes, n_held, rows, tm, interpret,
+            )
+
+        def padded(rows):
+            return (rows + tm - 1) // tm * tm if rows >= tm else rows
+
+        most = padded(n * min(top_k, held))
+        usual = padded(4 * even * held)
+        if usual < most:
+            out = jax.lax.cond(
+                n_held <= usual, lambda: through(usual),
+                lambda: through(most),
+            )
+        else:
+            out = through(most)
+        out = with_logical_constraint(
+            out.reshape(b, s, d), ("batch", "seq", "embed")
+        )
+    counters = ShareCounters(
+        rows_held=n_held,
+        rows_max=jnp.max(group_sizes),
+        rows_dropped=jnp.zeros((), jnp.int32),
+    )
+    return out, counters
+
+
+def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
+                is_held, group_sizes, n_held, rows, tm, interpret):
+    """``moe_mlp_share``'s expert compute through a buffer of ``rows``
+    rows (>= ``n_held``): gather the held pairs' tokens by expert, two
+    grouped matmuls, weight each row, and sum a token's rows back."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    n, d = xf.shape
+    top_k = weights.shape[1]
+    f = w_gate.shape[-1]
+    cdt = xf.dtype
+    front = order[:rows] if rows <= order.shape[0] else jnp.pad(
+        order, (0, rows - order.shape[0])
+    )
+    # Pair j sits at sorted row inv_order[j]; an absent expert's pair is
+    # past the buffer and reads, or is read as, a row of zeros.
+    at = jnp.where(is_held, inv_order, rows)
+    xs = _gather_with_inverse(
+        xf, front // top_k, at.reshape(n, top_k)
+    )                                                  # [rows, d], by expert
+    w_gu = jnp.concatenate([w_gate.astype(cdt), w_up.astype(cdt)], axis=-1)
+    tm = _tile(rows, cap=tm)
+    hu = gmm(
+        xs, w_gu, group_sizes, preferred_element_type=cdt,
+        interpret=interpret, tiling=(tm, _tile(d), _tile(2 * f)),
+    )
+    act = (jax.nn.silu(hu[:, :f]) * hu[:, f:]).astype(cdt)
+    ys = gmm(
+        act, w_down.astype(cdt), group_sizes, preferred_element_type=cdt,
+        interpret=interpret, tiling=(tm, _tile(f), _tile(d)),
+    )                                              # rows past the groups: 0
+    by_row = _gather_with_inverse(
+        weights.reshape(n * top_k, 1), front, at[:, None]
+    )
+    ys = (ys.astype(jnp.float32) * by_row).astype(cdt)
+    read_by = jnp.where(jnp.arange(rows) < n_held, front, n * top_k)
+    per_pair = _gather_with_inverse(ys, at, read_by[:, None])
+    return jnp.sum(
+        per_pair.reshape(n, top_k, d).astype(jnp.float32), axis=1
+    ).astype(cdt)                                      # back in token order

@@ -66,4 +66,4 @@ def dot_product_attention(
     out = jnp.einsum(
         "bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32)
     )
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)

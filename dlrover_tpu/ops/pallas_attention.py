@@ -6,7 +6,9 @@ kv dimension innermost — TPU grids run sequentially, so the running
 block is written once on the last one. Q/K/V blocks stream HBM→VMEM via
 BlockSpec; the [block_q, block_k] logits tile hits the MXU in the input
 dtype (bf16 at full MXU rate) with f32 accumulation. GQA is handled in
-the index maps (query head -> kv head), never materialized.
+the index maps (query head -> kv head), never materialized. The value
+head size may differ from q/k's (latent attention: 192 / 128): scores
+contract over q/k's, the accumulators and ``o`` / ``dO`` / ``dv`` carry v's.
 
 Backward (FlashAttention-2 style): the forward additionally writes the
 row log-sum-exp ``lse`` ([b*h, sq, 128] lane-broadcast, the layout trick
@@ -139,6 +141,7 @@ def _flash_kernel(
 def _flash_forward(q, k, v, causal, softmax_scale, interpret):
     b, sq, h, d = q.shape
     _, skv, hkv, _ = k.shape
+    dv = v.shape[-1]  # the value head size may differ from q/k's (MLA)
     groups = h // hkv
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
     block_q = _pick_block(sq)
@@ -163,14 +166,15 @@ def _flash_forward(q, k, v, causal, softmax_scale, interpret):
     # fresh sessions — the deltas sat inside the ±8% session-to-session
     # spread, while the non-affine index maps measurably slowed the
     # forward (18.1 -> 19.1 ms). Simple affine maps win.
-    if d % 128 == 0 or h == 1:
+    if (d % 128 == 0 and dv % 128 == 0) or h == 1:
         operands = (
             q.reshape(b, sq, h * d),
             k.reshape(b, skv, hkv * d),
-            v.reshape(b, skv, hkv * d),
+            v.reshape(b, skv, hkv * dv),
         )
-        q_block = (1, block_q, d)
-        kv_block = (1, block_k, d)
+        out_dims = (b, sq, h * dv)
+        q_block, o_block = (1, block_q, d), (1, block_q, dv)
+        k_block, v_block = (1, block_k, d), (1, block_k, dv)
 
         def q_map(bh, qi, ki):
             return (bh // h, qi, bh % h)
@@ -179,7 +183,7 @@ def _flash_forward(q, k, v, causal, softmax_scale, interpret):
             return (bh // h, ki, (bh % h) // groups)
 
         def post(out):
-            return out.reshape(b, sq, h, d)
+            return out.reshape(b, sq, h, dv)
 
     else:
         operands = (
@@ -187,8 +191,9 @@ def _flash_forward(q, k, v, causal, softmax_scale, interpret):
             k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3),
         )
-        q_block = (1, 1, block_q, d)
-        kv_block = (1, 1, block_k, d)
+        out_dims = (b, h, sq, dv)
+        q_block, o_block = (1, 1, block_q, d), (1, 1, block_q, dv)
+        k_block, v_block = (1, 1, block_k, d), (1, 1, block_k, dv)
 
         def q_map(bh, qi, ki):
             return (bh // h, bh % h, qi, 0)
@@ -209,21 +214,21 @@ def _flash_forward(q, k, v, causal, softmax_scale, interpret):
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
+            jax.ShapeDtypeStruct(out_dims, q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32),
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec(q_block, q_map),
-            pl.BlockSpec(kv_block, kv_map),
-            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(k_block, kv_map),
+            pl.BlockSpec(v_block, kv_map),
         ],
         out_specs=(
-            pl.BlockSpec(q_block, q_map),
+            pl.BlockSpec(o_block, q_map),
             pl.BlockSpec((1, block_q, LANES), lambda bh, qi, ki: (bh, qi, 0)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
@@ -409,6 +414,7 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
     out of its per-hop loop and calls this directly."""
     b, h, sq, d = qT.shape
     _, hkv, skv, _ = kT.shape
+    dv = vT.shape[-1]
     groups = h // hkv
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
     # 1024 blocks measure ~10% faster than 512 on v5e at d<=128 (same
@@ -423,8 +429,8 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
     block_k = _pick_block(skv, target=bwd_target)
     nq = sq // block_q
 
-    q_block = (1, 1, block_q, d)
-    kv_block = (1, 1, block_k, d)
+    q_block, do_block = (1, 1, block_q, d), (1, 1, block_q, dv)
+    k_block, v_block = (1, 1, block_k, d), (1, 1, block_k, dv)
     stat_block = (1, block_q, LANES)
 
     # ---- dq: grid (b*h, q_blocks, kv_blocks) --------------------------
@@ -446,9 +452,9 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
         grid=(b * h, nq, skv // block_k),
         in_specs=[
             pl.BlockSpec(q_block, q_map),
-            pl.BlockSpec(kv_block, kv_map),
-            pl.BlockSpec(kv_block, kv_map),
-            pl.BlockSpec(q_block, q_map),
+            pl.BlockSpec(k_block, kv_map),
+            pl.BlockSpec(v_block, kv_map),
+            pl.BlockSpec(do_block, q_map),
             pl.BlockSpec(stat_block, stat_map),
             pl.BlockSpec(stat_block, stat_map),
         ],
@@ -483,19 +489,19 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
         grid=(b * hkv, skv // block_k, groups * nq),
         in_specs=[
             pl.BlockSpec(q_block, q_map2),
-            pl.BlockSpec(kv_block, kv_map2),
-            pl.BlockSpec(kv_block, kv_map2),
-            pl.BlockSpec(q_block, q_map2),
+            pl.BlockSpec(k_block, kv_map2),
+            pl.BlockSpec(v_block, kv_map2),
+            pl.BlockSpec(do_block, q_map2),
             pl.BlockSpec(stat_block, stat_map2),
             pl.BlockSpec(stat_block, stat_map2),
         ],
         out_specs=(
-            pl.BlockSpec(kv_block, kv_map2),
-            pl.BlockSpec(kv_block, kv_map2),
+            pl.BlockSpec(k_block, kv_map2),
+            pl.BlockSpec(v_block, kv_map2),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qT, kT, vT, doT, lse, di)

@@ -127,7 +127,11 @@ class ElasticTrainer:
         data_wait_s: float = 0.0,
         ckpt_block_s: float = 0.0,
         allreduce_wait_s: float = 0.0,
+        metrics=None,
     ):
+        """``metrics``: the step's own ``metrics`` (``make_train_step``),
+        whose model counters (expert rows held / busiest / dropped) ride
+        the ``train.step`` span as attrs when a tracer is armed."""
         self.global_step += steps
         # Chaos site: "mid-step" from the job's perspective — the step
         # landed on device but nothing downstream (reports, checkpoints
@@ -149,7 +153,7 @@ class ElasticTrainer:
             )
         self._emit_step_spans(
             step_time_s * max(steps, 1),
-            data_wait_s, allreduce_wait_s, ckpt_block_s,
+            data_wait_s, allreduce_wait_s, ckpt_block_s, metrics,
         )
         # Progress beacon for the rolling-deadline hang watchdog (§29):
         # one global check when none is installed.
@@ -191,22 +195,27 @@ class ElasticTrainer:
         data_wait_s: float,
         allreduce_wait_s: float,
         ckpt_block_s: float,
+        metrics=None,
     ):
         """Retrospective per-step phase tree: one ``train.step`` root
         per completed step with data-fetch / compute / allreduce-wait /
         ckpt-persist children cut from the durations the caller already
         measured. Phase placement inside the step is the canonical
         order (fetch -> compute -> allreduce -> persist); the exact
-        durations ride as attrs. Disarmed: one global check."""
+        durations ride as attrs, and so do the model's step counters
+        (``moe_rows_*``: expert load beside the step's phases) when the
+        step's ``metrics`` holds them. Disarmed: one global check."""
         tracer = tracing.active_tracer()
         if tracer is None:
             return
         end = time.monotonic()
         start = end - max(step_wall_s, 0.0)
-        root = tracer.record_span(
-            "train.step", start, end,
-            attrs={"step": self.global_step, "dp_size": self.dp_size},
+        attrs = {"step": self.global_step, "dp_size": self.dp_size}
+        attrs.update(
+            (k, int(v)) for k, v in (metrics or {}).items()
+            if k.startswith("moe_rows_")
         )
+        root = tracer.record_span("train.step", start, end, attrs=attrs)
         waits = data_wait_s + allreduce_wait_s + ckpt_block_s
         compute_s = max(step_wall_s - waits, 0.0)
         cursor = start

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import llama
+from dlrover_tpu.models import model_for
 from dlrover_tpu.parallel.sharding import (
     DEFAULT_RULES,
     logical_to_spec,
@@ -63,16 +63,28 @@ def make_optimizer(tc: TrainConfig) -> optax.GradientTransformation:
 # ---------------------------------------------------------------------------
 
 
+def _has_buffers(model) -> bool:
+    return hasattr(model, "init_buffers")
+
+
+def _buffers_kw(model, buffers):
+    """``loss_fn``'s extra argument for a model that has buffers."""
+    return {"buffers": buffers} if _has_buffers(model) else {}
+
+
 def state_specs(
-    config: llama.TpuLMConfig,
+    config,
     optimizer: optax.GradientTransformation,
     rules=DEFAULT_RULES,
 ) -> Dict[str, Any]:
-    """PartitionSpec pytree for {"params", "opt_state", "step"}."""
+    """PartitionSpec pytree for {"params", "opt_state", "step"} — and
+    "buffers" where the config's model (``models.model_for``) has state
+    that is not trained: beside the parameters, outside the optimizer."""
+    model = model_for(config)
     pshapes = jax.eval_shape(
-        lambda: llama.init_params(config, jax.random.key(0))[0]
+        lambda: model.init_params(config, jax.random.key(0))[0]
     )
-    param_specs = spec_tree(llama.param_axes(config), rules)
+    param_specs = spec_tree(model.param_axes(config), rules)
     opt_shapes = jax.eval_shape(optimizer.init, pshapes)
     opt_specs = optax.tree_map_params(
         optimizer,
@@ -82,7 +94,10 @@ def state_specs(
         transform_non_params=lambda _: P(),
         is_leaf=lambda x: isinstance(x, P),
     )
-    return {"params": param_specs, "opt_state": opt_specs, "step": P()}
+    specs = {"params": param_specs, "opt_state": opt_specs, "step": P()}
+    if _has_buffers(model):
+        specs["buffers"] = spec_tree(model.buffer_axes(config), rules)
+    return specs
 
 
 def state_shardings(specs, mesh: Mesh):
@@ -96,7 +111,7 @@ def batch_spec(rules=DEFAULT_RULES) -> P:
 
 
 def init_train_state(
-    config: llama.TpuLMConfig,
+    config,
     optimizer: optax.GradientTransformation,
     mesh: Mesh,
     rng: jax.Array,
@@ -106,13 +121,18 @@ def init_train_state(
     specs = state_specs(config, optimizer, rules)
     shardings = state_shardings(specs, mesh)
 
+    model = model_for(config)
+
     def init(rng):
-        params, _ = llama.init_params(config, rng)
-        return {
+        params, _ = model.init_params(config, rng)
+        state = {
             "params": params,
             "opt_state": optimizer.init(params),
             "step": jnp.zeros((), jnp.int32),
         }
+        if _has_buffers(model):
+            state["buffers"] = model.init_buffers(config, rng)
+        return state
 
     with mesh:
         state = jax.jit(init, out_shardings=shardings)(rng)
@@ -125,7 +145,7 @@ def init_train_state(
 
 
 def make_train_step(
-    config: llama.TpuLMConfig,
+    config,
     tc: TrainConfig,
     optimizer: optax.GradientTransformation,
     mesh: Mesh,
@@ -137,8 +157,10 @@ def make_train_step(
 
     batch["tokens"]: [grad_accum * micro_batch, seq+1] int32. The leading
     dim is split into ``grad_accum`` scan iterations; gradients average in
-    f32.
+    f32. ``metrics`` carries the model's own counters (the ``counters``
+    of its loss's aux, summed over the microbatches) beside the loss.
     """
+    model = model_for(config)
     attention_fn = None
     if dict(mesh.shape).get("sp", 1) > 1:
         # Sequence-parallel mesh: attention must hop K/V around the sp
@@ -147,23 +169,28 @@ def make_train_step(
         from dlrover_tpu.ops.ring_attention import make_ring_attention
 
         attention_fn = make_ring_attention(mesh, rules)
-    _loss = loss_fn or (
-        lambda params, batch: llama.loss_fn(
-            config, params, batch, attention_fn=attention_fn
+
+    def _loss(params, batch, buffers):
+        if loss_fn is not None:
+            return loss_fn(params, batch)
+        return model.loss_fn(
+            config, params, batch, attention_fn=attention_fn,
+            **_buffers_kw(model, buffers),
         )
-    )
+
     specs = state_specs(config, optimizer, rules)
     shardings = state_shardings(specs, mesh)
     bspec = NamedSharding(mesh, batch_spec(rules))
 
-    def single_grad(params, micro):
-        (loss, metrics), grads = jax.value_and_grad(_loss, has_aux=True)(
-            params, micro
+    def single_grad(params, micro, buffers):
+        (loss, aux), grads = jax.value_and_grad(_loss, has_aux=True)(
+            params, micro, buffers
         )
-        return loss, metrics, grads
+        return loss, aux.get("counters", {}), grads
 
     def step(state, batch):
         params = state["params"]
+        buffers = state.get("buffers")
         tokens = batch["tokens"]
         ga = tc.grad_accum
         if ga > 1:
@@ -176,8 +203,8 @@ def make_train_step(
             micro_tokens = tokens.reshape(ga, mb, tokens.shape[-1])
 
             def accum(carry, mt):
-                loss, metrics, grads = single_grad(
-                    params, {"tokens": mt}
+                loss, counters, grads = single_grad(
+                    params, {"tokens": mt}, buffers
                 )
                 g_acc, l_acc = carry
                 g_acc = jax.tree_util.tree_map(
@@ -185,16 +212,19 @@ def make_train_step(
                     g_acc,
                     grads,
                 )
-                return (g_acc, l_acc + loss / ga), metrics
+                return (g_acc, l_acc + loss / ga), counters
 
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
-            (grads, loss), _ = jax.lax.scan(
+            (grads, loss), counters = jax.lax.scan(
                 accum, (zeros, jnp.zeros((), jnp.float32)), micro_tokens
             )
+            counters = {k: jnp.sum(v, axis=0) for k, v in counters.items()}
         else:
-            loss, _, grads = single_grad(params, {"tokens": tokens})
+            loss, counters, grads = single_grad(
+                params, {"tokens": tokens}, buffers
+            )
 
         # named_scope: lands in the compiled HLO's op_name, which
         # benchmark/trace_reduce.py buckets device time by.
@@ -204,15 +234,17 @@ def make_train_step(
             )
             new_params = optax.apply_updates(params, updates)
             grad_norm = optax.global_norm(grads)
-        new_state = {
-            "params": new_params,
-            "opt_state": new_opt,
-            "step": state["step"] + 1,
-        }
+        new_state = dict(
+            state,
+            params=new_params,
+            opt_state=new_opt,
+            step=state["step"] + 1,
+        )
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,
             "step": new_state["step"],
+            **counters,
         }
         return new_state, metrics
 
@@ -239,14 +271,19 @@ def make_train_step(
 def make_eval_step(config, mesh, rules=DEFAULT_RULES):
     bspec = NamedSharding(mesh, batch_spec(rules))
 
-    def ev(params, batch):
-        loss, metrics = llama.loss_fn(config, params, batch)
+    model = model_for(config)
+
+    def ev(params, batch, buffers):
+        loss, metrics = model.loss_fn(
+            config, params, batch, **_buffers_kw(model, buffers)
+        )
         return metrics["ce"]
 
-    jitted = jax.jit(ev, in_shardings=(None, {"tokens": bspec}))
+    jitted = jax.jit(ev, in_shardings=(None, {"tokens": bspec}, None))
 
-    def run(params, batch):
+    def run(params, batch, buffers=None):
+        """``buffers``: the state's, for a model that has them."""
         with mesh:  # trace inside the mesh so logical constraints apply
-            return jitted(params, batch)
+            return jitted(params, batch, buffers)
 
     return run

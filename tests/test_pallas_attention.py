@@ -43,6 +43,39 @@ def test_flash_matches_dense(causal, h, hkv, d):
     )
 
 
+@pytest.mark.parametrize(
+    "h,hkv,d,dv",
+    [
+        (4, 4, 192, 128),  # latent attention: q/k 128 + 64, values 128
+        (4, 2, 24, 16),    # GQA with a smaller value head
+        (1, 1, 24, 16),    # single head, fold-heads path via h == 1
+    ],
+)
+def test_flash_with_its_own_value_head_size(h, hkv, d, dv):
+    """q/k of one head size, v of another: forward and all three
+    gradients against plain attention."""
+    q, k, _ = _qkv(jax.random.key(2), 2, 64, h, hkv, d)
+    v = jax.random.normal(jax.random.key(3), (2, 64, hkv, dv), jnp.float32)
+    w = jax.random.normal(jax.random.key(4), (2, 64, h, dv), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    flash = make_flash_attention(interpret=True)
+    out = jax.jit(flash)(q, k, v)
+    assert out.shape == (2, 64, h, dv)
+    np.testing.assert_allclose(
+        np.asarray(dot_product_attention(q, k, v)), np.asarray(out),
+        rtol=2e-5, atol=2e-5,
+    )
+    g_ref = jax.grad(loss(dot_product_attention), argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    for a, b in zip(g_ref, g_out):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-5, atol=5e-5
+        )
+
+
 def test_flash_grad_matches_dense():
     q, k, v = _qkv(jax.random.key(1), 1, 32, 4, 2, 8)
 

@@ -91,6 +91,50 @@ def test_flash_attention_backward_compiles(one_chip):
     assert _n_kernels(c) == 3  # fwd + dq + dk/dv
 
 
+def test_flash_attention_with_its_own_value_head_compiles(one_chip):
+    """Latent attention's shapes (q/k 192 = 128 + 64, v 128, 32 heads,
+    8,192 tokens): 192 is no multiple of the lane width, so the kernels
+    take the transposed layout with a full-width minor block."""
+    from dlrover_tpu.ops.pallas_attention import flash_attention
+
+    def sds(d):
+        return jax.ShapeDtypeStruct(
+            (1, 8192, 32, d), jnp.bfloat16, sharding=one_chip
+        )
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v).astype(jnp.float32))
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(192), sds(192), sds(128)
+    ).compile()
+    assert _n_kernels(c) == 3
+
+
+def test_expert_share_compiles_fwd_bwd(one_chip):
+    """``moe_mlp_share`` at the hybrid cell's shape (8 of 256 experts
+    held, top-8, 8,192 tokens of width 2,304): both row buffers' grouped
+    matmuls, under the ``lax.cond`` that picks one."""
+    from dlrover_tpu.models import moe
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, router, w_gate, w_up, w_down):
+        out, _ = moe.moe_mlp_share(
+            x, router, jnp.zeros((256,)), w_gate, w_up, w_down,
+            first=0, top_k=8, scaling=2.446,
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((1, 8192, 2304), jnp.bfloat16), sds((2304, 256)),
+        sds((8, 2304, 1024)), sds((8, 2304, 1024)), sds((8, 1024, 2304)),
+    ).compile()
+    # gmm x2 forward, gmm x2 + tgmm x2 backward, in each of two branches
+    assert _n_kernels(c) == 12
+
+
 @pytest.mark.parametrize("dispatch", [None, "fused", "gmm"])
 def test_moe_dispatch_compiles_fwd_bwd(one_chip, dispatch):
     """``moe_mlp_dropless`` at the bench's MoE shape (e=8, top-2,
