@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from dlrover_tpu.common.log import logger
 from dlrover_tpu.models import llama
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.ops import kda as kda_ops
@@ -207,8 +208,10 @@ def _kda_apply(config, p, h):
         with jax.named_scope("kda_scan"):
             o = kda_ops.kda_chunked(q, k, v, g, beta)
         # Kept across the layer's rematerialisation (``run_pattern``'s
-        # policy): the backward then re-runs the scan once, for its own
-        # residuals, and not a second time for what follows it.
+        # policy): the backward then runs the scan once more, for its own
+        # pullback (the ``jax.numpy`` form re-forms its residuals there,
+        # the kernels walk back over the kept ``kda_states``), and not a
+        # second time for what follows it.
         o = checkpoint_name(o, "kda_out")
         gate = jax.nn.sigmoid(_proj(
             "bsr,rhk->bhsk", _proj("bsd,dr->bsr", h, p["w_g1"]), p["w_g2"]
@@ -475,19 +478,29 @@ def run_pattern(config: HybridLMConfig, params, buffers, x):
 
     def block(kinds):
         """The layer, rematerialised in the backward, keeping its
-        projection matmuls' outputs and a KDA scan's output. A policy
+        projection matmuls' outputs, a KDA scan's output and, where the
+        scan is the kernels, the state entering each chunk (the
+        backward kernel's residual: with both kept, the layer's
+        re-forward runs no scan at all). A policy
         object a layer, not one for all: with a shared one the compiled
         step read 12,398 tokens/s where this reads 12,470 (my chip runs,
         PR 31; the layers' remat bodies are then laid out differently)."""
         policies = jax.checkpoint_policies
         keep = policies.save_from_both_policies(
-            policies.save_only_these_names("kda_out"),
+            policies.save_only_these_names("kda_out", "kda_states"),
             policies.dots_with_no_batch_dims_saveable,
         )
         return jax.checkpoint(
             functools.partial(_layer, config, kinds), policy=keep
         )
 
+    if any(mixer == "kda" for mixer, _ in config.leading + config.period):
+        # Once a trace of the step: which form the scan was built with.
+        logger.info(
+            "layer pattern: delta-rule scan of %d heads x %d is %s",
+            config.kda_heads, config.kda_head_dim,
+            kda_ops.kda_scan_kind(config.kda_head_dim, config.kda_head_dim),
+        )
     counters = _no_counters()
     for kinds, p, b in zip(
         config.leading, params["leading"], buffers["leading"]

@@ -38,16 +38,34 @@ Everything runs in float32 (``PRECISION`` for every matmul: on a TPU a
 float32 matmul is one bf16 pass unless asked otherwise); the unit
 triangular system is solved by (block) forward substitution with its
 inverse's closed form as the backward.
+
+Two ways of staging the one set of equations, chosen from what
+:func:`kda_chunked` can observe (:func:`kda_scan_kind`: platform, head
+size, mesh -- no switch): on a TPU at the published head size (keys and
+values one 128-lane tile) the Pallas kernels of ``ops/kda_kernels.py``,
+forward and backward, which keep a chunk's tensors in VMEM and carry
+the state (its cotangent) across the grid's chunk axis, so that the
+``S' = M S + B`` rewriting and the ``lax.scan`` are not needed there;
+anywhere else the ``jax.numpy`` form of this file
+(:func:`kda_chunked_xla`), which is also the definition the kernels are
+tested against. ``head_groups`` bounds the ``jax.numpy`` form's live
+chunk tensors only: the kernels have none in HBM and walk the heads in
+their grid.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from dlrover_tpu.ops import kda_kernels
+from dlrover_tpu.parallel.sharding import current_mesh
 
 PRECISION = jax.lax.Precision.HIGHEST
-CHUNK = 64       # tokens a chunk (the state's stride)
-SUB_CHUNK = 16   # tokens a sub-chunk (the exact diagonal blocks)
+# One pair of sizes for both forms (the kernels are written for them).
+CHUNK = kda_kernels.CHUNK       # tokens a chunk (the state's stride): 64
+SUB_CHUNK = kda_kernels.SUB     # a sub-chunk (the exact diagonal blocks): 16
 GROUP_TOKENS = 65536   # head-tokens walked at once (``head_groups``)
 
 
@@ -265,14 +283,39 @@ def head_groups(heads: int, seq: int) -> int:
     return heads
 
 
-def kda_chunked(q, k, v, g, beta, chunk=CHUNK, sub=SUB_CHUNK):
+def kda_scan_kind(dk: int, dv: int) -> str:
+    """Which form :func:`kda_chunked` runs, read from the platform and
+    the head's shape alone: ``"pallas"`` (``ops/kda_kernels.py``) on a
+    TPU when keys and values are one whole 128-lane tile, outside a
+    multi-device mesh (GSPMD cannot partition a Mosaic kernel);
+    ``"xla"``, the ``jax.numpy`` form below, anywhere else."""
+    mesh = current_mesh()
+    if (
+        jax.default_backend() == "tpu"
+        and dk == dv == kda_kernels.LANES
+        and (mesh is None or mesh.size == 1)
+    ):
+        return "pallas"
+    return "xla"
+
+
+def kda_chunked(q, k, v, g, beta):
     """The same function of the same arguments as
     :func:`kda_recurrent`, in chunks; any sequence length (the tail is
-    padded with tokens that leave the state alone). The heads are walked
-    a group at a time (:func:`head_groups` of them), each group
-    rematerialised in the backward, so that one group's chunk tensors
-    are live at once and not the layer's (heads are independent; the
-    result is the same)."""
+    padded with tokens that leave the state alone). Which of the two
+    forms below runs is :func:`kda_scan_kind`'s answer."""
+    if kda_scan_kind(k.shape[-1], v.shape[-1]) == "pallas":
+        return kda_chunked_kernels(q, k, v, g, beta)
+    return kda_chunked_xla(q, k, v, g, beta)
+
+
+def kda_chunked_xla(q, k, v, g, beta, chunk=CHUNK, sub=SUB_CHUNK):
+    """:func:`kda_chunked` in plain ``jax.numpy``: the definition the
+    kernels are held to, and what runs off a TPU or at a head size that
+    is not a lane tile. The heads are walked a group at a time
+    (:func:`head_groups` of them), each group rematerialised in the
+    backward, so that one group's chunk tensors are live at once and
+    not the layer's (heads are independent; the result is the same)."""
     b, h, s = beta.shape
     n_groups = head_groups(h, s)
 
@@ -283,6 +326,43 @@ def kda_chunked(q, k, v, g, beta, chunk=CHUNK, sub=SUB_CHUNK):
     one = jax.checkpoint(lambda xs: _kda_chunked(*xs, chunk, sub))
     out = jax.lax.map(one, tuple(split(x) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(out, 0, 1).reshape(b, h, s, out.shape[-1])
+
+
+def kda_chunked_kernels(q, k, v, g, beta, interpret=False):
+    """:func:`kda_chunked` through the Pallas kernels of
+    ``ops/kda_kernels.py`` (head size 128, chunk 64, sub-chunk 16): no
+    chunk tensor in HBM, the heads walked by the grid. ``interpret``
+    runs them off a TPU, for the tests."""
+    s = beta.shape[2]
+    pad = -s % kda_kernels.CHUNK
+
+    def whole_chunks(x):
+        """float32, the tokens zero-padded to whole chunks."""
+        widths = [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 3)
+        return jnp.pad(x.astype(jnp.float32), widths)
+
+    xs = (whole_chunks(x) for x in (q, k, g, v, beta))
+    return _scan_kernels(interpret, *xs)[:, :, :s]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _scan_kernels(interpret, q, k, g, v, beta):
+    return kda_kernels.scan_forward(q, k, g, v, beta, interpret=interpret)[0]
+
+
+def _scan_kernels_fwd(interpret, q, k, g, v, beta):
+    out, states = kda_kernels.scan_forward(
+        q, k, g, v, beta, interpret=interpret
+    )
+    states = checkpoint_name(states, "kda_states")
+    return out, (q, k, g, v, beta, states)
+
+
+def _scan_kernels_bwd(interpret, res, d_out):
+    return kda_kernels.scan_backward(*res, d_out, interpret=interpret)
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
 def _kda_chunked(q, k, v, g, beta, chunk, sub):

@@ -111,6 +111,37 @@ def test_flash_attention_with_its_own_value_head_compiles(one_chip):
     assert _n_kernels(c) == 3
 
 
+def test_kda_scan_kernels_compile_at_the_cells_shape(one_chip):
+    """``kimilinear-train-8k``'s scan (32 heads x 8,192 tokens x 128,
+    float32): on a TPU ``kda_chunked`` is the two Pallas kernels, and
+    both carry the caller's scope into their ``op_name``, the backward
+    too, so ``benchmark/hybrid_scopes`` books them under ``kda_scan``."""
+    from dlrover_tpu.ops import kda
+
+    assert kda.kda_scan_kind(128, 128) == "pallas"
+    x = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32,
+                             sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32,
+                                sharding=one_chip)
+
+    def pulled(*xs):
+        with jax.named_scope("kda_scan"):
+            out, pull = jax.vjp(kda.kda_chunked, *xs)
+        return out, pull(out)
+
+    c = jax.jit(pulled).lower(x, x, x, x, beta).compile()
+    from benchmark import hybrid_scopes
+
+    names = [
+        line.split('op_name="')[1].split('"')[0]
+        for line in c.as_text().splitlines() if "tpu_custom_call" in line
+    ]
+    assert [hybrid_scopes.scope_of(n) for n in names] == ["kda_scan"] * 2
+    assert sorted(n.split("/")[-2] for n in names) == [
+        "kda_scan_bwd", "kda_scan_fwd"
+    ]
+
+
 def test_expert_share_compiles_fwd_bwd(one_chip):
     """``moe_mlp_share`` at the hybrid cell's shape (8 of 256 experts
     held, top-8, 8,192 tokens of width 2,304): both row buffers' grouped
