@@ -21,12 +21,17 @@ engine (serving/engine.py) drives the same layer blocks with genuinely
 ragged per-slot fills — the masking (_append_free_attention,
 dot_product_attention positions) is per-row either way.
 
-MoE caveat: expert capacity is derived from the LOCAL sequence length
-of each call (models/moe.py expert_capacity), so token-drop behavior
-differs between a full teacher-forced forward and prefill+decode —
-single-token decode steps clamp capacity to 1 and never drop. This is
-the standard train/infer capacity asymmetry of capacity-factor MoE,
-not a bug; exact logit parity holds for dense configs only.
+MoE caveat, for the capacity-factor path only (``llama.py``'s expert
+layers through ``moe.moe_mlp``): expert capacity is derived from the
+LOCAL sequence length of each call (``moe.expert_capacity``), so token
+drops differ between a full teacher-forced forward and prefill+decode
+(a single-token step clamps capacity to 1 and never drops): exact logit
+parity holds for dense configs only. The expert layer the ENGINES serve
+is the dropless one (``moe.routed_experts``: every expert held, as
+``models/sparse_lm.py`` calls it): one function for the full forward,
+the prefill chunk and the decode step, no capacity in any of them, so a
+token's expert output is the same in all three
+(``tests/test_keye_serving.py``).
 
     state = ... (restored params)
     out = generate(cfg, params, prompt_tokens, max_new_tokens=64)
@@ -559,12 +564,14 @@ def prepare_decode_params(config, params):
     on v5e with f32 masters = one 1.3GB sweep per step; the cast cost
     amortizes over the whole loop and every per-step read halves) plus
     the fused wqkv/w_gu projections (_fuse_decode_params). Norm scales
-    and the MoE router stay f32 (same precision rule as
+    (``models/sparse_lm.py``'s q/k and index-key norms among them) and
+    the MoE router stay f32 (same precision rule as
     llama.run_layer_stack). Pure jnp: generate()'s jitted run calls it
     traced, the serving engine calls it eagerly once per engine."""
     cdt = config.compute_dtype
     if cdt != jnp.float32:
-        keep = {"attn_norm", "mlp_norm", "router"}
+        keep = {"attn_norm", "mlp_norm", "router", "q_norm", "k_norm",
+                "ik_norm_scale", "ik_norm_bias"}
         params = {
             "embed": params["embed"].astype(cdt),
             "layers": {

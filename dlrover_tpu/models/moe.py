@@ -30,7 +30,7 @@ this is parity-plus for the TPU build.
 """
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -678,6 +678,8 @@ class ShareCounters(NamedTuple):
     rows_held: jnp.ndarray     # (token, k) pairs the held experts computed
     rows_max: jnp.ndarray      # the busiest held expert's rows
     rows_dropped: jnp.ndarray  # 0: the row buffer holds any routing
+    # experts that got a row at all (``routed_experts`` alone counts it)
+    experts_hit: Optional[jnp.ndarray] = None
 
 
 def router_scores(x, router_w):
@@ -700,6 +702,19 @@ def sigmoid_route(x, router_w, router_bias, top_k: int, scaling: float):
     )
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def softmax_route(x, router_w, top_k: int):
+    """Softmax router: ``p = softmax(x W)`` over all experts in float32,
+    the ``top_k`` largest, weights renormalised over the chosen ones.
+    x ``[n, d]`` -> (experts ``[n, k]`` int32, weights ``[n, k]``)."""
+    probs = jax.nn.softmax(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ), axis=-1)
+    chosen, experts = jax.lax.top_k(probs, top_k)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), weights
 
 
@@ -773,24 +788,74 @@ def moe_mlp_share(
     what expert parallelism asks of a shard
     before and after its exchange; no exchange happens here.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b, s, d = x.shape
-    held = w_gate.shape[0]
-    e_all = router_w.shape[-1]
-    n = b * s
-    xf = x.reshape(n, d)
+    xf = x.reshape(b * s, d)
     with jax.named_scope("router"):
         experts, weights = sigmoid_route(
             xf, router_w, router_bias, top_k, scaling
         )
+    out, group_sizes, n_held = _routed_share(
+        xf, (b, s, d), experts, weights, router_w.shape[-1], w_down,
+        w_gate=w_gate, w_up=w_up, first=first, interpret=interpret,
+    )
+    return out, ShareCounters(
+        rows_held=n_held,
+        rows_max=jnp.max(group_sizes),
+        rows_dropped=jnp.zeros((), jnp.int32),
+    )
+
+
+def routed_experts(x, experts, weights, w_gu, w_down, n_experts: int,
+                   group_offset=None, interpret=None):
+    """A whole dropless expert layer for a caller that has routed: x
+    ``[batch, seq, embed]``, ``experts`` / ``weights [n, k]`` (every
+    expert is held) -> (out, :class:`ShareCounters`). ``w_gu [groups,
+    embed, 2 * mlp]`` holds gate and up side by side (a decode step
+    reads its weights once; joining them a call would read and write
+    them all again) and ``w_down [groups, mlp, embed]``. ``groups`` may
+    be more than ``n_experts``: expert ``e`` is group ``group_offset +
+    e`` of the stack (a layer's offset into all layers' experts, traced
+    in a layer loop), and the other groups get no row. The same
+    function for a full forward, a prefill chunk and a decode step: no
+    capacity in any of them, so a token's experts give it the same
+    output whatever else the call carries."""
+    b, s, d = x.shape
+    out, group_sizes, n_held = _routed_share(
+        x.reshape(b * s, d), (b, s, d), experts, weights, n_experts,
+        w_down, w_gu=w_gu, held=n_experts, group_offset=group_offset,
+        interpret=interpret,
+    )
+    return out, ShareCounters(
+        rows_held=n_held,
+        rows_max=jnp.max(group_sizes),
+        rows_dropped=jnp.zeros((), jnp.int32),
+        experts_hit=jnp.sum(group_sizes > 0, dtype=jnp.int32),
+    )
+
+
+def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
+                  w_gate=None, w_up=None, w_gu=None, first=0, held=None,
+                  group_offset=None, interpret=None):
+    """The expert compute both entries share: ``xf [n, embed]`` and its
+    routing -> (out ``shape``, rows a weight group got, their sum). Experts
+    ``first .. first + held - 1`` are computed (``held``: every group of
+    ``w_down`` when None); expert ``first`` is weight group
+    ``group_offset`` (0 when None)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n, top_k = experts.shape
+    groups = w_down.shape[0]
+    if held is None:
+        held = groups
     with jax.named_scope("experts"):
         local = experts.reshape(n * top_k) - first
         is_held = (local >= 0) & (local < held)
-        local = jnp.where(is_held, local, held)        # absent: sorted last
+        if group_offset is not None:
+            local = local + group_offset
+        local = jnp.where(is_held, local, groups)      # absent: sorted last
         order = jnp.argsort(local, stable=True)
         inv_order = jnp.argsort(order)
-        group_sizes = jnp.bincount(local, length=held + 1)[:held].astype(
+        group_sizes = jnp.bincount(local, length=groups + 1)[:groups].astype(
             jnp.int32
         )
         n_held = jnp.sum(group_sizes)
@@ -802,7 +867,7 @@ def moe_mlp_share(
         def through(rows):
             return _share_rows(
                 xf, weights, w_gate, w_up, w_down, order, inv_order,
-                is_held, group_sizes, n_held, rows, tm, interpret,
+                is_held, group_sizes, n_held, rows, tm, interpret, w_gu,
             )
 
         def padded(rows):
@@ -810,7 +875,7 @@ def moe_mlp_share(
 
         most = padded(n * min(top_k, held))
         usual = padded(4 * even * held)
-        if usual < most:
+        if 0 < usual < most:   # (0: fewer pairs than experts)
             out = jax.lax.cond(
                 n_held <= usual, lambda: through(usual),
                 lambda: through(most),
@@ -818,18 +883,14 @@ def moe_mlp_share(
         else:
             out = through(most)
         out = with_logical_constraint(
-            out.reshape(b, s, d), ("batch", "seq", "embed")
+            out.reshape(shape), ("batch", "seq", "embed")
         )
-    counters = ShareCounters(
-        rows_held=n_held,
-        rows_max=jnp.max(group_sizes),
-        rows_dropped=jnp.zeros((), jnp.int32),
-    )
-    return out, counters
+    return out, group_sizes, n_held
 
 
 def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
-                is_held, group_sizes, n_held, rows, tm, interpret):
+                is_held, group_sizes, n_held, rows, tm, interpret,
+                w_gu=None):
     """``moe_mlp_share``'s expert compute through a buffer of ``rows``
     rows (>= ``n_held``): gather the held pairs' tokens by expert, two
     grouped matmuls, weight each row, and sum a token's rows back."""
@@ -837,7 +898,7 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
 
     n, d = xf.shape
     top_k = weights.shape[1]
-    f = w_gate.shape[-1]
+    f = w_down.shape[-2]
     cdt = xf.dtype
     front = order[:rows] if rows <= order.shape[0] else jnp.pad(
         order, (0, rows - order.shape[0])
@@ -848,7 +909,12 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     xs = _gather_with_inverse(
         xf, front // top_k, at.reshape(n, top_k)
     )                                                  # [rows, d], by expert
-    w_gu = jnp.concatenate([w_gate.astype(cdt), w_up.astype(cdt)], axis=-1)
+    if w_gu is None:
+        w_gu = jnp.concatenate(
+            [w_gate.astype(cdt), w_up.astype(cdt)], axis=-1
+        )
+    else:
+        w_gu = w_gu.astype(cdt)
     tm = _tile(rows, cap=tm)
     hu = gmm(
         xs, w_gu, group_sizes, preferred_element_type=cdt,

@@ -121,10 +121,11 @@ class _Flight:
     how this token ends it (``"finished"`` / ``"truncated"``): the host
     knows that by count when it launches, and gave the slot away."""
 
-    __slots__ = ("nxt", "rows", "first", "first_row")
+    __slots__ = ("nxt", "rows", "first", "first_row", "aux")
 
     def __init__(self):
         self.nxt = None           # [slots] int32 on the device
+        self.aux = None           # a sparse model's step: expert counts
         self.rows: List[tuple] = []
         self.first = None         # int32 scalar on the device
         self.first_row: Optional[tuple] = None
@@ -528,6 +529,10 @@ class ServingEngine:
         self._leaving: Dict[int, Request] = {}
         self._done: List[Request] = []
         self._no_first = jnp.zeros((), jnp.int32)
+        # A model whose attention reads a learned selection of its cache
+        # (models/sparse_lm.py): how many rows a query keeps, else 0.
+        self._index_topk = getattr(config, "index_topk", 0)
+        self._moe_rows_dropped = 0
 
     def _fresh_pool(self):
         shape = (
@@ -769,9 +774,13 @@ class ServingEngine:
         if st is not None:
             st.mark("decode_prep", at)
             st.counts["n_decoding"] = len(decoding)
-            st.counts["kv_rows"] = int(
-                sum(self._lengths[r.slot] for r in decoding)
-            )
+            fills = [int(self._lengths[r.slot]) for r in decoding]
+            st.counts["kv_rows"] = sum(fills)
+            if self._index_topk:
+                # The K/V rows the launch reads: a slot's selection.
+                st.counts["selected_rows"] = sum(
+                    min(f, self._index_topk) for f in fills
+                )
 
     def run_until_idle(self, max_iters: int = 100000) -> List[Request]:
         """Drive step() until nothing is pending; returns all finished."""
@@ -962,14 +971,17 @@ class ServingEngine:
             return self._no_first, np.int32(-1)
         return cur.first, np.int32(cur.first_row[1])
 
-    def _launched_decode(self, decoding: List[Request], nxt) -> None:
+    def _launched_decode(self, decoding: List[Request], nxt,
+                         aux=None) -> None:
         """The decode launch is enqueued: advance every slot in it by
         the row its fed token lands in, and settle BY COUNT who decodes
         on. A request whose token in flight is its last (of
         ``max_new_tokens``, or with no row left to feed it back) leaves
-        its slot here."""
+        its slot here. ``aux``: what the program returned after its
+        tokens (kvpool/sparse.py), fetched with them."""
         cur = self._cur
         cur.nxt = nxt
+        cur.aux = aux
         for r in decoding:
             slot = r.slot
             self._lengths[slot] += 1   # the fed token's KV lands
@@ -989,7 +1001,17 @@ class ServingEngine:
         them out: the first token of the prompt it finished, one token
         to every request its decode launch carried. First-token time is
         stamped here, when the host holds the token."""
-        nxt, first = jax.device_get((flight.nxt, flight.first))
+        nxt, first, *aux = jax.device_get(
+            (flight.nxt, flight.first) if flight.aux is None
+            else (flight.nxt, flight.first, flight.aux)
+        )
+        if aux:
+            (aux,) = aux
+            # [experts hit (mean over layers), expert rows dropped] of
+            # the launch whose tokens arrive here.
+            self._moe_rows_dropped += int(aux[1])
+            if self._step_trace is not None:
+                self._step_trace.counts["experts_hit"] = float(aux[0])
         if flight.first_row is not None:
             req, slot, end = flight.first_row
             req.first_token_ts = time.monotonic()

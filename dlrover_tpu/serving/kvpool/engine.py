@@ -131,6 +131,10 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
     is no part of it: on the v5e the decode kernel was ahead of the
     gather down to 4 slots x 576 rows and 16 slots x 128 rows (PERF.md
     §6, PR 25), the chunk kernel at every ``start`` (PR 28)."""
+    if getattr(config, "index_topk", 0):
+        # A learned selection of the cache (kvpool/sparse.py): index
+        # keys scored through the table, the selected rows gathered.
+        return "sparse_gather"
     if kv_dtype != "fp" or not _on_tpu():
         return "xla_gather"
     # Pallas costs ~1.2 s to import: only a process that may run the
@@ -469,70 +473,55 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
     return prefill
 
 
-def _build_cow_copy(counts, quantized: bool = False):
-    """Device block copy src -> dst (both K and V, all layers, plus
-    the scale pools for int8): the copy-on-write primitive. src/dst
-    are traced scalars — privatizing any block never retraces."""
+def _build_cow_copy(counts, n_pools: int):
+    """Device block copy src -> dst in EVERY pool array, all layers (K
+    and V; the scale pools of an int8 cache; the index keys of a sparse
+    model): the copy-on-write primitive. What a block holds is the
+    model's; that a block operation moves all of it is the pool's
+    (docs/DESIGN.md §37). src/dst are traced scalars — privatizing any
+    block never retraces."""
 
-    def cow(k, v, src, dst):
+    def cow(*args):
         counts["cow"] += 1  # traces only
-        k = k.at[:, dst].set(k[:, src])
-        v = v.at[:, dst].set(v[:, src])
-        return k, v
+        pools, (src, dst) = args[:n_pools], args[n_pools:]
+        return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
-    def cow_q8(k, v, ks, vs, src, dst):
-        counts["cow"] += 1  # traces only
-        k = k.at[:, dst].set(k[:, src])
-        v = v.at[:, dst].set(v[:, src])
-        ks = ks.at[:, dst].set(ks[:, src])
-        vs = vs.at[:, dst].set(vs[:, src])
-        return k, v, ks, vs
-
-    return cow_q8 if quantized else cow
+    return cow
 
 
-def _build_import_scatter(counts, quantized: bool = False):
+def _build_import_scatter(counts, n_pools: int):
     """Migration import (kvpool/migrate, §36): land one migrated
-    block's rows — host data, shape [L, block_size, kh, hd] (+ scale
-    rows for int8) — at pool row ``dst``. ``dst`` is a traced scalar
-    like the COW src/dst, so importing any number of requests into any
-    blocks never retraces."""
+    block's rows — host data, one ``[L, block_size, ...]`` array a pool
+    array, in the pools' order — at pool row ``dst``. ``dst`` is a
+    traced scalar like the COW src/dst, so importing any number of
+    requests into any blocks never retraces."""
 
-    def imp(k, v, dk, dv, dst):
+    def imp(*args):
         counts["imp"] += 1  # traces only
-        k = k.at[:, dst].set(dk.astype(k.dtype))
-        v = v.at[:, dst].set(dv.astype(v.dtype))
-        return k, v
+        pools, rows = args[:n_pools], args[n_pools:2 * n_pools]
+        dst = args[2 * n_pools]
+        return tuple(
+            p.at[:, dst].set(r.astype(p.dtype))
+            for p, r in zip(pools, rows)
+        )
 
-    def imp_q8(k, v, ks, vs, dk, dv, dks, dvs, dst):
-        counts["imp"] += 1  # traces only
-        k = k.at[:, dst].set(dk)
-        v = v.at[:, dst].set(dv)
-        ks = ks.at[:, dst].set(dks)
-        vs = vs.at[:, dst].set(dvs)
-        return k, v, ks, vs
-
-    return imp_q8 if quantized else imp
+    return imp
 
 
-def _build_export_gather(counts, quantized: bool = False):
+def _build_export_gather(counts, n_pools: int):
     """Migration export (kvpool/migrate, §36): read one block's rows
-    out of the pool at row ``src`` — the gather mirror of the import
-    scatter. ``src`` is a traced scalar, so exporting a request of ANY
-    block count is n calls of one compiled program; the jnp
+    out of every pool array at row ``src`` — the gather mirror of the
+    import scatter. ``src`` is a traced scalar, so exporting a request
+    of ANY block count is n calls of one compiled program; the jnp
     fancy-index alternative (``k[:, ids]``) recompiles per block-count
     and stalled the serve loop ~400ms per new shape on CPU. No pool
     donation: the request stays live on the source until released."""
 
-    def exp(k, v, src):
+    def exp(*args):
         counts["exp"] += 1  # traces only
-        return k[:, src], v[:, src]
+        return tuple(p[:, args[n_pools]] for p in args[:n_pools])
 
-    def exp_q8(k, v, ks, vs, src):
-        counts["exp"] += 1  # traces only
-        return k[:, src], v[:, src], ks[:, src], vs[:, src]
-
-    return exp_q8 if quantized else exp
+    return exp
 
 
 def _build_paged_verify(config, slots: int, max_blocks: int,
@@ -794,28 +783,39 @@ def _paged_steps_for(
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
-    pool_args = (0, 1, 2, 3) if quantized else (0, 1)
-    decode = jax.jit(
-        _build_paged_decode(config, slots, max_blocks, block_size,
-                            counts, quantized=quantized, attn=attn),
-        donate_argnums=pool_args,
-    )
-    prefill = jax.jit(
-        _build_paged_prefill(config, max_blocks, block_size, chunk,
-                             counts, quantized=quantized, attn=attn),
-        donate_argnums=pool_args,
-    )
-    cow = jax.jit(
-        _build_cow_copy(counts, quantized=quantized),
-        donate_argnums=pool_args,
-    )
+    if attn == "sparse_gather":
+        if quantized:
+            raise ValueError("a sparse model's pool is not quantized")
+        # Imported here: the module builds on this one.
+        from dlrover_tpu.serving.kvpool import sparse
+
+        pool_args = (0, 1, 2)                 # K, V, index keys
+        build_decode = sparse.build_decode(
+            config, slots, max_blocks, block_size, counts
+        )
+        build_prefill = sparse.build_prefill(
+            config, max_blocks, block_size, chunk, counts
+        )
+    else:
+        pool_args = (0, 1, 2, 3) if quantized else (0, 1)
+        build_decode = _build_paged_decode(
+            config, slots, max_blocks, block_size, counts,
+            quantized=quantized, attn=attn,
+        )
+        build_prefill = _build_paged_prefill(
+            config, max_blocks, block_size, chunk, counts,
+            quantized=quantized, attn=attn,
+        )
+    decode = jax.jit(build_decode, donate_argnums=pool_args)
+    prefill = jax.jit(build_prefill, donate_argnums=pool_args)
+    n_pools = len(pool_args)
+    cow = jax.jit(_build_cow_copy(counts, n_pools), donate_argnums=pool_args)
     imp = jax.jit(
-        _build_import_scatter(counts, quantized=quantized),
-        donate_argnums=pool_args,
+        _build_import_scatter(counts, n_pools), donate_argnums=pool_args
     )
     # No donation: export reads the pools and the source keeps serving
     # from them until the importer acks.
-    exp = jax.jit(_build_export_gather(counts, quantized=quantized))
+    exp = jax.jit(_build_export_gather(counts, n_pools))
     return _PagedSteps(prefill=prefill, decode=decode, cow=cow,
                        imp=imp, exp=exp, trace_counts=counts,
                        pool_attention=attn)
@@ -906,6 +906,7 @@ class PagedServingEngine(ServingEngine):
         self._prefix_hits = 0
         self._prefix_misses = 0
         self._prefix_hit_blocks = 0
+        self._prefix_hit_tokens = 0   # prompt rows no chunk had to run
         # The base __init__ builds the value pools via _fresh_pool();
         # the int8 scale pools pair up right after it returns (nothing
         # in between touches them).
@@ -918,6 +919,7 @@ class PagedServingEngine(ServingEngine):
             spec_draft_layers=spec_draft_layers,
         )
         self._kscale, self._vscale = self._fresh_scales()
+        self._ki = self._fresh_index_keys()
         # Block watermark: only admit a request the pool can hold
         # (prompt + first decode block) counting evictable cache as
         # free — otherwise bursty arrivals thrash preemptions, each
@@ -932,9 +934,12 @@ class PagedServingEngine(ServingEngine):
         )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
-            "(%s KV), decode and prefill attention %s",
+            "(%s KV%s), decode and prefill attention %s",
             slots, max_len, self.num_blocks, block_size,
-            kv_cache_dtype, self.pool_attention,
+            kv_cache_dtype,
+            f" + index keys [{self._index_dim}] a row, top-"
+            f"{config.index_topk}" if self._index_dim else "",
+            self.pool_attention,
         )
         self.metrics.annotate(
             "serving_engine_built", slots=slots, max_len=max_len,
@@ -954,11 +959,14 @@ class PagedServingEngine(ServingEngine):
         # 1.94x-per-token capacity lever the equal-HBM bench exploits.
         from dlrover_tpu.ops.kv_quant import bytes_per_head_row
 
-        self._block_bytes = int(
+        itemsize = jnp.dtype(config.compute_dtype).itemsize
+        self._index_block_bytes = int(
+            config.n_layers * block_size * self._index_dim * itemsize
+        )
+        self._block_bytes = self._index_block_bytes + int(
             2 * config.n_layers * block_size * config.n_kv_heads
             * bytes_per_head_row(
-                config.head_dim, kv_cache_dtype,
-                jnp.dtype(config.compute_dtype).itemsize,
+                config.head_dim, kv_cache_dtype, itemsize,
             )
         )
         self.metrics.kv_blocks_total.set(self._allocator.managed)
@@ -968,6 +976,15 @@ class PagedServingEngine(ServingEngine):
     @property
     def _quantized(self) -> bool:
         return self.kv_cache_dtype == "int8"
+
+    @property
+    def _index_dim(self) -> int:
+        """Width of the pool's index-key rows: the model's, 0 for a
+        model whose attention reads its whole cache."""
+        return (
+            self.config.index_dim
+            if getattr(self.config, "index_topk", 0) else 0
+        )
 
     @property
     def pool_attention(self) -> str:
@@ -990,19 +1007,33 @@ class PagedServingEngine(ServingEngine):
 
     def _pools(self):
         """The donated-pool argument tuple every compiled program
-        leads with: (k, v) for fp, (k, v, k_scale, v_scale) for int8.
-        Call sites splat this and hand the returned tuple back to
-        :meth:`_set_pools` — ONE argument list per program, whatever
-        the dtype."""
+        leads with: (k, v) for fp, (k, v, k_scale, v_scale) for int8,
+        (k, v, index keys) for a sparse model. Call sites splat this
+        and hand the returned tuple back to :meth:`_set_pools` — ONE
+        argument list per program, whatever a block holds."""
         if self._quantized:
             return (self._k, self._v, self._kscale, self._vscale)
+        if self._index_dim:
+            return (self._k, self._v, self._ki)
         return (self._k, self._v)
 
     def _set_pools(self, pools) -> None:
         if self._quantized:
             self._k, self._v, self._kscale, self._vscale = pools
+        elif self._index_dim:
+            self._k, self._v, self._ki = pools
         else:
             self._k, self._v = pools
+
+    def _fresh_index_keys(self):
+        """The index-key pool of a sparse model (None otherwise), paired
+        with every value-pool rebuild like the int8 scales."""
+        if not self._index_dim:
+            return None
+        return jnp.zeros(
+            (self.config.n_layers, self.num_blocks, self.block_size,
+             self._index_dim), self.config.compute_dtype,
+        )
 
     def _fresh_scales(self):
         """(k_scale, v_scale) pools for the int8 cache — (None, None)
@@ -1034,8 +1065,9 @@ class PagedServingEngine(ServingEngine):
         # Both ways a launch is fed: the host's tokens and a chunk's
         # first token, then the vector that launch returned.
         fed = jnp.asarray(np.zeros(self.slots, np.int32))
+        n_pools = len(pools)
         for first, first_slot in ((first, 0), (self._no_first, -1)):
-            *pools, fed = self._steps.decode(
+            out = self._steps.decode(
                 *pools, self._params,
                 jnp.asarray(np.zeros((self.slots, self.max_blocks),
                                      np.int32)),
@@ -1044,6 +1076,7 @@ class PagedServingEngine(ServingEngine):
                 jnp.asarray(np.zeros(self.slots, np.float32)),
                 self._rng, np.int32(0), first, np.int32(first_slot),
             )
+            pools, fed = out[:n_pools], out[n_pools]
         pools = self._steps.cow(*pools, np.int32(0), np.int32(0))
         blk_shape = (
             self.config.n_layers, self.block_size,
@@ -1056,9 +1089,14 @@ class PagedServingEngine(ServingEngine):
                 *pools, z8, z8, zs, zs, np.int32(0)
             )
         else:
-            # Import hands dequantized f32 host rows (kvpool/migrate).
+            # Import hands dequantized f32 host rows (kvpool/migrate),
+            # and a sparse model's index keys as the pool keeps them.
             zf = jnp.zeros(blk_shape, jnp.float32)
-            pools = self._steps.imp(*pools, zf, zf, np.int32(0))
+            extra = [
+                jnp.zeros(p.shape[:1] + p.shape[2:], p.dtype)
+                for p in pools[2:]
+            ]
+            pools = self._steps.imp(*pools, zf, zf, *extra, np.int32(0))
         # Export gather (non-donating): warm so the first migration
         # out of this engine never stalls the serve loop on a compile.
         jax.block_until_ready(self._steps.exp(*pools, np.int32(0)))
@@ -1085,6 +1123,7 @@ class PagedServingEngine(ServingEngine):
         del pools
         self._k, self._v = self._fresh_pool()
         self._kscale, self._vscale = self._fresh_scales()
+        self._ki = self._fresh_index_keys()
         self._trace_snapshot = self._all_trace_counts()
 
     # ---- block bookkeeping -------------------------------------------------
@@ -1122,7 +1161,7 @@ class PagedServingEngine(ServingEngine):
             except BlockPoolExhausted:
                 missing = n - self._allocator.free_count()
                 if self._cache is not None and self._cache.evict_lru(
-                    missing
+                    missing, must_free=True
                 ):
                     continue
                 victim = self._pick_preemption_victim(requester)
@@ -1240,6 +1279,12 @@ class PagedServingEngine(ServingEngine):
         self._tables[slot, :len(hit)] = hit
         req.prefill_pos = start
         self._lengths[slot] = start
+        self._prefix_hit_tokens += start
+        if self._step_trace is not None:
+            counts = self._step_trace.counts
+            counts["prefix_hit_tokens"] = (
+                counts.get("prefix_hit_tokens", 0) + start
+            )
         self.metrics.annotate(
             "serving_prefix_hit", rid=req.rid, blocks=len(hit),
             resumed_at=start,
@@ -1257,6 +1302,7 @@ class PagedServingEngine(ServingEngine):
         # prefix cache, tables, int8 scale pools) restart from scratch.
         self._k, self._v = self._fresh_pool()
         self._kscale, self._vscale = self._fresh_scales()
+        self._ki = self._fresh_index_keys()
         self._allocator = BlockAllocator(self.num_blocks, reserved=1)
         if self._cache is not None:
             self._cache = PrefixCache(
@@ -1342,15 +1388,18 @@ class PagedServingEngine(ServingEngine):
         for r in decoding:
             active[r.slot] = True
         self._mark_decode_prep(decoding)
-        *pools, nxt = self._steps.decode(
-            *self._pools(), self._params, _h2d(self._tables),
+        pools = self._pools()
+        out = self._steps.decode(
+            *pools, self._params, _h2d(self._tables),
             _h2d(self._lengths), self._fed_tokens(),
             jnp.asarray(active), _h2d(self._temps),
             self._rng, np.int32(self._step_idx), *self._fed_first(),
         )
-        self._set_pools(pools)
+        self._set_pools(out[:len(pools)])
         self._mark("decode_launch")
-        self._launched_decode(decoding, nxt)
+        # A sparse model's step hands back its expert counts after the
+        # tokens; they are fetched with them, a step later.
+        self._launched_decode(decoding, *out[len(pools):])
 
     # ---- speculative decode hooks (§35) ------------------------------------
 
@@ -1408,6 +1457,16 @@ class PagedServingEngine(ServingEngine):
         )
         stats["cow_copies"] = self._allocator.cow_copies_total
         stats["pool_attention"] = self.pool_attention
+        if self._index_dim:
+            # The third per-token array: its share of the bytes above,
+            # and the whole array's size on the device.
+            stats["index_bytes_in_use"] = (
+                (stats["used"] + stats["cached"]) * self._index_block_bytes
+            )
+            stats["index_pool_bytes"] = (
+                self.num_blocks * self._index_block_bytes
+            )
+            stats["moe_rows_dropped"] = self._moe_rows_dropped
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value
@@ -1418,6 +1477,7 @@ class PagedServingEngine(ServingEngine):
             stats["prefix_hits"] = self._prefix_hits
             stats["prefix_misses"] = self._prefix_misses
             stats["prefix_hit_blocks"] = self._prefix_hit_blocks
+            stats["prefix_hit_tokens"] = self._prefix_hit_tokens
             stats["prefix_hit_rate"] = round(
                 self._prefix_hits / lookups if lookups else 0.0, 4
             )
@@ -1433,3 +1493,11 @@ class PagedServingEngine(ServingEngine):
                 f"free+used+cached {total} != managed "
                 f"{self._allocator.managed}: {stats}"
             )
+        # Every pool array is addressed by the same block ids: they
+        # agree on how many blocks there are and how long a block is.
+        for pool in self._pools():
+            if pool.shape[:3] != self._k.shape[:3]:
+                raise AssertionError(
+                    f"a pool array of {pool.shape[:3]} beside K "
+                    f"{self._k.shape[:3]}: one table cannot address both"
+                )

@@ -28,9 +28,13 @@ without killing in-flight decodes).
   clock — the ``serving.migrate`` span lands between the (source-side)
   prefill and the local decode.
 
-Payload layout: ``MAGIC | u32 header_len | json header | kv wire``
-with the kv wire from :func:`ops.kv_quant.kv_to_wire` (its own
-self-describing header carries dtype + shapes). Wall-clock export
+Payload layout: ``MAGIC | u32 header_len | json header | [index keys]
+| kv wire`` with the kv wire from :func:`ops.kv_quant.kv_to_wire` (its
+own self-describing header carries dtype + shapes). A sparse model's
+blocks also hold index keys (kvpool/sparse.py): they travel as the pool
+keeps them, bit for bit (the header's ``index`` gives dtype, shape and
+byte count), because which rows a query selects hangs on their exact
+values, and a block that arrived without them would be read as zeros. Wall-clock export
 stamps bound the migration pause across processes on one host.
 """
 
@@ -105,6 +109,14 @@ def export_request(engine, req: Request,
         )
     else:
         wire = kv_to_wire(k_rows, v_rows)
+    index_bytes, index_meta = b"", None
+    if engine._index_dim:
+        ki_rows = np.stack([np.asarray(r[2]) for r in rows], axis=1)
+        index_bytes = np.ascontiguousarray(ki_rows).view(np.uint8).tobytes()
+        index_meta = {
+            "dtype": str(ki_rows.dtype), "shape": list(ki_rows.shape),
+            "nbytes": len(index_bytes),
+        }
     admit_ts = req.admit_ts if req.admit_ts is not None else (
         req.submit_ts
     )
@@ -122,6 +134,7 @@ def export_request(engine, req: Request,
         "n_blocks": len(blocks),
         "block_size": engine.block_size,
         "src_kv_dtype": engine.kv_cache_dtype,
+        "index": index_meta,
         # Source-side phase durations, for timeline reconstruction on
         # the destination clock (monotonic stamps don't cross
         # processes; durations do).
@@ -137,7 +150,7 @@ def export_request(engine, req: Request,
     }
     hdr = json.dumps(header).encode()
     return b"".join(
-        [MIGRATE_MAGIC, struct.pack("<I", len(hdr)), hdr, wire]
+        [MIGRATE_MAGIC, struct.pack("<I", len(hdr)), hdr, index_bytes, wire]
     )
 
 
@@ -188,9 +201,27 @@ def import_request(engine, payload: bytes,
     t_in = time.monotonic()
     header = peek_header(payload)
     (hlen,) = struct.unpack_from("<I", payload, 4)
-    kq, vq, ks, vs, _ = kv_from_wire(payload[8 + hlen:])
+    index_meta = header.get("index")
+    n_index = index_meta["nbytes"] if index_meta else 0
+    kq, vq, ks, vs, _ = kv_from_wire(payload[8 + hlen + n_index:])
     L, n, bs, kh, hd = kq.shape
     cfg = engine.config
+    ki_rows = None
+    if bool(index_meta) != bool(engine._index_dim):
+        raise MigrationError(
+            "index keys on one side only: wire "
+            f"{'has' if index_meta else 'lacks'} them, engine "
+            f"{'keeps' if engine._index_dim else 'does not keep'} them"
+        )
+    if index_meta:
+        ki_rows = np.frombuffer(
+            payload[8 + hlen:8 + hlen + n_index], np.uint8
+        ).view(jnp.dtype(index_meta["dtype"])).reshape(index_meta["shape"])
+        if ki_rows.shape != (L, n, bs, engine._index_dim):
+            raise MigrationError(
+                f"index keys {ki_rows.shape} vs K/V blocks {(L, n, bs)} "
+                f"x {engine._index_dim}"
+            )
     if (L, kh, hd) != (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim):
         raise MigrationError(
             f"model shape mismatch: wire {(L, kh, hd)} vs engine "
@@ -255,9 +286,10 @@ def import_request(engine, payload: bytes,
         kf = kq.astype(np.float32) * ks[..., None]
         vf = vq.astype(np.float32) * vs[..., None]
         for i, dst in enumerate(blocks):
+            extra = () if ki_rows is None else (jnp.asarray(ki_rows[:, i]),)
             engine._set_pools(engine._steps.imp(
                 *engine._pools(),
-                jnp.asarray(kf[:, i]), jnp.asarray(vf[:, i]),
+                jnp.asarray(kf[:, i]), jnp.asarray(vf[:, i]), *extra,
                 np.int32(dst),
             ))
     if engine._cache is not None:

@@ -23,7 +23,8 @@ evictable (evicting a mid-chain entry would orphan its suffix —
 unreachable entries silently pinning blocks forever), and eviction
 drops the cache's reference, freeing the block once no slot still
 points at it. ``evict_lru`` is also the allocator's relief valve: the
-engine calls it before preempting a request when the pool runs dry.
+engine calls it before preempting a request when the pool runs dry, and
+then takes only leaves no live slot still holds (``must_free``).
 """
 
 from collections import OrderedDict
@@ -80,8 +81,13 @@ class PrefixCache:
         bs = self.block_size
         keys: List[Tuple[Tuple, Tuple[int, ...]]] = []
         parent: Optional[Tuple] = _ROOT
-        for k in range(len(prompt) // bs):
-            block = tuple(int(t) for t in prompt[k * bs:(k + 1) * bs])
+        # One conversion for the whole prompt: a 32k-token document is
+        # 512 blocks, and an ``int()`` a token was most of an admission.
+        tokens = prompt.tolist() if hasattr(prompt, "tolist") else [
+            int(t) for t in prompt
+        ]
+        for k in range(len(tokens) // bs):
+            block = tuple(tokens[k * bs:(k + 1) * bs])
             key = (hash((parent, block)), k)
             keys.append((key, block))
             parent = key
@@ -147,18 +153,28 @@ class PrefixCache:
 
     # ---- eviction ----------------------------------------------------------
 
-    def evict_lru(self, n_blocks: int) -> int:
+    def evict_lru(self, n_blocks: int, must_free: bool = False) -> int:
         """Release up to ``n_blocks`` cache references, oldest LEAF
         first (never a mid-chain entry — an orphaned suffix would pin
-        blocks unreachably). Returns how many entries were evicted; the
-        underlying blocks free only once no slot references them."""
+        blocks unreachably). Returns how many entries were evicted.
+
+        ``must_free`` (the allocator's relief valve): only leaves whose
+        block the cache ALONE still references are taken, so every
+        eviction frees its block. A leaf a live slot also holds frees
+        nothing when dropped, and dropping it uncovers its parent: a dry
+        pool would otherwise eat a long shared chain from its tail while
+        the slots reading it keep every block allocated, and the next
+        request for that chain prefills it again."""
         evicted = 0
         while evicted < n_blocks:
             victim = None
             for key, entry in self._entries.items():
-                if not entry.children:
-                    victim = entry
-                    break
+                if entry.children:
+                    continue
+                if must_free and self._alloc.refcount(entry.block_id) > 1:
+                    continue
+                victim = entry
+                break
             if victim is None:
                 break
             del self._entries[victim.key]

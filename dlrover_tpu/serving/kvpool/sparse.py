@@ -1,0 +1,308 @@
+"""The paged decode and prefill programs of a model whose attention
+reads a learned selection of its cache (``models/sparse_lm.py``).
+
+The pool holds a THIRD per-token array under the same block tables, the
+index keys ``[layers, num_blocks, block_size, index_dim]``: each layer
+scores the slot's index keys through its table, selects, and reads only
+the selected K/V rows (``ops/sparse_attention.py``):
+
+- the decode step (``[slots, 1]`` queries) gathers a slot's index keys
+  (one small row a token), finds its ``index_topk`` rows and gathers
+  those K/V rows straight from the stacked pool: the K/V a step reads
+  is ``topk`` rows a slot, whatever its fill;
+- the prefill chunk (``[1, chunk]`` queries whose own keys are causal
+  and whose prefix lives in the pool) gathers the slot's view of all
+  three arrays, lays the chunk's own rows into it and attends under the
+  selection as a mask: its queries pick ``chunk`` different sets.
+
+Both are append-free like the dense in-place programs: the new rows of
+all layers land after the layer scan (one row a slot, or the chunk's
+blocks). Below ``index_topk`` rows the selection is the causal mask and
+both equal full attention. The programs keep the names ``step`` and
+``prefill`` (a trace names a device op by its program), and the decode
+step returns, after the tokens, ``[experts hit (mean over layers),
+expert rows dropped]`` for the host to fetch with them.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models import generate as gen_lib
+from dlrover_tpu.models import llama
+from dlrover_tpu.models import sparse_lm
+from dlrover_tpu.ops import sparse_attention as sa
+from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+
+
+def _at_layer(pool, layer, *index):
+    """``pool[layer, *index]`` (``index``: arrays of one shape) as ONE
+    gather over the stacked pool. With the layer a traced scalar the
+    indexing slices the layer's whole pool out first (a 0.3 GB copy a
+    layer and a step for K and for V: 18 of the decode step's 44 ms on
+    the chip; PERF.md §6, PR 33); as an array of the others' shape it is
+    one more coordinate of the gather."""
+    return pool[(jnp.full_like(index[0], layer),) + index]
+
+
+def _land_rows(pool, rows, blocks, offsets):
+    """``rows [L, n, ...]`` into ``pool`` at ``(blocks [n], offsets
+    [n])`` of every layer: one scatter of rows (of whole blocks, and the
+    compiler re-lays the whole pool, there and back)."""
+    return pool.at[:, blocks, offsets].set(rows.astype(pool.dtype))
+
+
+def _layer(config, params, p, layer, x, positions, attend):
+    """One decoder block with the cache behind ``attend(q, k, v, q_idx,
+    k_idx, w)``; returns the new rows for the caller to land."""
+    residual = x
+    with jax.named_scope("attn"):
+        q, k, v, q_idx, k_idx, w = sparse_lm.attention_inputs(
+            config, p, x, positions
+        )
+        attn = attend(q, k, v, q_idx, k_idx, w)
+        x = llama.attention_out(config, p, attn, residual)
+    x, counters = sparse_lm.expert_mlp(
+        config, p, x, params["layers"], layer
+    )
+    return x, (k, v, k_idx), counters
+
+
+def decode_select(config, ki, layer, tables, lengths, block_size: int,
+                  q_idx, k_idx, w):
+    """Score a layer's index keys through the tables and select: every
+    slot's query (at position ``lengths``) over its pool rows and its
+    own new row -> (``idx [slots, topk]`` logical rows, ``valid [slots,
+    topk]``)."""
+    slots, max_blocks = tables.shape
+    max_len = max_blocks * block_size
+    topk = min(config.index_topk, max_len)
+    at = jnp.minimum(lengths, max_len - 1)
+    with jax.named_scope("index"):
+        view = _at_layer(ki, layer, tables).reshape(slots, max_len, -1)
+        view = view.at[jnp.arange(slots), at].set(
+            k_idx[:, 0].astype(view.dtype)
+        )
+        scores = sa.index_scores(q_idx, w, view)[:, 0]
+    with jax.named_scope("select"):
+        visible = jnp.arange(max_len)[None, :] <= at[:, None]
+        return sa.select_indices(scores, visible, topk)
+
+
+def decode_attend(config, k, v, ki, layer, tables, lengths,
+                  block_size: int):
+    """The decode step's ``attend`` for one layer: select, gather the
+    selected K/V rows from the stacked pool (the query's own row comes
+    from the layer's hands) and attend over them."""
+    max_len = tables.shape[1] * block_size
+    at = jnp.minimum(lengths, max_len - 1)
+
+    def attend(q, k_new, v_new, q_idx, k_idx, w):
+        idx, valid = decode_select(
+            config, ki, layer, tables, lengths, block_size, q_idx, k_idx, w
+        )
+        with jax.named_scope("sparse"):
+            blk = jnp.take_along_axis(tables, idx // block_size, axis=1)
+            off = idx % block_size
+            own = (idx == at[:, None])[..., None, None]
+            k_sel = jnp.where(
+                own, k_new.astype(k.dtype), _at_layer(k, layer, blk, off)
+            )
+            v_sel = jnp.where(
+                own, v_new.astype(v.dtype), _at_layer(v, layer, blk, off)
+            )
+            return sa.gathered_attention(q[:, 0], k_sel, v_sel, valid)[:, None]
+
+    return attend
+
+
+def _slot_view(pool, layer, table_row, new, start, block_size: int):
+    """One slot's logical rows of ``pool`` with the chunk's own rows
+    ``new [1, chunk, ...]`` laid in at ``start``. K and V are gathered
+    ROW by row: gathered block by block, what reads the view next (a
+    head's keys) hands its layout up through the gather, and the
+    compiler re-lays the whole pool to suit it."""
+    if pool.ndim == 4:                     # index keys: block by block
+        rows = _at_layer(pool, layer, table_row)
+        rows = rows.reshape((-1,) + rows.shape[2:])
+    else:
+        at = jnp.arange(table_row.shape[0] * block_size)
+        rows = _at_layer(
+            pool, layer, table_row[at // block_size], at % block_size
+        )
+    return jax.lax.dynamic_update_slice_in_dim(
+        rows, new[0].astype(rows.dtype), start, axis=0
+    )
+
+
+# Queries a prefill chunk scores, selects and attends for at a time:
+# a sub-block whose rows all lie at or past ``n_valid`` (the padding of
+# a short last chunk) is skipped.
+CHUNK_QUERY_BLOCK = 128
+
+
+def chunk_select(config, ki_view, at, q_idx, w):
+    """A block of chunk queries' selection as a mask ``[rows,
+    max_len]``: queries at logical positions ``at [rows]`` over the
+    slot's view of the index keys (the chunk's own laid in)."""
+    max_len = ki_view.shape[0]
+    with jax.named_scope("index"):
+        scores = sa.index_scores(q_idx, w, ki_view)
+    with jax.named_scope("select"):
+        visible = jnp.arange(max_len)[None, :] <= at[:, None]
+        return sa.select_mask(
+            scores, visible, min(config.index_topk, max_len)
+        )
+
+
+def chunk_attend(config, k, v, ki, layer, table_row, start,
+                 block_size: int, n_valid=None):
+    """The prefill chunk's ``attend`` for one layer: the chunk's queries
+    (positions ``start ...``) over the slot's rows below ``start`` and
+    the chunk's own, a block of queries at a time: select, then attend
+    over the slot's view under the selection as a mask. Rows at or past
+    ``n_valid`` (None: none) are padding: their output is never read,
+    and a block of nothing else is left at zero."""
+
+    def attend(q, k_new, v_new, q_idx, k_idx, w):
+        chunk = q.shape[1]
+        sub = min(CHUNK_QUERY_BLOCK, chunk)
+        if chunk % sub:
+            sub = chunk
+        k_view, v_view, ki_view = (
+            _slot_view(pool, layer, table_row, new, start, block_size)
+            for pool, new in ((k, k_new), (v, v_new), (ki, k_idx))
+        )
+
+        def block(lo):
+            def run():
+                take = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                    a[0], lo, sub, axis=0
+                )
+                mask = chunk_select(
+                    config, ki_view, start + lo + jnp.arange(sub),
+                    take(q_idx), take(w),
+                )
+                with jax.named_scope("sparse"):
+                    return sa.masked_attention(take(q), k_view, v_view, mask)
+
+            if n_valid is None:
+                return run()
+            return jax.lax.cond(
+                lo < n_valid, run,
+                lambda: jnp.zeros((sub,) + q.shape[2:], q.dtype),
+            )
+
+        out = jax.lax.map(block, jnp.arange(0, chunk, sub))
+        return out.reshape((1, chunk) + q.shape[2:])
+
+    return attend
+
+
+def decode_forward(config, k, v, ki, params, tables, lengths, tokens,
+                   block_size: int):
+    """All layers for one token a slot: float32 ``logits [slots,
+    vocab]``, the new rows ``(k, v, k_idx)`` each ``[L, slots, 1, ...]``
+    and per layer the experts hit and the expert rows dropped."""
+    positions = lengths[:, None]
+    x = llama.embed_tokens(config, params, tokens[:, None])
+
+    def body(carry, layer_in):
+        # The pools are closed over WHOLE, as in the dense in-place
+        # programs: the gathers pick their rows from all layers'.
+        pl, layer = layer_in
+        y, new, c = _layer(
+            config, params, pl, layer, carry, positions,
+            decode_attend(config, k, v, ki, layer, tables, lengths,
+                          block_size),
+        )
+        return y, (new, c.experts_hit, c.rows_dropped)
+
+    x, (news, hit, dropped) = sparse_lm.scan_layers(config, params, body, x)
+    return llama.unembed(config, params, x)[:, 0], news, hit, dropped
+
+
+def chunk_forward(config, k, v, ki, params, tokens, table_row, start,
+                  block_size: int, n_valid=None):
+    """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
+    ``start ...``: the final hidden states ``[1, chunk, d]`` and the
+    chunk's new rows ``(k, v, k_idx)`` each ``[L, chunk, ...]``. Rows at
+    or past ``n_valid`` are padding (:func:`chunk_attend`)."""
+    positions = (
+        start + jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    )[None, :]
+    x = llama.embed_tokens(config, params, tokens)
+
+    def body(carry, layer_in):
+        pl, layer = layer_in
+        y, (k_new, v_new, ki_new), _ = _layer(
+            config, params, pl, layer, carry, positions,
+            chunk_attend(config, k, v, ki, layer, table_row, start,
+                         block_size, n_valid),
+        )
+        return y, (k_new[0], v_new[0], ki_new[0])
+
+    return sparse_lm.scan_layers(config, params, body, x)
+
+
+def build_decode(config, slots: int, max_blocks: int, block_size: int,
+                 counts):
+    max_len = max_blocks * block_size
+
+    def step(k, v, ki, params, tables, lengths, tokens, active, temps,
+             rng, step_idx, first=0, first_slot=-1):
+        counts["decode"] += 1  # traces only
+        tokens = _place_first(tokens, first, first_slot)
+        logits, (k_news, v_news, ki_news), hit, dropped = decode_forward(
+            config, k, v, ki, params, tables, lengths, tokens, block_size
+        )
+        write = jnp.minimum(lengths, max_len - 1)
+        blk = jnp.take_along_axis(
+            tables, (write // block_size)[:, None], axis=1
+        )[:, 0]
+        blk = jnp.where(active, blk, SENTINEL_BLOCK)
+        off = jnp.where(active, write % block_size, 0)
+        k = _land_rows(k, k_news[:, :, 0], blk, off)
+        v = _land_rows(v, v_news[:, :, 0], blk, off)
+        ki = _land_rows(ki, ki_news[:, :, 0], blk, off)
+        sub = jax.random.fold_in(rng, step_idx * 2)
+        nxt = gen_lib.sample_token(logits, sub, temps)
+        aux = jnp.stack([
+            jnp.mean(hit.astype(jnp.float32)),
+            jnp.sum(dropped).astype(jnp.float32),
+        ])
+        return k, v, ki, jnp.where(active, nxt, tokens), aux
+
+    return step
+
+
+def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
+                  counts):
+    def land(pool, rows, table_row, start):
+        # ``rows`` [L, chunk, ...] at the slot's logical rows start ...
+        at = start + jnp.arange(chunk)
+        return _land_rows(
+            pool, rows, table_row[at // block_size], at % block_size
+        )
+
+    def prefill(k, v, ki, params, tokens, table_row, start, n_valid,
+                temp, rng, step_idx, last=True):
+        counts["prefill"] += 1  # traces only
+        x, (k_news, v_news, ki_news) = chunk_forward(
+            config, k, v, ki, params, tokens, table_row, start, block_size,
+            n_valid,
+        )
+        k = land(k, k_news, table_row, start)
+        v = land(v, v_news, table_row, start)
+        ki = land(ki, ki_news, table_row, start)
+
+        def head():
+            h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+            logits = llama.unembed(config, params, h)[0, 0]
+            sub = jax.random.fold_in(rng, step_idx * 2 + 1)
+            return gen_lib.sample_token(logits, sub, temp)
+
+        first = jax.lax.cond(last, head, lambda: jnp.zeros((), jnp.int32))
+        return k, v, ki, first
+
+    return prefill

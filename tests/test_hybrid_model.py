@@ -294,3 +294,32 @@ def test_a_pattern_must_name_kinds_that_exist():
     ) == jax.tree_util.tree_structure(jax.tree_util.tree_map(
         lambda x: 0, axes, is_leaf=lambda x: isinstance(x, tuple)
     ))
+
+
+def test_the_train_step_lowers_to_the_text_it_had_before_expert_serving(tiny):
+    """PR 33 split ``moe_mlp_share`` so that a served model routes for
+    itself (``moe.routed_experts``); the trained share keeps its
+    signature and, held here, its program: this step's lowered text is
+    byte for byte what the commit before that PR lowers (sha256 of the
+    text, taken there at this size). A PR that means to change the
+    hybrid train step replaces the digest."""
+    import hashlib
+
+    cfg, _, _, tokens = tiny
+    mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
+    tc = ts.TrainConfig(warmup_steps=2)
+    opt = ts.make_optimizer(tc)
+    state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
+    step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
+    with mesh:
+        text = step.jitted.lower(
+            state, {"tokens": jnp.zeros((2, 81), jnp.int32)}
+        ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        "8d3d698e6e67c3b3"
+    out, counters = moe.moe_mlp_share(
+        jnp.zeros((1, 8, 32)), jnp.zeros((32, 16)), jnp.zeros((16,)),
+        *(jnp.zeros(s) for s in ((4, 32, 8), (4, 32, 8), (4, 8, 32))),
+        first=4, top_k=4,
+    )
+    assert counters.experts_hit is None      # the served layer's alone

@@ -1,0 +1,340 @@
+"""The sparse-attention expert model (models/sparse_lm.py) through the
+paged engine against the plain reference (benchmark/reference_keye.py),
+on LOGITS, at a size where the selection is active (``index_topk`` 8,
+contexts of 9-60 rows): chunked prefill + paged decode, a prefix hit
+against the same request served cold, copy-on-write, preemption and
+export/import with the index keys in tow, and the expert layer in a
+full forward, a prefill chunk and a decode step."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_keye as ref
+from dlrover_tpu.models import sparse_lm
+from dlrover_tpu.serving.kvpool import PagedServingEngine, migrate, sparse
+
+CFG = sparse_lm.tiny_config()
+CFG_JSON = dict(
+    num_attention_heads=CFG.n_heads, num_key_value_heads=CFG.n_kv_heads,
+    num_experts_per_tok=CFG.moe_top_k, moe_intermediate_size=CFG.mlp_dim,
+    rope_theta=CFG.rope_theta,
+    sa_config=dict(indexer_num_heads=CFG.index_heads,
+                   indexer_head_dim=CFG.index_dim, topk=CFG.index_topk),
+)
+MAX_LEN, CHUNK, BS = 96, 8, 4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return sparse_lm.init_params(CFG, jax.random.key(7))
+
+
+def _engine(params, cfg=CFG, **kw):
+    kw = {"slots": 3, "max_len": MAX_LEN, "prefill_chunk": CHUNK,
+          "block_size": BS, **kw}
+    return PagedServingEngine(cfg, params, **kw)
+
+
+def _prompt(seed, n):
+    return np.random.default_rng(seed).integers(0, CFG.vocab_size, n).tolist()
+
+
+def _drive(eng):
+    done = []
+    while eng.pending():
+        done.extend(eng.step())
+        eng.check_block_invariants()
+    return done
+
+
+def _ref_logits(params, seq, positions, cfg_json=CFG_JSON, **kw):
+    pad = -len(seq) % 8
+    logits, _ = ref.logits_at(
+        params, jnp.asarray(seq + [0] * pad), jnp.asarray(positions),
+        cfg_json, **kw,
+    )
+    return np.asarray(logits)
+
+
+def _next_logits(eng, req):
+    """The decode program's logits for ``req``'s next token, from the
+    engine's own pool, table and fill (what ``step`` samples from)."""
+    eng._drain("test")
+    tokens = np.zeros(eng.slots, np.int32)
+    tokens[req.slot] = req.tokens[-1]
+    logits, *_ = sparse.decode_forward(
+        eng.config, *eng._pools(), eng._params, jnp.asarray(eng._tables),
+        jnp.asarray(eng._lengths), jnp.asarray(tokens), eng.block_size,
+    )
+    return np.asarray(logits[req.slot])
+
+
+def _step_until(eng, req, n_tokens):
+    while len(req.tokens) + req.inflight < n_tokens:
+        eng.step()
+    eng._drain("test")
+
+
+@pytest.mark.parametrize("n_prompt", [9, 21, 37, 60])
+def test_chunked_prefill_and_paged_decode_give_the_references_logits(
+        params, n_prompt):
+    """Prompt in chunks through the pool, then decode steps: the logits
+    the next step samples from are the plain forward's at that position,
+    after 1 token (the prefill's) and after 5; the tokens emitted are
+    its argmax; and the last chunk's own logits are its rows."""
+    eng = _engine(params)
+    prompt = _prompt(n_prompt, n_prompt)
+    req = eng.submit(prompt, 8)
+    for n_out in (1, 5):
+        _step_until(eng, req, n_out)
+        seq = prompt + req.tokens
+        want = _ref_logits(params, seq, [len(seq) - 1])[0]
+        np.testing.assert_allclose(
+            _next_logits(eng, req), want, rtol=2e-4, atol=2e-4
+        )
+    # the prompt's last chunk, recomputed over the pool's rows below it
+    start = (n_prompt - 1) // CHUNK * CHUNK
+    chunk = np.zeros((1, CHUNK), np.int32)
+    chunk[0, :n_prompt - start] = prompt[start:]
+    x, _ = sparse.chunk_forward(
+        eng.config, *eng._pools(), eng._params, jnp.asarray(chunk),
+        jnp.asarray(eng._tables[req.slot]), jnp.int32(start), BS,
+    )
+    from dlrover_tpu.models import llama
+
+    got = np.asarray(llama.unembed(CFG, eng._params, x))[0, :n_prompt - start]
+    want = _ref_logits(params, prompt, list(range(start, n_prompt)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    _drive(eng)
+    seq = prompt + req.tokens
+    rows = _ref_logits(params, seq, n_prompt - 1 + np.arange(8))
+    assert rows.argmax(-1).tolist() == req.tokens
+    assert eng.kv_stats()["moe_rows_dropped"] == 0
+
+
+def test_selection_matters_at_this_size_and_vanishes_below_topk(params):
+    """Past ``topk`` rows the sparse logits differ from dense attention's
+    (so the tests above would catch a selection that is not applied);
+    with ``topk`` above the context the engine IS full causal
+    attention."""
+    prompt = _prompt(1, 40)
+    sparse_rows = _ref_logits(params, prompt, [39])
+    dense_rows = _ref_logits(params, prompt, [39], dense=True)
+    assert np.abs(sparse_rows - dense_rows).max() > 1e-2
+    wide = sparse_lm.tiny_config(index_topk=MAX_LEN)
+    eng = _engine(params, cfg=wide)
+    req = eng.submit(prompt, 4)
+    _step_until(eng, req, 2)
+    seq = prompt + req.tokens
+    want = _ref_logits(params, seq, [len(seq) - 1], dense=True)[0]
+    np.testing.assert_allclose(
+        _next_logits(eng, req), want, rtol=2e-4, atol=2e-4
+    )
+
+
+def test_a_prefix_hit_is_the_same_request_served_cold(params):
+    """The second request's document part comes from the trie, K, V AND
+    index keys: its logits and tokens are those of a cold engine."""
+    doc, q0, q1 = _prompt(2, 32), _prompt(3, 7), _prompt(4, 11)
+    cold = _engine(params)
+    r_cold = cold.submit(doc + q1, 6)
+    _step_until(cold, r_cold, 3)
+    warm = _engine(params)
+    warm.submit(doc + q0, 2)
+    _drive(warm)
+    r_warm = warm.submit(doc + q1, 6)
+    _step_until(warm, r_warm, 3)
+    assert r_warm.prefix_hit_blocks == len(doc) // BS
+    assert warm.kv_stats()["prefix_hit_tokens"] == len(doc)
+    assert r_warm.tokens == r_cold.tokens
+    np.testing.assert_array_equal(
+        _next_logits(warm, r_warm), _next_logits(cold, r_cold)
+    )
+    # the shared blocks are the first request's, index keys included
+    shared = warm._slot_blocks[r_warm.slot][:len(doc) // BS]
+    own = cold._slot_blocks[r_cold.slot][:len(doc) // BS]
+    np.testing.assert_array_equal(
+        np.asarray(warm._ki[:, shared]), np.asarray(cold._ki[:, own])
+    )
+    assert np.abs(np.asarray(warm._ki[:, shared])).max() > 0
+
+
+def test_copy_on_write_copies_the_index_keys(params):
+    """A full-prompt hit re-runs the last chunk; with blocks longer than
+    a chunk that chunk lies inside a SHARED block, which is privatized
+    first, and the copy carries the index keys."""
+    prompt = _prompt(5, 32)           # 4 whole chunks, 2 whole blocks
+    eng = _engine(params, block_size=16)
+    first = eng.submit(prompt, 3)
+    _drive(eng)
+    again = eng.submit(prompt, 3)
+    _step_until(eng, again, 1)
+    stats = eng.kv_stats()
+    assert stats["cow_copies"] >= 1 and again.prefix_hit_blocks
+    cached = eng._cache.lookup(prompt)      # the trie's own chain
+    mine = eng._slot_blocks[again.slot][:len(cached)]
+    copied = [i for i, (a, b) in enumerate(zip(cached, mine)) if a != b]
+    assert copied
+    for i in copied:
+        for pool in eng._pools():
+            np.testing.assert_array_equal(
+                np.asarray(pool[:, cached[i]]), np.asarray(pool[:, mine[i]])
+            )
+    for block in cached:
+        eng._allocator.decref(block)
+    _drive(eng)
+    assert again.tokens == first.tokens
+
+
+def test_preemption_keeps_the_pool_consistent(params):
+    """A pool too small for three long requests preempts the youngest;
+    everyone still gets the tokens an unpressed engine gives."""
+    prompts = [_prompt(10 + i, 30) for i in range(3)]
+    roomy = _engine(params, prefix_cache=False)
+    want = [roomy.submit(p, 10) for p in prompts]
+    _drive(roomy)
+    tight = _engine(params, prefix_cache=False, num_blocks=MAX_LEN // BS + 2)
+    got = [tight.submit(p, 10) for p in prompts]
+    _drive(tight)
+    assert tight.metrics.kv_preemptions.value() >= 1
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+    assert tight.kv_stats()["used"] == 0
+
+
+def test_a_dry_pool_does_not_evict_a_document_its_slots_still_read(params):
+    """Two resident documents; two long answers over the first are in
+    flight when a short question touches the second, so the FIRST is the
+    least recently used; then the answers' growth runs the pool dry. The
+    relief valve must free the second document's blocks: the first one's
+    leaf is held by two slots and frees nothing. (Before, the dry pool
+    dropped it all the same, uncovered its parent, and ate the document
+    from its tail without freeing a block: the next question over it
+    prefilled it again, a 5.7 s stall on the chip.)"""
+    doc_a, doc_b = _prompt(40, 32), _prompt(41, 32)
+    eng = _engine(params, max_len=64, num_blocks=1 + 2 * (32 // BS) + 6)
+    preempted = eng.metrics.kv_preemptions.value()   # (a shared registry)
+    for doc in (doc_a, doc_b):
+        eng.submit(doc, 1)
+        _drive(eng)
+    long = [eng.submit(doc_a + _prompt(60 + i, 3), 12) for i in range(2)]
+    _step_until(eng, long[1], 1)
+    short = eng.submit(doc_b + _prompt(70, 3), 2)
+    _drive(eng)
+    assert [len(r.tokens) for r in long + [short]] == [12, 12, 2]
+    assert eng._cache.evicted_blocks_total > 0          # the pool ran dry
+    assert eng.metrics.kv_preemptions.value() == preempted
+    hits0 = eng.kv_stats()["prefix_hit_tokens"]
+    eng.submit(doc_a + _prompt(80, 3), 1)
+    _drive(eng)
+    assert eng.kv_stats()["prefix_hit_tokens"] - hits0 == 32
+    eng.check_block_invariants()
+
+
+def test_export_and_import_carry_the_index_keys(params):
+    """A request leaves one engine mid-decode and goes on in another:
+    the payload holds its index keys bit for bit (K and V go as int8),
+    and a dense destination refuses it."""
+    prompt = _prompt(6, 26)
+    src = _engine(params)
+    req = src.submit(prompt, 8)
+    _step_until(src, req, 3)
+    payload = migrate.export_request(src, req)
+    header = migrate.peek_header(payload)
+    fill = header["fill"]
+    assert header["index"]["shape"] == [
+        CFG.n_layers, header["n_blocks"], BS, CFG.index_dim
+    ]
+    dst = _engine(params)
+    moved = migrate.import_request(dst, payload)
+    rows_src = np.asarray(src._ki[:, src._slot_blocks[req.slot]])
+    rows_dst = np.asarray(dst._ki[:, dst._slot_blocks[moved.slot]])
+    flat = lambda a: a.reshape(a.shape[0], -1, a.shape[-1])[:, :fill]  # noqa: E731
+    np.testing.assert_array_equal(flat(rows_src), flat(rows_dst))
+    migrate.release_exported(src, req)
+    _drive(dst)
+    dst.check_block_invariants()
+    assert len(moved.tokens) == 8
+    from dlrover_tpu.models import llama
+
+    dense_cfg = llama.tiny_config(
+        n_layers=CFG.n_layers, n_kv_heads=CFG.n_kv_heads,
+        head_dim=CFG.head_dim, dtype="float32",
+    )
+    dense = PagedServingEngine(
+        dense_cfg, llama.init_params(dense_cfg, jax.random.key(0))[0],
+        slots=2, max_len=MAX_LEN, prefill_chunk=CHUNK, block_size=BS,
+    )
+    with pytest.raises(migrate.MigrationError, match="index keys"):
+        migrate.import_request(dense, payload)
+
+
+def test_the_expert_layer_is_one_function_of_the_token(params):
+    """The same token's expert output from a full forward, a prefill
+    chunk and a decode step's batch: no capacity anywhere, nothing
+    dropped, so what else a call carries changes nothing."""
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(size=(1, 24, CFG.embed_dim)).astype(np.float32))
+    p = sparse_lm.layer_params(CFG, params, 0)
+    full, c_full = sparse_lm.expert_mlp(CFG, p, x)
+    chunk, c_chunk = sparse_lm.expert_mlp(CFG, p, x[:, 8:16])
+    step, c_step = sparse_lm.expert_mlp(
+        CFG, p, jnp.swapaxes(x[:, [3, 9, 20]], 0, 1)
+    )
+    np.testing.assert_allclose(chunk[0], full[0, 8:16], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        step[:, 0], full[0, [3, 9, 20]], rtol=1e-5, atol=1e-6
+    )
+    for c in (c_full, c_chunk, c_step):
+        assert int(c.rows_dropped) == 0
+    assert int(c_full.rows_held) == 24 * CFG.moe_top_k
+    assert 1 <= int(c_step.experts_hit) <= 3 * CFG.moe_top_k
+    # and it is the reference's layer
+    with jax.default_matmul_precision("highest"):
+        h = ref._norm(x[0], p["mlp_norm"])
+        want, _ = ref.experts(p, h, ref.shape_of(CFG_JSON))
+    np.testing.assert_allclose(
+        full[0] - x[0], want, rtol=1e-4, atol=1e-5
+    )
+
+
+def test_dense_programs_are_what_they_were(params):
+    """A dense config's pool has two arrays and its decode and prefill
+    programs do not know of a third: their lowered text is the text the
+    builders give when called as before this model existed."""
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    cfg = llama.tiny_config(dtype="float32")
+    counts = {"prefill": 0, "decode": 0}
+    steps = paged._paged_steps_for(cfg, 2, 9, 4, 4, 8, "fp", "xla_gather")
+    eng = PagedServingEngine(
+        cfg, llama.init_params(cfg, jax.random.key(0))[0], slots=2,
+        max_len=16, prefill_chunk=8, block_size=4,
+    )
+    assert len(eng._pools()) == 2 and eng._ki is None
+    assert "index_pool_bytes" not in eng.kv_stats()
+    i32 = jnp.int32
+    pools = eng._pools()
+    dec_args = (
+        *pools, eng._params, jnp.zeros((2, 4), i32), jnp.zeros(2, i32),
+        jnp.zeros(2, i32), jnp.zeros(2, bool), jnp.zeros(2, jnp.float32),
+        jax.random.key(0), i32(0), i32(0), i32(-1),
+    )
+    pre_args = (
+        *pools, eng._params, jnp.zeros((1, 8), i32), jnp.zeros(4, i32),
+        i32(0), i32(1), jnp.float32(0), jax.random.key(0), i32(0),
+        jnp.bool_(True),
+    )
+    direct_decode = jax.jit(
+        paged._build_paged_decode(cfg, 2, 4, 4, counts), donate_argnums=(0, 1)
+    )
+    direct_prefill = jax.jit(
+        paged._build_paged_prefill(cfg, 4, 4, 8, counts), donate_argnums=(0, 1)
+    )
+    assert steps.decode.lower(*dec_args).as_text() == \
+        direct_decode.lower(*dec_args).as_text()
+    assert steps.prefill.lower(*pre_args).as_text() == \
+        direct_prefill.lower(*pre_args).as_text()
+    assert len(steps.decode.lower(*dec_args).out_info) == 3

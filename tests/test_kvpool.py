@@ -147,6 +147,38 @@ def test_prefix_cache_leaf_first_eviction_frees_blocks():
     assert a.free_count() == a.managed
 
 
+def test_relief_eviction_skips_leaves_a_slot_still_holds():
+    """The allocator's relief valve asks for blocks that FREE: a leaf a
+    live slot also references frees nothing, and dropping it would
+    uncover its parent, so a dry pool ate a long shared chain from the
+    tail while its readers kept every block allocated."""
+    a = BlockAllocator(17, reserved=1)
+    cache = PrefixCache(a, block_size=4)
+    shared = np.arange(12, dtype=np.int32)         # a 3-block document
+    doc = a.alloc(3)
+    cache.insert(shared, doc)                      # a live slot holds it
+    done = np.arange(100, 108, dtype=np.int32)     # a finished request's
+    old = a.alloc(2)
+    cache.insert(done, old)
+    for b in old:
+        a.decref(b)                                # ... slot released
+    free = a.free_count()
+    # Oldest-first without the flag takes the document's tail although
+    # a slot reads it; nothing is freed.
+    assert cache.evict_lru(1) == 1
+    assert a.free_count() == free and a.refcount(doc[2]) == 1
+    assert cache.lookup(shared) == doc[:2]         # the chain got shorter
+    for b in doc[:2]:
+        a.decref(b)
+    # The relief valve passes over the held chain and frees the finished
+    # request's blocks, leaf first; then it has nothing left to take.
+    assert cache.evict_lru(5, must_free=True) == 2
+    assert a.free_count() == free + 2
+    assert [a.refcount(b) for b in doc[:2]] == [2, 2]
+    assert cache.evict_lru(1, must_free=True) == 0
+    assert cache.cached_entries == 2
+
+
 # ---- paged engine: exactness, reuse, retraces -------------------------------
 
 

@@ -475,3 +475,43 @@ def test_slab_decode_step_is_plain_xla_and_one_rolled_loop(one_chip, kind):
     mem = c.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes
+
+
+def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
+    """``keye-serve-docqa-32k``'s decode step and prefill chunk (2 of
+    its 5 layers: the scan's body is one layer either way) at published
+    widths, 16 slots x 33,792 rows over the cell's 5,200-block pool,
+    lower for the described v5e under their trace names; the three pool
+    arrays alias their outputs, the expert matmuls are the grouped
+    kernel (two calls a layer body, no dense [tokens, experts, ...]
+    product), and weights + pool + temporaries stay inside the chip."""
+    from benchmark import common, sparse_scopes, trace_reduce
+    from benchmark import rehearse_keye
+
+    cfg_json = common.load_json("configs", "keye-vl2-30b-a3b.json")
+    programs = rehearse_keye.lower_engine_programs(
+        cfg_json, topo.devices[0], n_layers=2
+    )
+    aliased = (
+        "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias), "
+        "{2}: (2, {}, may-alias)"
+    )
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        assert aliased in text
+        assert 2 <= _n_kernels(c) <= 4           # gmm: gate|up, down
+        # the scopes the cell's readers book device time to (in the
+        # chunk program select and sparse sit inside the query blocks'
+        # loop and its cond: ``attn/while/body/.../sparse``)
+        booked = {
+            sparse_scopes.scope_of(op_name)
+            for op_name in trace_reduce.scopes_from_hlo(text).values()
+        }
+        assert booked >= {"index", "select", "sparse", "router", "experts"}
+        m = c.memory_analysis()
+        # 2 layers of weights + the 2-layer pool + temporaries: the 5
+        # layers add 3 x (1.25 + 0.65) GB of arguments, no temporaries.
+        assert m.temp_size_in_bytes < 2.0e9
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes < 8e9

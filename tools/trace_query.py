@@ -136,7 +136,12 @@ def step_summary(spans: List[Dict]) -> Dict:
     enqueued while the previous launch's tokens were still on the
     device (one step in flight: ~100 in steady state, 0 on a path that
     drains every step), ``retraced_steps`` names the iterations that
-    recompiled a program."""
+    recompiled a program. A sparse-attention expert model's steps also
+    count ``selected_rows_mean`` (the rows a decode launch's attention
+    reads, beside the ``kv_rows_mean`` it could), ``experts_hit_mean``
+    (distinct experts a launch's tokens reach, mean over layers) and
+    ``prefix_hit_tokens`` (prompt rows the prefix cache supplied); a
+    dense model's table has none of the three."""
     steps = [
         s for s in spans
         if s.get("name") == "serving.step" and s.get("dur_s") is not None
@@ -173,7 +178,22 @@ def step_summary(spans: List[Dict]) -> Dict:
             / len(decoding) if decoding else 0.0
         ),
         "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
+        **_sparse_counts(attrs),
     }}
+
+
+def _sparse_counts(attrs: List[Dict]) -> Dict:
+    out = {}
+    for name, count in (("selected_rows_mean", "selected_rows"),
+                        ("experts_hit_mean", "experts_hit")):
+        values = [a[count] for a in attrs if count in a]
+        if values:
+            out[name] = sum(values) / len(values)
+    if any("prefix_hit_tokens" in a for a in attrs):
+        out["prefix_hit_tokens"] = sum(
+            a.get("prefix_hit_tokens", 0) for a in attrs
+        )
+    return out
 
 
 def summarize(spans: List[Dict]) -> List[Dict]:
