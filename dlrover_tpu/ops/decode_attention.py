@@ -1,7 +1,7 @@
 """Attention over a KV cache that is READ, not rewritten: what serving
 runs over its paged pool, and the reference for what it runs next.
 
-Three functions, and the predicates that say where the two kernels
+Four functions, and the predicates that say where the three kernels
 lower:
 
 - :func:`pool_decode_attention` (Pallas; :func:`pool_kernel_supported`),
@@ -16,14 +16,23 @@ lower:
   program: one slot's chunk of T tokens against the rows below the
   chunk, read from the same pool in place, then the chunk's own K/V
   causally, in one online softmax (PERF.md §5, PR 28).
+- :func:`sparse_chunk_attention` (Pallas;
+  :func:`sparse_chunk_kernel_supported`), the same chunk for a model
+  whose queries attend a learned SELECTION of the cache
+  (``serving/kvpool/sparse.py``): the chunk kernel's page copies and
+  online softmax, the selection an operand applied to the scores in
+  VMEM (PERF.md §6, PR 34).
 - :func:`spec_verify_attention` (plain XLA), T query tokens a row
   against a read-only cache: the speculative verify step's math, and
   the reference for a T-query pool kernel over several slots
   (ROADMAP S4(c)).
 
 ``serving/kvpool/engine.pool_attention_kind`` picks between the two
-kernels and the engine's XLA gather from what it can see (platform,
-pool dtype, page and chunk shapes); nothing here reads the environment.
+dense kernels and the engine's XLA gather, and
+``sparse_chunk_attention_kind`` between the sparse one and
+``ops.sparse_attention.masked_attention``, from what they can see
+(platform, pool dtype, page and chunk shapes); nothing here reads the
+environment.
 
 A one-token step over a SLAB cache (``generate()``, the flat
 ``ServingEngine``) has no kernel here: it runs
@@ -558,52 +567,14 @@ def _head_rows(ref, head, kv_heads: int, rows: int):
     )
 
 
-def _chunk_kernel(
-    layer_ref, start_ref, tbl_ref,                # scalar prefetch
-    q_ref, kn_ref, vn_ref, k_hbm, v_hbm,          # inputs
-    o_ref,                                        # output
-    kbuf, vbuf, sem, m_ref, l_ref, acc_ref,       # scratch
-    *, chunk_pages: int, page_rows: int, kv_heads: int, group: int,
-    tile: int, exact: bool,
-):
-    """One call = one layer's attention for one slot's prefill chunk;
-    one grid step = ``tile`` of its tokens, all heads.
-
-    Two key groups in one online softmax per (KV head, query row):
-
-    - the slot's cache rows ``[0, start)``, copied from the pool page
-      by page as :func:`_pool_kernel` copies them (the next chunk of
-      pages in flight while this one is computed), every row visible
-      to every query, the last page masked at ``start``;
-    - the chunk's own K/V, from VMEM, causally.
-
-    Query rows lie ``[kv_heads, tile * group, d]``, row ``r`` of a head
-    is token ``r // group``: a KV head's rows are read out of the page
-    layout by :func:`_head_rows` and meet only their own queries, so
-    nothing is scored to be masked away (the decode kernel's one
-    matmul over all heads costs ``kv_heads`` x the softmax work, which
-    for 32 query rows is nothing and for 8,192 would be the kernel's
-    time)."""
-    step = pl.program_id(0)
-    layer = layer_ref[0]
-    start = start_ref[0]
-    block_size = page_rows // kv_heads
-    chunk_rows = chunk_pages * block_size
-    n_pages = (start + block_size - 1) // block_size
-    n_chunks = (n_pages + chunk_pages - 1) // chunk_pages
-    t_own = kn_ref.shape[1]
-    rows = tile * group
+def _page_stream(layer, tbl_ref, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
+                 *, chunk_pages: int, page_rows: int):
+    """The chunk kernels' page copies: ``(start_copies, wait_copies,
+    pages_in)`` over one slot's pages ``tbl_ref[0 .. n_pages)`` of
+    ``layer``, in VMEM chunks of ``chunk_pages`` pages, K into
+    ``kbuf[buf]`` and V into ``vbuf[buf]``, one contiguous DMA a page
+    and a lane group."""
     _, lane_groups, _, lanes = kbuf.shape
-
-    @pl.when(step == 0)
-    def _():
-        # Finite wherever a page copy has not written (0 x NaN).
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
-
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def pages_in(chunk):
         return jnp.clip(n_pages - chunk * chunk_pages, 0, chunk_pages)
@@ -638,6 +609,15 @@ def _chunk_kernel(
 
         jax.lax.fori_loop(0, pages_in(chunk), body, 0)
 
+    return start_copies, wait_copies, pages_in
+
+
+def _online_softmax(q_ref, m_ref, l_ref, acc_ref, *, rows: int,
+                    exact: bool):
+    """The chunk kernels' one online softmax: ``attend(head, k, v,
+    visible)`` over the per-(KV head, query row) statistics ``m_ref`` /
+    ``l_ref`` ``[kv_heads, 1, rows]`` and the accumulator ``acc_ref``
+    ``[kv_heads, d, rows]``, all float32."""
     nt = (((1,), (1,)), ((), ()))      # [n, d] x [m, d] -> [n, m]
     tn = (((0,), (0,)), ((), ()))      # [n, d] x [n, m] -> [d, m]
 
@@ -663,14 +643,25 @@ def _chunk_kernel(
         over vregs, one short reduce at the end) and its per-query
         statistics are lane-dense ``[1, rows]`` rows, where queries x
         keys would reduce along the lanes and keep a ``[rows, 128]``
-        tile a statistic. Every call shows each query a key at least
-        (a chunk of pages is attended only if it holds a row below
-        ``start``, and a token sees itself), so the running max is a
-        real logit after it and a masked key's probability is
-        exp(NEG_INF - m) == 0 with no second select."""
+        tile a statistic. A ``visible`` narrower than ``rows`` (``[n,
+        rows / r]``: one answer for the ``r`` query heads of a token,
+        the rows lying head-major) is applied a lane block at a time,
+        never widened. A masked key's probability is exp(NEG_INF - m)
+        == 0 with no second select once the running max is a real
+        logit; a query no key has been shown yet (every caller shows it
+        one before it reads the answer) carries exp(0) a masked key in
+        its sum and accumulator, all finite, and its first real logit
+        scales them by exp(NEG_INF - m) == 0, exactly."""
         s = dot(k, q_ref[head], nt)
         if visible is not None:
-            s = jnp.where(visible, s, NEG_INF)
+            width = visible.shape[1]
+            if width == rows:
+                s = jnp.where(visible, s, NEG_INF)
+            else:
+                s = jnp.concatenate([
+                    jnp.where(visible, s[:, j:j + width], NEG_INF)
+                    for j in range(0, rows, width)
+                ], axis=1)
         m_prev = m_ref[head]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -689,6 +680,66 @@ def _chunk_kernel(
             pv = dot(v, p.astype(v.dtype), tn)
         acc_ref[head] = acc_ref[head] * alpha + pv
         m_ref[head] = m_new
+
+    return attend
+
+
+def _chunk_kernel(
+    layer_ref, start_ref, tbl_ref,                # scalar prefetch
+    q_ref, kn_ref, vn_ref, k_hbm, v_hbm,          # inputs
+    o_ref,                                        # output
+    kbuf, vbuf, sem, m_ref, l_ref, acc_ref,       # scratch
+    *, chunk_pages: int, page_rows: int, kv_heads: int, group: int,
+    tile: int, exact: bool,
+):
+    """One call = one layer's attention for one slot's prefill chunk;
+    one grid step = ``tile`` of its tokens, all heads.
+
+    Two key groups in one online softmax per (KV head, query row)
+    (:func:`_online_softmax`):
+
+    - the slot's cache rows ``[0, start)``, copied from the pool page
+      by page as :func:`_pool_kernel` copies them (the next chunk of
+      pages in flight while this one is computed), every row visible
+      to every query, the last page masked at ``start``;
+    - the chunk's own K/V, from VMEM, causally.
+
+    Query rows lie ``[kv_heads, tile * group, d]``, row ``r`` of a head
+    is token ``r // group``: a KV head's rows are read out of the page
+    layout by :func:`_head_rows` and meet only their own queries, so
+    nothing is scored to be masked away (the decode kernel's one
+    matmul over all heads costs ``kv_heads`` x the softmax work, which
+    for 32 query rows is nothing and for 8,192 would be the kernel's
+    time). Every call of ``attend`` shows each query a key at least (a
+    chunk of pages is attended only if it holds a row below ``start``,
+    and a token sees itself), so the running max is a real logit after
+    the first."""
+    step = pl.program_id(0)
+    layer = layer_ref[0]
+    start = start_ref[0]
+    block_size = page_rows // kv_heads
+    chunk_rows = chunk_pages * block_size
+    n_pages = (start + block_size - 1) // block_size
+    n_chunks = (n_pages + chunk_pages - 1) // chunk_pages
+    t_own = kn_ref.shape[1]
+    rows = tile * group
+
+    @pl.when(step == 0)
+    def _():
+        # Finite wherever a page copy has not written (0 x NaN).
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    start_copies, wait_copies, _ = _page_stream(
+        layer, tbl_ref, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
+        chunk_pages=chunk_pages, page_rows=page_rows,
+    )
+    attend = _online_softmax(
+        q_ref, m_ref, l_ref, acc_ref, rows=rows, exact=exact
+    )
 
     @pl.when(n_chunks > 0)
     def _():
@@ -748,6 +799,24 @@ def _chunk_kernel(
     jax.lax.fori_loop(0, kv_heads, own_body, 0)
 
 
+def _chunk_scratch(pool_dtype, buf_rows: int, kv_heads: int, rows: int,
+                   head_dim: int):
+    """The chunk kernels' scratch: K and V page buffers (two each, a
+    head wider than 128 in 128-lane groups; one group, off the chip,
+    for a narrow one), their DMA semaphores, and the online softmax's
+    running max, sum and accumulator a (KV head, query row)."""
+    lane_groups = max(head_dim // 128, 1)
+    chunk_buf = (2, lane_groups, buf_rows, head_dim // lane_groups)
+    return [
+        pltpu.VMEM(chunk_buf, pool_dtype),
+        pltpu.VMEM(chunk_buf, pool_dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((kv_heads, 1, rows), jnp.float32),
+        pltpu.VMEM((kv_heads, 1, rows), jnp.float32),
+        pltpu.VMEM((kv_heads, head_dim, rows), jnp.float32),
+    ]
+
+
 def pool_chunk_attention(
     q,            # [T, n_heads, d] — one slot's prefill chunk
     k_new,        # [T, kv_heads, d] — the chunk's own K/V, not yet in
@@ -804,11 +873,6 @@ def pool_chunk_attention(
         jnp.asarray(table_row, jnp.int32),
     )
     pooled = (n_layers, nb_pool, page_rows, d)
-    # 128-lane groups of the head (one, off the chip, for a narrow one).
-    lane_groups = max(d // 128, 1)
-    chunk_buf = (
-        2, lane_groups, chunk_pages * page_rows, d // lane_groups
-    )
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     tiled = pl.BlockSpec((kh, rows, d), lambda i, *_: (0, i, 0))
     out = pl.pallas_call(
@@ -825,14 +889,9 @@ def pool_chunk_attention(
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=tiled,
-            scratch_shapes=[
-                pltpu.VMEM(chunk_buf, k_pool.dtype),
-                pltpu.VMEM(chunk_buf, v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((kh, 1, rows), jnp.float32),
-                pltpu.VMEM((kh, 1, rows), jnp.float32),
-                pltpu.VMEM((kh, d, rows), jnp.float32),
-            ],
+            scratch_shapes=_chunk_scratch(
+                k_pool.dtype, chunk_pages * page_rows, kh, rows, d
+            ),
         ),
         out_shape=jax.ShapeDtypeStruct((kh, t * g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -846,3 +905,271 @@ def pool_chunk_attention(
         k_pool.reshape(pooled), v_pool.reshape(pooled),
     )
     return out.reshape(kh, t, g, d).transpose(1, 0, 2, 3).reshape(t, h, d)
+
+
+# ---- the same chunk under a per-query SELECTION of its keys ---------------
+
+# What the sparse chunk kernel may use of the core's VMEM: the dense
+# kernel's terms, the selection's two blocks (4.3 MB a buffer at 33,792
+# rows a slot) and a score tile of 1,024 keys x 1,024 query rows at
+# 64-row x 4-head pages. Its page chunk is the dense kernels'
+# ``_POOL_CHUNK_BYTES``: on the v5e at the ``keye-serve-docqa-32k``
+# shape 256 KB / 512 KB / 1 MB / 2 MB took 2.92 / 2.75 / 2.65 / 2.66 ms
+# a layer over four 128-token tiles and 32,768 rows below the chunk
+# (``tools/bench_sparse_attention.py --parts chunk_kernel``, PR 34).
+_SPARSE_VMEM_BYTES = 48 << 20
+# Lanes of a vreg: on the chip the selection is applied a lane block of
+# one token tile at a time, so a token tile is a whole number of them.
+_LANES = 128
+
+
+def _sparse_chunk_vmem_bytes(block_size, n_heads, kv_heads, head_dim,
+                             chunk, max_blocks, tile, itemsize) -> int:
+    """An upper reckoning of the sparse chunk kernel's VMEM:
+    :func:`_chunk_vmem_bytes`, and the two selection blocks (the pool's
+    rows to whole chunks of pages, the chunk's own), int8, pipelined."""
+    pages = max(1, _pool_chunk_pages(
+        block_size, kv_heads, head_dim, max_blocks
+    ))
+    keys = -(-max_blocks // pages) * pages * block_size
+    return _chunk_vmem_bytes(
+        block_size, n_heads, kv_heads, head_dim, chunk, tile, itemsize
+    ) + 2 * (keys + chunk) * tile
+
+
+def sparse_chunk_kernel_supported(pool_dtype, block_size: int,
+                                  n_heads: int, kv_heads: int,
+                                  head_dim: int, chunk: int,
+                                  max_blocks: int) -> bool:
+    """Shapes :func:`sparse_chunk_attention` lowers for on a TPU: a bf16
+    pool whose page ``[block_size * kv_heads, head_dim]`` is a whole
+    number of (16, 128) tiles and one contiguous DMA (8 KV heads a row
+    as :func:`pool_kernel_supported` has it, or 4: XLA lays ``[...,
+    4, 128]`` bf16 out in ``T(4,128)(2,1)`` tiles, two of which are one
+    ``T(8,128)(2,1)`` tile of the collapsed rows, byte for byte), a
+    token tile of whole lane blocks, and buffers that fit the VMEM the
+    kernel asks for."""
+    tile = _chunk_token_tile(chunk, n_heads)
+    return bool(
+        jnp.dtype(pool_dtype) == jnp.bfloat16
+        and head_dim % 128 == 0
+        and (kv_heads % 8 == 0 or kv_heads == 4)
+        and n_heads % kv_heads == 0
+        and (block_size * kv_heads) % 16 == 0
+        and _pool_chunk_pages(block_size, kv_heads, head_dim, 1) == 1
+        and tile and tile % _LANES == 0
+        and _sparse_chunk_vmem_bytes(
+            block_size, n_heads, kv_heads, head_dim, chunk, max_blocks,
+            tile, 2,
+        ) <= _SPARSE_VMEM_BYTES
+    )
+
+
+def _sparse_chunk_kernel(
+    layer_ref, start_ref, valid_ref, tbl_ref,     # scalar prefetch
+    q_ref, kn_ref, vn_ref, sel_ref, own_ref, k_hbm, v_hbm,   # inputs
+    o_ref,                                        # output
+    kbuf, vbuf, sem, m_ref, l_ref, acc_ref,       # scratch
+    *, chunk_pages: int, page_rows: int, kv_heads: int, group: int,
+    tile: int,
+):
+    """:func:`_chunk_kernel` with each query attending the keys a
+    selection marks, and those alone: the same page copies
+    (:func:`_page_stream`) and the same online softmax
+    (:func:`_online_softmax`), the visibility an operand.
+
+    ``sel_ref`` ``[keys, tile]`` int8 is this token tile's selection
+    over the slot's cache rows (nothing marked at or past ``start``:
+    what a last page holds there is not the chunk), ``own_ref``
+    ``[chunk, tile]`` over the chunk's own keys; both hold causality
+    already, so no other mask is applied. Query rows lie ``[kv_heads,
+    group * tile, d]``, row ``r`` of a head is token ``r % tile``: the
+    ``group`` query heads of a token share its selection, and head-major
+    the selection is one lane block wide and repeats. A token tile at or
+    past ``valid_ref`` (padding) reads nothing and is left zero. A
+    query may find no key of its own in a chunk of pages, or in all of
+    them; it finds one before the end (a selection marks a key for
+    every query), which is what :func:`_online_softmax` asks."""
+    step = pl.program_id(0)
+    layer = layer_ref[0]
+    start = start_ref[0]
+    block_size = page_rows // kv_heads
+    chunk_rows = chunk_pages * block_size
+    n_pages = (start + block_size - 1) // block_size
+    n_chunks = (n_pages + chunk_pages - 1) // chunk_pages
+    rows = tile * group
+
+    @pl.when(step == 0)
+    def _():
+        # Finite wherever a page copy has not written (0 x NaN).
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(step * tile >= valid_ref[0])
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(step * tile < valid_ref[0])
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        start_copies, wait_copies, _ = _page_stream(
+            layer, tbl_ref, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
+            chunk_pages=chunk_pages, page_rows=page_rows,
+        )
+        attend = _online_softmax(
+            q_ref, m_ref, l_ref, acc_ref, rows=rows, exact=False
+        )
+
+        @pl.when(n_chunks > 0)
+        def _():
+            start_copies(0, 0)
+
+        def chunk_body(chunk, buf):
+            @pl.when(chunk + 1 < n_chunks)
+            def _():
+                start_copies(chunk + 1, 1 - buf)
+
+            wait_copies(chunk, buf)
+            keys = pl.ds(
+                pl.multiple_of(chunk * chunk_rows, chunk_rows), chunk_rows
+            )
+
+            def head_body(head, carry):
+                attend(
+                    head,
+                    _head_rows(kbuf.at[buf], head, kv_heads, chunk_rows),
+                    _head_rows(vbuf.at[buf], head, kv_heads, chunk_rows),
+                    sel_ref[keys, :].astype(jnp.int32) != 0,
+                )
+                return carry
+
+            jax.lax.fori_loop(0, kv_heads, head_body, 0)
+            return 1 - buf
+
+        jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+
+        def own_body(head, carry):
+            attend(
+                head, kn_ref[head], vn_ref[head],
+                own_ref[...].astype(jnp.int32) != 0,
+            )
+            o_ref[head] = (
+                acc_ref[head] / l_ref[head]
+            ).T.astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, kv_heads, own_body, 0)
+
+
+def sparse_chunk_attention(
+    q,            # [T, n_heads, d] — one slot's prefill chunk
+    k_new,        # [T, kv_heads, d] — the chunk's own K/V, not yet in
+    v_new,        #   the pool
+    k_pool,       # [layers, num_blocks, block_size, kv_heads, d]
+    v_pool,
+    layer,        # [] int32
+    table_row,    # [max_blocks] int32 — the slot's pages
+    start,        # [] int32 — cache rows already filled: [0, start)
+    selection,    # [T, max_len] bool — query t's keys, by logical row
+    n_valid=None,  # [] int32 — queries at or past it are padding
+    interpret=None,
+):
+    """``ops.sparse_attention.masked_attention`` of the chunk's queries
+    over the slot's logical cache with the chunk written at ``start``,
+    under ``selection``, without that view and without a logit in HBM:
+    rows below ``start`` come straight from the stacked pool through
+    ``table_row``, only the pages that hold them, the chunk's own K/V
+    from the caller's hands (``start + T <= max_len``), and the
+    selection — which holds causality: it marks no row past its query —
+    is the only mask (:func:`_sparse_chunk_kernel`).
+
+    Arithmetic is ``masked_attention``'s: K and V as stored, float32
+    logits, running max, sum and accumulator, the probabilities rounded
+    to the pool's dtype once before they meet V, every selected key of
+    every query attended and no other. The query is scaled in its own
+    dtype (there the logits are), and the order of summation is an
+    online softmax over chunks of ``_POOL_CHUNK_BYTES`` of pages, then
+    the chunk itself. A token tile of nothing but padding is skipped
+    and its rows are zero; padding rows of a tile with a valid query
+    are attended like any other (their selection marks a key too).
+    Returns ``[T, n_heads, d]`` in ``q.dtype``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t, h, d = q.shape
+    n_layers, nb_pool, block_size, kh, _ = k_pool.shape
+    if h % kh:
+        raise ValueError(f"n_heads {h} not divisible by kv_heads {kh}")
+    g = h // kh
+    page_rows = block_size * kh
+    max_blocks = table_row.shape[0]
+    max_len = max_blocks * block_size
+    chunk_pages = max(1, _pool_chunk_pages(block_size, kh, d, max_blocks))
+    # Off the chip (interpret mode) any chunk goes in one tile; on it
+    # the caller asked sparse_chunk_kernel_supported.
+    tile = _chunk_token_tile(t, h) or t
+    n_tiles, rows = t // tile, tile * g
+    start = jnp.minimum(jnp.asarray(start, jnp.int32), max_len)
+    # [T, kh, g, d] -> [kh, tiles x g x tile, d]: a KV head's query rows
+    # together, a token tile a run of rows, head-major inside it.
+    qs = (q * d ** -0.5).reshape(n_tiles, tile, kh, g, d)
+    qs = qs.transpose(2, 0, 3, 1, 4).reshape(kh, t * g, d)
+    own = [x.astype(k_pool.dtype).transpose(1, 0, 2) for x in (k_new, v_new)]
+
+    def by_tile(sel):
+        # [T, keys] -> [tiles, keys, tile] int8: KEYS x QUERIES, as the
+        # scores lie.
+        sel = sel.reshape(n_tiles, tile, -1).transpose(0, 2, 1)
+        return sel.astype(jnp.int8)
+
+    # The pool's share of the selection (logical rows below start), to
+    # whole chunks of pages; the chunk's own is the [T, T] at start.
+    keys = -(-max_blocks // chunk_pages) * chunk_pages * block_size
+    below = selection & (jnp.arange(max_len) < start)
+    sel_pool = by_tile(jnp.pad(below, ((0, 0), (0, keys - max_len))))
+    sel_own = by_tile(
+        jax.lax.dynamic_slice_in_dim(selection, start, t, axis=1)
+    )
+    scalars = (
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        start.reshape(1),
+        jnp.asarray(t if n_valid is None else n_valid, jnp.int32).reshape(1),
+        jnp.asarray(table_row, jnp.int32),
+    )
+    pooled = (n_layers, nb_pool, page_rows, d)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    tiled = pl.BlockSpec((kh, rows, d), lambda i, *_: (0, i, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _sparse_chunk_kernel, chunk_pages=chunk_pages,
+            page_rows=page_rows, kv_heads=kh, group=g, tile=tile,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(n_tiles,),
+            in_specs=[
+                tiled, whole, whole,
+                pl.BlockSpec((None, keys, tile), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((None, t, tile), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=tiled,
+            scratch_shapes=_chunk_scratch(
+                k_pool.dtype, chunk_pages * page_rows, kh, rows, d
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((kh, t * g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_SPARSE_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_pool_sparse_chunk_attention",
+    )(
+        *scalars, qs, *own, sel_pool, sel_own,
+        k_pool.reshape(pooled), v_pool.reshape(pooled),
+    )
+    out = out.reshape(kh, n_tiles, g, tile, d).transpose(1, 3, 0, 2, 4)
+    return out.reshape(t, h, d)

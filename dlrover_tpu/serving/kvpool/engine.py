@@ -98,6 +98,8 @@ class _PagedSteps(NamedTuple):
     exp: object          # migration export: pool[src] -> one block's rows
     trace_counts: Dict[str, int]
     pool_attention: str = "xla_gather"   # see pool_attention_kind
+    # see sparse_chunk_attention_kind; "" for a dense model
+    sparse_chunk_attention: str = ""
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -133,7 +135,9 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
     §6, PR 25), the chunk kernel at every ``start`` (PR 28)."""
     if getattr(config, "index_topk", 0):
         # A learned selection of the cache (kvpool/sparse.py): index
-        # keys scored through the table, the selected rows gathered.
+        # keys scored through the table, the selected rows gathered;
+        # what the chunk attends with under its selection is
+        # sparse_chunk_attention_kind's to say.
         return "sparse_gather"
     if kv_dtype != "fp" or not _on_tpu():
         return "xla_gather"
@@ -147,6 +151,35 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
     ):
         return "paged_kernel"
     return "xla_gather"
+
+
+def sparse_chunk_attention_kind(config, pool_dtype, block_size: int,
+                                chunk: int, max_blocks: int) -> str:
+    """What a sparse model's prefill chunk attends with under its
+    selection (``kvpool/sparse.chunk_attend``): ``"chunk_kernel"``
+    (``ops.decode_attention.sparse_chunk_attention``: K and V read from
+    the pool in place, the selection applied to the scores in VMEM)
+    where that kernel lowers — a TPU, a bf16 pool, a page that is one
+    DMA, a token tile of whole lane blocks, buffers inside the VMEM it
+    asks for — and ``"masked_attention"``, the definition, over the
+    slot's gathered views everywhere else. Decided by what the code can
+    see, like :func:`pool_attention_kind` and for its reasons: no
+    option, nothing falls back after it, so what it admits has to
+    compile (``tests/test_tpu_compile.py`` holds it to the cell's
+    shape). The decode step is not its business: that one gathers the
+    selected rows whatever this says."""
+    if not _on_tpu():
+        return "masked_attention"
+    from dlrover_tpu.ops.decode_attention import (
+        sparse_chunk_kernel_supported,
+    )
+
+    if sparse_chunk_kernel_supported(
+        pool_dtype, block_size, config.n_heads, config.n_kv_heads,
+        config.head_dim, chunk, max_blocks,
+    ):
+        return "chunk_kernel"
+    return "masked_attention"
 
 
 def _layer_over_pool(config, p, x, positions, attend):
@@ -768,10 +801,13 @@ def _paged_steps(
     all plain traced arguments. ``kv_dtype`` "int8" programs also
     donate the scale pools. The decode and prefill programs' attention
     (:func:`pool_attention_kind`) is part of the key."""
+    attn = pool_attention_kind(config, block_size, kv_dtype, chunk)
     return _paged_steps_for(
         config, slots, num_blocks, max_blocks, block_size, chunk,
-        kv_dtype,
-        pool_attention_kind(config, block_size, kv_dtype, chunk),
+        kv_dtype, attn,
+        sparse_chunk_attention_kind(
+            config, config.compute_dtype, block_size, chunk, max_blocks
+        ) if attn == "sparse_gather" else "",
     )
 
 
@@ -779,7 +815,7 @@ def _paged_steps(
 def _paged_steps_for(
     config: llama.TpuLMConfig, slots: int, num_blocks: int,
     max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
-    attn: str,
+    attn: str, sparse_chunk: str = "",
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
@@ -794,7 +830,8 @@ def _paged_steps_for(
             config, slots, max_blocks, block_size, counts
         )
         build_prefill = sparse.build_prefill(
-            config, max_blocks, block_size, chunk, counts
+            config, max_blocks, block_size, chunk, counts,
+            kind=sparse_chunk,
         )
     else:
         pool_args = (0, 1, 2, 3) if quantized else (0, 1)
@@ -818,7 +855,8 @@ def _paged_steps_for(
     exp = jax.jit(_build_export_gather(counts, n_pools))
     return _PagedSteps(prefill=prefill, decode=decode, cow=cow,
                        imp=imp, exp=exp, trace_counts=counts,
-                       pool_attention=attn)
+                       pool_attention=attn,
+                       sparse_chunk_attention=sparse_chunk)
 
 
 class PagedServingEngine(ServingEngine):
@@ -934,16 +972,20 @@ class PagedServingEngine(ServingEngine):
         )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
-            "(%s KV%s), decode and prefill attention %s",
+            "(%s KV%s), decode and prefill attention %s%s",
             slots, max_len, self.num_blocks, block_size,
             kv_cache_dtype,
             f" + index keys [{self._index_dim}] a row, top-"
             f"{config.index_topk}" if self._index_dim else "",
             self.pool_attention,
+            ", the chunk under its selection by "
+            f"{self.sparse_chunk_attention}" if self._index_dim else "",
         )
         self.metrics.annotate(
             "serving_engine_built", slots=slots, max_len=max_len,
             pool_attention=self.pool_attention,
+            **({"sparse_chunk_attention": self.sparse_chunk_attention}
+               if self._index_dim else {}),
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -992,6 +1034,14 @@ class PagedServingEngine(ServingEngine):
         and prefill programs were built with
         (:func:`pool_attention_kind`)."""
         return self._steps.pool_attention
+
+    @property
+    def sparse_chunk_attention(self) -> str:
+        """``"chunk_kernel"`` or ``"masked_attention"``: what a sparse
+        model's prefill program attends with under its selection
+        (:func:`sparse_chunk_attention_kind`); ``""`` for a dense
+        model."""
+        return self._steps.sparse_chunk_attention
 
     def _fresh_pool(self):
         shape = (
@@ -1467,6 +1517,7 @@ class PagedServingEngine(ServingEngine):
                 self.num_blocks * self._index_block_bytes
             )
             stats["moe_rows_dropped"] = self._moe_rows_dropped
+            stats["sparse_chunk_attention"] = self.sparse_chunk_attention
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value

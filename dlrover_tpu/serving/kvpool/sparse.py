@@ -11,9 +11,13 @@ the selected K/V rows (``ops/sparse_attention.py``):
   those K/V rows straight from the stacked pool: the K/V a step reads
   is ``topk`` rows a slot, whatever its fill;
 - the prefill chunk (``[1, chunk]`` queries whose own keys are causal
-  and whose prefix lives in the pool) gathers the slot's view of all
-  three arrays, lays the chunk's own rows into it and attends under the
-  selection as a mask: its queries pick ``chunk`` different sets.
+  and whose prefix lives in the pool) gathers the slot's view of the
+  index keys, lays the chunk's own into it, selects, and attends under
+  the selection as a mask, its queries picking ``chunk`` different
+  sets: on a TPU in a Pallas kernel that reads K and V from the pool in
+  place (``ops.decode_attention.sparse_chunk_attention``), elsewhere by
+  ``masked_attention``, the definition, over the gathered views of K
+  and V (``engine.sparse_chunk_attention_kind`` says which).
 
 Both are append-free like the dense in-place programs: the new rows of
 all layers land after the layer scan (one row a slot, or the chunk's
@@ -32,6 +36,7 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.models import sparse_lm
 from dlrover_tpu.ops import sparse_attention as sa
 from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool import engine as paged
 from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
 
 
@@ -156,44 +161,78 @@ def chunk_select(config, ki_view, at, q_idx, w):
 
 
 def chunk_attend(config, k, v, ki, layer, table_row, start,
-                 block_size: int, n_valid=None):
+                 block_size: int, n_valid=None, kind=None):
     """The prefill chunk's ``attend`` for one layer: the chunk's queries
     (positions ``start ...``) over the slot's rows below ``start`` and
-    the chunk's own, a block of queries at a time: select, then attend
-    over the slot's view under the selection as a mask. Rows at or past
+    the chunk's own, under each query's selection. The selection is made
+    a block of queries at a time (:func:`chunk_select`); rows at or past
     ``n_valid`` (None: none) are padding: their output is never read,
-    and a block of nothing else is left at zero."""
+    and a block of nothing else selects nothing and is left at zero.
 
+    ``kind`` (``engine.sparse_chunk_attention_kind``; None: asked here,
+    of what this call can see) says what attends under the selection:
+    ``"chunk_kernel"``, ``ops.decode_attention.sparse_chunk_attention``
+    once for the chunk with K and V read from the pool in place, or
+    ``"masked_attention"``, the definition, a block at a time over the
+    slot's gathered view."""
     def attend(q, k_new, v_new, q_idx, k_idx, w):
         chunk = q.shape[1]
+        how = kind or paged.sparse_chunk_attention_kind(
+            config, k.dtype, block_size, chunk, table_row.shape[0]
+        )
         sub = min(CHUNK_QUERY_BLOCK, chunk)
         if chunk % sub:
             sub = chunk
-        k_view, v_view, ki_view = (
-            _slot_view(pool, layer, table_row, new, start, block_size)
-            for pool, new in ((k, k_new), (v, v_new), (ki, k_idx))
-        )
+        ki_view = _slot_view(ki, layer, table_row, k_idx, start, block_size)
 
-        def block(lo):
-            def run():
+        def blocks(run, nothing):
+            """``run(lo, take)`` for each block of queries ``lo ...``
+            with a valid row, ``nothing`` for the others, stacked."""
+            def block(lo):
                 take = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
                     a[0], lo, sub, axis=0
                 )
-                mask = chunk_select(
-                    config, ki_view, start + lo + jnp.arange(sub),
-                    take(q_idx), take(w),
+                if n_valid is None:
+                    return run(lo, take)
+                return jax.lax.cond(
+                    lo < n_valid, lambda: run(lo, take),
+                    lambda: jnp.zeros(*nothing),
                 )
-                with jax.named_scope("sparse"):
-                    return sa.masked_attention(take(q), k_view, v_view, mask)
 
-            if n_valid is None:
-                return run()
-            return jax.lax.cond(
-                lo < n_valid, run,
-                lambda: jnp.zeros((sub,) + q.shape[2:], q.dtype),
+            return jax.lax.map(block, jnp.arange(0, chunk, sub))
+
+        def select(lo, take):
+            return chunk_select(
+                config, ki_view, start + lo + jnp.arange(sub),
+                take(q_idx), take(w),
             )
 
-        out = jax.lax.map(block, jnp.arange(0, chunk, sub))
+        if how == "chunk_kernel":
+            # Imported here: Pallas costs ~1.2 s, and only a process
+            # that may run the kernel pays it.
+            from dlrover_tpu.ops.decode_attention import (
+                sparse_chunk_attention,
+            )
+
+            selection = blocks(select, ((sub, ki_view.shape[0]), bool))
+            with jax.named_scope("sparse"):
+                out = sparse_chunk_attention(
+                    q[0], k_new[0], v_new[0], k, v, layer, table_row,
+                    start, selection.reshape(chunk, -1), n_valid,
+                )
+            return out[None]
+
+        k_view, v_view = (
+            _slot_view(pool, layer, table_row, new, start, block_size)
+            for pool, new in ((k, k_new), (v, v_new))
+        )
+
+        def run(lo, take):
+            mask = select(lo, take)
+            with jax.named_scope("sparse"):
+                return sa.masked_attention(take(q), k_view, v_view, mask)
+
+        out = blocks(run, ((sub,) + q.shape[2:], q.dtype))
         return out.reshape((1, chunk) + q.shape[2:])
 
     return attend
@@ -223,11 +262,12 @@ def decode_forward(config, k, v, ki, params, tables, lengths, tokens,
 
 
 def chunk_forward(config, k, v, ki, params, tokens, table_row, start,
-                  block_size: int, n_valid=None):
+                  block_size: int, n_valid=None, kind=None):
     """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
     ``start ...``: the final hidden states ``[1, chunk, d]`` and the
     chunk's new rows ``(k, v, k_idx)`` each ``[L, chunk, ...]``. Rows at
-    or past ``n_valid`` are padding (:func:`chunk_attend`)."""
+    or past ``n_valid`` are padding, and ``kind`` is what attends under
+    the selection (:func:`chunk_attend`)."""
     positions = (
         start + jnp.arange(tokens.shape[1], dtype=jnp.int32)
     )[None, :]
@@ -238,7 +278,7 @@ def chunk_forward(config, k, v, ki, params, tokens, table_row, start,
         y, (k_new, v_new, ki_new), _ = _layer(
             config, params, pl, layer, carry, positions,
             chunk_attend(config, k, v, ki, layer, table_row, start,
-                         block_size, n_valid),
+                         block_size, n_valid, kind),
         )
         return y, (k_new[0], v_new[0], ki_new[0])
 
@@ -277,7 +317,7 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts):
+                  counts, kind=None):
     def land(pool, rows, table_row, start):
         # ``rows`` [L, chunk, ...] at the slot's logical rows start ...
         at = start + jnp.arange(chunk)
@@ -290,7 +330,7 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         counts["prefill"] += 1  # traces only
         x, (k_news, v_news, ki_news) = chunk_forward(
             config, k, v, ki, params, tokens, table_row, start, block_size,
-            n_valid,
+            n_valid, kind,
         )
         k = land(k, k_news, table_row, start)
         v = land(v, v_news, table_row, start)

@@ -77,14 +77,34 @@ def _step_until(eng, req, n_tokens):
     eng._drain("test")
 
 
+def _take_the_chunk_kernel(monkeypatch):
+    """Build the prefill program as on a TPU: the platform probe says
+    so (the kernel then runs in interpret mode) and the predicate admits
+    the tiny model's float32 pool and narrow heads."""
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(da, "sparse_chunk_kernel_supported", lambda *a: True)
+
+
+@pytest.mark.parametrize("chunk_attention", ["masked_attention",
+                                             "chunk_kernel"])
 @pytest.mark.parametrize("n_prompt", [9, 21, 37, 60])
 def test_chunked_prefill_and_paged_decode_give_the_references_logits(
-        params, n_prompt):
+        params, n_prompt, chunk_attention, monkeypatch):
     """Prompt in chunks through the pool, then decode steps: the logits
     the next step samples from are the plain forward's at that position,
     after 1 token (the prefill's) and after 5; the tokens emitted are
-    its argmax; and the last chunk's own logits are its rows."""
+    its argmax; and the last chunk's own logits are its rows. Once with
+    the chunk attended by ``masked_attention`` over the gathered views
+    (what a CPU builds), once by the Pallas kernel over the pool in
+    place (what a TPU builds; here interpreted)."""
+    if chunk_attention == "chunk_kernel":
+        _take_the_chunk_kernel(monkeypatch)
     eng = _engine(params)
+    assert eng.pool_attention == "sparse_gather"
+    assert eng.kv_stats()["sparse_chunk_attention"] == chunk_attention
     prompt = _prompt(n_prompt, n_prompt)
     req = eng.submit(prompt, 8)
     for n_out in (1, 5):

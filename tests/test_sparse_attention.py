@@ -126,3 +126,109 @@ def test_below_topk_rows_the_selection_is_dense_attention():
     np.testing.assert_allclose(
         sa.masked_attention(q, k, v, mask), dense, rtol=2e-5, atol=2e-6
     )
+
+
+# Each case: one slot's prefill chunk of ``chunk`` tokens over a pool of
+# ``bs``-row pages behind a shuffled table, ``start`` cache rows below
+# it, each query selecting ``topk`` of the rows it may see. 8 query
+# heads on 2 KV heads; a VMEM chunk holds 2 pages, a grid step 8 tokens.
+_KERNEL_CASES = {
+    # A prompt's first chunk: nothing below it, no page read; its first
+    # queries see fewer rows than topk.
+    "start_0": dict(start=0),
+    # The prefix ends mid-page: that page's rows past ``start`` hold
+    # something else than the chunk's own rows, which the selection marks.
+    "start_mid_page": dict(start=20),
+    "start_at_a_page_boundary": dict(start=32),
+    # Many pages at a tiny page (the cell: 512 pages below the chunk).
+    "many_pages": dict(start=232, bs=4, mb=64),
+    # The last token tile is padding: skipped, left zero.
+    "a_padded_tile_is_skipped": dict(start=24, n_valid=7),
+    "a_tile_half_valid": dict(start=24, n_valid=12),
+    # Fewer visible rows than topk: causal attention.
+    "below_topk_is_causal_attention": dict(start=8, topk=64),
+    # A row of tied index scores: which of them are attended is the
+    # mask's tie rule (lower positions), none of the kernel's.
+    "tied_scores": dict(start=40, tied=True),
+    # Every selected key of the first tile's queries lies in the chunk
+    # itself: chunk after chunk of pages shows them nothing.
+    "nothing_selected_below_start": dict(start=48, own_only=True, topk=1),
+}
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_sparse_chunk_kernel_is_masked_attention_over_the_view(
+    case, pool_dtype, monkeypatch,
+):
+    """``ops.decode_attention.sparse_chunk_attention`` (interpret mode
+    on the CPU) against ``masked_attention`` over the slot's gathered
+    view with the chunk laid in at ``start``, under one selection.
+    Layer 1 of a two-layer pool; pages the table does not reach below
+    ``start`` hold NaNs, which the kernel must never copy."""
+    from dlrover_tpu.ops import decode_attention as da
+
+    spec = dict(_KERNEL_CASES[case])
+    t, h, kh, d = 16, 8, 2, 128
+    bs, mb, start = spec.get("bs", 8), spec.get("mb", 12), spec["start"]
+    topk, n_valid = spec.get("topk", 10), spec.get("n_valid")
+    n_layers, layer, max_len = 2, 1, mb * bs
+    monkeypatch.setattr(da, "_POOL_CHUNK_BYTES", 2 * bs * kh * d * 2)
+    monkeypatch.setattr(da, "_CHUNK_QUERY_ROWS", 8 * h)
+    rs = np.random.RandomState(3)
+    table = (rs.permutation(2 * mb)[:mb] + 1).astype(np.int32)
+    ks = jax.random.split(jax.random.key(5), 6)
+    pool_shape = (n_layers, 2 * mb + 1, bs, kh, d)
+    k_pool = jax.random.normal(ks[0], pool_shape).astype(pool_dtype)
+    v_pool = jax.random.normal(ks[1], pool_shape).astype(pool_dtype)
+    q = jax.random.normal(ks[2], (t, h, d)).astype(pool_dtype)
+    k_new = jax.random.normal(ks[3], (t, kh, d)).astype(pool_dtype)
+    v_new = jax.random.normal(ks[4], (t, kh, d)).astype(pool_dtype)
+    scores = jax.random.normal(ks[5], (t, max_len))
+    if spec.get("tied"):
+        scores = scores.at[3].set(0.25).at[9, 5:50].set(7.0)
+    if spec.get("own_only"):
+        scores = scores.at[:8, start:].add(100.0)
+    at = start + jnp.arange(t)
+    visible = jnp.arange(max_len)[None, :] <= at[:, None]
+    selection = sa.select_mask(scores, visible, topk)
+    if spec.get("own_only"):
+        assert not np.asarray(selection)[:8, :start].any()
+
+    def view(pool, new):
+        rows = pool[layer][table].reshape(max_len, kh, d)
+        return jax.lax.dynamic_update_slice(rows, new, (start, 0, 0))
+
+    want = np.asarray(sa.masked_attention(
+        q, view(k_pool, k_new), view(v_pool, v_new), selection
+    ), np.float32)
+    if topk >= start + t:
+        np.testing.assert_allclose(want, np.asarray(sa.masked_attention(
+            q, view(k_pool, k_new), view(v_pool, v_new), visible
+        ), np.float32))
+    unread = table[-(-start // bs):]
+    k_pool = k_pool.at[:, unread].set(jnp.nan)
+    v_pool = v_pool.at[:, unread].set(jnp.nan)
+    got = np.asarray(da.sparse_chunk_attention(
+        q, k_new, v_new, k_pool, v_pool, jnp.int32(layer),
+        jnp.asarray(table), jnp.int32(start), selection,
+        None if n_valid is None else jnp.int32(n_valid),
+    ), np.float32)
+    assert np.isfinite(got).all()
+    live = t if n_valid is None else -(-n_valid // 8) * 8
+    assert not got[live:].any()
+    # f32: the order of summation; bf16: the query's scale in its own
+    # dtype, the probabilities' and the output's rounding.
+    tol = dict(rtol=2e-5, atol=2e-6) if pool_dtype == "float32" else dict(
+        rtol=2 ** -6, atol=2 ** -7
+    )
+    np.testing.assert_allclose(got[:live], want[:live], **tol)
+    # A key the selection does not mark is not attended: another
+    # selection is another answer.
+    other = np.asarray(da.sparse_chunk_attention(
+        q, k_new, v_new, k_pool, v_pool, jnp.int32(layer),
+        jnp.asarray(table), jnp.int32(start),
+        sa.select_mask(-scores, visible, topk),
+    ), np.float32)
+    if topk < start + t:
+        assert np.abs(other - got)[t - 1].max() > 1e-2

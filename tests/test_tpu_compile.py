@@ -515,3 +515,88 @@ def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
         # layers add 3 x (1.25 + 0.65) GB of arguments, no temporaries.
         assert m.temp_size_in_bytes < 2.0e9
         assert m.argument_size_in_bytes + m.temp_size_in_bytes < 8e9
+        # The chunk attends under its selection in the Pallas kernel
+        # over the pool in place (PR 34): no gathered [max_len] view of
+        # K or V, no float32 logits a KV head and query block. The
+        # decode step gathers its selected rows as before.
+        view, logits = "bf16[33792,4,128]", "f32[128,8,33792]"
+        if name == "jit_prefill":
+            assert "paged_pool_sparse_chunk_attention" in text
+            assert view not in text and logits not in text
+        else:
+            assert "paged_pool_sparse_chunk_attention" not in text
+
+
+# What ``sparse_chunk_attention_kind`` sees -> what it must answer;
+# unnamed: bf16, 64-row pages of 4 KV heads x 128 under 32 query heads,
+# a 512-token chunk, 528 pages a slot (the cell's shape), on a TPU.
+_SPARSE_KIND_CASES = {
+    "the_cells_shape": ({}, "chunk_kernel"),
+    "eight_kv_heads_16_row_pages": (
+        dict(kv_heads=8, block_size=16, max_blocks=144, chunk=256),
+        "chunk_kernel",
+    ),
+    "off_the_chip": (dict(on_tpu=False), "masked_attention"),
+    "a_float32_pool": (dict(dtype="float32"), "masked_attention"),
+    # a token tile of 64 tokens is no whole lane block of 128
+    "a_chunk_of_half_a_lane_block": (dict(chunk=64), "masked_attention"),
+    # 12 KV heads are no whole tile of the pool's layout
+    "twelve_kv_heads": (dict(kv_heads=12, heads=48), "masked_attention"),
+    # a quarter of a million rows a slot: the selection's block alone
+    # is 2 x 33 MB of VMEM
+    "a_slot_of_262k_rows": (dict(max_blocks=4096), "masked_attention"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPARSE_KIND_CASES))
+def test_sparse_chunk_attention_kind_admits_only_what_compiles(
+    case, one_chip, monkeypatch,
+):
+    """The sparse chunk's attention is chosen by what the code can see,
+    and nothing falls back after the choice: where the answer is
+    ``chunk_kernel`` the kernel compiles for the described v5e at that
+    shape (the pool read in place: no copy of it), and a float32 pool,
+    another platform or a shape outside the kernel's tiling or VMEM
+    answers ``masked_attention``."""
+    from dlrover_tpu.models import sparse_lm
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    seen, want = _SPARSE_KIND_CASES[case]
+    seen = dict(seen)
+    on_tpu = seen.pop("on_tpu", True)
+    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    h, kh = seen.get("heads", 32), seen.get("kv_heads", 4)
+    bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 528)
+    t, dtype = seen.get("chunk", 512), seen.get("dtype", "bfloat16")
+    cfg = sparse_lm.tiny_config(
+        n_heads=h, n_kv_heads=kh, head_dim=128, dtype=dtype
+    )
+    assert paged.sparse_chunk_attention_kind(
+        cfg, cfg.compute_dtype, bs, t, mb
+    ) == want
+    if want != "chunk_kernel":
+        return
+    d, n_layers, nb = 128, 2, mb + 1
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip
+    )
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = arr((n_layers, nb, bs, kh, d), bf)
+    c = jax.jit(
+        lambda *a: (da.sparse_chunk_attention(*a), a[3], a[4]),
+        donate_argnums=(3, 4),
+    ).lower(
+        arr((t, h, d), bf), arr((t, kh, d), bf), arr((t, kh, d), bf),
+        pool, pool, arr((), i32), arr((mb,), i32), arr((), i32),
+        arr((t, mb * bs), bool), arr((), i32),
+    ).compile()
+    text = c.as_text()
+    assert _n_kernels(c) == 1
+    assert "paged_pool_sparse_chunk_attention" in text
+    # the pools go in and out untouched: the collapse of [block_size,
+    # kv_heads] into rows is a bitcast of their layout, not a copy
+    assert f"bf16[{n_layers},{nb}," not in "".join(
+        line for line in text.splitlines() if " copy(" in line
+    )
+    assert c.memory_analysis().temp_size_in_bytes < 64e6

@@ -2,7 +2,16 @@
 ``keye-serve-docqa-32k``'s shape (16 slots x 33,792 rows, a 512-token
 chunk, 5 layers of 128 experts): which of score / select / compact /
 gather / attend / experts a decode step and a prefill chunk spend their
-time in, and the two whole programs.
+time in, and the two whole programs. The part ``chunk_kernel`` is the
+A/B of the chunk's attention under its selection, one layer: the Pallas
+kernel over the pool in place
+(``ops.decode_attention.sparse_chunk_attention``) at 1, 2 and 4 valid
+query tiles beside ``masked_attention`` over the gathered views (one
+128-query block, which is what the ``jax.numpy`` path runs a block), the
+two views' gathers it no longer needs, both sides' worst difference from
+the float32 softmax over the selected keys, and the kernel again with
+its mask, its ``exp``, its ``PV`` product or its strided head reads
+taken out (wrong answers, timed only: what is left says what bounds it).
 
     chiprun -- python3 tools/bench_sparse_attention.py [--parts ...] [--layers N]
     JAX_PLATFORMS=cpu python3 tools/bench_sparse_attention.py --tiny   # rehearsal, times nothing real
@@ -35,6 +44,141 @@ def timed(fn, *args, iters=5):
     jax.block_until_ready(out)
     return {"ms": 1e3 * (time.time() - t0) / iters,
             "first_call_s": round(compile_s, 2)}
+
+
+class _Ablated:
+    """``ops.decode_attention`` with a part of the chunk kernels taken
+    out while a kernel is traced (wrong answers, for timing alone):
+    ``mask`` (the select on the scores), ``exp`` (the identity in its
+    place), ``pv`` (no probabilities-by-values product), ``head_rows``
+    (a KV head's rows read as a contiguous run instead of a strided
+    load and a shift). ``what`` names them, ``+`` between."""
+
+    def __init__(self, module, what):
+        import jax
+        import jax.numpy as jnp
+
+        self._module, self._jnp, self._lax = module, jnp, jax.lax
+        self._what = set(filter(None, what.split("+")))
+        self._over = {}
+        if "mask" in self._what:
+            self._over["where"] = lambda c, a, b: (
+                a if isinstance(b, float) else jnp.where(c, a, b)
+            )
+        if "exp" in self._what:
+            self._over["exp"] = lambda x: x
+        self._dot = jax.lax.dot_general
+        self._head_rows = module._head_rows
+
+    def __getattr__(self, name):
+        return self._over.get(name) or getattr(self._jnp, name)
+
+    def __enter__(self):
+        jnp = self._jnp
+        self._module.jnp = self
+        if "pv" in self._what:
+            def dot_general(a, b, dims, **kw):
+                if dims == (((0,), (0,)), ((), ())):
+                    return jnp.zeros((a.shape[1], b.shape[1]), jnp.float32)
+                return self._dot(a, b, dims, **kw)
+
+            self._lax.dot_general = dot_general
+        if "head_rows" in self._what:
+            self._module._head_rows = lambda ref, head, kv_heads, rows: (
+                jnp.concatenate(
+                    [ref[j, :rows] for j in range(ref.shape[0])], axis=-1
+                )
+            )
+
+    def __exit__(self, *exc):
+        self._module.jnp = self._jnp
+        self._lax.dot_general = self._dot
+        self._module._head_rows = self._head_rows
+
+
+def chunk_kernel_part(cfg, eng, report, rng, iters, tiny):
+    """The chunk's attention under its selection, one layer, both ways
+    (see the module's docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.ops import decode_attention as da
+    from dlrover_tpu.ops import sparse_attention as sa
+    from dlrover_tpu.serving.kvpool import sparse
+
+    max_len, chunk = eng["max_len"], eng["prefill_chunk"]
+    bs = eng["block_size"]
+    h, kh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    topk = min(cfg.index_topk, max_len)
+    cdt = cfg.compute_dtype
+    mb = max_len // bs
+    nb = (eng.get("num_blocks") or 16 * mb + 1) if not tiny else 2 * mb + 1
+    f = lambda *s: jnp.asarray(rng.normal(size=s), cdt)  # noqa: E731
+    k_pool, v_pool = f(1, nb, bs, kh, hd), f(1, nb, bs, kh, hd)
+    table = jnp.asarray(1 + rng.permutation(nb - 1)[:mb], jnp.int32)
+    start = max_len - 2 * chunk
+    q, k_new, v_new = f(chunk, h, hd), f(chunk, kh, hd), f(chunk, kh, hd)
+    at = start + jnp.arange(chunk)
+    visible = jnp.arange(max_len)[None, :] <= at[:, None]
+    selection = jax.jit(lambda s, v: sa.select_mask(s, v, topk))(
+        jnp.asarray(rng.normal(size=(chunk, max_len)), jnp.float32), visible
+    )
+    layer, start = jnp.int32(0), jnp.int32(start)
+    sub = min(sparse.CHUNK_QUERY_BLOCK, chunk)
+    tile = da._chunk_token_tile(chunk, h) or chunk
+    report("chunk_kernel.shape", {
+        "start": int(start), "token_tile": tile, "select_block": sub,
+        "kind_here": "chunk_kernel" if da.sparse_chunk_kernel_supported(
+            cdt, bs, h, kh, hd, chunk, mb) else "masked_attention",
+    })
+
+    views = jax.jit(lambda kp, vp, kn, vn: tuple(
+        sparse._slot_view(p, layer, table, n[None], start, bs)
+        for p, n in ((kp, kn), (vp, vn))
+    ))
+    k_view, v_view = views(k_pool, v_pool, k_new, v_new)
+    masked = jax.jit(sa.masked_attention)
+    if not tiny:
+        report("chunk_kernel.view_gathers_k_and_v", timed(
+            views, k_pool, v_pool, k_new, v_new, iters=iters))
+        report("chunk_kernel.masked_attention_one_block", timed(
+            masked, q[:sub], k_view, v_view, selection[:sub], iters=iters))
+
+    def kernel():
+        fn = jax.jit(lambda kp, vp, sel, n_valid: da.sparse_chunk_attention(
+            q, k_new, v_new, kp, vp, layer, table, start, sel, n_valid,
+        ))
+        return lambda n_valid: fn(k_pool, v_pool, selection, n_valid)
+
+    # Both against the float32 softmax over the selected keys.
+    want = jax.jit(sa.masked_attention)(
+        q.astype(jnp.float32), k_view.astype(jnp.float32),
+        v_view.astype(jnp.float32), selection,
+    )
+    err = lambda got: float(jnp.max(jnp.abs(  # noqa: E731
+        got.astype(jnp.float32) - want)))
+    report("chunk_kernel.max_abs_err_vs_float32", {
+        "kernel": err(kernel()(jnp.int32(chunk))),
+        "masked_attention": err(jnp.concatenate([
+            masked(q[lo:lo + sub], k_view, v_view, selection[lo:lo + sub])
+            for lo in range(0, chunk, sub)
+        ])),
+    })
+    if tiny:
+        return
+    for what in ("", "mask", "exp", "mask+exp", "pv", "head_rows",
+                 "mask+exp+pv+head_rows"):
+        with _Ablated(da, what):
+            fn = kernel()
+            for tiles in (1, 2, 4):
+                if tiles * tile > chunk:
+                    continue
+                report(
+                    f"chunk_kernel.kernel_{tiles}_tiles"
+                    + (f".without_{what}" if what else ""),
+                    timed(fn, jnp.int32(tiles * tile), iters=iters),
+                )
 
 
 def profile(engine, decode, prefill, calls=3, top=14):
@@ -85,7 +229,7 @@ def main(argv=None):
                     help="trace 3 calls of each program: ms a call by "
                          "scope and by op")
     ap.add_argument("--parts", nargs="*", default=[
-        "ops_decode", "ops_chunk", "experts", "programs",
+        "ops_decode", "ops_chunk", "chunk_kernel", "experts", "programs",
     ])
     args = ap.parse_args(argv)
     import jax
@@ -159,6 +303,8 @@ def main(argv=None):
         q, k = f(chunk, h, hd), f(max_len, kh, hd)
         report("chunk.masked_attention", timed(
             jax.jit(sa.masked_attention), q, k, k, mask, iters=it))
+    if "chunk_kernel" in args.parts:
+        chunk_kernel_part(cfg, eng, report, rng, it, args.tiny)
     if "experts" in args.parts or "programs" in args.parts:
         params = jax.jit(
             lambda key: sparse_lm.init_params(cfg, key, dtype=cdt)
