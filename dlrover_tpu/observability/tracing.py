@@ -45,6 +45,17 @@ Design rules, same discipline as :func:`dlrover_tpu.fault.fault_point`:
   or ``close()`` flushes it. Steps share the ring with request spans:
   the default 4,096 then reach back 75 s where requests alone had
   3 minutes (docs/DESIGN.md §29).
+- **Set-up spans are local too.** ``compile.backend`` (attrs
+  ``fun_name``, ``cache``: ``hit`` / ``written`` / ``uncached``),
+  ``compile.cache_load`` and ``compile.trace_lower``
+  (common/compile_cache.py: one per compile-or-load, cache hit and
+  outermost trace or lowering, on JAX's own wall reads through
+  ``record_span(..., start_wall=)``), and the engines'
+  ``serving.engine_build`` / ``serving.warmup``. Armed, a compile event
+  costs its listener (a few microseconds, which it costs disarmed too)
+  plus one ring append, ~25 us; after warm-up nothing compiles, so a
+  steady window holds none of them (PERF.md, PR 35: 0 records in the
+  30 s window of every cell).
 - **One clock with the device trace.** A record's ``ts`` is epoch
   seconds (``time.time()`` back-dated by the monotonic distance),
   ``mono`` is ``time.monotonic()``. The JAX profiler's host plane
@@ -299,6 +310,7 @@ class Tracer:
         attrs: Optional[Dict] = None,
         status: str = "ok",
         local: bool = False,
+        start_wall: Optional[float] = None,
     ) -> Span:
         """Retrospective span from already-recorded monotonic
         timestamps — the hot-loop pattern: the engine/trainer keeps
@@ -308,10 +320,12 @@ class Tracer:
 
         ``local`` spans stay in this process: ring and JSONL sink, but
         neither the export buffer nor ``on_finish`` (the module
-        docstring says why ``serving.step`` is one)."""
+        docstring says why ``serving.step`` is one). ``start_wall``:
+        the epoch read the caller already has for ``start_mono`` (a
+        compile span carries JAX's own), else derived from now."""
         trace_id, parent_id = self._resolve_parent(parent)
-        now_mono = time.monotonic()
-        start_wall = time.time() - (now_mono - start_mono)
+        if start_wall is None:
+            start_wall = time.time() - (time.monotonic() - start_mono)
         sp = Span(
             self, name, kind, trace_id, parent_id, attrs,
             start_mono=start_mono, start_wall=start_wall,

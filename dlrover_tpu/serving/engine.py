@@ -93,17 +93,34 @@ STEP_PHASES = (
 )
 
 
-class _StepTrace:
-    """Marks and counts of ONE armed ``step()``: plain floats and ints
-    while the iteration runs, one retrospective span at its end."""
+# Phases of the two set-up spans, named like a step's by the mark that
+# closes them, each name once a span. ``serving.engine_build``: the
+# paged engine's allocator and trie (``prefix_cache``), the eager fused
+# copy of the weights (``fuse_params``), the jit wrappers (``build_programs``;
+# nothing compiles there, jit is lazy), the value pools
+# (``alloc_pool``), the host mirrors of the slots (``host_state``), and
+# after the base constructor the paged engine's scale and index-key
+# pools (``alloc_side_pools``) and its own jit wrappers
+# (``paged_programs``). ``serving.warmup``: one phase a program run,
+# named as ``trace_counts`` names it (``decode`` twice: fed by the
+# host, then by a launch), each closed by a ``block_until_ready`` so
+# that a program's compile, load and first run are its own, then
+# ``reset_pool``. The ``compile.*`` spans of common/compile_cache.py
+# fall inside these by time.
+BUILD_PHASES = ("prefix_cache", "fuse_params", "build_programs",
+                "alloc_pool", "host_state", "alloc_side_pools",
+                "paged_programs")
 
-    __slots__ = ("t0", "last", "phases", "counts")
+
+class _PhaseMarks:
+    """Clock reads at phase boundaries: ``phases`` is ``[[name,
+    offset_s, dur_s], ...]`` and tiles ``t0``..``last``."""
+
+    __slots__ = ("t0", "last", "phases")
 
     def __init__(self, t0: float):
         self.t0 = self.last = t0
         self.phases: List[list] = []
-        self.counts = {"n_admitted": 0, "n_decoding": 0,
-                       "prefill_tokens": 0, "overlapped": 0}
 
     def mark(self, phase: str, at: Optional[float] = None) -> None:
         """Close ``phase`` now (or at an already-taken clock read)."""
@@ -111,6 +128,29 @@ class _StepTrace:
             at = time.monotonic()
         self.phases.append([phase, self.last - self.t0, at - self.last])
         self.last = at
+
+    def emit(self, name: str, **attrs) -> float:
+        """The marks as one ``local`` span when a Tracer is armed;
+        returns the seconds they cover either way."""
+        tracer = tracing.active_tracer()
+        if tracer is not None:
+            tracer.record_span(
+                name, self.t0, self.last, local=True,
+                attrs=dict(attrs, phases=self.phases),
+            )
+        return self.last - self.t0
+
+
+class _StepTrace(_PhaseMarks):
+    """Marks and counts of ONE armed ``step()``: plain floats and ints
+    while the iteration runs, one retrospective span at its end."""
+
+    __slots__ = ("counts",)
+
+    def __init__(self, t0: float):
+        super().__init__(t0)
+        self.counts = {"n_admitted": 0, "n_decoding": 0,
+                       "prefill_tokens": 0, "overlapped": 0}
 
 
 class _Flight:
@@ -428,6 +468,7 @@ class ServingEngine:
         spec_k: int = 0,
         spec_drafter: str = "ngram",
         spec_draft_layers: int = 2,
+        build_marks: Optional[_PhaseMarks] = None,
     ):
         if config.pp_stages > 1:
             raise NotImplementedError(
@@ -435,6 +476,10 @@ class ServingEngine:
                 "stages for inference"
             )
         enable_compile_cache()  # before the step programs compile
+        # Construction times itself (a dozen clock reads, armed or
+        # not). A subclass that began the marks hands them in as
+        # ``build_marks``, and ends them itself.
+        build = build_marks or _PhaseMarks(time.monotonic())
         if max_len % 8:
             raise ValueError("max_len must be a multiple of 8")
         if max_len % prefill_chunk:
@@ -487,7 +532,10 @@ class ServingEngine:
         )
         self.metrics = serving_metrics(registry)
         self.metrics.slots_total.set(slots)
-        self._params = gen_lib.prepare_decode_params(config, params)
+        self._params = jax.block_until_ready(
+            gen_lib.prepare_decode_params(config, params)
+        )
+        build.mark("fuse_params")
         self._steps = _compiled_steps(config, slots, max_len,
                                       prefill_chunk)
         self._spec = (
@@ -509,7 +557,9 @@ class ServingEngine:
         self._trace_snapshot = self._all_trace_counts()
         self._rng = rng if rng is not None else jax.random.key(0)
         self._step_idx = 0
-        self._k, self._v = self._fresh_pool()
+        build.mark("build_programs")
+        self._k, self._v = jax.block_until_ready(self._fresh_pool())
+        build.mark("alloc_pool")
         # Host mirrors of the device-side per-slot state; passed into
         # every step call (tiny H2D) so host and device can never
         # drift. ``_lengths`` advances when a launch is enqueued;
@@ -533,6 +583,26 @@ class ServingEngine:
         # (models/sparse_lm.py): how many rows a query keeps, else 0.
         self._index_topk = getattr(config, "index_topk", 0)
         self._moe_rows_dropped = 0
+        self.engine_build_s = self.warmup_s = 0.0
+        build.mark("host_state")
+        if build_marks is None:
+            self._end_build(build)
+
+    def _end_build(self, build: _PhaseMarks) -> None:
+        """Close construction: ``engine_build_s``, and armed one
+        ``serving.engine_build`` span whose ``phases`` tile it."""
+        self.engine_build_s = build.emit(
+            "serving.engine_build", **self._build_bytes()
+        )
+
+    def _build_bytes(self) -> Dict[str, int]:
+        return {
+            "params_bytes": sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(self._params)
+            ),
+            "pool_bytes": self._k.nbytes + self._v.nbytes,
+            "index_pool_bytes": 0,
+        }
 
     def _fresh_pool(self):
         shape = (
@@ -607,12 +677,15 @@ class ServingEngine:
         """Compile both step programs on throwaway state, then reset the
         pool — so the first real request pays no compile and the
         trace counters are settled for no-retrace assertions."""
+        marks = _PhaseMarks(time.monotonic())
         chunk = np.zeros((1, self.prefill_chunk), np.int32)
         k, v, first = self._steps.prefill(
             self._k, self._v, self._params, jnp.asarray(chunk),
             np.int32(0), np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0),
         )
+        jax.block_until_ready(first)
+        marks.mark("prefill")
         # Both ways a launch is fed: the host's tokens and a chunk's
         # first token, then the vector that launch returned.
         fed = jnp.asarray(np.zeros(self.slots, np.int32))
@@ -624,6 +697,8 @@ class ServingEngine:
                 jnp.asarray(np.zeros(self.slots, np.float32)),
                 self._rng, np.int32(0), first, np.int32(first_slot),
             )
+            jax.block_until_ready(fed)
+            marks.mark("decode")
         nxt = fed
         if self._spec is not None:
             z_i = jnp.asarray(np.zeros(self.slots, np.int32))
@@ -636,15 +711,23 @@ class ServingEngine:
                 k, v, drafts = self._spec.draft(
                     k, v, self._params, z_i, z_i, z_b
                 )
+                jax.block_until_ready(drafts)
+                marks.mark("draft")
             k, v, _, acc = self._spec.verify(
                 k, v, self._params, z_i, z_i, drafts, z_i, z_b, z_f,
                 self._rng, np.int32(0),
             )
             nxt = acc
-        jax.block_until_ready(nxt)
+            jax.block_until_ready(nxt)
+            marks.mark("verify")
         del k, v
-        self._k, self._v = self._fresh_pool()
+        self._k, self._v = jax.block_until_ready(self._fresh_pool())
         self._trace_snapshot = self._all_trace_counts()
+        self._end_warmup(marks)
+
+    def _end_warmup(self, marks: _PhaseMarks) -> None:
+        marks.mark("reset_pool")
+        self.warmup_s = marks.emit("serving.warmup")
 
     def step(self) -> List[Request]:
         """One scheduler iteration: admissions, at most one prefill
@@ -711,7 +794,6 @@ class ServingEngine:
         self._done = []
         idx = self._step_idx
         self._step_idx += 1
-        self.metrics.iterations.inc()
         self.metrics.queue_depth.set(len(sch.queue))
         for name, depth in sch.queue_depth_by_class().items():
             self.metrics.class_queue_depth.set(depth, slo_class=name)
@@ -1120,7 +1202,6 @@ class ServingEngine:
                 if dl - n_acc:
                     self.metrics.spec_tokens.inc(dl - n_acc,
                                                  kind="rejected")
-                self.metrics.spec_accept_rate.observe(n_acc / dl)
             self._spec_emitted += n_acc + 1
             self._spec_slot_steps += 1
             self._iter_advance.append(n_acc + 1)

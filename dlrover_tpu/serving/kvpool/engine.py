@@ -62,6 +62,7 @@ slot's logical cache is the pool rows its BLOCK TABLE names:
 """
 
 import functools
+import time
 from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
@@ -74,6 +75,7 @@ from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import llama
 from dlrover_tpu.serving.engine import (
     ServingEngine,
+    _PhaseMarks,
     _h2d,
     _place_first,
 )
@@ -913,6 +915,9 @@ class PagedServingEngine(ServingEngine):
                 f"prefill_chunk {prefill_chunk} and block_size "
                 f"{block_size} must divide one another"
             )
+        # serving.engine_build opens here and ends with this __init__
+        # (the base constructor marks its own phases into it).
+        build = _PhaseMarks(time.monotonic())
         self.kv_cache_dtype = kv_cache_dtype
         self.block_size = block_size
         self.max_blocks = max_len // block_size
@@ -945,6 +950,7 @@ class PagedServingEngine(ServingEngine):
         self._prefix_misses = 0
         self._prefix_hit_blocks = 0
         self._prefix_hit_tokens = 0   # prompt rows no chunk had to run
+        build.mark("prefix_cache")
         # The base __init__ builds the value pools via _fresh_pool();
         # the int8 scale pools pair up right after it returns (nothing
         # in between touches them).
@@ -954,10 +960,12 @@ class PagedServingEngine(ServingEngine):
             drain_mode=drain_mode, rng=rng, registry=registry,
             max_requeues=max_requeues, slo_classes=slo_classes,
             spec_k=spec_k, spec_drafter=spec_drafter,
-            spec_draft_layers=spec_draft_layers,
+            spec_draft_layers=spec_draft_layers, build_marks=build,
         )
         self._kscale, self._vscale = self._fresh_scales()
         self._ki = self._fresh_index_keys()
+        jax.block_until_ready((self._kscale, self._vscale, self._ki))
+        build.mark("alloc_side_pools")
         # Block watermark: only admit a request the pool can hold
         # (prompt + first decode block) counting evictable cache as
         # free — otherwise bursty arrivals thrash preemptions, each
@@ -980,12 +988,6 @@ class PagedServingEngine(ServingEngine):
             self.pool_attention,
             ", the chunk under its selection by "
             f"{self.sparse_chunk_attention}" if self._index_dim else "",
-        )
-        self.metrics.annotate(
-            "serving_engine_built", slots=slots, max_len=max_len,
-            pool_attention=self.pool_attention,
-            **({"sparse_chunk_attention": self.sparse_chunk_attention}
-               if self._index_dim else {}),
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -1012,6 +1014,17 @@ class PagedServingEngine(ServingEngine):
             )
         )
         self.metrics.kv_blocks_total.set(self._allocator.managed)
+        build.mark("paged_programs")
+        self._end_build(build)
+
+    def _build_bytes(self) -> Dict[str, int]:
+        sizes = super()._build_bytes()
+        index = self._ki.nbytes if self._ki is not None else 0
+        sizes["pool_bytes"] = (
+            sum(p.nbytes for p in self._pools()) - index
+        )
+        sizes["index_pool_bytes"] = index
+        return sizes
 
     # ---- pool construction / programs --------------------------------------
 
@@ -1104,6 +1117,7 @@ class PagedServingEngine(ServingEngine):
     def warmup(self) -> None:
         """Compile all three paged programs on throwaway state, then
         rebuild the pool — first real request pays no compile."""
+        marks = _PhaseMarks(time.monotonic())
         chunk = np.zeros((1, self.prefill_chunk), np.int32)
         pools = self._pools()
         *pools, first = self._steps.prefill(
@@ -1112,6 +1126,8 @@ class PagedServingEngine(ServingEngine):
             np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0), np.bool_(True),
         )
+        jax.block_until_ready(first)
+        marks.mark("prefill")
         # Both ways a launch is fed: the host's tokens and a chunk's
         # first token, then the vector that launch returned.
         fed = jnp.asarray(np.zeros(self.slots, np.int32))
@@ -1127,7 +1143,12 @@ class PagedServingEngine(ServingEngine):
                 self._rng, np.int32(0), first, np.int32(first_slot),
             )
             pools, fed = out[:n_pools], out[n_pools]
-        pools = self._steps.cow(*pools, np.int32(0), np.int32(0))
+            jax.block_until_ready(fed)
+            marks.mark("decode")
+        pools = jax.block_until_ready(
+            self._steps.cow(*pools, np.int32(0), np.int32(0))
+        )
+        marks.mark("cow")
         blk_shape = (
             self.config.n_layers, self.block_size,
             self.config.n_kv_heads, self.config.head_dim,
@@ -1147,9 +1168,12 @@ class PagedServingEngine(ServingEngine):
                 for p in pools[2:]
             ]
             pools = self._steps.imp(*pools, zf, zf, *extra, np.int32(0))
+        jax.block_until_ready(pools)
+        marks.mark("imp")
         # Export gather (non-donating): warm so the first migration
         # out of this engine never stalls the serve loop on a compile.
         jax.block_until_ready(self._steps.exp(*pools, np.int32(0)))
+        marks.mark("exp")
         if self._spec is not None:
             tbl = jnp.asarray(
                 np.zeros((self.slots, self.max_blocks), np.int32)
@@ -1164,17 +1188,21 @@ class PagedServingEngine(ServingEngine):
                 *pools, drafts = self._spec.draft(
                     *pools, self._params, tbl, z_i, z_i, z_b
                 )
+                jax.block_until_ready(drafts)
+                marks.mark("draft")
             *pools, _em, acc = self._spec.verify(
                 *pools, self._params, tbl, z_i, z_i, drafts, z_i,
                 z_b, z_f, self._rng, np.int32(0),
             )
             jax.block_until_ready(acc)
-        jax.block_until_ready(pools[-1])
+            marks.mark("verify")
         del pools
         self._k, self._v = self._fresh_pool()
         self._kscale, self._vscale = self._fresh_scales()
         self._ki = self._fresh_index_keys()
+        jax.block_until_ready(self._pools())
         self._trace_snapshot = self._all_trace_counts()
+        self._end_warmup(marks)
 
     # ---- block bookkeeping -------------------------------------------------
 
@@ -1317,11 +1345,8 @@ class PagedServingEngine(ServingEngine):
                 self._allocator.decref(block)
             hit = hit[:keep]
         if not hit:
-            self.metrics.prefix_lookups.inc(outcome="miss")
             self._prefix_misses += 1
             return
-        self.metrics.prefix_lookups.inc(outcome="hit")
-        self.metrics.prefix_hit_blocks.inc(len(hit))
         self._prefix_hits += 1
         self._prefix_hit_blocks += len(hit)
         req.prefix_hit_blocks = len(hit)
@@ -1335,10 +1360,6 @@ class PagedServingEngine(ServingEngine):
             counts["prefix_hit_tokens"] = (
                 counts.get("prefix_hit_tokens", 0) + start
             )
-        self.metrics.annotate(
-            "serving_prefix_hit", rid=req.rid, blocks=len(hit),
-            resumed_at=start,
-        )
 
     def _release_slot(self, req: Request, slot: int) -> None:
         for block in self._slot_blocks[slot]:
@@ -1507,6 +1528,10 @@ class PagedServingEngine(ServingEngine):
         )
         stats["cow_copies"] = self._allocator.cow_copies_total
         stats["pool_attention"] = self.pool_attention
+        # What a restart pays before the first request: construction
+        # and warm-up as the engine timed them (0.0: not warmed up).
+        stats["engine_build_s"] = self.engine_build_s
+        stats["warmup_s"] = self.warmup_s
         if self._index_dim:
             # The third per-token array: its share of the bytes above,
             # and the whole array's size on the device.

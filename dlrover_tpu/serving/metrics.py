@@ -58,9 +58,6 @@ class ServingMetrics:
             "useful-token fraction in /api/goodput",
             labelnames=("kind",),
         )
-        self.iterations = reg.counter(
-            "serving_iterations_total", "engine scheduler iterations"
-        )
         self.retraces = reg.counter(
             "serving_retraces_total",
             "step-program traces (must stay flat after warmup)",
@@ -122,16 +119,6 @@ class ServingMetrics:
             "bytes of KV pool HBM referenced by live slots or the "
             "prefix cache (allocated blocks x block bytes, K+V)",
         )
-        self.prefix_lookups = reg.counter(
-            "serving_prefix_lookups_total",
-            "prefix-cache lookups at admission, by outcome",
-            labelnames=("outcome",),
-        )
-        self.prefix_hit_blocks = reg.counter(
-            "serving_prefix_hit_blocks_total",
-            "warm blocks handed to admitted requests by the prefix "
-            "cache (each skips block_size tokens of prefill)",
-        )
         self.kv_cow_copies = reg.counter(
             "serving_kv_cow_copies_total",
             "copy-on-write block privatizations (a shared block was "
@@ -155,12 +142,6 @@ class ServingMetrics:
             "running mean of tokens committed per verify step across "
             "decoding slots (accepted drafts + the correction/bonus "
             "token; 1.0 = no speculation win, K+1 = every draft lands)",
-        )
-        self.spec_accept_rate = reg.histogram(
-            "serving_spec_accept_rate",
-            "per-slot fraction of drafted tokens accepted by one "
-            "verify step (observed only for slots that drafted)",
-            buckets=(0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0),
         )
 
     def annotate(self, event: str, **fields):

@@ -15,7 +15,6 @@ import time
 from dataclasses import dataclass
 from typing import Optional
 
-from dlrover_tpu.common.compile_cache import enable_compile_cache
 from dlrover_tpu.common.constants import NodeEnv, WorkerEnv
 from dlrover_tpu.common.log import logger
 
@@ -70,6 +69,11 @@ def init_distributed(timeout_secs: int = 300) -> DistributedContext:
     if _context is not None:
         return _context
     ctx = read_worker_env()
+    # Imported here: the module listens to JAX's compile events from
+    # its import on, so it imports JAX, and this module's importers
+    # (the checkpoint engine's saver side) must not.
+    from dlrover_tpu.common.compile_cache import enable_compile_cache
+
     enable_compile_cache()
     if ctx.num_processes > 1 and ctx.coordinator_address:
         import jax

@@ -223,13 +223,18 @@ def test_disarmed_no_span_and_no_added_clock_read(
     monkeypatch.setattr(engine_mod, "time", clock)
     tracer = tracing.arm(Tracer(service="test"))
     try:
-        serve(build(kind, parts))
+        eng = build(kind, parts)
+        armed_build_reads, clock.reads = clock.reads, 0
+        serve(eng)
         steps = [s["attrs"] for s in step_spans(tracer)]
     finally:
         tracing.disarm()
     armed_reads, clock.reads = clock.reads, 0
     n_spans = len(tracer.finished())
     eng = build(kind, parts)
+    # Construction marks its phases armed or not: the same few reads.
+    assert clock.reads == armed_build_reads <= 12
+    clock.reads = 0
     serve(eng)
     assert len(tracer.finished()) == n_spans and eng._step_trace is None
     # What step() read before it timed itself: its own start, the

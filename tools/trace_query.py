@@ -31,6 +31,12 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # steps recompiled)
     python tools/trace_query.py --steps spans_engine.jsonl
 
+    # a worker's or a replica's start: seconds compiling, loading
+    # from the persistent cache and tracing + lowering by program (with
+    # how the cache answered each compile), the cache directory as the
+    # process found it, and the engine's build and warm-up phases
+    python tools/trace_query.py --setup spans_engine.jsonl
+
     # one trace's tree + critical path (a serving.step: its phases)
     python tools/trace_query.py --trace 7f3a... spans_*.jsonl
 
@@ -182,6 +188,44 @@ def step_summary(spans: List[Dict]) -> Dict:
     }}
 
 
+def setup_summary(spans: List[Dict]) -> Dict:
+    """A process's start from its own spans (§29): the program's one
+    aggregation (``common/compile_cache.py``'s ``setup_summary``) over
+    the sink's ``compile.*`` and engine set-up spans. The sink of a
+    process armed after its first compile holds only what came
+    later."""
+    # Imported here: the module listens to JAX, so it imports it.
+    from dlrover_tpu.common import compile_cache
+
+    return compile_cache.setup_summary(
+        compile_cache.records_from_spans(spans), spans
+    )
+
+
+def _print_setup(table: Dict) -> None:
+    cache = table["cache"]
+    if cache:
+        print(f"cache directory at start: {cache['entries']} entries, "
+              f"{cache['bytes']} bytes")
+    print(f"{'program':<36}{'compile_s':>11}{'load_s':>9}{'saved_s':>9}"
+          f"{'trace_lower_s':>15}{'hit':>5}{'written':>9}{'uncached':>10}")
+    for r in table["programs"] + [dict(table["totals"], name="(all)")]:
+        print(
+            f"{r['name'][:35]:<36}{r['compile_s']:>11.3f}"
+            f"{r['cache_load_s']:>9.3f}{r['saved_s']:>9.3f}"
+            f"{r['trace_lower_s']:>15.3f}"
+            f"{r['hit']:>5}{r['written']:>9}{r['uncached']:>10}"
+        )
+    for e in table["engine"]:
+        sizes = "  ".join(
+            f"{k}={v}" for k, v in e.items()
+            if k not in ("name", "dur_s", "phases")
+        )
+        print(f"{e['name']} {e['dur_s']:.3f} s  {sizes}")
+        for phase, seconds in e["phases"]:
+            print(f"  {seconds:>9.3f} s  {phase}")
+
+
 def _sparse_counts(attrs: List[Dict]) -> Dict:
     out = {}
     for name, count in (("selected_rows_mean", "selected_rows"),
@@ -293,6 +337,11 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", action="store_true",
                     help="per-phase latency table from serving.step "
                     "spans (share of step time) + the steps' counts")
+    ap.add_argument("--setup", action="store_true",
+                    help="a process's start: compile / cache-load / "
+                    "trace seconds by program from compile.* spans, the "
+                    "cache directory at start, engine build and "
+                    "warm-up phases")
     ap.add_argument("--trace",
                     help="print one trace's tree + critical path")
     ap.add_argument("--json", action="store_true",
@@ -319,6 +368,18 @@ def main(argv=None) -> int:
                 f"  {hop['dur_s'] * 1e3:9.3f}ms "
                 f"(self {hop['self_s'] * 1e3:8.3f}ms)  {hop['name']}"
             )
+        return 0
+
+    if ns.setup:
+        table = setup_summary(spans)
+        if not table["programs"] and not table["engine"]:
+            print("no compile.* or engine set-up spans found",
+                  file=sys.stderr)
+            return 1
+        if ns.json:
+            print(json.dumps(table))
+        else:
+            _print_setup(table)
         return 0
 
     if ns.summary or ns.verbs or ns.serving or ns.steps:
