@@ -83,6 +83,7 @@ from dlrover_tpu.serving.kvpool.allocator import (
     BlockAllocator,
     BlockPoolExhausted,
 )
+from dlrover_tpu.serving.kvpool.index_pool import IndexKeyPool
 from dlrover_tpu.serving.kvpool.prefix_cache import PrefixCache
 from dlrover_tpu.serving import spec_decode as spec_lib
 from dlrover_tpu.serving.scheduler import DECODE, PREFILL, Request
@@ -983,7 +984,8 @@ class PagedServingEngine(ServingEngine):
             "(%s KV%s), decode and prefill attention %s%s",
             slots, max_len, self.num_blocks, block_size,
             kv_cache_dtype,
-            f" + index keys [{self._index_dim}] a row, top-"
+            f" + index keys [{self._index_dim}], "
+            f"{self.index_tokens_per_row} to a row, top-"
             f"{config.index_topk}" if self._index_dim else "",
             self.pool_attention,
             ", the chunk under its selection by "
@@ -1042,6 +1044,13 @@ class PagedServingEngine(ServingEngine):
         )
 
     @property
+    def index_tokens_per_row(self) -> int:
+        """Tokens a row of the index-key pool holds on the device
+        (``index_pool.tokens_per_row``: from ``index_dim`` and
+        ``block_size`` alone); 0 for a model that keeps none."""
+        return self._ki.pack if self._ki is not None else 0
+
+    @property
     def pool_attention(self) -> str:
         """``"paged_kernel"`` or ``"xla_gather"``: what the plain decode
         and prefill programs were built with
@@ -1093,9 +1102,9 @@ class PagedServingEngine(ServingEngine):
         with every value-pool rebuild like the int8 scales."""
         if not self._index_dim:
             return None
-        return jnp.zeros(
-            (self.config.n_layers, self.num_blocks, self.block_size,
-             self._index_dim), self.config.compute_dtype,
+        return IndexKeyPool.zeros(
+            self.config.n_layers, self.num_blocks, self.block_size,
+            self._index_dim, self.config.compute_dtype,
         )
 
     def _fresh_scales(self):
@@ -1161,7 +1170,8 @@ class PagedServingEngine(ServingEngine):
             )
         else:
             # Import hands dequantized f32 host rows (kvpool/migrate),
-            # and a sparse model's index keys as the pool keeps them.
+            # and a sparse model's index keys in the pool's dtype and
+            # logical shape.
             zf = jnp.zeros(blk_shape, jnp.float32)
             extra = [
                 jnp.zeros(p.shape[:1] + p.shape[2:], p.dtype)
@@ -1541,6 +1551,7 @@ class PagedServingEngine(ServingEngine):
             stats["index_pool_bytes"] = (
                 self.num_blocks * self._index_block_bytes
             )
+            stats["index_tokens_per_row"] = self.index_tokens_per_row
             stats["moe_rows_dropped"] = self._moe_rows_dropped
             stats["sparse_chunk_attention"] = self.sparse_chunk_attention
         if self._cache is not None:

@@ -31,8 +31,10 @@ without killing in-flight decodes).
 Payload layout: ``MAGIC | u32 header_len | json header | [index keys]
 | kv wire`` with the kv wire from :func:`ops.kv_quant.kv_to_wire` (its
 own self-describing header carries dtype + shapes). A sparse model's
-blocks also hold index keys (kvpool/sparse.py): they travel as the pool
-keeps them, bit for bit (the header's ``index`` gives dtype, shape and
+blocks also hold index keys (kvpool/sparse.py): they travel in the
+pool's dtype, bit for bit, in the LOGICAL shape ``[layers, n,
+block_size, index_dim]`` whatever a row of the sender's pool holds
+(kvpool/index_pool.py; the header's ``index`` gives dtype, shape and
 byte count), because which rows a query selects hangs on their exact
 values, and a block that arrived without them would be read as zeros. Wall-clock export
 stamps bound the migration pause across processes on one host.

@@ -2,9 +2,12 @@
 reads a learned selection of its cache (``models/sparse_lm.py``).
 
 The pool holds a THIRD per-token array under the same block tables, the
-index keys ``[layers, num_blocks, block_size, index_dim]``: each layer
-scores the slot's index keys through its table, selects, and reads only
-the selected K/V rows (``ops/sparse_attention.py``):
+index keys, logically ``[layers, num_blocks, block_size, index_dim]`` and
+on the device ``pack`` tokens to a 128-lane row
+(``kvpool/index_pool.IndexKeyPool``; a bare array of the logical shape
+is the pool of one token a row, the same code at another row width):
+each layer scores the slot's index keys through its table, selects, and
+reads only the selected K/V rows (``ops/sparse_attention.py``):
 
 - the decode step (``[slots, 1]`` queries) gathers a slot's index keys
   (one small row a token), finds its ``index_topk`` rows and gathers
@@ -21,11 +24,12 @@ the selected K/V rows (``ops/sparse_attention.py``):
 
 Both are append-free like the dense in-place programs: the new rows of
 all layers land after the layer scan (one row a slot, or the chunk's
-blocks). Below ``index_topk`` rows the selection is the causal mask and
-both equal full attention. The programs keep the names ``step`` and
-``prefill`` (a trace names a device op by its program), and the decode
-step returns, after the tokens, ``[experts hit (mean over layers),
-expert rows dropped]`` for the host to fetch with them.
+blocks), the index keys by ``(layer, block, row)`` coordinate. Below
+``index_topk`` rows the selection is the causal mask and both equal full
+attention. The programs keep the names ``step`` and ``prefill`` (a trace
+names a device op by its program), and the decode step returns, after
+the tokens, ``[experts hit (mean over layers), expert rows dropped]``
+for the host to fetch with them.
 """
 
 import jax
@@ -38,23 +42,58 @@ from dlrover_tpu.ops import sparse_attention as sa
 from dlrover_tpu.serving.engine import _place_first
 from dlrover_tpu.serving.kvpool import engine as paged
 from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool.index_pool import (
+    IndexKeyPool,
+    gather_at_layer,
+)
 
 
 def _at_layer(pool, layer, *index):
     """``pool[layer, *index]`` (``index``: arrays of one shape) as ONE
-    gather over the stacked pool. With the layer a traced scalar the
-    indexing slices the layer's whole pool out first (a 0.3 GB copy a
-    layer and a step for K and for V: 18 of the decode step's 44 ms on
-    the chip; PERF.md §6, PR 33); as an array of the others' shape it is
-    one more coordinate of the gather."""
-    return pool[(jnp.full_like(index[0], layer),) + index]
+    gather over the stacked pool (:func:`index_pool.gather_at_layer`).
+    Of an :class:`IndexKeyPool`, by TOKEN coordinates: at ``(blocks,
+    offsets)`` the tokens' keys ``[..., index_dim]``, at ``(blocks,)``
+    the blocks' rows as stored, which flattened are the tokens in
+    order."""
+    if isinstance(pool, IndexKeyPool):
+        if len(index) == 1:
+            return pool.blocks_at(layer, *index)
+        return pool.tokens_at(layer, *index)
+    return gather_at_layer(pool, layer, *index)
 
 
 def _land_rows(pool, rows, blocks, offsets):
-    """``rows [L, n, ...]`` into ``pool`` at ``(blocks [n], offsets
-    [n])`` of every layer: one scatter of rows (of whole blocks, and the
-    compiler re-lays the whole pool, there and back)."""
+    """``rows [L, n, ...]`` into K or V at ``(blocks [n], offsets [n])``
+    of every layer: one scatter of rows, in place."""
     return pool.at[:, blocks, offsets].set(rows.astype(pool.dtype))
+
+
+def _like(ki, pool: IndexKeyPool):
+    """``pool`` as the caller holds its index keys: the pytree, or the
+    bare array it came as."""
+    return pool if isinstance(ki, IndexKeyPool) else pool.rows
+
+
+def _scores_as_stored(q_idx, w, view, pack: int):
+    """Index scores ``[..., q, rows * pack]`` of queries ``q_idx [..., q,
+    hi, di]`` over a view AS STORED ``[..., rows, pack * di]``, the rows
+    never un-paired: the queries are laid into each token's lanes of a
+    row-wide operand (``[q | 0]``, ``[0 | q]``; the other lanes add exact
+    zeros to the float32 sum), each placement scores every row
+    (``sa.index_scores``), and the ``pack`` score rows are interleaved
+    into token order."""
+    if pack == 1:
+        return sa.index_scores(q_idx, w, view)
+    di = q_idx.shape[-1]
+    lead = [(0, 0)] * (q_idx.ndim - 1)
+    placed = jnp.stack([
+        jnp.pad(q_idx, lead + [(j * di, (pack - 1 - j) * di)])
+        for j in range(pack)
+    ])
+    scores = jax.vmap(lambda q: sa.index_scores(q, w, view))(placed)
+    scores = jnp.moveaxis(scores, 0, -1)          # [..., q, rows, pack]
+    # index_scores scaled by the operand's width, pack x the key's
+    return scores.reshape(scores.shape[:-2] + (-1,)) * pack ** 0.5
 
 
 def _layer(config, params, p, layer, x, positions, attend):
@@ -83,12 +122,13 @@ def decode_select(config, ki, layer, tables, lengths, block_size: int,
     max_len = max_blocks * block_size
     topk = min(config.index_topk, max_len)
     at = jnp.minimum(lengths, max_len - 1)
+    pool = IndexKeyPool.of(ki)
     with jax.named_scope("index"):
-        view = _at_layer(ki, layer, tables).reshape(slots, max_len, -1)
-        view = view.at[jnp.arange(slots), at].set(
-            k_idx[:, 0].astype(view.dtype)
+        view = pool.blocks_at(layer, tables).reshape(
+            slots, max_len // pool.pack, -1
         )
-        scores = sa.index_scores(q_idx, w, view)[:, 0]
+        view = pool.lay_in(view, k_idx[:, 0], at)
+        scores = _scores_as_stored(q_idx, w, view, pool.pack)[:, 0]
     with jax.named_scope("select"):
         visible = jnp.arange(max_len)[None, :] <= at[:, None]
         return sa.select_indices(scores, visible, topk)
@@ -126,10 +166,11 @@ def _slot_view(pool, layer, table_row, new, start, block_size: int):
     ``new [1, chunk, ...]`` laid in at ``start``. K and V are gathered
     ROW by row: gathered block by block, what reads the view next (a
     head's keys) hands its layout up through the gather, and the
-    compiler re-lays the whole pool to suit it."""
+    compiler re-lays the whole pool to suit it. The index keys' view is
+    one key a row, ``[max_len, index_dim]``, whatever the pool's."""
     if pool.ndim == 4:                     # index keys: block by block
-        rows = _at_layer(pool, layer, table_row)
-        rows = rows.reshape((-1,) + rows.shape[2:])
+        pool = IndexKeyPool.of(pool)
+        rows = pool.blocks_at(layer, table_row).reshape(-1, pool.index_dim)
     else:
         at = jnp.arange(table_row.shape[0] * block_size)
         rows = _at_layer(
@@ -304,7 +345,9 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         off = jnp.where(active, write % block_size, 0)
         k = _land_rows(k, k_news[:, :, 0], blk, off)
         v = _land_rows(v, v_news[:, :, 0], blk, off)
-        ki = _land_rows(ki, ki_news[:, :, 0], blk, off)
+        ki = _like(ki, IndexKeyPool.of(ki).land_tokens(
+            ki_news[:, :, 0], blk, off
+        ))
         sub = jax.random.fold_in(rng, step_idx * 2)
         nxt = gen_lib.sample_token(logits, sub, temps)
         aux = jnp.stack([
@@ -334,7 +377,9 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         )
         k = land(k, k_news, table_row, start)
         v = land(v, v_news, table_row, start)
-        ki = land(ki, ki_news, table_row, start)
+        ki = _like(ki, IndexKeyPool.of(ki).land_run(
+            ki_news, table_row, start, block_size, SENTINEL_BLOCK
+        ))
 
         def head():
             h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)

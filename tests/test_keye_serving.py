@@ -4,7 +4,14 @@ on LOGITS, at a size where the selection is active (``index_topk`` 8,
 contexts of 9-60 rows): chunked prefill + paged decode, a prefix hit
 against the same request served cold, copy-on-write, preemption and
 export/import with the index keys in tow, and the expert layer in a
-full forward, a prefill chunk and a decode step."""
+full forward, a prefill chunk and a decode step. The cases run over the
+shapes of ``SHAPES``: how many index keys the pool holds to a 128-lane
+row follows from ``index_dim`` and ``block_size``
+(``kvpool/index_pool.py``), and nothing a request is answered with may
+depend on it."""
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +21,7 @@ import pytest
 from benchmark import reference_keye as ref
 from dlrover_tpu.models import sparse_lm
 from dlrover_tpu.serving.kvpool import PagedServingEngine, migrate, sparse
+from dlrover_tpu.serving.kvpool import index_pool
 
 CFG = sparse_lm.tiny_config()
 CFG_JSON = dict(
@@ -24,11 +32,62 @@ CFG_JSON = dict(
                    indexer_head_dim=CFG.index_dim, topk=CFG.index_topk),
 )
 MAX_LEN, CHUNK, BS = 96, 8, 4
+# name -> (index_dim, block_size): what the pool's third array packs to
+SHAPES = {
+    "one_a_row": (CFG.index_dim, BS),     # 16 keys do not divide 4 tokens
+    "sixteen_a_row": (8, 16),
+    "two_a_row": (64, 4),
+}
+
+
+class Shape(NamedTuple):
+    cfg: sparse_lm.SparseLMConfig
+    cfg_json: dict
+    params: dict
+    bs: int
+    pack: int
+
+
+@functools.lru_cache(maxsize=None)
+def _model(index_dim):
+    cfg = sparse_lm.tiny_config(index_dim=index_dim)
+    cfg_json = dict(CFG_JSON, sa_config=dict(
+        CFG_JSON["sa_config"], indexer_head_dim=index_dim
+    ))
+    return cfg, cfg_json, sparse_lm.init_params(cfg, jax.random.key(7))
+
+
+def _shape(index_dim, bs):
+    return Shape(*_model(index_dim), bs,
+                 index_pool.tokens_per_row(index_dim, bs))
+
+
+@pytest.fixture(params=list(SHAPES))
+def shape(request):
+    got = _shape(*SHAPES[request.param])
+    assert got.pack == {"one": 1, "two": 2, "sixteen": 16}[
+        request.param.split("_")[0]
+    ]
+    return got
 
 
 @pytest.fixture(scope="module")
 def params():
-    return sparse_lm.init_params(CFG, jax.random.key(7))
+    return _model(CFG.index_dim)[2]
+
+
+def _shaped(shape, **kw):
+    eng = _engine(shape.params, cfg=shape.cfg,
+                  **{"block_size": shape.bs, **kw})
+    assert eng.kv_stats()["index_tokens_per_row"] == \
+        index_pool.tokens_per_row(shape.cfg.index_dim, eng.block_size)
+    return eng
+
+
+def _one_a_row(monkeypatch):
+    """Engines built from here on hold one index key a row whatever
+    their shape: a replica of before the pool packed."""
+    monkeypatch.setattr(index_pool, "tokens_per_row", lambda *a: 1)
 
 
 def _engine(params, cfg=CFG, **kw):
@@ -88,21 +147,27 @@ def _take_the_chunk_kernel(monkeypatch):
     monkeypatch.setattr(da, "sparse_chunk_kernel_supported", lambda *a: True)
 
 
-@pytest.mark.parametrize("chunk_attention", ["masked_attention",
-                                             "chunk_kernel"])
+@pytest.mark.parametrize("shape_name, chunk_attention", [
+    ("one_a_row", "masked_attention"), ("one_a_row", "chunk_kernel"),
+    ("sixteen_a_row", "masked_attention"), ("two_a_row", "masked_attention"),
+])
 @pytest.mark.parametrize("n_prompt", [9, 21, 37, 60])
 def test_chunked_prefill_and_paged_decode_give_the_references_logits(
-        params, n_prompt, chunk_attention, monkeypatch):
+        n_prompt, shape_name, chunk_attention, monkeypatch):
     """Prompt in chunks through the pool, then decode steps: the logits
     the next step samples from are the plain forward's at that position,
     after 1 token (the prefill's) and after 5; the tokens emitted are
     its argmax; and the last chunk's own logits are its rows. Once with
     the chunk attended by ``masked_attention`` over the gathered views
     (what a CPU builds), once by the Pallas kernel over the pool in
-    place (what a TPU builds; here interpreted)."""
+    place (what a TPU builds; here interpreted). The decode steps pass
+    odd and even fills, and the chunks' last is short of a row."""
     if chunk_attention == "chunk_kernel":
         _take_the_chunk_kernel(monkeypatch)
-    eng = _engine(params)
+    shape = _shape(*SHAPES[shape_name])
+    params, bs = shape.params, shape.bs
+    ref_logits = functools.partial(_ref_logits, cfg_json=shape.cfg_json)
+    eng = _shaped(shape)
     assert eng.pool_attention == "sparse_gather"
     assert eng.kv_stats()["sparse_chunk_attention"] == chunk_attention
     prompt = _prompt(n_prompt, n_prompt)
@@ -110,7 +175,7 @@ def test_chunked_prefill_and_paged_decode_give_the_references_logits(
     for n_out in (1, 5):
         _step_until(eng, req, n_out)
         seq = prompt + req.tokens
-        want = _ref_logits(params, seq, [len(seq) - 1])[0]
+        want = ref_logits(params, seq, [len(seq) - 1])[0]
         np.testing.assert_allclose(
             _next_logits(eng, req), want, rtol=2e-4, atol=2e-4
         )
@@ -120,16 +185,18 @@ def test_chunked_prefill_and_paged_decode_give_the_references_logits(
     chunk[0, :n_prompt - start] = prompt[start:]
     x, _ = sparse.chunk_forward(
         eng.config, *eng._pools(), eng._params, jnp.asarray(chunk),
-        jnp.asarray(eng._tables[req.slot]), jnp.int32(start), BS,
+        jnp.asarray(eng._tables[req.slot]), jnp.int32(start), bs,
     )
     from dlrover_tpu.models import llama
 
-    got = np.asarray(llama.unembed(CFG, eng._params, x))[0, :n_prompt - start]
-    want = _ref_logits(params, prompt, list(range(start, n_prompt)))
+    got = np.asarray(
+        llama.unembed(shape.cfg, eng._params, x)
+    )[0, :n_prompt - start]
+    want = ref_logits(params, prompt, list(range(start, n_prompt)))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     _drive(eng)
     seq = prompt + req.tokens
-    rows = _ref_logits(params, seq, n_prompt - 1 + np.arange(8))
+    rows = ref_logits(params, seq, n_prompt - 1 + np.arange(8))
     assert rows.argmax(-1).tolist() == req.tokens
     assert eng.kv_stats()["moe_rows_dropped"] == 0
 
@@ -154,14 +221,15 @@ def test_selection_matters_at_this_size_and_vanishes_below_topk(params):
     )
 
 
-def test_a_prefix_hit_is_the_same_request_served_cold(params):
+def test_a_prefix_hit_is_the_same_request_served_cold(shape):
     """The second request's document part comes from the trie, K, V AND
     index keys: its logits and tokens are those of a cold engine."""
+    BS = shape.bs
     doc, q0, q1 = _prompt(2, 32), _prompt(3, 7), _prompt(4, 11)
-    cold = _engine(params)
+    cold = _shaped(shape)
     r_cold = cold.submit(doc + q1, 6)
     _step_until(cold, r_cold, 3)
-    warm = _engine(params)
+    warm = _shaped(shape)
     warm.submit(doc + q0, 2)
     _drive(warm)
     r_warm = warm.submit(doc + q1, 6)
@@ -181,12 +249,17 @@ def test_a_prefix_hit_is_the_same_request_served_cold(params):
     assert np.abs(np.asarray(warm._ki[:, shared])).max() > 0
 
 
-def test_copy_on_write_copies_the_index_keys(params):
+@pytest.mark.parametrize("index_dim, pack", [(8, 16), (64, 2), (24, 1)])
+def test_copy_on_write_copies_the_index_keys(index_dim, pack):
     """A full-prompt hit re-runs the last chunk; with blocks longer than
     a chunk that chunk lies inside a SHARED block, which is privatized
-    first, and the copy carries the index keys."""
+    first, and the copy carries the index keys: at 16 keys a row the
+    re-run chunk is the second half of a row whose first half the copy
+    brought."""
     prompt = _prompt(5, 32)           # 4 whole chunks, 2 whole blocks
-    eng = _engine(params, block_size=16)
+    shape = _shape(index_dim, 16)
+    assert shape.pack == pack
+    eng = _shaped(shape)
     first = eng.submit(prompt, 3)
     _drive(eng)
     again = eng.submit(prompt, 3)
@@ -208,14 +281,16 @@ def test_copy_on_write_copies_the_index_keys(params):
     assert again.tokens == first.tokens
 
 
-def test_preemption_keeps_the_pool_consistent(params):
+def test_preemption_keeps_the_pool_consistent(shape):
     """A pool too small for three long requests preempts the youngest;
     everyone still gets the tokens an unpressed engine gives."""
     prompts = [_prompt(10 + i, 30) for i in range(3)]
-    roomy = _engine(params, prefix_cache=False)
+    roomy = _shaped(shape, prefix_cache=False)
     want = [roomy.submit(p, 10) for p in prompts]
     _drive(roomy)
-    tight = _engine(params, prefix_cache=False, num_blocks=MAX_LEN // BS + 2)
+    tight = _shaped(
+        shape, prefix_cache=False, num_blocks=MAX_LEN // shape.bs + 2
+    )
     got = [tight.submit(p, 10) for p in prompts]
     _drive(tight)
     assert tight.metrics.kv_preemptions.value() >= 1
@@ -252,21 +327,40 @@ def test_a_dry_pool_does_not_evict_a_document_its_slots_still_read(params):
     eng.check_block_invariants()
 
 
-def test_export_and_import_carry_the_index_keys(params):
+@pytest.mark.parametrize("shape_name, packs", [
+    ("one_a_row", "as_built"),
+    ("sixteen_a_row", "source_only"), ("sixteen_a_row", "destination_only"),
+    ("two_a_row", "source_only"), ("two_a_row", "destination_only"),
+])
+def test_export_and_import_carry_the_index_keys(shape_name, packs,
+                                                monkeypatch):
     """A request leaves one engine mid-decode and goes on in another:
     the payload holds its index keys bit for bit (K and V go as int8),
-    and a dense destination refuses it."""
+    in the LOGICAL shape whatever the source's rows hold, so a replica
+    that packs and one that does not exchange blocks; and a dense
+    destination refuses it."""
+    shape = _shape(*SHAPES[shape_name])
+    BS = shape.bs
     prompt = _prompt(6, 26)
-    src = _engine(params)
+    with monkeypatch.context() as m:
+        if packs == "destination_only":
+            _one_a_row(m)
+        src = _shaped(shape)
     req = src.submit(prompt, 8)
     _step_until(src, req, 3)
     payload = migrate.export_request(src, req)
     header = migrate.peek_header(payload)
     fill = header["fill"]
     assert header["index"]["shape"] == [
-        CFG.n_layers, header["n_blocks"], BS, CFG.index_dim
+        CFG.n_layers, header["n_blocks"], BS, shape.cfg.index_dim
     ]
-    dst = _engine(params)
+    with monkeypatch.context() as m:
+        if packs == "source_only":
+            _one_a_row(m)
+        dst = _shaped(shape)
+    if packs != "as_built":
+        assert {src.index_tokens_per_row, dst.index_tokens_per_row} == \
+            {1, shape.pack}
     moved = migrate.import_request(dst, payload)
     rows_src = np.asarray(src._ki[:, src._slot_blocks[req.slot]])
     rows_dst = np.asarray(dst._ki[:, dst._slot_blocks[moved.slot]])
@@ -288,6 +382,43 @@ def test_export_and_import_carry_the_index_keys(params):
     )
     with pytest.raises(migrate.MigrationError, match="index keys"):
         migrate.import_request(dense, payload)
+
+
+@pytest.mark.parametrize("shape_name", ["sixteen_a_row", "two_a_row"])
+def test_what_a_row_holds_changes_no_token(shape_name, monkeypatch):
+    """The same model, pool and requests (a document asked twice, a
+    cold prompt, fills odd and even) on the engine as its shape builds
+    it and on one holding one key a row: the same tokens, and the same
+    index keys in every block a request holds."""
+    shape = _shape(*SHAPES[shape_name])
+    doc = _prompt(20, 32)
+    prompts = [doc + _prompt(21, 5), _prompt(22, 19), doc + _prompt(23, 10)]
+
+    def serve():
+        eng = _shaped(shape)
+        first = eng.submit(prompts[0], 4)
+        _drive(eng)
+        rest = [eng.submit(p, 9) for p in prompts[1:]]
+        _step_until(eng, rest[-1], 6)
+        keys = [
+            np.asarray(eng._ki[:, eng._slot_blocks[r.slot]]).reshape(
+                CFG.n_layers, -1, shape.cfg.index_dim
+            )[:, :eng._lengths[r.slot]]
+            for r in rest
+        ]
+        _drive(eng)
+        return eng, [r.tokens for r in [first] + rest], keys
+
+    packed, tokens, keys = serve()
+    _one_a_row(monkeypatch)
+    plain, want_tokens, want_keys = serve()
+    assert (packed.index_tokens_per_row, plain.index_tokens_per_row) == \
+        (shape.pack, 1)
+    assert packed._ki.rows.shape[-1] == 128
+    assert packed._ki.shape == plain._ki.shape == plain._ki.rows.shape
+    assert tokens == want_tokens
+    for got, want in zip(keys, want_keys):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_the_expert_layer_is_one_function_of_the_token(params):

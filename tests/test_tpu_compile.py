@@ -527,6 +527,82 @@ def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
             assert "paged_pool_sparse_chunk_attention" not in text
 
 
+@pytest.mark.parametrize("pool", ["the_engines_pool", "a_bare_array"])
+def test_sparse_serving_programs_do_not_copy_the_index_key_pool(topo, pool):
+    """``keye-serve-docqa-32k``'s decode step and prefill chunk over the
+    index-key pool AS THE ENGINE BUILDS IT (``IndexKeyPool.zeros``: two
+    64-wide keys to a 128-lane row, ``[layers, 5200, 32, 128]``) hold no
+    ``copy`` of any pool array: the block gather, the scores and the
+    landing scatter take the rows as stored. Over a BARE ``[layers,
+    5200, 64, 64]`` array (one key a row: what the engine held before,
+    and what ``benchmark/rehearse_keye.py`` lowers) the same two
+    programs re-tile the index pool, whose device layout has the block
+    axis minor-most: three 0.21 GB copies a call at 5 layers (PERF.md
+    §6, PR 36). K and V are in place either way."""
+    from benchmark import common
+    from benchmark.runners import serve_sparse
+    from dlrover_tpu.models import generate as gen_lib, sparse_lm
+    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool.index_pool import IndexKeyPool
+
+    cfg_json = common.load_json("configs", "keye-vl2-30b-a3b.json")
+    cfg = serve_sparse.sparse_config(cfg_json, n_layers=2)
+    eng = cfg_json["serve_engine"]
+    slots, bs, chunk = eng["slots"], eng["block_size"], eng["prefill_chunk"]
+    mb, nb = eng["max_len"] // bs, eng["num_blocks"]
+    here = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=here), tree
+    )
+    arr = lambda shape, dt: on_chip(  # noqa: E731
+        jax.ShapeDtypeStruct(shape, dt)
+    )
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    params = on_chip(jax.eval_shape(
+        lambda k: gen_lib.prepare_decode_params(
+            cfg, sparse_lm.init_params(cfg, k, dtype=cfg.compute_dtype)
+        ), key,
+    ))
+    cdt, L = cfg.compute_dtype, cfg.n_layers
+    kv = arr((L, nb, bs, cfg.n_kv_heads, cfg.head_dim), cdt)
+    ki = on_chip(jax.eval_shape(
+        lambda: IndexKeyPool.zeros(L, nb, bs, cfg.index_dim, cdt)
+    ))
+    assert ki.pack == 2 and ki.rows.shape == (L, nb, bs // 2, 128)
+    if pool == "a_bare_array":
+        ki = arr(ki.shape, cdt)
+    steps = paged._paged_steps(cfg, slots, nb, mb, bs, chunk)
+    i32, f32 = jnp.int32, jnp.float32
+    programs = {
+        "jit_step": steps.decode.lower(
+            kv, kv, ki, params, arr((slots, mb), i32), arr((slots,), i32),
+            arr((slots,), i32), arr((slots,), bool), arr((slots,), f32),
+            key, arr((), i32), arr((), i32), arr((), i32),
+        ),
+        "jit_prefill": steps.prefill.lower(
+            kv, kv, ki, params, arr((1, chunk), i32), arr((mb,), i32),
+            arr((), i32), arr((), i32), arr((), f32), key, arr((), i32),
+            arr((), bool),
+        ),
+    }
+    for name, lowered in programs.items():
+        c = lowered.compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        copies = [
+            line.split(" copy(")[0].split("=")[-1].strip()
+            for line in text.splitlines() if " copy(" in line
+        ]
+        of_a_pool = [c_ for c_ in copies if c_.startswith(f"bf16[{L},{nb},")]
+        if pool == "the_engines_pool":
+            assert of_a_pool == [], (name, of_a_pool)
+            assert c.memory_analysis().temp_size_in_bytes < 1.2e9
+        else:
+            assert len(of_a_pool) >= 2, (name, copies)
+            assert all(f"bf16[{L},{nb},{bs},{cfg.index_dim}]" in c_
+                       for c_ in of_a_pool), of_a_pool
+
+
 # What ``sparse_chunk_attention_kind`` sees -> what it must answer;
 # unnamed: bf16, 64-row pages of 4 KV heads x 128 under 32 query heads,
 # a 512-token chunk, 528 pages a slot (the cell's shape), on a TPU.

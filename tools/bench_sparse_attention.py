@@ -12,6 +12,13 @@ two views' gathers it no longer needs, both sides' worst difference from
 the float32 softmax over the selected keys, and the kernel again with
 its mask, its ``exp``, its ``PV`` product or its strided head reads
 taken out (wrong answers, timed only: what is left says what bounds it).
+The part ``index_pool`` is the A/B of how the index-key pool is held
+(``kvpool/index_pool.py``): one layer's block gather, scores and
+selection and the landing of every layer's new keys, the decode step's
+way and the chunk's, over the pool as the engine builds it (two 64-wide
+keys to a 128-lane row) beside a bare ``[layers, num_blocks, block_size,
+index_dim]`` array, ms a call and how many copies of the pool's size
+each compiled text holds.
 
     chiprun -- python3 tools/bench_sparse_attention.py [--parts ...] [--layers N]
     JAX_PLATFORMS=cpu python3 tools/bench_sparse_attention.py --tiny   # rehearsal, times nothing real
@@ -181,6 +188,89 @@ def chunk_kernel_part(cfg, eng, report, rng, iters, tiny):
                 )
 
 
+def index_pool_part(cfg, eng, report, rng, iters, tiny):
+    """The index-key pool's reads and landings, packed beside bare (see
+    the module's docstring). The pool is donated and threaded from call
+    to call, as the engine's programs take it."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.serving.kvpool import SENTINEL_BLOCK, sparse
+    from dlrover_tpu.serving.kvpool.index_pool import IndexKeyPool
+
+    slots, max_len, chunk = eng["slots"], eng["max_len"], eng["prefill_chunk"]
+    bs, layers = eng["block_size"], cfg.n_layers
+    hi, di, cdt = cfg.index_heads, cfg.index_dim, cfg.compute_dtype
+    mb = max_len // bs
+    nb = eng.get("num_blocks") or slots * mb + 1
+    f = lambda *s: jnp.asarray(rng.normal(size=s), cdt)  # noqa: E731
+    tables = jnp.asarray(
+        1 + (rng.permutation(slots * mb) % (nb - 1)).reshape(slots, mb),
+        jnp.int32,
+    )
+    lengths = jnp.asarray(
+        rng.integers(max_len - 2 * chunk, max_len - 1, slots), jnp.int32
+    )
+    layer = jnp.int32(layers - 1)
+
+    def step(ki, q_idx, k_idx, w, news):
+        idx, _ = sparse.decode_select(
+            cfg, ki, layer, tables, lengths, bs, q_idx, k_idx, w
+        )
+        blk = jnp.take_along_axis(
+            tables, (lengths // bs)[:, None], axis=1
+        )[:, 0]
+        pool = IndexKeyPool.of(ki).land_tokens(news, blk, lengths % bs)
+        return sparse._like(ki, pool), idx
+
+    def chunk_fn(ki, q_idx, k_idx, w, news):
+        start = jnp.int32(max_len - 2 * chunk)
+        view = sparse._slot_view(ki, layer, tables[0], k_idx, start, bs)
+        sub = min(sparse.CHUNK_QUERY_BLOCK, chunk)
+        mask = sparse.chunk_select(
+            cfg, view, start + jnp.arange(sub), q_idx[:sub], w[:sub]
+        )
+        pool = IndexKeyPool.of(ki).land_run(
+            news, tables[0], start, bs, SENTINEL_BLOCK
+        )
+        return sparse._like(ki, pool), mask
+
+    calls = {
+        "decode": (step, (f(slots, 1, hi, di), f(slots, 1, di),
+                          f(slots, 1, hi), f(layers, slots, di))),
+        "chunk": (chunk_fn, (f(chunk, hi, di), f(1, chunk, di),
+                             f(chunk, hi), f(layers, chunk, di))),
+    }
+    made = IndexKeyPool.zeros(layers, nb, bs, di, cdt)
+    report("index_pool.shape", {
+        "logical": list(made.shape), "rows": list(made.rows.shape),
+        "tokens_per_row": made.pack,
+    })
+    for kind in ("packed", "bare"):
+        for name, (fn, operands) in calls.items():
+            ki = (
+                IndexKeyPool.zeros(layers, nb, bs, di, cdt)
+                if kind == "packed" else jnp.zeros(made.shape, cdt)
+            )
+            jitted = jax.jit(fn, donate_argnums=0)
+            text = jitted.lower(ki, *operands).compile().as_text()
+            copies = sum(
+                f"[{layers},{nb}," in line.split(" copy(")[0]
+                for line in text.splitlines() if " copy(" in line
+            )
+            ki, out = jitted(ki, *operands)
+            jax.block_until_ready(out)
+            t0 = time.time()
+            for _ in range(iters):
+                ki, out = jitted(ki, *operands)
+            jax.block_until_ready((ki, out))
+            report(f"index_pool.{name}.{kind}", {
+                "ms": 1e3 * (time.time() - t0) / iters,
+                "pool_sized_copies": copies,
+            })
+            del ki
+
+
 def profile(engine, decode, prefill, calls=3, top=14):
     """ms a call of each program by scope and by (scope, op), from a
     profiler session over ``calls`` calls of each."""
@@ -229,7 +319,8 @@ def main(argv=None):
                     help="trace 3 calls of each program: ms a call by "
                          "scope and by op")
     ap.add_argument("--parts", nargs="*", default=[
-        "ops_decode", "ops_chunk", "chunk_kernel", "experts", "programs",
+        "ops_decode", "ops_chunk", "chunk_kernel", "index_pool", "experts",
+        "programs",
     ])
     args = ap.parse_args(argv)
     import jax
@@ -305,6 +396,8 @@ def main(argv=None):
             jax.jit(sa.masked_attention), q, k, k, mask, iters=it))
     if "chunk_kernel" in args.parts:
         chunk_kernel_part(cfg, eng, report, rng, it, args.tiny)
+    if "index_pool" in args.parts:
+        index_pool_part(cfg, eng, report, rng, it, args.tiny)
     if "experts" in args.parts or "programs" in args.parts:
         params = jax.jit(
             lambda key: sparse_lm.init_params(cfg, key, dtype=cdt)
