@@ -248,20 +248,43 @@ def _mla_axes(config):
     }
 
 
-def _mla_apply(config, p, h):
+def mla_keys_values(config, kva, latent, w_kvb, rotate=None):
+    """The up-projection of a latent layer: the normed ``latent [b, s,
+    r]`` and ``kva [b, s, r + rope]``, whose last ``rope`` numbers are
+    the positional key the heads share (turned by ``rotate`` when
+    given) -> keys ``[b, s, h, nope + rope]`` and values ``[b, s, h,
+    v]``. The one place the package turns a latent into per-head keys
+    and values: this model's mixer, and ``models/latent_lm.py``'s
+    definition over cache rows (already rotated)."""
+    r, nope = config.kv_lora_rank, config.qk_nope_dim
+    kvb = _proj("bsr,rhk->bshk", latent, w_kvb)
+    shared = kva[..., None, r:]
+    if rotate is not None:
+        shared = rotate(shared)
+    shared = jnp.broadcast_to(
+        shared, kvb.shape[:3] + (config.qk_rope_dim,)
+    )
+    k = jnp.concatenate([kvb[..., :nope], shared], axis=-1)
+    return k, kvb[..., nope:]
+
+
+def _mla_apply(config, p, h, rotate=None):
+    """``rotate`` (None: the positional slices stay as projected, this
+    model's ``mla_use_nope``): what turns the queries' positional slice
+    ``[b, s, h, rope]`` and the shared key's ``[b, s, 1, rope]`` by
+    their positions."""
     with jax.named_scope("mla"):
         r, nope = config.kv_lora_rank, config.qk_nope_dim
         q = _proj("bsd,dhk->bshk", h, p["wq"])
+        if rotate is not None:
+            q = jnp.concatenate(
+                [q[..., :nope], rotate(q[..., nope:])], axis=-1
+            )
         kva = _proj("bsd,dr->bsr", h, p["w_kva"])
         latent = rms_norm(kva[..., :r], p["kv_norm"])
-        kvb = _proj("bsr,rhk->bshk", latent, p["w_kvb"])
         # The positional slice of a key is one vector shared by the
         # heads and, in this model, NOT rotated (mla_use_nope).
-        shared = jnp.broadcast_to(
-            kva[..., None, r:], kvb.shape[:3] + (config.qk_rope_dim,)
-        )
-        k = jnp.concatenate([kvb[..., :nope], shared], axis=-1)
-        v = kvb[..., nope:]
+        k, v = mla_keys_values(config, kva, latent, p["w_kvb"], rotate)
         q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
         attend = llama.default_attention_fn() or dot_product_attention
         out = attend(q, k, v, causal=True)     # scaled by 1/sqrt(nope + rope)

@@ -68,6 +68,13 @@ class SparseLMConfig:
         return self.index_heads * self.index_dim + self.index_dim \
             + self.index_heads
 
+    @property
+    def cache_rows(self):
+        """What a token leaves in the cache, a layer: name -> shape (the
+        pool's statement, ``serving/kvpool/layout.py``)."""
+        head = (self.n_kv_heads, self.head_dim)
+        return (("k", head), ("v", head), ("index_keys", (self.index_dim,)))
+
     def count_params(self) -> int:
         d, hd, f = self.embed_dim, self.head_dim, self.mlp_dim
         attn = d * (self.n_heads + 2 * self.n_kv_heads) * hd

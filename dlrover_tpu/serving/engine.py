@@ -558,7 +558,7 @@ class ServingEngine:
         self._rng = rng if rng is not None else jax.random.key(0)
         self._step_idx = 0
         build.mark("build_programs")
-        self._k, self._v = jax.block_until_ready(self._fresh_pool())
+        self._alloc_pool()
         build.mark("alloc_pool")
         # Host mirrors of the device-side per-slot state; passed into
         # every step call (tiny H2D) so host and device can never
@@ -603,6 +603,11 @@ class ServingEngine:
             "pool_bytes": self._k.nbytes + self._v.nbytes,
             "index_pool_bytes": 0,
         }
+
+    def _alloc_pool(self) -> None:
+        """Build the cache's device arrays (the paged engine builds
+        what its model's blocks hold: kvpool/layout.py)."""
+        self._k, self._v = jax.block_until_ready(self._fresh_pool())
 
     def _fresh_pool(self):
         shape = (

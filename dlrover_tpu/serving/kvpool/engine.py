@@ -83,7 +83,7 @@ from dlrover_tpu.serving.kvpool.allocator import (
     BlockAllocator,
     BlockPoolExhausted,
 )
-from dlrover_tpu.serving.kvpool.index_pool import IndexKeyPool
+from dlrover_tpu.serving.kvpool import layout as pool_layout
 from dlrover_tpu.serving.kvpool.prefix_cache import PrefixCache
 from dlrover_tpu.serving import spec_decode as spec_lib
 from dlrover_tpu.serving.scheduler import DECODE, PREFILL, Request
@@ -136,6 +136,10 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
     is no part of it: on the v5e the decode kernel was ahead of the
     gather down to 4 slots x 576 rows and 16 slots x 128 rows (PERF.md
     §6, PR 25), the chunk kernel at every ``start`` (PR 28)."""
+    if getattr(config, "kind", "") == "latent_lm":
+        # One latent row a token (kvpool/latent.py): the decode step
+        # absorbs the up-projection and reads the rows themselves.
+        return "latent_absorbed"
     if getattr(config, "index_topk", 0):
         # A learned selection of the cache (kvpool/sparse.py): index
         # keys scored through the table, the selected rows gathered;
@@ -512,7 +516,8 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
 def _build_cow_copy(counts, n_pools: int):
     """Device block copy src -> dst in EVERY pool array, all layers (K
     and V; the scale pools of an int8 cache; the index keys of a sparse
-    model): the copy-on-write primitive. What a block holds is the
+    model; a latent model's one array): the copy-on-write primitive.
+    What a block holds is the
     model's; that a block operation moves all of it is the pool's
     (docs/DESIGN.md §37). src/dst are traced scalars — privatizing any
     block never retraces."""
@@ -822,13 +827,24 @@ def _paged_steps_for(
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
-    if attn == "sparse_gather":
-        if quantized:
-            raise ValueError("a sparse model's pool is not quantized")
+    # The pools every program leads with and hands back, donated: the
+    # model's statement of what a block holds (kvpool/layout.py).
+    n_pools = len(pool_layout.pool_arrays(config, kv_dtype))
+    pool_args = tuple(range(n_pools))
+    if attn == "latent_absorbed":
+        # Imported here: the module builds on this one.
+        from dlrover_tpu.serving.kvpool import latent
+
+        build_decode = latent.build_decode(
+            config, slots, max_blocks, block_size, counts
+        )
+        build_prefill = latent.build_prefill(
+            config, max_blocks, block_size, chunk, counts
+        )
+    elif attn == "sparse_gather":
         # Imported here: the module builds on this one.
         from dlrover_tpu.serving.kvpool import sparse
 
-        pool_args = (0, 1, 2)                 # K, V, index keys
         build_decode = sparse.build_decode(
             config, slots, max_blocks, block_size, counts
         )
@@ -837,7 +853,6 @@ def _paged_steps_for(
             kind=sparse_chunk,
         )
     else:
-        pool_args = (0, 1, 2, 3) if quantized else (0, 1)
         build_decode = _build_paged_decode(
             config, slots, max_blocks, block_size, counts,
             quantized=quantized, attn=attn,
@@ -848,7 +863,6 @@ def _paged_steps_for(
         )
     decode = jax.jit(build_decode, donate_argnums=pool_args)
     prefill = jax.jit(build_prefill, donate_argnums=pool_args)
-    n_pools = len(pool_args)
     cow = jax.jit(_build_cow_copy(counts, n_pools), donate_argnums=pool_args)
     imp = jax.jit(
         _build_import_scatter(counts, n_pools), donate_argnums=pool_args
@@ -920,6 +934,15 @@ class PagedServingEngine(ServingEngine):
         # (the base constructor marks its own phases into it).
         build = _PhaseMarks(time.monotonic())
         self.kv_cache_dtype = kv_cache_dtype
+        # What a block holds (kvpool/layout.py), and the device arrays
+        # by name; ``_pools()`` is their tuple in the layout's order.
+        self._layout = pool_layout.pool_arrays(config, kv_cache_dtype)
+        self._arrays: Dict[str, object] = {}
+        if spec_k and [a.name for a in self._layout][:2] != ["k", "v"]:
+            raise ValueError(
+                "the speculative programs read K and V; this model's "
+                "blocks hold " + self._block_holds()
+            )
         self.block_size = block_size
         self.max_blocks = max_len // block_size
         if num_blocks is None:
@@ -952,9 +975,7 @@ class PagedServingEngine(ServingEngine):
         self._prefix_hit_blocks = 0
         self._prefix_hit_tokens = 0   # prompt rows no chunk had to run
         build.mark("prefix_cache")
-        # The base __init__ builds the value pools via _fresh_pool();
-        # the int8 scale pools pair up right after it returns (nothing
-        # in between touches them).
+        # The base __init__ builds every pool array via _alloc_pool().
         super().__init__(
             config, params, slots, max_len,
             prefill_chunk=prefill_chunk, token_budget=token_budget,
@@ -963,9 +984,6 @@ class PagedServingEngine(ServingEngine):
             spec_k=spec_k, spec_drafter=spec_drafter,
             spec_draft_layers=spec_draft_layers, build_marks=build,
         )
-        self._kscale, self._vscale = self._fresh_scales()
-        self._ki = self._fresh_index_keys()
-        jax.block_until_ready((self._kscale, self._vscale, self._ki))
         build.mark("alloc_side_pools")
         # Block watermark: only admit a request the pool can hold
         # (prompt + first decode block) counting evictable cache as
@@ -981,13 +999,14 @@ class PagedServingEngine(ServingEngine):
         )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
-            "(%s KV%s), decode and prefill attention %s%s",
+            "(%s KV%s), a block holds %s; decode and prefill attention "
+            "%s%s",
             slots, max_len, self.num_blocks, block_size,
             kv_cache_dtype,
             f" + index keys [{self._index_dim}], "
             f"{self.index_tokens_per_row} to a row, top-"
             f"{config.index_topk}" if self._index_dim else "",
-            self.pool_attention,
+            self._block_holds(), self.pool_attention,
             ", the chunk under its selection by "
             f"{self.sparse_chunk_attention}" if self._index_dim else "",
         )
@@ -1000,35 +1019,47 @@ class PagedServingEngine(ServingEngine):
                 kv_dtype=kv_cache_dtype,
             )
         self._trace_snapshot = self._all_trace_counts()
-        # K+V bytes per block, for the HBM-in-use gauge: int8 pools
-        # pay 1 byte/element + one f32 scale per (row, head) — the
-        # 1.94x-per-token capacity lever the equal-HBM bench exploits.
-        from dlrover_tpu.ops.kv_quant import bytes_per_head_row
-
-        itemsize = jnp.dtype(config.compute_dtype).itemsize
-        self._index_block_bytes = int(
-            config.n_layers * block_size * self._index_dim * itemsize
-        )
-        self._block_bytes = self._index_block_bytes + int(
-            2 * config.n_layers * block_size * config.n_kv_heads
-            * bytes_per_head_row(
-                config.head_dim, kv_cache_dtype, itemsize,
-            )
-        )
+        # Bytes per block, for the HBM-in-use gauge, by array: int8
+        # pools pay 1 byte/element + one f32 scale per (row, head) —
+        # the 1.94x-per-token capacity lever the equal-HBM bench
+        # exploits.
+        self._array_block_bytes = {
+            a.name: a.block_bytes(config.n_layers, block_size)
+            for a in self._layout
+        }
+        self._block_bytes = sum(self._array_block_bytes.values())
         self.metrics.kv_blocks_total.set(self._allocator.managed)
         build.mark("paged_programs")
         self._end_build(build)
 
     def _build_bytes(self) -> Dict[str, int]:
-        sizes = super()._build_bytes()
         index = self._ki.nbytes if self._ki is not None else 0
-        sizes["pool_bytes"] = (
-            sum(p.nbytes for p in self._pools()) - index
-        )
-        sizes["index_pool_bytes"] = index
-        return sizes
+        return {
+            "params_bytes": sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(self._params)
+            ),
+            "pool_bytes": sum(p.nbytes for p in self._pools()) - index,
+            "index_pool_bytes": index,
+        }
 
     # ---- pool construction / programs --------------------------------------
+
+    def _pool_array(name):  # noqa: N805 (a property factory)
+        """The device array ``name`` of the pool; None for a model whose
+        blocks hold none."""
+        return property(
+            lambda self: self._arrays.get(name),
+            lambda self, value: self._arrays.__setitem__(name, value),
+        )
+
+    _k, _v = _pool_array("k"), _pool_array("v")
+    _kscale, _vscale = _pool_array("k_scale"), _pool_array("v_scale")
+    _ki = _pool_array("index_keys")
+    _latent = _pool_array("latent")
+    del _pool_array
+
+    def _block_holds(self) -> str:
+        return ", ".join(a.describe() for a in self._layout)
 
     @property
     def _quantized(self) -> bool:
@@ -1065,63 +1096,33 @@ class PagedServingEngine(ServingEngine):
         model."""
         return self._steps.sparse_chunk_attention
 
-    def _fresh_pool(self):
-        shape = (
-            self.config.n_layers, self.num_blocks, self.block_size,
-            self.config.n_kv_heads, self.config.head_dim,
-        )
-        if self._quantized:
-            return jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8)
-        return (
-            jnp.zeros(shape, self.config.compute_dtype),
-            jnp.zeros(shape, self.config.compute_dtype),
-        )
+    def _fresh_arrays(self) -> Dict[str, object]:
+        """Every array of the pool, zeroed: ONE rebuild site for all of
+        them (init, warmup, step-error recovery), so that no two can be
+        mismatched."""
+        return {
+            a.name: pool_layout.fresh(
+                a, self.config.n_layers, self.num_blocks, self.block_size
+            )
+            for a in self._layout
+        }
+
+    def _alloc_pool(self) -> None:
+        self._arrays = jax.block_until_ready(self._fresh_arrays())
 
     def _pools(self):
         """The donated-pool argument tuple every compiled program
-        leads with: (k, v) for fp, (k, v, k_scale, v_scale) for int8,
-        (k, v, index keys) for a sparse model. Call sites splat this
-        and hand the returned tuple back to :meth:`_set_pools` — ONE
+        leads with, in the layout's order: (k, v) for fp, (k, v,
+        k_scale, v_scale) for int8, (k, v, index keys) for a sparse
+        model, (latent,) for a latent one. Call sites splat this and
+        hand the returned tuple back to :meth:`_set_pools` — ONE
         argument list per program, whatever a block holds."""
-        if self._quantized:
-            return (self._k, self._v, self._kscale, self._vscale)
-        if self._index_dim:
-            return (self._k, self._v, self._ki)
-        return (self._k, self._v)
+        return tuple(self._arrays[a.name] for a in self._layout)
 
     def _set_pools(self, pools) -> None:
-        if self._quantized:
-            self._k, self._v, self._kscale, self._vscale = pools
-        elif self._index_dim:
-            self._k, self._v, self._ki = pools
-        else:
-            self._k, self._v = pools
-
-    def _fresh_index_keys(self):
-        """The index-key pool of a sparse model (None otherwise), paired
-        with every value-pool rebuild like the int8 scales."""
-        if not self._index_dim:
-            return None
-        return IndexKeyPool.zeros(
-            self.config.n_layers, self.num_blocks, self.block_size,
-            self._index_dim, self.config.compute_dtype,
-        )
-
-    def _fresh_scales(self):
-        """(k_scale, v_scale) pools for the int8 cache — (None, None)
-        for fp. Every value-pool rebuild site (init, warmup,
-        step-error recovery) pairs a _fresh_pool() call with this one
-        so value and scale pools can never be mismatched."""
-        if not self._quantized:
-            return None, None
-        shape = (
-            self.config.n_layers, self.num_blocks, self.block_size,
-            self.config.n_kv_heads,
-        )
-        return (
-            jnp.zeros(shape, jnp.float32),
-            jnp.zeros(shape, jnp.float32),
-        )
+        self._arrays = {
+            a.name: pool for a, pool in zip(self._layout, pools)
+        }
 
     def warmup(self) -> None:
         """Compile all three paged programs on throwaway state, then
@@ -1158,27 +1159,20 @@ class PagedServingEngine(ServingEngine):
             self._steps.cow(*pools, np.int32(0), np.int32(0))
         )
         marks.mark("cow")
-        blk_shape = (
-            self.config.n_layers, self.block_size,
-            self.config.n_kv_heads, self.config.head_dim,
-        )
-        if self._quantized:
-            z8 = jnp.zeros(blk_shape, jnp.int8)
-            zs = jnp.zeros(blk_shape[:-1], jnp.float32)
-            pools = self._steps.imp(
-                *pools, z8, z8, zs, zs, np.int32(0)
+        # Import hands one block's rows an array, in the logical shape
+        # and as kvpool/migrate hands them: a dense model's K and V
+        # dequantized to f32 on the host, everything else in the pool's
+        # own dtype.
+        rows = [
+            jnp.zeros(
+                (self.config.n_layers, self.block_size) + a.row_shape,
+                a.import_dtype,
             )
-        else:
-            # Import hands dequantized f32 host rows (kvpool/migrate),
-            # and a sparse model's index keys in the pool's dtype and
-            # logical shape.
-            zf = jnp.zeros(blk_shape, jnp.float32)
-            extra = [
-                jnp.zeros(p.shape[:1] + p.shape[2:], p.dtype)
-                for p in pools[2:]
-            ]
-            pools = self._steps.imp(*pools, zf, zf, *extra, np.int32(0))
-        jax.block_until_ready(pools)
+            for a in self._layout
+        ]
+        pools = jax.block_until_ready(
+            self._steps.imp(*pools, *rows, np.int32(0))
+        )
         marks.mark("imp")
         # Export gather (non-donating): warm so the first migration
         # out of this engine never stalls the serve loop on a compile.
@@ -1207,10 +1201,7 @@ class PagedServingEngine(ServingEngine):
             jax.block_until_ready(acc)
             marks.mark("verify")
         del pools
-        self._k, self._v = self._fresh_pool()
-        self._kscale, self._vscale = self._fresh_scales()
-        self._ki = self._fresh_index_keys()
-        jax.block_until_ready(self._pools())
+        self._alloc_pool()
         self._trace_snapshot = self._all_trace_counts()
         self._end_warmup(marks)
 
@@ -1381,9 +1372,7 @@ class PagedServingEngine(ServingEngine):
         # A failed step may have invalidated the donated pools: the
         # device blocks AND everything that points at them (allocator,
         # prefix cache, tables, int8 scale pools) restart from scratch.
-        self._k, self._v = self._fresh_pool()
-        self._kscale, self._vscale = self._fresh_scales()
-        self._ki = self._fresh_index_keys()
+        self._arrays = self._fresh_arrays()
         self._allocator = BlockAllocator(self.num_blocks, reserved=1)
         if self._cache is not None:
             self._cache = PrefixCache(
@@ -1545,15 +1534,30 @@ class PagedServingEngine(ServingEngine):
         if self._index_dim:
             # The third per-token array: its share of the bytes above,
             # and the whole array's size on the device.
+            per_block = self._array_block_bytes["index_keys"]
             stats["index_bytes_in_use"] = (
-                (stats["used"] + stats["cached"]) * self._index_block_bytes
+                (stats["used"] + stats["cached"]) * per_block
             )
-            stats["index_pool_bytes"] = (
-                self.num_blocks * self._index_block_bytes
-            )
+            stats["index_pool_bytes"] = self.num_blocks * per_block
             stats["index_tokens_per_row"] = self.index_tokens_per_row
             stats["moe_rows_dropped"] = self._moe_rows_dropped
             stats["sparse_chunk_attention"] = self.sparse_chunk_attention
+        if self._latent is not None:
+            # The one array of a latent model: its bytes in use, the
+            # whole array's size, and one token's row of one layer.
+            per_block = self._array_block_bytes["latent"]
+            stats["latent_bytes_in_use"] = (
+                (stats["used"] + stats["cached"]) * per_block
+            )
+            stats["latent_pool_bytes"] = self.num_blocks * per_block
+            stats["latent_row_bytes"] = per_block // (
+                self.config.n_layers * self.block_size
+            )
+            stats["moe_rows_dropped"] = self._moe_rows_dropped
+            # Imported here: the module builds on this one.
+            from dlrover_tpu.serving.kvpool import latent
+
+            stats["latent_chunk_attention"] = latent.CHUNK_ATTENTION
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value
@@ -1582,9 +1586,10 @@ class PagedServingEngine(ServingEngine):
             )
         # Every pool array is addressed by the same block ids: they
         # agree on how many blocks there are and how long a block is.
-        for pool in self._pools():
-            if pool.shape[:3] != self._k.shape[:3]:
+        want = (self.config.n_layers, self.num_blocks, self.block_size)
+        for a, pool in zip(self._layout, self._pools()):
+            if pool.shape[:3] != want:
                 raise AssertionError(
-                    f"a pool array of {pool.shape[:3]} beside K "
-                    f"{self._k.shape[:3]}: one table cannot address both"
+                    f"pool array {a.name} of {pool.shape[:3]} in a pool "
+                    f"of {want}: one table cannot address it"
                 )

@@ -30,11 +30,20 @@ LANES = 128
 
 
 def tokens_per_row(index_dim: int, block_size: int) -> int:
-    """Tokens a 128-lane row of the index-key pool holds: ``128 //
-    index_dim`` where that fills the row and divides a block, else 1."""
-    if index_dim <= 0 or LANES % index_dim:
+    """Tokens a row of the pool holds, so that the row is whole 128-lane
+    rows: ``128 // index_dim`` where that fills one and divides a block,
+    two of a row 1.5, 2.5 ... lane rows wide, else 1."""
+    if index_dim <= 0:
         return 1
-    pack = LANES // index_dim
+    if index_dim > LANES:
+        # A row wider than the lanes and not a multiple of them (a latent
+        # model's 576 = 4.5 x 128: kvpool/latent.py): the fewest tokens
+        # that fill whole lane rows, two at most.
+        pack = 2 if index_dim % LANES and (2 * index_dim) % LANES == 0 else 1
+    elif LANES % index_dim:
+        return 1
+    else:
+        pack = LANES // index_dim
     return pack if block_size % pack == 0 else 1
 
 

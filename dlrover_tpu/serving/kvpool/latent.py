@@ -1,0 +1,336 @@
+"""The paged decode and prefill programs of a latent-attention model
+(``models/latent_lm.py``).
+
+The pool holds ONE array, ``latent [layers, num_blocks, block_size,
+kv_lora_rank + qk_rope_dim]``: a token's normed latent and its rotated
+positional key, with no head axis and no V. A 576-wide row is 4.5 of a
+TPU's 128-lane rows, and an array whose rows are not whole lane rows is
+given a device layout that every gather and scatter re-tiles the WHOLE
+pool for (the described-v5e compile of the bare array: two 4 GB copies a
+decode step; PERF.md section 6, PRs 36 and 38), so the device holds two
+tokens to a 1,152-lane row (``kvpool/index_pool.IndexKeyPool``, the
+index keys' class at another width, which is what ``pool`` is in every
+function here; a width that does not pack is one token a row through
+the same code) and lands rows by ``(layer, block, row)`` coordinate. Neither program ever builds a key or a value of a
+cached token:
+
+- the decode step (``[slots, 1]`` queries) ABSORBS the up-projection:
+  ``w_kvb``'s key half is folded into the query
+  (``latent_lm.absorb_queries``), a score is the query's dot with the
+  cached row, the softmax-weighted sum of the rows' latents comes out
+  ``[heads, kv_lora_rank]`` and only then meets ``w_kvb``'s value half
+  (``latent_lm.values_out``). It reads each visible row once a layer
+  through the slot's table (a gathered ``[slots, max_len]`` view AS
+  STORED, never un-paired: the query is laid into each token's lanes of
+  a row-wide operand, ``[q | 0]`` and ``[0 | q]``, and the weighted sum
+  is read back from each token's lanes; the query's own row comes from
+  the layer's hands);
+- the prefill chunk (``[1, chunk]`` queries whose own keys are causal
+  and whose prefix lives in the pool) walks the slot's prefix in blocks
+  of :data:`CHUNK_PREFIX_ROWS` rows (un-paired: 2.4 MB a block) under a
+  running softmax, so that no ``[heads, chunk, max_len]`` logits exist,
+  then its own rows, ABSORBED like the decode step
+  (:data:`CHUNK_ATTENTION`).
+
+Both are append-free like the dense in-place programs: the new rows of
+all layers land after the layer loop (one row a slot, or the chunk's
+blocks). The programs keep the names ``step`` and ``prefill`` (a trace
+names a device op by its program), and the decode step returns, after
+the tokens, ``[experts hit (mean over the expert layers), expert rows
+dropped]`` for the host to fetch with them, as ``kvpool/sparse.py``'s
+does.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models import generate as gen_lib
+from dlrover_tpu.models import latent_lm
+from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+
+# Prefix rows a prefill chunk scores at a time: [heads, chunk, this]
+# float32 logits are the largest temporary of the chunk's attention
+# (128 MB at 32 heads x 512 queries).
+CHUNK_PREFIX_ROWS = 2048
+
+
+def _scores(spec: str, queries, rows):
+    """Scores of ``queries`` against cache ``rows`` (an einsum ``spec``):
+    bfloat16 operands, products summed in float32, handed on in float32
+    (the softmax and its sum follow in float32)."""
+    return jnp.einsum(
+        spec, queries, rows, preferred_element_type=jnp.float32
+    )
+
+
+# How a prefill chunk scores a block of cached rows (``kv_stats()`` and
+# the construction log line say it): the decode step's form, queries
+# folded through ``w_kvb``, scores and the weighted sum over the rows
+# themselves (``2 T heads R (2 kv_lora_rank + qk_rope_dim)`` FLOPs a
+# layer). The other form, a block's keys and values built from its
+# latents and attention as written (``2 R kv_lora_rank heads (nope + v)
+# + 2 T heads R (nope + rope + v)``: half the FLOPs at the cell's
+# shape), was built and timed beside it on the chip: 47.0 ms a chunk
+# against 47.2 at 16,384 cached rows (my chip run, PR 38; PERF.md
+# section 6): the blocks' float32 softmax, not either form's matmuls,
+# is what a chunk's attention waits for. One was kept, the one that
+# shares the decode step's code.
+CHUNK_ATTENTION = "absorbed"
+
+
+def _placed(q, pack: int):
+    """``q [..., w]`` laid into each token's lanes of a row ``pack``
+    tokens wide: ``[pack, ..., pack * w]`` (``[q | 0]``, ``[0 | q]``);
+    the other lanes add exact zeros to a float32 sum."""
+    w = q.shape[-1]
+    lead = [(0, 0)] * (q.ndim - 1)
+    return jnp.stack([
+        jnp.pad(q, lead + [(j * w, (pack - 1 - j) * w)])
+        for j in range(pack)
+    ])
+
+
+def decode_attend(config, pool, layer, tables, lengths, block_size: int,
+                  taps=None):
+    """The decode step's ``attend`` for one layer: every slot's query
+    (at position ``lengths``) over its pool rows below ``lengths`` and
+    its own new row. ``taps``: a dict filled with what the step keeps to
+    itself (a check's probe reads it; the served program passes none):
+    the absorbed ``queries [slots, heads, cache_width]`` and their
+    ``scores [slots, heads, max_len]`` against the slots' rows, float32,
+    before the scale and the mask."""
+    slots, max_blocks = tables.shape
+    max_len = max_blocks * block_size
+    r, width = config.kv_lora_rank, config.cache_width
+    pack = pool.pack
+
+    def attend(p, q_nope, q_rope, row):
+        with jax.named_scope("absorb"):
+            q = latent_lm.absorb_queries(config, p, q_nope[:, 0], q_rope[:, 0])
+        view = pool.blocks_at(layer, tables).reshape(
+            slots, max_len // pack, pack * width
+        )
+        own = row[:, 0].astype(view.dtype)
+        with jax.named_scope("scores"):
+            scores = _scores(
+                "pshw,stw->shtp", _placed(q, pack), view
+            ).reshape(slots, -1, max_len)
+            if taps is not None:
+                taps.update(queries=q, scores=scores)
+            visible = jnp.arange(max_len)[None, :] < lengths[:, None]
+            scores = jnp.where(visible[:, None, :], scores, -jnp.inf)
+            mine = _scores("shw,sw->sh", q, own)
+            probs = jax.nn.softmax(
+                jnp.concatenate([scores, mine[..., None]], axis=-1)
+                * config.softmax_scale, axis=-1,
+            ).astype(view.dtype)
+        with jax.named_scope("values"):
+            # a token's probability against its row AS STORED; its
+            # latent is read back from its own lanes
+            wide = jnp.einsum(
+                "shtp,stw->pshw",
+                probs[..., :-1].reshape(slots, -1, max_len // pack, pack),
+                view, preferred_element_type=jnp.float32,
+            )
+            mixed = sum(
+                wide[j, ..., j * width:j * width + r] for j in range(pack)
+            ) + probs[..., -1:].astype(jnp.float32) * own[:, None, :r]
+            return latent_lm.values_out(
+                config, p, mixed.astype(view.dtype)
+            )[:, None]
+
+    return attend
+
+
+def _softmax_start(heads: int, queries: int, width: int):
+    """A running softmax over blocks of keys: (largest score, sum of
+    exponentials, weighted values ``[queries, heads, width]``), all
+    float32."""
+    return (
+        jnp.full((heads, queries), -jnp.inf, jnp.float32),
+        jnp.zeros((heads, queries), jnp.float32),
+        jnp.zeros((queries, heads, width), jnp.float32),
+    )
+
+
+def _softmax_add(carry, scores, values):
+    """One more block: ``scores [heads, queries, keys]`` float32 (-inf
+    where masked), ``values(probs) -> [queries, heads, width]``."""
+    top, total, acc = carry
+    new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+    # A query that has seen nothing visible yet keeps -inf: its running
+    # state stays finite (and zero).
+    safe = jnp.where(jnp.isfinite(new_top), new_top, 0.0)
+    scale = jnp.exp(top - safe)
+    probs = jnp.exp(scores - safe[..., None])
+    total = total * scale + jnp.sum(probs, axis=-1)
+    acc = acc * scale.T[..., None] + values(probs)
+    return new_top, total, acc
+
+
+def _softmax_finish(carry):
+    _, total, acc = carry
+    return acc / total.T[..., None]
+
+
+def chunk_attend(config, pool, layer, table_row, start, block_size: int):
+    """The prefill chunk's ``attend`` for one layer: the chunk's queries
+    (positions ``start ...``) over the slot's rows below ``start``, a
+    block of :data:`CHUNK_PREFIX_ROWS` at a time, and over the chunk's
+    own rows, causally. Rows past a short last chunk's tokens are
+    padding: they come after every valid query, so causality hides them,
+    and their own output is never read."""
+    r = config.kv_lora_rank
+    per = max(CHUNK_PREFIX_ROWS // block_size, 1)      # table entries a block
+    span = per * block_size
+    n_table = -(-table_row.shape[0] // per) * per
+    table = jnp.pad(
+        table_row, (0, n_table - table_row.shape[0]),
+        constant_values=SENTINEL_BLOCK,
+    )
+    scale = config.softmax_scale
+
+    def attend(p, q_nope, q_rope, row):
+        chunk, heads = q_nope.shape[1], q_nope.shape[2]
+        cdt = row.dtype
+        with jax.named_scope("absorb"):
+            q = latent_lm.absorb_queries(config, p, q_nope[0], q_rope[0])
+
+        def over(rows, visible, carry):
+            """``rows [k, cache_width]`` under ``visible [q, k]``."""
+            rows = rows.astype(cdt)
+            with jax.named_scope("scores"):
+                scores = _scores("qhw,kw->hqk", q, rows) * scale
+                scores = jnp.where(visible[None], scores, -jnp.inf)
+            with jax.named_scope("values"):
+                return _softmax_add(
+                    carry, scores,
+                    lambda probs: jnp.einsum(
+                        "hqk,kr->qhr", probs.astype(cdt), rows[:, :r],
+                        preferred_element_type=jnp.float32,
+                    ),
+                )
+
+        def prefix_block(i, carry):
+            ids = jax.lax.dynamic_slice_in_dim(table, i * per, per)
+            rows = pool.blocks_at(layer, ids).reshape(span, -1)
+            below = (i * span + jnp.arange(span)) < start
+            return over(
+                rows, jnp.broadcast_to(below[None, :], (chunk, span)), carry
+            )
+
+        carry = jax.lax.fori_loop(
+            0, (start + span - 1) // span, prefix_block,
+            _softmax_start(heads, chunk, r),
+        )
+        causal = jnp.arange(chunk)[None, :] <= jnp.arange(chunk)[:, None]
+        out = _softmax_finish(over(row[0], causal, carry)).astype(cdt)
+        with jax.named_scope("values"):
+            return latent_lm.values_out(config, p, out)[None]
+
+    return attend
+
+
+def decode_forward(config, pool, params, tables, lengths, tokens,
+                   block_size: int):
+    """All layers for one token a slot: float32 ``logits [slots,
+    vocab]``, the new rows ``[L, slots, cache_width]`` and per expert
+    layer the experts hit and the expert rows dropped."""
+    positions = lengths[:, None]
+    streams = latent_lm.embed_streams(config, params, tokens[:, None])
+
+    def body(streams, p, layer):
+        # The pool is closed over WHOLE, as in the dense in-place
+        # programs: the gather picks its rows from all layers'.
+        streams, row, c = latent_lm.block(
+            config, params, p, layer, streams, positions,
+            decode_attend(config, pool, layer, tables, lengths, block_size),
+        )
+        counts = None if c is None else jnp.stack(
+            [c.experts_hit, c.rows_dropped]
+        )
+        return streams, (row[:, 0], counts)
+
+    streams, (rows, counts) = latent_lm.layer_loop(
+        config, params, body, streams
+    )
+    logits = latent_lm.unembed_streams(config, params, streams)[:, 0]
+    return logits, rows, counts
+
+
+def chunk_forward(config, pool, params, tokens, table_row, start,
+                  block_size: int):
+    """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
+    ``start ...``: the final streams ``[1, chunk, n, d]`` and the
+    chunk's new rows ``[L, chunk, cache_width]``."""
+    positions = (
+        start + jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    )[None, :]
+    streams = latent_lm.embed_streams(config, params, tokens)
+
+    def body(streams, p, layer):
+        streams, row, _ = latent_lm.block(
+            config, params, p, layer, streams, positions,
+            chunk_attend(config, pool, layer, table_row, start, block_size),
+        )
+        return streams, (row[0], None)
+
+    streams, (rows, _) = latent_lm.layer_loop(config, params, body, streams)
+    return streams, rows
+
+
+def build_decode(config, slots: int, max_blocks: int, block_size: int,
+                 counts):
+    max_len = max_blocks * block_size
+
+    def step(pool, params, tables, lengths, tokens, active, temps, rng,
+             step_idx, first=0, first_slot=-1):
+        counts["decode"] += 1  # traces only
+        tokens = _place_first(tokens, first, first_slot)
+        logits, rows, moe = decode_forward(
+            config, pool, params, tables, lengths, tokens, block_size
+        )
+        write = jnp.minimum(lengths, max_len - 1)
+        blk = jnp.take_along_axis(
+            tables, (write // block_size)[:, None], axis=1
+        )[:, 0]
+        blk = jnp.where(active, blk, SENTINEL_BLOCK)
+        off = jnp.where(active, write % block_size, 0)
+        pool = pool.land_tokens(rows, blk, off)
+        sub = jax.random.fold_in(rng, step_idx * 2)
+        nxt = gen_lib.sample_token(logits, sub, temps)
+        if moe is None:
+            aux = jnp.zeros((2,), jnp.float32)
+        else:
+            aux = jnp.stack([
+                jnp.mean(moe[:, 0].astype(jnp.float32)),
+                jnp.sum(moe[:, 1]).astype(jnp.float32),
+            ])
+        return pool, jnp.where(active, nxt, tokens), aux
+
+    return step
+
+
+def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
+                  counts):
+    def prefill(pool, params, tokens, table_row, start, n_valid, temp,
+                rng, step_idx, last=True):
+        counts["prefill"] += 1  # traces only
+        streams, rows = chunk_forward(
+            config, pool, params, tokens, table_row, start, block_size
+        )
+        pool = pool.land_run(
+            rows, table_row, start, block_size, SENTINEL_BLOCK
+        )
+
+        def head():
+            h = jax.lax.dynamic_slice_in_dim(streams, n_valid - 1, 1, axis=1)
+            logits = latent_lm.unembed_streams(config, params, h)[0, 0]
+            sub = jax.random.fold_in(rng, step_idx * 2 + 1)
+            return gen_lib.sample_token(logits, sub, temp)
+
+        first = jax.lax.cond(last, head, lambda: jnp.zeros((), jnp.int32))
+        return pool, first
+
+    return prefill

@@ -36,7 +36,12 @@ pool's dtype, bit for bit, in the LOGICAL shape ``[layers, n,
 block_size, index_dim]`` whatever a row of the sender's pool holds
 (kvpool/index_pool.py; the header's ``index`` gives dtype, shape and
 byte count), because which rows a query selects hangs on their exact
-values, and a block that arrived without them would be read as zeros. Wall-clock export
+values, and a block that arrived without them would be read as zeros.
+Every other array a model's blocks hold beside K and V travels the same
+way (``kvpool/layout.py``: the header's ``raw`` lists name, dtype, shape
+and byte count, the bytes follow the index keys'); a latent model's
+blocks hold nothing else (``latent``), and its payload ends there, with
+no kv wire. Wall-clock export
 stamps bound the migration pause across processes on one host.
 """
 
@@ -101,24 +106,32 @@ def export_request(engine, req: Request,
         jax.device_get(engine._steps.exp(*engine._pools(), np.int32(b)))
         for b in blocks
     ]
-    k_rows = np.stack([r[0] for r in rows], axis=1)
-    v_rows = np.stack([r[1] for r in rows], axis=1)
-    if engine._quantized:
+    by_name = {
+        a.name: np.stack([np.asarray(r[i]) for r in rows], axis=1)
+        for i, a in enumerate(engine._layout)
+    }
+    wire = b""
+    if "k" in by_name:
         wire = kv_to_wire(
-            k_rows, v_rows,
-            k_scale=np.stack([r[2] for r in rows], axis=1),
-            v_scale=np.stack([r[3] for r in rows], axis=1),
+            by_name["k"], by_name["v"],
+            k_scale=by_name.get("k_scale"), v_scale=by_name.get("v_scale"),
         )
-    else:
-        wire = kv_to_wire(k_rows, v_rows)
-    index_bytes, index_meta = b"", None
-    if engine._index_dim:
-        ki_rows = np.stack([np.asarray(r[2]) for r in rows], axis=1)
-        index_bytes = np.ascontiguousarray(ki_rows).view(np.uint8).tobytes()
-        index_meta = {
-            "dtype": str(ki_rows.dtype), "shape": list(ki_rows.shape),
-            "nbytes": len(index_bytes),
+    # What the blocks hold beside K and V travels raw, in the pool's
+    # dtype and logical shape (the index keys under their own key).
+    raw_bytes, index_meta, raw_meta = b"", None, []
+    for a in engine._layout:
+        if not a.raw:
+            continue
+        data = np.ascontiguousarray(by_name[a.name]).view(np.uint8).tobytes()
+        meta = {
+            "dtype": str(by_name[a.name].dtype),
+            "shape": list(by_name[a.name].shape), "nbytes": len(data),
         }
+        raw_bytes += data
+        if a.name == "index_keys":
+            index_meta = meta
+        else:
+            raw_meta.append(dict(meta, name=a.name))
     admit_ts = req.admit_ts if req.admit_ts is not None else (
         req.submit_ts
     )
@@ -137,6 +150,7 @@ def export_request(engine, req: Request,
         "block_size": engine.block_size,
         "src_kv_dtype": engine.kv_cache_dtype,
         "index": index_meta,
+        "raw": raw_meta,
         # Source-side phase durations, for timeline reconstruction on
         # the destination clock (monotonic stamps don't cross
         # processes; durations do).
@@ -152,7 +166,7 @@ def export_request(engine, req: Request,
     }
     hdr = json.dumps(header).encode()
     return b"".join(
-        [MIGRATE_MAGIC, struct.pack("<I", len(hdr)), hdr, index_bytes, wire]
+        [MIGRATE_MAGIC, struct.pack("<I", len(hdr)), hdr, raw_bytes, wire]
     )
 
 
@@ -204,31 +218,47 @@ def import_request(engine, payload: bytes,
     header = peek_header(payload)
     (hlen,) = struct.unpack_from("<I", payload, 4)
     index_meta = header.get("index")
-    n_index = index_meta["nbytes"] if index_meta else 0
-    kq, vq, ks, vs, _ = kv_from_wire(payload[8 + hlen + n_index:])
-    L, n, bs, kh, hd = kq.shape
-    cfg = engine.config
-    ki_rows = None
     if bool(index_meta) != bool(engine._index_dim):
         raise MigrationError(
             "index keys on one side only: wire "
             f"{'has' if index_meta else 'lacks'} them, engine "
             f"{'keeps' if engine._index_dim else 'does not keep'} them"
         )
-    if index_meta:
-        ki_rows = np.frombuffer(
-            payload[8 + hlen:8 + hlen + n_index], np.uint8
-        ).view(jnp.dtype(index_meta["dtype"])).reshape(index_meta["shape"])
-        if ki_rows.shape != (L, n, bs, engine._index_dim):
-            raise MigrationError(
-                f"index keys {ki_rows.shape} vs K/V blocks {(L, n, bs)} "
-                f"x {engine._index_dim}"
-            )
-    if (L, kh, hd) != (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim):
+    metas = ([dict(index_meta, name="index_keys")] if index_meta else []) \
+        + list(header.get("raw") or ())
+    wants = [a for a in engine._layout if a.raw]
+    if [m["name"] for m in metas] != [a.name for a in wants]:
         raise MigrationError(
-            f"model shape mismatch: wire {(L, kh, hd)} vs engine "
-            f"{(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}"
+            f"wire blocks hold {[m['name'] for m in metas]} beside K/V, "
+            f"the engine's {[a.name for a in wants]}"
         )
+    raw_rows, at = {}, 8 + hlen
+    for m in metas:
+        raw_rows[m["name"]] = np.frombuffer(
+            payload[at:at + m["nbytes"]], np.uint8
+        ).view(jnp.dtype(m["dtype"])).reshape(m["shape"])
+        at += m["nbytes"]
+    cfg = engine.config
+    has_kv = any(a.name == "k" for a in engine._layout)
+    if has_kv:
+        kq, vq, ks, vs, _ = kv_from_wire(payload[at:])
+        L, n, bs, kh, hd = kq.shape
+        if (L, kh, hd) != (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim):
+            raise MigrationError(
+                f"model shape mismatch: wire {(L, kh, hd)} vs engine "
+                f"{(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}"
+            )
+    elif len(payload) != at or not wants:
+        raise MigrationError("a kv wire for an engine that keeps no K/V")
+    else:
+        L, n, bs = raw_rows[wants[0].name].shape[:3]
+    for a in wants:
+        want = (cfg.n_layers, n, bs) + a.row_shape
+        if raw_rows[a.name].shape != want:
+            raise MigrationError(
+                f"{a.name} {raw_rows[a.name].shape} vs blocks "
+                f"{(cfg.n_layers, n, bs)} x {a.row_shape}"
+            )
     if bs != engine.block_size or bs != header["block_size"]:
         raise MigrationError(
             f"block_size mismatch: wire {bs} vs engine "
@@ -274,26 +304,22 @@ def import_request(engine, payload: bytes,
     engine._lengths[slot] = fill
     engine._tokens[slot] = req.tokens[-1]
     engine._temps[slot] = req.temperature
-    if engine._quantized:
-        for i, dst in enumerate(blocks):
-            engine._set_pools(engine._steps.imp(
-                *engine._pools(),
-                jnp.asarray(kq[:, i]), jnp.asarray(vq[:, i]),
-                jnp.asarray(ks[:, i]), jnp.asarray(vs[:, i]),
-                np.int32(dst),
-            ))
-    else:
+    arriving = dict(raw_rows)
+    if has_kv and engine._quantized:
+        arriving.update(k=kq, v=vq, k_scale=ks, v_scale=vs)
+    elif has_kv:
         # fp destination: dequantize the int8 wire rows on the host
         # (q * scale is exact in f32 — the idempotent-roundtrip rule).
-        kf = kq.astype(np.float32) * ks[..., None]
-        vf = vq.astype(np.float32) * vs[..., None]
-        for i, dst in enumerate(blocks):
-            extra = () if ki_rows is None else (jnp.asarray(ki_rows[:, i]),)
-            engine._set_pools(engine._steps.imp(
-                *engine._pools(),
-                jnp.asarray(kf[:, i]), jnp.asarray(vf[:, i]), *extra,
-                np.int32(dst),
-            ))
+        arriving.update(
+            k=kq.astype(np.float32) * ks[..., None],
+            v=vq.astype(np.float32) * vs[..., None],
+        )
+    for i, dst in enumerate(blocks):
+        engine._set_pools(engine._steps.imp(
+            *engine._pools(),
+            *(jnp.asarray(arriving[a.name][:, i]) for a in engine._layout),
+            np.int32(dst),
+        ))
     if engine._cache is not None:
         # Imported chains join the destination trie: the NEXT request
         # sharing this prompt hits warm blocks — hit-rate survives

@@ -854,9 +854,7 @@ def test_prefill_runs_its_head_on_a_last_chunk_only(
 
     def chunk(*flag):
         # The programs donate their pools: a fresh set a call.
-        pools = eng._fresh_pool()
-        if kv_dtype == "int8":
-            pools += eng._fresh_scales()
+        pools = tuple(eng._fresh_arrays().values())
         *pools, first = eng._steps.prefill(*pools, *args, *flag)
         return [np.asarray(p) for p in pools], int(first)
 

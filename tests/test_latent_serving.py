@@ -1,0 +1,430 @@
+"""The latent-attention model (``models/latent_lm.py``) through the paged
+engine (``serving/kvpool/latent.py``), on a CPU at tiny size with seeded
+random weights: chunked prefill + absorbed decode through the pool
+against the plain reference's full forward (``benchmark/reference_xing``)
+on LOGITS, at positions past ``rope_original_max`` so that the YaRN blend
+is live; the absorbed decode against the unabsorbed definition; prefix
+hits, copy-on-write, preemption and migration over the one latent array;
+the residual maps; the expert layer in all three programs."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_xing
+from dlrover_tpu.models import hybrid, latent_lm
+from dlrover_tpu.ops import rope
+from dlrover_tpu.serving.kvpool import (
+    PagedServingEngine,
+    export_request,
+    import_request,
+    latent,
+    layout,
+    release_exported,
+)
+from dlrover_tpu.serving.kvpool.index_pool import IndexKeyPool
+from tests.benchmark import tiny_xing
+
+BS, CHUNK = 4, 8
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = latent_lm.tiny_config()
+    return cfg, latent_lm.init_params(cfg, jax.random.key(0))
+
+
+def cfg_json_of(cfg):
+    """The published keys that describe ``cfg`` (for the reference)."""
+    out = dict(tiny_xing.CONFIG)
+    out.update(
+        hidden_size=cfg.embed_dim, vocab_size=cfg.vocab_size,
+        num_hidden_layers=cfg.n_layers,
+        first_k_dense_replace=cfg.first_dense,
+        num_attention_heads=cfg.n_heads, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_dim, qk_rope_head_dim=cfg.qk_rope_dim,
+        v_head_dim=cfg.v_head_dim, hc_mult=cfg.hc_mult,
+        n_routed_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.moe_top_k,
+        rope_scaling=dict(
+            tiny_xing.CONFIG["rope_scaling"], factor=cfg.rope_factor,
+            original_max_position_embeddings=cfg.rope_original_max,
+            beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
+        ),
+    )
+    return out
+
+
+def prompts(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+
+
+def engine(cfg, params, **kw):
+    kw = dict(dict(slots=3, max_len=64, prefill_chunk=CHUNK, block_size=BS,
+                   num_blocks=60), **kw)
+    return PagedServingEngine(cfg, params, **kw)
+
+
+def serve(eng, items):
+    reqs = [eng.submit(p, n) for p, n in items]
+    while eng.pending():
+        eng.step()
+    eng.check_block_invariants()
+    return [list(r.tokens) for r in reqs]
+
+
+def reference_logits(cfg, params, seq, rows):
+    tokens = np.zeros(-(-len(seq) // 64) * 64, np.int32)
+    tokens[:len(seq)] = seq
+    return np.asarray(reference_xing.forward_at(
+        params, jnp.asarray(tokens), jnp.asarray(rows, jnp.int32),
+        cfg_json_of(cfg),
+    )["logits"])
+
+
+def test_the_config_states_one_latent_row_and_the_pool_builds_it(tiny):
+    cfg, params = tiny
+    assert cfg.cache_rows == (("latent", (cfg.cache_width,)),)
+    assert cfg.cache_width == cfg.kv_lora_rank + cfg.qk_rope_dim
+    (a,) = layout.pool_arrays(cfg)
+    assert (a.name, a.row_shape, a.raw) == ("latent", (24,), True)
+    with pytest.raises(ValueError, match="int8 pool holds K and V alone"):
+        layout.pool_arrays(cfg, "int8")
+    eng = engine(cfg, params)
+    (pool,) = eng._pools()
+    assert pool.shape == (cfg.n_layers, 60, BS, 24)
+    assert eng._k is None and eng._v is None and eng._latent is pool
+    stats = eng.kv_stats()
+    assert stats["pool_attention"] == "latent_absorbed"
+    assert stats["latent_chunk_attention"] == "absorbed"
+    assert stats["latent_row_bytes"] == 24 * 4
+    assert stats["latent_pool_bytes"] == pool.nbytes
+    assert eng._block_bytes * eng.num_blocks == pool.nbytes
+    with pytest.raises(ValueError, match="speculative programs read K and V"):
+        engine(cfg, params, spec_k=2)
+
+
+def test_dense_int8_and_sparse_models_state_todays_arrays():
+    from dlrover_tpu.models import llama, sparse_lm
+
+    dense = llama.tiny_config()
+    head = (dense.n_kv_heads, dense.head_dim)
+    assert [(a.name, a.row_shape, jnp.dtype(a.dtype).name, a.raw)
+            for a in layout.pool_arrays(dense)] == [
+        ("k", head, "float32", False), ("v", head, "float32", False)]
+    assert [(a.name, a.row_shape, jnp.dtype(a.dtype).name)
+            for a in layout.pool_arrays(dense, "int8")] == [
+        ("k", head, "int8"), ("v", head, "int8"),
+        ("k_scale", head[:1], "float32"), ("v_scale", head[:1], "float32")]
+    sparse = sparse_lm.tiny_config()
+    names = [a.name for a in layout.pool_arrays(sparse)]
+    assert names == ["k", "v", "index_keys"]
+    assert layout.pool_arrays(sparse)[2].raw
+
+
+def test_engine_logits_are_the_references_past_the_yarn_range(tiny):
+    """Chunked prefill and absorbed decode through the pool, program by
+    program, against the reference's full forward on LOGITS; the rows lie
+    at positions 37 ... 45 of a model whose rotation was published for 16,
+    where the blended and the stretched frequencies both turn."""
+    cfg, params = tiny
+    assert cfg.rope_original_max == 16 and cfg.rope_factor > 1
+    inv = np.asarray(latent_lm.inv_frequencies(cfg))
+    plain = np.asarray(rope.rope_frequencies(cfg.qk_rope_dim, cfg.rope_theta))
+    assert inv[0] == plain[0] and np.isclose(inv[-1], plain[-1] / 8)
+    assert ((inv < plain) & (inv > plain / 8)).any(), "no blended pair"
+    (prompt,) = prompts(cfg, (37,))
+    max_blocks = 16
+    pool = layout.fresh(layout.pool_arrays(cfg)[0], cfg.n_layers, 40, BS)
+    table = jnp.arange(1, max_blocks + 1, dtype=jnp.int32)
+    served = latent_lm.prepare_decode_params(cfg, params)
+    got = []
+    for start in range(0, len(prompt), CHUNK):
+        piece = prompt[start:start + CHUNK]
+        tokens = np.zeros((1, CHUNK), np.int32)
+        tokens[0, :len(piece)] = piece
+        streams, rows = latent.chunk_forward(
+            cfg, pool, served, jnp.asarray(tokens), table, jnp.int32(start),
+            BS,
+        )
+        pool = pool.land_run(rows, table, start, BS, 0)
+    got.append(np.asarray(latent_lm.unembed_streams(
+        cfg, served, streams[:, len(piece) - 1:len(piece)]
+    ))[0, 0])
+    seq = list(prompt)
+    for _ in range(8):
+        seq.append(int(got[-1].argmax()))
+        at = len(seq) - 1
+        logits, rows, moe = latent.decode_forward(
+            cfg, pool, served, table[None], jnp.asarray([at], jnp.int32),
+            jnp.asarray([seq[-1]], jnp.int32), BS,
+        )
+        pool = pool.land_tokens(
+            rows, table[at // BS][None], jnp.asarray([at % BS])
+        )
+        got.append(np.asarray(logits)[0])
+        assert int(np.asarray(moe)[:, 1].sum()) == 0
+    want = reference_logits(
+        cfg, params, seq, range(len(prompt) - 1, len(seq))
+    )
+    np.testing.assert_allclose(np.stack(got), want, atol=2e-4, rtol=1e-4)
+    assert (np.stack(got).argmax(-1) == want.argmax(-1)).all()
+
+
+def test_served_tokens_are_the_full_forwards(tiny):
+    cfg, params = tiny
+    items = list(zip(prompts(cfg, (37, 21, 30, 9)), (6, 5, 7, 4)))
+    eng = engine(cfg, params)
+    eng.warmup()
+    traced = dict(eng.trace_counts)
+    tokens = serve(eng, items)
+    assert dict(eng.trace_counts) == traced      # no retrace after warm-up
+    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t))
+    for (prompt, n), out in zip(items, tokens):
+        logits, dropped = fwd(jnp.asarray([prompt + out]))
+        want = np.asarray(logits)[0, len(prompt) - 1:-1].argmax(-1)
+        assert out == want.tolist() and len(out) == n
+        assert int(dropped) == 0
+    assert eng.kv_stats()["moe_rows_dropped"] == 0
+
+
+def test_the_absorbed_decode_is_the_unabsorbed_definition(tiny):
+    """One layer, one slot: the decode step's attention over pool rows
+    and the chunk's against attention as written over the same rows."""
+    cfg, params = tiny
+    p = latent_lm.layer_params(params, 1)
+    rng = np.random.default_rng(3)
+    n = 27
+    h = jnp.asarray(rng.normal(size=(1, n, cfg.embed_dim)), jnp.float32)
+    positions = jnp.arange(n, dtype=jnp.int32)[None]
+    q_nope, q_rope, row = latent_lm.latent_inputs(cfg, p, h, positions)
+    want = latent_lm.definition_attention(
+        cfg, p, q_nope[0], q_rope[0], row[0]
+    )
+    pool = layout.fresh(layout.pool_arrays(cfg)[0], cfg.n_layers, 12, BS)
+    table = jnp.arange(1, 9, dtype=jnp.int32)
+    rows = jnp.zeros((cfg.n_layers, n, cfg.cache_width)).at[1].set(row[0])
+    pool = pool.land_run(rows[:, :n - 1], table, 0, BS, 0)
+    got = latent.decode_attend(
+        cfg, pool, 1, table[None], jnp.asarray([n - 1], jnp.int32), BS
+    )(p, q_nope[:, -1:], q_rope[:, -1:], row[:, -1:])
+    np.testing.assert_allclose(got[0, 0], want[-1], atol=1e-5, rtol=1e-5)
+    start = 16
+    got = latent.chunk_attend(cfg, pool, 1, table, start, BS)(
+        p, q_nope[:, start:start + CHUNK], q_rope[:, start:start + CHUNK],
+        row[:, start:start + CHUNK],
+    )
+    np.testing.assert_allclose(
+        got[0], want[start:start + CHUNK], atol=1e-5, rtol=1e-5
+    )
+
+
+def test_a_prefix_hit_request_is_the_request_served_cold(tiny):
+    cfg, params = tiny
+    (context,), (turn,) = prompts(cfg, (32,), 5), prompts(cfg, (7,), 6)
+    cold = engine(cfg, params)
+    (want,) = serve(cold, [(context + turn, 6)])
+    assert cold.kv_stats()["prefix_hits"] == 0
+    warm = engine(cfg, params)
+    serve(warm, [(context, 1)])
+    (got,) = serve(warm, [(context + turn, 6)])
+    stats = warm.kv_stats()
+    assert got == want
+    assert stats["prefix_hits"] == 1 and stats["prefix_hit_tokens"] == 32
+    shared = warm._cache.lookup(np.asarray(context))
+    assert len(shared) == 32 // BS
+    own = cold._cache.lookup(np.asarray(context))
+    np.testing.assert_array_equal(
+        np.asarray(warm._latent[:, np.asarray(shared)]),
+        np.asarray(cold._latent[:, np.asarray(own)]),
+    )
+
+
+def test_cow_and_preemption_keep_the_latent_rows_consistent(tiny):
+    """A full-prompt hit re-runs its last chunk; with blocks longer than
+    a chunk that chunk lies inside a SHARED block, which is copied first,
+    latent rows and all. Then a pool too small for its slots: the
+    youngest request is preempted and served again, and every answer is
+    the unpressed engine's."""
+    cfg, params = tiny
+    (prompt,) = prompts(cfg, (32,), 7)
+    long_blocks = engine(cfg, params, prefill_chunk=4, block_size=8,
+                         num_blocks=40)
+    (first,) = serve(long_blocks, [(prompt, 5)])
+    shared = long_blocks._cache.lookup(np.asarray(prompt))
+    before = np.asarray(long_blocks._latent[:, np.asarray(shared)])
+    (again,) = serve(long_blocks, [(prompt, 5)])
+    assert again == first
+    assert long_blocks.kv_stats()["cow_copies"] >= 1
+    np.testing.assert_array_equal(          # the shared chain is untouched
+        np.asarray(long_blocks._latent[:, np.asarray(shared)]), before
+    )
+    assert np.abs(before).max() > 0
+    (context,) = prompts(cfg, (30,), 7)
+    turns = prompts(cfg, (5, 9, 3, 6), 8)
+    items = [(context + t, 12) for t in turns]
+    roomy = engine(cfg, params, slots=4, num_blocks=120)
+    want = serve(roomy, items)
+    tight = engine(cfg, params, slots=4, num_blocks=24)
+    serve(tight, [(context, 1)])
+    got = serve(tight, items)
+    assert got == want
+    stats = tight.kv_stats()
+    assert tight.metrics.kv_preemptions.value() > 0
+    assert stats["used"] == 0 and stats["moe_rows_dropped"] == 0
+
+
+def test_a_migrated_request_carries_its_latent_rows(tiny):
+    cfg, params = tiny
+    (prompt,) = prompts(cfg, (19,), 9)
+    (want,) = serve(engine(cfg, params), [(prompt, 9)])
+    src, dst = engine(cfg, params), engine(cfg, params)
+    req = src.submit(prompt, 9)
+    while len(req.tokens) < 3:
+        src.step()
+    payload = export_request(src, req)
+    moved = import_request(dst, payload)
+    release_exported(src, req)
+    rows_src = np.asarray(src._latent[:, src._slot_blocks[req.slot]]) \
+        if req.slot >= 0 else None
+    assert rows_src is None        # the source let go of the slot
+    while dst.pending():
+        dst.step()
+    assert list(moved.tokens) == want
+    for eng in (src, dst):
+        eng.check_block_invariants()
+    dense = __import__("dlrover_tpu.models.llama", fromlist=["x"])
+    other = PagedServingEngine(
+        dense.tiny_config(), dense.init_params(
+            dense.tiny_config(), jax.random.key(0))[0],
+        slots=2, max_len=64, prefill_chunk=CHUNK, block_size=BS,
+    )
+    from dlrover_tpu.serving.kvpool import MigrationError
+
+    with pytest.raises(MigrationError):
+        import_request(other, payload)
+
+
+def test_a_packed_pool_serves_what_a_bare_one_serves():
+    """A 192-wide row is 1.5 lane rows: the device holds two tokens to a
+    384-lane row, and everything (decode, chunk, landings, COW, prefix
+    sharing) reads it by token coordinates."""
+    cfg = latent_lm.tiny_config(kv_lora_rank=128, qk_rope_dim=64)
+    params = latent_lm.init_params(cfg, jax.random.key(1))
+    items = list(zip(prompts(cfg, (33, 18, 26), 11), (6, 7, 5)))
+    packed = engine(cfg, params)
+    assert packed._latent.pack == 2
+    assert packed._latent.rows.shape[-2:] == (BS // 2, 384)
+    assert packed._latent.shape == (cfg.n_layers, 60, BS, 192)
+    got = serve(packed, items)
+    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t)[0])
+    for (prompt, _), out in zip(items, got):
+        logits = np.asarray(fwd(jnp.asarray([prompt + out])))[0]
+        assert out == logits[len(prompt) - 1:-1].argmax(-1).tolist()
+    bare = IndexKeyPool.of(jnp.zeros((cfg.n_layers, 60, BS, 192)))
+    assert bare.pack == 1
+
+
+@pytest.mark.parametrize("width,block,want", [
+    (576, 64, 2), (192, 4, 2), (576, 1, 1), (640, 64, 1), (64, 64, 2),
+    (24, 4, 1), (256, 64, 1),
+])
+def test_tokens_a_device_row_holds(width, block, want):
+    from dlrover_tpu.serving.kvpool.index_pool import tokens_per_row
+
+    assert tokens_per_row(width, block) == want
+
+
+def test_h_res_is_doubly_stochastic_and_one_stream_is_the_plain_residual(tiny):
+    cfg, params = tiny
+    hp = jax.tree_util.tree_map(lambda a: a[0], params["layers"]["hc_attn"])
+    streams = jnp.asarray(
+        np.random.default_rng(0).normal(size=(64, cfg.hc_mult, cfg.embed_dim)),
+        jnp.float32,
+    )
+    maps = latent_lm.mhc_maps(cfg, hp, streams)
+    assert np.abs(np.asarray(maps.res.sum(-1)) - 1).max() < 1e-5
+    assert np.abs(np.asarray(maps.res.sum(-2)) - 1).max() < 1e-5
+    assert (np.asarray(maps.res) > 0).all()
+    assert np.asarray(maps.res).std() > 0.01          # not the uniform map
+    assert ((np.asarray(maps.pre) > 0) & (np.asarray(maps.pre) < 1)).all()
+    assert ((np.asarray(maps.post) > 0) & (np.asarray(maps.post) < 2)).all()
+    # n = 1, the maps forced to 1: x + f(norm(x)), sublayer by sublayer
+    one = dataclasses.replace(cfg, hc_mult=1, n_layers=2)
+    p1 = latent_lm.init_params(one, jax.random.key(2))
+    unit = latent_lm.ResidualMaps(
+        pre=jnp.ones((1, 5, 1)), post=jnp.ones((1, 5, 1)),
+        res=jnp.ones((1, 5, 1, 1)),
+    )
+    x = jnp.asarray(
+        np.random.default_rng(1).normal(size=(1, 5, one.embed_dim)),
+        jnp.float32,
+    )
+    y = 0.5 * x[..., ::-1]
+    np.testing.assert_array_equal(
+        latent_lm.mhc_read(unit, x[..., None, :]), x
+    )
+    np.testing.assert_allclose(
+        latent_lm.mhc_write(unit, x[..., None, :], y)[..., 0, :], x + y
+    )
+    # and a one-stream Sinkhorn map IS 1 (to hc_eps)
+    hp1 = jax.tree_util.tree_map(lambda a: a[0], p1["layers"]["hc_mlp"])
+    res = latent_lm.mhc_maps(one, hp1, x[0, :, None, :]).res
+    np.testing.assert_allclose(np.asarray(res), 1.0, atol=2e-6)
+
+
+def test_the_expert_layer_gives_a_token_one_output_in_every_program(tiny):
+    """The same normed input through the expert layer alone in a chunk of
+    8, in a decode batch of 3 and in a whole sequence: no capacity, so the
+    token's output is the same, and nothing is dropped."""
+    cfg, params = tiny
+    rng = np.random.default_rng(4)
+    h = jnp.asarray(rng.normal(size=(40, cfg.embed_dim)), jnp.float32)
+    whole, c = latent_lm.feed(cfg, params, jnp.int32(2), h[None])
+    assert int(c.rows_dropped) == 0
+    chunk, c = latent_lm.feed(cfg, params, jnp.int32(2), h[None, 16:24])
+    np.testing.assert_allclose(chunk[0], whole[0, 16:24], atol=1e-6)
+    step, c = latent_lm.feed(
+        cfg, params, jnp.int32(2), h[jnp.asarray([16, 3, 30])][:, None]
+    )
+    np.testing.assert_allclose(step[:, 0], whole[0, [16, 3, 30]], atol=1e-6)
+    assert int(c.rows_dropped) == 0 and 1 <= int(c.experts_hit) <= 6
+    # against the reference's every-expert-computes-every-token form
+    sh = reference_xing.shape_of(cfg_json_of(cfg))
+    _, pf = reference_xing.layer_weights(params, 2, sh)
+    with jax.default_matmul_precision("highest"):
+        want, ids, _ = reference_xing.mlp(pf, h, sh)
+    np.testing.assert_allclose(whole[0], want, atol=1e-5, rtol=1e-4)
+    got_ids, _ = latent_lm.route(
+        cfg, {k: params["moe"][k][1] for k in ("router", "router_bias")}, h
+    )
+    assert (np.sort(got_ids, -1) == np.sort(ids, -1)).all()
+
+
+def test_the_mixer_of_the_trained_model_takes_the_rotation_as_an_option():
+    """``hybrid._mla_apply`` with no rotation is the program it was (the
+    lowered-text digest in tests/test_hybrid_model.py holds it to the
+    byte); given one, queries' and the shared key's positional slices turn
+    and a shift of every position leaves the output alone."""
+    cfg = hybrid.tiny_config()
+    p = hybrid._mla_init(cfg, jax.random.key(0))
+    h = jnp.asarray(
+        np.random.default_rng(0).normal(size=(1, 12, cfg.embed_dim)),
+        jnp.float32,
+    )
+    nope = hybrid._mla_apply(cfg, p, h)
+
+    def rotate_from(first):
+        positions = first + jnp.arange(12, dtype=jnp.int32)[None]
+        return lambda x: rope.apply_rope(x, positions, 1e4)
+
+    turned = hybrid._mla_apply(cfg, p, h, rotate=rotate_from(0))
+    assert float(jnp.abs(turned - nope).max()) > 1e-3
+    np.testing.assert_allclose(
+        hybrid._mla_apply(cfg, p, h, rotate=rotate_from(40)), turned,
+        atol=2e-5,
+    )

@@ -527,6 +527,58 @@ def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
             assert "paged_pool_sparse_chunk_attention" not in text
 
 
+def test_latent_serving_programs_compile_at_the_cells_shape(topo):
+    """``xing-serve-sessions-16k``'s decode step and prefill chunk (1
+    dense + 1 expert layer of its 1 + 5: the scan's body is one expert
+    layer either way) at published widths, 32 slots x 17,408 rows over
+    the cell's 9,216-block pool, lower for the described v5e under their
+    trace names from what the engine's constructor builds
+    (``kvpool.engine._paged_steps``); the ONE pool array aliases its
+    output and is never copied (two 576-wide rows to a 1,152-lane device
+    row: the bare array is re-tiled whole, 4 GB twice a step), the expert
+    matmuls are the grouped kernel, every scope the cell's readers book
+    device time to is there, and no ``[heads, chunk, max_len]`` logits
+    exist in the chunk program."""
+    from benchmark import common, latent_scopes, trace_reduce
+    from benchmark import rehearse_xing
+
+    cfg_json = common.load_json("configs", "xing4-29b-a4b.json")
+    programs = rehearse_xing.lower_engine_programs(
+        cfg_json, topo.devices[0], probes=False, n_layers=2
+    )
+    pool = "bf16[2,9216,32,1152]"
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        assert "{0}: (0, {}, may-alias)" in text
+        assert 2 <= _n_kernels(c) <= 4           # gmm: gate|up, down
+        made = [
+            line for line in text.splitlines()
+            if f"= {pool}" in line and " parameter(" not in line
+            and "get-tuple-element" not in line and "bitcast" not in line
+        ]
+        # the landing scatter (alone or fused), in place: never a copy
+        assert made and not any(
+            " copy(" in line or " transpose(" in line for line in made
+        ), made
+        booked = {
+            latent_scopes.scope_of(op_name)
+            for op_name in trace_reduce.scopes_from_hlo(text).values()
+        }
+        assert booked >= {"mla", "mhc", "router", "experts", "shared",
+                          "dense"}
+        m = c.memory_analysis()
+        # 2 layers of weights (5.4 GB) + the 2-layer pool (1.4 GB) +
+        # temporaries: the other 4 expert layers add 4 x (1.49 + 0.68)
+        # GB of arguments and no temporaries.
+        assert m.temp_size_in_bytes < 1.5e9
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes < 8.5e9
+        if name == "jit_prefill":
+            assert "f32[32,512,17408]" not in text
+            assert "f32[32,512,2048]" in text    # a block of prefix rows
+
+
 @pytest.mark.parametrize("pool", ["the_engines_pool", "a_bare_array"])
 def test_sparse_serving_programs_do_not_copy_the_index_key_pool(topo, pool):
     """``keye-serve-docqa-32k``'s decode step and prefill chunk over the
