@@ -101,8 +101,8 @@ class _PagedSteps(NamedTuple):
     exp: object          # migration export: pool[src] -> one block's rows
     trace_counts: Dict[str, int]
     pool_attention: str = "xla_gather"   # see pool_attention_kind
-    # see sparse_chunk_attention_kind; "" for a dense model
-    sparse_chunk_attention: str = ""
+    sparse_chunk_attention: str = ""     # sparse_chunk_attention_kind
+    latent_decode_attention: str = ""    # latent.decode_attention_kind
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -816,6 +816,7 @@ def _paged_steps(
         sparse_chunk_attention_kind(
             config, config.compute_dtype, block_size, chunk, max_blocks
         ) if attn == "sparse_gather" else "",
+        _latent_decode_kind(config, attn, slots, block_size, max_blocks),
     )
 
 
@@ -823,7 +824,7 @@ def _paged_steps(
 def _paged_steps_for(
     config: llama.TpuLMConfig, slots: int, num_blocks: int,
     max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
-    attn: str, sparse_chunk: str = "",
+    attn: str, sparse_chunk: str = "", latent_decode: str = "",
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
@@ -836,7 +837,7 @@ def _paged_steps_for(
         from dlrover_tpu.serving.kvpool import latent
 
         build_decode = latent.build_decode(
-            config, slots, max_blocks, block_size, counts
+            config, slots, max_blocks, block_size, counts, latent_decode
         )
         build_prefill = latent.build_prefill(
             config, max_blocks, block_size, chunk, counts
@@ -870,10 +871,8 @@ def _paged_steps_for(
     # No donation: export reads the pools and the source keeps serving
     # from them until the importer acks.
     exp = jax.jit(_build_export_gather(counts, n_pools))
-    return _PagedSteps(prefill=prefill, decode=decode, cow=cow,
-                       imp=imp, exp=exp, trace_counts=counts,
-                       pool_attention=attn,
-                       sparse_chunk_attention=sparse_chunk)
+    return _PagedSteps(prefill, decode, cow, imp, exp, counts, attn,
+                       sparse_chunk, latent_decode)
 
 
 class PagedServingEngine(ServingEngine):
@@ -1000,15 +999,16 @@ class PagedServingEngine(ServingEngine):
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
-            "%s%s",
-            slots, max_len, self.num_blocks, block_size,
-            kv_cache_dtype,
+            "%s%s%s",
+            slots, max_len, self.num_blocks, block_size, kv_cache_dtype,
             f" + index keys [{self._index_dim}], "
             f"{self.index_tokens_per_row} to a row, top-"
             f"{config.index_topk}" if self._index_dim else "",
             self._block_holds(), self.pool_attention,
             ", the chunk under its selection by "
             f"{self.sparse_chunk_attention}" if self._index_dim else "",
+            f", the decode step's rows by {self.latent_decode_attention}"
+            if self.latent_decode_attention else "",
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -1518,6 +1518,15 @@ class PagedServingEngine(ServingEngine):
 
     # ---- observability -----------------------------------------------------
 
+    @property
+    def latent_decode_attention(self) -> str:
+        """``"pool_kernel"`` or ``"gathered_view"``: what a latent
+        model's decode program reads its cached rows with
+        (``latent.decode_attention_kind``); ``""`` for any other model.
+        (Not beside :attr:`pool_attention`: see
+        :func:`_latent_decode_kind`.)"""
+        return self._steps.latent_decode_attention
+
     def kv_stats(self) -> Dict[str, object]:
         """Allocator + prefix-cache accounting (heartbeats, SignalBus,
         bench, the chaos block-reclaim invariant)."""
@@ -1558,6 +1567,7 @@ class PagedServingEngine(ServingEngine):
             from dlrover_tpu.serving.kvpool import latent
 
             stats["latent_chunk_attention"] = latent.CHUNK_ATTENTION
+            stats["latent_decode_attention"] = self.latent_decode_attention
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value
@@ -1593,3 +1603,21 @@ class PagedServingEngine(ServingEngine):
                     f"pool array {a.name} of {pool.shape[:3]} in a pool "
                     f"of {want}: one table cannot address it"
                 )
+
+
+def _latent_decode_kind(config, attn: str, slots: int, block_size: int,
+                        max_blocks: int) -> str:
+    """``latent.decode_attention_kind`` for a latent model's programs,
+    ``""`` for any other's: the part of :func:`_paged_steps`'s key that
+    says what the decode step reads its cached rows with. Down here, the
+    module imported here: ``kvpool/latent.py`` builds on this one, and
+    no line above, where the dense and the sparse programs' kernels are
+    called from, moves for it (their compiled kernels carry those line
+    numbers: PERF.md section 6, PRs 35 and 40)."""
+    if attn != "latent_absorbed":
+        return ""
+    from dlrover_tpu.serving.kvpool import latent
+
+    return latent.decode_attention_kind(
+        config, config.compute_dtype, block_size, max_blocks, slots
+    )

@@ -20,11 +20,15 @@ cached token:
   cached row, the softmax-weighted sum of the rows' latents comes out
   ``[heads, kv_lora_rank]`` and only then meets ``w_kvb``'s value half
   (``latent_lm.values_out``). It reads each visible row once a layer
-  through the slot's table (a gathered ``[slots, max_len]`` view AS
-  STORED, never un-paired: the query is laid into each token's lanes of
-  a row-wide operand, ``[q | 0]`` and ``[0 | q]``, and the weighted sum
-  is read back from each token's lanes; the query's own row comes from
-  the layer's hands);
+  through the slot's table, AS STORED, never un-paired: the query is
+  laid into each token's lanes of a row-wide operand, ``[q | 0]`` and
+  ``[0 | q]``, and the weighted sum is read back from each token's
+  lanes; the query's own row comes from the layer's hands. On a TPU
+  that is a Pallas kernel over the pool in place, filled pages only
+  (``ops/latent_decode_attention.py``); elsewhere, and as the
+  definition of what the kernel computes, a gathered ``[slots,
+  max_len]`` view and ``jax.numpy`` (:func:`decode_attention_kind` says
+  which);
 - the prefill chunk (``[1, chunk]`` queries whose own keys are causal
   and whose prefix lives in the pool) walks the slot's prefix in blocks
   of :data:`CHUNK_PREFIX_ROWS` rows (un-paired: 2.4 MB a block) under a
@@ -47,7 +51,9 @@ import jax.numpy as jnp
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import latent_lm
 from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool import engine as paged
 from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool.index_pool import tokens_per_row
 
 # Prefix rows a prefill chunk scores at a time: [heads, chunk, this]
 # float32 logits are the largest temporary of the chunk's attention
@@ -79,6 +85,41 @@ def _scores(spec: str, queries, rows):
 CHUNK_ATTENTION = "absorbed"
 
 
+def decode_attention_kind(config, pool_dtype, block_size: int,
+                          max_blocks: int, slots: int) -> str:
+    """What the decode step reads its cached rows with
+    (:func:`decode_attend`): ``"pool_kernel"``
+    (``ops.latent_decode_attention.pool_latent_decode_attention``: the
+    packed pool read in place, filled pages only, scores, softmax and
+    the weighted sum of latents in VMEM) where that kernel lowers — a
+    TPU, a bf16 pool whose page is whole (16, 128) tiles and one DMA, a
+    row of ``pack * cache_width`` whole 128-lane blocks, query rows of
+    whole tiles, buffers inside the VMEM it asks for, the ``slots``
+    tables inside the scalar memory — and ``"gathered_view"``, the
+    definition, over every slot's whole table gathered, everywhere else.
+    Decided by what the code can see, like ``engine.pool_attention_kind``
+    and for its reasons: no option, nothing falls back after it, so what
+    it admits has to compile (``tests/test_tpu_compile.py`` holds it to
+    the cell's shape). ``kv_stats()["latent_decode_attention"]`` and the
+    engine's construction log line say which. The prefill chunk is not
+    its business (:func:`chunk_attend` as it is)."""
+    if not paged._on_tpu():
+        return "gathered_view"
+    # Pallas costs ~1.2 s to import: only a process that may run the
+    # kernel pays it (the repo's idiom for ops/ kernels).
+    from dlrover_tpu.ops.latent_decode_attention import (
+        latent_kernel_supported,
+    )
+
+    pack = tokens_per_row(config.cache_width, block_size)
+    if latent_kernel_supported(
+        pool_dtype, block_size // pack, pack * config.cache_width,
+        config.n_heads, pack, slots, max_blocks,
+    ):
+        return "pool_kernel"
+    return "gathered_view"
+
+
 def _placed(q, pack: int):
     """``q [..., w]`` laid into each token's lanes of a row ``pack``
     tokens wide: ``[pack, ..., pack * w]`` (``[q | 0]``, ``[0 | q]``);
@@ -92,22 +133,57 @@ def _placed(q, pack: int):
 
 
 def decode_attend(config, pool, layer, tables, lengths, block_size: int,
-                  taps=None):
+                  taps=None, kind=None):
     """The decode step's ``attend`` for one layer: every slot's query
     (at position ``lengths``) over its pool rows below ``lengths`` and
     its own new row. ``taps``: a dict filled with what the step keeps to
     itself (a check's probe reads it; the served program passes none):
     the absorbed ``queries [slots, heads, cache_width]`` and their
     ``scores [slots, heads, max_len]`` against the slots' rows, float32,
-    before the scale and the mask."""
+    before the scale and the mask (the kernel's are its own, a second
+    output, zero where a row is not visible).
+
+    ``kind`` (:func:`decode_attention_kind`; None: asked here, of what
+    this call can see) says what reads the rows: ``"pool_kernel"``, the
+    Pallas kernel over the pool in place, or ``"gathered_view"``, the
+    definition, over every slot's whole table gathered."""
     slots, max_blocks = tables.shape
     max_len = max_blocks * block_size
     r, width = config.kv_lora_rank, config.cache_width
     pack = pool.pack
+    kind = kind or decode_attention_kind(
+        config, pool.dtype, block_size, max_blocks, slots
+    )
+
+    def in_place(p, q, own):
+        from dlrover_tpu.ops.latent_decode_attention import (
+            pool_latent_decode_attention,
+        )
+
+        with jax.named_scope("scores"):
+            # a tile's scores are formed by this module's _scores, as
+            # the gathered form's and the chunk's are
+            out = pool_latent_decode_attention(
+                q, own, pool.rows, layer, tables, lengths, rank=r,
+                scale=config.softmax_scale,
+                scores=lambda qs, rows: _scores("qw,kw->qk", qs, rows),
+                raw_scores=taps is not None,
+            )
+            if taps is None:
+                mixed = out
+            else:
+                mixed, scores = out
+                taps.update(queries=q, scores=scores)
+        with jax.named_scope("values"):
+            return latent_lm.values_out(
+                config, p, mixed.astype(pool.dtype)
+            )[:, None]
 
     def attend(p, q_nope, q_rope, row):
         with jax.named_scope("absorb"):
             q = latent_lm.absorb_queries(config, p, q_nope[:, 0], q_rope[:, 0])
+        if kind == "pool_kernel":
+            return in_place(p, q, row[:, 0])
         view = pool.blocks_at(layer, tables).reshape(
             slots, max_len // pack, pack * width
         )
@@ -233,10 +309,11 @@ def chunk_attend(config, pool, layer, table_row, start, block_size: int):
 
 
 def decode_forward(config, pool, params, tables, lengths, tokens,
-                   block_size: int):
+                   block_size: int, kind=None):
     """All layers for one token a slot: float32 ``logits [slots,
     vocab]``, the new rows ``[L, slots, cache_width]`` and per expert
-    layer the experts hit and the expert rows dropped."""
+    layer the experts hit and the expert rows dropped. ``kind``: see
+    :func:`decode_attend`."""
     positions = lengths[:, None]
     streams = latent_lm.embed_streams(config, params, tokens[:, None])
 
@@ -245,7 +322,9 @@ def decode_forward(config, pool, params, tables, lengths, tokens,
         # programs: the gather picks its rows from all layers'.
         streams, row, c = latent_lm.block(
             config, params, p, layer, streams, positions,
-            decode_attend(config, pool, layer, tables, lengths, block_size),
+            decode_attend(
+                config, pool, layer, tables, lengths, block_size, kind=kind
+            ),
         )
         counts = None if c is None else jnp.stack(
             [c.experts_hit, c.rows_dropped]
@@ -281,7 +360,7 @@ def chunk_forward(config, pool, params, tokens, table_row, start,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts):
+                 counts, kind=None):
     max_len = max_blocks * block_size
 
     def step(pool, params, tables, lengths, tokens, active, temps, rng,
@@ -289,7 +368,8 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         counts["decode"] += 1  # traces only
         tokens = _place_first(tokens, first, first_slot)
         logits, rows, moe = decode_forward(
-            config, pool, params, tables, lengths, tokens, block_size
+            config, pool, params, tables, lengths, tokens, block_size,
+            kind=kind,
         )
         write = jnp.minimum(lengths, max_len - 1)
         blk = jnp.take_along_axis(

@@ -489,3 +489,70 @@ def test_dense_programs_are_what_they_were(params):
     assert steps.prefill.lower(*pre_args).as_text() == \
         direct_prefill.lower(*pre_args).as_text()
     assert len(steps.decode.lower(*dec_args).out_info) == 3
+
+
+def test_sparse_programs_are_what_the_sparse_builders_give(params):
+    """The sibling of the dense case above, for this model: the decode
+    and prefill programs ``_paged_steps_for`` hands a sparse engine lower
+    to the text ``kvpool/sparse.py``'s builders give when called
+    directly, whatever else that function has learnt to build since (a
+    latent model's decode kind is a further part of its key, "" here)."""
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    slots, max_blocks = 3, MAX_LEN // BS
+    eng = _engine(params)
+    steps = eng._steps
+    assert steps is paged._paged_steps_for(
+        CFG, slots, eng.num_blocks, max_blocks, BS, CHUNK, "fp",
+        "sparse_gather", "masked_attention", "",
+    )
+    assert steps.latent_decode_attention == ""
+    counts = {"prefill": 0, "decode": 0}
+    i32 = jnp.int32
+    pools = eng._pools()
+    assert len(pools) == 3
+    dec_args = (
+        *pools, eng._params, jnp.zeros((slots, max_blocks), i32),
+        jnp.zeros(slots, i32), jnp.zeros(slots, i32),
+        jnp.zeros(slots, bool), jnp.zeros(slots, jnp.float32),
+        jax.random.key(0), i32(0), i32(0), i32(-1),
+    )
+    pre_args = (
+        *pools, eng._params, jnp.zeros((1, CHUNK), i32),
+        jnp.zeros(max_blocks, i32), i32(0), i32(1), jnp.float32(0),
+        jax.random.key(0), i32(0), jnp.bool_(True),
+    )
+    direct_decode = jax.jit(
+        sparse.build_decode(CFG, slots, max_blocks, BS, counts),
+        donate_argnums=(0, 1, 2),
+    )
+    direct_prefill = jax.jit(
+        sparse.build_prefill(
+            CFG, max_blocks, BS, CHUNK, counts, kind="masked_attention"
+        ),
+        donate_argnums=(0, 1, 2),
+    )
+    assert steps.decode.lower(*dec_args).as_text() == \
+        direct_decode.lower(*dec_args).as_text()
+    assert steps.prefill.lower(*pre_args).as_text() == \
+        direct_prefill.lower(*pre_args).as_text()
+
+
+@pytest.mark.parametrize("model", ["dense", "sparse"])
+def test_no_other_models_engine_speaks_of_a_latent_pool(model, params):
+    """``kv_stats()`` of a dense and of a sparse engine carry no
+    ``latent_*`` key, and their programs' key no latent kind: what a
+    latent model's decode step reads its rows with is nothing to them."""
+    from dlrover_tpu.models import llama
+
+    if model == "dense":
+        cfg = llama.tiny_config(dtype="float32")
+        eng = PagedServingEngine(
+            cfg, llama.init_params(cfg, jax.random.key(0))[0], slots=2,
+            max_len=16, prefill_chunk=8, block_size=4,
+        )
+    else:
+        eng = _engine(params)
+    assert not [k for k in eng.kv_stats() if k.startswith("latent")]
+    assert eng.latent_decode_attention == ""
+    assert eng._latent is None

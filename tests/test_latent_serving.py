@@ -175,10 +175,44 @@ def test_engine_logits_are_the_references_past_the_yarn_range(tiny):
     assert (np.stack(got).argmax(-1) == want.argmax(-1)).all()
 
 
-def test_served_tokens_are_the_full_forwards(tiny):
+@pytest.fixture
+def take_the_pool_kernel(monkeypatch):
+    """``take(tile_rows)``: from here on the decode program is built as
+    on a TPU: the platform probe says so (the kernel then runs in
+    interpret mode) and the predicate admits the tiny model's float32
+    pool and narrow rows; ``tile_rows``: device rows a VMEM tile holds,
+    so that a slot's pages are several tiles. The engines' programs are
+    dropped afterwards: the tile's size is no part of their key."""
+    from dlrover_tpu.ops import latent_decode_attention as lda
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    def take(tile_rows):
+        monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+        monkeypatch.setattr(lda, "latent_kernel_supported", lambda *a: True)
+        monkeypatch.setattr(lda, "TILE_ROWS", tile_rows)
+
+    yield take
+    paged._paged_steps_for.cache_clear()
+
+
+KINDS = ("gathered_view", "pool_kernel")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_served_tokens_are_the_full_forwards(tiny, kind, take_the_pool_kernel):
+    """Once with the decode step's rows gathered (what a CPU builds),
+    once read by the Pallas kernel over the pool in place (what a TPU
+    builds; here interpreted, two pages a tile): the greedy tokens are
+    the full forward's either way, so each other's."""
+    if kind == "pool_kernel":
+        take_the_pool_kernel(2 * BS)
     cfg, params = tiny
     items = list(zip(prompts(cfg, (37, 21, 30, 9)), (6, 5, 7, 4)))
     eng = engine(cfg, params)
+    stats = eng.kv_stats()
+    assert stats["latent_decode_attention"] == kind
+    assert eng.latent_decode_attention == kind
+    assert stats["pool_attention"] == "latent_absorbed"
     eng.warmup()
     traced = dict(eng.trace_counts)
     tokens = serve(eng, items)
@@ -192,9 +226,14 @@ def test_served_tokens_are_the_full_forwards(tiny):
     assert eng.kv_stats()["moe_rows_dropped"] == 0
 
 
-def test_the_absorbed_decode_is_the_unabsorbed_definition(tiny):
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_absorbed_decode_is_the_unabsorbed_definition(
+        tiny, kind, take_the_pool_kernel):
     """One layer, one slot: the decode step's attention over pool rows
-    and the chunk's against attention as written over the same rows."""
+    (gathered, and by the kernel over the pool in place) and the chunk's
+    against attention as written over the same rows."""
+    if kind == "pool_kernel":
+        take_the_pool_kernel(2 * BS)
     cfg, params = tiny
     p = latent_lm.layer_params(params, 1)
     rng = np.random.default_rng(3)
@@ -221,6 +260,144 @@ def test_the_absorbed_decode_is_the_unabsorbed_definition(tiny):
     np.testing.assert_allclose(
         got[0], want[start:start + CHUNK], atol=1e-5, rtol=1e-5
     )
+
+
+# A decode batch's fills, a slot each (blocks of BS = 4 tokens, tables of
+# 8 blocks, a tile of two pages = 8 tokens; slot 1's table is all the
+# sentinel block: an inactive slot). Every batch is ragged, and every
+# slot's table runs backwards through the pool.
+_BATCHES = {
+    # a slot with nothing cached answers with its own row's latent
+    "a_fill_of_0": (0, 0, 19, 0, 7),
+    "a_fill_of_1": (1, 1, 27, 1, 14),
+    # with two tokens to a device row an odd fill ends on the first: the
+    # row's second token is hidden
+    "an_odd_fill": (5, 3, 13, 25, 9),
+    "on_a_page_boundary": (4, 12, 20, 28, 4),
+    "on_a_tile_boundary": (8, 16, 24, 8, 16),
+    "a_table_longer_than_the_fill": (3, 0, 2, 6, 1),
+    "a_full_table": (32, 32, 31, 29, 32),
+}
+
+
+@pytest.fixture(scope="module")
+def latent_batches():
+    """Per tokens-a-row: a config, one layer's weights and one sequence's
+    attention inputs with attention as written over it."""
+    out = {}
+    for pack, kw in ((1, {}), (2, dict(kv_lora_rank=128, qk_rope_dim=64))):
+        cfg = latent_lm.tiny_config(**kw)
+        params = latent_lm.init_params(cfg, jax.random.key(pack))
+        p = latent_lm.layer_params(params, 1)
+        n = 33
+        h = jnp.asarray(
+            np.random.default_rng(pack).normal(size=(1, n, cfg.embed_dim)),
+            jnp.float32,
+        )
+        positions = jnp.arange(n, dtype=jnp.int32)[None]
+        q_nope, q_rope, row = latent_lm.latent_inputs(cfg, p, h, positions)
+        want = latent_lm.definition_attention(
+            cfg, p, q_nope[0], q_rope[0], row[0]
+        )
+        out[pack] = cfg, p, q_nope[0], q_rope[0], row[0], want
+    return out
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+@pytest.mark.parametrize("batch", sorted(_BATCHES))
+def test_the_pool_kernel_is_the_gathered_view_over_a_ragged_batch(
+        latent_batches, batch, pack, take_the_pool_kernel):
+    """Slot ``i`` holds the first ``fills[i]`` rows of one sequence in
+    its own pages and asks with the next token's query: the kernel over
+    the pool in place (a tile of two pages, so a slot's rows are several
+    tiles and the last one part filled) gives what the gathered form
+    gives and what attention as written gives at that token, and its own
+    scores are the gathered form's over the visible rows, zero past
+    them."""
+    cfg, p, q_nope, q_rope, row, want = latent_batches[pack]
+    fills = np.asarray(_BATCHES[batch])
+    slots, max_blocks = len(fills), 8
+    pool = layout.fresh(
+        layout.pool_arrays(cfg)[0], cfg.n_layers, slots * max_blocks + 1, BS
+    )
+    assert pool.pack == pack
+    tables = 1 + np.arange(slots * max_blocks, dtype=np.int32).reshape(
+        slots, max_blocks
+    )[:, ::-1]
+    for i, fill in enumerate(fills):
+        if not fill:
+            continue
+        rows = jnp.zeros((cfg.n_layers, fill, cfg.cache_width))
+        pool = pool.land_run(
+            rows.at[1].set(row[:fill]), jnp.asarray(tables[i]), 0, BS, 0
+        )
+    tables[1] = 0        # an inactive slot: the sentinel block's rows
+    at = jnp.asarray(fills)
+    args = (p, q_nope[at][:, None], q_rope[at][:, None], row[at][:, None])
+    call = lambda **kw: latent.decode_attend(  # noqa: E731
+        cfg, pool, 1, jnp.asarray(tables), at.astype(jnp.int32), BS, **kw
+    )(*args)[:, 0]
+    seen_view, seen_kernel = {}, {}
+    view = call(kind="gathered_view", taps=seen_view)
+    take_the_pool_kernel(2 * BS // pack)
+    got = call()                  # the kind is asked of what it can see
+    np.testing.assert_allclose(got, view, atol=2e-6, rtol=1e-5)
+    np.testing.assert_allclose(
+        call(taps=seen_kernel), got, atol=1e-7, rtol=1e-6
+    )
+    real = np.arange(slots) != 1
+    np.testing.assert_allclose(
+        got[real], want[fills][real], atol=1e-5, rtol=1e-5
+    )
+    visible = np.arange(max_blocks * BS)[None, :] < fills[:, None]
+    scores = np.asarray(seen_kernel["scores"])
+    assert scores.shape == (slots, cfg.n_heads, max_blocks * BS)
+    np.testing.assert_allclose(
+        scores, np.where(visible[:, None], seen_view["scores"], 0.0),
+        atol=1e-5, rtol=1e-5,
+    )
+    assert (scores[~np.broadcast_to(visible[:, None], scores.shape)] == 0).all()
+    np.testing.assert_array_equal(
+        seen_kernel["queries"], seen_view["queries"]
+    )
+
+
+def test_the_kernels_scores_are_the_modules_own(
+        latent_batches, take_the_pool_kernel, monkeypatch):
+    """A tile's scores are formed by ``latent._scores``, the statement of
+    their precision that the gathered form and the chunk share: scores
+    rounded THERE (what the harness's bfloat16-scores control plants) are
+    the scores the kernel hands out and attends with."""
+    cfg, p, q_nope, q_rope, row, _ = latent_batches[2]
+    fill, max_blocks = 21, 8
+    pool = layout.fresh(
+        layout.pool_arrays(cfg)[0], cfg.n_layers, max_blocks + 1, BS
+    )
+    table = jnp.arange(1, max_blocks + 1, dtype=jnp.int32)
+    rows = jnp.zeros((cfg.n_layers, fill, cfg.cache_width))
+    pool = pool.land_run(rows.at[1].set(row[:fill]), table, 0, BS, 0)
+    take_the_pool_kernel(BS)
+
+    def run():
+        seen = {}
+        out = latent.decode_attend(
+            cfg, pool, 1, table[None], jnp.asarray([fill], jnp.int32), BS,
+            taps=seen,
+        )(p, q_nope[fill][None, None], q_rope[fill][None, None],
+          row[fill][None, None])
+        return np.asarray(out), np.asarray(seen["scores"])[..., :fill]
+
+    out, exact = run()
+    real = latent._scores
+    monkeypatch.setattr(latent, "_scores", lambda *a: jax.lax.reduce_precision(
+        real(*a), exponent_bits=8, mantissa_bits=7
+    ))
+    out_low, low = run()
+    np.testing.assert_array_equal(
+        low, np.asarray(jnp.asarray(exact).astype(jnp.bfloat16), np.float32)
+    )
+    assert 1e-4 < np.abs(low - exact).max() / np.abs(exact).max() < 1e-2
+    assert np.abs(out_low - out).max() > 0
 
 
 def test_a_prefix_hit_request_is_the_request_served_cold(tiny):
@@ -428,3 +605,35 @@ def test_the_mixer_of_the_trained_model_takes_the_rotation_as_an_option():
         hybrid._mla_apply(cfg, p, h, rotate=rotate_from(40)), turned,
         atol=2e-5,
     )
+
+
+def test_the_tools_latent_part_rehearses_off_a_tpu():
+    """``tools/bench_paged_decode.py --parts latent --tiny``: the
+    gathered form, then the kernel at each ``--tile-rows`` against it
+    (interpret mode), a line each and no time; the program's own tile
+    size is back where it was afterwards (the tool sets it, no option of
+    the program does)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    tool = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "bench_paged_decode.py",
+    )
+    out = subprocess.run(
+        [sys.executable, tool, "--tiny", "--parts", "latent",
+         "--tile-rows", "2,4"],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, text=True,
+        capture_output=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    rows = [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
+    assert [(r["part"], r["form"]) for r in rows] == [
+        ("latent", "gathered_view"), ("latent", "pool_kernel"),
+        ("latent", "pool_kernel"),
+    ]
+    assert [r["tile_rows"] for r in rows[1:]] == [2, 4]
+    assert all(r["rel_err_of_gathered"] < 1e-5 for r in rows[1:])
+    assert not [k for r in rows for k in r if k in ("ms", "rows_gb_s")]

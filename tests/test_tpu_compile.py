@@ -538,21 +538,40 @@ def test_latent_serving_programs_compile_at_the_cells_shape(topo):
     row: the bare array is re-tiled whole, 4 GB twice a step), the expert
     matmuls are the grouped kernel, every scope the cell's readers book
     device time to is there, and no ``[heads, chunk, max_len]`` logits
-    exist in the chunk program."""
+    exist in the chunk program. The decode step reads its rows by the
+    Pallas kernel over the pool in place (PR 40), booked to ``attn/mla``
+    where ``latent_attn_ms_per_step`` reads it: no gathered ``[slots,
+    max_len]`` view and no float32 scores of it are left in the step;
+    the chunk is what it was. The check's probe of the decode step lowers
+    with the kernel's scores as its second output."""
     from benchmark import common, latent_scopes, trace_reduce
     from benchmark import rehearse_xing
 
     cfg_json = common.load_json("configs", "xing4-29b-a4b.json")
     programs = rehearse_xing.lower_engine_programs(
-        cfg_json, topo.devices[0], probes=False, n_layers=2
+        cfg_json, topo.devices[0], probes=True, n_layers=2
     )
     pool = "bf16[2,9216,32,1152]"
+    kernel = "paged_latent_decode_attention"
     for name in ("jit_step", "jit_prefill"):
         c = programs[name].compile()
         text = c.as_text()
         assert name + "," in text.splitlines()[0]
         assert "{0}: (0, {}, may-alias)" in text
-        assert 2 <= _n_kernels(c) <= 4           # gmm: gate|up, down
+        scopes = trace_reduce.scopes_from_hlo(text)
+        if name == "jit_step":
+            # gmm: gate|up, down; the latent kernel in the dense layer
+            # and in the scan's body
+            assert 4 <= _n_kernels(c) <= 6
+            calls = [v for k, v in scopes.items() if k.startswith(kernel)]
+            assert len(calls) == 2 and all(
+                latent_scopes.scope_of(op_name) == "mla" for op_name in calls
+            ), calls
+            assert "bf16[32,8704,1152]" not in text    # the gathered view
+            assert "f32[32,32,17408]" not in text      # its scores
+        else:
+            assert 2 <= _n_kernels(c) <= 4           # gmm: gate|up, down
+            assert kernel not in text
         made = [
             line for line in text.splitlines()
             if f"= {pool}" in line and " parameter(" not in line
@@ -562,21 +581,110 @@ def test_latent_serving_programs_compile_at_the_cells_shape(topo):
         assert made and not any(
             " copy(" in line or " transpose(" in line for line in made
         ), made
-        booked = {
-            latent_scopes.scope_of(op_name)
-            for op_name in trace_reduce.scopes_from_hlo(text).values()
-        }
+        booked = {latent_scopes.scope_of(v) for v in scopes.values()}
         assert booked >= {"mla", "mhc", "router", "experts", "shared",
                           "dense"}
         m = c.memory_analysis()
         # 2 layers of weights (5.4 GB) + the 2-layer pool (1.4 GB) +
         # temporaries: the other 4 expert layers add 4 x (1.49 + 0.68)
-        # GB of arguments and no temporaries.
-        assert m.temp_size_in_bytes < 1.5e9
+        # GB of arguments and no temporaries. The step's were 0.70 GB
+        # of view and scores before the kernel.
+        assert m.temp_size_in_bytes < (
+            0.1e9 if name == "jit_step" else 1.5e9
+        )
         assert m.argument_size_in_bytes + m.temp_size_in_bytes < 8.5e9
         if name == "jit_prefill":
             assert "f32[32,512,17408]" not in text
             assert "f32[32,512,2048]" in text    # a block of prefix rows
+    text = programs["probe_decode0"].compile().as_text()
+    assert kernel in text
+    # the kernel's own raw scores: 64 query rows against a slot's 8,704
+    # device rows, to whole tiles
+    from dlrover_tpu.ops import latent_decode_attention as lda
+
+    rows = -(-8704 // lda.TILE_ROWS) * lda.TILE_ROWS
+    assert f"f32[32,64,{rows}]" in text
+
+
+# What ``latent.decode_attention_kind`` sees -> what it must answer;
+# unnamed: a bf16 pool of 64-token pages, 576-wide rows (two to a
+# 1,152-lane device row) under 32 heads, 32 slots x 272 pages (the
+# cell's shape), on a TPU.
+_LATENT_KIND_CASES = {
+    "the_cells_shape": ({}, "pool_kernel"),
+    # one token a 640-lane row, 128-row pages: four pages a tile
+    "a_width_of_whole_lane_rows": (
+        dict(kv_lora_rank=512, rope=128, block_size=128, max_blocks=64),
+        "pool_kernel",
+    ),
+    "off_the_chip": (dict(on_tpu=False), "gathered_view"),
+    "a_float32_pool": (dict(dtype="float32"), "gathered_view"),
+    # 520 lanes a token: neither one nor two are whole 128-lane blocks
+    "a_width_that_does_not_pack": (
+        dict(kv_lora_rank=512, rope=8), "gathered_view"
+    ),
+    # 16 tokens are 8 device rows: half a (16, 128) tile a page
+    "a_block_too_small_to_tile": (dict(block_size=16), "gathered_view"),
+    # 128 slots x 4,096 pages: 2 MB of tables for 1 MB of scalar memory
+    "tables_past_the_scalar_memory": (
+        dict(slots=128, max_blocks=4096), "gathered_view"
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LATENT_KIND_CASES))
+def test_latent_decode_attention_kind_admits_only_what_compiles(
+    case, one_chip, monkeypatch,
+):
+    """What reads the latent decode step's rows is chosen by what the
+    code can see, and nothing falls back after the choice: where the
+    answer is ``pool_kernel`` the kernel compiles for the described v5e
+    at that shape (the pool read in place: no copy of it), and another
+    platform, a float32 pool or a shape outside the kernel's tiling or
+    scalar memory answers ``gathered_view``."""
+    from dlrover_tpu.models import latent_lm
+    from dlrover_tpu.ops import latent_decode_attention as lda
+    from dlrover_tpu.serving.kvpool import engine as paged, latent, layout
+
+    seen, want = _LATENT_KIND_CASES[case]
+    on_tpu = seen.get("on_tpu", True)
+    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 272)
+    slots = seen.get("slots", 32)
+    cfg = latent_lm.tiny_config(
+        n_heads=32, kv_lora_rank=seen.get("kv_lora_rank", 512),
+        qk_rope_dim=seen.get("rope", 64), dtype=seen.get("dtype", "bfloat16"),
+    )
+    assert latent.decode_attention_kind(
+        cfg, cfg.compute_dtype, bs, mb, slots
+    ) == want
+    if want != "pool_kernel":
+        return
+    n_layers, nb = 2, 2 * mb + 1
+    (a,) = layout.pool_arrays(cfg)
+    rows = jax.eval_shape(lambda: layout.fresh(a, n_layers, nb, bs)).rows
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip
+    )
+    bf, i32 = jnp.bfloat16, jnp.int32
+    c = jax.jit(
+        lambda q, own, pool, *a: (lda.pool_latent_decode_attention(
+            q, own, pool, *a, rank=cfg.kv_lora_rank, scale=0.1
+        ), pool),
+        donate_argnums=(2,),
+    ).lower(
+        arr((slots, 32, cfg.cache_width), bf), arr((slots, cfg.cache_width), bf),
+        arr(rows.shape, bf), arr((), i32), arr((slots, mb), i32),
+        arr((slots,), i32),
+    ).compile()
+    text = c.as_text()
+    assert _n_kernels(c) == 1
+    assert "paged_latent_decode_attention" in text
+    # the pool goes in and out untouched
+    assert f"bf16[{n_layers},{nb}," not in "".join(
+        line for line in text.splitlines() if " copy(" in line
+    )
+    assert c.memory_analysis().temp_size_in_bytes < 16e6
 
 
 @pytest.mark.parametrize("pool", ["the_engines_pool", "a_bare_array"])

@@ -29,7 +29,14 @@ f32 and rounded to bf16 once, beside the gather's error — all layers'
 chunk attention alone over ``--query-rows`` tile sizes, and the whole
 prefill program both ways with the head on and off. Exits 1 if a kernel
 is not finite or, fed f32 queries, more than 1e-5 off the reference at
-the highest matmul precision. A smoke reading, not a
+the highest matmul precision. ``latent`` (asked for by name, whatever
+the preset): one layer of a latent model's decode attention at the
+``xing-serve-sessions-16k`` shape (32 slots, fills ~16.6k of 17,408
+rows, the 9,216-block pool of two 576-wide rows to a device row), the
+gathered ``[slots, max_len]`` view against
+``pool_latent_decode_attention`` over the pool in place at
+``--tile-rows`` device rows a VMEM tile: ms a call and the visible
+rows' GB/s. A smoke reading, not a
 benchmark: one process, host-clock timing around ``block_until_ready``.
 Times mean something on a TPU only: anywhere else the tool refuses to
 run, unless ``--tiny`` rehearses it (interpret mode, nothing timed).
@@ -142,6 +149,109 @@ def _reference(q, k_new, v_new, k_pool, v_pool, layer, tables, fills):
         v_pool[layer][tables].reshape(shape),
         k_new[:, None], v_new[:, None], jnp.asarray(fills),
     )[:, 0]
+
+
+# The ``latent`` part's shape: ``xing-serve-sessions-16k``'s engine (32
+# slots x 272 pages of 64 tokens over the 9,216-block pool, two 576-wide
+# rows to a 1,152-lane device row, 32 heads) and its fills.
+LATENT = dict(
+    slots=32, max_blocks=272, num_blocks=9216, block=64, layers=6,
+    fills=(16400, 16900),
+    model=dict(n_heads=32, kv_lora_rank=512, qk_rope_dim=64,
+               qk_nope_dim=128, v_head_dim=128, dtype="bfloat16"),
+)
+TINY_LATENT = dict(
+    slots=3, max_blocks=6, num_blocks=24, block=4, layers=2,
+    fills=(9, 23), model=dict(kv_lora_rank=128, qk_rope_dim=64),
+)
+
+
+def _latent(tile_rows, repeats, seed, tiny):
+    """One layer of a latent model's decode attention
+    (``kvpool/latent.decode_attend``: absorb, the rows, ``w_kvb``'s
+    value half) at the ``xing-serve-sessions-16k`` shape, the gathered
+    ``[slots, max_len]`` view against the Pallas kernel over the packed
+    pool in place at each of ``tile_rows`` device rows a VMEM tile: ms a
+    call and the visible rows' GB/s, one JSON line each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.models import latent_lm
+    from dlrover_tpu.ops import latent_decode_attention as lda
+    from dlrover_tpu.serving.kvpool import latent
+    from dlrover_tpu.serving.kvpool.index_pool import (
+        IndexKeyPool,
+        tokens_per_row,
+    )
+
+    shape = TINY_LATENT if tiny else LATENT
+    cfg = latent_lm.tiny_config(**shape["model"])
+    slots, mb, bs = shape["slots"], shape["max_blocks"], shape["block"]
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.key(seed), 5)
+    dt = cfg.compute_dtype
+    pack = tokens_per_row(cfg.cache_width, bs)
+    pool = IndexKeyPool(_normal(keys[0], (
+        shape["layers"], shape["num_blocks"], bs // pack,
+        pack * cfg.cache_width,
+    )).astype(dt), cfg.cache_width)
+    tables = jnp.asarray(
+        1 + rng.permutation(shape["num_blocks"] - 1)[:slots * mb]
+        .reshape(slots, mb).astype(np.int32)
+    )
+    fills = jnp.asarray(rng.integers(*shape["fills"], slots), jnp.int32)
+    r = cfg.kv_lora_rank
+    p = {"w_kvb": jax.random.normal(
+        keys[1], (r, cfg.n_heads, cfg.qk_nope_dim + cfg.v_head_dim), dt
+    ) * r ** -0.5}
+    q_nope = jax.random.normal(
+        keys[2], (slots, 1, cfg.n_heads, cfg.qk_nope_dim), dt
+    )
+    q_rope = jax.random.normal(
+        keys[3], (slots, 1, cfg.n_heads, cfg.qk_rope_dim), dt
+    )
+    row = jax.random.normal(keys[4], (slots, 1, cfg.cache_width), dt)
+    layer = jnp.int32(shape["layers"] - 1)
+    row_bytes = int(fills.sum()) * cfg.cache_width * pool.rows.dtype.itemsize
+
+    def timed(f, *args):
+        ms = _timed(lambda: jax.block_until_ready(f(*args)), repeats)
+        return {} if ms is None else {
+            "ms": ms, "rows_gb_s": round(row_bytes / ms / 1e6, 1)
+        }
+
+    def attend(kind):
+        return jax.jit(lambda pool, layer, tables, fills: latent.decode_attend(
+            cfg, pool, layer, tables, fills, bs, kind=kind
+        )(p, q_nope, q_rope, row))
+
+    args = (pool, layer, tables, fills)
+    view = attend("gathered_view")
+    want = view(*args).astype(jnp.float32)
+    print(json.dumps({
+        "part": "latent", "form": "gathered_view", "slots": slots,
+        "rows_mean": float(fills.mean()), **timed(view, *args),
+    }), flush=True)
+    ok = True
+    shipped = lda.TILE_ROWS
+    try:
+        for rows in tile_rows or [shipped]:
+            lda.TILE_ROWS = rows
+            kernel = attend("pool_kernel")
+            got = kernel(*args).astype(jnp.float32)
+            err = float(
+                jnp.linalg.norm(got - want) / jnp.linalg.norm(want)
+            )
+            ok = ok and bool(jnp.isfinite(got).all()) and err < 0.02
+            print(json.dumps({
+                "part": "latent", "form": "pool_kernel", "tile_rows": rows,
+                "shipped": rows == shipped, "rel_err_of_gathered": err,
+                **timed(kernel, *args),
+            }), flush=True)
+    finally:
+        lda.TILE_ROWS = shipped
+    return ok
 
 
 def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
@@ -509,6 +619,10 @@ def main():
     ap.add_argument("--chunk-kb", default="256,512,1024,2048",
                     help="VMEM chunk sizes to time the kernel at (the "
                     "one shipped: ops.decode_attention._POOL_CHUNK_BYTES)")
+    ap.add_argument("--tile-rows",
+                    help="latent: device rows a VMEM tile of the latent "
+                    "kernel, to time it at (default, the one shipped: "
+                    "ops.latent_decode_attention.TILE_ROWS)")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--tiny", action="store_true",
@@ -530,8 +644,17 @@ def main():
             "run in interpret mode and its times would mean nothing; "
             "--tiny rehearses the script without timing"
         )
+    parts = ns.parts.split(",")
+    if "latent" in parts:
+        parts.remove("latent")
+        ok = _latent(
+            [int(x) for x in ns.tile_rows.split(",")] if ns.tile_rows
+            else None, 0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
+        )
+        if not ok or not parts:
+            raise SystemExit(0 if ok else 1)
     ok = run(
-        argparse.Namespace(**shape), ns.parts.split(","),
+        argparse.Namespace(**shape), parts,
         [int(kb) for kb in ns.chunk_kb.split(",")],
         0 if ns.tiny else ns.repeats, ns.seed,
         [int(x) for x in ns.starts.split(",")],
