@@ -11,6 +11,11 @@ logical axes, and the function itself. What ships:
 - mixer ``mla``: latent attention without rotary (NoPE): q of
   ``qk_nope + qk_rope`` per head, a shared low-rank K/V latent, values of
   their own head size, through the flash kernel;
+- mixer ``mla_rope``: the same layer with its positional slices ROTATED
+  (``ops/rope.apply_rope`` at ``rope_theta``, on the queries' slice of
+  every head and on the one key slice the heads share) and, where
+  ``q_lora_rank`` is set, queries through a bottleneck (``w_qa`` ->
+  RMSNorm -> ``w_qb``, :func:`mla_bottleneck_queries`);
 - ffn ``dense``: SwiGLU; ffn ``moe``: a shared expert plus this chip's
   share of a sigmoid-routed expert layer (``moe.moe_mlp_share``).
 
@@ -26,6 +31,17 @@ leading repeat axis, ...], "final_norm", "lm_head"}``, a layer being
 state but not trained (the routers' score-correction bias) lives in a
 twin tree of BUFFERS (``init_buffers``), which the train step carries
 beside the parameters and gives no gradient and no optimizer state.
+
+With ``mtp_depth`` 1 a multi-token-prediction MODULE sits beside the
+stack (DeepSeek-V3, arXiv:2412.19437 section 2.2): ``params["mtp"] =
+{"norm_h", "norm_e", "w_eh", "block": layer, "norm"}`` and
+``buffers["mtp"] = {"block": ...}``. It joins the stack's output at
+position ``i`` (before the final norm) with the embedding of token
+``i + 1``, runs one more block of the period's last kind and predicts
+token ``i + 2`` through the model's OWN embedding and head, which so
+collect gradient from both losses (:func:`mtp_loss`); a sequence is then
+``seq_len + 2`` tokens. Depth 0 has neither key and traces to the same
+program as before the module existed.
 """
 
 import dataclasses
@@ -41,12 +57,15 @@ from dlrover_tpu.common.log import logger
 from dlrover_tpu.models import llama
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.ops import kda as kda_ops
+from dlrover_tpu.ops import rope
 from dlrover_tpu.ops.attention import dot_product_attention
 from dlrover_tpu.ops.norms import rms_norm
 from dlrover_tpu.parallel.sharding import with_logical_constraint
 
 Pattern = Tuple[Tuple[str, str], ...]
 COUNTERS = ("moe_rows_held", "moe_rows_max", "moe_rows_dropped")
+MTP_COUNTER = "mtp_moe_rows_held"    # the module's block, apart
+REMAT_KEEP = ("dots", "attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,12 +86,14 @@ class HybridLMConfig:
     kda_head_dim: int = 16               # keys and values
     kda_conv: int = 4
     kda_gate_rank: int = 16
-    # mla
+    # mla, mla_rope
     n_heads: int = 4
     kv_lora_rank: int = 32
     qk_nope_dim: int = 16
     qk_rope_dim: int = 8
     v_head_dim: int = 16
+    q_lora_rank: int = 0                 # mla_rope: 0 is one plain wq
+    rope_theta: float = 1e4
     # ffn
     mlp_dim: int = 128                   # the dense SwiGLU
     moe_mlp_dim: int = 32                # each expert, and the shared one
@@ -81,9 +102,22 @@ class HybridLMConfig:
     experts_held: Tuple[int, int] = (0, 16)   # (first, count) living here
     n_shared_experts: int = 1
     routed_scaling: float = 1.0
+    # A multi-token-prediction module beside the stack (0: none, 1: one
+    # block predicting the token after next) and its loss's weight.
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
+    # What a block keeps for its backward: "dots" (its projection
+    # matmuls' outputs and a KDA scan's) or "attention" (the flash
+    # kernels' outputs alone: the projections run again).
+    remat_keep: str = "dots"
     dtype: str = "bfloat16"              # compute dtype (params stay f32)
 
     def __post_init__(self):
+        if self.mtp_depth not in (0, 1) or self.remat_keep not in REMAT_KEEP:
+            raise ValueError(
+                f"mtp_depth {self.mtp_depth} (0 or 1), remat_keep "
+                f"{self.remat_keep!r} (one of {REMAT_KEEP})"
+            )
         for mixer, ffn in self.leading + self.period:
             if mixer not in MIXERS or ffn not in FFNS:
                 raise ValueError(f"no layer kind ({mixer!r}, {ffn!r})")
@@ -100,6 +134,17 @@ class HybridLMConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def mtp_kinds(self) -> Tuple[str, str]:
+        """The kind of the prediction module's block: the period's last."""
+        return self.period[-1]
+
+    @property
+    def positional(self) -> bool:
+        """Whether any mixer of the model reads positions."""
+        kinds = self.leading + self.period
+        return any(MIXERS[mixer].positional for mixer, _ in kinds)
+
 
 def tiny_config(**overrides) -> HybridLMConfig:
     """One dense-FFN KDA layer and one period, CPU-test sized."""
@@ -109,9 +154,11 @@ def tiny_config(**overrides) -> HybridLMConfig:
 class Kind(NamedTuple):
     init: Callable      # (config, key) -> params
     axes: Callable      # (config) -> logical axes, same tree
-    apply: Callable     # mixer: (config, p, h) -> y
+    apply: Callable     # mixer: (config, p, h) -> y, or with
+    #                     ``positional``: (config, p, h, positions) -> y
     #                     ffn: (config, p, buffers, h) -> (y, counters)
     buffers: Callable = lambda config, key: {}
+    positional: bool = False
 
 
 def _dense(key, shape, fan_in):
@@ -220,15 +267,24 @@ def _kda_apply(config, p, h):
         return _proj("bhsk,hkd->bsd", o, p["wo"])
 
 
-# -- mixer: MLA without rotary ------------------------------------------------
+# -- mixers: MLA, without rotary and with --------------------------------------
 
 
-def _mla_init(config, key):
+def _mla_init(config, key, q_rank=0):
     d, h, r = config.embed_dim, config.n_heads, config.kv_lora_rank
     dq = config.qk_nope_dim + config.qk_rope_dim
     ks = jax.random.split(key, 4)
+    if q_rank:
+        k_a, k_b = jax.random.split(ks[0])
+        queries = {
+            "w_qa": _dense(k_a, (d, q_rank), d),
+            "q_norm": jnp.zeros((q_rank,), jnp.float32),
+            "w_qb": _dense(k_b, (q_rank, h, dq), q_rank),
+        }
+    else:
+        queries = {"wq": _dense(ks[0], (d, h, dq), d)}
     return {
-        "wq": _dense(ks[0], (d, h, dq), d),
+        **queries,
         "w_kva": _dense(ks[1], (d, r + config.qk_rope_dim), d),
         "kv_norm": jnp.zeros((r,), jnp.float32),
         "w_kvb": _dense(
@@ -238,14 +294,31 @@ def _mla_init(config, key):
     }
 
 
-def _mla_axes(config):
+def _mla_axes(config, q_rank=0):
+    if q_rank:
+        queries = {
+            "w_qa": ("embed", None), "q_norm": ("norm",),
+            "w_qb": (None, "heads", "head_dim"),
+        }
+    else:
+        queries = {"wq": ("embed", "heads", "head_dim")}
     return {
-        "wq": ("embed", "heads", "head_dim"),
+        **queries,
         "w_kva": ("embed", None),
         "kv_norm": ("norm",),
         "w_kvb": (None, "heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
     }
+
+
+def mla_bottleneck_queries(p, h):
+    """A latent layer's queries through their low-rank bottleneck:
+    ``h [b, s, d]`` -> ``w_qa`` -> RMSNorm -> ``w_qb`` -> ``[b, s, heads,
+    nope + rope]``, not yet rotated. The one such function of the
+    package: the ``mla_rope`` mixer's and ``models/latent_lm.py``'s
+    (``latent_inputs``)."""
+    c_q = rms_norm(_proj("bsd,dr->bsr", h, p["w_qa"]), p["q_norm"])
+    return _proj("bsr,rhk->bshk", c_q, p["w_qb"])
 
 
 def mla_keys_values(config, kva, latent, w_kvb, rotate=None):
@@ -275,7 +348,10 @@ def _mla_apply(config, p, h, rotate=None):
     their positions."""
     with jax.named_scope("mla"):
         r, nope = config.kv_lora_rank, config.qk_nope_dim
-        q = _proj("bsd,dhk->bshk", h, p["wq"])
+        if "w_qa" in p:
+            q = mla_bottleneck_queries(p, h)
+        else:
+            q = _proj("bsd,dhk->bshk", h, p["wq"])
         if rotate is not None:
             q = jnp.concatenate(
                 [q[..., :nope], rotate(q[..., nope:])], axis=-1
@@ -283,12 +359,21 @@ def _mla_apply(config, p, h, rotate=None):
         kva = _proj("bsd,dr->bsr", h, p["w_kva"])
         latent = rms_norm(kva[..., :r], p["kv_norm"])
         # The positional slice of a key is one vector shared by the
-        # heads and, in this model, NOT rotated (mla_use_nope).
+        # heads and, without ``rotate``, NOT rotated (mla_use_nope).
         k, v = mla_keys_values(config, kva, latent, p["w_kvb"], rotate)
         q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
         attend = llama.default_attention_fn() or dot_product_attention
         out = attend(q, k, v, causal=True)     # scaled by 1/sqrt(nope + rope)
         return _proj("bshk,hkd->bsd", out, p["wo"])
+
+
+def _mla_rope_apply(config, p, h, positions):
+    """The latent layer with its positional slices turned by plain RoPE
+    (``rope_theta``, all ``qk_rope_dim`` channels, the half-split
+    pairing of ``ops/rope.apply_rope``) at ``positions [b, s]``."""
+    return _mla_apply(config, p, h, functools.partial(
+        rope.apply_rope, positions=positions, theta=config.rope_theta
+    ))
 
 
 # -- ffns ---------------------------------------------------------------------
@@ -371,6 +456,11 @@ def _moe_apply(config, p, buffers, h):
 MIXERS: Dict[str, Kind] = {
     "kda": Kind(_kda_init, _kda_axes, _kda_apply),
     "mla": Kind(_mla_init, _mla_axes, _mla_apply),
+    "mla_rope": Kind(
+        lambda c, k: _mla_init(c, k, c.q_lora_rank),
+        lambda c: _mla_axes(c, c.q_lora_rank),
+        _mla_rope_apply, positional=True,
+    ),
 }
 FFNS: Dict[str, Kind] = {
     "dense": Kind(
@@ -430,7 +520,7 @@ def _per_layer(config, key, make):
 
 
 def param_axes(config: HybridLMConfig) -> Dict[str, Any]:
-    return {
+    axes = {
         "embed": ("vocab", "embed"),
         "leading": [_layer_axes(config, kinds) for kinds in config.leading],
         "period": [
@@ -439,6 +529,14 @@ def param_axes(config: HybridLMConfig) -> Dict[str, Any]:
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
     }
+    if config.mtp_depth:
+        axes["mtp"] = {
+            "norm_h": ("norm",), "norm_e": ("norm",),
+            "w_eh": (None, "embed"),
+            "block": _layer_axes(config, config.mtp_kinds),
+            "norm": ("norm",),
+        }
+    return axes
 
 
 def init_params(config: HybridLMConfig, rng: jax.Array):
@@ -452,17 +550,30 @@ def init_params(config: HybridLMConfig, rng: jax.Array):
         "final_norm": jnp.zeros((d,), jnp.float32),
         "lm_head": _dense(k_head, (d, v), d),
     }
+    if config.mtp_depth:
+        # A stream of its own: the stack's weights are the same at any depth.
+        k_join, k_block = jax.random.split(jax.random.fold_in(rng, 2))
+        params["mtp"] = {
+            "norm_h": jnp.zeros((d,), jnp.float32),
+            "norm_e": jnp.zeros((d,), jnp.float32),
+            "w_eh": _dense(k_join, (2 * d, d), 2 * d),
+            "block": _layer_init(config, config.mtp_kinds, k_block),
+            "norm": jnp.zeros((d,), jnp.float32),
+        }
     return params, param_axes(config)
 
 
 def init_buffers(config: HybridLMConfig, rng: jax.Array):
     """State that is not trained, shaped like ``params``' layers: the
-    expert layers' score-correction bias. Seeded from another stream
-    than the parameters."""
-    return _per_layer(
-        config, jax.random.fold_in(rng, 1),
-        lambda kinds, key: FFNS[kinds[1]].buffers(config, key),
-    )
+    expert layers' score-correction bias (the prediction module's block
+    has one too). Seeded from another stream than the parameters."""
+    make = lambda kinds, key: FFNS[kinds[1]].buffers(config, key)  # noqa: E731
+    buffers = _per_layer(config, jax.random.fold_in(rng, 1), make)
+    if config.mtp_depth:
+        buffers["mtp"] = {
+            "block": make(config.mtp_kinds, jax.random.fold_in(rng, 3))
+        }
+    return buffers
 
 
 def buffer_axes(config: HybridLMConfig):
@@ -472,10 +583,12 @@ def buffer_axes(config: HybridLMConfig):
     )
 
 
-def _mix(config, mixer, p, x):
+def _mix(config, mixer, positions, p, x):
     with jax.named_scope("attn"):
         h = rms_norm(x, p["mixer_norm"]).astype(config.compute_dtype)
-        x = x + MIXERS[mixer].apply(config, p["mixer"], h).astype(x.dtype)
+        kind = MIXERS[mixer]
+        where = (positions,) if kind.positional else ()
+        x = x + kind.apply(config, p["mixer"], h, *where).astype(x.dtype)
         return with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -487,36 +600,47 @@ def _feed(config, ffn, p, buffers, x):
         return with_logical_constraint(x, ("batch", "seq", "embed")), counters
 
 
-def _layer(config, kinds, p, buffers, x):
+def _layer(config, kinds, positions, p, buffers, x):
     """One block. The scopes land in every op's ``op_name``, forward and
     backward, and nest under the ``attn`` / ``mlp`` the dense model has,
-    so ``benchmark/trace_reduce.py`` buckets them as it does those."""
+    so ``benchmark/trace_reduce.py`` buckets them as it does those.
+    ``positions [b, s]`` (None where no mixer of the model reads them)
+    is closed over, not an argument: a block that reads none is the
+    same program with and without."""
     mixer, ffn = kinds
-    return _feed(config, ffn, p, buffers, _mix(config, mixer, p, x))
+    return _feed(config, ffn, p, buffers, _mix(config, mixer, positions, p, x))
 
 
-def run_pattern(config: HybridLMConfig, params, buffers, x):
-    """Leading layers unrolled, then the period scanned over its
-    repeats. Returns (hidden, summed counters ``[len(COUNTERS)]``)."""
-
-    def block(kinds):
-        """The layer, rematerialised in the backward, keeping its
-        projection matmuls' outputs, a KDA scan's output and, where the
-        scan is the kernels, the state entering each chunk (the
-        backward kernel's residual: with both kept, the layer's
-        re-forward runs no scan at all). A policy
-        object a layer, not one for all: with a shared one the compiled
-        step read 12,398 tokens/s where this reads 12,470 (my chip runs,
-        PR 31; the layers' remat bodies are then laid out differently)."""
-        policies = jax.checkpoint_policies
+def _block(config, kinds, positions):
+    """The layer, rematerialised in the backward. ``remat_keep``
+    ``"dots"`` keeps its projection matmuls' outputs, a KDA scan's
+    output and, where the scan is the kernels, the state entering each
+    chunk (the backward kernel's residual: with both kept, the layer's
+    re-forward runs no scan at all); ``"attention"`` keeps only what the
+    flash kernels left for their backward (``ops/pallas_attention``'s
+    ``flash_out`` / ``flash_lse``), so the projections and the experts
+    run again and a block holds its input and one attention output. A
+    policy object a layer, not one for all: with a shared one the
+    compiled step read 12,398 tokens/s where this reads 12,470 (my chip
+    runs, PR 31; the layers' remat bodies are then laid out
+    differently)."""
+    policies = jax.checkpoint_policies
+    if config.remat_keep == "attention":
+        keep = policies.save_only_these_names("flash_out", "flash_lse")
+    else:
         keep = policies.save_from_both_policies(
             policies.save_only_these_names("kda_out", "kda_states"),
             policies.dots_with_no_batch_dims_saveable,
         )
-        return jax.checkpoint(
-            functools.partial(_layer, config, kinds), policy=keep
-        )
+    return jax.checkpoint(
+        functools.partial(_layer, config, kinds, positions), policy=keep
+    )
 
+
+def run_pattern(config: HybridLMConfig, params, buffers, x, positions=None):
+    """Leading layers unrolled, then the period scanned over its
+    repeats. Returns (hidden, summed counters ``[len(COUNTERS)]``)."""
+    block = functools.partial(_block, config, positions=positions)
     if any(mixer == "kda" for mixer, _ in config.leading + config.period):
         # Once a trace of the step: which form the scan was built with.
         logger.info(
@@ -545,21 +669,72 @@ def run_pattern(config: HybridLMConfig, params, buffers, x):
     return x, counters + jnp.sum(per_repeat, axis=0)
 
 
+def _positions(config, tokens):
+    """``[b, s]`` of 0 .. s - 1 where a mixer of the model reads
+    positions, else None."""
+    if not config.positional:
+        return None
+    b, s = tokens.shape
+    return jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+
+
 def forward_hidden(config, params, buffers, tokens):
     x = llama.embed_tokens(config, params, tokens)
-    return run_pattern(config, params, buffers, x)
+    return run_pattern(
+        config, params, buffers, x, _positions(config, tokens)
+    )
+
+
+def mtp_loss(config, params, buffers, hidden, tokens, targets, mask=None):
+    """The prediction module's loss: ``hidden [b, s, d]`` the stack's
+    output BEFORE the final norm at positions 0 .. s - 1, ``tokens [b,
+    s]`` the tokens one to the right of those the stack read, ``targets
+    [b, s]`` two to the right. ``u_i = W_eh [norm_h(h_i) ; norm_e(
+    Emb(t_{i+1}))]``, one block over the ``u`` (causal, position ``i``
+    in its own rotation), then the model's own head behind the module's
+    norm. Returns (token-mean CE + z-loss, the block's counters). Every
+    op sits under the ``mtp`` scope: ``mtp/join``, ``mtp/attn/...``,
+    ``mtp/mlp/...``, ``mtp/vocab``."""
+    p, cdt = params["mtp"], config.compute_dtype
+    with jax.named_scope("mtp"):
+        with jax.named_scope("join"):
+            e = llama.embed_tokens(config, params, tokens)
+            joined = jnp.concatenate([
+                rms_norm(hidden, p["norm_h"]).astype(cdt),
+                rms_norm(e, p["norm_e"]).astype(cdt),
+            ], axis=-1)
+            u = _proj("bsd,de->bse", joined, p["w_eh"]).astype(hidden.dtype)
+            u = with_logical_constraint(u, ("batch", "seq", "embed"))
+        g, counters = _block(
+            config, config.mtp_kinds, _positions(config, tokens)
+        )(p["block"], buffers["mtp"]["block"], u)
+        head = {"final_norm": p["norm"], "lm_head": params["lm_head"]}
+        return llama.head_loss(config, head, g, targets, mask), counters
 
 
 def loss_fn(config, params, batch, buffers=None, attention_fn=None):
-    """batch: {"tokens": [b, s + 1]} -> (loss, {"ce", "aux", "counters"}).
-    No position enters but through KDA's recurrence and convolutions, so
-    there is no ``positions`` and no ``attention_fn`` to choose."""
+    """batch: {"tokens": [b, s + 1 + mtp_depth]} -> (loss, {"ce", "aux",
+    "counters"}). The stack reads ``tokens[:, :s]`` and is held to
+    ``tokens[:, 1:s + 1]``; positions are 0 .. s - 1 (only the
+    ``mla_rope`` mixer reads them), so there is no ``positions`` in the
+    batch and no ``attention_fn`` to choose. With ``mtp_depth`` 1 the
+    loss is ``ce + mtp_weight * ce_mtp``, the module held to
+    ``tokens[:, 2:]``; ``ce_mtp`` comes back beside ``ce`` and the
+    module's expert rows (``MTP_COUNTER``) beside the stack's counters."""
     del attention_fn
-    tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    depth = config.mtp_depth
+    s = batch["tokens"].shape[1] - 1 - depth
+    tokens, targets = batch["tokens"][:, :s], batch["tokens"][:, 1:s + 1]
     x, counters = forward_hidden(config, params, buffers, tokens)
     ce = llama.head_loss(config, params, x, targets, batch.get("mask"))
     aux = jnp.zeros((), jnp.float32)
-    return ce, {
-        "ce": ce, "aux": aux,
-        "counters": dict(zip(COUNTERS, counters)),
-    }
+    out = {"ce": ce, "aux": aux, "counters": dict(zip(COUNTERS, counters))}
+    if not depth:
+        return ce, out
+    ce_mtp, c = mtp_loss(
+        config, params, buffers, x, targets, batch["tokens"][:, 2:],
+        batch.get("mask"),
+    )
+    out["ce_mtp"] = ce_mtp
+    out["counters"][MTP_COUNTER] = c[0]
+    return ce + config.mtp_weight * ce_mtp, out

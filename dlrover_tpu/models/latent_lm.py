@@ -355,10 +355,7 @@ def latent_inputs(config: LatentLMConfig, p, h, positions):
     c, cdt = config, config.compute_dtype
     r, nope = c.kv_lora_rank, c.qk_nope_dim
     inv_freq = inv_frequencies(c)
-    c_q = rms_norm(
-        jnp.einsum("bsd,dr->bsr", h, p["w_qa"].astype(cdt)), p["q_norm"]
-    )
-    q = jnp.einsum("bsr,rhk->bshk", c_q, p["w_qb"].astype(cdt))
+    q = hybrid.mla_bottleneck_queries(p, h)
     q_rope = rope.apply_rope(q[..., nope:], positions, inv_freq=inv_freq)
     kva = jnp.einsum("bsd,dr->bsr", h, p["w_kva"].astype(cdt))
     latent = rms_norm(kva[..., :r], p["kv_norm"])

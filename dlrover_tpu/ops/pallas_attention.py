@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -47,6 +48,24 @@ def _pick_block(s: int, target: int = 1024) -> int:
         if s % cand == 0 and cand <= s:
             return cand
     return s
+
+
+# (q/k head size, value head size) -> {"fwd" | "bwd": (q target, kv
+# target)}: block targets picked on the chip for ONE shape, in place of
+# the defaults below (1024 forward; backward 1024 up to d = 128, else
+# 512). A shape that is not listed keeps the defaults, so an entry moves
+# no other model's kernels. ``tools/bench_flash_blocks.py`` times the
+# three kernels under candidate entries. 256 / 256 (20 heads, 8,192
+# tokens; my chip run, PR 43, one layer): the backward at 512 x 1024
+# reads 19.6 ms against 21.4 at the default 512 x 512 (1024 x 512:
+# 20.0; 256-blocks 25-34; 1024 x 1024 does not fit VMEM); the forward's
+# default 1024 x 1024 reads 7.65 ms and won (1024 x 512: 8.35; 512 x
+# 512: 10.15; 2048 x 1024 does not fit).
+BLOCK_TARGETS = {(256, 256): {"bwd": (512, 1024)}}
+
+
+def _block_targets(kind: str, d: int, dv: int, default: int):
+    return BLOCK_TARGETS.get((d, dv), {}).get(kind, (default, default))
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +163,9 @@ def _flash_forward(q, k, v, causal, softmax_scale, interpret):
     dv = v.shape[-1]  # the value head size may differ from q/k's (MLA)
     groups = h // hkv
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    block_q = _pick_block(sq)
-    block_k = _pick_block(skv)
+    target_q, target_k = _block_targets("fwd", d, dv, 1024)
+    block_q = _pick_block(sq, target_q)
+    block_k = _pick_block(skv, target_k)
     grid = (b * h, sq // block_q, skv // block_k)
 
     # Mosaic requires the BLOCK's last two dims to be divisible by
@@ -424,9 +444,11 @@ def flash_backward_T(qT, kT, vT, doT, lse, di, causal, softmax_scale,
     # (s/p/dp f32 + two accumulators), so larger head dims — unverified
     # and with proportionally bigger blocks — keep the conservative 512
     # cap to stay inside VMEM.
-    bwd_target = 1024 if d <= 128 else 512
-    block_q = _pick_block(sq, target=bwd_target)
-    block_k = _pick_block(skv, target=bwd_target)
+    target_q, target_k = _block_targets(
+        "bwd", d, dv, 1024 if d <= 128 else 512
+    )
+    block_q = _pick_block(sq, target=target_q)
+    block_k = _pick_block(skv, target=target_k)
     nq = sq // block_q
 
     q_block, do_block = (1, 1, block_q, d), (1, 1, block_q, dv)
@@ -636,6 +658,12 @@ def _fwd(q, k, v, causal, softmax_scale, interpret):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     out, lse_c = flash_forward(q, k, v, causal, softmax_scale, interpret)
+    # Named (an identity that lowers to nothing) so that a remat policy
+    # can keep exactly what the backward kernels need beside q, k, v and
+    # never run the forward kernel twice: ``save_only_these_names(
+    # "flash_out", "flash_lse")`` (models/hybrid.py, ``remat_keep``).
+    out = checkpoint_name(out, "flash_out")
+    lse_c = checkpoint_name(lse_c, "flash_lse")
     return out, (q, k, v, out, lse_c)
 
 

@@ -130,8 +130,10 @@ class ElasticTrainer:
         metrics=None,
     ):
         """``metrics``: the step's own ``metrics`` (``make_train_step``),
-        whose model counters (expert rows held / busiest / dropped) ride
-        the ``train.step`` span as attrs when a tracer is armed."""
+        whose model counters (expert rows held / busiest / dropped, a
+        prediction module's rows apart, and the two parts of a two-part
+        loss) ride the ``train.step`` span as attrs when a tracer is
+        armed."""
         self.global_step += steps
         # Chaos site: "mid-step" from the job's perspective — the step
         # landed on device but nothing downstream (reports, checkpoints
@@ -203,18 +205,22 @@ class ElasticTrainer:
         measured. Phase placement inside the step is the canonical
         order (fetch -> compute -> allreduce -> persist); the exact
         durations ride as attrs, and so do the model's step counters
-        (``moe_rows_*``: expert load beside the step's phases) when the
-        step's ``metrics`` holds them. Disarmed: one global check."""
+        (``moe_rows_*`` and ``mtp_moe_rows_held``: expert load beside the
+        step's phases; ``ce`` / ``ce_mtp``: the two parts of a loss that
+        has a prediction module's) when the step's ``metrics`` holds
+        them -- after the caller's own fetch of the loss, so nothing here
+        waits on the device. Disarmed: one global check."""
         tracer = tracing.active_tracer()
         if tracer is None:
             return
         end = time.monotonic()
         start = end - max(step_wall_s, 0.0)
         attrs = {"step": self.global_step, "dp_size": self.dp_size}
-        attrs.update(
-            (k, int(v)) for k, v in (metrics or {}).items()
-            if k.startswith("moe_rows_")
-        )
+        for k, v in (metrics or {}).items():
+            if k.startswith("moe_rows_") or k == "mtp_moe_rows_held":
+                attrs[k] = int(v)
+            elif k in ("ce", "ce_mtp"):
+                attrs[k] = float(v)
         root = tracer.record_span("train.step", start, end, attrs=attrs)
         waits = data_wait_s + allreduce_wait_s + ckpt_block_s
         compute_s = max(step_wall_s - waits, 0.0)

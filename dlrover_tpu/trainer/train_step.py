@@ -158,7 +158,10 @@ def make_train_step(
     batch["tokens"]: [grad_accum * micro_batch, seq+1] int32. The leading
     dim is split into ``grad_accum`` scan iterations; gradients average in
     f32. ``metrics`` carries the model's own counters (the ``counters``
-    of its loss's aux, summed over the microbatches) beside the loss.
+    of its loss's aux, summed over the microbatches) beside the loss,
+    and, for a model whose loss has a second part (``ce_mtp``: a
+    multi-token-prediction module), the two parts ``ce`` and ``ce_mtp``
+    apart, averaged over the microbatches.
     """
     model = model_for(config)
     attention_fn = None
@@ -186,7 +189,11 @@ def make_train_step(
         (loss, aux), grads = jax.value_and_grad(_loss, has_aux=True)(
             params, micro, buffers
         )
-        return loss, aux.get("counters", {}), grads
+        # A loss of two parts reports them apart; a loss of one part adds
+        # nothing (its program stays as it was).
+        parts = ("ce", "ce_mtp") if "ce_mtp" in aux else ()
+        parts = {k: aux[k] for k in parts}
+        return loss, (aux.get("counters", {}), parts), grads
 
     def step(state, batch):
         params = state["params"]
@@ -217,12 +224,13 @@ def make_train_step(
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
-            (grads, loss), counters = jax.lax.scan(
+            (grads, loss), (counters, parts) = jax.lax.scan(
                 accum, (zeros, jnp.zeros((), jnp.float32)), micro_tokens
             )
             counters = {k: jnp.sum(v, axis=0) for k, v in counters.items()}
+            parts = {k: jnp.mean(v, axis=0) for k, v in parts.items()}
         else:
-            loss, counters, grads = single_grad(
+            loss, (counters, parts), grads = single_grad(
                 params, {"tokens": tokens}, buffers
             )
 
@@ -245,6 +253,7 @@ def make_train_step(
             "grad_norm": grad_norm,
             "step": new_state["step"],
             **counters,
+            **parts,
         }
         return new_state, metrics
 

@@ -111,6 +111,71 @@ def test_flash_attention_with_its_own_value_head_compiles(one_chip):
     assert _n_kernels(c) == 3
 
 
+def test_flash_attention_at_256_256_compiles(one_chip):
+    """``glm47flash-train-8k``'s shapes (q/k 256 = 192 + 64, v 256, 20
+    heads, 8,192 tokens): both head sizes are multiples of the lane
+    width, so the forward folds the heads into the minor axis; the
+    backward runs at the shape's own 512 x 1024 blocks
+    (``BLOCK_TARGETS``), which reach its kernels and no other shape's."""
+    from dlrover_tpu.ops import pallas_attention as pa
+
+    def sds(heads, d):
+        return jax.ShapeDtypeStruct(
+            (1, 8192, heads, d), jnp.bfloat16, sharding=one_chip
+        )
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v).astype(jnp.float32))
+
+    grad = lambda: jax.jit(jax.grad(loss, argnums=(0, 1, 2)))  # noqa: E731
+    c = grad().lower(sds(20, 256), sds(20, 256), sds(20, 256)).compile()
+    assert _n_kernels(c) == 3
+    assert pa._block_targets("fwd", 256, 256, 1024) == (1024, 1024)
+    assert pa._block_targets("bwd", 256, 256, 512) == (512, 1024)
+    assert pa._block_targets("fwd", 128, 128, 1024) == (1024, 1024)
+    assert pa._block_targets("bwd", 192, 128, 512) == (512, 512)
+    assert pa._block_targets("bwd", 128, 128, 1024) == (1024, 1024)
+
+
+def test_latent_train_step_compiles_at_the_cells_shape(topo):
+    """``glm47flash-train-8k``'s step at published widths and 8,192 + 2
+    tokens (1 dense + 1 expert block of its 1 + 4: the scan's body is
+    one expert block either way, and the module whole) lowers for the
+    described v5e through ``make_train_step``: every block's three flash
+    kernels are there ONCE (what a block keeps for its backward is the
+    forward kernel's two outputs, so the re-forward runs none), the
+    module's sit under ``mtp`` and the stack's do not, and every scope
+    the cell's readers book device time to is in the program."""
+    from benchmark import common, mtp_scopes, rehearse_glm
+
+    cfg_json = common.load_json("configs", "glm-4.7-flash.json")
+    traffic = common.load_json("traffic", "pretrain-mtp-8k.json")
+    c = rehearse_glm.lower_step(
+        cfg_json, traffic, topo.devices[0], n_periods=1
+    ).compile()
+    text = c.as_text()
+    names = [
+        line.split('op_name="')[1].split('"')[0]
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and 'op_name="' in line
+    ]
+    booked = [mtp_scopes.scope_of(n) for n in names]
+    assert booked.count("mla") == 6 and booked.count("mtp/mla") == 3
+    assert booked.count("mtp/experts") > 0 and booked.count("experts") > 0
+    every = {
+        mtp_scopes.scope_of(m) for m in set(
+            line.split('op_name="')[1].split('"')[0]
+            for line in text.splitlines() if 'op_name="' in line
+        )
+    }
+    assert {
+        "mla", "dense", "router", "experts", "shared", "vocab",
+        "mtp/join", "mtp/mla", "mtp/router", "mtp/experts", "mtp/shared",
+        "mtp/vocab",
+    } <= every
+    assert c.memory_analysis().peak_memory_in_bytes < 16e9
+
+
 def test_kda_scan_kernels_compile_at_the_cells_shape(one_chip):
     """``kimilinear-train-8k``'s scan (32 heads x 8,192 tokens x 128,
     float32): on a TPU ``kda_chunked`` is the two Pallas kernels, and
