@@ -1567,6 +1567,9 @@ class PagedServingEngine(ServingEngine):
             from dlrover_tpu.serving.kvpool import latent
 
             stats["latent_chunk_attention"] = latent.CHUNK_ATTENTION
+            stats["latent_chunk_query_rows"] = latent.chunk_query_rows(
+                self.prefill_chunk
+            )
             stats["latent_decode_attention"] = self.latent_decode_attention
         if self._cache is not None:
             for key, value in self._cache.stats().items():

@@ -32,9 +32,9 @@ cached token:
 - the prefill chunk (``[1, chunk]`` queries whose own keys are causal
   and whose prefix lives in the pool) walks the slot's prefix in blocks
   of :data:`CHUNK_PREFIX_ROWS` rows (un-paired: 2.4 MB a block) under a
-  running softmax, so that no ``[heads, chunk, max_len]`` logits exist,
-  then its own rows, ABSORBED like the decode step
-  (:data:`CHUNK_ATTENTION`).
+  running softmax, then its own rows, ABSORBED like the decode step
+  (:data:`CHUNK_ATTENTION`), a TILE of queries at a time and only the
+  tiles that hold a valid row (:func:`chunk_rows_scored`).
 
 Both are append-free like the dense in-place programs: the new rows of
 all layers land after the layer loop (one row a slot, or the chunk's
@@ -55,10 +55,10 @@ from dlrover_tpu.serving.kvpool import engine as paged
 from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.index_pool import tokens_per_row
 
-# Prefix rows a prefill chunk scores at a time: [heads, chunk, this]
-# float32 logits are the largest temporary of the chunk's attention
-# (128 MB at 32 heads x 512 queries).
+# Prefix rows a prefill chunk scores at a time, and query rows a TILE of
+# it holds (:func:`chunk_query_rows`): their float32 logits are 32 MB.
 CHUNK_PREFIX_ROWS = 2048
+CHUNK_QUERY_ROWS = 128
 
 
 def _scores(spec: str, queries, rows):
@@ -74,14 +74,14 @@ def _scores(spec: str, queries, rows):
 # the construction log line say it): the decode step's form, queries
 # folded through ``w_kvb``, scores and the weighted sum over the rows
 # themselves (``2 T heads R (2 kv_lora_rank + qk_rope_dim)`` FLOPs a
-# layer). The other form, a block's keys and values built from its
-# latents and attention as written (``2 R kv_lora_rank heads (nope + v)
-# + 2 T heads R (nope + rope + v)``: half the FLOPs at the cell's
-# shape), was built and timed beside it on the chip: 47.0 ms a chunk
-# against 47.2 at 16,384 cached rows (my chip run, PR 38; PERF.md
-# section 6): the blocks' float32 softmax, not either form's matmuls,
-# is what a chunk's attention waits for. One was kept, the one that
-# shares the decode step's code.
+# layer). The other form, keys and values built from a block's latents
+# (half the FLOPs at the cell's shape), read 47.0 ms a chunk against
+# 47.2 on the chip (PR 38; PERF.md section 6). By the cell's trace at
+# 512 query rows (ledger, PRs 40, 43; PERF.md section 5) a chunk waits
+# ~22 ms for matmul fusions, at no less than ~82 % of the MXU's peak,
+# and ~10.8 ms for float32 softmax passes: the matmuls are two thirds,
+# and both scale with the query rows scored. One form was kept, the one
+# that shares the decode step's code.
 CHUNK_ATTENTION = "absorbed"
 
 
@@ -250,62 +250,62 @@ def _softmax_finish(carry):
     return acc / total.T[..., None]
 
 
-def chunk_attend(config, pool, layer, table_row, start, block_size: int):
-    """The prefill chunk's ``attend`` for one layer: the chunk's queries
-    (positions ``start ...``) over the slot's rows below ``start``, a
-    block of :data:`CHUNK_PREFIX_ROWS` at a time, and over the chunk's
-    own rows, causally. Rows past a short last chunk's tokens are
-    padding: they come after every valid query, so causality hides them,
-    and their own output is never read."""
-    r = config.kv_lora_rank
-    per = max(CHUNK_PREFIX_ROWS // block_size, 1)      # table entries a block
-    span = per * block_size
-    n_table = -(-table_row.shape[0] // per) * per
-    table = jnp.pad(
-        table_row, (0, n_table - table_row.shape[0]),
-        constant_values=SENTINEL_BLOCK,
-    )
-    scale = config.softmax_scale
+# The prefill chunk's query tiles. A short last chunk's rows past its
+# ``n_valid`` tokens are padding: they come after every valid query, so
+# causality hides them, and their own output is never read. The cell's
+# turns fill ~215 of a chunk's 512 rows, so the chunk's attention walks
+# its queries a tile at a time under a trip count taken from ``n_valid``,
+# and a skipped tile's rows come out as exact ZEROS, not as whatever a
+# softmax over nothing gives: they still ride through the residual
+# mixes, the router and the experts of every later layer and are landed
+# in the pool (written, invisible, overwritten), so they have to stay
+# finite.
+#
+# The tile was chosen on the chip among 32 / 64 / 128 / 256, one layer at
+# the cell's shape (``tools/bench_paged_decode.py --parts latent``; my
+# chip run, PR 44; PERF.md section 6): a FULL chunk 6.01 / 5.10 / 4.59 /
+# 4.55 ms against 6.53 as one tile of 512, and over the cell's turns
+# (log-uniform 64-512 valid rows) ~3.06 / 2.81 / 2.77 / 3.28: a tile
+# costs ~0.34 / 0.55 / 0.98 / 1.9 ms, so the smaller ones skip more rows
+# and run each row slower. With the tiles OUTSIDE the prefix blocks'
+# loop (a block re-gathered a tile) a layer reads within 0.1 ms of tiles
+# inside it (the running state sliced and updated in place) either way
+# round, ~2.77 against ~2.80 over the turns: the simpler order was kept.
 
-    def attend(p, q_nope, q_rope, row):
-        chunk, heads = q_nope.shape[1], q_nope.shape[2]
-        cdt = row.dtype
-        with jax.named_scope("absorb"):
-            q = latent_lm.absorb_queries(config, p, q_nope[0], q_rope[0])
 
-        def over(rows, visible, carry):
-            """``rows [k, cache_width]`` under ``visible [q, k]``."""
-            rows = rows.astype(cdt)
-            with jax.named_scope("scores"):
-                scores = _scores("qhw,kw->hqk", q, rows) * scale
-                scores = jnp.where(visible[None], scores, -jnp.inf)
-            with jax.named_scope("values"):
-                return _softmax_add(
-                    carry, scores,
-                    lambda probs: jnp.einsum(
-                        "hqk,kr->qhr", probs.astype(cdt), rows[:, :r],
-                        preferred_element_type=jnp.float32,
-                    ),
-                )
+def chunk_query_rows(chunk: int) -> int:
+    """Query rows a tile of a ``chunk``-row prefill chunk holds:
+    :data:`CHUNK_QUERY_ROWS`, or the whole chunk where that does not
+    divide it (``kv_stats()["latent_chunk_query_rows"]``)."""
+    return chunk if chunk % CHUNK_QUERY_ROWS else CHUNK_QUERY_ROWS
 
-        def prefix_block(i, carry):
-            ids = jax.lax.dynamic_slice_in_dim(table, i * per, per)
-            rows = pool.blocks_at(layer, ids).reshape(span, -1)
-            below = (i * span + jnp.arange(span)) < start
-            return over(
-                rows, jnp.broadcast_to(below[None, :], (chunk, span)), carry
-            )
 
-        carry = jax.lax.fori_loop(
-            0, (start + span - 1) // span, prefix_block,
-            _softmax_start(heads, chunk, r),
+def chunk_rows_scored(n_valid, chunk: int):
+    """Query rows a chunk of ``n_valid`` valid rows scores: its tiles up
+    to the last that holds a valid row. The chunk program takes its trip
+    count from this function and an account of a traced run takes its
+    rows from it (over the step spans' ``prefill_tokens``), so the two
+    cannot disagree. ``n_valid``: an int, an array or a traced scalar."""
+    tile = chunk_query_rows(chunk)
+    return -(-n_valid // tile) * tile
+
+
+def _attend_block(q, rows, visible, carry, scale, rank: int):
+    """One more block of cached ``rows [k, cache_width]`` for a tile's
+    absorbed queries ``q [tile, heads, cache_width]`` under ``visible
+    [tile, k]``: the running softmax ``carry`` (:func:`_softmax_add`)
+    with the block's scores and its rows' latents added."""
+    with jax.named_scope("scores"):
+        scores = _scores("qhw,kw->hqk", q, rows) * scale
+        scores = jnp.where(visible[None], scores, -jnp.inf)
+    with jax.named_scope("values"):
+        return _softmax_add(
+            carry, scores,
+            lambda probs: jnp.einsum(
+                "hqk,kr->qhr", probs.astype(rows.dtype), rows[:, :rank],
+                preferred_element_type=jnp.float32,
+            ),
         )
-        causal = jnp.arange(chunk)[None, :] <= jnp.arange(chunk)[:, None]
-        out = _softmax_finish(over(row[0], causal, carry)).astype(cdt)
-        with jax.named_scope("values"):
-            return latent_lm.values_out(config, p, out)[None]
-
-    return attend
 
 
 def decode_forward(config, pool, params, tables, lengths, tokens,
@@ -339,19 +339,19 @@ def decode_forward(config, pool, params, tables, lengths, tokens,
 
 
 def chunk_forward(config, pool, params, tokens, table_row, start,
-                  block_size: int):
+                  block_size: int, n_valid=None):
     """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
-    ``start ...``: the final streams ``[1, chunk, n, d]`` and the
-    chunk's new rows ``[L, chunk, cache_width]``."""
-    positions = (
-        start + jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    )[None, :]
+    ``start ...`` (``n_valid``: :func:`chunk_attend`'s): the final streams
+    ``[1, chunk, n, d]`` and the chunk's new rows ``[L, chunk, width]``."""
+    positions = (start + jnp.arange(tokens.shape[1], dtype=jnp.int32))[None]
     streams = latent_lm.embed_streams(config, params, tokens)
 
     def body(streams, p, layer):
         streams, row, _ = latent_lm.block(
             config, params, p, layer, streams, positions,
-            chunk_attend(config, pool, layer, table_row, start, block_size),
+            chunk_attend(
+                config, pool, layer, table_row, start, block_size, n_valid
+            ),
         )
         return streams, (row[0], None)
 
@@ -392,13 +392,84 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
     return step
 
 
+def chunk_attend(config, pool, layer, table_row, start, block_size: int,
+                 n_valid=None):
+    """The prefill chunk's ``attend`` for one layer: the chunk's queries
+    (positions ``start ...``) over the slot's rows below ``start``, a
+    block of :data:`CHUNK_PREFIX_ROWS` at a time, and over the chunk's
+    own rows, causally, a tile of :func:`chunk_query_rows` queries at a
+    time, and only the ``chunk_rows_scored(n_valid, chunk)`` rows of the
+    tiles that hold a valid row (``n_valid`` a traced scalar: the trip
+    count of the tiles' loop; None: every tile). Every valid row gets
+    what the whole chunk would give it, bit for bit (a tile's arithmetic
+    does not depend on how many tiles run). The rows of a skipped tile
+    are exact ZEROS, never NaN or Inf: later layers and the pool still
+    take them (the comment above :func:`chunk_query_rows`)."""
+    r = config.kv_lora_rank
+    per = max(CHUNK_PREFIX_ROWS // block_size, 1)      # table entries a block
+    span = per * block_size
+    n_table = -(-table_row.shape[0] // per) * per
+    table = jnp.pad(
+        table_row, (0, n_table - table_row.shape[0]),
+        constant_values=SENTINEL_BLOCK,
+    )
+    scale = config.softmax_scale
+
+    def attend(p, q_nope, q_rope, row):
+        chunk, heads = q_nope.shape[1], q_nope.shape[2]
+        tile = chunk_query_rows(chunk)
+        cdt = row.dtype
+        with jax.named_scope("absorb"):
+            q_all = latent_lm.absorb_queries(config, p, q_nope[0], q_rope[0])
+
+        def query_tile(t, out):
+            first = t * tile
+            q = jax.lax.dynamic_slice_in_dim(q_all, first, tile)
+
+            def prefix_block(i, carry):
+                ids = jax.lax.dynamic_slice_in_dim(table, i * per, per)
+                rows = pool.blocks_at(layer, ids).reshape(span, -1)
+                below = (i * span + jnp.arange(span)) < start
+                return _attend_block(
+                    q, rows.astype(cdt),
+                    jnp.broadcast_to(below[None, :], (tile, span)),
+                    carry, scale, r,
+                )
+
+            carry = jax.lax.fori_loop(
+                0, (start + span - 1) // span, prefix_block,
+                _softmax_start(heads, tile, r),
+            )
+            causal = (
+                jnp.arange(chunk)[None, :]
+                <= first + jnp.arange(tile)[:, None]
+            )
+            carry = _attend_block(q, row[0], causal, carry, scale, r)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, _softmax_finish(carry).astype(cdt), first, 0
+            )
+
+        scored = chunk_rows_scored(
+            chunk if n_valid is None else n_valid, chunk
+        )
+        out = jax.lax.fori_loop(
+            0, scored // tile, query_tile,
+            jnp.zeros((chunk, heads, r), cdt),
+        )
+        with jax.named_scope("values"):
+            return latent_lm.values_out(config, p, out)[None]
+
+    return attend
+
+
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
                   counts):
     def prefill(pool, params, tokens, table_row, start, n_valid, temp,
                 rng, step_idx, last=True):
         counts["prefill"] += 1  # traces only
         streams, rows = chunk_forward(
-            config, pool, params, tokens, table_row, start, block_size
+            config, pool, params, tokens, table_row, start, block_size,
+            n_valid,
         )
         pool = pool.land_run(
             rows, table_row, start, block_size, SENTINEL_BLOCK

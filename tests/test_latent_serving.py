@@ -213,6 +213,8 @@ def test_served_tokens_are_the_full_forwards(tiny, kind, take_the_pool_kernel):
     assert stats["latent_decode_attention"] == kind
     assert eng.latent_decode_attention == kind
     assert stats["pool_attention"] == "latent_absorbed"
+    # a chunk of 8 is not whole tiles of the module's size: one tile
+    assert stats["latent_chunk_query_rows"] == CHUNK
     eng.warmup()
     traced = dict(eng.trace_counts)
     tokens = serve(eng, items)
@@ -260,6 +262,146 @@ def test_the_absorbed_decode_is_the_unabsorbed_definition(
     np.testing.assert_allclose(
         got[0], want[start:start + CHUNK], atol=1e-5, rtol=1e-5
     )
+
+
+@pytest.fixture
+def query_tiles_of(monkeypatch):
+    """``tiles(rows, prefix_rows)``: from here on a chunk's queries are
+    walked in tiles of ``rows`` and its prefix in blocks of
+    ``prefix_rows`` (the module's constants are sized for 512-row chunks
+    over 16k rows; a CPU test's chunk of 8 or 16 is one tile of them).
+    The engines' programs are dropped before and afterwards: the tile's
+    size is no part of their key."""
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    def tiles(rows, prefix_rows=latent.CHUNK_PREFIX_ROWS):
+        paged._paged_steps_for.cache_clear()
+        monkeypatch.setattr(latent, "CHUNK_QUERY_ROWS", rows)
+        monkeypatch.setattr(latent, "CHUNK_PREFIX_ROWS", prefix_rows)
+
+    yield tiles
+    paged._paged_steps_for.cache_clear()
+
+
+@pytest.fixture(scope="module")
+def one_layers_chunk(tiny):
+    """One layer's inputs for 28 tokens, their rows landed in a pool of
+    8 blocks, and attention as written over them."""
+    cfg, params = tiny
+    p = latent_lm.layer_params(params, 1)
+    n = 28
+    h = jnp.asarray(
+        np.random.default_rng(11).normal(size=(1, n, cfg.embed_dim)),
+        jnp.float32,
+    )
+    q_nope, q_rope, row = latent_lm.latent_inputs(
+        cfg, p, h, jnp.arange(n, dtype=jnp.int32)[None]
+    )
+    want = latent_lm.definition_attention(
+        cfg, p, q_nope[0], q_rope[0], row[0]
+    )
+    pool = layout.fresh(layout.pool_arrays(cfg)[0], cfg.n_layers, 12, BS)
+    table = jnp.arange(1, 9, dtype=jnp.int32)
+    rows = jnp.zeros((cfg.n_layers, n, cfg.cache_width)).at[1].set(row[0])
+    pool = pool.land_run(rows, table, 0, BS, 0)
+
+    def attend(start, chunk, n_valid=None):
+        """``chunk_attend`` of rows ``start .. start + chunk``, jitted
+        with ``n_valid`` traced (as the prefill program has it)."""
+        at = slice(start, start + chunk)
+
+        def run(pool, n_valid):
+            return latent.chunk_attend(
+                cfg, pool, 1, table, start, BS, n_valid
+            )(p, q_nope[:, at], q_rope[:, at], row[:, at])[0]
+
+        if n_valid is None:
+            return np.asarray(jax.jit(lambda pool: run(pool, None))(pool))
+        return np.asarray(jax.jit(run)(pool, jnp.int32(n_valid)))
+
+    return attend, np.asarray(want)
+
+
+_TILE, _TILED_CHUNK = 4, 16
+
+
+@pytest.mark.parametrize("start", (0, 12))
+@pytest.mark.parametrize(
+    "n_valid", (1, _TILE - 1, _TILE, _TILE + 1, _TILED_CHUNK)
+)
+def test_a_chunk_scores_the_tiles_that_hold_a_valid_row(
+        one_layers_chunk, query_tiles_of, n_valid, start):
+    """A chunk of 16 queries in tiles of 4 over a prefix of 0 rows and
+    of 12 (one and a half blocks of 8): every row below ``n_valid`` is
+    BIT FOR BIT what the whole-chunk call gives (a tile's arithmetic
+    does not depend on how many tiles run) and attention as written to
+    float32 rounding; every row of a skipped tile is an exact zero;
+    nothing anywhere is non-finite; and ``n_valid=None`` is
+    ``n_valid=chunk``."""
+    query_tiles_of(_TILE, 8)
+    attend, want = one_layers_chunk
+    whole = attend(start, _TILED_CHUNK)
+    np.testing.assert_allclose(
+        whole, want[start:start + _TILED_CHUNK], atol=1e-5, rtol=1e-5
+    )
+    got = attend(start, _TILED_CHUNK, n_valid)
+    scored = latent.chunk_rows_scored(n_valid, _TILED_CHUNK)
+    assert scored == -(-n_valid // _TILE) * _TILE
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[:scored], whole[:scored])
+    assert not got[scored:].any()
+    assert np.abs(got[:scored]).min(axis=-1).max() > 0  # tiles that ran
+    if n_valid == _TILED_CHUNK:
+        np.testing.assert_array_equal(got, whole)
+
+
+@pytest.mark.parametrize("n_valid", (1, 6))
+def test_a_chunk_that_is_not_whole_tiles_is_one_tile(
+        one_layers_chunk, query_tiles_of, n_valid):
+    """6 queries under tiles of 4: one tile of 6, every row scored
+    whatever ``n_valid`` says."""
+    query_tiles_of(_TILE, 8)
+    attend, want = one_layers_chunk
+    assert latent.chunk_query_rows(6) == 6
+    assert latent.chunk_rows_scored(n_valid, 6) == 6
+    np.testing.assert_allclose(
+        attend(12, 6, n_valid), want[12:18], atol=1e-5, rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize("n_valid", range(1, _TILED_CHUNK + 1))
+def test_the_account_of_rows_scored_is_the_programs_trip_count(
+        one_layers_chunk, query_tiles_of, n_valid):
+    """``chunk_rows_scored`` is the tile times the trips the program
+    made: the rows that came out of it other than zero."""
+    query_tiles_of(_TILE, 8)
+    attend, _ = one_layers_chunk
+    ran = np.abs(attend(12, _TILED_CHUNK, n_valid)).max(axis=(1, 2)) > 0
+    assert int(ran.sum()) == latent.chunk_rows_scored(n_valid, _TILED_CHUNK)
+    assert ran[:int(ran.sum())].all()
+
+
+def test_a_short_last_chunk_serves_the_full_forwards_tokens(
+        tiny, query_tiles_of):
+    """Chunks of 8 in tiles of 4: last chunks of 5, 5, 6 and 1 valid
+    rows leave a tile out or run both, middle chunks run both, and the
+    greedy tokens are the plain forward's."""
+    query_tiles_of(4, 8)
+    cfg, params = tiny
+    items = list(zip(prompts(cfg, (37, 21, 30, 9)), (6, 5, 7, 4)))
+    eng = engine(cfg, params)
+    stats = eng.kv_stats()
+    assert stats["latent_chunk_query_rows"] == 4
+    assert stats["latent_chunk_attention"] == "absorbed"
+    eng.warmup()
+    traced = dict(eng.trace_counts)
+    tokens = serve(eng, items)
+    assert dict(eng.trace_counts) == traced      # n_valid is an input
+    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t))
+    for (prompt, n), out in zip(items, tokens):
+        logits, _ = fwd(jnp.asarray([prompt + out]))
+        want = np.asarray(logits)[0, len(prompt) - 1:-1].argmax(-1)
+        assert out == want.tolist() and len(out) == n
 
 
 # A decode batch's fills, a slot each (blocks of BS = 4 tokens, tables of
@@ -607,12 +749,9 @@ def test_the_mixer_of_the_trained_model_takes_the_rotation_as_an_option():
     )
 
 
-def test_the_tools_latent_part_rehearses_off_a_tpu():
-    """``tools/bench_paged_decode.py --parts latent --tiny``: the
-    gathered form, then the kernel at each ``--tile-rows`` against it
-    (interpret mode), a line each and no time; the program's own tile
-    size is back where it was afterwards (the tool sets it, no option of
-    the program does)."""
+def run_the_tool(*args):
+    """``tools/bench_paged_decode.py --tiny --parts latent`` with
+    ``args`` on a CPU: the JSON lines it printed."""
     import json
     import os
     import subprocess
@@ -623,17 +762,76 @@ def test_the_tools_latent_part_rehearses_off_a_tpu():
         "tools", "bench_paged_decode.py",
     )
     out = subprocess.run(
-        [sys.executable, tool, "--tiny", "--parts", "latent",
-         "--tile-rows", "2,4"],
+        [sys.executable, tool, "--tiny", "--parts", "latent", *args],
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, text=True,
         capture_output=True, timeout=300,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
+    return [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
+
+
+def test_the_tools_latent_part_rehearses_off_a_tpu():
+    """``tools/bench_paged_decode.py --parts latent --tiny``: the
+    gathered form, then the kernel at each ``--tile-rows`` against it
+    (interpret mode), a line each, then the prefill chunk's attention in
+    one line: the whole chunk as one tile and tiles of
+    ``--chunk-query-rows``, each at every count of valid rows, the tiled
+    forms the whole-chunk form's on the valid rows; no time anywhere.
+    The program's own tile sizes are back where they were afterwards
+    (the tool sets them, no option of the program does)."""
+    rows = run_the_tool("--tile-rows", "2,4", "--chunk-query-rows", "2,4")
     assert [(r["part"], r["form"]) for r in rows] == [
         ("latent", "gathered_view"), ("latent", "pool_kernel"),
-        ("latent", "pool_kernel"),
+        ("latent", "pool_kernel"), ("latent", "chunk"),
     ]
-    assert [r["tile_rows"] for r in rows[1:]] == [2, 4]
-    assert all(r["rel_err_of_gathered"] < 1e-5 for r in rows[1:])
+    assert [r["tile_rows"] for r in rows[1:3]] == [2, 4]
+    assert all(r["rel_err_of_gathered"] < 1e-5 for r in rows[1:3])
     assert not [k for r in rows for k in r if k in ("ms", "rows_gb_s")]
+    tiles = rows[3]["tiles"]
+    assert list(tiles) == ["8", "2", "4"]        # the whole chunk first
+    assert all(list(t) == ["1", "3", "8"] for t in tiles.values())
+    assert [tiles[t]["3"]["rows_scored_share"] for t in tiles] == [
+        1.0, 0.5, 0.5
+    ]
+    assert tiles["2"]["1"]["rows_scored_share"] == 0.25
+    assert all(
+        r["rel_err_of_whole_chunk"] < 1e-5 and "ms" not in r
+        for t in ("2", "4") for r in tiles[t].values()
+    )
+
+
+def test_the_tools_chunk_account_reads_a_traced_runs_spans(tmp_path):
+    """``--chunks-of traced.json``: rows scored over rows launched by
+    the chunks the run's ``serving.step`` spans have on record, at the
+    tile its ``kv_stats`` names (none: the whole chunk, as before the
+    tiles), inside the profiler session and over all; nothing runs."""
+    import json
+
+    def step(ts, n):
+        return {"name": "serving.step", "ts": ts, "dur_s": 0.5,
+                "attrs": {"prefill_tokens": n}}
+
+    facts = {
+        "traced_window": [10.0, 20.0],
+        "kv_stats": {"latent_chunk_query_rows": 4},
+        "spans": [step(1.0, 8), step(2.0, 0), step(11.0, 3), step(12.0, 5),
+                  {"name": "serving.queue_wait", "ts": 12.0, "dur_s": 0.1,
+                   "attrs": {}}],
+    }
+    got = []
+    for name in ("tiled", "whole"):
+        path = tmp_path / (name + ".json")
+        path.write_text(json.dumps(facts))
+        (line,) = run_the_tool("--chunks-of", str(path))
+        got.append(line)
+        facts["kv_stats"].pop("latent_chunk_query_rows", None)
+    tiled, whole = got
+    assert tiled["query_rows"] == 4 and whole["query_rows"] == 8
+    assert tiled["traced"] == {
+        "chunks": 2, "full_chunks": 0, "rows_valid": 8, "rows_scored": 12,
+        "rows_launched": 16, "scored_over_launched": 0.75,
+    }
+    assert tiled["all"]["scored_over_launched"] == round(20 / 24, 4)
+    assert tiled["all"]["full_chunks"] == 1
+    assert whole["traced"]["scored_over_launched"] == 1.0
+    assert whole["all"]["rows_scored"] == whole["all"]["rows_launched"] == 24

@@ -606,11 +606,16 @@ def test_latent_serving_programs_compile_at_the_cells_shape(topo):
     exist in the chunk program. The decode step reads its rows by the
     Pallas kernel over the pool in place (PR 40), booked to ``attn/mla``
     where ``latent_attn_ms_per_step`` reads it: no gathered ``[slots,
-    max_len]`` view and no float32 scores of it are left in the step;
-    the chunk is what it was. The check's probe of the decode step lowers
-    with the kernel's scores as its second output."""
+    max_len]`` view and no float32 scores of it are left in the step.
+    The chunk walks its queries in tiles of ``latent.CHUNK_QUERY_ROWS``
+    under a trip count taken from ``n_valid`` (PR 44): its largest
+    scores are a TILE's against a block of prefix rows, no whole
+    chunk's, and it is still ``jax.numpy``, no latent kernel. The
+    check's probe of the decode step lowers with the kernel's scores as
+    its second output."""
     from benchmark import common, latent_scopes, trace_reduce
     from benchmark import rehearse_xing
+    from dlrover_tpu.serving.kvpool import latent
 
     cfg_json = common.load_json("configs", "xing4-29b-a4b.json")
     programs = rehearse_xing.lower_engine_programs(
@@ -653,14 +658,20 @@ def test_latent_serving_programs_compile_at_the_cells_shape(topo):
         # 2 layers of weights (5.4 GB) + the 2-layer pool (1.4 GB) +
         # temporaries: the other 4 expert layers add 4 x (1.49 + 0.68)
         # GB of arguments and no temporaries. The step's were 0.70 GB
-        # of view and scores before the kernel.
+        # of view and scores before the kernel; the chunk's 0.24 GB
+        # (134 MB of a whole chunk's scores against a block among
+        # them) before its queries went in tiles, 0.118 GB since.
         assert m.temp_size_in_bytes < (
-            0.1e9 if name == "jit_step" else 1.5e9
+            0.1e9 if name == "jit_step" else 0.13e9
         )
         assert m.argument_size_in_bytes + m.temp_size_in_bytes < 8.5e9
         if name == "jit_prefill":
+            tile = latent.CHUNK_QUERY_ROWS
+            assert tile < 512 and not 512 % tile
             assert "f32[32,512,17408]" not in text
-            assert "f32[32,512,2048]" in text    # a block of prefix rows
+            assert "f32[32,512,2048]" not in text    # a whole chunk's
+            # a tile's queries against a block of prefix rows
+            assert f"f32[32,{tile},2048]" in text
     text = programs["probe_decode0"].compile().as_text()
     assert kernel in text
     # the kernel's own raw scores: 64 query rows against a slot's 8,704

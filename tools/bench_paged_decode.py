@@ -36,7 +36,15 @@ rows, the 9,216-block pool of two 576-wide rows to a device row), the
 gathered ``[slots, max_len]`` view against
 ``pool_latent_decode_attention`` over the pool in place at
 ``--tile-rows`` device rows a VMEM tile: ms a call and the visible
-rows' GB/s. A smoke reading, not a
+rows' GB/s; then one layer of its prefill chunk's attention
+(``latent.chunk_attend``: 512 queries x 32 heads over one slot's 16,384
+cached rows) with 64 / 128 / 215 / 512 of the chunk's rows valid, its
+queries walked in tiles of ``--chunk-query-rows`` rows and in one tile
+(the whole-chunk form). ``--chunks-of TRACED_JSON`` (with ``latent``,
+nothing else runs and no TPU is needed): rows scored over rows launched
+by the prefill chunks a traced benchmark run of that cell has on record
+(``latent.chunk_rows_scored`` over its ``serving.step`` spans'
+``prefill_tokens``, at the tile its ``kv_stats`` names). A smoke reading, not a
 benchmark: one process, host-clock timing around ``block_until_ready``.
 Times mean something on a TPU only: anywhere else the tool refuses to
 run, unless ``--tiny`` rehearses it (interpret mode, nothing timed).
@@ -156,23 +164,136 @@ def _reference(q, k_new, v_new, k_pool, v_pool, layer, tables, fills):
 # rows to a 1,152-lane device row, 32 heads) and its fills.
 LATENT = dict(
     slots=32, max_blocks=272, num_blocks=9216, block=64, layers=6,
-    fills=(16400, 16900),
+    fills=(16400, 16900), chunk=512, start=16384,
+    n_valid=(64, 128, 215, 512), query_rows=(32, 64, 128),
     model=dict(n_heads=32, kv_lora_rank=512, qk_rope_dim=64,
                qk_nope_dim=128, v_head_dim=128, dtype="bfloat16"),
 )
 TINY_LATENT = dict(
     slots=3, max_blocks=6, num_blocks=24, block=4, layers=2,
-    fills=(9, 23), model=dict(kv_lora_rank=128, qk_rope_dim=64),
+    fills=(9, 23), chunk=8, start=12, n_valid=(1, 3, 8),
+    query_rows=(2, 4), model=dict(kv_lora_rank=128, qk_rope_dim=64),
 )
 
 
-def _latent(tile_rows, repeats, seed, tiny):
+def _latent_chunk(cfg, pool, p, layer, table_row, shape, query_rows,
+                  repeats, keys):
+    """One layer of a latent model's prefill chunk attention
+    (``kvpool/latent.chunk_attend``: absorb, the prefix in blocks, the
+    chunk's own rows, ``w_kvb``'s value half) at the cell's shape, with
+    each of ``shape["n_valid"]`` of the chunk's rows valid: the queries
+    in one tile (the whole-chunk form, which scores every row whatever
+    is valid) and in tiles of each of ``query_rows``. ONE JSON line:
+    ms a call by tile and valid rows, the share of the chunk's rows each
+    scored, and the tiled forms' worst distance from the whole-chunk
+    form over the valid rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.serving.kvpool import latent
+
+    chunk, start, bs = shape["chunk"], shape["start"], shape["block"]
+    dt = cfg.compute_dtype
+    q_nope = jax.random.normal(
+        keys[0], (1, chunk, cfg.n_heads, cfg.qk_nope_dim), dt
+    )
+    q_rope = jax.random.normal(
+        keys[1], (1, chunk, cfg.n_heads, cfg.qk_rope_dim), dt
+    )
+    row = jax.random.normal(keys[2], (1, chunk, cfg.cache_width), dt)
+    line = {
+        "part": "latent", "form": "chunk", "chunk": chunk, "start": start,
+        "shipped_query_rows": latent.CHUNK_QUERY_ROWS, "tiles": {},
+    }
+    ok, whole = True, {}
+    shipped = latent.CHUNK_QUERY_ROWS
+    try:
+        for tile in [chunk] + [t for t in query_rows if t != chunk]:
+            latent.CHUNK_QUERY_ROWS = tile
+            attend = jax.jit(lambda pool, layer, n_valid: latent.chunk_attend(
+                cfg, pool, layer, table_row, start, bs, n_valid
+            )(p, q_nope, q_rope, row)[0])
+            readings = line["tiles"][str(tile)] = {}
+            for n in shape["n_valid"]:
+                args = (pool, layer, jnp.int32(n))
+                got = attend(*args).astype(jnp.float32)
+                ok = ok and bool(jnp.isfinite(got).all())
+                reading = {"rows_scored_share": round(
+                    int(latent.chunk_rows_scored(n, chunk)) / chunk, 4
+                )}
+                if tile == chunk:
+                    whole[n] = got
+                else:
+                    err = float(
+                        jnp.linalg.norm(got[:n] - whole[n][:n])
+                        / jnp.linalg.norm(whole[n][:n])
+                    )
+                    ok = ok and err < 0.02
+                    reading["rel_err_of_whole_chunk"] = err
+                _put(reading, "ms", _timed(
+                    lambda: jax.block_until_ready(attend(*args)), repeats
+                ))
+                readings[str(n)] = reading
+    finally:
+        latent.CHUNK_QUERY_ROWS = shipped
+    print(json.dumps(line), flush=True)
+    return ok
+
+
+def _latent_chunk_account(path, chunk):
+    """Rows scored over rows launched by the prefill chunks of a traced
+    benchmark run (its ``traced.json``): every ``serving.step`` span
+    that ran a chunk says its ``prefill_tokens`` (the chunk's
+    ``n_valid``), the run's ``kv_stats`` the tile in force (none before
+    PR 44: the whole chunk), and ``latent.chunk_rows_scored`` is the
+    function the program took its trip count from. One JSON line: the
+    chunks that ended inside the profiler session, and all on record
+    (set-up's context chunks, all of them full, the ramp's and the
+    window's turns)."""
+    from dlrover_tpu.serving.kvpool import latent
+
+    with open(path) as f:
+        facts = json.load(f)
+    lo, hi = facts.get("traced_window") or (None, None)
+    chunks = [
+        (s["ts"] + s["dur_s"], s["attrs"]["prefill_tokens"])
+        for s in facts.get("spans") or ()
+        if s["name"] == "serving.step" and s.get("dur_s") is not None
+        and s["attrs"].get("prefill_tokens")
+    ]
+    shipped = latent.CHUNK_QUERY_ROWS
+    tile = (facts.get("kv_stats") or {}).get("latent_chunk_query_rows", chunk)
+    line = {"part": "latent", "form": "chunk_account", "chunk": chunk,
+            "query_rows": tile}
+    try:
+        latent.CHUNK_QUERY_ROWS = tile
+        for name, valid in (
+            ("traced", [n for end, n in chunks
+                        if lo is not None and lo <= end <= hi]),
+            ("all", [n for _, n in chunks]),
+        ):
+            scored = sum(latent.chunk_rows_scored(n, chunk) for n in valid)
+            line[name] = {
+                "chunks": len(valid), "full_chunks": valid.count(chunk),
+                "rows_valid": sum(valid), "rows_scored": scored,
+                "rows_launched": chunk * len(valid),
+                "scored_over_launched": (
+                    round(scored / (chunk * len(valid)), 4) if valid else None
+                ),
+            }
+    finally:
+        latent.CHUNK_QUERY_ROWS = shipped
+    print(json.dumps(line), flush=True)
+
+
+def _latent(tile_rows, query_rows, repeats, seed, tiny):
     """One layer of a latent model's decode attention
     (``kvpool/latent.decode_attend``: absorb, the rows, ``w_kvb``'s
     value half) at the ``xing-serve-sessions-16k`` shape, the gathered
     ``[slots, max_len]`` view against the Pallas kernel over the packed
     pool in place at each of ``tile_rows`` device rows a VMEM tile: ms a
-    call and the visible rows' GB/s, one JSON line each."""
+    call and the visible rows' GB/s, one JSON line each; then the
+    prefill chunk's (:func:`_latent_chunk`, tiles of ``query_rows``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -189,7 +310,7 @@ def _latent(tile_rows, repeats, seed, tiny):
     cfg = latent_lm.tiny_config(**shape["model"])
     slots, mb, bs = shape["slots"], shape["max_blocks"], shape["block"]
     rng = np.random.default_rng(seed)
-    keys = jax.random.split(jax.random.key(seed), 5)
+    keys = jax.random.split(jax.random.key(seed), 8)
     dt = cfg.compute_dtype
     pack = tokens_per_row(cfg.cache_width, bs)
     pool = IndexKeyPool(_normal(keys[0], (
@@ -251,7 +372,10 @@ def _latent(tile_rows, repeats, seed, tiny):
             }), flush=True)
     finally:
         lda.TILE_ROWS = shipped
-    return ok
+    return _latent_chunk(
+        cfg, pool, p, layer, tables[0], shape,
+        query_rows or shape["query_rows"], repeats, keys[5:],
+    ) and ok
 
 
 def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
@@ -623,6 +747,15 @@ def main():
                     help="latent: device rows a VMEM tile of the latent "
                     "kernel, to time it at (default, the one shipped: "
                     "ops.latent_decode_attention.TILE_ROWS)")
+    ap.add_argument("--chunk-query-rows",
+                    help="latent: query rows a tile of the prefill "
+                    "chunk's attention, to time it at beside the whole "
+                    "chunk as one tile (default 32,64,128; the one "
+                    "shipped: kvpool.latent.CHUNK_QUERY_ROWS)")
+    ap.add_argument("--chunks-of", metavar="TRACED_JSON",
+                    help="latent: print rows scored over rows launched "
+                    "by the prefill chunks of that traced benchmark run "
+                    "and exit (needs no TPU)")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--tiny", action="store_true",
@@ -636,6 +769,14 @@ def main():
         k: v for k in ("slots", "max_blocks", "heads", "layers", "chunk")
         if (v := getattr(ns, k)) is not None
     })
+    parts = ns.parts.split(",")
+    if ns.chunks_of:
+        if "latent" not in parts:
+            ap.error("--chunks-of is the latent part's")
+        _latent_chunk_account(
+            ns.chunks_of, (TINY_LATENT if ns.tiny else LATENT)["chunk"]
+        )
+        return
     import jax
 
     if not ns.tiny and jax.default_backend() != "tpu":
@@ -644,12 +785,14 @@ def main():
             "run in interpret mode and its times would mean nothing; "
             "--tiny rehearses the script without timing"
         )
-    parts = ns.parts.split(",")
     if "latent" in parts:
         parts.remove("latent")
         ok = _latent(
             [int(x) for x in ns.tile_rows.split(",")] if ns.tile_rows
-            else None, 0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
+            else None,
+            [int(x) for x in ns.chunk_query_rows.split(",")]
+            if ns.chunk_query_rows else None,
+            0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
         )
         if not ok or not parts:
             raise SystemExit(0 if ok else 1)
