@@ -167,6 +167,60 @@ def _tile(dim: int, cap: int = 512) -> int:
     return t
 
 
+# A row tile is never under 128 rows, and 128 rows on a weight block are
+# 128 FLOP a weight byte where a v5e does ~240 in the time it moves one
+# (197 TFLOP/s over 819 GB/s): a group with fewer rows than that waits
+# for its weights, whatever the tile.
+ROW_TILE = 128
+
+# What a Pallas TPU kernel may hold in VMEM unless it asks for more
+# (megablox asks for nothing), less half a MiB for the compiler's own.
+_VMEM_BUDGET = 16 * 2**20 - 2**19
+
+
+def _weight_block(even: int, tm: int, k: int, n: int, itemsize: int):
+    """``(tk, tn)`` of the ``[k, n]`` weight block a grouped matmul
+    streams a grid step, from shapes alone.
+
+    ``even`` is the rows an evenly loaded expert gets. From ``ROW_TILE``
+    up a visit of a group keeps the MXU busy for as long as its weights
+    take to arrive, and the blocks are :func:`_tile`'s (powers of two up
+    to 512: the trained shapes, letter for letter). Below it every visit
+    waits for its weights. ``gmm``'s grid is ``(n / tn, visits, k /
+    tk)``: a step of a 0.25-0.5 MB block costs its DMA and ~0.23 us
+    more, a quarter to a third of it, and from ~2 MB a block up little
+    but the DMA is left (PERF.md section 6, PR 45). There the block is
+    the LARGEST whose dimensions are multiples of 128 dividing ``k`` and
+    ``n`` (not powers of two: 1,792 and 896 divide 3,584) that fits
+    VMEM, whole rows of the matrix preferred among equals (one
+    contiguous run a block). What has to fit: two weight blocks, two row
+    blocks, two output blocks and one float32 accumulator, the forward's
+    ``[tm, tn]`` or the ``[tk, tn]`` of the weights' gradient (``gmm``'s
+    pullback runs ``tgmm`` under the forward's tiling: a block that only
+    the forward could hold would be a backward that does not compile).
+    A dimension with no such divisor keeps ``_tile``'s."""
+    if even >= ROW_TILE:
+        return _tile(k), _tile(n)
+
+    def cuts(dim):
+        return [
+            t for t in range(128, dim + 1, 128) if dim % t == 0
+        ] or [_tile(dim)]
+
+    def vmem(tk, tn):
+        return (
+            2 * tk * tn * itemsize + 2 * tm * (tk + tn) * itemsize
+            + 4 * max(tm, tk) * tn
+        )
+
+    # never empty: a dimension's smallest cut is 128 or less
+    fits = [
+        (tk, tn) for tk in cuts(k) for tn in cuts(n)
+        if vmem(tk, tn) <= _VMEM_BUDGET
+    ]
+    return max(fits, key=lambda b: (b[0] * b[1], b[1]))
+
+
 # The dispatch/combine gathers are permutation-shaped, and XLA's
 # transpose of a gather is a SCATTER(-add) — slow on TPU and the bulk
 # of the dropless path's overhead in the backward. Both inverses are
@@ -862,12 +916,13 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
         # A row tile no larger than the rows an evenly loaded expert
         # gets: a group pays for whole tiles.
         even = n * top_k // e_all
-        tm = min(max(even, 128), 512)
+        tm = min(max(even, ROW_TILE), 512)
 
         def through(rows):
             return _share_rows(
                 xf, weights, w_gate, w_up, w_down, order, inv_order,
                 is_held, group_sizes, n_held, rows, tm, interpret, w_gu,
+                even,
             )
 
         def padded(rows):
@@ -890,10 +945,12 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
 
 def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
                 is_held, group_sizes, n_held, rows, tm, interpret,
-                w_gu=None):
+                w_gu, even):
     """``moe_mlp_share``'s expert compute through a buffer of ``rows``
     rows (>= ``n_held``): gather the held pairs' tokens by expert, two
-    grouped matmuls, weight each row, and sum a token's rows back."""
+    grouped matmuls, weight each row, and sum a token's rows back.
+    ``even``: the rows an evenly loaded expert gets, which says how the
+    matmuls' weight blocks are cut (:func:`_weight_block`)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     n, d = xf.shape
@@ -916,14 +973,17 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     else:
         w_gu = w_gu.astype(cdt)
     tm = _tile(rows, cap=tm)
+    size = jnp.dtype(cdt).itemsize
     hu = gmm(
         xs, w_gu, group_sizes, preferred_element_type=cdt,
-        interpret=interpret, tiling=(tm, _tile(d), _tile(2 * f)),
+        interpret=interpret,
+        tiling=(tm, *_weight_block(even, tm, d, 2 * f, size)),
     )
     act = (jax.nn.silu(hu[:, :f]) * hu[:, f:]).astype(cdt)
     ys = gmm(
         act, w_down.astype(cdt), group_sizes, preferred_element_type=cdt,
-        interpret=interpret, tiling=(tm, _tile(f), _tile(d)),
+        interpret=interpret,
+        tiling=(tm, *_weight_block(even, tm, f, d, size)),
     )                                              # rows past the groups: 0
     by_row = _gather_with_inverse(
         weights.reshape(n * top_k, 1), front, at[:, None]

@@ -231,6 +231,53 @@ def test_expert_share_compiles_fwd_bwd(one_chip):
     assert _n_kernels(c) == 12
 
 
+# The served expert layer of the two expert serve cells: (tokens, top_k,
+# embed, mlp, experts a layer); five layers' experts are one stack.
+_SERVED_EXPERT_SHAPES = {
+    "xing_decode": (32, 4, 3584, 1024, 64),
+    "xing_chunk": (512, 4, 3584, 1024, 64),
+    "keye_decode": (16, 8, 2048, 768, 128),
+    "keye_chunk": (512, 8, 2048, 768, 128),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_SERVED_EXPERT_SHAPES))
+def test_served_expert_layer_compiles_under_its_weight_blocks(
+        one_chip, shape):
+    """``moe.routed_experts`` at the serve cells' decode and chunk
+    shapes, where a group holds fewer rows than a row tile and
+    ``moe._weight_block`` cuts the weights into blocks of megabytes: a
+    block over the scoped VMEM limit fails here as it would on the
+    chip. The pullback too (``gmm``'s VJP runs ``tgmm`` under the
+    forward's tiling, with a float32 accumulator the block's size)."""
+    from dlrover_tpu.models import moe
+
+    n, top_k, d, f, e = _SERVED_EXPERT_SHAPES[shape]
+    groups = 5 * e
+    assert moe._weight_block(n * top_k // e, 128, d, 2 * f, 2) != (
+        moe._tile(d), moe._tile(2 * f)
+    )
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, experts, weights, w_gu, w_down, at):
+        out, _ = moe.routed_experts(
+            x, experts, weights, w_gu, w_down, e, group_offset=at * e
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = (
+        sds((1, n, d)), sds((n, top_k), jnp.int32),
+        sds((n, top_k), jnp.float32), sds((groups, d, 2 * f)),
+        sds((groups, f, d)), sds((), jnp.int32),
+    )
+    assert _n_kernels(jax.jit(layer).lower(*args).compile()) == 2
+    back = jax.jit(jax.grad(layer, argnums=(0, 3, 4))).lower(*args).compile()
+    # the first gmm, and both pullbacks (a gmm and a tgmm each)
+    assert _n_kernels(back) == 5
+
+
 @pytest.mark.parametrize("dispatch", [None, "fused", "gmm"])
 def test_moe_dispatch_compiles_fwd_bwd(one_chip, dispatch):
     """``moe_mlp_dropless`` at the bench's MoE shape (e=8, top-2,
