@@ -1,9 +1,9 @@
 """Warm-standby worker process.
 
 A restart pays interpreter start-up plus the framework imports before
-it can even ask for the chip. On a v5e host bench_e2e.py's restarted
+it can even ask for the chip. On a v5e host a restarted 334M
 worker spent 3.5 s importing when spawned cold and 1.0 s when adopted
-(its own ``restart_imports_s``, two runs each way, PR 21) out of a
+(``restart_imports_s``, two runs each way, PR 21) out of a
 17-31 s recovery. A standby is a pre-spawned interpreter that has already
 imported jax and blocks on stdin until the agent ADOPTS it as the next
 worker incarnation: the agent writes one JSON line carrying the final
@@ -19,7 +19,7 @@ exists until the adopted script first touches a device, so the standby
 never contends for the chip with the live worker.
 
 Spawned by ElasticAgent when ``WorkerSpec.warm_standby`` is set (see
-agent/training.py); exercised end-to-end by bench_e2e.py.
+agent/training.py); covered by ``tests/test_elastic_agent.py``.
 """
 
 import json

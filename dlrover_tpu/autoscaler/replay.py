@@ -12,8 +12,8 @@ read the counterfactual ledger a different policy WOULD have produced,
 without touching the live job.
 
 Scoring is a goodput model over the recorded horizon, calibrated from
-MEASURED actuation costs (:class:`CostModel` defaults come from the
-bench history: rescale-to-first-step seconds, ckpt blocking cost). Per
+actuation costs (:class:`CostModel`: rescale-to-first-step seconds,
+ckpt blocking cost; its defaults are CPU readings). Per
 candidate it estimates lost wall time in four explainable buckets —
 actuation pauses, ckpt save overhead along the candidate's interval
 trajectory, replay exposure at the failures the recording actually
@@ -173,51 +173,15 @@ def assert_replay_identity(recording: Recording) -> Dict:
 
 @dataclass
 class CostModel:
-    """Measured actuation costs the goodput model charges. Defaults are
-    the 2-core-CPU bench numbers; :meth:`from_bench` recalibrates from
-    the newest bench artifact that carries the keys."""
+    """Actuation costs the goodput model charges. Defaults are
+    2-core-CPU readings, not a chip's; a caller with measured costs
+    passes them."""
 
-    rescale_to_first_step_s: float = 0.4   # bench `rescale` phase
+    rescale_to_first_step_s: float = 0.4   # one rescale pause
     evict_pause_s: float = 0.4             # evict == one rescale pause
     fleet_change_s: float = 0.05           # router add/drain latency
     save_block_s: float = 0.01             # ckpt blocking cost per save
     straggler_flag_threshold: float = 1.5  # score at which tax accrues
-
-    _BENCH_KEYS = {
-        "rescale_to_first_step_s": "rescale_to_first_step_s",
-        "ckpt_save_block_s": "save_block_s",
-    }
-
-    @classmethod
-    def from_bench(cls, paths: Iterable[str]) -> "CostModel":
-        """Best-effort calibration from bench JSON artifacts, newest
-        first. Each cost key takes the FIRST (newest) artifact that
-        carries it — an artifact missing a key does not stop the scan,
-        and keys no artifact carries keep their defaults."""
-        import json
-        import os
-
-        model = cls()
-        remaining = dict(cls._BENCH_KEYS)
-        for path in paths:
-            if not remaining:
-                break
-            if not os.path.exists(path):
-                continue
-            try:
-                data = json.loads(open(path).read())
-            except (OSError, ValueError):
-                continue
-            for bench_key in list(remaining):
-                value = data.get(bench_key)
-                if isinstance(value, (int, float)) and value > 0:
-                    setattr(model, remaining.pop(bench_key),
-                            float(value))
-        if "rescale_to_first_step_s" not in remaining:
-            # The eviction pause IS one rescale pause; keep the pair
-            # coherent when the rescale number was calibrated.
-            model.evict_pause_s = model.rescale_to_first_step_s
-        return model
 
     def to_dict(self) -> Dict[str, float]:
         return {

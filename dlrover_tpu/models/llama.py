@@ -337,8 +337,8 @@ def _moe_resolve_impl(config) -> str:
     form on multi-device meshes without ep (the global-argsort core has
     data-dependent group sizes GSPMD cannot lower soundly — it must
     never see a sharded batch directly), or the ragged-all-to-all ep
-    form. "auto" follows the measured crossover (bench.py
-    moe_crossover_sweep, v5e): gshard wins at the default capacity
+    form. "auto" follows the measured crossover (r05,
+    v5e, 2026-08-01, 334M): gshard wins at the default capacity
     factor (1.25: e.g. 9.3 vs 12.9 ms/layer at 8 experts), dropless
     wins once the capacity budget reaches ~2.0 — and at that point it
     is also drop-free, so auto picks it there. Multi-device auto stays
@@ -750,7 +750,7 @@ def resolve_ce_path(config, n_tokens: int) -> str:
     matmuls; gradients computed in the forward, see ops/fused_ce.py)
     while never materializing the [N, V] logits. "auto" engages it
     only ABOVE the measured N*V crossover
-    (ops/fused_ce.AUTO_FUSED_MIN_NV ≈ 2 GiB of f32 logits): bench r05
+    (ops/fused_ce.AUTO_FUSED_MIN_NV ≈ 2 GiB of f32 logits): r05 (v5e)
     measured the chunked path at 1.042x dense at the flagship shape
     just below the line, while above it the memory freed is what lets
     the attn_save remat policy fit at 32k tokens and the time cost is

@@ -42,26 +42,14 @@ import numpy as np
 
 NEG_INF = -1e30
 
-# Measured dense/fused crossover in N*V elements (f32-logits bytes / 4).
-# Evidence trail (the §33 kernel campaign re-measured after the MoE /
-# decode changes shifted step composition — CE itself is untouched by
-# them, and the ratio held): v5e bench r05 AND the BENCH_SELF
-# re-measure both put the flagship head shape n=16384, v=32000 —
-# N*V = 5.24e8, just below this line — at chunked = 1.042x DENSE (the
-# [d, V] f32 dw-carry HBM round-trip per row chunk is pure overhead
-# while the logits still fit), so dense keeps its edge below the line;
-# above it the ~2 GiB+ logits are what stop long-context steps from
-# fitting (the attn_save remat budget), and the fused path's time cost
-# is a wash. llama.resolve_ce_path delegates here; the CE A/B bench
-# reports the choice (ce_auto_path) plus ce_auto_pin_consistent — a
-# live check that the measured ratio still agrees with this pin, so a
-# drifted crossover is loud in the artifact rather than silently
-# mis-routing the auto path.
-CE_CROSSOVER_EVIDENCE = {
-    "nv": 16384 * 32000,
-    "chunked_vs_dense": 1.042,
-    "rounds": ("r05", "BENCH_SELF"),
-}
+# Dense/fused crossover in N*V elements (f32-logits bytes / 4). What was
+# measured (r05, v5e, 2026-08-01, 334M): the flagship head shape
+# n=16384, v=32000 (N*V = 5.24e8, just below this line) ran chunked at
+# 1.042x DENSE (the [d, V] f32 dw-carry HBM round-trip per row chunk is
+# pure overhead while the logits still fit), so dense keeps its edge
+# below the line; above it the ~2 GiB+ logits are what stop
+# long-context steps from fitting (the attn_save remat budget), and the
+# fused path's time cost is a wash. llama.resolve_ce_path delegates here.
 AUTO_FUSED_MIN_NV = 2 * 1024**3 // 4
 
 

@@ -1,7 +1,7 @@
 """Int8 KV-cache quantization: per-(row, head) scales, symmetric.
 
 Decode is HBM-bandwidth-bound and the KV cache is the stream that
-grows with context: BENCH_SELF pins the decode step at 1.33–1.46× the
+grows with context: r05 (v5e, 2026-08-01, 334M) read the decode step at 1.33–1.46× the
 HBM roofline with bf16 KV. Storing the cache as int8 halves the bytes
 every decode step must move — the direct lever on that gap — and
 doubles how many paged-KV blocks fit in the same HBM (the serving

@@ -1,6 +1,6 @@
 """Self-speculative decoding: drafters + the batched accept/reject law.
 
-Decode buys exactly one token per weight/KV sweep; BENCH_SELF pins
+Decode buys exactly one token per weight/KV sweep; r05 (v5e, 334M) read
 that sweep at 1.33-1.46x the HBM roofline, so the remaining raw-speed
 axis is tokens PER step (ROADMAP item 2). Speculative decoding
 (Leviathan-style draft-then-verify, self-drafting so no second model

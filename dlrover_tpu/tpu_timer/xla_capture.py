@@ -90,8 +90,7 @@ def parse_chrome_trace(path: str) -> List[Tuple[str, bool, float, float]]:
 def _capture_trace(parse_fn, capture_s: float):
     """Open a trace session for ``capture_s``, close it ON ANY EXIT (a
     leaked active session breaks every later capture in the process),
-    and apply ``parse_fn`` to the newest trace file. The single home of
-    the session/teardown invariant for both capture entry points."""
+    and apply ``parse_fn`` to the newest trace file."""
     import jax
 
     tmpdir = tempfile.mkdtemp(prefix="dlrover_tpu_xla_cap_")
@@ -135,84 +134,6 @@ def capture_device_events(
         return [ev for ev in events if ev[1]]
 
     return _capture_trace(parse, capture_s)
-
-
-def parse_op_profile(path: str) -> List[Dict]:
-    """Per-op device events WITH compiler metadata, for attribution.
-
-    Each "XLA Ops"-plane complete event becomes
-    ``{name, scope (tf_op: the jax name-stack path), category
-    (hlo_category), dur_us, flops (model_flops), bytes
-    (bytes_accessed)}``. The jax name stack is what ``jax.named_scope``
-    blocks in the model land in — forward ops carry e.g.
-    ``jit(step)/attn/dot_general`` and their backward transposes keep
-    the same scope token, so substring bucketing attributes fwd+bwd
-    together (bucket_by_scope)."""
-    with gzip.open(path, "rt") as f:
-        data = json.load(f)
-    events = data.get("traceEvents", [])
-    plane: Dict[int, str] = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            plane[e["pid"]] = e.get("args", {}).get("name", "")
-    out = []
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        if not plane.get(e.get("pid"), "").startswith("/device:"):
-            continue
-        args = e.get("args", {}) or {}
-        if "tf_op" not in args and "hlo_category" not in args:
-            continue  # module-level envelope events, copies to host, …
-        if str(args.get("hlo_category", "")) in (
-            "while", "conditional", "call", "fusion envelope",
-        ):
-            # Control-flow ENVELOPE spans contain their body ops, which
-            # the trace also reports individually — keeping both would
-            # double-count every scan body (measured: the grad-accum +
-            # layer-scan whiles alone are ~62% of raw span time).
-            continue
-        out.append({
-            "name": str(e.get("name", ""))[:120],
-            "scope": str(args.get("tf_op", "")),
-            "category": str(args.get("hlo_category", "")),
-            "dur_us": float(e.get("dur", 0)),
-            "flops": float(args.get("model_flops", 0) or 0),
-            "bytes": float(args.get("bytes_accessed", 0) or 0),
-        })
-    return out
-
-
-def capture_op_profile(capture_s: float = 1.0) -> List[Dict]:
-    """Capture a trace window and return the per-op profile
-    (parse_op_profile rows) of whatever ran on device during it."""
-    return _capture_trace(parse_op_profile, capture_s)
-
-
-def bucket_by_scope(
-    ops: List[Dict], buckets: Dict[str, Tuple[str, ...]]
-) -> Dict[str, float]:
-    """Share of device-busy time per scope bucket.
-
-    ``buckets`` maps bucket name -> substrings matched (first hit wins,
-    in dict order) against each op's jax name-stack path; unmatched time
-    lands in "other". Returns fractional shares summing to ~1.0 (empty
-    input: {}).
-    """
-    totals = {name: 0.0 for name in buckets}
-    totals["other"] = 0.0
-    for op in ops:
-        scope = op.get("scope", "") or op.get("name", "")
-        for name, keys in buckets.items():
-            if any(k in scope for k in keys):
-                totals[name] += op["dur_us"]
-                break
-        else:
-            totals["other"] += op["dur_us"]
-    grand = sum(totals.values())
-    if grand <= 0:
-        return {}
-    return {k: v / grand for k, v in totals.items()}
 
 
 def _base_name(name: str) -> str:

@@ -272,7 +272,7 @@ def make_train_step(
 
     # The raw jit object, for AOT compilation (``run.jitted.lower(
     # abstract_state, abstract_batch).compile()``) — restart paths
-    # overlap the compile with the restore H2D (bench_e2e.py).
+    # overlap the compile with the restore H2D.
     run.jitted = jitted
     return run, specs
 

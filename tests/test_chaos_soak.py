@@ -4,8 +4,7 @@ on CPU, with all invariants asserted — the recovery paths run in CI's
 slow lane, not just on demand (docs/DESIGN.md §26).
 
 The full three-episode matrix (torn shard writes, serving step errors,
-...) runs via ``python tools/chaos_soak.py --seed 0 --episodes 3`` and
-as bench.py's ``chaos_goodput`` phase.
+...) runs via ``python tools/chaos_soak.py --seed 0 --episodes 3``.
 """
 
 import pytest

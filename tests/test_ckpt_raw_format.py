@@ -669,22 +669,3 @@ def test_elastic_trainer_restore_adopts_step(tmp_path):
     finally:
         ckpt._engine._shm.unlink()
         ckpt.close()
-
-
-def test_bench_ckpt_io_smoke():
-    import sys
-
-    sys.path.insert(
-        0,
-        os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools"),
-    )
-    import bench_ckpt_io
-
-    out = bench_ckpt_io.run_bench(total_mb=8, procs=2, leaves=2)
-    for key in (
-        "persist_raw_mb_per_s",
-        "restore_raw_mb_per_s",
-        "restore_npz_mb_per_s",
-        "restore_speedup_vs_npz",
-    ):
-        assert out[key] > 0, out

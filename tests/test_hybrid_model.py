@@ -47,6 +47,23 @@ def tiny():
     return cfg, params, buffers, tokens
 
 
+@pytest.fixture(scope="module")
+def tiny_step(tiny):
+    """The train step of ``tiny`` at the default ``TrainConfig`` on the
+    one-axis mesh, traced ONCE for the tests that run it, read its
+    metrics or hash its text: ``(mesh, state, step, lowered)``. The step
+    donates nothing, so ``state`` stays the initial one."""
+    cfg, _, _, tokens = tiny
+    mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
+    tc = ts.TrainConfig(warmup_steps=2)
+    opt = ts.make_optimizer(tc)
+    state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
+    step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
+    with mesh:
+        lowered = step.jitted.lower(state, {"tokens": tokens})
+    return mesh, state, step, lowered
+
+
 def test_loss_and_gradients_match_the_reference(tiny):
     cfg, params, buffers, tokens = tiny
     (loss, aux), grads = jax.jit(jax.value_and_grad(
@@ -209,16 +226,14 @@ def test_a_skewed_router_drops_nothing(hot):
         np.testing.assert_allclose(a, r, atol=5e-5)
 
 
-def test_train_step_carries_counters_and_leaves_the_bias_alone(tiny):
+def test_train_step_carries_counters_and_leaves_the_bias_alone(
+        tiny, tiny_step):
     """Through make_train_step / init_train_state on the one-axis mesh:
     the step's metrics hold the model's counters, the score-correction
     bias gets no optimizer state and no update, the weights do move."""
     cfg, _, _, tokens = tiny
     assert model_for(cfg) is hybrid
-    mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
-    tc = ts.TrainConfig(warmup_steps=2)
-    opt = ts.make_optimizer(tc)
-    state, specs = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
+    _, state, step, _ = tiny_step
     assert set(state) == {"params", "opt_state", "step", "buffers"}
     n_params = len(jax.tree_util.tree_leaves(state["params"]))
     moments = [
@@ -227,7 +242,6 @@ def test_train_step_carries_counters_and_leaves_the_bias_alone(tiny):
     ]
     assert len(moments) == 2 * n_params       # Adam's m and v, no more
     before = jax.device_get((state["buffers"], state["params"]["lm_head"]))
-    step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
     for _ in range(3):
         state, metrics = step(state, {"tokens": tokens})
     assert set(metrics) == {"loss", "grad_norm", "step", *hybrid.COUNTERS}
@@ -247,17 +261,17 @@ def test_the_heads_are_walked_in_groups_that_fit(heads, seq, groups):
     assert kda.head_groups(heads, seq) == groups
 
 
-def test_grad_accum_sums_the_counters(tiny):
+def test_grad_accum_sums_the_counters(tiny, tiny_step):
     cfg, _, _, tokens = tiny
-    mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
-    outs = []
-    for accum in (1, 2):
-        tc = ts.TrainConfig(warmup_steps=2, grad_accum=accum)
-        opt = ts.make_optimizer(tc)
-        state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
-        step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
-        outs.append(step(state, {"tokens": tokens})[1])
-    one, two = outs
+    mesh, state, step, _ = tiny_step
+    one = step(state, {"tokens": tokens})[1]
+    # The same initial state (the optimizer's is the same tree) through
+    # the step that walks the batch in two halves.
+    tc = ts.TrainConfig(warmup_steps=2, grad_accum=2)
+    halves, _ = ts.make_train_step(
+        cfg, tc, ts.make_optimizer(tc), mesh, donate=False
+    )
+    two = halves(state, {"tokens": tokens})[1]
     assert int(one["moe_rows_held"]) == int(two["moe_rows_held"])
     assert float(one["loss"]) == pytest.approx(float(two["loss"]), rel=1e-5)
 
@@ -296,7 +310,8 @@ def test_a_pattern_must_name_kinds_that_exist():
     ))
 
 
-def test_the_train_step_lowers_to_the_text_it_had_before_expert_serving(tiny):
+def test_the_train_step_lowers_to_the_text_it_had_before_expert_serving(
+        tiny_step):
     """PR 33 split ``moe_mlp_share`` so that a served model routes for
     itself (``moe.routed_experts``); the trained share keeps its
     signature and, held here, its program: this step's lowered text is
@@ -305,16 +320,7 @@ def test_the_train_step_lowers_to_the_text_it_had_before_expert_serving(tiny):
     hybrid train step replaces the digest."""
     import hashlib
 
-    cfg, _, _, tokens = tiny
-    mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
-    tc = ts.TrainConfig(warmup_steps=2)
-    opt = ts.make_optimizer(tc)
-    state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
-    step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
-    with mesh:
-        text = step.jitted.lower(
-            state, {"tokens": jnp.zeros((2, 81), jnp.int32)}
-        ).as_text()
+    text = tiny_step[3].as_text()      # lowered for int32 [2, 81] tokens
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         "8d3d698e6e67c3b3"
     out, counters = moe.moe_mlp_share(

@@ -47,12 +47,30 @@ def tiny():
     return (cfg,) + _state(cfg)
 
 
-def test_both_losses_and_every_gradient_match_the_reference(tiny):
+@pytest.fixture(scope="module")
+def tiny_grads(tiny):
+    """``tiny``'s loss, its parts and every gradient leaf, compiled once
+    for the tests that hold something against them."""
     cfg, params, buffers, tokens = tiny
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda p: hybrid.loss_fn(cfg, p, {"tokens": tokens}, buffers),
         has_aux=True,
     ))(params)
+
+
+@pytest.fixture(scope="module")
+def reference_grads(tiny):
+    """``jax.grad`` of the reference's whole graph at ``tiny``."""
+    cfg, params, buffers, tokens = tiny
+    return jax.jit(jax.grad(
+        lambda p: reference.loss(p, buffers, tokens, _spec(cfg))
+    ))(params)
+
+
+def test_both_losses_and_every_gradient_match_the_reference(
+        tiny, tiny_grads, reference_grads):
+    cfg, params, buffers, tokens = tiny
+    (loss, aux), grads = tiny_grads
     ref_main, ref_mtp = reference.batch_losses(
         params, buffers, np.asarray(tokens), _spec(cfg)
     )
@@ -61,11 +79,8 @@ def test_both_losses_and_every_gradient_match_the_reference(tiny):
     assert float(loss) == pytest.approx(
         ref_main + cfg.mtp_weight * ref_mtp, rel=2e-6
     )
-    ref_grads = jax.jit(jax.grad(
-        lambda p: reference.loss(p, buffers, tokens, _spec(cfg))
-    ))(params)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    ref_flat = jax.tree_util.tree_leaves(ref_grads)
+    ref_flat = jax.tree_util.tree_leaves(reference_grads)
     assert len(flat) == len(ref_flat) == 51
     names = [jax.tree_util.keystr(path) for path, _ in flat]
     # The module's own leaves, and the two arrays both losses reach.
@@ -82,7 +97,8 @@ def test_both_losses_and_every_gradient_match_the_reference(tiny):
     assert 0 < int(c["mtp_moe_rows_held"]) < int(c["moe_rows_held"])
 
 
-def test_the_layer_walk_gives_the_same_gradient_as_the_whole_graph(tiny):
+def test_the_layer_walk_gives_the_same_gradient_as_the_whole_graph(
+        tiny, reference_grads):
     """``batch_loss_and_grads`` (a layer's pullback at a time, on the
     host: what the chip's ``correct`` reads) against ``jax.grad`` of the
     reference's whole graph."""
@@ -94,14 +110,11 @@ def test_the_layer_walk_gives_the_same_gradient_as_the_whole_graph(tiny):
         params, buffers, np.asarray(tokens), _spec(cfg)
     )
     assert (main, mtp) == pytest.approx(want, rel=1e-6)
-    whole = jax.jit(jax.grad(
-        lambda p: reference.loss(p, buffers, tokens, _spec(cfg))
-    ))(params)
     assert jax.tree_util.tree_structure(walked) == (
-        jax.tree_util.tree_structure(whole)
+        jax.tree_util.tree_structure(reference_grads)
     )
     for a, b in zip(jax.tree_util.tree_leaves(walked),
-                    jax.tree_util.tree_leaves(whole)):
+                    jax.tree_util.tree_leaves(reference_grads)):
         np.testing.assert_allclose(
             a, b, rtol=0, atol=2e-5 * float(jnp.max(jnp.abs(b))) + 1e-9
         )
@@ -149,7 +162,9 @@ def test_the_reference_rotation_is_the_pairwise_one():
 def test_the_bottleneck_is_the_latent_models_own():
     """One function for the two models that have the bottleneck."""
     cfg = latent_lm.tiny_config()
-    params = latent_lm.init_params(cfg, jax.random.key(0))
+    params = jax.jit(lambda key: latent_lm.init_params(cfg, key))(
+        jax.random.key(0)
+    )
     p = latent_lm.layer_params(params, 0)
     h = jax.random.normal(
         jax.random.key(1), (1, 9, cfg.embed_dim), cfg.compute_dtype
@@ -228,16 +243,17 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(block):
 
 
 @pytest.mark.parametrize("remat_keep", hybrid.REMAT_KEEP)
-def test_what_a_block_keeps_does_not_change_the_gradient(tiny, remat_keep):
+def test_what_a_block_keeps_does_not_change_the_gradient(
+        tiny, tiny_grads, remat_keep):
     cfg, params, buffers, tokens = tiny
     import dataclasses
 
     other = dataclasses.replace(cfg, remat_keep=remat_keep)
-    grad = lambda c: jax.jit(jax.grad(  # noqa: E731
-        lambda p: hybrid.loss_fn(c, p, {"tokens": tokens}, buffers)[0]
+    kept = jax.jit(jax.grad(
+        lambda p: hybrid.loss_fn(other, p, {"tokens": tokens}, buffers)[0]
     ))(params)
-    for a, b in zip(jax.tree_util.tree_leaves(grad(cfg)),
-                    jax.tree_util.tree_leaves(grad(other))):
+    for a, b in zip(jax.tree_util.tree_leaves(tiny_grads[1]),
+                    jax.tree_util.tree_leaves(kept)):
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     with pytest.raises(ValueError):
         dataclasses.replace(cfg, remat_keep="everything")
@@ -245,22 +261,34 @@ def test_what_a_block_keeps_does_not_change_the_gradient(tiny, remat_keep):
         dataclasses.replace(cfg, mtp_depth=2)
 
 
-@pytest.mark.parametrize("grad_accum", [1, 2])
-def test_the_step_reports_the_two_losses_and_the_modules_rows(grad_accum):
+@pytest.fixture(scope="module")
+def one_period():
+    """A dense block, one expert block and the module, 14 (+2) tokens:
+    the config, a batch, the one-axis mesh, a fresh train state (the
+    steps below donate nothing) and the loss's parts as one program."""
     cfg = hybrid.tiny_config(
         mtp_depth=1, experts_held=(4, 4), **dict(PATTERN, n_periods=1)
     )
-    tokens = _state(cfg, seq=14)[2]
     mesh = build_mesh(MeshConfig(dp=1), jax.devices()[:1])
-    tc = ts.TrainConfig(warmup_steps=1, grad_accum=grad_accum)
-    opt = ts.make_optimizer(tc)
+    opt = ts.make_optimizer(ts.TrainConfig(warmup_steps=1))
     state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
-    bias = jax.tree_util.tree_map(np.asarray, state["buffers"])
-    step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
-    new, metrics = step(state, {"tokens": tokens})
-    auxes = [jax.jit(lambda p, b, t: hybrid.loss_fn(
+    parts = jax.jit(lambda p, b, t: hybrid.loss_fn(
         cfg, p, {"tokens": t}, b
-    )[1])(state["params"], state["buffers"], t) for t in (
+    )[1])
+    return cfg, _state(cfg, seq=14)[2], mesh, state, parts
+
+
+@pytest.mark.parametrize("grad_accum", [1, 2])
+def test_the_step_reports_the_two_losses_and_the_modules_rows(
+        one_period, grad_accum):
+    cfg, tokens, mesh, state, parts = one_period
+    tc = ts.TrainConfig(warmup_steps=1, grad_accum=grad_accum)
+    bias = jax.tree_util.tree_map(np.asarray, state["buffers"])
+    step, _ = ts.make_train_step(
+        cfg, tc, ts.make_optimizer(tc), mesh, donate=False
+    )
+    new, metrics = step(state, {"tokens": tokens})
+    auxes = [parts(state["params"], state["buffers"], t) for t in (
         tokens.reshape(grad_accum, -1, tokens.shape[1])
     )]
     assert set(hybrid.COUNTERS) | {"ce", "ce_mtp", hybrid.MTP_COUNTER} <= (
@@ -293,7 +321,10 @@ def test_a_loss_of_one_part_reports_no_parts():
     opt = ts.make_optimizer(tc)
     state, _ = ts.init_train_state(cfg, opt, mesh, jax.random.key(0))
     step, _ = ts.make_train_step(cfg, tc, opt, mesh, donate=False)
-    _, metrics = step(state, {"tokens": _state(cfg, seq=14)[2]})
+    with mesh:      # what the step reports is in its trace: no compile
+        _, metrics = jax.eval_shape(
+            step.jitted, state, {"tokens": _state(cfg, seq=14)[2]}
+        )
     assert "ce" not in metrics and hybrid.MTP_COUNTER not in metrics
     assert set(hybrid.COUNTERS) <= set(metrics)
 

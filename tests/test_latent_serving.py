@@ -31,10 +31,17 @@ from tests.benchmark import tiny_xing
 BS, CHUNK = 4, 8
 
 
+def seeded_params(cfg, seed):
+    """One program a model, not one a leaf's shape."""
+    return jax.jit(lambda key: latent_lm.init_params(cfg, key))(
+        jax.random.key(seed)
+    )
+
+
 @pytest.fixture(scope="module")
 def tiny():
     cfg = latent_lm.tiny_config()
-    return cfg, latent_lm.init_params(cfg, jax.random.key(0))
+    return cfg, seeded_params(cfg, 0)
 
 
 def cfg_json_of(cfg):
@@ -75,6 +82,28 @@ def serve(eng, items):
         eng.step()
     eng.check_block_invariants()
     return [list(r.tokens) for r in reqs]
+
+
+def greedy_of_the_full_forward(cfg, params):
+    """``want(prompt, out)``: the plain forward's greedy tokens at the
+    positions that emitted ``out``, and the rows it dropped. ONE program
+    a model: a sequence is padded to 64 tokens, which a causal model's
+    earlier positions cannot see."""
+    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t))
+
+    def want(prompt, out):
+        tokens = np.zeros((1, 64), np.int32)
+        tokens[0, :len(prompt) + len(out)] = prompt + out
+        logits, dropped = fwd(jnp.asarray(tokens))
+        rows = slice(len(prompt) - 1, len(prompt) + len(out) - 1)
+        return np.asarray(logits)[0, rows].argmax(-1).tolist(), int(dropped)
+
+    return want
+
+
+@pytest.fixture(scope="module")
+def full_forward(tiny):
+    return greedy_of_the_full_forward(*tiny)
 
 
 def reference_logits(cfg, params, seq, rows):
@@ -142,14 +171,21 @@ def test_engine_logits_are_the_references_past_the_yarn_range(tiny):
     pool = layout.fresh(layout.pool_arrays(cfg)[0], cfg.n_layers, 40, BS)
     table = jnp.arange(1, max_blocks + 1, dtype=jnp.int32)
     served = latent_lm.prepare_decode_params(cfg, params)
+    # Each program compiled once, as the engine has it: its chunks and
+    # its steps differ in traced values alone.
+    chunk_forward = jax.jit(lambda pool, tokens, start: latent.chunk_forward(
+        cfg, pool, served, tokens, table, start, BS
+    ))
+    decode_forward = jax.jit(lambda pool, at, token: latent.decode_forward(
+        cfg, pool, served, table[None], at, token, BS
+    ))
     got = []
     for start in range(0, len(prompt), CHUNK):
         piece = prompt[start:start + CHUNK]
         tokens = np.zeros((1, CHUNK), np.int32)
         tokens[0, :len(piece)] = piece
-        streams, rows = latent.chunk_forward(
-            cfg, pool, served, jnp.asarray(tokens), table, jnp.int32(start),
-            BS,
+        streams, rows = chunk_forward(
+            pool, jnp.asarray(tokens), jnp.int32(start)
         )
         pool = pool.land_run(rows, table, start, BS, 0)
     got.append(np.asarray(latent_lm.unembed_streams(
@@ -159,9 +195,9 @@ def test_engine_logits_are_the_references_past_the_yarn_range(tiny):
     for _ in range(8):
         seq.append(int(got[-1].argmax()))
         at = len(seq) - 1
-        logits, rows, moe = latent.decode_forward(
-            cfg, pool, served, table[None], jnp.asarray([at], jnp.int32),
-            jnp.asarray([seq[-1]], jnp.int32), BS,
+        logits, rows, moe = decode_forward(
+            pool, jnp.asarray([at], jnp.int32),
+            jnp.asarray([seq[-1]], jnp.int32),
         )
         pool = pool.land_tokens(
             rows, table[at // BS][None], jnp.asarray([at % BS])
@@ -175,31 +211,46 @@ def test_engine_logits_are_the_references_past_the_yarn_range(tiny):
     assert (np.stack(got).argmax(-1) == want.argmax(-1)).all()
 
 
+def own_programs(monkeypatch):
+    """From here to the test's end, engines build their programs into a
+    cache of the test's own: what a test compiles under a patched tile
+    size or platform probe (no part of a program's key) serves no other
+    test, and the programs the other tests share stay compiled."""
+    import functools
+
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    monkeypatch.setattr(paged, "_paged_steps_for", functools.lru_cache(
+        maxsize=16
+    )(paged._paged_steps_for.__wrapped__))
+
+
 @pytest.fixture
 def take_the_pool_kernel(monkeypatch):
     """``take(tile_rows)``: from here on the decode program is built as
     on a TPU: the platform probe says so (the kernel then runs in
     interpret mode) and the predicate admits the tiny model's float32
     pool and narrow rows; ``tile_rows``: device rows a VMEM tile holds,
-    so that a slot's pages are several tiles. The engines' programs are
-    dropped afterwards: the tile's size is no part of their key."""
+    so that a slot's pages are several tiles. The engines' programs
+    built from here on are the test's own (``own_programs``)."""
     from dlrover_tpu.ops import latent_decode_attention as lda
     from dlrover_tpu.serving.kvpool import engine as paged
 
     def take(tile_rows):
+        own_programs(monkeypatch)
         monkeypatch.setattr(paged, "_on_tpu", lambda: True)
         monkeypatch.setattr(lda, "latent_kernel_supported", lambda *a: True)
         monkeypatch.setattr(lda, "TILE_ROWS", tile_rows)
 
-    yield take
-    paged._paged_steps_for.cache_clear()
+    return take
 
 
 KINDS = ("gathered_view", "pool_kernel")
 
 
 @pytest.mark.parametrize("kind", KINDS)
-def test_served_tokens_are_the_full_forwards(tiny, kind, take_the_pool_kernel):
+def test_served_tokens_are_the_full_forwards(
+        tiny, full_forward, kind, take_the_pool_kernel):
     """Once with the decode step's rows gathered (what a CPU builds),
     once read by the Pallas kernel over the pool in place (what a TPU
     builds; here interpreted, two pages a tile): the greedy tokens are
@@ -219,12 +270,10 @@ def test_served_tokens_are_the_full_forwards(tiny, kind, take_the_pool_kernel):
     traced = dict(eng.trace_counts)
     tokens = serve(eng, items)
     assert dict(eng.trace_counts) == traced      # no retrace after warm-up
-    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t))
     for (prompt, n), out in zip(items, tokens):
-        logits, dropped = fwd(jnp.asarray([prompt + out]))
-        want = np.asarray(logits)[0, len(prompt) - 1:-1].argmax(-1)
-        assert out == want.tolist() and len(out) == n
-        assert int(dropped) == 0
+        want, dropped = full_forward(prompt, out)
+        assert out == want and len(out) == n
+        assert dropped == 0
     assert eng.kv_stats()["moe_rows_dropped"] == 0
 
 
@@ -270,17 +319,15 @@ def query_tiles_of(monkeypatch):
     walked in tiles of ``rows`` and its prefix in blocks of
     ``prefix_rows`` (the module's constants are sized for 512-row chunks
     over 16k rows; a CPU test's chunk of 8 or 16 is one tile of them).
-    The engines' programs are dropped before and afterwards: the tile's
-    size is no part of their key."""
-    from dlrover_tpu.serving.kvpool import engine as paged
+    The engines' programs built from here on are the test's own
+    (``own_programs``)."""
 
     def tiles(rows, prefix_rows=latent.CHUNK_PREFIX_ROWS):
-        paged._paged_steps_for.cache_clear()
+        own_programs(monkeypatch)
         monkeypatch.setattr(latent, "CHUNK_QUERY_ROWS", rows)
         monkeypatch.setattr(latent, "CHUNK_PREFIX_ROWS", prefix_rows)
 
-    yield tiles
-    paged._paged_steps_for.cache_clear()
+    return tiles
 
 
 @pytest.fixture(scope="module")
@@ -305,9 +352,13 @@ def one_layers_chunk(tiny):
     rows = jnp.zeros((cfg.n_layers, n, cfg.cache_width)).at[1].set(row[0])
     pool = pool.land_run(rows, table, 0, BS, 0)
 
+    programs = {}
+
     def attend(start, chunk, n_valid=None):
         """``chunk_attend`` of rows ``start .. start + chunk``, jitted
-        with ``n_valid`` traced (as the prefill program has it)."""
+        with ``n_valid`` traced (as the prefill program has it): one
+        program a (start, chunk, whole or not) under the tiles in
+        force, whatever ``n_valid`` is."""
         at = slice(start, start + chunk)
 
         def run(pool, n_valid):
@@ -315,9 +366,15 @@ def one_layers_chunk(tiny):
                 cfg, pool, 1, table, start, BS, n_valid
             )(p, q_nope[:, at], q_rope[:, at], row[:, at])[0]
 
+        key = (start, chunk, n_valid is None, latent.CHUNK_QUERY_ROWS,
+               latent.CHUNK_PREFIX_ROWS)
+        if key not in programs:
+            programs[key] = jax.jit(
+                (lambda pool: run(pool, None)) if n_valid is None else run
+            )
         if n_valid is None:
-            return np.asarray(jax.jit(lambda pool: run(pool, None))(pool))
-        return np.asarray(jax.jit(run)(pool, jnp.int32(n_valid)))
+            return np.asarray(programs[key](pool))
+        return np.asarray(programs[key](pool, jnp.int32(n_valid)))
 
     return attend, np.asarray(want)
 
@@ -382,7 +439,7 @@ def test_the_account_of_rows_scored_is_the_programs_trip_count(
 
 
 def test_a_short_last_chunk_serves_the_full_forwards_tokens(
-        tiny, query_tiles_of):
+        tiny, full_forward, query_tiles_of):
     """Chunks of 8 in tiles of 4: last chunks of 5, 5, 6 and 1 valid
     rows leave a tile out or run both, middle chunks run both, and the
     greedy tokens are the plain forward's."""
@@ -397,11 +454,8 @@ def test_a_short_last_chunk_serves_the_full_forwards_tokens(
     traced = dict(eng.trace_counts)
     tokens = serve(eng, items)
     assert dict(eng.trace_counts) == traced      # n_valid is an input
-    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t))
     for (prompt, n), out in zip(items, tokens):
-        logits, _ = fwd(jnp.asarray([prompt + out]))
-        want = np.asarray(logits)[0, len(prompt) - 1:-1].argmax(-1)
-        assert out == want.tolist() and len(out) == n
+        assert out == full_forward(prompt, out)[0] and len(out) == n
 
 
 # A decode batch's fills, a slot each (blocks of BS = 4 tokens, tables of
@@ -422,14 +476,28 @@ _BATCHES = {
 }
 
 
+def landing(cfg, sequence):
+    def land(pool, table, fill):
+        rows = jnp.where(
+            jnp.arange(len(sequence))[:, None] < fill, sequence, 0.0
+        )
+        layers = jnp.zeros((cfg.n_layers,) + sequence.shape)
+        return pool.land_run(layers.at[1].set(rows), table, 0, BS, 0)
+
+    return land
+
+
 @pytest.fixture(scope="module")
 def latent_batches():
-    """Per tokens-a-row: a config, one layer's weights and one sequence's
-    attention inputs with attention as written over it."""
+    """Per tokens-a-row: a config, one layer's weights, one sequence's
+    attention inputs with attention as written over it, and ``land(pool,
+    table, fill)``: the sequence's first ``fill`` rows in the pages of
+    ``table`` (one program whatever the fill: all 32 rows a table holds
+    are landed, those past the fill as the zeros a fresh pool has)."""
     out = {}
     for pack, kw in ((1, {}), (2, dict(kv_lora_rank=128, qk_rope_dim=64))):
         cfg = latent_lm.tiny_config(**kw)
-        params = latent_lm.init_params(cfg, jax.random.key(pack))
+        params = seeded_params(cfg, pack)
         p = latent_lm.layer_params(params, 1)
         n = 33
         h = jnp.asarray(
@@ -441,7 +509,8 @@ def latent_batches():
         want = latent_lm.definition_attention(
             cfg, p, q_nope[0], q_rope[0], row[0]
         )
-        out[pack] = cfg, p, q_nope[0], q_rope[0], row[0], want
+        out[pack] = (cfg, p, q_nope[0], q_rope[0], row[0], want,
+                     jax.jit(landing(cfg, row[0, :32])))
     return out
 
 
@@ -456,7 +525,7 @@ def test_the_pool_kernel_is_the_gathered_view_over_a_ragged_batch(
     gives and what attention as written gives at that token, and its own
     scores are the gathered form's over the visible rows, zero past
     them."""
-    cfg, p, q_nope, q_rope, row, want = latent_batches[pack]
+    cfg, p, q_nope, q_rope, row, want, land = latent_batches[pack]
     fills = np.asarray(_BATCHES[batch])
     slots, max_blocks = len(fills), 8
     pool = layout.fresh(
@@ -467,12 +536,7 @@ def test_the_pool_kernel_is_the_gathered_view_over_a_ragged_batch(
         slots, max_blocks
     )[:, ::-1]
     for i, fill in enumerate(fills):
-        if not fill:
-            continue
-        rows = jnp.zeros((cfg.n_layers, fill, cfg.cache_width))
-        pool = pool.land_run(
-            rows.at[1].set(row[:fill]), jnp.asarray(tables[i]), 0, BS, 0
-        )
+        pool = land(pool, jnp.asarray(tables[i]), jnp.int32(fill))
     tables[1] = 0        # an inactive slot: the sentinel block's rows
     at = jnp.asarray(fills)
     args = (p, q_nope[at][:, None], q_rope[at][:, None], row[at][:, None])
@@ -510,7 +574,7 @@ def test_the_kernels_scores_are_the_modules_own(
     their precision that the gathered form and the chunk share: scores
     rounded THERE (what the harness's bfloat16-scores control plants) are
     the scores the kernel hands out and attends with."""
-    cfg, p, q_nope, q_rope, row, _ = latent_batches[2]
+    cfg, p, q_nope, q_rope, row, _, _ = latent_batches[2]
     fill, max_blocks = 21, 8
     pool = layout.fresh(
         layout.pool_arrays(cfg)[0], cfg.n_layers, max_blocks + 1, BS
@@ -633,17 +697,16 @@ def test_a_packed_pool_serves_what_a_bare_one_serves():
     384-lane row, and everything (decode, chunk, landings, COW, prefix
     sharing) reads it by token coordinates."""
     cfg = latent_lm.tiny_config(kv_lora_rank=128, qk_rope_dim=64)
-    params = latent_lm.init_params(cfg, jax.random.key(1))
+    params = seeded_params(cfg, 1)
     items = list(zip(prompts(cfg, (33, 18, 26), 11), (6, 7, 5)))
     packed = engine(cfg, params)
     assert packed._latent.pack == 2
     assert packed._latent.rows.shape[-2:] == (BS // 2, 384)
     assert packed._latent.shape == (cfg.n_layers, 60, BS, 192)
     got = serve(packed, items)
-    fwd = jax.jit(lambda t: latent_lm.forward(cfg, params, t)[0])
+    want = greedy_of_the_full_forward(cfg, params)
     for (prompt, _), out in zip(items, got):
-        logits = np.asarray(fwd(jnp.asarray([prompt + out])))[0]
-        assert out == logits[len(prompt) - 1:-1].argmax(-1).tolist()
+        assert out == want(prompt, out)[0]
     bare = IndexKeyPool.of(jnp.zeros((cfg.n_layers, 60, BS, 192)))
     assert bare.pack == 1
 
@@ -674,7 +737,7 @@ def test_h_res_is_doubly_stochastic_and_one_stream_is_the_plain_residual(tiny):
     assert ((np.asarray(maps.post) > 0) & (np.asarray(maps.post) < 2)).all()
     # n = 1, the maps forced to 1: x + f(norm(x)), sublayer by sublayer
     one = dataclasses.replace(cfg, hc_mult=1, n_layers=2)
-    p1 = latent_lm.init_params(one, jax.random.key(2))
+    p1 = seeded_params(one, 2)
     unit = latent_lm.ResidualMaps(
         pre=jnp.ones((1, 5, 1)), post=jnp.ones((1, 5, 1)),
         res=jnp.ones((1, 5, 1, 1)),
@@ -703,13 +766,12 @@ def test_the_expert_layer_gives_a_token_one_output_in_every_program(tiny):
     cfg, params = tiny
     rng = np.random.default_rng(4)
     h = jnp.asarray(rng.normal(size=(40, cfg.embed_dim)), jnp.float32)
-    whole, c = latent_lm.feed(cfg, params, jnp.int32(2), h[None])
+    feed = jax.jit(lambda h: latent_lm.feed(cfg, params, jnp.int32(2), h))
+    whole, c = feed(h[None])
     assert int(c.rows_dropped) == 0
-    chunk, c = latent_lm.feed(cfg, params, jnp.int32(2), h[None, 16:24])
+    chunk, c = feed(h[None, 16:24])
     np.testing.assert_allclose(chunk[0], whole[0, 16:24], atol=1e-6)
-    step, c = latent_lm.feed(
-        cfg, params, jnp.int32(2), h[jnp.asarray([16, 3, 30])][:, None]
-    )
+    step, c = feed(h[jnp.asarray([16, 3, 30])][:, None])
     np.testing.assert_allclose(step[:, 0], whole[0, [16, 3, 30]], atol=1e-6)
     assert int(c.rows_dropped) == 0 and 1 <= int(c.experts_hit) <= 6
     # against the reference's every-expert-computes-every-token form

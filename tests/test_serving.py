@@ -217,33 +217,3 @@ def test_serving_metrics_land_in_registry(tiny):
     assert reg.get("serving_ttft_seconds").count() == 1
     assert reg.get("serving_retraces_total").value() == 0
     assert reg.get("serving_slots_total").value() == 2
-
-
-# ---- slow A/B: continuous batching must actually win ------------------------
-
-
-@pytest.mark.slow
-def test_bench_serving_speedup():
-    import os
-    import sys
-
-    sys.path.insert(
-        0,
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools"),
-    )
-    import bench_serving
-
-    r = bench_serving.run_bench(slots=4, n_requests=24, max_len=224,
-                                prefill_chunk=16)
-    assert r["retraces_after_warmup"] == 0
-    assert r["speedup_vs_static"] >= 1.5, r
-    assert r["ttft_p99_s"] <= r["static_ttft_p99_s"], r
-    # §31 equal-HBM acceptance: the paged pool admits strictly more
-    # effective concurrent slots, the prefix cache actually hits, and
-    # paged decode is token-exact (asserted inside run_paged_ab too).
-    assert r["kv_effective_slots"] > r["flat_effective_slots"], r
-    assert r["prefix_hit_rate"] > 0, r
-    assert r["paged_token_exact"] == 1 and (
-        r["paged_retraces_after_warmup"] == 0
-    ), r

@@ -676,24 +676,3 @@ def test_trace_query_summary_and_critical_path(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "critical path" in out
-
-
-# ---------------------------------------------------------------------------
-# Serving bench A/B hook (tiny workload: the wiring, not the numbers)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_serving_reports_tracing_overhead():
-    _tools_on_path()
-    import bench_serving
-
-    out = bench_serving.run_bench(
-        slots=2, n_requests=6, max_len=64, prefill_chunk=8,
-    )
-    assert "tracing_overhead_pct" in out
-    assert out["traced_tokens_per_s"] > 0
-    # Generous bound for a noisy shared box; the bench phase reports
-    # the real number against the <2% budget.
-    assert out["tracing_overhead_pct"] < 50.0
-    assert tracing.active_tracer() is None  # A/B disarms after itself

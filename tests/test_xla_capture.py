@@ -18,10 +18,8 @@ import pytest
 from dlrover_tpu.tpu_timer import get_timer
 from dlrover_tpu.tpu_timer.xla_capture import (
     XlaCaptureListener,
-    bucket_by_scope,
     capture_device_events,
     parse_chrome_trace,
-    parse_op_profile,
     record_events,
     request_xla_capture,
 )
@@ -56,73 +54,6 @@ def test_parse_chrome_trace(tmp_path):
     by_name = {e[0]: e for e in events}
     assert by_name["jit_matmul(123)"][1] is True  # device plane
     assert by_name["PjRtCpuClient::Compile"][1] is False
-
-
-def test_parse_op_profile_and_bucketing(tmp_path):
-    """Scope attribution: per-op tf_op metadata buckets device time into
-    model components, forward and backward (transpose) alike."""
-    trace = {
-        "traceEvents": [
-            {"ph": "M", "pid": 3, "name": "process_name",
-             "args": {"name": "/device:TPU:0 (...)"}},
-            {"ph": "M", "pid": 7, "name": "process_name",
-             "args": {"name": "/host:CPU"}},
-            # forward attention matmul
-            {"ph": "X", "pid": 3, "name": "convolution_fusion.1",
-             "ts": 0.0, "dur": 30.0,
-             "args": {"tf_op": "jit(step)/attn/dot_general:",
-                      "hlo_category": "convolution fusion",
-                      "model_flops": "1000", "bytes_accessed": "10"}},
-            # backward of the same scope (transpose keeps the token)
-            {"ph": "X", "pid": 3, "name": "fusion.9", "ts": 40.0,
-             "dur": 30.0,
-             "args": {"tf_op":
-                      "jit(step)/transpose(jvp(attn))/dot_general:",
-                      "hlo_category": "convolution fusion"}},
-            {"ph": "X", "pid": 3, "name": "fusion.2", "ts": 80.0,
-             "dur": 25.0,
-             "args": {"tf_op": "jit(step)/mlp/dot_general:",
-                      "hlo_category": "convolution fusion"}},
-            {"ph": "X", "pid": 3, "name": "fusion.3", "ts": 110.0,
-             "dur": 10.0,
-             "args": {"tf_op": "jit(step)/optimizer/mul:",
-                      "hlo_category": "fusion"}},
-            {"ph": "X", "pid": 3, "name": "fusion.4", "ts": 130.0,
-             "dur": 5.0,
-             "args": {"tf_op": "jit(step)/broadcast:",
-                      "hlo_category": "fusion"}},
-            # module envelope (no metadata) and host events: excluded
-            {"ph": "X", "pid": 3, "name": "jit_step(123)",
-             "ts": 0.0, "dur": 140.0, "args": {"run_id": "1"}},
-            {"ph": "X", "pid": 7, "name": "PjRt thing",
-             "ts": 0.0, "dur": 99.0, "args": {"tf_op": "x"}},
-            # control-flow envelope: its body ops are reported above —
-            # keeping it would double-count every scan body
-            {"ph": "X", "pid": 3, "name": "while.222", "ts": 0.0,
-             "dur": 120.0,
-             "args": {"tf_op": "jit(step)/while:",
-                      "hlo_category": "while"}},
-        ]
-    }
-    path = tmp_path / "p.trace.json.gz"
-    with gzip.open(path, "wt") as f:
-        json.dump(trace, f)
-    ops = parse_op_profile(str(path))
-    assert len(ops) == 5  # envelope + host excluded
-    assert ops[0]["flops"] == 1000.0 and ops[0]["bytes"] == 10.0
-    shares = bucket_by_scope(ops, {
-        "attn": ("attn",),
-        "mlp": ("mlp",),
-        "vocab": ("vocab", "lm_head"),
-        "optimizer": ("optimizer",),
-    })
-    assert abs(sum(shares.values()) - 1.0) < 1e-9
-    assert abs(shares["attn"] - 60.0 / 100.0) < 1e-9
-    assert abs(shares["mlp"] - 25.0 / 100.0) < 1e-9
-    assert abs(shares["optimizer"] - 10.0 / 100.0) < 1e-9
-    assert abs(shares["other"] - 5.0 / 100.0) < 1e-9
-    assert shares["vocab"] == 0.0
-    assert bucket_by_scope([], {"attn": ("attn",)}) == {}
 
 
 def _churn(stop):
@@ -161,8 +92,11 @@ def test_trigger_file_drives_capture(tmp_path, monkeypatch):
     listener.start()
     try:
         request_xla_capture(0)
-        deadline = time.time() + 30
-        while time.time() < deadline and listener.captures == 0:
+        # A cap, not a budget: the loop ends at the first capture, and a
+        # profiler session on a host whose every core is busy has taken
+        # more than 30 s (PRs 40, 45).
+        deadline = time.monotonic() + 180
+        while time.monotonic() < deadline and listener.captures == 0:
             time.sleep(0.1)
         assert listener.captures >= 1
     finally:

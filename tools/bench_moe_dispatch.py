@@ -8,7 +8,7 @@ v5e and re-defaulted to ``gmm`` on one reading of this probe; ROADMAP
 S3 decides from it whether ``fused`` wins or goes.
 
 One layer's ``moe_mlp_dropless`` forward + backward (grads of x and the
-three expert weights) at the bench's MoE shape — 8 x 2048 tokens,
+three expert weights) at r05's MoE shape: 8 x 2048 tokens,
 d = f = 1024, 8 experts, top-2 — with seeded random inputs:
 
     python tools/bench_moe_dispatch.py          # on the chip: chiprun -- ...

@@ -37,8 +37,7 @@ exactly-once accounting. The
 implementation and the invariant definitions live in
 ``dlrover_tpu/testing/soak.py`` (docs/DESIGN.md §26-§30); exit code 0
 means every episode held every invariant. Prints one JSON summary line
-with goodput fraction and per-fault MTTR — the same numbers
-``bench.py``'s ``chaos_goodput`` phase reports.
+with goodput fraction and per-fault MTTR.
 """
 
 import argparse

@@ -4,8 +4,7 @@ Loads a §34 SignalRecorder recording (``DLROVER_TPU_AUTOSCALE_RECORD``
 output, or the autoscale soak's), asserts the replay identity invariant
 (the recorded PolicyConfig must reproduce the live ledger decision for
 decision), then replays N candidate policies over the same stream and
-ranks them under the goodput model — actuation costs calibrated from
-the newest bench artifact that carries the keys.
+ranks them under the goodput model (``CostModel``'s default costs).
 
     python tools/whatif.py RECORDING [--candidates cands.json]
                                      [--top 5] [--full]
@@ -14,20 +13,12 @@ the newest bench artifact that carries the keys.
 ...}`` applied over the RECORDED config; without it a built-in spread
 of perturbations (more/less trigger-happy eviction, wider/narrower
 fleet bands, frozen cadence) is ranked. Prints one JSON document.
-
-Also exposes ``run_bench()`` — the ``whatif`` bench phase: a synthetic
-deterministic recording is generated in-process (fake clocks, no
-sleeps), recorded through the real SignalRecorder, replayed for
-identity, and timed for replay throughput (snapshots/s).
 """
 
 import argparse
 import json
 import os
-import shutil
 import sys
-import tempfile
-import time
 from dataclasses import replace
 from typing import Dict, List, Optional, Tuple
 
@@ -46,12 +37,6 @@ from dlrover_tpu.autoscaler import (  # noqa: E402
     SignalRecorder,
     load_recording,
     rank_policies,
-)
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_ARTIFACTS = (
-    os.path.join(_REPO, "BENCH_SELF.json"),
-    os.path.join(_REPO, "BENCH_r05.json"),
 )
 
 
@@ -102,7 +87,7 @@ def rank_recording(
         load_candidates(candidates_path, base)
         if candidates_path else builtin_candidates(base)
     )
-    cost = cost or CostModel.from_bench(BENCH_ARTIFACTS)
+    cost = cost or CostModel()
     result = rank_policies(recording, candidates, cost,
                            with_decisions=with_decisions)
     result["recording"] = {
@@ -116,7 +101,7 @@ def rank_recording(
 
 
 # ---------------------------------------------------------------------------
-# Synthetic recording + the bench phase
+# Synthetic recording (what `tests/test_whatif.py` drives this tool with)
 # ---------------------------------------------------------------------------
 
 
@@ -212,60 +197,18 @@ def synthesize_recording(
     }
 
 
-def run_bench(snapshots: int = 4000, seed: int = 0) -> Dict:
-    """The ``whatif`` bench phase: synthesize → load → identity →
-    throughput → rank. All fake-clock, so the snapshots/s number is
-    pure replay machinery."""
-    tmp = tempfile.mkdtemp(prefix="whatif-bench-")
-    path = os.path.join(tmp, "signals.jsonl")
-    try:
-        # fsync=False: the durability discipline is pointless on a
-        # throwaway temp recording, and 4000 fsyncs on slow storage
-        # would bill the phase for the disk, not the replay machinery.
-        synth = synthesize_recording(path, snapshots=snapshots,
-                                     seed=seed, fsync=False)
-        t0 = time.monotonic()
-        load_recording(path)
-        load_s = time.monotonic() - t0
-        result = rank_recording(path)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    best = result["ranked"][0] if result["ranked"] else {}
-    return {
-        "whatif_snapshots": synth["snapshots"],
-        "whatif_recorded_decisions": synth["decisions"],
-        "whatif_outcomes_recorded": synth["outcomes"],
-        "whatif_identity_ok": bool(result["identity"]["identical"]),
-        "whatif_replay_snapshots_per_s": result[
-            "replay_snapshots_per_s"
-        ],
-        "whatif_load_s": round(load_s, 4),
-        "whatif_candidates": result["candidates"],
-        "whatif_best_candidate": best.get("name"),
-        "whatif_best_est_goodput": best.get("est_goodput_frac"),
-    }
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         description="rank candidate autoscaler policies over a recording"
     )
-    parser.add_argument("recording", nargs="?", default=None,
-                        help="SignalRecorder JSONL path")
+    parser.add_argument("recording", help="SignalRecorder JSONL path")
     parser.add_argument("--candidates", default=None,
                         help="JSON file of {name: config-overrides}")
     parser.add_argument("--top", type=int, default=0,
                         help="print only the best N candidates")
     parser.add_argument("--full", action="store_true",
                         help="include counterfactual decision ledgers")
-    parser.add_argument("--bench", action="store_true",
-                        help="run the synthetic bench instead")
-    parser.add_argument("--snapshots", type=int, default=4000)
     args = parser.parse_args(argv)
-    if args.bench or args.recording is None:
-        print(json.dumps(run_bench(snapshots=args.snapshots)),
-              flush=True)
-        return 0
     result = rank_recording(
         args.recording, candidates_path=args.candidates,
         with_decisions=args.full,
