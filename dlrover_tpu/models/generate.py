@@ -568,11 +568,11 @@ def prepare_decode_params(config, params):
     the MoE router stay f32 (same precision rule as
     llama.run_layer_stack). Pure jnp: generate()'s jitted run calls it
     traced, the serving engine calls it eagerly once per engine."""
-    if getattr(config, "kind", "") == "latent_lm":
+    if getattr(config, "kind", "") in ("latent_lm", "conv_lm"):
         # Its tree is nested and stored as a server reads it.
-        from dlrover_tpu.models import latent_lm
+        from dlrover_tpu.models import model_for
 
-        return latent_lm.prepare_decode_params(config, params)
+        return model_for(config).prepare_decode_params(config, params)
     cdt = config.compute_dtype
     if cdt != jnp.float32:
         keep = {"attn_norm", "mlp_norm", "router", "q_norm", "k_norm",

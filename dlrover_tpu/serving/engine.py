@@ -1099,6 +1099,7 @@ class ServingEngine:
             self._moe_rows_dropped += int(aux[1])
             if self._step_trace is not None:
                 self._step_trace.counts["experts_hit"] = float(aux[0])
+                self._step_trace.counts["expert_rows_dropped"] = int(aux[1])
         if flight.first_row is not None:
             req, slot, end = flight.first_row
             req.first_token_ts = time.monotonic()
@@ -1361,7 +1362,12 @@ class ServingEngine:
             return
         tracer.record_span(
             "serving.prefill", req.admit_ts, req.first_token_ts,
-            parent=root, attrs={"prompt_len": req.prompt_len},
+            parent=root, attrs=dict(
+                {"prompt_len": req.prompt_len},
+                **({"prefix_rounded_down_blocks":
+                    req.prefix_rounded_down_blocks}
+                   if req.prefix_rounded_down_blocks else {}),
+            ),
         )
         decode_start = req.first_token_ts
         if req.migrate_end_ts is not None:

@@ -1,0 +1,398 @@
+"""The paged decode and prefill programs of a model whose layers follow a
+pattern of gated short convolutions and grouped-query attention
+(``models/conv_lm.py``).
+
+Two kinds of cache, both the engine's (``kvpool/layout.py``):
+
+- per-TOKEN rows in pages, for the attention layers alone: ``k_rows`` and
+  ``v_rows [attention layers, num_blocks, block_size, kv_heads *
+  head_dim]``, a token's K (V) of a layer held FLAT, so that heads
+  narrower than a 128-lane row pad nothing (8 heads of 64 are four whole
+  lane rows); addressed by the slot's block table like every pool;
+- per-SLOT state, for the convolution layers: ``conv_state [conv
+  layers, slots, taps - 1, embed_dim]``, the last gated inputs of the
+  slot's sequence, which no table addresses, and beside it the
+  SNAPSHOTS ``[conv layers, snapshots, taps - 1, embed_dim]`` that the
+  prefix cache's entries own (``kvpool/prefix_cache.py``): the state as
+  of a block boundary, without which a cached run of blocks cannot be
+  continued.
+
+Every program takes and hands back all four arrays. The decode step
+reads and writes the state of its ACTIVE slots and leaves the others'
+alone; a prefill chunk starts from its slot's state, leaves the state
+after its last VALID row (padding rows change nothing: the state after
+``n`` rows is a slice of ``[state | z]``, ``conv_lm.conv_mix``), and
+writes the state as of row ``snap_at`` of the chunk into snapshot
+``snap_id`` (the host passes the sentinel snapshot 0 when it wants
+none). Attention reads the slot's rows through its table as a gathered
+view (the decode step: ``[slots, max_len]``; the chunk: the prefix in
+blocks of :data:`CHUNK_PREFIX_ROWS` under a running softmax) and the new
+tokens' own K/V from the layer's hands; a flat row is read a 128-lane
+row at a time with the queries laid into their heads' lanes
+(:func:`lane_pack`), never split into 64-wide heads, which the device
+would pad and re-lay. Both programs are
+append-free: the new rows of the attention layers land after the layer
+loop. Chunk starts are BLOCK-aligned, not chunk-aligned: a prefix hit
+resumes at the boundary its snapshot was taken at.
+
+The programs keep the names ``step`` and ``prefill`` (a trace names a
+device op by its program), and the decode step returns, after the
+tokens, ``[experts hit (mean over the expert layers), expert rows
+dropped]`` for the host to fetch with them, as ``kvpool/latent.py``'s
+does.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models import conv_lm
+from dlrover_tpu.models import generate as gen_lib
+from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool.latent import (
+    _softmax_add,
+    _softmax_finish,
+    _softmax_start,
+)
+
+# Prefix rows a prefill chunk scores at a time: the float32 scores of a
+# 512-row chunk's 32 heads against them are 134 MB.
+CHUNK_PREFIX_ROWS = 2048
+
+
+def lane_pack(config) -> int:
+    """KV heads a LANE ROW of a flat K (V) row holds: as many whole heads
+    as fit 128 lanes and divide the KV heads (2 of 64; 1 of 128).
+    Attention reads a flat row a lane row at a time and never splits it
+    into heads: a ``[..., kv_heads, 64]`` view pads every head to 128
+    lanes on the device, twice the bytes, re-laid every step."""
+    pack = max(128 // config.head_dim, 1)
+    while config.n_kv_heads % pack:
+        pack -= 1
+    return pack
+
+
+def _placed(config, q):
+    """Queries over LANE ROWS: ``q [..., heads, hd]`` -> ``[..., J, pack
+    * G, pack * hd]`` (``J`` lane rows of ``pack`` KV heads, ``G`` query
+    heads a KV head), each query laid into its own KV head's lanes and
+    zeros in its neighbours', so that its dot with a lane row is its dot
+    with its head's key. Head order is kept."""
+    pack, hd = lane_pack(config), config.head_dim
+    g = config.n_heads // config.n_kv_heads
+    lead = q.shape[:-2]
+    q = q.reshape(lead + (config.n_kv_heads // pack, pack, g, 1, hd))
+    eye = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]    # [p, 1, p', 1]
+    return (q * eye).reshape(lead + (-1, pack * g, pack * hd))
+
+
+def _own_lanes(config, out):
+    """The inverse on attention's output: ``out [..., J, pack * G, pack *
+    hd]`` (every head's weighted sum of whole lane rows) -> ``[...,
+    heads, hd]``, each head's own lanes."""
+    pack, hd = lane_pack(config), config.head_dim
+    g = config.n_heads // config.n_kv_heads
+    lead = out.shape[:-3]
+    out = out.reshape(lead + (-1, pack, g, pack, hd))
+    own = jnp.eye(pack, dtype=out.dtype)
+    return jnp.einsum("...jpgqd,pq->...jpgd", out, own).reshape(
+        lead + (config.n_heads, hd)
+    )
+
+
+def decode_attend(config, k_pool, v_pool, at: int, tables, lengths,
+                  block_size: int, taps=None):
+    """The decode step's ``attend`` for attention layer ``at`` of the
+    pool: one query a slot over the slot's visible rows (``< lengths``),
+    gathered through its table AS STORED (flat rows, read a lane row at
+    a time: :func:`lane_pack`), and over its own new row."""
+    slots, max_blocks = tables.shape
+    max_len = max_blocks * block_size
+    width = lane_pack(config) * config.head_dim
+    n_rows = config.kv_width // width
+    scale = conv_lm.softmax_scale(config)
+    f32 = jnp.float32
+
+    def attend(q, k_new, v_new):
+        k_view = k_pool[at, tables].reshape(slots, max_len, -1)
+        v_view = v_pool[at, tables].reshape(slots, max_len, -1)
+        lanes = lambda a, j: a[..., j * width:(j + 1) * width]  # noqa: E731
+        qp = _placed(config, q[:, 0])                 # [s, J, pG, width]
+        k_own = k_new[:, 0].reshape(slots, n_rows, width)
+        v_own = v_new[:, 0].reshape(slots, n_rows, width)
+        scores = jnp.stack([
+            jnp.einsum("sgw,stw->sgt", qp[:, j], lanes(k_view, j),
+                       preferred_element_type=f32)
+            for j in range(n_rows)
+        ], axis=1)                                     # [s, J, pG, T]
+        visible = jnp.arange(max_len)[None, :] < lengths[:, None]
+        scores = jnp.where(visible[:, None, None, :], scores, -jnp.inf)
+        mine = jnp.einsum(
+            "sjgw,sjw->sjg", qp, k_own, preferred_element_type=f32
+        )
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, mine[..., None]], axis=-1) * scale,
+            axis=-1,
+        )
+        if taps is not None:
+            taps.update(probs=probs)
+        seen = probs[..., :-1].astype(v_view.dtype)
+        out = jnp.stack([
+            jnp.einsum("sgt,stw->sgw", seen[:, j], lanes(v_view, j),
+                       preferred_element_type=f32)
+            for j in range(n_rows)
+        ], axis=1) + probs[..., -1:] * v_own[:, :, None, :].astype(f32)
+        return _own_lanes(config, out)[:, None].astype(q.dtype)
+
+    return attend
+
+
+def chunk_attend(config, k_pool, v_pool, at: int, table_row, start,
+                 block_size: int):
+    """The prefill chunk's ``attend`` for attention layer ``at``: the
+    chunk's queries (positions ``start ...``) over the slot's rows below
+    ``start``, a block of :data:`CHUNK_PREFIX_ROWS` at a time, and over
+    the chunk's own rows, causally; rows read flat, a lane row at a time
+    (:func:`lane_pack`)."""
+    heads = config.n_heads
+    per = max(CHUNK_PREFIX_ROWS // block_size, 1)    # table entries a block
+    span = per * block_size
+    n_table = -(-table_row.shape[0] // per) * per
+    table = jnp.pad(
+        table_row, (0, n_table - table_row.shape[0]),
+        constant_values=SENTINEL_BLOCK,
+    )
+    width = lane_pack(config) * config.head_dim
+    n_rows = config.kv_width // width
+    scale = conv_lm.softmax_scale(config)
+    f32 = jnp.float32
+
+    def attend(q, k_new, v_new):
+        chunk = q.shape[1]
+        qp = _placed(config, q[0])                     # [c, J, pG, width]
+        lanes = lambda a, j: a[..., j * width:(j + 1) * width]  # noqa: E731
+
+        def add(carry, k_rows, v_rows, visible):
+            """One more block of flat rows ``[t, kv_width]``."""
+            scores = jnp.concatenate([
+                jnp.einsum("qgw,tw->gqt", qp[:, j], lanes(k_rows, j),
+                           preferred_element_type=f32)
+                for j in range(n_rows)
+            ], axis=0) * scale                         # [heads, c, t]
+            scores = jnp.where(visible[None], scores, -jnp.inf)
+
+            def values(probs):
+                probs = probs.astype(v_rows.dtype).reshape(
+                    (n_rows, -1) + probs.shape[1:]
+                )
+                out = jnp.stack([
+                    jnp.einsum("gqt,tw->qgw", probs[j], lanes(v_rows, j),
+                               preferred_element_type=f32)
+                    for j in range(n_rows)
+                ], axis=1)                             # [c, J, pG, width]
+                return _own_lanes(config, out)
+
+            return _softmax_add(carry, scores, values)
+
+        def prefix_block(i, carry):
+            ids = jax.lax.dynamic_slice_in_dim(table, i * per, per)
+            below = (i * span + jnp.arange(span)) < start
+            return add(
+                carry,
+                k_pool[at, ids].reshape(span, -1),
+                v_pool[at, ids].reshape(span, -1),
+                jnp.broadcast_to(below[None, :], (chunk, span)),
+            )
+
+        carry = jax.lax.fori_loop(
+            0, (start + span - 1) // span, prefix_block,
+            _softmax_start(heads, chunk, config.head_dim),
+        )
+        causal = jnp.arange(chunk)[None, :] <= jnp.arange(chunk)[:, None]
+        carry = add(
+            carry, k_new[0].reshape(chunk, -1), v_new[0].reshape(chunk, -1),
+            causal,
+        )
+        return _softmax_finish(carry).astype(q.dtype)[None]
+
+    return attend
+
+
+def decode_forward(config, k_pool, v_pool, state, params, tables, lengths,
+                   tokens, block_size: int, taps=None):
+    """All layers for one token a slot: float32 ``logits [slots,
+    vocab]``, the attention layers' new rows ``(k, v) [La, slots,
+    kv_width]``, every slot's state after the token ``[Lc, slots, taps -
+    1, d]`` and the expert layers' counters. ``taps``: a dict a layer's
+    ``{layer: block taps}`` land in (the checks' probes)."""
+    positions = lengths[:, None]
+    slots = tokens.shape[0]
+    x = conv_lm.embed(config, params, tokens[:, None])
+    states, k_news, v_news, counters = [], [], [], []
+    for layer, kind in enumerate(config.layer_types):
+        at = config.index_in_kind(layer)
+        seen = None if taps is None else taps.setdefault(layer, {})
+        if kind == conv_lm.CONV:
+            x, zz, c = conv_lm.block(
+                config, params, layer, x, positions, state[at], taps=seen
+            )
+            states.append(zz[:, 1:])
+        else:
+            x, (k_new, v_new), c = conv_lm.block(
+                config, params, layer, x, positions,
+                decode_attend(
+                    config, k_pool, v_pool, at, tables, lengths, block_size
+                ),
+                taps=seen,
+            )
+            k_news.append(k_new[:, 0].reshape(slots, -1))
+            v_news.append(v_new[:, 0].reshape(slots, -1))
+        if c is not None:
+            counters.append(c)
+    logits = conv_lm.unembed(config, params, x)[:, 0]
+    # (a pattern without one of the kinds stacks nothing of it)
+    rows = lambda new: (  # noqa: E731
+        jnp.stack(new) if new else jnp.zeros((0, slots, config.kv_width))
+    )
+    return (
+        logits, (rows(k_news), rows(v_news)),
+        jnp.stack(states) if states else state, counters,
+    )
+
+
+def chunk_forward(config, k_pool, v_pool, state, params, tokens, table_row,
+                  start, slot, block_size: int, taps=None):
+    """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
+    ``start ...`` from the slot's state: the final residual ``[1, chunk,
+    d]``, the attention layers' new rows ``(k, v) [La, chunk,
+    kv_width]`` and every convolution layer's ``zz [Lc, taps - 1 +
+    chunk, d]`` (``conv_lm.conv_mix``: the state as of any row of the
+    chunk is a slice of it)."""
+    chunk = tokens.shape[1]
+    positions = (start + jnp.arange(chunk, dtype=jnp.int32))[None]
+    x = conv_lm.embed(config, params, tokens)
+    zzs, k_news, v_news = [], [], []
+    for layer, kind in enumerate(config.layer_types):
+        at = config.index_in_kind(layer)
+        seen = None if taps is None else taps.setdefault(layer, {})
+        if kind == conv_lm.CONV:
+            own = jax.lax.dynamic_index_in_dim(
+                state[at], slot, axis=0, keepdims=True
+            )
+            x, zz, _ = conv_lm.block(
+                config, params, layer, x, positions, own, taps=seen
+            )
+            zzs.append(zz[0])
+        else:
+            x, (k_new, v_new), _ = conv_lm.block(
+                config, params, layer, x, positions,
+                chunk_attend(
+                    config, k_pool, v_pool, at, table_row, start, block_size
+                ),
+                taps=seen,
+            )
+            k_news.append(k_new[0].reshape(chunk, -1))
+            v_news.append(v_new[0].reshape(chunk, -1))
+    return x, (k_news, v_news), zzs
+
+
+def build_decode(config, slots: int, max_blocks: int, block_size: int,
+                 counts):
+    max_len = max_blocks * block_size
+
+    def step(k, v, state, snaps, params, tables, lengths, tokens, active,
+             temps, rng, step_idx, first=0, first_slot=-1):
+        counts["decode"] += 1  # traces only
+        tokens = _place_first(tokens, first, first_slot)
+        logits, (k_new, v_new), new_state, counters = decode_forward(
+            config, k, v, state, params, tables, lengths, tokens, block_size
+        )
+        write = jnp.minimum(lengths, max_len - 1)
+        blk = jnp.take_along_axis(
+            tables, (write // block_size)[:, None], axis=1
+        )[:, 0]
+        blk = jnp.where(active, blk, SENTINEL_BLOCK)
+        off = jnp.where(active, write % block_size, 0)
+        if k_new.shape[0]:
+            # The layer is a COORDINATE of the scatter: a window across
+            # the layer axis makes the compiler re-lay the whole pool,
+            # there and back (kvpool/index_pool.land_tokens).
+            at = (
+                jnp.arange(k_new.shape[0])[:, None],
+                jnp.broadcast_to(blk, k_new.shape[:2]),
+                jnp.broadcast_to(off, k_new.shape[:2]),
+            )
+            k = k.at[at].set(k_new.astype(k.dtype))
+            v = v.at[at].set(v_new.astype(v.dtype))
+        with jax.named_scope("state"):
+            # A slot that is not decoding keeps its state: a prompt
+            # between two of its chunks, a slot nobody holds.
+            state = jnp.where(
+                active[None, :, None, None], new_state.astype(state.dtype),
+                state,
+            )
+        sub = jax.random.fold_in(rng, step_idx * 2)
+        nxt = gen_lib.sample_token(logits, sub, temps)
+        return (k, v, state, snaps, jnp.where(active, nxt, tokens),
+                conv_lm.expert_counts(counters))
+
+    return step
+
+
+def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
+                  counts):
+    if chunk % block_size:
+        raise ValueError(
+            f"prefill_chunk {chunk} must be whole blocks of {block_size}: "
+            "a chunk of this model starts at any block boundary"
+        )
+    n_touch = chunk // block_size
+    keep = config.conv_taps - 1
+
+    def prefill(k, v, state, snaps, params, tokens, table_row, start,
+                n_valid, temp, rng, step_idx, last=True, slot=0, snap_at=0,
+                snap_id=0):
+        counts["prefill"] += 1  # traces only
+        x, (k_new, v_new), zzs = chunk_forward(
+            config, k, v, state, params, tokens, table_row, start, slot,
+            block_size,
+        )
+        if k_new:
+            # Whole blocks from a block-aligned start; a block past the
+            # slot's allocation (or the table's end) is the sentinel.
+            ids = jax.lax.dynamic_slice_in_dim(
+                jnp.pad(table_row, (0, n_touch),
+                        constant_values=SENTINEL_BLOCK),
+                start // block_size, n_touch,
+            )
+            land = lambda pool, rows: pool.at[:, ids].set(  # noqa: E731
+                jnp.stack(rows).astype(pool.dtype).reshape(
+                    len(rows), n_touch, block_size, -1
+                )
+            )
+            k, v = land(k, k_new), land(v, v_new)
+        if zzs:
+            zz = jnp.stack(zzs)                     # [Lc, keep + chunk, d]
+            with jax.named_scope("state"):
+                state = state.at[:, slot].set(
+                    jax.lax.dynamic_slice_in_dim(
+                        zz, n_valid, keep, axis=1
+                    ).astype(state.dtype)
+                )
+                with jax.named_scope("snapshot"):
+                    snaps = snaps.at[:, snap_id].set(
+                        jax.lax.dynamic_slice_in_dim(
+                            zz, snap_at, keep, axis=1
+                        ).astype(snaps.dtype)
+                    )
+
+        def head():
+            h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+            logits = conv_lm.unembed(config, params, h)[0, 0]
+            sub = jax.random.fold_in(rng, step_idx * 2 + 1)
+            return gen_lib.sample_token(logits, sub, temp)
+
+        first = jax.lax.cond(last, head, lambda: jnp.zeros((), jnp.int32))
+        return k, v, state, snaps, first
+
+    return prefill

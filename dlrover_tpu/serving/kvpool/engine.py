@@ -140,6 +140,12 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
         # One latent row a token (kvpool/latent.py): the decode step
         # absorbs the up-projection and reads the rows themselves.
         return "latent_absorbed"
+    if getattr(config, "kind", "") == "conv_lm":
+        # Attention layers among convolution layers (kvpool/conv.py):
+        # K and V held flat, 64-wide heads; both programs read the
+        # slot's rows as a gathered view (the pool kernels take heads
+        # of whole lane rows: ROADMAP.md, Speed).
+        return "conv_gathered_view"
     if getattr(config, "index_topk", 0):
         # A learned selection of the cache (kvpool/sparse.py): index
         # keys scored through the table, the selected rows gathered;
@@ -513,24 +519,27 @@ def _build_paged_prefill(config, max_blocks: int, block_size: int,
     return prefill
 
 
-def _build_cow_copy(counts, n_pools: int):
+def _build_cow_copy(counts, n_pools: int, n_state: int = 0):
     """Device block copy src -> dst in EVERY pool array, all layers (K
     and V; the scale pools of an int8 cache; the index keys of a sparse
     model; a latent model's one array): the copy-on-write primitive.
     What a block holds is the
     model's; that a block operation moves all of it is the pool's
     (docs/DESIGN.md §37). src/dst are traced scalars — privatizing any
-    block never retraces."""
+    block never retraces. The ``n_state`` per-slot arrays (and their
+    snapshots) that follow the pools ride through untouched: no block
+    holds any of them."""
 
     def cow(*args):
         counts["cow"] += 1  # traces only
-        pools, (src, dst) = args[:n_pools], args[n_pools:]
-        return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
+        pools, state = args[:n_pools], args[n_pools:n_pools + n_state]
+        src, dst = args[n_pools + n_state:]
+        return tuple(p.at[:, dst].set(p[:, src]) for p in pools) + state
 
     return cow
 
 
-def _build_import_scatter(counts, n_pools: int):
+def _build_import_scatter(counts, n_pools: int, n_state: int = 0):
     """Migration import (kvpool/migrate, §36): land one migrated
     block's rows — host data, one ``[L, block_size, ...]`` array a pool
     array, in the pools' order — at pool row ``dst``. ``dst`` is a
@@ -539,17 +548,18 @@ def _build_import_scatter(counts, n_pools: int):
 
     def imp(*args):
         counts["imp"] += 1  # traces only
-        pools, rows = args[:n_pools], args[n_pools:2 * n_pools]
-        dst = args[2 * n_pools]
+        pools, state = args[:n_pools], args[n_pools:n_pools + n_state]
+        rows = args[n_pools + n_state:2 * n_pools + n_state]
+        dst = args[2 * n_pools + n_state]
         return tuple(
             p.at[:, dst].set(r.astype(p.dtype))
             for p, r in zip(pools, rows)
-        )
+        ) + state
 
     return imp
 
 
-def _build_export_gather(counts, n_pools: int):
+def _build_export_gather(counts, n_pools: int, n_state: int = 0):
     """Migration export (kvpool/migrate, §36): read one block's rows
     out of every pool array at row ``src`` — the gather mirror of the
     import scatter. ``src`` is a traced scalar, so exporting a request
@@ -560,7 +570,9 @@ def _build_export_gather(counts, n_pools: int):
 
     def exp(*args):
         counts["exp"] += 1  # traces only
-        return tuple(p[:, args[n_pools]] for p in args[:n_pools])
+        return tuple(
+            p[:, args[n_pools + n_state]] for p in args[:n_pools]
+        )
 
     return exp
 
@@ -831,8 +843,20 @@ def _paged_steps_for(
     # The pools every program leads with and hands back, donated: the
     # model's statement of what a block holds (kvpool/layout.py).
     n_pools = len(pool_layout.pool_arrays(config, kv_dtype))
-    pool_args = tuple(range(n_pools))
-    if attn == "latent_absorbed":
+    # ... and the per-slot arrays with their snapshots, after them.
+    n_state = 2 * len(pool_layout.state_arrays(config))
+    pool_args = tuple(range(n_pools + n_state))
+    if attn == "conv_gathered_view":
+        # Imported here: the module builds on this one.
+        from dlrover_tpu.serving.kvpool import conv
+
+        build_decode = conv.build_decode(
+            config, slots, max_blocks, block_size, counts
+        )
+        build_prefill = conv.build_prefill(
+            config, max_blocks, block_size, chunk, counts
+        )
+    elif attn == "latent_absorbed":
         # Imported here: the module builds on this one.
         from dlrover_tpu.serving.kvpool import latent
 
@@ -864,13 +888,16 @@ def _paged_steps_for(
         )
     decode = jax.jit(build_decode, donate_argnums=pool_args)
     prefill = jax.jit(build_prefill, donate_argnums=pool_args)
-    cow = jax.jit(_build_cow_copy(counts, n_pools), donate_argnums=pool_args)
+    cow = jax.jit(
+        _build_cow_copy(counts, n_pools, n_state), donate_argnums=pool_args
+    )
     imp = jax.jit(
-        _build_import_scatter(counts, n_pools), donate_argnums=pool_args
+        _build_import_scatter(counts, n_pools, n_state),
+        donate_argnums=pool_args,
     )
     # No donation: export reads the pools and the source keeps serving
     # from them until the importer acks.
-    exp = jax.jit(_build_export_gather(counts, n_pools))
+    exp = jax.jit(_build_export_gather(counts, n_pools, n_state))
     return _PagedSteps(prefill, decode, cow, imp, exp, counts, attn,
                        sparse_chunk, latent_decode)
 
@@ -936,7 +963,30 @@ class PagedServingEngine(ServingEngine):
         # What a block holds (kvpool/layout.py), and the device arrays
         # by name; ``_pools()`` is their tuple in the layout's order.
         self._layout = pool_layout.pool_arrays(config, kv_cache_dtype)
+        # ... and what a SLOT holds whatever its length (none: every
+        # model whose whole state is rows in pages).
+        self._state_layout = pool_layout.state_arrays(config)
+        # Every program's leading arguments by name, in order: the
+        # per-token arrays, the per-slot ones, their snapshots.
+        state = [a.name for a in self._state_layout]
+        self._pool_names = [a.name for a in self._layout] + state + [
+            name + "_snapshots" for name in state
+        ]
         self._arrays: Dict[str, object] = {}
+        if self._state_layout and (kv_cache_dtype != "fp" or spec_k):
+            raise ValueError(
+                "a model with per-slot state ("
+                + ", ".join(a.describe() for a in self._state_layout)
+                + ") is served from a floating-point pool without "
+                "speculative decoding: the int8 and the verify / draft "
+                "programs know rows in pages alone"
+            )
+        if self._state_layout and prefill_chunk % block_size:
+            raise ValueError(
+                f"prefill_chunk {prefill_chunk} must be whole blocks of "
+                f"{block_size} for a model with per-slot state: a prefix "
+                "hit resumes at the block boundary of its snapshot"
+            )
         if spec_k and [a.name for a in self._layout][:2] != ["k", "v"]:
             raise ValueError(
                 "the speculative programs read K and V; this model's "
@@ -956,9 +1006,14 @@ class PagedServingEngine(ServingEngine):
             )
         self.num_blocks = num_blocks
         self._allocator = BlockAllocator(num_blocks, reserved=1)
+        self.state_snapshots = (
+            pool_layout.default_snapshots(num_blocks, slots)
+            if self._state_layout and prefix_cache else 0
+        )
         self._cache: Optional[PrefixCache] = (
             PrefixCache(self._allocator, block_size,
-                        capacity_blocks=prefix_cache_blocks)
+                        capacity_blocks=prefix_cache_blocks,
+                        snapshots=self.state_snapshots)
             if prefix_cache else None
         )
         self._tables = np.zeros(
@@ -973,6 +1028,14 @@ class PagedServingEngine(ServingEngine):
         self._prefix_misses = 0
         self._prefix_hit_blocks = 0
         self._prefix_hit_tokens = 0   # prompt rows no chunk had to run
+        # Per-slot state: slots restored from a snapshot / zeroed at
+        # admission, snapshots written, hit blocks given up because no
+        # snapshot lay that deep, and the id each prefilling slot holds.
+        self._state_restores = 0
+        self._state_restores_from_snapshot = 0
+        self._state_snapshots_taken = 0
+        self._prefix_rounded_down_blocks = 0
+        self._slot_snapshot = [0] * slots
         build.mark("prefix_cache")
         # The base __init__ builds every pool array via _alloc_pool().
         super().__init__(
@@ -999,7 +1062,7 @@ class PagedServingEngine(ServingEngine):
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
-            "%s%s%s",
+            "%s%s%s%s",
             slots, max_len, self.num_blocks, block_size, kv_cache_dtype,
             f" + index keys [{self._index_dim}], "
             f"{self.index_tokens_per_row} to a row, top-"
@@ -1009,6 +1072,10 @@ class PagedServingEngine(ServingEngine):
             f"{self.sparse_chunk_attention}" if self._index_dim else "",
             f", the decode step's rows by {self.latent_decode_attention}"
             if self.latent_decode_attention else "",
+            "; a slot holds " + ", ".join(
+                a.describe() for a in self._state_layout
+            ) + f", {self.state_snapshots} snapshots"
+            if self._state_layout else "",
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -1024,7 +1091,7 @@ class PagedServingEngine(ServingEngine):
         # the 1.94x-per-token capacity lever the equal-HBM bench
         # exploits.
         self._array_block_bytes = {
-            a.name: a.block_bytes(config.n_layers, block_size)
+            a.name: a.block_bytes(pool_layout.pool_layers(config), block_size)
             for a in self._layout
         }
         self._block_bytes = sum(self._array_block_bytes.values())
@@ -1034,13 +1101,21 @@ class PagedServingEngine(ServingEngine):
 
     def _build_bytes(self) -> Dict[str, int]:
         index = self._ki.nbytes if self._ki is not None else 0
-        return {
+        n_pools = len(self._layout)
+        out = {
             "params_bytes": sum(
                 x.nbytes for x in jax.tree_util.tree_leaves(self._params)
             ),
-            "pool_bytes": sum(p.nbytes for p in self._pools()) - index,
+            "pool_bytes": sum(
+                p.nbytes for p in self._pools()[:n_pools]
+            ) - index,
             "index_pool_bytes": index,
         }
+        if self._state_layout:
+            out["state_bytes"] = sum(
+                p.nbytes for p in self._pools()[n_pools:]
+            )
+        return out
 
     # ---- pool construction / programs --------------------------------------
 
@@ -1100,12 +1175,19 @@ class PagedServingEngine(ServingEngine):
         """Every array of the pool, zeroed: ONE rebuild site for all of
         them (init, warmup, step-error recovery), so that no two can be
         mismatched."""
-        return {
+        layers = pool_layout.pool_layers(self.config)
+        arrays = {
             a.name: pool_layout.fresh(
-                a, self.config.n_layers, self.num_blocks, self.block_size
+                a, layers, self.num_blocks, self.block_size
             )
             for a in self._layout
         }
+        # Per-slot state, then its snapshots (row 0: the sentinel).
+        for a in self._state_layout:
+            arrays[a.name] = a.fresh(self.slots)
+        for a in self._state_layout:
+            arrays[a.name + "_snapshots"] = a.fresh(self.state_snapshots + 1)
+        return arrays
 
     def _alloc_pool(self) -> None:
         self._arrays = jax.block_until_ready(self._fresh_arrays())
@@ -1114,15 +1196,15 @@ class PagedServingEngine(ServingEngine):
         """The donated-pool argument tuple every compiled program
         leads with, in the layout's order: (k, v) for fp, (k, v,
         k_scale, v_scale) for int8, (k, v, index keys) for a sparse
-        model, (latent,) for a latent one. Call sites splat this and
-        hand the returned tuple back to :meth:`_set_pools` — ONE
-        argument list per program, whatever a block holds."""
-        return tuple(self._arrays[a.name] for a in self._layout)
+        model, (latent,) for a latent one; after them a model's
+        per-slot arrays and their snapshots (``kvpool/layout.py``).
+        Call sites splat this and hand the returned tuple back to
+        :meth:`_set_pools` — ONE argument list per program, whatever a
+        block or a slot holds."""
+        return tuple(self._arrays[name] for name in self._pool_names)
 
     def _set_pools(self, pools) -> None:
-        self._arrays = {
-            a.name: pool for a, pool in zip(self._layout, pools)
-        }
+        self._arrays = dict(zip(self._pool_names, pools))
 
     def warmup(self) -> None:
         """Compile all three paged programs on throwaway state, then
@@ -1135,6 +1217,7 @@ class PagedServingEngine(ServingEngine):
             jnp.zeros(self.max_blocks, jnp.int32),
             np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0), np.bool_(True),
+            *((np.int32(0),) * 3 if self._state_layout else ()),
         )
         jax.block_until_ready(first)
         marks.mark("prefill")
@@ -1165,7 +1248,8 @@ class PagedServingEngine(ServingEngine):
         # own dtype.
         rows = [
             jnp.zeros(
-                (self.config.n_layers, self.block_size) + a.row_shape,
+                (pool_layout.pool_layers(self.config), self.block_size)
+                + a.row_shape,
                 a.import_dtype,
             )
             for a in self._layout
@@ -1178,6 +1262,12 @@ class PagedServingEngine(ServingEngine):
         # out of this engine never stalls the serve loop on a compile.
         jax.block_until_ready(self._steps.exp(*pools, np.int32(0)))
         marks.mark("exp")
+        if self._state_layout:
+            self._set_pools(pools)
+            self._restore_state(0, 0)
+            self._put_slot_state(0, self._get_slot_state(0))
+            pools = jax.block_until_ready(self._pools())
+            marks.mark("state")
         if self._spec is not None:
             tbl = jnp.asarray(
                 np.zeros((self.slots, self.max_blocks), np.int32)
@@ -1328,6 +1418,9 @@ class PagedServingEngine(ServingEngine):
         slot = req.slot
         self._tables[slot, :] = SENTINEL_BLOCK
         self._slot_blocks[slot] = []
+        if self._state_layout:
+            self._admit_state_slot(req)
+            return
         if self._cache is None:
             return
         hit = self._cache.lookup(req.prompt)
@@ -1345,9 +1438,15 @@ class PagedServingEngine(ServingEngine):
             for block in hit[keep:]:
                 self._allocator.decref(block)
             hit = hit[:keep]
+        self._adopt_hit(req, hit, start)
+
+    def _adopt_hit(self, req: Request, hit: List[int], start: int) -> None:
+        """``hit`` (cached blocks, already incref'd) becomes the head of
+        the slot's table and the prompt resumes at row ``start``."""
         if not hit:
             self._prefix_misses += 1
             return
+        slot = req.slot
         self._prefix_hits += 1
         self._prefix_hit_blocks += len(hit)
         req.prefix_hit_blocks = len(hit)
@@ -1367,6 +1466,10 @@ class PagedServingEngine(ServingEngine):
             self._allocator.decref(block)
         self._slot_blocks[slot] = []
         self._tables[slot, :] = SENTINEL_BLOCK
+        if self._slot_snapshot[slot]:
+            # Lent to a prompt that left before it was inserted.
+            self._cache.give_snapshot(self._slot_snapshot[slot])
+            self._slot_snapshot[slot] = 0
 
     def _reset_pool(self) -> None:
         # A failed step may have invalidated the donated pools: the
@@ -1378,9 +1481,11 @@ class PagedServingEngine(ServingEngine):
             self._cache = PrefixCache(
                 self._allocator, self.block_size,
                 capacity_blocks=self._cache.capacity_blocks,
+                snapshots=self.state_snapshots,
             )
         self._tables[:, :] = SENTINEL_BLOCK
         self._slot_blocks = [[] for _ in range(self.slots)]
+        self._slot_snapshot = [0] * self.slots
 
     def _sync_pool_metrics(self) -> None:
         stats = self._allocator.stats(self._live_block_ids())
@@ -1418,6 +1523,7 @@ class PagedServingEngine(ServingEngine):
             np.float32(req.temperature), self._rng,
             np.int32(self._step_idx),
             np.bool_(start + n_valid == req.prompt_len),
+            *self._chunk_state_args(req, start, n_valid),
         )
         self._set_pools(pools)
         self._mark("prefill_launch")
@@ -1430,8 +1536,12 @@ class PagedServingEngine(ServingEngine):
             # Register the FULL prompt blocks for future hits (partial
             # tails stay private: the owner's decode appends into them).
             n_full = req.prompt_len // self.block_size
+            snapshot, self._slot_snapshot[req.slot] = (
+                self._slot_snapshot[req.slot], 0
+            )
             self._cache.insert(
-                req.prompt, self._slot_blocks[req.slot][:n_full]
+                req.prompt, self._slot_blocks[req.slot][:n_full],
+                snapshot=(n_full, snapshot) if snapshot else None,
             )
         self._launched_first(req, first)
 
@@ -1536,6 +1646,24 @@ class PagedServingEngine(ServingEngine):
         )
         stats["cow_copies"] = self._allocator.cow_copies_total
         stats["pool_attention"] = self.pool_attention
+        stats["kv_layers"] = pool_layout.pool_layers(self.config)
+        stats["state_layers"] = sum(a.layers for a in self._state_layout)
+        if self._state_layout:
+            entry = sum(a.entry_bytes() for a in self._state_layout)
+            live = self._cache.snapshots_live if self._cache else 0
+            stats["state_bytes"] = self.slots * entry
+            stats["state_snapshots_live"] = live
+            stats["state_snapshot_bytes"] = live * entry
+            stats["state_snapshot_capacity"] = self.state_snapshots
+            stats["state_restores"] = self._state_restores
+            stats["state_restores_from_snapshot"] = (
+                self._state_restores_from_snapshot
+            )
+            stats["state_snapshots"] = self._state_snapshots_taken
+            stats["prefix_rounded_down_blocks"] = (
+                self._prefix_rounded_down_blocks
+            )
+            stats["moe_rows_dropped"] = self._moe_rows_dropped
         # What a restart pays before the first request: construction
         # and warm-up as the engine timed them (0.0: not warmed up).
         stats["engine_build_s"] = self.engine_build_s
@@ -1599,13 +1727,111 @@ class PagedServingEngine(ServingEngine):
             )
         # Every pool array is addressed by the same block ids: they
         # agree on how many blocks there are and how long a block is.
-        want = (self.config.n_layers, self.num_blocks, self.block_size)
+        want = (pool_layout.pool_layers(self.config), self.num_blocks,
+                self.block_size)
         for a, pool in zip(self._layout, self._pools()):
             if pool.shape[:3] != want:
                 raise AssertionError(
                     f"pool array {a.name} of {pool.shape[:3]} in a pool "
                     f"of {want}: one table cannot address it"
                 )
+        if self._cache is not None and self._state_layout:
+            # Every snapshot id is free, attached to an entry, or lent
+            # to a slot that is still prefilling.
+            lent = sum(1 for s in self._slot_snapshot if s)
+            held = (self._cache.snapshots_live + self._cache.snapshots_free
+                    + lent)
+            if held != self.state_snapshots:
+                raise AssertionError(
+                    f"{held} snapshot ids accounted for of "
+                    f"{self.state_snapshots}"
+                )
+
+    # ---- per-slot state (kvpool/layout.py) ---------------------------------
+
+    def _all_trace_counts(self) -> Dict[str, int]:
+        counts = super()._all_trace_counts()
+        if self._state_layout:
+            counts.update(_state_steps(len(self._state_layout)).trace_counts)
+        return counts
+
+    def _admit_state_slot(self, req: Request) -> None:
+        """Admission for a model with per-slot state: the slot starts
+        from the snapshot of the deepest cached boundary that has one
+        (the blocks up to it slot into the table; a deeper match without
+        a snapshot is given up), else from zeros. A hit is BLOCK-aligned:
+        its chunks start where the snapshot was taken."""
+        hit, snapshot, bs = [], 0, self.block_size
+        if self._cache is not None:
+            # Never skip the FINAL prompt token (see _admit_slot).
+            hit, snapshot, rounded = self._cache.lookup_with_state(
+                req.prompt, max_blocks=(req.prompt_len - 1) // bs
+            )
+            self._prefix_rounded_down_blocks += rounded
+            req.prefix_rounded_down_blocks = rounded
+            self._adopt_hit(req, hit, len(hit) * bs)
+        t0 = time.monotonic()
+        self._restore_state(req.slot, snapshot)
+        self._state_restores += 1
+        if self._step_trace is not None:
+            counts = self._step_trace.counts
+            for name, more in (
+                ("state_restores", 1),
+                ("state_restores_from_snapshot", int(bool(snapshot))),
+                ("state_restore_s", time.monotonic() - t0),
+            ):
+                counts[name] = counts.get(name, 0) + more
+
+    def _restore_state(self, slot: int, snapshot: int) -> None:
+        """The slot's state := snapshot ``snapshot`` (0: zeros), on the
+        device: one compiled copy, no host round trip."""
+        self._state_restores_from_snapshot += bool(snapshot)
+        blocks, state, snaps, steps = self._state_pools()
+        self._set_pools(blocks + steps.restore(
+            *state, *snaps, np.int32(slot), np.int32(snapshot)
+        ))
+
+    def _state_pools(self):
+        """``_pools()`` in its three parts (per-token arrays, per-slot
+        state, snapshots) and the programs that move a slot's state."""
+        pools, n = self._pools(), len(self._state_layout)
+        at = len(self._layout)
+        return (pools[:at], pools[at:at + n], pools[at + n:],
+                _state_steps(n))
+
+    def _get_slot_state(self, slot: int):
+        """A slot's state, one host array a state array (migration)."""
+        _, state, _, steps = self._state_pools()
+        return jax.device_get(steps.get(*state, np.int32(slot)))
+
+    def _put_slot_state(self, slot: int, rows) -> None:
+        blocks, state, snaps, steps = self._state_pools()
+        self._set_pools(blocks + steps.put(
+            *state, *(jnp.asarray(r) for r in rows), np.int32(slot)
+        ) + snaps)
+
+    def _chunk_state_args(self, req: Request, start: int, n_valid: int):
+        """What a chunk launch of a model with per-slot state carries
+        after the plain arguments: the slot, and where in the chunk to
+        take a snapshot and under which id. A prompt gets ONE: the state
+        at its last whole-block boundary, in the chunk that holds it."""
+        if not self._state_layout:
+            return ()
+        boundary = req.prompt_len // self.block_size * self.block_size
+        snap_at = snap_id = 0
+        # Exactly one chunk of a prompt has the boundary past its first
+        # row and at or before its last valid one's end (a boundary that
+        # is a chunk's first row was the END of the chunk before it, or
+        # the hit's own boundary, which has its snapshot).
+        if self._cache is not None and start < boundary <= start + n_valid:
+            snap_id = self._cache.take_snapshot()
+            if snap_id:
+                snap_at = boundary - start
+                self._slot_snapshot[req.slot] = snap_id
+                self._state_snapshots_taken += 1
+                if self._step_trace is not None:
+                    self._step_trace.counts["state_snapshots"] = 1
+        return np.int32(req.slot), np.int32(snap_at), np.int32(snap_id)
 
 
 def _latent_decode_kind(config, attn: str, slots: int, block_size: int,
@@ -1623,4 +1849,54 @@ def _latent_decode_kind(config, attn: str, slots: int, block_size: int,
 
     return latent.decode_attention_kind(
         config, config.compute_dtype, block_size, max_blocks, slots
+    )
+
+
+class _StateSteps(NamedTuple):
+    restore: object
+    get: object
+    put: object
+    trace_counts: Dict[str, int]
+
+
+@functools.lru_cache(maxsize=4)
+def _state_steps(n_state: int) -> _StateSteps:
+    """The programs that move a SLOT's state, for ``n_state`` per-slot
+    arrays (``kvpool/layout.py``), whatever they hold: ``restore(*state,
+    *snapshots, slot, snapshot)`` sets the slot's state to a snapshot's
+    (snapshot 0, the sentinel: to zeros) and hands back both tuples;
+    ``get(*state, slot)`` / ``put(*state, *rows, slot)`` read and write
+    one slot's (migration). Slot and snapshot are traced scalars: no
+    admission retraces."""
+    counts = {"state_restore": 0, "state_get": 0, "state_put": 0}
+
+    def restore(*args):
+        counts["state_restore"] += 1  # traces only
+        state, snaps = args[:n_state], args[n_state:2 * n_state]
+        slot, snapshot = args[2 * n_state:]
+        with jax.named_scope("state"), jax.named_scope("restore"):
+            return tuple(
+                s.at[:, slot].set(
+                    jnp.where(snapshot > 0, p[:, snapshot], 0).astype(s.dtype)
+                )
+                for s, p in zip(state, snaps)
+            ) + tuple(snaps)
+
+    def get(*args):
+        counts["state_get"] += 1  # traces only
+        return tuple(s[:, args[n_state]] for s in args[:n_state])
+
+    def put(*args):
+        counts["state_put"] += 1  # traces only
+        state, rows = args[:n_state], args[n_state:2 * n_state]
+        return tuple(
+            s.at[:, args[2 * n_state]].set(r.astype(s.dtype))
+            for s, r in zip(state, rows)
+        )
+
+    return _StateSteps(
+        jax.jit(restore, donate_argnums=tuple(range(2 * n_state))),
+        jax.jit(get),
+        jax.jit(put, donate_argnums=tuple(range(n_state))),
+        counts,
     )

@@ -17,11 +17,30 @@ a dense GQA model: ``k`` and ``v`` of ``[kv_heads, head_dim]``):
   qk_rope_dim]`` alone, with no head axis and no V (on the device two
   576-wide rows to a 1,152-lane row, the same class).
 
+- a pattern of convolution and attention layers
+  (``models/conv_lm.py``): ``k_rows``, ``v_rows [kv_heads * head_dim]``,
+  a token's K and V held flat (no head narrower than a lane row pads
+  one), over the ATTENTION layers alone (``config.cache_layers``: the
+  pool's layer axis; every other config's is ``n_layers``).
+
 Everything that MOVES a block (copy-on-write, import, export, prefix
 sharing, preemption, release) moves every array of this tuple and never
 asks what they are; everything that SIZES a block sums over it. Only the
 programs that read and write rows (``kvpool/engine.py``'s dense ones,
-``kvpool/sparse.py``, ``kvpool/latent.py``) know the arrays by name.
+``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``) know the
+arrays by name.
+
+A SECOND kind of array holds what a sequence keeps whatever its length
+(``config.state_rows``: name -> (layers, a slot's shape); no other
+config states any): per-SLOT state ``[layers, slots, *shape]``, which
+no block table addresses, and beside each its SNAPSHOTS ``[layers,
+snapshots, *shape]``: the state as of a block boundary, owned by the
+prefix cache's entry for that boundary (``kvpool/prefix_cache.py``). A
+run of cached blocks can be continued only from a boundary that has one.
+The engine treats them as it treats the arrays above: every program
+takes and returns them after the pool's, admission restores or zeroes a
+slot's, a chunk writes a snapshot, migration carries a slot's raw, and
+``kv_stats()`` sizes them; only ``kvpool/conv.py`` knows what they mean.
 """
 
 from typing import NamedTuple, Tuple
@@ -115,4 +134,47 @@ def fresh(array: PoolArray, n_layers: int, num_blocks: int,
         )
     return jnp.zeros(
         (n_layers, num_blocks, block_size) + array.row_shape, array.dtype
+    )
+
+
+def pool_layers(config) -> int:
+    """The pool's layer axis: the layers that keep per-token rows."""
+    return getattr(config, "cache_layers", None) or config.n_layers
+
+
+class StateArray(NamedTuple):
+    name: str
+    layers: int
+    shape: Tuple[int, ...]          # a slot's (a snapshot's), a layer
+    dtype: object
+
+    def entry_bytes(self) -> int:
+        """Bytes of one slot's state (one snapshot), all layers."""
+        return int(
+            self.layers * np.prod(self.shape, dtype=np.int64)
+            * jnp.dtype(self.dtype).itemsize
+        )
+
+    def fresh(self, entries: int):
+        return jnp.zeros((self.layers, entries) + self.shape, self.dtype)
+
+    def describe(self) -> str:
+        shape = "x".join(str(n) for n in self.shape)
+        return (f"{self.name} [{shape}] {jnp.dtype(self.dtype).name} x "
+                f"{self.layers} layers")
+
+
+def default_snapshots(num_blocks: int, slots: int) -> int:
+    """Snapshot ids an engine keeps unless told otherwise: one a cached
+    block at most (block 0 is the sentinel), and one a slot for those
+    lent to prompts that are still prefilling."""
+    return num_blocks - 1 + slots
+
+
+def state_arrays(config) -> Tuple[StateArray, ...]:
+    """The per-slot arrays ``config`` states (none: every model whose
+    whole state is rows in pages)."""
+    return tuple(
+        StateArray(name, int(layers), tuple(shape), config.compute_dtype)
+        for name, (layers, shape) in getattr(config, "state_rows", ())
     )

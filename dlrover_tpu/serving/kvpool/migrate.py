@@ -41,7 +41,14 @@ Every other array a model's blocks hold beside K and V travels the same
 way (``kvpool/layout.py``: the header's ``raw`` lists name, dtype, shape
 and byte count, the bytes follow the index keys'); a latent model's
 blocks hold nothing else (``latent``), and its payload ends there, with
-no kv wire. Wall-clock export
+no kv wire. A model with per-SLOT state (``kvpool/layout.py``:
+``models/conv_lm.py``'s convolution state) ships the slot's state too,
+raw and bit for bit, after the blocks' arrays (the header's ``state``
+lists name, dtype, shape and byte count): a request that arrived
+without it would decode on from another sequence's state, so an engine
+that holds such state refuses a payload that lacks it, and the other
+way round (:class:`MigrationError`). Snapshots stay behind: they belong
+to the source's prefix cache. Wall-clock export
 stamps bound the migration pause across processes on one host.
 """
 
@@ -132,6 +139,17 @@ def export_request(engine, req: Request,
             index_meta = meta
         else:
             raw_meta.append(dict(meta, name=a.name))
+    # The slot's own state, one array a state array of the layout.
+    state_meta = []
+    if engine._state_layout:
+        for a, rows in zip(engine._state_layout,
+                           engine._get_slot_state(slot)):
+            data = np.ascontiguousarray(rows).view(np.uint8).tobytes()
+            state_meta.append({
+                "name": a.name, "dtype": str(rows.dtype),
+                "shape": list(rows.shape), "nbytes": len(data),
+            })
+            raw_bytes += data
     admit_ts = req.admit_ts if req.admit_ts is not None else (
         req.submit_ts
     )
@@ -151,6 +169,7 @@ def export_request(engine, req: Request,
         "src_kv_dtype": engine.kv_cache_dtype,
         "index": index_meta,
         "raw": raw_meta,
+        "state": state_meta,
         # Source-side phase durations, for timeline reconstruction on
         # the destination clock (monotonic stamps don't cross
         # processes; durations do).
@@ -238,7 +257,29 @@ def import_request(engine, payload: bytes,
             payload[at:at + m["nbytes"]], np.uint8
         ).view(jnp.dtype(m["dtype"])).reshape(m["shape"])
         at += m["nbytes"]
+    state_metas = list(header.get("state") or ())
+    if [m["name"] for m in state_metas] != [
+        a.name for a in engine._state_layout
+    ]:
+        raise MigrationError(
+            f"wire carries the slot state {[m['name'] for m in state_metas]}"
+            f", the engine's slots hold "
+            f"{[a.name for a in engine._state_layout]}"
+        )
+    state_rows = []
+    for m, a in zip(state_metas, engine._state_layout):
+        rows = np.frombuffer(
+            payload[at:at + m["nbytes"]], np.uint8
+        ).view(jnp.dtype(m["dtype"])).reshape(m["shape"])
+        if rows.shape != (a.layers,) + a.shape:
+            raise MigrationError(
+                f"{a.name} {rows.shape} vs a slot's "
+                f"{(a.layers,) + a.shape}"
+            )
+        state_rows.append(rows)
+        at += m["nbytes"]
     cfg = engine.config
+    pool_layers = engine._pools()[0].shape[0]
     has_kv = any(a.name == "k" for a in engine._layout)
     if has_kv:
         kq, vq, ks, vs, _ = kv_from_wire(payload[at:])
@@ -253,11 +294,11 @@ def import_request(engine, payload: bytes,
     else:
         L, n, bs = raw_rows[wants[0].name].shape[:3]
     for a in wants:
-        want = (cfg.n_layers, n, bs) + a.row_shape
+        want = (pool_layers, n, bs) + a.row_shape
         if raw_rows[a.name].shape != want:
             raise MigrationError(
                 f"{a.name} {raw_rows[a.name].shape} vs blocks "
-                f"{(cfg.n_layers, n, bs)} x {a.row_shape}"
+                f"{(pool_layers, n, bs)} x {a.row_shape}"
             )
     if bs != engine.block_size or bs != header["block_size"]:
         raise MigrationError(
@@ -320,6 +361,8 @@ def import_request(engine, payload: bytes,
             *(jnp.asarray(arriving[a.name][:, i]) for a in engine._layout),
             np.int32(dst),
         ))
+    if state_rows:
+        engine._put_slot_state(slot, state_rows)
     if engine._cache is not None:
         # Imported chains join the destination trie: the NEXT request
         # sharing this prompt hits warm blocks — hit-rate survives

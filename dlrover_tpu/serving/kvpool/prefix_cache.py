@@ -25,6 +25,18 @@ drops the cache's reference, freeing the block once no slot still
 points at it. ``evict_lru`` is also the allocator's relief valve: the
 engine calls it before preempting a request when the pool runs dry, and
 then takes only leaves no live slot still holds (``must_free``).
+
+**Snapshots** (``snapshots > 0``: a model with per-slot state,
+``kvpool/layout.py``). A run of cached blocks can be continued only with
+the state AS OF its last row, which no block holds, so an entry may own
+one snapshot id (a row of the engine's snapshot arrays; 0 is the
+sentinel nobody owns): the state at its block's END boundary. The ids
+live here with the entries: ``take_snapshot`` lends one to a prefilling
+request, ``insert`` attaches it to the entry of the boundary it was
+taken at (or takes it back: the entry had one, or was never made),
+evicting an entry frees its id with its block. ``lookup_with_state``
+returns the chain only as far as the DEEPEST entry holding a snapshot,
+with that id and how many matched blocks lay beyond it.
 """
 
 from collections import OrderedDict
@@ -48,6 +60,9 @@ class _Entry:
     # KV (correctness must not hang on 64-bit hash uniqueness).
     tokens: Tuple[int, ...] = ()
     children: Set[Tuple] = field(default_factory=set)
+    # The state at this block's end boundary (0: none); see the module
+    # docstring.
+    snapshot: int = 0
 
 
 class PrefixCache:
@@ -58,6 +73,7 @@ class PrefixCache:
         allocator: BlockAllocator,
         block_size: int,
         capacity_blocks: Optional[int] = None,
+        snapshots: int = 0,
     ):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
@@ -71,6 +87,10 @@ class PrefixCache:
         self.misses_total = 0
         self.hit_blocks_total = 0
         self.evicted_blocks_total = 0
+        # Snapshot ids 1..snapshots (0 is the sentinel), lowest first.
+        self.snapshots = snapshots
+        self._free_snapshots = list(range(snapshots, 0, -1))
+        self.snapshots_live = 0       # entries that hold one
 
     # ---- keys --------------------------------------------------------------
 
@@ -95,18 +115,22 @@ class PrefixCache:
 
     # ---- lookup / insert ---------------------------------------------------
 
-    def lookup(self, prompt: Sequence[int]) -> List[int]:
-        """Longest cached chain of full prompt blocks. Every returned
-        block is INCREF'd for the caller — the hit is a loan the slot
-        must decref like any other block it owns."""
-        blocks: List[int] = []
+    def _matched(self, prompt: Sequence[int]) -> List[_Entry]:
+        """The longest cached chain of full prompt blocks, touched."""
+        held: List[_Entry] = []
         for key, tokens in self._chain_keys(prompt):
             entry = self._entries.get(key)
             if entry is None or entry.tokens != tokens:
                 break
             self._entries.move_to_end(key)
-            self._alloc.incref(entry.block_id)
-            blocks.append(entry.block_id)
+            held.append(entry)
+        return held
+
+    def _lend(self, entries: Sequence[_Entry]) -> List[int]:
+        """The entries' blocks, INCREF'd for the caller and counted."""
+        blocks = [entry.block_id for entry in entries]
+        for block_id in blocks:
+            self._alloc.incref(block_id)
         if blocks:
             self.hits_total += 1
             self.hit_blocks_total += len(blocks)
@@ -114,15 +138,56 @@ class PrefixCache:
             self.misses_total += 1
         return blocks
 
-    def insert(self, prompt: Sequence[int], blocks: Sequence[int]) -> int:
+    def lookup(self, prompt: Sequence[int]) -> List[int]:
+        """Longest cached chain of full prompt blocks. Every returned
+        block is INCREF'd for the caller — the hit is a loan the slot
+        must decref like any other block it owns."""
+        return self._lend(self._matched(prompt))
+
+    def lookup_with_state(
+        self, prompt: Sequence[int], max_blocks: Optional[int] = None
+    ) -> Tuple[List[int], int, int]:
+        """:meth:`lookup` for a model with per-slot state (module
+        docstring): ``(blocks, snapshot id, rounded down)``, the chain
+        up to the deepest entry among its first ``max_blocks`` that
+        holds a snapshot, that snapshot (0 with no blocks), and how many
+        matched blocks lay beyond it and were given up."""
+        held = self._matched(prompt)
+        usable = held[:max_blocks]
+        while usable and not usable[-1].snapshot:
+            usable.pop()
+        snapshot = usable[-1].snapshot if usable else 0
+        return self._lend(usable), snapshot, len(held) - len(usable)
+
+    def take_snapshot(self) -> int:
+        """Lend a free snapshot id to a request that is about to write
+        the state at a boundary (0: none is free, write the sentinel).
+        It comes back through :meth:`insert` or :meth:`give_snapshot`."""
+        return self._free_snapshots.pop() if self._free_snapshots else 0
+
+    def give_snapshot(self, snapshot: int) -> None:
+        if snapshot:
+            self._free_snapshots.append(snapshot)
+
+    def _free_entry_snapshot(self, entry: _Entry) -> None:
+        if entry.snapshot:
+            self.snapshots_live -= 1
+            self.give_snapshot(entry.snapshot)
+
+    def insert(self, prompt: Sequence[int], blocks: Sequence[int],
+               snapshot=None) -> int:
         """Register a prefilled prompt's full blocks (``blocks[k]``
         holds rows ``[k*bs, (k+1)*bs)``). Newly cached blocks gain one
         cache-owned reference; chains already present are touched, not
         re-owned (a concurrent twin's identical blocks stay owned by
-        its slot alone). Returns the number of blocks newly cached."""
+        its slot alone). ``snapshot``: ``(n, id)``, the state as of the
+        end of the prompt's ``n``-th block under a lent id; the entry of
+        that boundary adopts it unless it holds one already. Returns
+        the number of blocks newly cached."""
         keys = self._chain_keys(prompt)
         n_full = min(len(keys), len(blocks))
         added = 0
+        at, lent = snapshot or (0, 0)
         parent: Optional[Tuple] = _ROOT
         for k in range(n_full):
             key, tokens = keys[k]
@@ -144,7 +209,11 @@ class PrefixCache:
                 break
             else:
                 self._entries.move_to_end(key)
+            if lent and k == at - 1 and not entry.snapshot:
+                entry.snapshot, lent = lent, 0
+                self.snapshots_live += 1
             parent = key
+        self.give_snapshot(lent)
         if self.capacity_blocks is not None:
             over = len(self._entries) - self.capacity_blocks
             if over > 0:
@@ -186,6 +255,7 @@ class PrefixCache:
                     victim.key
                 )
             self._alloc.decref(victim.block_id)
+            self._free_entry_snapshot(victim)
             evicted += 1
             self.evicted_blocks_total += 1
         return evicted
@@ -195,6 +265,7 @@ class PrefixCache:
         the device blocks are gone, the warm set with them)."""
         for entry in self._entries.values():
             self._alloc.decref(entry.block_id)
+            self._free_entry_snapshot(entry)
         self._entries.clear()
 
     # ---- accounting --------------------------------------------------------
@@ -205,6 +276,10 @@ class PrefixCache:
 
     def cached_block_ids(self) -> Set[int]:
         return {e.block_id for e in self._entries.values()}
+
+    @property
+    def snapshots_free(self) -> int:
+        return len(self._free_snapshots)
 
     def hit_rate(self) -> float:
         total = self.hits_total + self.misses_total

@@ -143,6 +143,9 @@ class Request:
     # Paged engines (serving/kvpool): warm prefix-cache blocks this
     # request's block table started from — 0 on a miss or a flat engine.
     prefix_hit_blocks: int = 0
+    # ... and, for a model with per-slot state, matched blocks given up
+    # because no state snapshot lay that deep.
+    prefix_rounded_down_blocks: int = 0
     # Speculative decoding (serving/spec_decode, §35): drafted /
     # accepted token counts and aggregate wall time attributed to the
     # draft vs verify phases (the engine splits each iteration's cost
@@ -577,6 +580,7 @@ class Scheduler:
         req.first_token_ts = None
         req.admit_ts = None
         req.prefix_hit_blocks = 0
+        req.prefix_rounded_down_blocks = 0
         req.migrate_start_ts = None
         req.migrate_end_ts = None
         req.spec_drafted = 0

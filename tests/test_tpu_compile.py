@@ -729,6 +729,68 @@ def test_latent_serving_programs_compile_at_the_cells_shape(topo):
     assert f"f32[32,64,{rows}]" in text
 
 
+def test_conv_serving_programs_compile_at_the_cells_shape(topo):
+    """``lfm2-serve-sessions-8k``'s decode step and prefill chunk (a
+    dense convolution layer, then attention, convolution and attention
+    layers over experts, of its 9) at published widths, 32 slots x 9,216 rows
+    over the cell's 5,120-block pool, lower for the described v5e under
+    their trace names from what the engine's constructor builds
+    (``kvpool.engine._paged_steps``): all four arrays (K, V, the slots'
+    state, its snapshots) alias their outputs; the K/V pool, held flat,
+    compiles to its LOGICAL bytes and is never copied or re-laid (a
+    landing scatter with the layer as a window re-lays it whole, twice a
+    step); no ``[..., 8, 64]`` view of the gathered rows exists (the
+    device pads a 64-wide head to 128 lanes: attention reads lane rows,
+    ``conv.lane_pack``); the expert matmuls are the grouped kernel; and
+    every scope the cell's readers book device time to is there."""
+    from benchmark import common, conv_scopes, trace_reduce
+    from benchmark import rehearse_lfm2
+    from dlrover_tpu.serving.kvpool import conv, engine as paged
+
+    cfg_json = common.load_json("configs", "lfm2-24b-a2b.json")
+    programs, logical = rehearse_lfm2.lower_engine_programs(
+        cfg_json, topo.devices[0], probes=False,
+        layer_types=("conv", "full_attention", "conv", "full_attention"),
+        n_dense=1,
+    )
+    pool = "bf16[2,5120,64,512]"
+    assert logical["k_rows"] == 2 * 5120 * 64 * 512 * 2
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        for i in range(4):
+            assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
+        assert 6 <= _n_kernels(c) <= 12         # gmm: gate|up, down x 3
+        made = [
+            line for line in text.splitlines()
+            if f"= {pool}" in line and " parameter(" not in line
+            and "get-tuple-element" not in line and "bitcast" not in line
+        ]
+        # the landing scatters (alone or fused), in place: never a copy
+        assert made and not any(
+            " copy(" in line or " transpose(" in line for line in made
+        ), made
+        assert "bf16[32,9216,8,64]" not in text    # heads split out
+        scopes = trace_reduce.scopes_from_hlo(text)
+        booked = {conv_scopes.scope_of(v) for v in scopes.values()}
+        assert booked >= {"conv", "gqa", "router", "experts", "dense"}
+        if name == "jit_prefill":
+            assert "snapshot" in booked
+            assert "f32[32,512,9216]" not in text   # no whole-view scores
+        m = c.memory_analysis()
+        # the pool's two arrays at their logical bytes among the
+        # arguments (a padded minor dimension would double them)
+        assert m.alias_size_in_bytes < 2 * logical["k_rows"] + 0.31e9
+        assert m.temp_size_in_bytes < 0.7e9
+    from benchmark.runners import serve_conv
+
+    cfg = serve_conv.conv_config(cfg_json)
+    assert conv.lane_pack(cfg) == 2
+    assert paged.pool_attention_kind(cfg, 64, "fp", 512) == \
+        "conv_gathered_view"
+
+
 # What ``latent.decode_attention_kind`` sees -> what it must answer;
 # unnamed: a bf16 pool of 64-token pages, 576-wide rows (two to a
 # 1,152-lane device row) under 32 heads, 32 slots x 272 pages (the

@@ -1,9 +1,12 @@
 """sha256 of the LOWERED programs (StableHLO text) of the cells whose
-code a change to the layer pattern, the train step or the flash kernels
-can reach, for a described TPU v5e: ``mistral7b-train``'s step,
-``kimilinear-train-8k``'s step and ``xing-serve-sessions-16k``'s two
-engine programs. Run it on two checkouts and compare: the same hash is
-the same program, so the cell cannot move.
+code a change to the layer pattern, the train step, the flash kernels or
+the paged engine can reach, for a described TPU v5e: ``mistral7b-train``'s
+step, ``kimilinear-train-8k``'s step, and the two engine programs of each
+accepted serve cell (``nemo12b-serve-chat``, built as
+``benchmark/rehearse.py`` builds them, ``keye-serve-docqa-32k``, as
+``rehearse_keye.py``, and ``xing-serve-sessions-16k``, as
+``rehearse_xing.py``). Run it on two checkouts and compare: the same hash
+is the same program, so the cell cannot move.
 
     JAX_PLATFORMS=cpu python3 tools/program_hashes.py [ROOT] [--dump DIR]
 
@@ -39,7 +42,8 @@ def main(argv):
     from jax.experimental import topologies
     from jax.sharding import NamedSharding
 
-    from benchmark import common, rehearse_kimi_linear, rehearse_xing
+    from benchmark import common, rehearse_keye, rehearse_kimi_linear
+    from benchmark import rehearse_xing
     from benchmark import run as bench_run
     from dlrover_tpu.models import llama
     from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -107,12 +111,67 @@ def main(argv):
     report("kimilinear-train-8k.step", rehearse_kimi_linear.lower_step(
         ctx["config"], ctx["traffic"], device
     ))
-    ctx = context("xing-serve-sessions-16k")
-    programs = rehearse_xing.lower_engine_programs(
-        ctx["config"], device, probes=False
-    )
-    for name in ("jit_step", "jit_prefill"):
-        report("xing-serve-sessions-16k." + name, programs[name])
+    def dense_engine(ctx):
+        """``benchmark/rehearse.serve_programs``' two, lowered and not
+        compiled."""
+        from jax.sharding import SingleDeviceSharding
+
+        from benchmark.runners import serve as serve_runner
+        from dlrover_tpu.models import generate as gen_lib
+        from dlrover_tpu.serving.kvpool import engine as paged
+
+        cfg, eng = common.lm_config(ctx["config"]), ctx["config"]["serve_engine"]
+        one = SingleDeviceSharding(device)
+        arr = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dtype, sharding=one
+        )
+        on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: arr(x.shape, x.dtype), tree
+        )
+        key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+        params = on_chip(jax.eval_shape(
+            lambda k: gen_lib.prepare_decode_params(
+                cfg, serve_runner.init_params(cfg, k)
+            ), key,
+        ))
+        slots, bs = eng["slots"], eng["block_size"]
+        max_blocks = eng["max_len"] // bs
+        num_blocks = slots * max_blocks + 1
+        steps = paged._paged_steps(
+            cfg, slots, num_blocks, max_blocks, bs, eng["prefill_chunk"]
+        )
+        pool = arr(
+            (cfg.n_layers, num_blocks, bs, cfg.n_kv_heads, cfg.head_dim),
+            cfg.compute_dtype,
+        )
+        i32 = jnp.int32
+        return {
+            "jit_step": steps.decode.lower(
+                pool, pool, params, arr((slots, max_blocks), i32),
+                arr((slots,), i32), arr((slots,), i32), arr((slots,), bool),
+                arr((slots,), jnp.float32), key, arr((), i32),
+            ),
+            "jit_prefill": steps.prefill.lower(
+                pool, pool, params, arr((1, eng["prefill_chunk"]), i32),
+                arr((max_blocks,), i32), arr((), i32), arr((), i32),
+                arr((), jnp.float32), key, arr((), i32),
+            ),
+        }
+
+    serve_cells = {
+        "nemo12b-serve-chat": dense_engine,
+        "keye-serve-docqa-32k": lambda ctx: rehearse_keye.lower_engine_programs(
+            ctx["config"], device
+        ),
+        "xing-serve-sessions-16k": lambda ctx:
+            rehearse_xing.lower_engine_programs(
+                ctx["config"], device, probes=False
+            ),
+    }
+    for cell, lower in serve_cells.items():
+        programs = lower(context(cell))
+        for name in ("jit_step", "jit_prefill"):
+            report(cell + "." + name, programs[name])
 
 
 if __name__ == "__main__":
