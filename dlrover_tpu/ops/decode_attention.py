@@ -36,13 +36,13 @@ environment.
 
 A one-token step over a SLAB cache (``generate()``, the flat
 ``ServingEngine``) has no kernel here: it runs
-``models/generate._append_free_attention``, plain XLA. The Pallas
-kernel this module once held for it — a sequential ``(batch, kv_head,
-block)`` grid, also over one layer's pool through a block table — took
-3.58-3.675 ms/token where the append-free step takes 1.26-1.35 (v5e,
-334 M parameters, <= 384-row cache, BENCH_r05), and over a layer's
-pool it would have kept the 2.44 ms per-layer pool slice that PR 25
-removed: deleted in PR 29, not to be rebuilt on that grid.
+``models/generate._append_free_attention``, plain XLA (a Pallas kernel
+on a ``(batch, kv_head, block)`` grid took 3.58-3.675 ms/token where
+that takes 1.26-1.35, BENCH_r05: deleted in PR 29, not to be rebuilt).
+The decode kernels over pools of another layout are modules of their
+own: ``ops/latent_decode_attention.py`` (packed latent rows, PR 40) and
+``ops/flat_decode_attention.py`` (flat rows of 64-wide heads a lane row
+at a time, PR 49; it takes its exact products from :func:`_split_bf16`).
 """
 
 import functools

@@ -24,13 +24,17 @@ after its last VALID row (padding rows change nothing: the state after
 ``n`` rows is a slice of ``[state | z]``, ``conv_lm.conv_mix``), and
 writes the state as of row ``snap_at`` of the chunk into snapshot
 ``snap_id`` (the host passes the sentinel snapshot 0 when it wants
-none). Attention reads the slot's rows through its table as a gathered
-view (the decode step: ``[slots, max_len]``; the chunk: the prefix in
-blocks of :data:`CHUNK_PREFIX_ROWS` under a running softmax) and the new
-tokens' own K/V from the layer's hands; a flat row is read a 128-lane
-row at a time with the queries laid into their heads' lanes
-(:func:`lane_pack`), never split into 64-wide heads, which the device
-would pad and re-lay. Both programs are
+none). Attention reads the slot's rows through its table and the new
+tokens' own K/V from the layer's hands. The decode step reads them IN
+PLACE where :func:`decode_attention_kind` answers ``pool_kernel`` (a
+TPU at the cell's shape: ``ops/flat_decode_attention.py``, a slot's
+filled pages only, a page a DMA, scores and softmax in VMEM; PR 49)
+and as a gathered ``[slots, max_len]`` view, the kernel's definition,
+everywhere else; the chunk gathers the prefix in blocks of
+:data:`CHUNK_PREFIX_ROWS` under a running softmax. Either way a flat
+row is read a 128-lane row at a time with the queries laid into their
+heads' lanes (:func:`lane_pack`), never split into 64-wide heads, which
+the device would pad and re-lay. Both programs are
 append-free: the new rows of the attention layers land after the layer
 loop. Chunk starts are BLOCK-aligned, not chunk-aligned: a prefix hit
 resumes at the boundary its snapshot was taken at.
@@ -48,6 +52,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import conv_lm
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.serving.engine import _place_first
+from dlrover_tpu.serving.kvpool import engine as paged
 from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.latent import (
     _softmax_add,
@@ -100,26 +105,78 @@ def _own_lanes(config, out):
     )
 
 
+def decode_attention_kind(config, pool_dtype, block_size: int,
+                          max_blocks: int, slots: int) -> str:
+    """What the decode step reads its cached rows with
+    (:func:`decode_attend`): ``"pool_kernel"``
+    (``ops.flat_decode_attention.pool_flat_decode_attention``: K and V
+    read from the stacked flat pool in place, filled pages only, scores,
+    softmax and the weighted sum in VMEM) where that kernel lowers — a
+    TPU, a bf16 pool whose page is whole (16, 128) tiles, one DMA, and
+    fits a VMEM chunk, a flat row of whole lane rows with
+    :func:`lane_pack` heads to each, the ``slots`` tables inside the
+    scalar memory — and ``"gathered_view"``, the definition, over every
+    slot's whole table gathered, everywhere else. Decided by what the
+    code can see, like ``latent.decode_attention_kind`` and for its
+    reasons: no option, nothing falls back after it, so what it admits
+    has to compile (``tests/test_tpu_compile.py`` holds it to the cell's
+    shape). ``kv_stats()["conv_decode_attention"]`` and the engine's
+    construction log line say which. The prefill chunk is not its
+    business (:func:`chunk_attend` as it is)."""
+    if not paged._on_tpu():
+        return "gathered_view"
+    # Pallas costs ~1.2 s to import: only a process that may run the
+    # kernel pays it (the repo's idiom for ops/ kernels).
+    from dlrover_tpu.ops.flat_decode_attention import flat_kernel_supported
+
+    pack = lane_pack(config)
+    if flat_kernel_supported(
+        pool_dtype, block_size, config.kv_width, pack * config.head_dim,
+        pack * (config.n_heads // config.n_kv_heads), slots, max_blocks,
+    ):
+        return "pool_kernel"
+    return "gathered_view"
+
+
 def decode_attend(config, k_pool, v_pool, at: int, tables, lengths,
-                  block_size: int, taps=None):
+                  block_size: int, kind=None, active=None):
     """The decode step's ``attend`` for attention layer ``at`` of the
-    pool: one query a slot over the slot's visible rows (``< lengths``),
-    gathered through its table AS STORED (flat rows, read a lane row at
-    a time: :func:`lane_pack`), and over its own new row."""
+    pool: one query a slot over the slot's visible rows (``< lengths``)
+    AS STORED (flat rows, read a lane row at a time: :func:`lane_pack`)
+    and over its own new row.
+
+    ``kind`` (:func:`decode_attention_kind`; None: asked here, of what
+    this call can see) says what reads the rows: ``"pool_kernel"``, the
+    Pallas kernel over the pool in place (a slot that is not ``active``
+    reads nothing there), or ``"gathered_view"``, the definition, over
+    every slot's whole table gathered."""
     slots, max_blocks = tables.shape
     max_len = max_blocks * block_size
     width = lane_pack(config) * config.head_dim
     n_rows = config.kv_width // width
     scale = conv_lm.softmax_scale(config)
     f32 = jnp.float32
+    kind = kind or decode_attention_kind(
+        config, k_pool.dtype, block_size, max_blocks, slots
+    )
 
     def attend(q, k_new, v_new):
-        k_view = k_pool[at, tables].reshape(slots, max_len, -1)
-        v_view = v_pool[at, tables].reshape(slots, max_len, -1)
-        lanes = lambda a, j: a[..., j * width:(j + 1) * width]  # noqa: E731
         qp = _placed(config, q[:, 0])                 # [s, J, pG, width]
         k_own = k_new[:, 0].reshape(slots, n_rows, width)
         v_own = v_new[:, 0].reshape(slots, n_rows, width)
+        if kind == "pool_kernel":
+            from dlrover_tpu.ops.flat_decode_attention import (
+                pool_flat_decode_attention,
+            )
+
+            out = pool_flat_decode_attention(
+                qp, k_own, v_own, k_pool, v_pool, at, tables, lengths,
+                active, scale=scale,
+            )
+            return _own_lanes(config, out)[:, None].astype(q.dtype)
+        k_view = k_pool[at, tables].reshape(slots, max_len, -1)
+        v_view = v_pool[at, tables].reshape(slots, max_len, -1)
+        lanes = lambda a, j: a[..., j * width:(j + 1) * width]  # noqa: E731
         scores = jnp.stack([
             jnp.einsum("sgw,stw->sgt", qp[:, j], lanes(k_view, j),
                        preferred_element_type=f32)
@@ -134,8 +191,6 @@ def decode_attend(config, k_pool, v_pool, at: int, tables, lengths,
             jnp.concatenate([scores, mine[..., None]], axis=-1) * scale,
             axis=-1,
         )
-        if taps is not None:
-            taps.update(probs=probs)
         seen = probs[..., :-1].astype(v_view.dtype)
         out = jnp.stack([
             jnp.einsum("sgt,stw->sgw", seen[:, j], lanes(v_view, j),
@@ -219,20 +274,24 @@ def chunk_attend(config, k_pool, v_pool, at: int, table_row, start,
 
 
 def decode_forward(config, k_pool, v_pool, state, params, tables, lengths,
-                   tokens, block_size: int, taps=None):
+                   tokens, block_size: int, taps=None, *, kind=None,
+                   active=None):
     """All layers for one token a slot: float32 ``logits [slots,
     vocab]``, the attention layers' new rows ``(k, v) [La, slots,
     kv_width]``, every slot's state after the token ``[Lc, slots, taps -
     1, d]`` and the expert layers' counters. ``taps``: a dict a layer's
-    ``{layer: block taps}`` land in (the checks' probes)."""
+    ``{layer: block taps}`` land in (the checks' probes). ``kind``,
+    ``active``: :func:`decode_attend`'s (None: it decides for itself
+    from its operands, and every slot reads its rows below
+    ``lengths``)."""
     positions = lengths[:, None]
     slots = tokens.shape[0]
     x = conv_lm.embed(config, params, tokens[:, None])
     states, k_news, v_news, counters = [], [], [], []
-    for layer, kind in enumerate(config.layer_types):
+    for layer, layer_kind in enumerate(config.layer_types):
         at = config.index_in_kind(layer)
         seen = None if taps is None else taps.setdefault(layer, {})
-        if kind == conv_lm.CONV:
+        if layer_kind == conv_lm.CONV:
             x, zz, c = conv_lm.block(
                 config, params, layer, x, positions, state[at], taps=seen
             )
@@ -241,7 +300,8 @@ def decode_forward(config, k_pool, v_pool, state, params, tables, lengths,
             x, (k_new, v_new), c = conv_lm.block(
                 config, params, layer, x, positions,
                 decode_attend(
-                    config, k_pool, v_pool, at, tables, lengths, block_size
+                    config, k_pool, v_pool, at, tables, lengths, block_size,
+                    kind, active,
                 ),
                 taps=seen,
             )
@@ -297,7 +357,9 @@ def chunk_forward(config, k_pool, v_pool, state, params, tokens, table_row,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts):
+                 counts, kind=None):
+    """``kind``: :func:`decode_attention_kind`'s answer for this shape
+    (None: asked when the step is traced)."""
     max_len = max_blocks * block_size
 
     def step(k, v, state, snaps, params, tables, lengths, tokens, active,
@@ -305,7 +367,8 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         counts["decode"] += 1  # traces only
         tokens = _place_first(tokens, first, first_slot)
         logits, (k_new, v_new), new_state, counters = decode_forward(
-            config, k, v, state, params, tables, lengths, tokens, block_size
+            config, k, v, state, params, tables, lengths, tokens, block_size,
+            kind=kind, active=active,
         )
         write = jnp.minimum(lengths, max_len - 1)
         blk = jnp.take_along_axis(

@@ -103,6 +103,7 @@ class _PagedSteps(NamedTuple):
     pool_attention: str = "xla_gather"   # see pool_attention_kind
     sparse_chunk_attention: str = ""     # sparse_chunk_attention_kind
     latent_decode_attention: str = ""    # latent.decode_attention_kind
+    conv_decode_attention: str = ""      # conv.decode_attention_kind
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -142,9 +143,9 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
         return "latent_absorbed"
     if getattr(config, "kind", "") == "conv_lm":
         # Attention layers among convolution layers (kvpool/conv.py):
-        # K and V held flat, 64-wide heads; both programs read the
-        # slot's rows as a gathered view (the pool kernels take heads
-        # of whole lane rows: ROADMAP.md, Speed).
+        # K and V held flat, 64-wide heads. The prefill chunk reads the
+        # slot's rows as a gathered view; what the decode step reads
+        # them with is conv.decode_attention_kind's to say (PR 49).
         return "conv_gathered_view"
     if getattr(config, "index_topk", 0):
         # A learned selection of the cache (kvpool/sparse.py): index
@@ -829,6 +830,7 @@ def _paged_steps(
             config, config.compute_dtype, block_size, chunk, max_blocks
         ) if attn == "sparse_gather" else "",
         _latent_decode_kind(config, attn, slots, block_size, max_blocks),
+        _conv_decode_kind(config, attn, slots, block_size, max_blocks),
     )
 
 
@@ -837,6 +839,7 @@ def _paged_steps_for(
     config: llama.TpuLMConfig, slots: int, num_blocks: int,
     max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
     attn: str, sparse_chunk: str = "", latent_decode: str = "",
+    conv_decode: str = "",
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
@@ -851,7 +854,7 @@ def _paged_steps_for(
         from dlrover_tpu.serving.kvpool import conv
 
         build_decode = conv.build_decode(
-            config, slots, max_blocks, block_size, counts
+            config, slots, max_blocks, block_size, counts, conv_decode
         )
         build_prefill = conv.build_prefill(
             config, max_blocks, block_size, chunk, counts
@@ -899,7 +902,7 @@ def _paged_steps_for(
     # from them until the importer acks.
     exp = jax.jit(_build_export_gather(counts, n_pools, n_state))
     return _PagedSteps(prefill, decode, cow, imp, exp, counts, attn,
-                       sparse_chunk, latent_decode)
+                       sparse_chunk, latent_decode, conv_decode)
 
 
 class PagedServingEngine(ServingEngine):
@@ -1059,6 +1062,7 @@ class PagedServingEngine(ServingEngine):
             config, slots, self.num_blocks, self.max_blocks,
             block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
         )
+        rows_by = self.latent_decode_attention or self.conv_decode_attention
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
@@ -1070,8 +1074,7 @@ class PagedServingEngine(ServingEngine):
             self._block_holds(), self.pool_attention,
             ", the chunk under its selection by "
             f"{self.sparse_chunk_attention}" if self._index_dim else "",
-            f", the decode step's rows by {self.latent_decode_attention}"
-            if self.latent_decode_attention else "",
+            f", the decode step's rows by {rows_by}" if rows_by else "",
             "; a slot holds " + ", ".join(
                 a.describe() for a in self._state_layout
             ) + f", {self.state_snapshots} snapshots"
@@ -1637,6 +1640,14 @@ class PagedServingEngine(ServingEngine):
         :func:`_latent_decode_kind`.)"""
         return self._steps.latent_decode_attention
 
+    @property
+    def conv_decode_attention(self) -> str:
+        """The same for a convolution / attention pattern model's decode
+        program (``conv.decode_attention_kind``); ``""`` for any other
+        model. Its prefill chunk reads a gathered view either way, which
+        is what :attr:`pool_attention` (``conv_gathered_view``) names."""
+        return self._steps.conv_decode_attention
+
     def kv_stats(self) -> Dict[str, object]:
         """Allocator + prefix-cache accounting (heartbeats, SignalBus,
         bench, the chaos block-reclaim invariant)."""
@@ -1664,6 +1675,8 @@ class PagedServingEngine(ServingEngine):
                 self._prefix_rounded_down_blocks
             )
             stats["moe_rows_dropped"] = self._moe_rows_dropped
+        if self.conv_decode_attention:
+            stats["conv_decode_attention"] = self.conv_decode_attention
         # What a restart pays before the first request: construction
         # and warm-up as the engine timed them (0.0: not warmed up).
         stats["engine_build_s"] = self.engine_build_s
@@ -1848,6 +1861,21 @@ def _latent_decode_kind(config, attn: str, slots: int, block_size: int,
     from dlrover_tpu.serving.kvpool import latent
 
     return latent.decode_attention_kind(
+        config, config.compute_dtype, block_size, max_blocks, slots
+    )
+
+
+def _conv_decode_kind(config, attn: str, slots: int, block_size: int,
+                      max_blocks: int) -> str:
+    """``conv.decode_attention_kind`` for a convolution / attention
+    pattern model's programs, ``""`` for any other's: its part of
+    :func:`_paged_steps`'s key, as :func:`_latent_decode_kind` is the
+    latent model's, down here for the same reasons."""
+    if attn != "conv_gathered_view":
+        return ""
+    from dlrover_tpu.serving.kvpool import conv
+
+    return conv.decode_attention_kind(
         config, config.compute_dtype, block_size, max_blocks, slots
     )
 

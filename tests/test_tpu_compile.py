@@ -742,7 +742,14 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
     step); no ``[..., 8, 64]`` view of the gathered rows exists (the
     device pads a 64-wide head to 128 lanes: attention reads lane rows,
     ``conv.lane_pack``); the expert matmuls are the grouped kernel; and
-    every scope the cell's readers book device time to is there."""
+    every scope the cell's readers book device time to is there. The
+    decode step reads its rows by the Pallas kernel over the flat pools
+    in place (PR 49), once an attention layer, booked to ``attn/gqa``
+    where ``gqa64_attn_ms_per_step`` reads it: no gathered ``[slots,
+    max_len]`` view is left in the step, and its temporaries, 0.62 GB of
+    views and scores before, are under 0.1 GB. The chunk still gathers
+    its prefix a block of 2,048 rows at a time, no kernel of this
+    module's, which is what ``pool_attention`` goes on naming."""
     from benchmark import common, conv_scopes, trace_reduce
     from benchmark import rehearse_lfm2
     from dlrover_tpu.serving.kvpool import conv, engine as paged
@@ -754,6 +761,7 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         n_dense=1,
     )
     pool = "bf16[2,5120,64,512]"
+    kernel = "paged_flat_decode_attention"
     assert logical["k_rows"] == 2 * 5120 * 64 * 512 * 2
     for name in ("jit_step", "jit_prefill"):
         c = programs[name].compile()
@@ -761,7 +769,19 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         assert name + "," in text.splitlines()[0]
         for i in range(4):
             assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
-        assert 6 <= _n_kernels(c) <= 12         # gmm: gate|up, down x 3
+        scopes = trace_reduce.scopes_from_hlo(text)
+        calls = [v for k, v in scopes.items() if k.startswith(kernel)]
+        if name == "jit_step":
+            # gmm: gate|up, down x 3, and the two attention layers'
+            assert 8 <= _n_kernels(c) <= 14
+            assert len(calls) == 2 and all(
+                conv_scopes.scope_of(op_name) == "gqa" for op_name in calls
+            ), calls
+            assert "bf16[32,9216,512]" not in text     # the gathered view
+            assert "f32[32,4,8,9216]" not in text      # its scores
+        else:
+            assert 6 <= _n_kernels(c) <= 12     # gmm: gate|up, down x 3
+            assert not calls and kernel not in text
         made = [
             line for line in text.splitlines()
             if f"= {pool}" in line and " parameter(" not in line
@@ -772,7 +792,6 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
             " copy(" in line or " transpose(" in line for line in made
         ), made
         assert "bf16[32,9216,8,64]" not in text    # heads split out
-        scopes = trace_reduce.scopes_from_hlo(text)
         booked = {conv_scopes.scope_of(v) for v in scopes.values()}
         assert booked >= {"conv", "gqa", "router", "experts", "dense"}
         if name == "jit_prefill":
@@ -782,13 +801,108 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         # the pool's two arrays at their logical bytes among the
         # arguments (a padded minor dimension would double them)
         assert m.alias_size_in_bytes < 2 * logical["k_rows"] + 0.31e9
-        assert m.temp_size_in_bytes < 0.7e9
+        assert m.temp_size_in_bytes < (
+            0.1e9 if name == "jit_step" else 0.7e9
+        )
     from benchmark.runners import serve_conv
 
     cfg = serve_conv.conv_config(cfg_json)
     assert conv.lane_pack(cfg) == 2
     assert paged.pool_attention_kind(cfg, 64, "fp", 512) == \
         "conv_gathered_view"
+    assert conv.decode_attention_kind(
+        cfg, cfg.compute_dtype, 64, 144, 32
+    ) == "pool_kernel"
+
+
+# What ``conv.decode_attention_kind`` sees -> what it must answer;
+# unnamed: a bf16 pool of 64-token pages of 512-wide flat rows (8 KV
+# heads of 64, two to a lane row) under 32 query heads, 32 slots x 144
+# pages (the cell's shape), on a TPU.
+_CONV_KIND_CASES = {
+    "the_cells_shape": ({}, "pool_kernel"),
+    # 128-wide heads, one to a lane row, 16-row pages: 64 pages a chunk
+    "a_head_to_a_lane_row": (
+        dict(head_dim=128, n_kv_heads=4, n_heads=16, block_size=16,
+             max_blocks=576),
+        "pool_kernel",
+    ),
+    "off_the_chip": (dict(on_tpu=False), "gathered_view"),
+    "a_float32_pool": (dict(dtype="float32"), "gathered_view"),
+    # 3 KV heads of 64: 192 lanes a row, and one head to a 64-lane row
+    "a_width_that_is_not_whole_lane_rows": (
+        dict(n_kv_heads=3, n_heads=12), "gathered_view"
+    ),
+    # 8 rows of bf16 are half a (16, 128) tile
+    "a_block_too_small_to_tile": (dict(block_size=8), "gathered_view"),
+    # a 2 MB page for a 1 MB chunk
+    "a_page_past_the_chunk": (dict(block_size=2048, max_blocks=8),
+                              "gathered_view"),
+    # 128 slots x 4,096 pages: 2 MB of tables for 1 MB of scalar memory
+    "tables_past_the_scalar_memory": (
+        dict(slots=128, max_blocks=4096), "gathered_view"
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_KIND_CASES))
+def test_conv_decode_attention_kind_admits_only_what_compiles(
+    case, one_chip, monkeypatch,
+):
+    """What reads the convolution / attention model's decode rows is
+    chosen by what the code can see, and nothing falls back after the
+    choice: where the answer is ``pool_kernel`` the kernel compiles for
+    the described v5e at that shape (the pools read in place: no copy of
+    either), and another platform, a float32 pool or a shape outside the
+    kernel's tiling, VMEM chunk or scalar memory answers
+    ``gathered_view``."""
+    from dlrover_tpu.models import conv_lm
+    from dlrover_tpu.ops import flat_decode_attention as fda
+    from dlrover_tpu.serving.kvpool import conv, engine as paged
+
+    seen, want = _CONV_KIND_CASES[case]
+    on_tpu = seen.get("on_tpu", True)
+    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 144)
+    slots = seen.get("slots", 32)
+    cfg = conv_lm.tiny_config(
+        n_heads=seen.get("n_heads", 32), n_kv_heads=seen.get("n_kv_heads", 8),
+        head_dim=seen.get("head_dim", 64), dtype=seen.get("dtype", "bfloat16"),
+    )
+    assert conv.decode_attention_kind(
+        cfg, cfg.compute_dtype, bs, mb, slots
+    ) == want
+    if want != "pool_kernel":
+        return
+    n_layers, nb = 2, 2 * mb + 1
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip
+    )
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pack = conv.lane_pack(cfg)
+    placed = (slots, cfg.n_kv_heads // pack,
+              pack * cfg.n_heads // cfg.n_kv_heads, 128)
+    c = jax.jit(
+        lambda q, k_own, v_own, k, v, *a: (fda.pool_flat_decode_attention(
+            q, k_own, v_own, k, v, *a, scale=0.125
+        ), k, v),
+        donate_argnums=(3, 4),
+    ).lower(
+        arr(placed, bf), arr(placed[:2] + (128,), bf),
+        arr(placed[:2] + (128,), bf),
+        arr((n_layers, nb, bs, cfg.kv_width), bf),
+        arr((n_layers, nb, bs, cfg.kv_width), bf),
+        arr((), i32), arr((slots, mb), i32), arr((slots,), i32),
+        arr((slots,), bool),
+    ).compile()
+    text = c.as_text()
+    assert _n_kernels(c) == 1
+    assert "paged_flat_decode_attention" in text
+    # the pools go in and out untouched
+    assert f"bf16[{n_layers},{nb}," not in "".join(
+        line for line in text.splitlines() if " copy(" in line
+    )
+    assert c.memory_analysis().temp_size_in_bytes < 16e6
 
 
 # What ``latent.decode_attention_kind`` sees -> what it must answer;

@@ -44,7 +44,16 @@ queries walked in tiles of ``--chunk-query-rows`` rows and in one tile
 nothing else runs and no TPU is needed): rows scored over rows launched
 by the prefill chunks a traced benchmark run of that cell has on record
 (``latent.chunk_rows_scored`` over its ``serving.step`` spans'
-``prefill_tokens``, at the tile its ``kv_stats`` names). A smoke reading, not a
+``prefill_tokens``, at the tile its ``kv_stats`` names). ``conv`` (asked
+for by name too): one attention layer of a convolution / attention
+pattern model's decode step at the ``lfm2-serve-sessions-8k`` shape (32
+slots, fills 8.3-8.8k of 9,216 rows, the 5,120-block pool of 64 x 512
+pages of flat K and V rows, 32 query heads over 8 KV heads of 64), the
+gathered ``[slots, max_len]`` views against
+``pool_flat_decode_attention`` over the pools in place at ``--chunk-kb``
+of pages a VMEM chunk, and with the kernel's compute taken out (its
+page copies alone): ms a call, the visible rows' GB/s and each form's
+error against the exact float32 softmax. A smoke reading, not a
 benchmark: one process, host-clock timing around ``block_until_ready``.
 Times mean something on a TPU only: anywhere else the tool refuses to
 run, unless ``--tiny`` rehearses it (interpret mode, nothing timed).
@@ -174,6 +183,137 @@ TINY_LATENT = dict(
     fills=(9, 23), chunk=8, start=12, n_valid=(1, 3, 8),
     query_rows=(2, 4), model=dict(kv_lora_rank=128, qk_rope_dim=64),
 )
+
+
+CONV = dict(
+    slots=32, max_blocks=144, num_blocks=5120, block=64, layers=2,
+    fills=(8300, 8800), calls=8,
+    model=dict(n_heads=32, n_kv_heads=8, head_dim=64, dtype="bfloat16"),
+)
+TINY_CONV = dict(
+    slots=3, max_blocks=5, num_blocks=16, block=16, layers=2,
+    fills=(20, 70), calls=2,
+    model=dict(n_heads=8, n_kv_heads=4, head_dim=64, dtype="bfloat16"),
+)
+
+
+def _conv(chunk_kb, repeats, seed, tiny):
+    """One attention layer of a convolution / attention pattern model's
+    decode step (``kvpool/conv.decode_attend``: placed queries, the
+    rows, each head's own lanes read back) at the
+    ``lfm2-serve-sessions-8k`` shape, the gathered ``[slots, max_len]``
+    views against the Pallas kernel over the flat pools in place at each
+    of ``chunk_kb`` of pages a VMEM chunk, whole and with its compute
+    taken out (the page copies alone): ms a call (``calls`` chained
+    calls a timed launch, alternating layers, so that a launch's host
+    cost is spread thin), the visible rows' GB/s, and the error against
+    the exact float32 softmax, one JSON line each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.models import conv_lm
+    from dlrover_tpu.ops import flat_decode_attention as fda
+    from dlrover_tpu.serving.kvpool import conv
+
+    shape = TINY_CONV if tiny else CONV
+    cfg = conv_lm.tiny_config(**shape["model"])
+    slots, mb, bs = shape["slots"], shape["max_blocks"], shape["block"]
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.key(seed), 5)
+    pool_dims = (shape["layers"], shape["num_blocks"], bs, cfg.kv_width)
+    k_pool, v_pool = _normal(keys[0], pool_dims), _normal(keys[1], pool_dims)
+    tables = jnp.asarray(
+        1 + rng.permutation(shape["num_blocks"] - 1)[:slots * mb]
+        .reshape(slots, mb).astype(np.int32)
+    )
+    fills = jnp.asarray(rng.integers(*shape["fills"], slots), jnp.int32)
+    q = _normal(keys[2], (slots, 1, cfg.n_heads, cfg.head_dim))
+    k_new = _normal(keys[3], (slots, 1, cfg.n_kv_heads, cfg.head_dim))
+    v_new = _normal(keys[4], (slots, 1, cfg.n_kv_heads, cfg.head_dim))
+    # K and V of the visible rows, read once
+    row_bytes = int(fills.sum()) * cfg.kv_width * 2 * 2
+    calls = shape["calls"]
+
+    def attend(kind):
+        def one(k_pool, v_pool, at, tables, fills, q):
+            return conv.decode_attend(
+                cfg, k_pool, v_pool, at, tables, fills, bs, kind=kind
+            )(q, k_new, v_new)
+
+        def chained(k_pool, v_pool, tables, fills):
+            def body(out, at):
+                # each call's query hangs on the last one's answer
+                return one(
+                    k_pool, v_pool, at, tables, fills,
+                    q + (out * 0).astype(q.dtype),
+                ), None
+
+            layers = jnp.arange(calls, dtype=jnp.int32) % shape["layers"]
+            return jax.lax.scan(body, jnp.zeros_like(q), layers)[0]
+
+        return jax.jit(one), jax.jit(chained)
+
+    def exact(k_pool, v_pool, tables, fills, q, k_new, v_new):
+        """The definition in float32 at the highest precision."""
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+        g = cfg.n_heads // cfg.n_kv_heads
+        view = lambda pool: pool[0][tables].reshape(  # noqa: E731
+            slots, mb * bs, cfg.n_kv_heads, cfg.head_dim
+        ).astype(f32)
+        qh = q[:, 0].astype(f32).reshape(slots, cfg.n_kv_heads, g, -1)
+        s = jnp.einsum("skgd,stkd->skgt", qh, view(k_pool), precision=hi)
+        s = jnp.where(
+            (jnp.arange(mb * bs)[None, :] < fills[:, None])[:, None, None],
+            s, -jnp.inf,
+        )
+        mine = jnp.einsum(
+            "skgd,skd->skg", qh, k_new[:, 0].astype(f32), precision=hi
+        )
+        p = jax.nn.softmax(
+            jnp.concatenate([s, mine[..., None]], -1)
+            * conv_lm.softmax_scale(cfg), axis=-1,
+        )
+        out = jnp.einsum(
+            "skgt,stkd->skgd", p[..., :-1], view(v_pool), precision=hi
+        ) + p[..., -1:] * v_new[:, 0].astype(f32)[:, :, None]
+        return out.reshape(slots, 1, cfg.n_heads, cfg.head_dim)
+
+    want = jax.jit(exact)(k_pool, v_pool, tables, fills, q, k_new, v_new)
+
+    def report(form, kind, **more):
+        one, chained = attend(kind)
+        got = one(k_pool, v_pool, jnp.int32(0), tables, fills, q)
+        got = got.astype(jnp.float32)
+        err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        ms = _timed(lambda: jax.block_until_ready(
+            chained(k_pool, v_pool, tables, fills)
+        ), repeats)
+        line = {"part": "conv", "form": form, **more, "rel_err_of_exact": err}
+        if ms is not None:
+            line.update(ms=round(ms / calls, 4),
+                        rows_gb_s=round(row_bytes * calls / ms / 1e6, 1))
+        print(json.dumps(line), flush=True)
+        return bool(jnp.isfinite(got).all()), err
+
+    ok, view_err = report(
+        "gathered_view", "gathered_view", slots=slots,
+        rows_mean=float(fills.mean()),
+    )
+    shipped, attend_chunk = fda.CHUNK_BYTES, fda._attend
+    try:
+        for kb in chunk_kb:
+            fda.CHUNK_BYTES = kb << 10
+            more = dict(chunk_kb=kb, shipped=fda.CHUNK_BYTES == shipped)
+            finite, err = report("pool_kernel", "pool_kernel", **more)
+            # no less exact than the form it replaces
+            ok = ok and finite and err <= max(view_err, 1e-5)
+            fda._attend = lambda *a, **kw: a[5]
+            report("pool_kernel_copies_alone", "pool_kernel", **more)
+            fda._attend = attend_chunk
+    finally:
+        fda.CHUNK_BYTES, fda._attend = shipped, attend_chunk
+    return ok
 
 
 def _latent_chunk(cfg, pool, p, layer, table_row, shape, query_rows,
@@ -742,7 +882,8 @@ def main():
                     "ops.decode_attention._CHUNK_QUERY_ROWS)")
     ap.add_argument("--chunk-kb", default="256,512,1024,2048",
                     help="VMEM chunk sizes to time the kernel at (the "
-                    "one shipped: ops.decode_attention._POOL_CHUNK_BYTES)")
+                    "one shipped: ops.decode_attention._POOL_CHUNK_BYTES; "
+                    "conv: ops.flat_decode_attention.CHUNK_BYTES)")
     ap.add_argument("--tile-rows",
                     help="latent: device rows a VMEM tile of the latent "
                     "kernel, to time it at (default, the one shipped: "
@@ -785,6 +926,14 @@ def main():
             "run in interpret mode and its times would mean nothing; "
             "--tiny rehearses the script without timing"
         )
+    if "conv" in parts:
+        parts.remove("conv")
+        ok = _conv(
+            [int(kb) for kb in ns.chunk_kb.split(",")],
+            0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
+        )
+        if not ok or not parts:
+            raise SystemExit(0 if ok else 1)
     if "latent" in parts:
         parts.remove("latent")
         ok = _latent(

@@ -4,8 +4,9 @@ the paged engine can reach, for a described TPU v5e: ``mistral7b-train``'s
 step, ``kimilinear-train-8k``'s step, and the two engine programs of each
 accepted serve cell (``nemo12b-serve-chat``, built as
 ``benchmark/rehearse.py`` builds them, ``keye-serve-docqa-32k``, as
-``rehearse_keye.py``, and ``xing-serve-sessions-16k``, as
-``rehearse_xing.py``). Run it on two checkouts and compare: the same hash
+``rehearse_keye.py``, ``xing-serve-sessions-16k``, as
+``rehearse_xing.py``, and ``lfm2-serve-sessions-8k``, as
+``rehearse_lfm2.py``). Run it on two checkouts and compare: the same hash
 is the same program, so the cell cannot move.
 
     JAX_PLATFORMS=cpu python3 tools/program_hashes.py [ROOT] [--dump DIR]
@@ -43,7 +44,7 @@ def main(argv):
     from jax.sharding import NamedSharding
 
     from benchmark import common, rehearse_keye, rehearse_kimi_linear
-    from benchmark import rehearse_xing
+    from benchmark import rehearse_lfm2, rehearse_xing
     from benchmark import run as bench_run
     from dlrover_tpu.models import llama
     from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -167,6 +168,10 @@ def main(argv):
             rehearse_xing.lower_engine_programs(
                 ctx["config"], device, probes=False
             ),
+        "lfm2-serve-sessions-8k": lambda ctx:
+            rehearse_lfm2.lower_engine_programs(
+                ctx["config"], device, probes=False
+            )[0],
     }
     for cell, lower in serve_cells.items():
         programs = lower(context(cell))
