@@ -1,17 +1,17 @@
-"""Grouped-query decode attention over a pool of FLAT K/V rows, in place.
+"""Grouped-query attention over a pool of FLAT K/V rows, in place.
 
-One function, :func:`pool_flat_decode_attention` (Pallas), and the
-predicate that says where it lowers (:func:`flat_kernel_supported`): the
-attention of ``serving/kvpool/conv.py``'s decode step on a TPU. One
-query token a slot against the two arrays such a model's pool holds,
-``[attention layers, num_blocks, block_size, kv_width]``: a token's K
-(V) of a layer held FLAT, heads narrower than a 128-lane row side by
-side in it (``conv.lane_pack``: 8 heads of 64 are four lane rows). The
-pools are read where they lie: a slot's filled pages only, a page one
-contiguous DMA, scores, softmax and the weighted sum in VMEM. The
-gathered ``[slots, max_len]`` views it replaces wrote every slot's WHOLE
-table of K and of V once and read it back, and walked float32 scores
-through HBM in the softmax's passes (PERF.md section 6, PR 49).
+:func:`pool_flat_decode_attention` (Pallas; :func:`flat_kernel_supported`
+says where it lowers) is the attention of ``serving/kvpool/conv.py``'s
+decode step on a TPU, one query token a slot, and at the file's end
+:func:`pool_flat_chunk_attention` (:func:`flat_chunk_kernel_supported`)
+its prefill chunk's, a tile of tokens at a time (PR 50), against the two
+arrays such a model's pool holds, ``[attention layers, num_blocks,
+block_size, kv_width]``: a token's K (V) held FLAT, heads narrower than
+a 128-lane row side by side in it (``conv.lane_pack``). The pools are
+read where they lie: filled pages only, a page one contiguous DMA,
+scores, softmax and the weighted sum in VMEM. The gathered views they
+replace wrote the rows out and read them back, and walked float32
+scores through HBM in the softmax's passes (PERF.md section 6, PR 49).
 
 A flat row is never split into heads: a ``[.., 8, 64]`` view is another
 tiling on the device (every head padded to 128 lanes). A page ``[block_size,
@@ -373,3 +373,343 @@ def pool_flat_decode_attention(
         name="paged_flat_decode_attention",
     )(*scalars, q32, s_own, v_own.astype(f32), k_pool, v_pool)
     return out.reshape(b, lane_rows, rows, width)[:, :, :n_q]
+
+
+# ---- a prefill chunk's attention over the flat pool, in place -------------
+
+# (imported down here: no line above the decode kernel's call site moves)
+from dlrover_tpu.ops.decode_attention import _page_stream  # noqa: E402
+
+# Bytes of K (and of V) of the prefix one inner step of the CHUNK kernel
+# copies and attends: the score tile of a lane row is ``[keys of such a
+# chunk, token tile x queries a token]`` float32, so the chunk is sized
+# with the tile (``kvpool/conv.CHUNK_TOKEN_TILE``), not by its DMAs. On
+# the v5e at the ``lfm2-serve-sessions-8k`` shape (512 launched rows
+# over 8,512 cached, one layer, every row valid;
+# ``tools/bench_paged_decode.py --parts conv``, my chip run, PR 50): at a
+# tile of 128 tokens 256 KB / 512 KB / 1 MB take 0.830 / 0.812 / 0.846
+# ms a call, at 64 tokens 0.924 / 0.876 / 0.879, where the gathered form
+# takes 6.166 and the kernel's copies and launch alone 0.25-0.36.
+CHUNK_PREFIX_BYTES = 512 << 10
+
+_TN = (((0,), (0,)), ((), ()))      # [n, d] x [n, m] -> [d, m]
+
+
+def _prefix_pages(block_size: int, kv_width: int) -> int:
+    """Pages of a bf16 pool in one VMEM chunk of the chunk kernel: as
+    many whole pages as ``CHUNK_PREFIX_BYTES`` holds; 0 where not even
+    one fits."""
+    return CHUNK_PREFIX_BYTES // (block_size * kv_width * 2)
+
+
+def _chunk_vmem_bytes(block_size: int, kv_width: int, rows: int,
+                      chunk: int, tile: int) -> int:
+    """An upper reckoning of the chunk kernel's VMEM at a bf16 pool: the
+    four page buffers, the chunk's own K and V (pipelined blocks: two
+    buffers each), a tile's queries and answer, the running statistics
+    and accumulator, and the live score tiles of one lane row."""
+    lane_rows = kv_width // LANES
+    cols = max(_prefix_pages(block_size, kv_width) * block_size, tile)
+    q_rows = tile * rows
+    return (
+        4 * cols * kv_width * 2                   # K, V double-buffered
+        + 2 * 2 * chunk * kv_width * 2            # own K, V
+        + 2 * 2 * lane_rows * q_rows * LANES * 2  # q in, answer out
+        + lane_rows * q_rows * (2 * 8 + LANES) * 4    # max, sum, acc
+        + 4 * cols * q_rows * 4                   # scores, probabilities
+    )
+
+
+def flat_chunk_kernel_supported(pool_dtype, block_size: int, kv_width: int,
+                                lane_row: int, rows: int, chunk: int,
+                                tile: int, max_blocks: int) -> bool:
+    """Shapes :func:`pool_flat_chunk_attention` lowers for on a TPU: a
+    bf16 pool whose page ``[block_size, kv_width]`` is whole (16, 128)
+    tiles, one contiguous DMA, and fits a VMEM chunk, a flat row of
+    whole lane rows that each hold whole heads (``lane_row`` is 128), a
+    ``chunk`` of whole token tiles of ``tile`` tokens whose ``rows``
+    placed queries a token make whole 128-lane blocks of a score tile's
+    columns (and whole (16, 128) tiles of the chunk's own rows), buffers
+    that fit the VMEM the kernel asks for, and a table that fits the
+    scalar memory."""
+    return bool(
+        jnp.dtype(pool_dtype) == jnp.bfloat16
+        and lane_row == LANES and kv_width % LANES == 0
+        and block_size % 16 == 0
+        and _prefix_pages(block_size, kv_width) >= 1
+        and tile > 0 and chunk % tile == 0 and tile % 16 == 0
+        and (tile * rows) % LANES == 0
+        and _chunk_vmem_bytes(block_size, kv_width, rows, chunk, tile)
+        <= VMEM_BYTES
+        and max_blocks * 4 <= SMEM_TABLE_BYTES
+    )
+
+
+def _dot_as_stored(a, b, dims):
+    """bf16 by bf16 is exact in float32: one MXU pass. Anything else
+    (interpret mode on float32 pools) is a float32 contraction."""
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+    return lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32), dims,
+        precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _attend_keys(q_ref, m_ref, l_ref, acc_ref, j, k, v, visible, scale):
+    """One online-softmax step of lane row ``j``'s placed queries
+    (``q_ref[j]`` ``[rows, 128]``, as they come) over keys ``k`` /
+    values ``v`` ``[n, 128]``, lane row ``j`` of ``n`` flat rows as
+    stored; ``visible`` ``[n, rows]``, or None where every key is.
+    Scores lie KEYS x QUERIES (``ops/decode_attention._online_softmax``'s
+    reasons: the reductions run down the sublanes and the statistics
+    ``m_ref`` / ``l_ref`` ``[J, 1, rows]`` are lane-dense rows; the
+    accumulator ``acc_ref`` is ``[J, 128, rows]``), float32, scaled
+    where the definition scales them; the probabilities are rounded to
+    the rows' dtype once before they meet V, as the definition rounds
+    them. A masked key's probability is exp(NEG_INF - m) == 0 once the
+    running max is a real logit, and every caller shows every query a
+    key in every call."""
+    s = _dot_as_stored(k, q_ref[j], _NT) * scale       # [n, rows]
+    if visible is not None:
+        s = jnp.where(visible, s, NEG_INF)
+    m_prev = m_ref[j]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[j] = alpha * l_ref[j] + jnp.sum(p, axis=0, keepdims=True)
+    acc_ref[j] = acc_ref[j] * alpha + _dot_as_stored(
+        v, p.astype(v.dtype), _TN
+    )
+    m_ref[j] = m_new
+
+
+def _chunk_kernel(
+    layer_ref, start_ref, valid_ref, tbl_ref,     # scalar prefetch
+    q_ref, kn_ref, vn_ref, k_hbm, v_hbm,          # inputs
+    o_ref,                                        # output
+    kbuf, vbuf, sem, m_ref, l_ref, acc_ref,       # scratch
+    *, chunk_pages: int, block_size: int, lane_rows: int, rows: int,
+    tile: int, scale: float,
+):
+    """One call = one attention layer's attention for one slot's prefill
+    chunk; one grid step = ``tile`` of its tokens, every lane row.
+
+    A tile whose first token is at or past ``valid_ref`` (the padding of
+    a short chunk) copies no page, runs no matmul and answers ZEROS: its
+    rows are never read as queries' answers, but they ride on through
+    the later layers and land in the pool, so nothing non-finite may
+    leave here. Any other tile runs two key groups through one online
+    softmax a (lane row, query row) (:func:`_attend_keys`):
+
+    - the slot's rows ``[0, start)``, page by page from the pools in
+      HBM (``ops/decode_attention._page_stream``, the chunk kernels'
+      copies: a page one contiguous DMA into double-buffered VMEM
+      chunks of ``chunk_pages`` pages, the next chunk in flight while
+      this one is attended), every row visible to every query, the last
+      chunk masked at ``start``;
+    - the chunk's own rows up to the tile's last, from VMEM, the tiles
+      before this one whole and this one causally.
+
+    Query rows lie ``[lane_rows, tile * rows, 128]``, row ``r`` of a
+    lane row is token ``r // rows``. Lane row ``j`` of the keys is a
+    static, lane-aligned slice of a buffer as stored. A chunk of pages
+    is attended only if it holds a row below ``start`` and a token sees
+    itself, so the running max is a real logit after the first call."""
+    step = pl.program_id(0)
+    layer = layer_ref[0]
+    start = start_ref[0]
+    cols = chunk_pages * block_size
+    q_rows = tile * rows
+    n_pages = (start + block_size - 1) // block_size
+    n_chunks = (n_pages + chunk_pages - 1) // chunk_pages
+    lanes = lambda j: slice(j * LANES, (j + 1) * LANES)  # noqa: E731
+
+    start_copies, wait_copies, _ = _page_stream(
+        layer, tbl_ref, n_pages, k_hbm, v_hbm, kbuf, vbuf, sem,
+        chunk_pages=chunk_pages, page_rows=block_size,
+    )
+
+    def attend(keys, values, visible):
+        for j in range(lane_rows):
+            _attend_keys(
+                q_ref, m_ref, l_ref, acc_ref, j, keys(j), values(j),
+                visible, scale,
+            )
+
+    @pl.when(step == 0)
+    def _():
+        # Finite wherever a page copy has not written (0 x NaN).
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(step * tile >= valid_ref[0])
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(step * tile < valid_ref[0])
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(n_chunks > 0)
+        def _():
+            start_copies(0, 0)
+
+        def chunk_body(chunk, b):
+            @pl.when(chunk + 1 < n_chunks)
+            def _():
+                start_copies(chunk + 1, 1 - b)
+
+            wait_copies(chunk, b)
+            filled = start - chunk * cols       # rows of it below start
+            stored = (
+                lambda j: kbuf[b, 0, :, lanes(j)],
+                lambda j: vbuf[b, 0, :, lanes(j)],
+            )
+
+            # Only the prefix's last chunk has rows to hide (what its
+            # page copies left of an earlier chunk, and a last page's
+            # rows past ``start``): every other one skips the mask.
+            @pl.when(filled >= cols)
+            def _():
+                attend(*stored, None)
+
+            @pl.when(filled < cols)
+            def _():
+                key = lax.broadcasted_iota(jnp.int32, (cols, q_rows), 0)
+                attend(*stored, key < filled)
+
+            return 1 - b
+
+        lax.fori_loop(0, n_chunks, chunk_body, 0)
+
+        def own(tile_at, visible):
+            at = pl.ds(pl.multiple_of(tile_at * tile, tile), tile)
+            attend(
+                lambda j: kn_ref[at, lanes(j)], lambda j: vn_ref[at, lanes(j)],
+                visible,
+            )
+
+        def before(tile_at, carry):
+            own(tile_at, None)
+            return carry
+
+        lax.fori_loop(0, step, before, 0)
+        # This tile's own keys: token u is visible to token t iff u <= t.
+        token = lax.broadcasted_iota(jnp.int32, (tile, q_rows), 1) // rows
+        own(step, lax.broadcasted_iota(jnp.int32, (tile, q_rows), 0) <= token)
+        for j in range(lane_rows):
+            o_ref[j] = (acc_ref[j] / l_ref[j]).T.astype(o_ref.dtype)
+
+
+def pool_flat_chunk_attention(
+    q,             # [T, J, R, 128] — one slot's chunk, placed queries
+    k_own,         # [T, J * 128] — the chunk's own K / V rows, flat;
+    v_own,         #   not yet in the pool
+    k_pool,        # [layers, num_blocks, block_size, J * 128]
+    v_pool,
+    layer,         # [] int32 — which attention layer of the stacked pool
+    table_row,     # [max_blocks] int32 — the slot's pages
+    start,         # [] int32 — rows of the slot already filled: [0, start)
+    n_valid=None,  # [] int32 — tokens at or past it are padding
+    *,
+    scale: float,
+    tile: int,
+    interpret=None,
+):
+    """A prefill chunk's attention of a model whose pool holds FLAT K/V
+    rows (``serving/kvpool/conv.py``), the pool read IN PLACE: the
+    softmax of the chunk's queries (positions ``start + t``) over the
+    slot's rows below ``start`` and over the chunk's own rows causally,
+    and the probabilities' sum of the V rows, without a gathered prefix,
+    without a score in HBM, and only for the token tiles (``tile``
+    tokens a grid step) that hold one of the chunk's ``n_valid`` tokens
+    (None: all of them): a tile past them answers exact zeros
+    (:func:`_chunk_kernel`).
+
+    ``q`` is laid over LANE ROWS (``conv._placed``) as
+    :func:`pool_flat_decode_attention` takes it, one token after the
+    other; the answer ``[T, J, R, 128]`` in ``q.dtype`` is every query's
+    weighted sum of whole lane rows of V (``conv._own_lanes`` reads each
+    head's own lanes back). The pools go in whole (``memory_space=ANY``)
+    as the device holds them; layer, ``start``, ``n_valid`` and the
+    table ride as scalar prefetch and pick the pages below ``start``,
+    each one contiguous DMA, once a tile.
+
+    Arithmetic is the definition's (``conv.chunk_attend``'s gathered
+    form): queries, K and V as they come, bf16 by bf16 into float32
+    logits, the scale on the logits, float32 running max, sum and
+    accumulator, the probabilities rounded to the rows' dtype once
+    before they meet V, no row dropped. What differs is the order of
+    summation: an online softmax over chunks of ``CHUNK_PREFIX_BYTES``
+    of pages and then the chunk's own rows a tile at a time, where the
+    definition walks blocks of ``conv.CHUNK_PREFIX_ROWS``."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t, lane_rows, n_q, width = q.shape
+    _, _, block_size, kv_width = k_pool.shape
+    if (width, lane_rows * width) != (LANES, kv_width):
+        raise ValueError(
+            f"queries over {lane_rows} lane rows of {width} against flat "
+            f"rows of {kv_width}: a lane row is {LANES} lanes"
+        )
+    if t % tile:
+        raise ValueError(f"a chunk of {t} tokens in tiles of {tile}")
+    max_blocks = table_row.shape[0]
+    # Off the chip (interpret mode, float32 pools) any page goes; on it
+    # the caller asked flat_chunk_kernel_supported.
+    chunk_pages = max(1, _prefix_pages(block_size, kv_width))
+    cols = chunk_pages * block_size
+    q_rows = tile * n_q
+    # [T, J, R, 128] -> [J, T * R, 128]: a lane row's query rows
+    # together, token-major, so that a token tile is a run of rows.
+    qs = q.transpose(1, 0, 2, 3).reshape(lane_rows, t * n_q, width)
+    i32 = jnp.int32
+    scalars = (
+        jnp.asarray(layer, i32).reshape(1),
+        jnp.clip(jnp.asarray(start, i32), 0, max_blocks * block_size)
+        .reshape(1),
+        jnp.asarray(t if n_valid is None else n_valid, i32).reshape(1),
+        jnp.asarray(table_row, i32),
+    )
+    tiled = pl.BlockSpec(
+        (lane_rows, q_rows, width), lambda i, *_: (0, i, 0)
+    )
+    whole = pl.BlockSpec((t, kv_width), lambda i, *_: (0, 0))
+    f32 = jnp.float32
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_kernel, chunk_pages=chunk_pages, block_size=block_size,
+            lane_rows=lane_rows, rows=n_q, tile=tile, scale=scale,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(t // tile,),
+            in_specs=[
+                tiled, whole, whole,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=tiled,
+            scratch_shapes=[
+                # (two buffers, ONE lane group: a page is copied whole)
+                pltpu.VMEM((2, 1, cols, kv_width), k_pool.dtype),
+                pltpu.VMEM((2, 1, cols, kv_width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((lane_rows, 1, q_rows), f32),
+                pltpu.VMEM((lane_rows, 1, q_rows), f32),
+                pltpu.VMEM((lane_rows, width, q_rows), f32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_flat_chunk_attention",
+    )(*scalars, qs, k_own, v_own, k_pool, v_pool)
+    return out.reshape(lane_rows, t, n_q, width).transpose(1, 0, 2, 3)

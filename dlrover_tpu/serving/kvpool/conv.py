@@ -25,19 +25,19 @@ after its last VALID row (padding rows change nothing: the state after
 writes the state as of row ``snap_at`` of the chunk into snapshot
 ``snap_id`` (the host passes the sentinel snapshot 0 when it wants
 none). Attention reads the slot's rows through its table and the new
-tokens' own K/V from the layer's hands. The decode step reads them IN
-PLACE where :func:`decode_attention_kind` answers ``pool_kernel`` (a
-TPU at the cell's shape: ``ops/flat_decode_attention.py``, a slot's
-filled pages only, a page a DMA, scores and softmax in VMEM; PR 49)
-and as a gathered ``[slots, max_len]`` view, the kernel's definition,
-everywhere else; the chunk gathers the prefix in blocks of
-:data:`CHUNK_PREFIX_ROWS` under a running softmax. Either way a flat
-row is read a 128-lane row at a time with the queries laid into their
-heads' lanes (:func:`lane_pack`), never split into 64-wide heads, which
-the device would pad and re-lay. Both programs are
-append-free: the new rows of the attention layers land after the layer
-loop. Chunk starts are BLOCK-aligned, not chunk-aligned: a prefix hit
-resumes at the boundary its snapshot was taken at.
+tokens' own K/V from the layer's hands. Both programs read them IN
+PLACE where :func:`decode_attention_kind` / :func:`chunk_attention_kind`
+answer ``pool_kernel`` (a TPU at the cell's shape:
+``ops/flat_decode_attention.py``, filled pages only, a page a DMA,
+scores and softmax in VMEM; PRs 49, 50; the chunk a tile of tokens at a
+time, and only the tiles below ``n_valid``), and by their definitions
+everywhere else: a gathered ``[slots, max_len]`` view, and the prefix
+in blocks of :data:`CHUNK_PREFIX_ROWS` under a running softmax. Either
+way a flat row is read a 128-lane row at a time with the queries laid
+into their heads' lanes (:func:`lane_pack`), never split into 64-wide
+heads, which the device would pad and re-lay. Both programs are
+append-free: the new rows land after the layer loop. Chunk starts are
+BLOCK-aligned, not chunk-aligned: a hit resumes at its snapshot's row.
 
 The programs keep the names ``step`` and ``prefill`` (a trace names a
 device op by its program), and the decode step returns, after the
@@ -122,7 +122,7 @@ def decode_attention_kind(config, pool_dtype, block_size: int,
     has to compile (``tests/test_tpu_compile.py`` holds it to the cell's
     shape). ``kv_stats()["conv_decode_attention"]`` and the engine's
     construction log line say which. The prefill chunk is not its
-    business (:func:`chunk_attend` as it is)."""
+    business but :func:`chunk_attention_kind`'s."""
     if not paged._on_tpu():
         return "gathered_view"
     # Pallas costs ~1.2 s to import: only a process that may run the
@@ -203,24 +203,24 @@ def decode_attend(config, k_pool, v_pool, at: int, tables, lengths,
 
 
 def chunk_attend(config, k_pool, v_pool, at: int, table_row, start,
-                 block_size: int):
+                 block_size: int, n_valid=None, kind=None):
     """The prefill chunk's ``attend`` for attention layer ``at``: the
     chunk's queries (positions ``start ...``) over the slot's rows below
-    ``start``, a block of :data:`CHUNK_PREFIX_ROWS` at a time, and over
-    the chunk's own rows, causally; rows read flat, a lane row at a time
-    (:func:`lane_pack`)."""
-    heads = config.n_heads
+    ``start`` and over the chunk's own rows, causally, rows read flat a
+    lane row at a time. ``kind`` ``"pool_kernel"``: in place. Else, here,
+    the DEFINITION: blocks of :data:`CHUNK_PREFIX_ROWS`, every row scored."""
+    if kind == "pool_kernel":
+        return chunk_attend_in_place(config, k_pool, v_pool, at, table_row,
+                                     start, n_valid)
     per = max(CHUNK_PREFIX_ROWS // block_size, 1)    # table entries a block
     span = per * block_size
     n_table = -(-table_row.shape[0] // per) * per
-    table = jnp.pad(
-        table_row, (0, n_table - table_row.shape[0]),
-        constant_values=SENTINEL_BLOCK,
-    )
+    table = jnp.pad(table_row, (0, n_table - table_row.shape[0]),
+                    constant_values=SENTINEL_BLOCK)
     width = lane_pack(config) * config.head_dim
     n_rows = config.kv_width // width
     scale = conv_lm.softmax_scale(config)
-    f32 = jnp.float32
+    f32, heads = jnp.float32, config.n_heads
 
     def attend(q, k_new, v_new):
         chunk = q.shape[1]
@@ -321,24 +321,23 @@ def decode_forward(config, k_pool, v_pool, state, params, tables, lengths,
 
 
 def chunk_forward(config, k_pool, v_pool, state, params, tokens, table_row,
-                  start, slot, block_size: int, taps=None):
+                  start, slot, block_size: int, taps=None, n_valid=None,
+                  kind=None):
     """All layers for one slot's chunk ``tokens [1, chunk]`` at rows
-    ``start ...`` from the slot's state: the final residual ``[1, chunk,
-    d]``, the attention layers' new rows ``(k, v) [La, chunk,
-    kv_width]`` and every convolution layer's ``zz [Lc, taps - 1 +
-    chunk, d]`` (``conv_lm.conv_mix``: the state as of any row of the
-    chunk is a slice of it)."""
+    ``start ...`` from the slot's state: the final residual, the attention
+    layers' new rows ``(k, v) [La, chunk, kv_width]`` and the convolution
+    layers' ``zz [Lc, taps - 1 + chunk, d]``. ``kind`` None: asked here."""
     chunk = tokens.shape[1]
+    kind = kind or chunk_attention_kind(config, k_pool.dtype, block_size,
+                                        table_row.shape[0], chunk)
     positions = (start + jnp.arange(chunk, dtype=jnp.int32))[None]
     x = conv_lm.embed(config, params, tokens)
     zzs, k_news, v_news = [], [], []
-    for layer, kind in enumerate(config.layer_types):
+    for layer, layer_kind in enumerate(config.layer_types):
         at = config.index_in_kind(layer)
         seen = None if taps is None else taps.setdefault(layer, {})
-        if kind == conv_lm.CONV:
-            own = jax.lax.dynamic_index_in_dim(
-                state[at], slot, axis=0, keepdims=True
-            )
+        if layer_kind == conv_lm.CONV:
+            own = jax.lax.dynamic_slice_in_dim(state[at], slot, 1)
             x, zz, _ = conv_lm.block(
                 config, params, layer, x, positions, own, taps=seen
             )
@@ -347,7 +346,8 @@ def chunk_forward(config, k_pool, v_pool, state, params, tokens, table_row,
             x, (k_new, v_new), _ = conv_lm.block(
                 config, params, layer, x, positions,
                 chunk_attend(
-                    config, k_pool, v_pool, at, table_row, start, block_size
+                    config, k_pool, v_pool, at, table_row, start, block_size,
+                    n_valid, kind,
                 ),
                 taps=seen,
             )
@@ -403,7 +403,9 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts):
+                  counts, kind=None):
+    """``kind``: :func:`chunk_attention_kind`'s answer for this shape
+    (None: asked when the chunk is traced)."""
     if chunk % block_size:
         raise ValueError(
             f"prefill_chunk {chunk} must be whole blocks of {block_size}: "
@@ -418,7 +420,7 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         counts["prefill"] += 1  # traces only
         x, (k_new, v_new), zzs = chunk_forward(
             config, k, v, state, params, tokens, table_row, start, slot,
-            block_size,
+            block_size, n_valid=n_valid, kind=kind,
         )
         if k_new:
             # Whole blocks from a block-aligned start; a block past the
@@ -459,3 +461,102 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         return k, v, state, snaps, first
 
     return prefill
+
+
+# ---- the prefill chunk over the pool in place ------------------------------
+#
+# Down here, below every line the decode step's kernel is called from: a
+# compiled kernel carries its call sites' line numbers (PERF.md section
+# 6, PRs 35, 40), so the file is LINE-NEUTRAL down to ``build_decode``'s
+# end whatever the chunk gains.
+
+# Tokens of a chunk one grid step of the chunk kernel attends: a tile
+# whose first token is at or past the chunk's ``n_valid`` reads nothing
+# (this traffic's turns fill ~215 of a chunk's 512 rows). Chosen on the
+# chip with the kernel's VMEM chunk (``tools/bench_paged_decode.py
+# --parts conv``, my chip run, PR 50; PERF.md section 6): one layer over
+# the cell's turns (64-512 valid rows, log-uniform) takes 0.498-0.530 ms
+# in tiles of 64, 0.505-0.518 in tiles of 128 and 0.542 in tiles of 256
+# (a tile costs 0.09 / 0.17 / 0.29 ms over ~0.15 a call): nothing to
+# choose between 64 and 128, and 128 is half the grid steps.
+CHUNK_TOKEN_TILE = 128
+
+
+def chunk_token_tile(chunk: int) -> int:
+    """Tokens a tile of a ``chunk``-token prefill chunk holds:
+    :data:`CHUNK_TOKEN_TILE`, or the whole chunk where that does not
+    divide it."""
+    return chunk if chunk % CHUNK_TOKEN_TILE else CHUNK_TOKEN_TILE
+
+
+def chunk_rows_scored(n_valid, chunk: int, kind: str):
+    """Query rows (tokens) a chunk of ``n_valid`` valid rows scores: all
+    ``chunk`` of them under the gathered form, and under the kernel its
+    tiles up to the last that holds a valid row (the kernel's own test,
+    tile by tile: ``first token < n_valid``)."""
+    if kind != "pool_kernel":
+        return chunk
+    tile = chunk_token_tile(chunk)
+    return -(-n_valid // tile) * tile
+
+
+def chunk_attention_kind(config, pool_dtype, block_size: int,
+                         max_blocks: int, chunk: int) -> str:
+    """What a prefill chunk reads its slot's rows with
+    (:func:`chunk_attend`): ``"pool_kernel"``
+    (``ops.flat_decode_attention.pool_flat_chunk_attention``: the prefix
+    read from the stacked flat pool in place, its pages below ``start``
+    only, scores, softmax and the weighted sum in VMEM, a tile of
+    :func:`chunk_token_tile` tokens at a time and only the tiles that
+    hold a valid row) where that kernel lowers — a TPU, a bf16 pool whose
+    page is whole (16, 128) tiles, one DMA, and fits a VMEM chunk, a flat
+    row of whole lane rows with :func:`lane_pack` heads to each, a chunk
+    of whole token tiles whose placed queries are whole lane blocks, a
+    table inside the scalar memory — and ``"gathered_view"``, the
+    definition, everywhere else. Decided by what the code can see, as
+    :func:`decode_attention_kind` is and for its reasons: no option,
+    nothing falls back after it, so what it admits has to compile
+    (``tests/test_tpu_compile.py``). ``kv_stats()["conv_chunk_attention"]``
+    and the engine's construction log line say which."""
+    if not paged._on_tpu():
+        return "gathered_view"
+    from dlrover_tpu.ops.flat_decode_attention import (
+        flat_chunk_kernel_supported,
+    )
+
+    pack = lane_pack(config)
+    if flat_chunk_kernel_supported(
+        pool_dtype, block_size, config.kv_width, pack * config.head_dim,
+        pack * (config.n_heads // config.n_kv_heads), chunk,
+        chunk_token_tile(chunk), max_blocks,
+    ):
+        return "pool_kernel"
+    return "gathered_view"
+
+
+def chunk_attend_in_place(config, k_pool, v_pool, at: int, table_row, start,
+                          n_valid=None):
+    """:func:`chunk_attend` through the Pallas kernel over the pool in
+    place (``ops.flat_decode_attention.pool_flat_chunk_attention``):
+    the same queries, keys, values, mask and softmax, the prefix never
+    gathered and no score in HBM. Tokens at or past ``n_valid`` (a traced
+    scalar; None: none) are padding: the token tiles that hold none of
+    the valid ones are not scored and answer exact ZEROS, never NaN or
+    Inf (they still ride through every later layer and land in the
+    pool: written, invisible, overwritten)."""
+    from dlrover_tpu.ops.flat_decode_attention import (
+        pool_flat_chunk_attention,
+    )
+
+    scale = conv_lm.softmax_scale(config)
+
+    def attend(q, k_new, v_new):
+        chunk = q.shape[1]
+        out = pool_flat_chunk_attention(
+            _placed(config, q[0]), k_new[0].reshape(chunk, -1),
+            v_new[0].reshape(chunk, -1), k_pool, v_pool, at, table_row,
+            start, n_valid, scale=scale, tile=chunk_token_tile(chunk),
+        )
+        return _own_lanes(config, out)[None].astype(q.dtype)
+
+    return attend

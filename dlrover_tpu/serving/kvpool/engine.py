@@ -104,6 +104,7 @@ class _PagedSteps(NamedTuple):
     sparse_chunk_attention: str = ""     # sparse_chunk_attention_kind
     latent_decode_attention: str = ""    # latent.decode_attention_kind
     conv_decode_attention: str = ""      # conv.decode_attention_kind
+    conv_chunk_attention: str = ""       # conv.chunk_attention_kind
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -143,9 +144,9 @@ def pool_attention_kind(config, block_size: int, kv_dtype: str,
         return "latent_absorbed"
     if getattr(config, "kind", "") == "conv_lm":
         # Attention layers among convolution layers (kvpool/conv.py):
-        # K and V held flat, 64-wide heads. The prefill chunk reads the
-        # slot's rows as a gathered view; what the decode step reads
-        # them with is conv.decode_attention_kind's to say (PR 49).
+        # K and V held flat, 64-wide heads. The name is the programs'
+        # definition; what each reads the slot's rows with is for
+        # conv.decode_attention_kind / chunk_attention_kind to say.
         return "conv_gathered_view"
     if getattr(config, "index_topk", 0):
         # A learned selection of the cache (kvpool/sparse.py): index
@@ -831,6 +832,7 @@ def _paged_steps(
         ) if attn == "sparse_gather" else "",
         _latent_decode_kind(config, attn, slots, block_size, max_blocks),
         _conv_decode_kind(config, attn, slots, block_size, max_blocks),
+        _conv_chunk_kind(config, attn, block_size, max_blocks, chunk),
     )
 
 
@@ -839,7 +841,7 @@ def _paged_steps_for(
     config: llama.TpuLMConfig, slots: int, num_blocks: int,
     max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
     attn: str, sparse_chunk: str = "", latent_decode: str = "",
-    conv_decode: str = "",
+    conv_decode: str = "", conv_chunk: str = "",
 ) -> _PagedSteps:
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
     quantized = kv_dtype == "int8"
@@ -857,7 +859,7 @@ def _paged_steps_for(
             config, slots, max_blocks, block_size, counts, conv_decode
         )
         build_prefill = conv.build_prefill(
-            config, max_blocks, block_size, chunk, counts
+            config, max_blocks, block_size, chunk, counts, conv_chunk
         )
     elif attn == "latent_absorbed":
         # Imported here: the module builds on this one.
@@ -902,7 +904,7 @@ def _paged_steps_for(
     # from them until the importer acks.
     exp = jax.jit(_build_export_gather(counts, n_pools, n_state))
     return _PagedSteps(prefill, decode, cow, imp, exp, counts, attn,
-                       sparse_chunk, latent_decode, conv_decode)
+                       sparse_chunk, latent_decode, conv_decode, conv_chunk)
 
 
 class PagedServingEngine(ServingEngine):
@@ -1038,6 +1040,7 @@ class PagedServingEngine(ServingEngine):
         self._state_restores_from_snapshot = 0
         self._state_snapshots_taken = 0
         self._prefix_rounded_down_blocks = 0
+        self._chunk_rows_launched = self._chunk_rows_scored = 0
         self._slot_snapshot = [0] * slots
         build.mark("prefix_cache")
         # The base __init__ builds every pool array via _alloc_pool().
@@ -1063,6 +1066,8 @@ class PagedServingEngine(ServingEngine):
             block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
         )
         rows_by = self.latent_decode_attention or self.conv_decode_attention
+        if self.conv_chunk_attention:
+            rows_by += f", the chunk's by {self.conv_chunk_attention}"
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
@@ -1648,6 +1653,15 @@ class PagedServingEngine(ServingEngine):
         is what :attr:`pool_attention` (``conv_gathered_view``) names."""
         return self._steps.conv_decode_attention
 
+    @property
+    def conv_chunk_attention(self) -> str:
+        """The same for that model's prefill chunk
+        (``conv.chunk_attention_kind``): ``pool_kernel`` where the chunk
+        reads the pool in place a tile of tokens at a time, scoring only
+        the tiles that hold a valid row, ``gathered_view`` where it runs
+        its definition; ``""`` for any other model."""
+        return self._steps.conv_chunk_attention
+
     def kv_stats(self) -> Dict[str, object]:
         """Allocator + prefix-cache accounting (heartbeats, SignalBus,
         bench, the chaos block-reclaim invariant)."""
@@ -1677,6 +1691,11 @@ class PagedServingEngine(ServingEngine):
             stats["moe_rows_dropped"] = self._moe_rows_dropped
         if self.conv_decode_attention:
             stats["conv_decode_attention"] = self.conv_decode_attention
+            stats["conv_chunk_attention"] = self.conv_chunk_attention
+            # Token rows the chunks launched carried, and those their
+            # attention scored (the kernel skips the tiles of padding).
+            stats["conv_chunk_rows_launched"] = self._chunk_rows_launched
+            stats["conv_chunk_rows_scored"] = self._chunk_rows_scored
         # What a restart pays before the first request: construction
         # and warm-up as the engine timed them (0.0: not warmed up).
         stats["engine_build_s"] = self.engine_build_s
@@ -1830,6 +1849,15 @@ class PagedServingEngine(ServingEngine):
         at its last whole-block boundary, in the chunk that holds it."""
         if not self._state_layout:
             return ()
+        if self.conv_chunk_attention:
+            # (counted here, the one thing every chunk launch of such a
+            # model calls below the programs' call sites: D15)
+            from dlrover_tpu.serving.kvpool import conv
+
+            self._chunk_rows_launched += self.prefill_chunk
+            self._chunk_rows_scored += conv.chunk_rows_scored(
+                n_valid, self.prefill_chunk, self.conv_chunk_attention
+            )
         boundary = req.prompt_len // self.block_size * self.block_size
         snap_at = snap_id = 0
         # Exactly one chunk of a prompt has the boundary past its first
@@ -1877,6 +1905,19 @@ def _conv_decode_kind(config, attn: str, slots: int, block_size: int,
 
     return conv.decode_attention_kind(
         config, config.compute_dtype, block_size, max_blocks, slots
+    )
+
+
+def _conv_chunk_kind(config, attn: str, block_size: int, max_blocks: int,
+                     chunk: int) -> str:
+    """``conv.chunk_attention_kind`` likewise: what that model's prefill
+    chunk reads its slot's rows with."""
+    if attn != "conv_gathered_view":
+        return ""
+    from dlrover_tpu.serving.kvpool import conv
+
+    return conv.chunk_attention_kind(
+        config, config.compute_dtype, block_size, max_blocks, chunk
     )
 
 

@@ -8,6 +8,8 @@ boundary, a growing session, a hit rounded down to the deepest
 snapshot); preemption, a reused slot, eviction, migration; what the
 engine refuses."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -294,3 +296,58 @@ def test_no_program_retraces_across_admissions(tiny, warm):
     before = dict(warm.trace_counts)
     serve(warm, [(p, 3) for p in prompts(cfg, [9, 12, 21, 5], seed=14)])
     assert dict(warm.trace_counts) == before
+
+
+def test_the_chunk_through_the_pool_kernel_emits_the_same_tokens(monkeypatch):
+    """The prefill program built with the chunk kernel — the platform
+    probe patched, the predicate admitting a float32 pool of 4-row
+    pages, tiles of 4 tokens in chunks of 8, two pages a VMEM chunk, the
+    kernel interpreted — emits the greedy tokens of the program built
+    over the gathered prefix: cold prompts whose last chunks are mostly
+    padding, and a prefix hit that resumes off a chunk boundary.
+    ``kv_stats()`` names the kind and counts the rows the chunks
+    launched and those their attention scored."""
+    from dlrover_tpu.ops import flat_decode_attention as fda
+    from dlrover_tpu.serving.kvpool import conv
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    # two KV heads of 64 are ONE lane row, under two query heads each
+    cfg = conv_lm.tiny_config(n_heads=4, n_kv_heads=2, head_dim=64)
+    params = seeded_params(cfg, 0)
+    first, other, third = prompts(cfg, [14, 9, 27], seed=2)
+
+    def a_day():
+        eng = engine(cfg, params, slots=2)
+        tokens = serve(eng, [(first, 3), (third, 5)])
+        tokens += serve(eng, [(first[:12] + other, 6)])
+        return tokens, eng.kv_stats()
+
+    want, stats = a_day()
+    assert stats["conv_chunk_attention"] == "gathered_view"
+    # 14 -> 2 chunks, 27 -> 4, the hit's 9 rows after 12 -> 2
+    assert stats["conv_chunk_rows_launched"] == 8 * CHUNK
+    assert stats["conv_chunk_rows_scored"] == 8 * CHUNK
+    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fda, "flat_chunk_kernel_supported", lambda *a: True)
+    monkeypatch.setattr(fda, "CHUNK_PREFIX_BYTES", 2 * BS * cfg.kv_width * 4)
+    monkeypatch.setattr(conv, "CHUNK_TOKEN_TILE", 4)
+    # tile and VMEM chunk are no part of a program's key: programs of
+    # the test's own
+    monkeypatch.setattr(paged, "_paged_steps_for", functools.lru_cache(
+        maxsize=16
+    )(paged._paged_steps_for.__wrapped__))
+    calls = []
+    kernel = fda.pool_flat_chunk_attention
+    monkeypatch.setattr(
+        fda, "pool_flat_chunk_attention",
+        lambda *a, **kw: calls.append((a[5], kw["tile"])) or kernel(*a, **kw),
+    )
+    got, stats = a_day()
+    assert calls == [(0, 4), (1, 4)]   # traced once, both attention layers
+    assert stats["conv_chunk_attention"] == "pool_kernel"
+    assert stats["conv_decode_attention"] == "gathered_view"   # float32
+    assert stats["pool_attention"] == "conv_gathered_view"
+    assert got == want
+    assert stats["conv_chunk_rows_launched"] == 8 * CHUNK
+    # valid rows 8, 6 | 8, 8, 8, 3 | 8, 1 in tiles of 4
+    assert stats["conv_chunk_rows_scored"] == 16 + 28 + 12

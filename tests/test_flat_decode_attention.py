@@ -169,6 +169,139 @@ def test_queries_that_are_not_lane_rows_are_refused():
         )
 
 
+# ---- the prefill chunk's kernel --------------------------------------------
+
+CHUNK, TILE = 32, 8
+# rows below the chunk: none, one block, blocks that are no multiple of
+# the definition's CHUNK_PREFIX_ROWS (patched to two blocks) nor of the
+# kernel's VMEM chunk, and a table's worth
+STARTS = (0, BS, 5 * BS, 8 * BS)
+# valid rows: one, a tile's edge, mid-tile, the whole chunk
+VALID = (1, TILE, TILE + 3, CHUNK)
+
+
+def _chunk_exact(cfg, q, k_new, v_new, k_pool, v_pool, at, table_row, start):
+    """The chunk's definition, float32 at the highest precision, by
+    64-wide heads over the slot's logical rows below ``start`` and the
+    chunk's own."""
+    hi = jax.lax.Precision.HIGHEST
+    f32, kh, hd = jnp.float32, cfg.n_kv_heads, cfg.head_dim
+    chunk = q.shape[1]
+    rows = lambda pool, new: jnp.concatenate([  # noqa: E731
+        pool.astype(f32)[at][table_row].reshape(-1, kh, hd),
+        new[0].astype(f32),
+    ])
+    qh = q[0].astype(f32).reshape(chunk, kh, -1, hd)
+    s = jnp.einsum("qkgd,tkd->kgqt", qh, rows(k_pool, k_new), precision=hi)
+    cached = table_row.shape[0] * k_pool.shape[2]
+    seen = jnp.concatenate([
+        jnp.broadcast_to(jnp.arange(cached)[None, :] < start,
+                         (chunk, cached)),
+        jnp.arange(chunk)[None, :] <= jnp.arange(chunk)[:, None],
+    ], axis=1)
+    p = jax.nn.softmax(
+        jnp.where(seen, s * conv_lm.softmax_scale(cfg), -jnp.inf), axis=-1
+    )
+    out = jnp.einsum("kgqt,tkd->qkgd", p, rows(v_pool, v_new), precision=hi)
+    return out.reshape(1, chunk, cfg.n_heads, hd)
+
+
+@pytest.mark.parametrize("dtype,prefix_pages", [
+    ("float32", 1), ("float32", 3), ("float32", 64), ("bfloat16", 2),
+])
+def test_flat_chunk_kernel_matches_the_gathered_form(
+    dtype, prefix_pages, monkeypatch,
+):
+    """A chunk of 32 tokens in tiles of 8 over layer 1 of a two-layer
+    pool, 8 KV heads of 64 under 4 query heads each: the kernel in place
+    against ``chunk_attend``'s ``jax.numpy`` form and the exact softmax
+    at every ``start`` and every ``n_valid``. The table's entries past
+    the prefix are the sentinel and every page the chunk may not read
+    holds NaNs; the rows of the tiles past the last scored one are
+    exactly zero and every output is finite."""
+    cfg = conv_lm.tiny_config(
+        n_heads=32, n_kv_heads=8, head_dim=64, dtype=dtype
+    )
+    dt = cfg.compute_dtype
+    layers, at, nb = 2, 1, MB + 4
+    monkeypatch.setattr(
+        fda, "CHUNK_PREFIX_BYTES", prefix_pages * BS * cfg.kv_width * 2
+    )
+    monkeypatch.setattr(conv, "CHUNK_PREFIX_ROWS", 2 * BS)
+    monkeypatch.setattr(conv, "CHUNK_TOKEN_TILE", TILE)
+    ks = jax.random.split(jax.random.key(7), 5)
+    normal = lambda k, *dims: jax.random.normal(  # noqa: E731
+        k, dims
+    ).astype(dt)
+    k_pool = normal(ks[0], layers, nb, BS, cfg.kv_width)
+    v_pool = normal(ks[1], layers, nb, BS, cfg.kv_width)
+    q = normal(ks[2], 1, CHUNK, cfg.n_heads, cfg.head_dim)
+    k_new = normal(ks[3], 1, CHUNK, cfg.n_kv_heads, cfg.head_dim)
+    v_new = normal(ks[4], 1, CHUNK, cfg.n_kv_heads, cfg.head_dim)
+    order = 1 + np.random.RandomState(1).permutation(nb - 1)
+
+    def form(kind, layer=at):
+        return jax.jit(lambda k, v, table, start, n_valid: conv.chunk_attend(
+            cfg, k, v, layer, table, start, BS, n_valid, kind
+        )(q, k_new, v_new).astype(jnp.float32))
+
+    kernel, view = form("pool_kernel"), form("gathered_view")
+    exact = jax.jit(lambda table, start: _chunk_exact(
+        cfg, q, k_new, v_new, k_pool, v_pool, at, table, start
+    ))
+    for start in STARTS:
+        pages = -(-start // BS)
+        table = np.full(MB + 2, paged.SENTINEL_BLOCK, np.int32)
+        table[:pages] = order[:pages]
+        unread = np.ones(nb, bool)
+        unread[order[:pages]] = False
+        table, start = jnp.asarray(table), jnp.int32(start)
+        want = np.asarray(exact(table, start))
+        # (a CPU has no bf16 x bf16 -> f32 matmul of the definition's
+        # shape: the bf16 kernel is held to the exact softmax alone)
+        gathered = want if dtype == "bfloat16" else np.asarray(
+            view(k_pool, v_pool, table, start, CHUNK)
+        )
+        poisoned = (
+            k_pool.at[:, unread].set(jnp.nan),
+            v_pool.at[:, unread].set(jnp.nan), table, start,
+        )
+        for n_valid in VALID:
+            got = np.asarray(kernel(*poisoned, jnp.int32(n_valid)))
+            scored = conv.chunk_rows_scored(n_valid, CHUNK, "pool_kernel")
+            assert scored == -(-n_valid // TILE) * TILE
+            assert np.isfinite(got).all()
+            assert not got[0, scored:].any()
+            got, near = got[0, :scored], gathered[0, :scored]
+            if dtype == "float32":
+                # the order of summation alone
+                tol = dict(rtol=2e-5, atol=2e-5)
+                np.testing.assert_allclose(got, want[0, :scored], **tol)
+                np.testing.assert_allclose(got, near, **tol)
+            else:
+                # its probabilities and its answer rounded once each
+                np.testing.assert_allclose(
+                    got, want[0, :scored], rtol=2 ** -6, atol=2 ** -6
+                )
+    assert conv.chunk_rows_scored(3, CHUNK, "gathered_view") == CHUNK
+    # another layer of the same pool is another answer (below start)
+    other = np.asarray(form("pool_kernel", 0)(
+        k_pool, v_pool, table, start, jnp.int32(CHUNK)
+    ))
+    assert np.abs(other[0] - got).max() > 1e-2
+
+
+def test_a_chunk_that_is_not_whole_tiles_is_refused():
+    cfg = conv_lm.tiny_config(n_heads=4, n_kv_heads=2, head_dim=64)
+    with pytest.raises(ValueError, match="in tiles of 8"):
+        fda.pool_flat_chunk_attention(
+            jnp.zeros((12, 1, 4, 128)), jnp.zeros((12, 128)),
+            jnp.zeros((12, 128)), jnp.zeros((1, 4, 4, 128)),
+            jnp.zeros((1, 4, 4, 128)), 0, jnp.zeros((2,), jnp.int32), 0,
+            scale=conv_lm.softmax_scale(cfg), tile=8,
+        )
+
+
 # ---- through the engine ----------------------------------------------------
 
 
@@ -254,9 +387,9 @@ def test_the_tools_conv_part_rehearses_off_a_tpu():
     """``tools/bench_paged_decode.py --parts conv --tiny``: the gathered
     form, then at each ``--chunk-kb`` the kernel (interpret mode), no
     further from the exact softmax than the gathered form, and the
-    kernel with its compute taken out, a line each; no time anywhere.
-    The program's own chunk size is back where it was afterwards (the
-    tool sets it, no option of the program does)."""
+    kernel with its compute taken out, a line each; then the same three
+    of the prefill chunk at each (``--token-tiles``, ``--prefix-kb``);
+    no time anywhere."""
     import json
     import os
     import subprocess
@@ -268,19 +401,26 @@ def test_the_tools_conv_part_rehearses_off_a_tpu():
     )
     out = subprocess.run(
         [sys.executable, tool, "--tiny", "--parts", "conv",
-         "--chunk-kb", "4"],
+         "--chunk-kb", "4", "--token-tiles", "8", "--prefix-kb", "4"],
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, text=True,
         capture_output=True, timeout=300,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     rows = [json.loads(x) for x in out.stdout.splitlines() if x[:1] == "{"]
+    forms = ["gathered_view", "pool_kernel", "pool_kernel_copies_alone"]
     assert [(r["part"], r["form"]) for r in rows] == [
-        ("conv", "gathered_view"),
-        ("conv", "pool_kernel"), ("conv", "pool_kernel_copies_alone"),
+        (part, form) for part in ("conv", "conv_chunk") for form in forms
     ]
-    assert [r["chunk_kb"] for r in rows[1:]] == [4, 4]
-    view, kernel, copies = rows
+    assert [r["chunk_kb"] for r in rows[1:3]] == [4, 4]
+    view, kernel, copies = rows[:3]
     assert kernel["rel_err_of_exact"] <= view["rel_err_of_exact"] < 0.01
     # nothing attended: the answer is the new token's own V row
     assert copies["rel_err_of_exact"] > 0.5
-    assert not [k for r in rows for k in r if k in ("ms", "rows_gb_s")]
+    view, kernel, copies = rows[3:]
+    assert (kernel["tile"], kernel["prefix_kb"]) == (8, 4)
+    # float32 off the chip: the order of summation alone
+    assert max(kernel["rel_err_of_exact"], view["rel_err_of_exact"]) < 1e-5
+    assert copies["rel_err_of_exact"] == 1.0     # nothing attended: zeros
+    assert not [
+        k for r in rows for k in r if k in ("ms", "rows_gb_s", "turn_ms")
+    ]

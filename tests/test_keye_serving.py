@@ -496,8 +496,8 @@ def test_sparse_programs_are_what_the_sparse_builders_give(params):
     and prefill programs ``_paged_steps_for`` hands a sparse engine lower
     to the text ``kvpool/sparse.py``'s builders give when called
     directly, whatever else that function has learnt to build since (a
-    latent model's decode kind and a convolution / attention model's are
-    further parts of its key, "" here)."""
+    latent model's decode kind and a convolution / attention model's two
+    are further parts of its key, "" here)."""
     from dlrover_tpu.serving.kvpool import engine as paged
 
     slots, max_blocks = 3, MAX_LEN // BS
@@ -505,7 +505,7 @@ def test_sparse_programs_are_what_the_sparse_builders_give(params):
     steps = eng._steps
     assert steps is paged._paged_steps_for(
         CFG, slots, eng.num_blocks, max_blocks, BS, CHUNK, "fp",
-        "sparse_gather", "masked_attention", "", "",
+        "sparse_gather", "masked_attention", "", "", "",
     )
     assert steps.latent_decode_attention == ""
     counts = {"prefill": 0, "decode": 0}

@@ -747,9 +747,13 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
     in place (PR 49), once an attention layer, booked to ``attn/gqa``
     where ``gqa64_attn_ms_per_step`` reads it: no gathered ``[slots,
     max_len]`` view is left in the step, and its temporaries, 0.62 GB of
-    views and scores before, are under 0.1 GB. The chunk still gathers
-    its prefix a block of 2,048 rows at a time, no kernel of this
-    module's, which is what ``pool_attention`` goes on naming."""
+    views and scores before, are under 0.1 GB. The prefill chunk reads
+    its prefix by the chunk kernel over the same pools in place (PR 50),
+    once an attention layer under ``attn/gqa`` of ``jit_prefill``, where
+    ``gqa64_chunk_attn_ms_per_chunk`` reads it: no gathered block of the
+    prefix and no float32 scores of 512 rows against one are left in the
+    layer body, and its temporaries, 0.64 GB before, are under 0.2 GB.
+    ``pool_attention`` goes on naming the definition."""
     from benchmark import common, conv_scopes, trace_reduce
     from benchmark import rehearse_lfm2
     from dlrover_tpu.serving.kvpool import conv, engine as paged
@@ -761,7 +765,8 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         n_dense=1,
     )
     pool = "bf16[2,5120,64,512]"
-    kernel = "paged_flat_decode_attention"
+    kernels = {"jit_step": "paged_flat_decode_attention",
+               "jit_prefill": "paged_flat_chunk_attention"}
     assert logical["k_rows"] == 2 * 5120 * 64 * 512 * 2
     for name in ("jit_step", "jit_prefill"):
         c = programs[name].compile()
@@ -770,18 +775,24 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         for i in range(4):
             assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
         scopes = trace_reduce.scopes_from_hlo(text)
-        calls = [v for k, v in scopes.items() if k.startswith(kernel)]
+        calls = [
+            v for k, v in scopes.items() if k.startswith(kernels[name])
+        ]
+        # gmm: gate|up, down x 3, and the two attention layers'
+        assert 8 <= _n_kernels(c) <= 14
+        assert len(calls) == 2 and all(
+            conv_scopes.scope_of(op_name) == "gqa" for op_name in calls
+        ), calls
+        assert not [k for k in kernels.values() if k != kernels[name]
+                    and k in text]
         if name == "jit_step":
-            # gmm: gate|up, down x 3, and the two attention layers'
-            assert 8 <= _n_kernels(c) <= 14
-            assert len(calls) == 2 and all(
-                conv_scopes.scope_of(op_name) == "gqa" for op_name in calls
-            ), calls
             assert "bf16[32,9216,512]" not in text     # the gathered view
             assert "f32[32,4,8,9216]" not in text      # its scores
         else:
-            assert 6 <= _n_kernels(c) <= 12     # gmm: gate|up, down x 3
-            assert not calls and kernel not in text
+            assert "bf16[32,64,512]" not in text       # a gathered block
+            assert "bf16[2048,512]" not in text
+            assert "f32[32,512,2048]" not in text      # its scores
+            assert "f32[32,512,512]" not in text       # the chunk's own
         made = [
             line for line in text.splitlines()
             if f"= {pool}" in line and " parameter(" not in line
@@ -802,7 +813,7 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         # arguments (a padded minor dimension would double them)
         assert m.alias_size_in_bytes < 2 * logical["k_rows"] + 0.31e9
         assert m.temp_size_in_bytes < (
-            0.1e9 if name == "jit_step" else 0.7e9
+            0.1e9 if name == "jit_step" else 0.2e9
         )
     from benchmark.runners import serve_conv
 
@@ -812,6 +823,9 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
         "conv_gathered_view"
     assert conv.decode_attention_kind(
         cfg, cfg.compute_dtype, 64, 144, 32
+    ) == "pool_kernel"
+    assert conv.chunk_attention_kind(
+        cfg, cfg.compute_dtype, 64, 144, 512
     ) == "pool_kernel"
 
 
@@ -845,6 +859,21 @@ _CONV_KIND_CASES = {
 }
 
 
+def _conv_kind_case(seen, monkeypatch):
+    """What a case of the two tables above and below sees: the platform
+    probe patched, and ``(config, block_size, max_blocks)``."""
+    from dlrover_tpu.models import conv_lm
+    from dlrover_tpu.serving.kvpool import engine as paged
+
+    on_tpu = seen.get("on_tpu", True)
+    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    cfg = conv_lm.tiny_config(
+        n_heads=seen.get("n_heads", 32), n_kv_heads=seen.get("n_kv_heads", 8),
+        head_dim=seen.get("head_dim", 64), dtype=seen.get("dtype", "bfloat16"),
+    )
+    return cfg, seen.get("block_size", 64), seen.get("max_blocks", 144)
+
+
 @pytest.mark.parametrize("case", sorted(_CONV_KIND_CASES))
 def test_conv_decode_attention_kind_admits_only_what_compiles(
     case, one_chip, monkeypatch,
@@ -856,19 +885,12 @@ def test_conv_decode_attention_kind_admits_only_what_compiles(
     either), and another platform, a float32 pool or a shape outside the
     kernel's tiling, VMEM chunk or scalar memory answers
     ``gathered_view``."""
-    from dlrover_tpu.models import conv_lm
     from dlrover_tpu.ops import flat_decode_attention as fda
-    from dlrover_tpu.serving.kvpool import conv, engine as paged
+    from dlrover_tpu.serving.kvpool import conv
 
     seen, want = _CONV_KIND_CASES[case]
-    on_tpu = seen.get("on_tpu", True)
-    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
-    bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 144)
+    cfg, bs, mb = _conv_kind_case(seen, monkeypatch)
     slots = seen.get("slots", 32)
-    cfg = conv_lm.tiny_config(
-        n_heads=seen.get("n_heads", 32), n_kv_heads=seen.get("n_kv_heads", 8),
-        head_dim=seen.get("head_dim", 64), dtype=seen.get("dtype", "bfloat16"),
-    )
     assert conv.decode_attention_kind(
         cfg, cfg.compute_dtype, bs, mb, slots
     ) == want
@@ -898,6 +920,92 @@ def test_conv_decode_attention_kind_admits_only_what_compiles(
     text = c.as_text()
     assert _n_kernels(c) == 1
     assert "paged_flat_decode_attention" in text
+    # the pools go in and out untouched
+    assert f"bf16[{n_layers},{nb}," not in "".join(
+        line for line in text.splitlines() if " copy(" in line
+    )
+    assert c.memory_analysis().temp_size_in_bytes < 16e6
+
+
+# What ``conv.chunk_attention_kind`` sees -> what it must answer;
+# unnamed: the same pool and heads, one slot's table of 144 pages under a
+# chunk of 512 tokens, on a TPU.
+_CONV_CHUNK_KIND_CASES = {
+    "the_cells_shape": ({}, "pool_kernel"),
+    # 128-wide heads, one to a lane row, 16-row pages: 4 placed queries
+    "a_head_to_a_lane_row": (
+        dict(head_dim=128, n_kv_heads=4, n_heads=16, block_size=16,
+             max_blocks=576),
+        "pool_kernel",
+    ),
+    # 192 tokens are no whole tiles of 128: one tile of 192
+    "a_chunk_that_is_one_odd_tile": (dict(chunk=192), "pool_kernel"),
+    "off_the_chip": (dict(on_tpu=False), "gathered_view"),
+    "a_float32_pool": (dict(dtype="float32"), "gathered_view"),
+    "a_width_that_is_not_whole_lane_rows": (
+        dict(n_kv_heads=3, n_heads=12), "gathered_view"
+    ),
+    "a_block_too_small_to_tile": (dict(block_size=8), "gathered_view"),
+    # a 2 MB page for a 512 KB chunk
+    "a_page_past_the_chunk": (dict(block_size=2048, max_blocks=8),
+                              "gathered_view"),
+    # 8 tokens are half a (16, 128) tile of the chunk's own rows
+    "a_chunk_too_small_to_tile": (dict(chunk=8), "gathered_view"),
+    # 2,112 tokens as ONE tile: 16,896 query rows of scores
+    "an_odd_tile_past_the_vmem": (dict(chunk=2112), "gathered_view"),
+    # 262,144 pages: 1 MB of table for the scalar memory
+    "a_table_past_the_scalar_memory": (
+        dict(max_blocks=262144), "gathered_view"
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_CHUNK_KIND_CASES))
+def test_conv_chunk_attention_kind_admits_only_what_compiles(
+    case, one_chip, monkeypatch,
+):
+    """What reads the convolution / attention model's prefix under a
+    prefill chunk is chosen by what the code can see, and nothing falls
+    back after the choice: where the answer is ``pool_kernel`` the chunk
+    kernel compiles for the described v5e at that shape (the pools read
+    in place: no copy of either), and another platform, a float32 pool
+    or a shape outside the kernel's tiling, VMEM or scalar memory
+    answers ``gathered_view``."""
+    from dlrover_tpu.ops import flat_decode_attention as fda
+    from dlrover_tpu.serving.kvpool import conv
+
+    seen, want = _CONV_CHUNK_KIND_CASES[case]
+    cfg, bs, mb = _conv_kind_case(seen, monkeypatch)
+    chunk = seen.get("chunk", 512)
+    assert conv.chunk_attention_kind(
+        cfg, cfg.compute_dtype, bs, mb, chunk
+    ) == want
+    if want != "pool_kernel":
+        return
+    n_layers, nb = 2, 2 * mb + 1
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip
+    )
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pack = conv.lane_pack(cfg)
+    placed = (chunk, cfg.n_kv_heads // pack,
+              pack * cfg.n_heads // cfg.n_kv_heads, 128)
+    c = jax.jit(
+        lambda q, k_own, v_own, k, v, *a: (fda.pool_flat_chunk_attention(
+            q, k_own, v_own, k, v, *a, scale=0.125,
+            tile=conv.chunk_token_tile(chunk),
+        ), k, v),
+        donate_argnums=(3, 4),
+    ).lower(
+        arr(placed, bf), arr((chunk, cfg.kv_width), bf),
+        arr((chunk, cfg.kv_width), bf),
+        arr((n_layers, nb, bs, cfg.kv_width), bf),
+        arr((n_layers, nb, bs, cfg.kv_width), bf),
+        arr((), i32), arr((mb,), i32), arr((), i32), arr((), i32),
+    ).compile()
+    text = c.as_text()
+    assert _n_kernels(c) == 1
+    assert "paged_flat_chunk_attention" in text
     # the pools go in and out untouched
     assert f"bf16[{n_layers},{nb}," not in "".join(
         line for line in text.splitlines() if " copy(" in line

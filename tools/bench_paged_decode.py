@@ -53,7 +53,15 @@ gathered ``[slots, max_len]`` views against
 ``pool_flat_decode_attention`` over the pools in place at ``--chunk-kb``
 of pages a VMEM chunk, and with the kernel's compute taken out (its
 page copies alone): ms a call, the visible rows' GB/s and each form's
-error against the exact float32 softmax. A smoke reading, not a
+error against the exact float32 softmax; then one attention layer of
+its PREFILL CHUNK (``conv.chunk_attend``: 512 launched rows over one
+slot's 8,512 cached rows), the gathered form against
+``pool_flat_chunk_attention`` at each of ``--token-tiles`` tokens a grid
+step and ``--prefix-kb`` of pages a VMEM chunk, whole and with its
+compute taken out: ms a call with every row valid and with 215, and
+``turn_ms``, the mean over the cell's turns (64-512 valid rows,
+log-uniform) from the times at one, two, ... scored tiles. A smoke
+reading, not a
 benchmark: one process, host-clock timing around ``block_until_ready``.
 Times mean something on a TPU only: anywhere else the tool refuses to
 run, unless ``--tiny`` rehearses it (interpret mode, nothing timed).
@@ -188,11 +196,13 @@ TINY_LATENT = dict(
 CONV = dict(
     slots=32, max_blocks=144, num_blocks=5120, block=64, layers=2,
     fills=(8300, 8800), calls=8,
+    chunk=512, start=8512, valid=(64, 512), some_valid=215,
     model=dict(n_heads=32, n_kv_heads=8, head_dim=64, dtype="bfloat16"),
 )
 TINY_CONV = dict(
     slots=3, max_blocks=5, num_blocks=16, block=16, layers=2,
     fills=(20, 70), calls=2,
+    chunk=32, start=40, valid=(4, 32), some_valid=11,
     model=dict(n_heads=8, n_kv_heads=4, head_dim=64, dtype="bfloat16"),
 )
 
@@ -313,6 +323,176 @@ def _conv(chunk_kb, repeats, seed, tiny):
             fda._attend = attend_chunk
     finally:
         fda.CHUNK_BYTES, fda._attend = shipped, attend_chunk
+    return ok
+
+
+def _share_of_turns(lo, hi, tile, chunk):
+    """P(a turn scores ``k`` tiles of ``tile`` tokens), ``k`` = 1 ..
+    ``chunk // tile``, for valid rows log-uniform on ``[lo, hi]``."""
+    import math
+
+    span = math.log(hi) - math.log(lo)
+    edges = [
+        min(max(k * tile, lo), hi) for k in range(chunk // tile + 1)
+    ]
+    return [
+        (math.log(b) - math.log(a)) / span for a, b in zip(edges, edges[1:])
+    ]
+
+
+def _conv_chunk(token_tiles, prefix_kb, repeats, seed, tiny):
+    """One attention layer of a convolution / attention pattern model's
+    PREFILL CHUNK (``kvpool/conv.chunk_attend``) at the
+    ``lfm2-serve-sessions-8k`` shape: ``chunk`` launched rows over one
+    slot's ``start`` cached rows. The gathered form (the definition:
+    every row scored, whatever is valid), then at each (token tile, KB
+    of pages a VMEM chunk) the Pallas kernel over the flat pools in
+    place, whole and with its compute taken out (the page copies
+    alone): the error against the exact float32 softmax, ms a call
+    (``calls`` chained calls a timed launch, alternating layers) with
+    every row valid and with ``some_valid``, and ``turn_ms``, the mean
+    over the traffic's turns from the times at one, two, ... scored
+    tiles; one JSON line each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dlrover_tpu.models import conv_lm
+    from dlrover_tpu.ops import flat_decode_attention as fda
+    from dlrover_tpu.serving.kvpool import conv
+
+    shape = TINY_CONV if tiny else CONV
+    model = dict(shape["model"])
+    if tiny:
+        # (a CPU has no bf16 x bf16 -> f32 matmul of the definition's)
+        model["dtype"] = "float32"
+    cfg = conv_lm.tiny_config(**model)
+    dt = cfg.compute_dtype
+    mb, bs, chunk = shape["max_blocks"], shape["block"], shape["chunk"]
+    start, calls = shape["start"], shape["calls"]
+    lo, hi = shape["valid"]
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.key(seed + 1), 5)
+    pool_dims = (shape["layers"], shape["num_blocks"], bs, cfg.kv_width)
+    k_pool = _normal(keys[0], pool_dims).astype(dt)
+    v_pool = _normal(keys[1], pool_dims).astype(dt)
+    table = jnp.asarray(
+        1 + rng.permutation(shape["num_blocks"] - 1)[:mb].astype(np.int32)
+    )
+    q = _normal(keys[2], (1, chunk, cfg.n_heads, cfg.head_dim)).astype(dt)
+    k_new = _normal(keys[3], (1, chunk, cfg.n_kv_heads, cfg.head_dim))
+    v_new = _normal(keys[4], (1, chunk, cfg.n_kv_heads, cfg.head_dim))
+    k_new, v_new = k_new.astype(dt), v_new.astype(dt)
+
+    def attend(kind):
+        def one(k_pool, v_pool, at, n_valid, q):
+            return conv.chunk_attend(
+                cfg, k_pool, v_pool, at, table, jnp.int32(start), bs,
+                n_valid, kind,
+            )(q, k_new, v_new)
+
+        def chained(k_pool, v_pool, n_valid):
+            def body(out, at):
+                # each call's queries hang on the last one's answer
+                return one(
+                    k_pool, v_pool, at, n_valid,
+                    q + (out * 0).astype(q.dtype),
+                ), None
+
+            layers = jnp.arange(calls, dtype=jnp.int32) % shape["layers"]
+            return jax.lax.scan(body, jnp.zeros_like(q), layers)[0]
+
+        return jax.jit(one), jax.jit(chained)
+
+    def exact(k_pool, v_pool, q, k_new, v_new):
+        """The definition in float32 at the highest precision."""
+        f32, hi_p = jnp.float32, jax.lax.Precision.HIGHEST
+        kh, hd = cfg.n_kv_heads, cfg.head_dim
+        rows = lambda pool, new: jnp.concatenate([  # noqa: E731
+            pool[0][table].reshape(mb * bs, kh, hd)[:start].astype(f32),
+            new[0].astype(f32),
+        ])
+        qh = q[0].astype(f32).reshape(chunk, kh, -1, hd)
+        s = jnp.einsum(
+            "qkgd,tkd->kgqt", qh, rows(k_pool, k_new), precision=hi_p
+        )
+        seen = jnp.arange(start + chunk)[None, :] <= (
+            start + jnp.arange(chunk)[:, None]
+        )
+        p = jax.nn.softmax(
+            jnp.where(seen, s * conv_lm.softmax_scale(cfg), -jnp.inf), -1
+        )
+        out = jnp.einsum(
+            "kgqt,tkd->qkgd", p, rows(v_pool, v_new), precision=hi_p
+        )
+        return out.reshape(1, chunk, cfg.n_heads, hd)
+
+    want = jax.jit(exact)(k_pool, v_pool, q, k_new, v_new)
+
+    def report(form, kind, **more):
+        tile = more.get("tile")
+        one, chained = attend(kind)
+        got = one(k_pool, v_pool, jnp.int32(0), jnp.int32(chunk), q)
+        got = got.astype(jnp.float32)
+        err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        ms = lambda n: _timed(lambda: jax.block_until_ready(  # noqa: E731
+            chained(k_pool, v_pool, jnp.int32(n))
+        ), repeats)
+        line = {"part": "conv_chunk", "form": form, **more,
+                "rel_err_of_exact": err}
+        whole = ms(chunk)
+        if whole is not None:
+            line["ms"] = round(whole / calls, 4)
+            line[f"ms_at_{shape['some_valid']}_valid"] = round(
+                ms(shape["some_valid"]) / calls, 4
+            )
+            if tile:
+                by_tiles = [
+                    ms(k * tile) / calls for k in range(1, chunk // tile)
+                ] + [whole / calls]
+                line["ms_by_tiles"] = [round(x, 4) for x in by_tiles]
+                line["turn_ms"] = round(sum(
+                    s * x for s, x in zip(
+                        _share_of_turns(lo, hi, tile, chunk), by_tiles
+                    )
+                ), 4)
+            else:
+                line["turn_ms"] = line["ms"]
+        print(json.dumps(line), flush=True)
+        return bool(jnp.isfinite(got).all()), err
+
+    def no_compute(q_ref, m_ref, l_ref, acc_ref, j, *rest):
+        """Nothing attended: a sum of one, so the answer is zeros."""
+        l_ref[j] = jnp.ones_like(l_ref[j])
+
+    ok, view_err = report(
+        "gathered_view", "gathered_view", rows=chunk, start=start
+    )
+    shipped = fda.CHUNK_PREFIX_BYTES, conv.CHUNK_TOKEN_TILE, fda._attend_keys
+    try:
+        for tile in token_tiles:
+            for kb in prefix_kb:
+                fda.CHUNK_PREFIX_BYTES, conv.CHUNK_TOKEN_TILE = kb << 10, tile
+                pack = conv.lane_pack(cfg)
+                if not tiny and not fda.flat_chunk_kernel_supported(
+                    dt, bs, cfg.kv_width, pack * cfg.head_dim,
+                    pack * cfg.n_heads // cfg.n_kv_heads, chunk, tile, mb,
+                ):
+                    continue
+                more = dict(tile=tile, prefix_kb=kb, shipped=(
+                    fda.CHUNK_PREFIX_BYTES, tile
+                ) == shipped[:2])
+                finite, err = report("pool_kernel", "pool_kernel", **more)
+                # the definition's arithmetic: no further from the exact
+                # than its rounding allows
+                ok = ok and finite and err <= max(1.5 * view_err, 1e-5)
+                fda._attend_keys = no_compute
+                report("pool_kernel_copies_alone", "pool_kernel", **more)
+                fda._attend_keys = shipped[2]
+    finally:
+        fda.CHUNK_PREFIX_BYTES, conv.CHUNK_TOKEN_TILE, fda._attend_keys = (
+            shipped
+        )
     return ok
 
 
@@ -884,6 +1064,14 @@ def main():
                     help="VMEM chunk sizes to time the kernel at (the "
                     "one shipped: ops.decode_attention._POOL_CHUNK_BYTES; "
                     "conv: ops.flat_decode_attention.CHUNK_BYTES)")
+    ap.add_argument("--token-tiles", default="64,128,256",
+                    help="conv: tokens a grid step of the chunk kernel, "
+                    "to time it at (the one shipped: "
+                    "kvpool.conv.CHUNK_TOKEN_TILE)")
+    ap.add_argument("--prefix-kb", default="256,512,1024",
+                    help="conv: KB of pages a VMEM chunk of the chunk "
+                    "kernel (the one shipped: "
+                    "ops.flat_decode_attention.CHUNK_PREFIX_BYTES)")
     ap.add_argument("--tile-rows",
                     help="latent: device rows a VMEM tile of the latent "
                     "kernel, to time it at (default, the one shipped: "
@@ -930,6 +1118,10 @@ def main():
         parts.remove("conv")
         ok = _conv(
             [int(kb) for kb in ns.chunk_kb.split(",")],
+            0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
+        ) and _conv_chunk(
+            [int(t) for t in ns.token_tiles.split(",")],
+            [int(kb) for kb in ns.prefix_kb.split(",")],
             0 if ns.tiny else ns.repeats, ns.seed, ns.tiny,
         )
         if not ok or not parts:
