@@ -568,7 +568,7 @@ def prepare_decode_params(config, params):
     the MoE router stay f32 (same precision rule as
     llama.run_layer_stack). Pure jnp: generate()'s jitted run calls it
     traced, the serving engine calls it eagerly once per engine."""
-    if getattr(config, "kind", "") in ("latent_lm", "conv_lm"):
+    if getattr(config, "kind", "") in ("latent_lm", "conv_lm", "window_lm"):
         # Its tree is nested and stored as a server reads it.
         from dlrover_tpu.models import model_for
 

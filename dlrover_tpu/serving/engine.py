@@ -1367,6 +1367,8 @@ class ServingEngine:
                 **({"prefix_rounded_down_blocks":
                     req.prefix_rounded_down_blocks}
                    if req.prefix_rounded_down_blocks else {}),
+                **({"window_blocks_released": req.window_blocks_released}
+                   if req.window_blocks_released else {}),
             ),
         )
         decode_start = req.first_token_ts

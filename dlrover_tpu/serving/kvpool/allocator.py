@@ -1,11 +1,14 @@
 """Block allocator for the paged KV pool: free list + refcounts + COW.
 
 Pure host bookkeeping (no jax anywhere — the same discipline as the
-scheduler): the device holds one ``[layers, num_blocks, block_size,
-kv_heads, head_dim]`` slab per K and V, and THIS object decides which
-block ids are free, which are owned by live slots, and which are kept
-warm by the prefix cache. A block id is just an int32 row index into
-the pool's block axis.
+scheduler): the device holds a ``[layers, num_blocks, block_size,
+kv_heads, head_dim]`` slab per K and V of a layer GROUP
+(``kvpool/layout.py``: one group, unless the model's layers keep their
+rows for different spans), and THIS object decides which of that
+group's block ids are free, which are owned by live slots, and which are
+kept warm by the prefix cache: an engine has one allocator a group
+(``kvpool/groups.py`` holds the others'). A block id is just an int32
+row index into its group's block axis.
 
 Ownership is refcounted, not owned-by-one: a block holding a shared
 prompt prefix is referenced by every slot whose block table points at

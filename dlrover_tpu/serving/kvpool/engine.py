@@ -942,6 +942,7 @@ class PagedServingEngine(ServingEngine):
         spec_k: int = 0,
         spec_drafter: str = "ngram",
         spec_draft_layers: int = 2,
+        window_blocks: Optional[int] = None,
     ):
         if kv_cache_dtype not in ("fp", "int8"):
             raise ValueError(
@@ -967,7 +968,27 @@ class PagedServingEngine(ServingEngine):
         self.kv_cache_dtype = kv_cache_dtype
         # What a block holds (kvpool/layout.py), and the device arrays
         # by name; ``_pools()`` is their tuple in the layout's order.
-        self._layout = pool_layout.pool_arrays(config, kv_cache_dtype)
+        # The pool's groups (one, unless the config states more): the
+        # first keeps every row, the others a reach below the next row.
+        self._groups = pool_layout.cache_groups(config)
+        if len(self._groups) > 1:
+            refused = [name for name, on in (
+                ("an int8 pool (kv_cache_dtype='int8')",
+                 kv_cache_dtype != "fp"),
+                ("speculative decoding (spec_k)", spec_k),
+                (f"a prefill_chunk of {prefill_chunk} that is not whole "
+                 f"blocks of {block_size}", prefill_chunk % block_size),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "a pool in layer groups ("
+                    + ", ".join(g.name for g in self._groups)
+                    + ") is not served with " + " nor with ".join(refused)
+                    + ": the int8 and the verify / draft programs know one "
+                    "group under one table, and a prefix hit resumes at a "
+                    "block boundary"
+                )
+        self._layout = pool_layout.grouped_pool_arrays(config, kv_cache_dtype)
         # ... and what a SLOT holds whatever its length (none: every
         # model whose whole state is rows in pages).
         self._state_layout = pool_layout.state_arrays(config)
@@ -1015,14 +1036,18 @@ class PagedServingEngine(ServingEngine):
             pool_layout.default_snapshots(num_blocks, slots)
             if self._state_layout and prefix_cache else 0
         )
-        self._cache: Optional[PrefixCache] = (
-            PrefixCache(self._allocator, block_size,
-                        capacity_blocks=prefix_cache_blocks,
-                        snapshots=self.state_snapshots)
-            if prefix_cache else None
+        # Every group's table, stacked as the grouped programs take
+        # them; ``_tables`` is the first group's, a view.
+        self._group_tables = np.zeros(
+            (len(self._groups), slots, self.max_blocks), np.int32
         )
-        self._tables = np.zeros(
-            (slots, self.max_blocks), np.int32
+        self._tables = self._group_tables[0]
+        self._reach_groups = self._build_reach_groups(
+            slots, prefill_chunk, window_blocks
+        )
+        self._cache: Optional[PrefixCache] = (
+            self._new_prefix_cache(prefix_cache_blocks)
+            if prefix_cache else None
         )
         self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
         # USABLE-hit accounting (what kv_stats/bench/heartbeats report):
@@ -1042,6 +1067,10 @@ class PagedServingEngine(ServingEngine):
         self._prefix_rounded_down_blocks = 0
         self._chunk_rows_launched = self._chunk_rows_scored = 0
         self._slot_snapshot = [0] * slots
+        # Reach groups: blocks released in the iteration under way, and
+        # each slot's lowest query position in its last launch.
+        self._released_this_step = 0
+        self._launch_position = np.zeros(slots, np.int64)
         build.mark("prefix_cache")
         # The base __init__ builds every pool array via _alloc_pool().
         super().__init__(
@@ -1061,17 +1090,25 @@ class PagedServingEngine(ServingEngine):
         # The base __init__ bound the FLAT step programs (never traced
         # — jit is lazy); swap in the paged programs, keyed on the
         # paged shapes, and re-settle the retrace snapshot.
-        self._steps = _paged_steps(
-            config, slots, self.num_blocks, self.max_blocks,
-            block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
+        self._steps = (
+            _grouped_steps(
+                config, slots, self.max_blocks, block_size, prefill_chunk,
+                tuple(g.num_blocks for g in self._reach_groups),
+            ) if self._reach_groups else _paged_steps(
+                config, slots, self.num_blocks, self.max_blocks,
+                block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
+            )
         )
-        rows_by = self.latent_decode_attention or self.conv_decode_attention
-        if self.conv_chunk_attention:
-            rows_by += f", the chunk's by {self.conv_chunk_attention}"
+        rows_by = (self.latent_decode_attention or self.conv_decode_attention
+                   or self.window_decode_attention)
+        if self.conv_chunk_attention or self.window_chunk_attention:
+            rows_by += ", the chunk's by " + (
+                self.conv_chunk_attention or self.window_chunk_attention
+            )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
-            "%s%s%s%s",
+            "%s%s%s%s%s",
             slots, max_len, self.num_blocks, block_size, kv_cache_dtype,
             f" + index keys [{self._index_dim}], "
             f"{self.index_tokens_per_row} to a row, top-"
@@ -1084,6 +1121,13 @@ class PagedServingEngine(ServingEngine):
                 a.describe() for a in self._state_layout
             ) + f", {self.state_snapshots} snapshots"
             if self._state_layout else "",
+            "; layer groups " + ", ".join(
+                [f"{self._groups[0].name} ({self._groups[0].layers} layers, "
+                 "keeps all)"]
+                + [f"{g.name} ({g.layers} layers, {g.num_blocks} blocks, "
+                   f"keeps {g.reach} rows below the next, then releases)"
+                   for g in self._reach_groups]
+            ) if self._reach_groups else "",
         )
         if self.spec_k:
             # Same swap for the spec programs (the flat ones the base
@@ -1099,11 +1143,21 @@ class PagedServingEngine(ServingEngine):
         # the 1.94x-per-token capacity lever the equal-HBM bench
         # exploits.
         self._array_block_bytes = {
-            a.name: a.block_bytes(pool_layout.pool_layers(config), block_size)
+            a.name: a.block_bytes(self._groups[a.group].layers, block_size)
             for a in self._layout
         }
-        self._block_bytes = sum(self._array_block_bytes.values())
-        self.metrics.kv_blocks_total.set(self._allocator.managed)
+        # (the first group's: what ``bytes_in_use`` goes by)
+        self._block_bytes = sum(
+            self._array_block_bytes[a.name] for a in self._layout
+            if not a.group
+        )
+        self.metrics.kv_blocks_total.set(
+            self._allocator.managed, group=self._groups[0].name
+        )
+        for g in self._reach_groups:
+            self.metrics.kv_blocks_total.set(
+                g.allocator.managed, group=g.name
+            )
         build.mark("paged_programs")
         self._end_build(build)
 
@@ -1183,10 +1237,13 @@ class PagedServingEngine(ServingEngine):
         """Every array of the pool, zeroed: ONE rebuild site for all of
         them (init, warmup, step-error recovery), so that no two can be
         mismatched."""
-        layers = pool_layout.pool_layers(self.config)
+        blocks = [self.num_blocks] + [
+            g.num_blocks for g in self._reach_groups
+        ]
         arrays = {
             a.name: pool_layout.fresh(
-                a, layers, self.num_blocks, self.block_size
+                a, self._groups[a.group].layers, blocks[a.group],
+                self.block_size,
             )
             for a in self._layout
         }
@@ -1222,7 +1279,8 @@ class PagedServingEngine(ServingEngine):
         pools = self._pools()
         *pools, first = self._steps.prefill(
             *pools, self._params, jnp.asarray(chunk),
-            jnp.zeros(self.max_blocks, jnp.int32),
+            jnp.zeros(self._program_tables.shape[:-2] + (self.max_blocks,),
+                      jnp.int32),
             np.int32(0), np.int32(1), np.float32(0.0),
             self._rng, np.int32(0), np.bool_(True),
             *((np.int32(0),) * 3 if self._state_layout else ()),
@@ -1236,8 +1294,7 @@ class PagedServingEngine(ServingEngine):
         for first, first_slot in ((first, 0), (self._no_first, -1)):
             out = self._steps.decode(
                 *pools, self._params,
-                jnp.asarray(np.zeros((self.slots, self.max_blocks),
-                                     np.int32)),
+                jnp.asarray(np.zeros_like(self._program_tables)),
                 jnp.asarray(np.zeros(self.slots, np.int32)), fed,
                 jnp.asarray(np.zeros(self.slots, bool)),
                 jnp.asarray(np.zeros(self.slots, np.float32)),
@@ -1262,14 +1319,15 @@ class PagedServingEngine(ServingEngine):
             )
             for a in self._layout
         ]
-        pools = jax.block_until_ready(
-            self._steps.imp(*pools, *rows, np.int32(0))
-        )
-        marks.mark("imp")
-        # Export gather (non-donating): warm so the first migration
-        # out of this engine never stalls the serve loop on a compile.
-        jax.block_until_ready(self._steps.exp(*pools, np.int32(0)))
-        marks.mark("exp")
+        if self._steps.imp is not None:     # (a grouped pool migrates none)
+            pools = jax.block_until_ready(
+                self._steps.imp(*pools, *rows, np.int32(0))
+            )
+            marks.mark("imp")
+            # Export gather (non-donating): warm so the first migration
+            # out of this engine never stalls the serve loop on a compile.
+            jax.block_until_ready(self._steps.exp(*pools, np.int32(0)))
+            marks.mark("exp")
         if self._state_layout:
             self._set_pools(pools)
             self._restore_state(0, 0)
@@ -1316,9 +1374,20 @@ class PagedServingEngine(ServingEngine):
         rows = req.prompt_len + (
             req.max_new_tokens if req.preemptions else 1
         )
-        need = -(-min(rows, self.max_len) // self.block_size)
+        rows = min(rows, self.max_len)
+        need = -(-rows // self.block_size)
         stats = self._allocator.stats(self._live_block_ids())
-        return stats["free"] + stats["cached"] >= need
+        if stats["free"] + stats["cached"] < need:
+            return False
+        # Each reach group by its own need: a long prompt wants its whole
+        # length of the first group and a band and a chunk of these.
+        for g in self._reach_groups:
+            stats = g.stats()
+            if stats["free"] + stats["cached"] < g.blocks_for(
+                rows, self.prefill_chunk
+            ):
+                return False
+        return True
 
     def _live_block_ids(self) -> set:
         live = set()
@@ -1429,6 +1498,9 @@ class PagedServingEngine(ServingEngine):
         if self._state_layout:
             self._admit_state_slot(req)
             return
+        if self._reach_groups:
+            self._admit_grouped_slot(req)
+            return
         if self._cache is None:
             return
         hit = self._cache.lookup(req.prompt)
@@ -1474,6 +1546,8 @@ class PagedServingEngine(ServingEngine):
             self._allocator.decref(block)
         self._slot_blocks[slot] = []
         self._tables[slot, :] = SENTINEL_BLOCK
+        for g in self._reach_groups:
+            g.release_slot(slot)
         if self._slot_snapshot[slot]:
             # Lent to a prompt that left before it was inserted.
             self._cache.give_snapshot(self._slot_snapshot[slot])
@@ -1485,12 +1559,10 @@ class PagedServingEngine(ServingEngine):
         # prefix cache, tables, int8 scale pools) restart from scratch.
         self._arrays = self._fresh_arrays()
         self._allocator = BlockAllocator(self.num_blocks, reserved=1)
+        for g in self._reach_groups:
+            g.reset()
         if self._cache is not None:
-            self._cache = PrefixCache(
-                self._allocator, self.block_size,
-                capacity_blocks=self._cache.capacity_blocks,
-                snapshots=self.state_snapshots,
-            )
+            self._cache = self._new_prefix_cache(self._cache.capacity_blocks)
         self._tables[:, :] = SENTINEL_BLOCK
         self._slot_blocks = [[] for _ in range(self.slots)]
         self._slot_snapshot = [0] * self.slots
@@ -1503,6 +1575,11 @@ class PagedServingEngine(ServingEngine):
         self.metrics.kv_bytes_in_use.set(
             (stats["used"] + stats["cached"]) * self._block_bytes
         )
+        if self._released_this_step:
+            self.metrics.kv_window_blocks_released.inc(
+                self._released_this_step
+            )
+            self._released_this_step = 0
 
     # ---- step internals ----------------------------------------------------
 
@@ -1521,12 +1598,13 @@ class PagedServingEngine(ServingEngine):
         )
         for idx in range(first_blk, last_blk + 1):
             self._privatize(req, idx)
+        self._prepare_reach_groups(req, start, start + n_valid)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
         self._mark_prefill_prep(n_valid, start + n_valid)
         *pools, first = self._steps.prefill(
             *self._pools(), self._params, jnp.asarray(chunk),
-            _h2d(self._tables[req.slot]),
+            _h2d(self._program_tables[..., req.slot, :]),
             np.int32(start), np.int32(n_valid),
             np.float32(req.temperature), self._rng,
             np.int32(self._step_idx),
@@ -1550,6 +1628,10 @@ class PagedServingEngine(ServingEngine):
             self._cache.insert(
                 req.prompt, self._slot_blocks[req.slot][:n_full],
                 snapshot=(n_full, snapshot) if snapshot else None,
+                tails=(n_full, [
+                    g.tail(req.slot, n_full * self.block_size)
+                    for g in self._reach_groups
+                ]) if self._reach_groups and n_full else None,
             )
         self._launched_first(req, first)
 
@@ -1569,6 +1651,8 @@ class PagedServingEngine(ServingEngine):
             cursor = min(self._lengths[r.slot], self.max_len - 1)
             self._ensure_blocks(r, cursor + 1)
             self._privatize(r, cursor // self.block_size)
+            if self._reach_groups and r.state == DECODE:
+                self._prepare_reach_groups(r, cursor, cursor + 1)
         decoding = [r for r in decoding if r.state == DECODE]
         if not decoding:
             return
@@ -1578,7 +1662,7 @@ class PagedServingEngine(ServingEngine):
         self._mark_decode_prep(decoding)
         pools = self._pools()
         out = self._steps.decode(
-            *pools, self._params, _h2d(self._tables),
+            *pools, self._params, _h2d(self._program_tables),
             _h2d(self._lengths), self._fed_tokens(),
             jnp.asarray(active), _h2d(self._temps),
             self._rng, np.int32(self._step_idx), *self._fed_first(),
@@ -1689,6 +1773,31 @@ class PagedServingEngine(ServingEngine):
                 self._prefix_rounded_down_blocks
             )
             stats["moe_rows_dropped"] = self._moe_rows_dropped
+        if self._reach_groups:
+            stats["groups"] = {
+                name: dict(
+                    layers=layers, blocks_free=g_stats["free"],
+                    pool_bytes=blocks * per_block,
+                    bytes_in_use=(
+                        g_stats["used"] + g_stats["cached"]
+                    ) * per_block,
+                )
+                for name, layers, blocks, per_block, g_stats in (
+                    self._group_accounts(stats)
+                )
+            }
+            stats["window_rows"] = self._window_rows(
+                r.slot for r in self.scheduler.active()
+            )
+            stats["window_blocks_released_total"] = sum(
+                g.released_total for g in self._reach_groups
+            )
+            stats["window_decode_attention"] = self.window_decode_attention
+            stats["window_chunk_attention"] = self.window_chunk_attention
+            stats["prefix_rounded_down_blocks"] = (
+                self._prefix_rounded_down_blocks
+            )
+            stats["moe_rows_dropped"] = self._moe_rows_dropped
         if self.conv_decode_attention:
             stats["conv_decode_attention"] = self.conv_decode_attention
             stats["conv_chunk_attention"] = self.conv_chunk_attention
@@ -1734,6 +1843,11 @@ class PagedServingEngine(ServingEngine):
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value
+            if self._reach_groups:
+                stats["prefix_tails_live"] = self._cache.tails_live
+                stats["prefix_tails_dropped"] = (
+                    self._cache.tails_dropped_total
+                )
             # Report USABLE hits (blocks that actually skipped
             # prefill), not the cache's raw lookup counters: a hit
             # fully discarded by chunk alignment saved nothing.
@@ -1759,9 +1873,14 @@ class PagedServingEngine(ServingEngine):
             )
         # Every pool array is addressed by the same block ids: they
         # agree on how many blocks there are and how long a block is.
-        want = (pool_layout.pool_layers(self.config), self.num_blocks,
-                self.block_size)
+        blocks = [self.num_blocks] + [
+            g.num_blocks for g in self._reach_groups
+        ]
+        for g in self._reach_groups:
+            g.check(self._launch_position)
         for a, pool in zip(self._layout, self._pools()):
+            want = (self._groups[a.group].layers, blocks[a.group],
+                    self.block_size)
             if pool.shape[:3] != want:
                 raise AssertionError(
                     f"pool array {a.name} of {pool.shape[:3]} in a pool "
@@ -1778,6 +1897,159 @@ class PagedServingEngine(ServingEngine):
                     f"{held} snapshot ids accounted for of "
                     f"{self.state_snapshots}"
                 )
+
+    # ---- layer groups (kvpool/layout.py, kvpool/groups.py) -----------------
+
+    @property
+    def window_decode_attention(self) -> str:
+        """``"pool_kernel"`` or ``"gathered_view"``: what the decode
+        program of a model whose pool is in groups reads its rows with
+        (``window.decode_attention_kind``); ``""`` for any other."""
+        return getattr(self._steps, "window_decode_attention", "")
+
+    @property
+    def window_chunk_attention(self) -> str:
+        """The same for its prefill chunk."""
+        return getattr(self._steps, "window_chunk_attention", "")
+
+    @property
+    def _program_tables(self) -> np.ndarray:
+        """The host mirror of what the programs take as tables: the one
+        ``[slots, max_blocks]`` table, or every group's stacked."""
+        return self._group_tables if self._reach_groups else self._tables
+
+    def _build_reach_groups(self, slots: int, chunk: int,
+                            window_blocks: Optional[int]):
+        """One :class:`ReachGroup` a group of the pool after the first.
+        ``window_blocks`` sizes each (None: every slot's band and chunk,
+        and as much again for cached prompts' tails)."""
+        # Imported here: nothing else of this module needs it.
+        from dlrover_tpu.serving.kvpool.groups import (
+            ReachGroup,
+            band_blocks,
+        )
+
+        out = []
+        for i, spec in enumerate(self._groups[1:], start=1):
+            per_slot = band_blocks(
+                spec.reach, self.block_size,
+                self.max_blocks * self.block_size, chunk,
+            )
+            blocks = window_blocks or 2 * slots * per_slot + 1
+            if blocks - 1 < per_slot:
+                raise ValueError(
+                    f"window_blocks {blocks} cannot hold one slot's band "
+                    f"and chunk in group {spec.name} ({per_slot} blocks + "
+                    "sentinel)"
+                )
+            out.append(ReachGroup(
+                spec.name, spec.layers, spec.reach, blocks,
+                self.block_size, self._group_tables[i],
+            ))
+        return out
+
+    def _new_prefix_cache(self, capacity_blocks) -> PrefixCache:
+        return PrefixCache(
+            self._allocator, self.block_size,
+            capacity_blocks=capacity_blocks,
+            snapshots=self.state_snapshots,
+            tail_allocators=[g.allocator for g in self._reach_groups],
+        )
+
+    def _group_accounts(self, first_stats):
+        """(name, layers, blocks, a block's bytes, allocator stats) a
+        group, the first from ``first_stats``."""
+        per_block = [0] * len(self._groups)
+        for a in self._layout:
+            per_block[a.group] += self._array_block_bytes[a.name]
+        yield (self._groups[0].name, self._groups[0].layers,
+               self.num_blocks, per_block[0], first_stats)
+        for i, g in enumerate(self._reach_groups, start=1):
+            yield g.name, g.layers, g.num_blocks, per_block[i], g.stats()
+
+    def _window_rows(self, slots) -> int:
+        """Rows of the reach groups' bands below the given slots' fills:
+        what their next decode launch reads of those groups, a layer."""
+        return sum(
+            min(int(self._lengths[slot]), g.reach)
+            for slot in slots for g in self._reach_groups
+        )
+
+    def _admit_grouped_slot(self, req: Request) -> None:
+        """Admission for a pool in groups: the slot starts from the
+        deepest cached boundary whose entry still owns the reach groups'
+        rows just below it (the first group's blocks up to it and those
+        tails slot into the tables; a deeper match without them is given
+        up), else from row 0. A hit is BLOCK-aligned."""
+        slot, bs = req.slot, self.block_size
+        self._launch_position[slot] = 0
+        for g in self._reach_groups:
+            g.release_slot(slot)        # (a reused slot holds none)
+        if self._cache is None:
+            return
+        # Never skip the FINAL prompt token (see _admit_slot).
+        hit, tails, rounded = self._cache.lookup_with_tails(
+            req.prompt, max_blocks=(req.prompt_len - 1) // bs
+        )
+        self._prefix_rounded_down_blocks += rounded
+        req.prefix_rounded_down_blocks = rounded
+        self._adopt_hit(req, hit, len(hit) * bs)
+        for g, ids in zip(self._reach_groups, tails):
+            for back, block_id in enumerate(reversed(ids), start=1):
+                g.adopt(slot, len(hit) - back, block_id)
+
+    def _alloc_reach_blocks(self, group, n: int,
+                            requester: Request) -> List[int]:
+        """:meth:`_alloc_blocks` in a reach group: cached prompts' tails
+        go first, oldest first, then the YOUNGEST active request (never
+        ``requester``) is preempted."""
+        while True:
+            try:
+                return group.allocator.alloc(n)
+            except BlockPoolExhausted:
+                missing = n - group.allocator.free_count()
+                if self._cache is not None and self._cache.drop_tails_lru(
+                    missing
+                ):
+                    continue
+                victim = self._pick_preemption_victim(requester)
+                if victim is None:
+                    raise
+                self._drain("preempt")
+                self._preempt(victim)
+
+    def _prepare_reach_groups(self, req: Request, position: int,
+                              upto_rows: int) -> None:
+        """Before a launch that reads ``req``'s rows from ``position``
+        (its lowest query) and writes ``[position, upto_rows)``: the rule
+        of release (``kvpool/groups.py``), then blocks for the rows it
+        writes, in every reach group."""
+        slot = req.slot
+        self._launch_position[slot] = position
+        for g in self._reach_groups:
+            released = g.release_below(slot, position)
+            if released:
+                self._released_this_step += released
+                req.window_blocks_released += released
+                if self._step_trace is not None:
+                    counts = self._step_trace.counts
+                    counts["window_blocks_released"] = released + counts.get(
+                        "window_blocks_released", 0
+                    )
+            want = g.missing(slot, position, min(upto_rows, self.max_len))
+            if want:
+                for logical, block_id in zip(
+                    want, self._alloc_reach_blocks(g, len(want), req)
+                ):
+                    g.adopt(slot, logical, block_id)
+
+    def _mark_decode_prep(self, decoding: List[Request],
+                          at: Optional[float] = None) -> None:
+        super()._mark_decode_prep(decoding, at)
+        if self._reach_groups and self._step_trace is not None:
+            self._step_trace.counts["window_rows"] = self._window_rows(
+                r.slot for r in decoding
+            )
 
     # ---- per-slot state (kvpool/layout.py) ---------------------------------
 
@@ -1968,4 +2240,75 @@ def _state_steps(n_state: int) -> _StateSteps:
         jax.jit(get),
         jax.jit(put, donate_argnums=tuple(range(n_state))),
         counts,
+    )
+
+
+class _GroupedSteps(NamedTuple):
+    """:class:`_PagedSteps` for a pool in groups: the same fields (the
+    engine reads both by name), and what the two programs read their rows
+    with. ``imp`` / ``exp`` are None: migration is refused by name."""
+
+    prefill: object
+    decode: object
+    cow: object
+    imp: object
+    exp: object
+    trace_counts: Dict[str, int]
+    pool_attention: str = "window_groups"
+    sparse_chunk_attention: str = ""
+    latent_decode_attention: str = ""
+    conv_decode_attention: str = ""
+    conv_chunk_attention: str = ""
+    window_decode_attention: str = ""
+    window_chunk_attention: str = ""
+
+
+def _grouped_steps(config, slots: int, max_blocks: int, block_size: int,
+                   chunk: int, group_blocks) -> _GroupedSteps:
+    """The programs of a model whose pool is in groups
+    (``kvpool/window.py``), keyed like :func:`_paged_steps` and by what
+    their attention reads its rows with. Down here for
+    :func:`_latent_decode_kind`'s reasons."""
+    from dlrover_tpu.serving.kvpool import window
+
+    args = (config, config.compute_dtype, block_size, max_blocks, slots,
+            chunk)
+    return _grouped_steps_for(
+        config, slots, max_blocks, block_size, chunk, group_blocks,
+        window.decode_attention_kind(*args),
+        window.chunk_attention_kind(*args),
+    )
+
+
+@functools.lru_cache(maxsize=8)
+def _grouped_steps_for(config, slots: int, max_blocks: int, block_size: int,
+                       chunk: int, group_blocks, decode_kind: str,
+                       chunk_kind: str) -> _GroupedSteps:
+    from dlrover_tpu.serving.kvpool import window
+
+    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
+    layout = pool_layout.grouped_pool_arrays(config)
+    n_first = sum(1 for a in layout if not a.group)
+    pool_args = tuple(range(len(layout)))
+
+    def cow(*args):
+        # The first group's arrays alone: the one group a block of which
+        # more than one owner can hold while it is written.
+        counts["cow"] += 1  # traces only
+        pools, (src, dst) = args[:len(layout)], args[len(layout):]
+        return tuple(
+            p.at[:, dst].set(p[:, src]) for p in pools[:n_first]
+        ) + tuple(pools[n_first:])
+
+    return _GroupedSteps(
+        jax.jit(window.build_prefill(
+            config, max_blocks, block_size, chunk, counts, chunk_kind
+        ), donate_argnums=pool_args),
+        jax.jit(window.build_decode(
+            config, slots, max_blocks, block_size, counts, decode_kind
+        ), donate_argnums=pool_args),
+        jax.jit(cow, donate_argnums=pool_args),
+        None, None, counts,
+        window_decode_attention=decode_kind,
+        window_chunk_attention=chunk_kind,
     )

@@ -1,7 +1,8 @@
 """What a block holds: the model's statement, read by the pool.
 
 A paged pool is a tuple of device arrays ``[layers, num_blocks,
-block_size, *row_shape]`` addressed by ONE block table. Which arrays,
+block_size, *row_shape]`` addressed by a block table, in one GROUP or in
+several (below). Which arrays,
 and what a token's row of each is, the model's config says
 (``config.cache_rows``: name -> per-token shape; a config without it is
 a dense GQA model: ``k`` and ``v`` of ``[kv_heads, head_dim]``):
@@ -29,6 +30,23 @@ asks what they are; everything that SIZES a block sums over it. Only the
 programs that read and write rows (``kvpool/engine.py``'s dense ones,
 ``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``) know the
 arrays by name.
+
+GROUPS (``config.cache_groups``: name -> (layers, ``"all"`` or a reach
+in rows); a config without it is ONE group that keeps all, and nothing
+of its pool or its programs changes). Layers that keep a sequence's rows
+for different spans keep them apart: each group has the arrays above
+over its OWN layer axis, its own number of blocks, its own block ids,
+its own ``BlockAllocator`` and its own ``[slots, max_blocks]`` table,
+indexed by the logical block as one table is. The first group keeps
+``"all"`` (it is what sizes a slot and what the prefix cache's entries
+name); a group with a reach ``r`` keeps a row only while some query yet
+to come can see it: the engine RELEASES a slot's block of that group
+once every row of it is below ``next row to be written - r``, and the
+table's entry goes to the sentinel block (``models/window_lm.py``: the
+sliding-window layers, ``r = sliding_window - 1``; its arrays are named
+``k_window``, ``v_window``: the group's name after the array's). What
+moves or sizes a block walks the groups; the programs take the groups'
+arrays in order and the tables stacked (``kvpool/window.py``).
 
 A SECOND kind of array holds what a sequence keeps whatever its length
 (``config.state_rows``: name -> (layers, a slot's shape); no other
@@ -70,6 +88,7 @@ class PoolArray(NamedTuple):
     # What a migrated block's rows arrive as (float32 for a dense
     # model's K and V: the wire's int8 rows dequantized on the host).
     import_dtype: object
+    group: int = 0                  # which of ``cache_groups`` holds it
 
     @property
     def raw(self) -> bool:
@@ -178,3 +197,48 @@ def state_arrays(config) -> Tuple[StateArray, ...]:
         StateArray(name, int(layers), tuple(shape), config.compute_dtype)
         for name, (layers, shape) in getattr(config, "state_rows", ())
     )
+
+
+class CacheGroup(NamedTuple):
+    name: str
+    layers: int
+    reach: object        # None: keeps all; else rows below the next one
+
+    @property
+    def keeps_all(self) -> bool:
+        return self.reach is None
+
+
+def cache_groups(config) -> Tuple[CacheGroup, ...]:
+    """The pool's groups for ``config`` (module docstring): one that
+    keeps all over :func:`pool_layers` layers unless the config states
+    more; the first always keeps all."""
+    stated = getattr(config, "cache_groups", None)
+    if stated is None:
+        return (CacheGroup("all", pool_layers(config), None),)
+    groups = tuple(
+        CacheGroup(name, int(layers), None if retain == "all" else int(retain))
+        for name, (layers, retain) in stated
+    )
+    if not groups or not groups[0].keeps_all or any(
+        g.keeps_all for g in groups[1:]
+    ):
+        raise ValueError(
+            f"cache_groups {stated}: the first group keeps all rows and "
+            "every other one states its reach"
+        )
+    return groups
+
+
+def grouped_pool_arrays(config, kv_cache_dtype: str = "fp"):
+    """:func:`pool_arrays` for every group of ``config``, in the order
+    every compiled program takes and returns them: the first group's
+    under their own names, every other group's with ``_<group>`` after
+    them and their ``group`` set."""
+    first = pool_arrays(config, kv_cache_dtype)
+    out = list(first)
+    for g, group in enumerate(cache_groups(config)[1:], start=1):
+        out.extend(
+            a._replace(name=f"{a.name}_{group.name}", group=g) for a in first
+        )
+    return tuple(out)

@@ -79,11 +79,24 @@ class MigrationRefused(MigrationError):
     a breaker strike."""
 
 
+def _refuse_grouped(engine, what: str) -> None:
+    """A pool in layer groups (``kvpool/layout.py``) migrates nothing
+    yet: the wire carries one table's blocks, and a reach group's held
+    rows and first block would have to ride with them."""
+    if getattr(engine, "_reach_groups", None):
+        raise MigrationError(
+            f"migration {what} refused: this engine's pool is in layer "
+            "groups (" + ", ".join(g.name for g in engine._groups)
+            + "), and the wire format carries the blocks of one table"
+        )
+
+
 def export_request(engine, req: Request,
                    now: Optional[float] = None) -> bytes:
     """Serialize ``req``'s blocks + scheduler state on the source
     engine. The request stays LIVE on the source — pair with
     :func:`release_exported` once the importer acks."""
+    _refuse_grouped(engine, "export")
     if req.inflight:
         # Fill and tokens are exported as the host committed them.
         engine._drain("migrate")
@@ -233,6 +246,7 @@ def import_request(engine, payload: bytes,
     """Admit a migrated request into ``engine`` mid-stream (see module
     docstring). Raises :class:`MigrationRefused` when the engine
     cannot hold it, :class:`MigrationError` on incompatibility."""
+    _refuse_grouped(engine, "import")
     t_in = time.monotonic()
     header = peek_header(payload)
     (hlen,) = struct.unpack_from("<I", payload, 4)

@@ -37,6 +37,21 @@ taken at (or takes it back: the entry had one, or was never made),
 evicting an entry frees its id with its block. ``lookup_with_state``
 returns the chain only as far as the DEEPEST entry holding a snapshot,
 with that id and how many matched blocks lay beyond it.
+
+**Tails** (``tail_allocators``: a pool in groups, ``kvpool/layout.py``).
+The entries name blocks of the FIRST group, which keeps every row. A
+group with a reach keeps only the rows a query can still see, so a run
+of cached blocks can be continued from a boundary only if that group
+still holds the rows just below it: an entry may own, a reach group, a
+TAIL, the group's blocks that hold rows ``[boundary - reach, boundary)``
+(oldest first), each under a cache-owned reference in that group's own
+allocator. ``insert`` attaches the tails a finished prompt still holds
+to the entry of its last whole block; ``lookup_with_tails`` returns the
+chain only as far as the deepest entry that owns them, with them
+(incref'd for the caller) and how many matched blocks lay beyond it;
+evicting an entry frees its tails with its block, and
+``drop_tails_lru`` frees tails alone, oldest first, when a reach group
+runs dry (the entry stays, and can no longer be continued from).
 """
 
 from collections import OrderedDict
@@ -63,6 +78,10 @@ class _Entry:
     # The state at this block's end boundary (0: none); see the module
     # docstring.
     snapshot: int = 0
+    # A reach group's blocks that hold the rows just below this block's
+    # end boundary, one tuple a group (none: ()); see the module
+    # docstring.
+    tails: Tuple[Tuple[int, ...], ...] = ()
 
 
 class PrefixCache:
@@ -74,6 +93,7 @@ class PrefixCache:
         block_size: int,
         capacity_blocks: Optional[int] = None,
         snapshots: int = 0,
+        tail_allocators: Sequence[BlockAllocator] = (),
     ):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
@@ -91,6 +111,10 @@ class PrefixCache:
         self.snapshots = snapshots
         self._free_snapshots = list(range(snapshots, 0, -1))
         self.snapshots_live = 0       # entries that hold one
+        # The reach groups' allocators, in the groups' order.
+        self._tail_allocs = tuple(tail_allocators)
+        self.tails_live = 0           # entries that own tails
+        self.tails_dropped_total = 0
 
     # ---- keys --------------------------------------------------------------
 
@@ -152,12 +176,65 @@ class PrefixCache:
         up to the deepest entry among its first ``max_blocks`` that
         holds a snapshot, that snapshot (0 with no blocks), and how many
         matched blocks lay beyond it and were given up."""
+        usable, rounded = self._continuable(
+            prompt, max_blocks, lambda entry: entry.snapshot
+        )
+        snapshot = usable[-1].snapshot if usable else 0
+        return self._lend(usable), snapshot, rounded
+
+    def _continuable(self, prompt, max_blocks, owns):
+        """The matched chain down to the deepest entry among its first
+        ``max_blocks`` that ``owns`` what a sequence needs to be
+        continued from its boundary, and how many matched blocks lay
+        beyond it."""
         held = self._matched(prompt)
         usable = held[:max_blocks]
-        while usable and not usable[-1].snapshot:
+        while usable and not owns(usable[-1]):
             usable.pop()
-        snapshot = usable[-1].snapshot if usable else 0
-        return self._lend(usable), snapshot, len(held) - len(usable)
+        return usable, len(held) - len(usable)
+
+    def lookup_with_tails(
+        self, prompt: Sequence[int], max_blocks: Optional[int] = None
+    ) -> Tuple[List[int], Tuple[Tuple[int, ...], ...], int]:
+        """:meth:`lookup` for a pool in groups (module docstring):
+        ``(blocks, tails, rounded down)``, the chain up to the deepest
+        entry among its first ``max_blocks`` that owns its tails, those
+        tails (one tuple of block ids a reach group, every id INCREF'd
+        for the caller in its group's allocator; ``()`` with no blocks),
+        and how many matched blocks lay beyond it and were given up."""
+        usable, rounded = self._continuable(
+            prompt, max_blocks, lambda entry: entry.tails
+        )
+        tails = usable[-1].tails if usable else ()
+        for alloc, ids in zip(self._tail_allocs, tails):
+            for block_id in ids:
+                alloc.incref(block_id)
+        return self._lend(usable), tails, rounded
+
+    def _free_entry_tails(self, entry: _Entry) -> int:
+        """Drop the entry's tails; returns the blocks that freed."""
+        freed = 0
+        if entry.tails:
+            self.tails_live -= 1
+            for alloc, ids in zip(self._tail_allocs, entry.tails):
+                freed += sum(alloc.decref(block_id) for block_id in ids)
+            entry.tails = ()
+        return freed
+
+    def drop_tails_lru(self, n_blocks: int) -> int:
+        """A reach group's relief valve: drop entries' tails, oldest
+        entry first, until ``n_blocks`` blocks have freed (or no entry
+        owns any). The entries stay: their first-group blocks are still
+        a prefix, only no longer one to continue from. Returns the
+        blocks freed."""
+        freed = 0
+        for entry in list(self._entries.values()):
+            if freed >= n_blocks:
+                break
+            if entry.tails:
+                freed += self._free_entry_tails(entry)
+                self.tails_dropped_total += 1
+        return freed
 
     def take_snapshot(self) -> int:
         """Lend a free snapshot id to a request that is about to write
@@ -175,19 +252,24 @@ class PrefixCache:
             self.give_snapshot(entry.snapshot)
 
     def insert(self, prompt: Sequence[int], blocks: Sequence[int],
-               snapshot=None) -> int:
+               snapshot=None, tails=None) -> int:
         """Register a prefilled prompt's full blocks (``blocks[k]``
         holds rows ``[k*bs, (k+1)*bs)``). Newly cached blocks gain one
         cache-owned reference; chains already present are touched, not
         re-owned (a concurrent twin's identical blocks stay owned by
         its slot alone). ``snapshot``: ``(n, id)``, the state as of the
         end of the prompt's ``n``-th block under a lent id; the entry of
-        that boundary adopts it unless it holds one already. Returns
-        the number of blocks newly cached."""
+        that boundary adopts it unless it holds one already.
+        ``tails``: ``(n, tails)``, the reach groups' blocks that hold
+        the rows below the end of the prompt's ``n``-th block (one
+        sequence of ids a group, the caller's own references): the entry
+        of that boundary takes a reference of its own on each unless it
+        owns tails already. Returns the number of blocks newly cached."""
         keys = self._chain_keys(prompt)
         n_full = min(len(keys), len(blocks))
         added = 0
         at, lent = snapshot or (0, 0)
+        tails_at, new_tails = tails or (0, ())
         parent: Optional[Tuple] = _ROOT
         for k in range(n_full):
             key, tokens = keys[k]
@@ -212,6 +294,13 @@ class PrefixCache:
             if lent and k == at - 1 and not entry.snapshot:
                 entry.snapshot, lent = lent, 0
                 self.snapshots_live += 1
+            if (new_tails and k == tails_at - 1 and not entry.tails
+                    and all(new_tails)):
+                entry.tails = tuple(tuple(ids) for ids in new_tails)
+                self.tails_live += 1
+                for alloc, ids in zip(self._tail_allocs, entry.tails):
+                    for block_id in ids:
+                        alloc.incref(block_id)
             parent = key
         self.give_snapshot(lent)
         if self.capacity_blocks is not None:
@@ -256,6 +345,7 @@ class PrefixCache:
                 )
             self._alloc.decref(victim.block_id)
             self._free_entry_snapshot(victim)
+            self._free_entry_tails(victim)
             evicted += 1
             self.evicted_blocks_total += 1
         return evicted
@@ -266,6 +356,7 @@ class PrefixCache:
         for entry in self._entries.values():
             self._alloc.decref(entry.block_id)
             self._free_entry_snapshot(entry)
+            self._free_entry_tails(entry)
         self._entries.clear()
 
     # ---- accounting --------------------------------------------------------

@@ -112,7 +112,14 @@ class ServingMetrics:
         )
         self.kv_blocks_total = reg.gauge(
             "serving_kv_blocks_total",
-            "managed (allocatable) blocks in the paged KV pool",
+            "managed (allocatable) blocks in the paged KV pool, by layer "
+            "group (one group, 'all', unless the model states more)",
+            labelnames=("group",),
+        )
+        self.kv_window_blocks_released = reg.counter(
+            "serving_kv_window_blocks_released_total",
+            "blocks of a group that keeps only the rows a query can "
+            "still see, released by live slots as they slid out of reach",
         )
         self.kv_bytes_in_use = reg.gauge(
             "serving_kv_bytes_in_use",

@@ -146,6 +146,9 @@ class Request:
     # ... and, for a model with per-slot state, matched blocks given up
     # because no state snapshot lay that deep.
     prefix_rounded_down_blocks: int = 0
+    # ... and, for a pool in layer groups, the blocks this request
+    # released as its rows slid out of a window's reach.
+    window_blocks_released: int = 0
     # Speculative decoding (serving/spec_decode, §35): drafted /
     # accepted token counts and aggregate wall time attributed to the
     # draft vs verify phases (the engine splits each iteration's cost
@@ -581,6 +584,7 @@ class Scheduler:
         req.admit_ts = None
         req.prefix_hit_blocks = 0
         req.prefix_rounded_down_blocks = 0
+        req.window_blocks_released = 0
         req.migrate_start_ts = None
         req.migrate_end_ts = None
         req.spec_drafted = 0

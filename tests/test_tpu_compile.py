@@ -1243,3 +1243,77 @@ def test_sparse_chunk_attention_kind_admits_only_what_compiles(
         line for line in text.splitlines() if " copy(" in line
     )
     assert c.memory_analysis().temp_size_in_bytes < 64e6
+
+
+def test_window_serving_programs_compile_at_the_cells_shape(topo):
+    """``mellum2-serve-mixed-16k``'s decode step and prefill chunk (one
+    period of its 8 layers: three sliding-window layers and a full one,
+    over experts) at published widths, 32 slots x 16,896 rows over BOTH
+    groups' pools (9,216 blocks of 1 full layer, 1,024 of 3 window
+    layers), lower for the described v5e under their trace names from
+    what the engine's constructor builds (``kvpool.engine.
+    _grouped_steps``): all four pool arrays alias their outputs and none
+    is copied or re-laid (whole-block landing windows re-lay a pool of 4
+    KV heads, there and back: the chunk lands a row a token); the full
+    layer reads its rows by the accepted pool kernels and every window
+    layer by ``ops/window_attention.py``'s, each booked to its own scope
+    (``attn/full``, ``attn/window``), so no ``[slots, max_len]`` view of
+    a window layer (nor of a full one) exists; the expert matmuls are the
+    grouped kernel; and every scope the cell's readers book device time
+    to is there."""
+    from benchmark import common, rehearse_mellum2, trace_reduce
+    from benchmark import window_scopes
+    from dlrover_tpu.serving.kvpool import window
+
+    cfg_json = common.load_json("configs", "mellum2-12b-a2.5b.json")
+    types = cfg_json["layer_types"][:4]
+    programs, logical = rehearse_mellum2.lower_engine_programs(
+        cfg_json, topo.devices[0], probes=False, layer_types=types,
+    )
+    assert logical == {
+        "k": 1 * 9216 * 64 * 512 * 2, "v": 1 * 9216 * 64 * 512 * 2,
+        "k_window": 3 * 1024 * 64 * 512 * 2,
+        "v_window": 3 * 1024 * 64 * 512 * 2,
+    }
+    kernels = {
+        "jit_step": {"full": "paged_pool_decode_attention",
+                     "window": "paged_window_decode_attention"},
+        "jit_prefill": {"full": "paged_pool_chunk_attention",
+                        "window": "paged_window_chunk_attention"},
+    }
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        for i in range(4):
+            assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
+        scopes = trace_reduce.scopes_from_hlo(text)
+        for scope, kernel in kernels[name].items():
+            calls = [v for k, v in scopes.items() if k.startswith(kernel)]
+            assert len(calls) == (1 if scope == "full" else 3), (kernel, calls)
+            assert all(
+                window_scopes.scope_of(op_name) == scope for op_name in calls
+            ), calls
+        # the pools go in and out untouched
+        copies = "".join(
+            line for line in text.splitlines() if " copy(" in line
+        )
+        assert "bf16[1,9216," not in copies and "bf16[3,1024," not in copies
+        # no gathered view of a slot's rows, of either group
+        assert "bf16[32,16896,4,128]" not in text
+        assert "bf16[1,16896,4,128]" not in text
+        assert "f32[32,16896,4,128]" not in text
+        booked = {window_scopes.scope_of(v) for v in scopes.values()}
+        assert booked >= {"window", "full", "router", "experts", "vocab"}
+        m = c.memory_analysis()
+        assert m.alias_size_in_bytes == sum(logical.values())
+        assert m.temp_size_in_bytes < 0.3e9
+    from benchmark.runners import serve_window
+
+    cfg = serve_window.window_config(cfg_json)
+    args = (cfg, cfg.compute_dtype, 64, 264, 32, 512)
+    assert window.decode_attention_kind(*args) == "pool_kernel"
+    assert window.chunk_attention_kind(*args) == "pool_kernel"
+    assert window.decode_attention_kind(
+        cfg, jnp.float32, 64, 264, 32, 512
+    ) == "gathered_view"

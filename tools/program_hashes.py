@@ -5,9 +5,11 @@ step, ``kimilinear-train-8k``'s step, and the two engine programs of each
 accepted serve cell (``nemo12b-serve-chat``, built as
 ``benchmark/rehearse.py`` builds them, ``keye-serve-docqa-32k``, as
 ``rehearse_keye.py``, ``xing-serve-sessions-16k``, as
-``rehearse_xing.py``, and ``lfm2-serve-sessions-8k``, as
-``rehearse_lfm2.py``). Run it on two checkouts and compare: the same hash
-is the same program, so the cell cannot move.
+``rehearse_xing.py``, ``lfm2-serve-sessions-8k``, as
+``rehearse_lfm2.py``, and ``mellum2-serve-mixed-16k``, as
+``rehearse_mellum2.py``, where the checkout's manifest has it). Run it on
+two checkouts and compare: the same hash is the same program, so the cell
+cannot move.
 
     JAX_PLATFORMS=cpu python3 tools/program_hashes.py [ROOT] [--dump DIR]
 
@@ -173,6 +175,16 @@ def main(argv):
                 ctx["config"], device, probes=False
             )[0],
     }
+    if any(w["name"] == "mellum2-serve-mixed-16k"
+           for w in manifest["workloads"]):
+        # (a parent older than the cell has neither its files nor its
+        # model: its accepted cells above are what is compared)
+        from benchmark import rehearse_mellum2
+
+        serve_cells["mellum2-serve-mixed-16k"] = lambda ctx: \
+            rehearse_mellum2.lower_engine_programs(
+                ctx["config"], device, probes=False
+            )[0]
     for cell, lower in serve_cells.items():
         programs = lower(context(cell))
         for name in ("jit_step", "jit_prefill"):

@@ -147,7 +147,11 @@ def step_summary(spans: List[Dict]) -> Dict:
     reads, beside the ``kv_rows_mean`` it could), ``experts_hit_mean``
     (distinct experts a launch's tokens reach, mean over layers) and
     ``prefix_hit_tokens`` (prompt rows the prefix cache supplied); a
-    dense model's table has none of the three."""
+    dense model's table has none of the three. A model whose pool is in
+    layer groups adds ``window_rows_mean`` (the rows a decode launch
+    reads of a group that keeps only what a query can see) and
+    ``window_blocks_released`` (blocks of it the steps' slots gave
+    back)."""
     steps = [
         s for s in spans
         if s.get("name") == "serving.step" and s.get("dur_s") is not None
@@ -229,10 +233,17 @@ def _print_setup(table: Dict) -> None:
 def _sparse_counts(attrs: List[Dict]) -> Dict:
     out = {}
     for name, count in (("selected_rows_mean", "selected_rows"),
-                        ("experts_hit_mean", "experts_hit")):
+                        ("experts_hit_mean", "experts_hit"),
+                        ("window_rows_mean", "window_rows")):
         values = [a[count] for a in attrs if count in a]
         if values:
             out[name] = sum(values) / len(values)
+    if any("window_blocks_released" in a for a in attrs):
+        # a pool in layer groups (kvpool/groups.py): blocks the steps'
+        # slots released as their rows slid out of a window's reach
+        out["window_blocks_released"] = sum(
+            a.get("window_blocks_released", 0) for a in attrs
+        )
     if any("prefix_hit_tokens" in a for a in attrs):
         out["prefix_hit_tokens"] = sum(
             a.get("prefix_hit_tokens", 0) for a in attrs
