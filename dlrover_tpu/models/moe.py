@@ -221,6 +221,62 @@ def _weight_block(even: int, tm: int, k: int, n: int, itemsize: int):
     return max(fits, key=lambda b: (b[0] * b[1], b[1]))
 
 
+# FLOP a v5e's MXU does in the time its HBM moves a byte (197 TFLOP/s over
+# 819 GB/s): what turns the lay-out matmul of :func:`_aligned_rows` into
+# the bytes it is weighed against.
+_FLOP_PER_BYTE = 240
+
+
+def _aligned_rows(even: int, tm: int, rows: int, held: int, n: int, d: int,
+                  f: int, itemsize: int) -> bool:
+    """Whether a call's sorted rows are laid out EXPERT-ALIGNED (every
+    held expert's rows from a row-tile boundary,
+    :func:`_share_rows_aligned`) or packed end to end
+    (:func:`_share_rows`), from shapes alone.
+
+    ``gmm`` visits a (group, row tile) pair once, and below ``ROW_TILE``
+    rows a group a visit costs what streaming the group's weights costs
+    (:func:`_weight_block`). Packed, ``rows`` sorted rows have ``tiles_m
+    - 1`` tile edges and nearly each falls inside a group, whose weights
+    then go through twice: ``(tiles_m - 1) * 3 d f`` weight elements
+    read for nothing. Aligned, no group straddles, and what that costs:
+    the buffer grows by ``held * tm`` rows (a group's last tile is part
+    empty), which the matmuls' row blocks and the activation pass over,
+    ``held * tm * (d + 3 f)`` elements; and the ``n`` tokens reach their
+    rows by a one-hot matmul (:func:`_lay_rows`), ``2 * buffer rows * n
+    * d`` FLOP. Aligned when the bytes saved outweigh the bytes added and
+    the bytes the HBM would move while the MXU lays the rows out. On the
+    chip (PERF.md section 6, PR 52) the quotient orders the served
+    cells' shapes as the layer's time does: a chunk of 512 tokens x
+    top-8 over 64 experts 1.9 (19 % faster aligned), x top-4 over 64
+    1.4 and 1.2 (12 and 9 %), x top-8 over 128 experts 0.9 (1 %
+    slower: twice the rows for the same edges), a decode step 0.14 or
+    0 (one or two tiles: 5 % slower); the trained shapes have ``even``
+    258 / 513 and are not asked."""
+    if even >= ROW_TILE or rows < 2 * tm:
+        return False
+    saved = (rows // tm - 1) * 3 * d * f * itemsize
+    added = held * tm * (d + 3 * f) * itemsize
+    lay = 2 * (rows + held * tm) * n * d / _FLOP_PER_BYTE
+    return saved >= added + lay
+
+
+def weight_visits(group_sizes, tm: int, aligned: bool):
+    """(group, row tile) pairs a grouped matmul visits, each one pass of
+    the group's weights: ``gmm``'s own count (``make_group_metadata``'s
+    ``num_tiles``). Packed end to end a group is visited once a tile its
+    rows touch; ``aligned`` (each group from a tile boundary) once a
+    tile it fills, ``ceil(rows / tm)``. ``group_sizes`` are the rows a
+    group got, unrounded, either way."""
+    if aligned:
+        return jnp.sum(-(-group_sizes // tm)).astype(jnp.int32)
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    return jnp.sum(jnp.where(
+        group_sizes > 0, (ends - 1) // tm - starts // tm + 1, 0
+    )).astype(jnp.int32)
+
+
 # The dispatch/combine gathers are permutation-shaped, and XLA's
 # transpose of a gather is a SCATTER(-add) — slow on TPU and the bulk
 # of the dropless path's overhead in the backward. Both inverses are
@@ -734,6 +790,9 @@ class ShareCounters(NamedTuple):
     rows_dropped: jnp.ndarray  # 0: the row buffer holds any routing
     # experts that got a row at all (``routed_experts`` alone counts it)
     experts_hit: Optional[jnp.ndarray] = None
+    # (expert, row tile) visits of each grouped matmul, where the rows
+    # are expert-aligned (:func:`weight_visits`); None where they are not
+    weight_visits: Optional[jnp.ndarray] = None
 
 
 def router_scores(x, router_w):
@@ -806,6 +865,40 @@ def _gwi_bwd(res, g):
 _gather_with_inverse.defvjp(_gwi_fwd, _gwi_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lay_rows(x, back, rows: int):
+    """``rows`` rows of which row ``back[i, j]`` is ``x[i]`` (``rows``:
+    none) and every other is zeros, by a ONE-HOT MATMUL ``[rows, len(x)]
+    @ x``: one 1 a filled row under a float32 accumulator, so the row
+    itself, to the bit. No index is gathered: the chip pays ~12 ns an
+    INDEX for a gather, whether it fetches a row of 4.6 KB or one
+    ``int32`` (PERF.md section 6, PR 52), a buffer of 12,288 rows is
+    0.15 ms a gather, and the MXU waits for weights in this layer
+    anyway. The 1s come from comparing ``back`` with the row numbers
+    (a row a token's ``j``-th copy at most: the copies' rows differ).
+    ``x`` must be finite (0 x inf). The transpose is
+    :func:`_gather_with_inverse`'s: the cotangent's unread rows may hold
+    anything, which a matmul would spread."""
+    lay = jnp.any(
+        back.T[None, :, :] == jnp.arange(rows)[:, None, None], axis=1
+    ).astype(x.dtype)                                  # [rows, len(x)]
+    # (a float32 ``x`` in one bfloat16 pass would lose its low bits)
+    exact = jax.lax.Precision.HIGHEST if x.dtype.itemsize > 2 else None
+    return jnp.dot(lay, x, preferred_element_type=x.dtype, precision=exact)
+
+
+def _lay_fwd(x, back, rows):
+    return _lay_rows(x, back, rows), back
+
+
+def _lay_bwd(rows, back, g):
+    dx, _, no_back = _gwi_bwd((back, back), g)
+    return dx, no_back
+
+
+_lay_rows.defvjp(_lay_fwd, _lay_bwd)
+
+
 def moe_mlp_share(
     x,
     router_w,      # [embed, all experts]
@@ -848,7 +941,7 @@ def moe_mlp_share(
         experts, weights = sigmoid_route(
             xf, router_w, router_bias, top_k, scaling
         )
-    out, group_sizes, n_held = _routed_share(
+    out, group_sizes, n_held, visits = _routed_share(
         xf, (b, s, d), experts, weights, router_w.shape[-1], w_down,
         w_gate=w_gate, w_up=w_up, first=first, interpret=interpret,
     )
@@ -856,6 +949,7 @@ def moe_mlp_share(
         rows_held=n_held,
         rows_max=jnp.max(group_sizes),
         rows_dropped=jnp.zeros((), jnp.int32),
+        weight_visits=visits,
     )
 
 
@@ -874,7 +968,7 @@ def routed_experts(x, experts, weights, w_gu, w_down, n_experts: int,
     capacity in any of them, so a token's experts give it the same
     output whatever else the call carries."""
     b, s, d = x.shape
-    out, group_sizes, n_held = _routed_share(
+    out, group_sizes, n_held, visits = _routed_share(
         x.reshape(b * s, d), (b, s, d), experts, weights, n_experts,
         w_down, w_gu=w_gu, held=n_experts, group_offset=group_offset,
         interpret=interpret,
@@ -884,6 +978,7 @@ def routed_experts(x, experts, weights, w_gu, w_down, n_experts: int,
         rows_max=jnp.max(group_sizes),
         rows_dropped=jnp.zeros((), jnp.int32),
         experts_hit=jnp.sum(group_sizes > 0, dtype=jnp.int32),
+        weight_visits=visits,
     )
 
 
@@ -891,9 +986,10 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
                   w_gate=None, w_up=None, w_gu=None, first=0, held=None,
                   group_offset=None, interpret=None):
     """The expert compute both entries share: ``xf [n, embed]`` and its
-    routing -> (out ``shape``, rows a weight group got, their sum). Experts
-    ``first .. first + held - 1`` are computed (``held``: every group of
-    ``w_down`` when None); expert ``first`` is weight group
+    routing -> (out ``shape``, rows a weight group got, their sum, the
+    matmuls' weight visits where the rows are expert-aligned or None).
+    Experts ``first .. first + held - 1`` are computed (``held``: every
+    group of ``w_down`` when None); expert ``first`` is weight group
     ``group_offset`` (0 when None)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -918,17 +1014,29 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
         even = n * top_k // e_all
         tm = min(max(even, ROW_TILE), 512)
 
+        def padded(rows):
+            return (rows + tm - 1) // tm * tm if rows >= tm else rows
+
+        most = padded(n * min(top_k, held))
+        # one layout a call, by the buffer that holds any routing
+        aligned = _aligned_rows(
+            even, tm, most, held, n, xf.shape[1], w_down.shape[-2],
+            jnp.dtype(xf.dtype).itemsize,
+        )
+
         def through(rows):
+            if aligned:
+                return _share_rows_aligned(
+                    xf, weights, w_gate, w_up, w_down, order, inv_order,
+                    is_held, local, group_sizes,
+                    (-(-rows // tm) + held) * tm, tm, interpret, w_gu, even,
+                )
             return _share_rows(
                 xf, weights, w_gate, w_up, w_down, order, inv_order,
                 is_held, group_sizes, n_held, rows, tm, interpret, w_gu,
                 even,
             )
 
-        def padded(rows):
-            return (rows + tm - 1) // tm * tm if rows >= tm else rows
-
-        most = padded(n * min(top_k, held))
         usual = padded(4 * even * held)
         if 0 < usual < most:   # (0: fewer pairs than experts)
             out = jax.lax.cond(
@@ -940,7 +1048,38 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
         out = with_logical_constraint(
             out.reshape(shape), ("batch", "seq", "embed")
         )
-    return out, group_sizes, n_held
+        visits = weight_visits(group_sizes, tm, True) if aligned else None
+    return out, group_sizes, n_held, visits
+
+
+def _grouped_swiglu(xs, w_gate, w_up, w_gu, w_down, group_sizes, tm, even,
+                    interpret):
+    """``xs [rows, d]`` by group -> each group's SwiGLU of its rows,
+    ``[rows, d]``: two grouped matmuls (megablox ``gmm``, which visits the
+    (group, row tile) pairs ``group_sizes`` name and writes no other
+    tile) in row tiles of ``tm`` under :func:`_weight_block`'s blocks."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    d, f = xs.shape[1], w_down.shape[-2]
+    cdt = xs.dtype
+    if w_gu is None:
+        w_gu = jnp.concatenate(
+            [w_gate.astype(cdt), w_up.astype(cdt)], axis=-1
+        )
+    else:
+        w_gu = w_gu.astype(cdt)
+    size = jnp.dtype(cdt).itemsize
+    hu = gmm(
+        xs, w_gu, group_sizes, preferred_element_type=cdt,
+        interpret=interpret,
+        tiling=(tm, *_weight_block(even, tm, d, 2 * f, size)),
+    )
+    act = (jax.nn.silu(hu[:, :f]) * hu[:, f:]).astype(cdt)
+    return gmm(
+        act, w_down.astype(cdt), group_sizes, preferred_element_type=cdt,
+        interpret=interpret,
+        tiling=(tm, *_weight_block(even, tm, f, d, size)),
+    )
 
 
 def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
@@ -951,11 +1090,8 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     grouped matmuls, weight each row, and sum a token's rows back.
     ``even``: the rows an evenly loaded expert gets, which says how the
     matmuls' weight blocks are cut (:func:`_weight_block`)."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
     n, d = xf.shape
     top_k = weights.shape[1]
-    f = w_down.shape[-2]
     cdt = xf.dtype
     front = order[:rows] if rows <= order.shape[0] else jnp.pad(
         order, (0, rows - order.shape[0])
@@ -966,25 +1102,10 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     xs = _gather_with_inverse(
         xf, front // top_k, at.reshape(n, top_k)
     )                                                  # [rows, d], by expert
-    if w_gu is None:
-        w_gu = jnp.concatenate(
-            [w_gate.astype(cdt), w_up.astype(cdt)], axis=-1
-        )
-    else:
-        w_gu = w_gu.astype(cdt)
-    tm = _tile(rows, cap=tm)
-    size = jnp.dtype(cdt).itemsize
-    hu = gmm(
-        xs, w_gu, group_sizes, preferred_element_type=cdt,
-        interpret=interpret,
-        tiling=(tm, *_weight_block(even, tm, d, 2 * f, size)),
-    )
-    act = (jax.nn.silu(hu[:, :f]) * hu[:, f:]).astype(cdt)
-    ys = gmm(
-        act, w_down.astype(cdt), group_sizes, preferred_element_type=cdt,
-        interpret=interpret,
-        tiling=(tm, *_weight_block(even, tm, f, d, size)),
-    )                                              # rows past the groups: 0
+    ys = _grouped_swiglu(
+        xs, w_gate, w_up, w_gu, w_down, group_sizes, _tile(rows, cap=tm),
+        even, interpret,
+    )                                  # (rows past the groups: unwritten)
     by_row = _gather_with_inverse(
         weights.reshape(n * top_k, 1), front, at[:, None]
     )
@@ -994,3 +1115,70 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     return jnp.sum(
         per_pair.reshape(n, top_k, d).astype(jnp.float32), axis=1
     ).astype(cdt)                                      # back in token order
+
+
+def _share_rows_aligned(xf, weights, w_gate, w_up, w_down, order, inv_order,
+                        is_held, local, group_sizes, rows, tm, interpret,
+                        w_gu, even):
+    """:func:`_share_rows` with the sorted rows laid out EXPERT-ALIGNED:
+    a group's rows start on a row-tile boundary (the tiles before it are
+    the whole tiles of the groups before it, an empty group none), and
+    ``gmm`` is handed the group sizes rounded up to whole tiles, so that
+    a tile belongs to one group and a group's weights go through once a
+    tile it FILLS, never once more for a tile it shares. ``rows``: the
+    packed buffer's and ``tm`` more a held expert (a group's last tile is
+    part empty: zeros in, zeros out, never read back; the tiles no group
+    holds are never visited). The same kernel under the same blocks as
+    the packed layout, and a row's result does not depend on where in
+    the buffer it sits. What differs besides the layout: the rows are
+    laid out by :func:`_lay_rows`, and the routing weight multiplies at
+    the combine, in float32, before a token's rows are summed (the
+    packed path rounds the weighted row to the compute dtype first), so
+    that no pass over the buffer is left but the matmuls' own and the
+    activation's. The maps between pairs and buffer rows are index
+    arithmetic over ``int32`` vectors; every transpose is a gather
+    (:func:`_gather_with_inverse`), as there."""
+    n, d = xf.shape
+    top_k = weights.shape[1]
+    groups = group_sizes.shape[0]
+    tiles = -(-group_sizes // tm)                      # a group fills
+    tile_ends = jnp.cumsum(tiles)
+    begins = (tile_ends - tiles) * tm                  # a group's first row
+    # ... less its first sorted row: what a pair's sorted row moves by
+    shift = begins - (jnp.cumsum(group_sizes) - group_sizes)
+    # Pair j sits at its sorted row, moved with its group (a comparison
+    # with every group and a sum, not a gather of ``shift``: see
+    # :func:`_lay_rows`); an absent expert's pair is past the buffer, as
+    # in the packed layout.
+    moved = jnp.sum(jnp.where(
+        local[:, None] == jnp.arange(groups)[None, :], shift[None, :], 0
+    ), axis=1)
+    at = jnp.where(is_held, inv_order + moved, rows)
+    xs = _lay_rows(
+        xf, at.reshape(n, top_k), rows
+    )                                  # [rows, d], by expert, from tiles
+    ys = _grouped_swiglu(
+        xs, w_gate, w_up, w_gu, w_down, tiles * tm, tm, even, interpret
+    )
+    # Buffer row -> the pair it holds, for the combine's transpose alone
+    # (a served program's compiler drops it unused): a tile's group (the groups
+    # that end at or before the tile, counted; past them all the last,
+    # whose rows the tile is past too), a row's place in the group, the
+    # pair sorted there.
+    tile_group = jnp.minimum(jnp.sum(
+        tile_ends[None, :] <= jnp.arange(rows // tm)[:, None], axis=1
+    ), groups - 1)
+    row = jnp.arange(rows).reshape(rows // tm, tm)
+    pair = jnp.where(
+        row - jnp.take(begins, tile_group)[:, None]
+        < jnp.take(group_sizes, tile_group)[:, None],
+        jnp.take(
+            order, row - jnp.take(shift, tile_group)[:, None], mode="clip"
+        ),
+        n * top_k,
+    ).reshape(rows, 1)
+    per_pair = _gather_with_inverse(ys, at, pair)
+    return jnp.sum(
+        per_pair.reshape(n, top_k, d).astype(jnp.float32)
+        * weights[:, :, None].astype(jnp.float32), axis=1,
+    ).astype(xf.dtype)                                 # back in token order

@@ -1,8 +1,9 @@
-"""The weight block a grouped matmul streams (``moe._weight_block``): the
-rule at the benchmark cells' shapes, read off the tilings the expert
-layer hands megablox ``gmm``, and the served expert layer under the
-large blocks against today's tiles and a dense per-expert reference
-(CPU, kernels interpreted)."""
+"""The weight block a grouped matmul streams (``moe._weight_block``) and
+the layout of the rows it streams them over (``moe._aligned_rows``): the
+two rules at the benchmark cells' shapes, read off the tilings and the
+row buffers the expert layer hands megablox ``gmm``, and the served
+expert layer under the large blocks against today's tiles and a dense
+per-expert reference (CPU, kernels interpreted)."""
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,15 @@ _CELLS = {
     "glm47flash_train": ("share", 8192, 4, 2048, 1536, 64, 8, 1),
 }
 _VMEM_LIMIT = 16 * 2**20
+# ... and the two serve cells that came after the weight blocks' table
+# (eight expert layers each): the row layout's table walks these too
+_LAYOUT_CELLS = {
+    **_CELLS,
+    "lfm2_decode": ("routed", 32, 4, 2048, 1536, 64, 64, 8),
+    "lfm2_chunk": ("routed", 512, 4, 2048, 1536, 64, 64, 8),
+    "mellum2_decode": ("routed", 32, 8, 2304, 896, 64, 64, 8),
+    "mellum2_chunk": ("routed", 512, 8, 2304, 896, 64, 64, 8),
+}
 
 
 @pytest.fixture
@@ -31,11 +41,16 @@ def tilings(monkeypatch):
     with the shapes it was called on; nothing is computed."""
     from jax.experimental.pallas.ops.tpu import megablox
 
-    seen = []
+    class Seen(list):
+        """The tilings, and ``rows``: of the buffers the matmuls ran over."""
+
+    seen = Seen()
+    seen.rows = set()
 
     def gmm(lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
             tiling=None, **_):
         seen.append((lhs.shape[1], rhs.shape[2], tiling))
+        seen.rows.add(lhs.shape[0])
         return jnp.zeros((lhs.shape[0], rhs.shape[2]), preferred_element_type)
 
     monkeypatch.setattr(megablox, "gmm", gmm)
@@ -43,14 +58,14 @@ def tilings(monkeypatch):
 
 
 def _trace(cell):
-    entry, n, top_k, d, f, e_all, held, layers = _CELLS[cell]
+    entry, n, top_k, d, f, e_all, held, layers = _LAYOUT_CELLS[cell]
     bf16 = jnp.bfloat16
 
     def sds(*shape, dtype=bf16):
         return jax.ShapeDtypeStruct(shape, dtype)
 
     if entry == "routed":
-        jax.eval_shape(
+        return jax.eval_shape(
             lambda x, ex, w, w_gu, w_down: moe.routed_experts(
                 x, ex, w, w_gu, w_down, e_all, group_offset=2 * e_all
             ),
@@ -59,7 +74,7 @@ def _trace(cell):
             sds(layers * held, d, 2 * f), sds(layers * held, f, d),
         )
     else:
-        jax.eval_shape(
+        return jax.eval_shape(
             lambda x, r, b, wg, wu, wd: moe.moe_mlp_share(
                 x, r, b, wg, wu, wd, first=held, top_k=top_k
             ),
@@ -99,6 +114,41 @@ def test_the_weight_block_follows_the_rows_a_group_holds(cell, tilings):
         )
     new, old = (sum(s[i] for s in steps.values()) for i in (0, 1))
     assert 7 * new <= old     # xing: 6 grid steps a visit for 42; keye 3 for 24
+
+
+# The cells whose rows are laid out expert-aligned: the rule's table (the
+# chip's readings under it: PERF.md section 6, PR 52).
+_ALIGNED = {"mellum2_chunk", "lfm2_chunk", "xing_chunk"}
+
+
+@pytest.mark.parametrize("cell", sorted(_LAYOUT_CELLS))
+def test_the_row_layout_follows_the_shape(cell, tilings):
+    """Expert-aligned where a group holds less than a row tile and the
+    weights the tile edges send through twice outweigh what the aligned
+    buffer adds (its rows, and the matmul that lays them out); packed,
+    today's buffer to the row, anywhere else: every decode step, the
+    chunk over 128 experts, the trained shapes. From shapes alone:
+    nothing is computed here."""
+    entry, n, top_k, d, f, e_all, held, _ = _LAYOUT_CELLS[cell]
+    _, counters = _trace(cell)
+    pairs = n * min(top_k, held)
+    even = n * top_k // e_all
+    tm = min(max(even, moe.ROW_TILE), 512)
+    packed = -(-pairs // tm) * tm if pairs >= tm else pairs
+    if cell in _ALIGNED:
+        assert tilings.rows == {packed + held * moe.ROW_TILE}
+        assert counters.weight_visits.shape == ()
+        assert moe._aligned_rows(even, tm, packed, held, n, d, f, 2)
+    else:
+        # (a share's usual buffer is four even loads; its full one rarely)
+        assert tilings.rows <= {packed, 4 * even * held}
+        assert packed in tilings.rows
+        assert counters.weight_visits is None
+        assert not moe._aligned_rows(even, tm, packed, held, n, d, f, 2)
+    if cell == "keye_chunk":
+        # the nearest miss: the same 31 edges as the chunk over 64
+        # experts for twice the rows added; half the experts would do
+        assert moe._aligned_rows(even, tm, packed, held // 2, n, d, f, 2)
 
 
 def test_the_weight_block_of_a_small_dimension_is_the_tile():
@@ -235,3 +285,37 @@ def test_the_tool_walks_its_table_of_blocks_at_toy_widths():
     assert today["experts_hit_mean"] == 4 and today["tm"] == 16
     assert today["grid_steps_per_layer"] == 6 * 2
     assert halved["grid_steps_per_layer"] == 6 * 4
+
+
+def test_the_tool_walks_both_row_layouts_at_toy_widths():
+    """``tools/bench_moe_dispatch.py --serve --layouts --valid-rows 10
+    --tiny``: a line a layout under the rule's blocks, the visits counted
+    by the layer itself, and both rules back in their places."""
+    import os
+    import sys
+
+    sys.path.insert(
+        0, os.path.join(os.path.dirname(__file__), "..", "tools")
+    )
+    import bench_moe_dispatch
+
+    rules = moe._weight_block, moe._aligned_rows
+    packed, aligned = bench_moe_dispatch.run_serve(
+        ["tiny"], repeats=1, tiny=True, layouts=True, valid_rows=10
+    )
+    assert (moe._weight_block, moe._aligned_rows) == rules
+    assert [packed["layout"], aligned["layout"]] == ["packed", "aligned"]
+    assert packed["by_rule"] == aligned["by_rule"] == "packed"
+    assert packed["chosen"] and aligned["chosen"]
+    assert packed["valid_rows"] == 10 and packed["finite"]
+    # 14 of 24 tokens are the eleventh repeated: two groups of 14 and more
+    assert packed["rows_max"] >= 14
+    # 48 rows packed in tiles of 16: two edges, each inside a group;
+    # aligned, a whole tile of 128 a group and four more for the buffer
+    assert packed["buffer_rows"] == 48 and aligned["buffer_rows"] == 5 * 128
+    assert packed["weight_visits_mean"] > packed["experts_hit_mean"]
+    assert aligned["weight_visits_mean"] == aligned["experts_hit_mean"]
+    # the first layer's output: one rounding apart at most
+    assert aligned["max_abs_diff_from_packed"] <= (
+        2.0 ** -6 * aligned["max_abs"]
+    )

@@ -278,6 +278,57 @@ def test_served_expert_layer_compiles_under_its_weight_blocks(
     assert _n_kernels(back) == 5
 
 
+def test_served_expert_chunk_compiles_over_expert_aligned_rows(one_chip):
+    """``mellum2-serve-mixed-16k``'s prefill chunk (512 tokens x top-8
+    over 64 experts of 2,304 x 896, the third of eight layers in the
+    stack): ``moe._aligned_rows`` lays its rows out expert-aligned, 4,096
+    + 64 x 128 of them, and both grouped matmuls compile over that
+    buffer under ``moe._weight_block``'s blocks inside the scoped VMEM
+    limit. XLA's own ops make two arrays of the buffer's size (the
+    one-hot matmul that lays the rows out; the SiLU product between the
+    kernels) and none copies one: no row gather and no fill going in, no
+    weighting pass coming out."""
+    from dlrover_tpu.models import moe
+
+    n, top_k, d, f, e, layers = 512, 8, 2304, 896, 64, 8
+    rows = n * top_k + e * moe.ROW_TILE
+    assert moe._aligned_rows(
+        n * top_k // e, moe.ROW_TILE, n * top_k, e, n, d, f, 2
+    )
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, experts, weights, w_gu, w_down, at):
+        out, counters = moe.routed_experts(
+            x, experts, weights, w_gu, w_down, e, group_offset=at * e,
+            interpret=False,
+        )
+        return out, counters.weight_visits
+
+    text = jax.jit(layer).lower(
+        sds((1, n, d)), sds((n, top_k), jnp.int32),
+        sds((n, top_k), jnp.float32), sds((layers * e, d, 2 * f)),
+        sds((layers * e, f, d)), sds((), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    made = [
+        line.split(" = ")[1] for line in text[text.index("\nENTRY "):]
+        .splitlines()
+        if " = " in line and line.split(" = ")[1].startswith(f"bf16[{rows},")
+    ]
+    # what the main program makes at the buffer's size: the rows laid
+    # out, the two matmuls' results, the activation between them
+    assert sorted(m.split("{")[0] for m in made) == sorted([
+        f"bf16[{rows},{d}]", f"bf16[{rows},{2 * f}]", f"bf16[{rows},{f}]",
+        f"bf16[{rows},{d}]",
+    ]), made
+    assert not [
+        m for m in made
+        if " copy(" in m or " select(" in m or " gather(" in m
+    ]
+
+
 @pytest.mark.parametrize("dispatch", [None, "fused", "gmm"])
 def test_moe_dispatch_compiles_fwd_bwd(one_chip, dispatch):
     """``moe_mlp_dropless`` at the bench's MoE shape (e=8, top-2,

@@ -23,33 +23,47 @@ one process, one shape, host-clock timing around ``block_until_ready``.
 ``--serve`` times the SERVED expert layer alone instead
 (``moe.routed_experts``, what the ``mlp/experts`` scope of a serve
 cell's programs holds) at the shapes of ``SERVE_SHAPES``: the decode
-step and the prefill chunk of ``xing-serve-sessions-16k`` and of
-``keye-serve-docqa-32k``, the cells' widths, all five expert layers'
-experts one stack of groups walked by a traced ``group_offset``, every
-token's ``top_k`` distinct experts drawn evenly from the seed (which
-hits the cells' ~55 of 64 and ~82 of 128 experts at decode). For each
+step and the prefill chunk of ``xing-serve-sessions-16k``,
+``keye-serve-docqa-32k``, ``lfm2-serve-sessions-8k`` and
+``mellum2-serve-mixed-16k``, the cells' widths, all of a cell's expert
+layers' experts (five, five, eight, eight) one stack of groups walked by
+a traced ``group_offset``, every token's ``top_k`` distinct experts
+drawn evenly from the seed (which hits the cells' ~55 of 64 and ~82 of
+128 experts at decode); with ``--valid-rows N`` the tokens past the
+``N``-th are the ``N``-th repeated, as a partial prefill chunk's pad
+rows are (one token routes one way: ``top_k`` heavy groups). For each
 shape it walks a table of the two grouped matmuls' weight blocks
 ``(tk, tn)`` (today's power-of-two tiles, ``moe._weight_block``'s
 choice, and ``SERVE_BLOCKS``) and prints one JSON line each: the
 blocks, the grid steps a layer, ms a layer (host clock over
 ``--repeats`` calls of all five layers in flight, so the device's
 time), and the GB/s at which the hit experts' weights moved beside the
-HBM peak. ``--tiny`` rehearses it on a CPU at toy widths and times
-nothing worth reading; without it, off a TPU, it exits 3.
+HBM peak. ``--layouts`` walks the two ROW LAYOUTS instead, under the
+rule's blocks: ``packed`` (sorted rows end to end) and ``aligned``
+(every expert's rows from a row-tile boundary, ``moe._aligned_rows``'s
+other answer), a line each with ``weight_visits_mean`` (the (expert,
+tile) visits a grouped matmul makes, ``moe.weight_visits``),
+``buffer_rows`` and ``by_rule`` (the layout the program takes at that
+shape); ``--profile`` adds ``ops_ms_per_layer``, the device's ms a layer
+by op from a profiler session. ``--tiny`` rehearses it on a CPU at toy
+widths and times nothing worth reading; without it, off a TPU, it exits
+3.
 
     chiprun -- python tools/bench_moe_dispatch.py --serve
+    chiprun -- python tools/bench_moe_dispatch.py --serve --layouts \
+        --shapes mellum2_chunk,lfm2_chunk --valid-rows 215
 """
 
 import argparse
+import functools
 import json
 import os
 import statistics
 import sys
 import time
 
-sys.path.insert(
-    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 # bf16 operands, f32 accumulation, different summation orders: the first
 # chip reading had 8e-5 (out) to 4e-3 (dx) between the two.
@@ -130,16 +144,25 @@ def run(batch=8, seq=2048, d=1024, f=1024, experts=8, top_k=2,
 
 # The served expert layer's shapes (the cells' configurations under
 # benchmark/configs/: hidden_size, moe_intermediate_size, experts,
-# experts a token, expert layers held; decode rows are the cells' slots,
-# chunk rows their prefill chunk).
+# experts a token, expert layers held (five unless said); decode rows are
+# the cells' slots, chunk rows their prefill chunk).
 SERVE_SHAPES = {
     "xing_decode": dict(tokens=32, top_k=4, d=3584, f=1024, experts=64),
     "xing_chunk": dict(tokens=512, top_k=4, d=3584, f=1024, experts=64),
     "keye_decode": dict(tokens=16, top_k=8, d=2048, f=768, experts=128),
     "keye_chunk": dict(tokens=512, top_k=8, d=2048, f=768, experts=128),
+    "lfm2_decode": dict(tokens=32, top_k=4, d=2048, f=1536, experts=64,
+                        layers=8),
+    "lfm2_chunk": dict(tokens=512, top_k=4, d=2048, f=1536, experts=64,
+                       layers=8),
+    "mellum2_decode": dict(tokens=32, top_k=8, d=2304, f=896, experts=64,
+                           layers=8),
+    "mellum2_chunk": dict(tokens=512, top_k=8, d=2304, f=896, experts=64,
+                          layers=8),
 }
 SERVE_LAYERS = 5
 TINY_SHAPE = dict(tokens=24, top_k=2, d=256, f=128, experts=4)
+LAYOUTS = ("packed", "aligned")
 
 # Blocks tried beside today's and the rule's, by (d, f): ((tk, tn) of
 # the gate-and-up matmul [d, 2f], (tk, tn) of the down matmul [f, d]).
@@ -159,15 +182,9 @@ SERVE_BLOCKS = {
 }
 
 
-def _grid_steps(group_sizes, tm, d, f, blocks):
+def _grid_steps(visits, d, f, blocks):
     """Grid steps of the two ``gmm`` calls over one layer's groups:
     (row tile, group) visits x the weight blocks a visit streams."""
-    ends = group_sizes.cumsum()
-    starts = ends - group_sizes
-    hit = group_sizes > 0
-    visits = int(
-        ((ends[hit] - 1) // tm - starts[hit] // tm + 1).sum()
-    )
     (tk1, tn1), (tk2, tn2) = blocks
     per_visit = (
         -(-d // tk1) * -(-2 * f // tn1) + -(-f // tk2) * -(-d // tn2)
@@ -175,8 +192,35 @@ def _grid_steps(group_sizes, tm, d, f, blocks):
     return visits * per_visit
 
 
-def run_serve(shapes, repeats=20, seed=0, tiny=False):
-    """One JSON-able dict a (shape, blocks) entry, printed as made."""
+def _ops_ms(fn, args, layers, calls=3, top=12):
+    """ms a layer by device op (kernels and XLA's fusions, each under
+    its own name: ``fusion.17`` is not ``fusion.16``), from a profiler
+    session over ``calls`` calls."""
+    import collections
+
+    import jax
+
+    from benchmark import common, trace_reduce
+
+    prof = common.Profile(os.path.join(ROOT, "chiprun_out"))
+    prof.start()
+    for _ in range(calls):
+        jax.block_until_ready(fn(*args))
+    ops = collections.Counter()
+    for lines in (prof.stop() or {"planes": {}})["planes"].values():
+        for name, _, dur, _, cat in lines.get(trace_reduce.OPS_LINE) or []:
+            if cat not in trace_reduce.ENVELOPES:
+                ops[name] += dur
+    return {
+        name: round(ns / 1e6 / calls / layers, 4)
+        for name, ns in ops.most_common(top)
+    }
+
+
+def run_serve(shapes, repeats=20, seed=0, tiny=False, layouts=False,
+              valid_rows=None, profile=False):
+    """One JSON-able dict a (shape, blocks) entry, or with ``layouts`` a
+    (shape, row layout) entry under the rule's blocks, printed as made."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -195,24 +239,38 @@ def run_serve(shapes, repeats=20, seed=0, tiny=False):
         peak = flops.peaks_for(
             device.device_kind, common.load_json("peaks.json")
         )["hbm_bytes_per_s"]
-    rule = moe._weight_block
+    block_rule, layout_rule = moe._weight_block, moe._aligned_rows
+    by_rule = []    # the layout rule's answers, a trace each
+
     lines = []
     for name in shapes:
         sh = TINY_SHAPE if tiny else SERVE_SHAPES[name]
         n, top_k, d, f, e = (
             sh["tokens"], sh["top_k"], sh["d"], sh["f"], sh["experts"]
         )
-        layers, cdt = SERVE_LAYERS, jnp.bfloat16
+        layers, cdt = sh.get("layers", SERVE_LAYERS), jnp.bfloat16
         keys = jax.random.split(jax.random.key(seed), 5)
         x = jax.random.normal(keys[0], (1, n, d), jnp.float32).astype(cdt)
-        w_gu = (jax.random.normal(keys[1], (layers * e, d, 2 * f), cdt)
-                * d ** -0.5).astype(cdt)
-        w_down = (jax.random.normal(keys[2], (layers * e, f, d), cdt)
-                  * f ** -0.5).astype(cdt)
+
+        @functools.partial(jax.jit, static_argnums=(1, 2))
+        def stack(key, k, n_):
+            # a layer's weights once and every layer the same: the time
+            # a layer takes is not in their values, and eight layers of
+            # 64 experts drawn apart would pass through twice their
+            # bytes in temporaries
+            one = (jax.random.normal(key, (e, k, n_), jnp.float32)
+                   * k ** -0.5).astype(cdt)
+            return jnp.tile(one, (layers, 1, 1))
+
+        w_gu, w_down = stack(keys[1], d, 2 * f), stack(keys[2], f, d)
         # top_k distinct experts a token, every expert as likely
         experts = jnp.argsort(
             jax.random.uniform(keys[3], (layers, n, e)), axis=-1
         )[..., :top_k].astype(jnp.int32)
+        if valid_rows is not None and valid_rows < n:
+            experts = experts.at[:, valid_rows:].set(
+                experts[:, valid_rows][:, None]
+            )
         weights = jax.nn.softmax(
             jax.random.normal(keys[4], (layers, n, top_k)), axis=-1
         )
@@ -221,19 +279,36 @@ def run_serve(shapes, repeats=20, seed=0, tiny=False):
             for at in range(layers)
         ])
         even = n * top_k // e
-        tm = moe._tile(n * top_k, cap=min(max(even, moe.ROW_TILE), 512))
+        # the aligned layout's row tile, and the packed one's (which a
+        # buffer smaller than a tile cuts down)
+        tm_whole = min(max(even, moe.ROW_TILE), 512)
+        tm = moe._tile(n * top_k, cap=tm_whole)
         size = jnp.dtype(cdt).itemsize
         today = (
             (moe._tile(d), moe._tile(2 * f)), (moe._tile(f), moe._tile(d))
         )
         chosen = (
-            rule(even, tm, d, 2 * f, size), rule(even, tm, f, d, size)
+            block_rule(even, tm, d, 2 * f, size),
+            block_rule(even, tm, f, d, size),
         )
-        table = list(dict.fromkeys([today, chosen, *SERVE_BLOCKS[(d, f)]]))
+        if layouts:
+            table = [(chosen, layout) for layout in LAYOUTS]
+        else:
+            table = [(blocks, None) for blocks in dict.fromkeys(
+                [today, chosen, *SERVE_BLOCKS.get((d, f), [])]
+            )]
         args = (x, experts, weights, w_gu, w_down)
-        for blocks in table:
+        packed_out = None
+        for blocks, layout in table:
             by_shape = {(d, 2 * f): blocks[0], (f, d): blocks[1]}
             moe._weight_block = lambda even, tm, k, n_, size: by_shape[k, n_]
+            # the rule is asked either way, so that a line says what the
+            # program takes; a named layout overrides its answer
+            def answer(*shape_args, layout=layout):
+                by_rule.append(layout_rule(*shape_args))
+                return by_rule[-1] if layout is None else layout == "aligned"
+
+            moe._aligned_rows = answer
 
             # a function a table entry: jit traces each under its blocks
             def all_layers(x, experts, weights, w_gu, w_down):
@@ -242,40 +317,72 @@ def run_serve(shapes, repeats=20, seed=0, tiny=False):
                         x, experts[at], weights[at], w_gu, w_down, e,
                         group_offset=at * e,
                     )
-                    return out, counters.experts_hit
+                    visits = counters.weight_visits
+                    if visits is None:
+                        visits = moe.weight_visits(
+                            jnp.bincount(
+                                experts[at].ravel(), length=e
+                            ), tm, False,
+                        )
+                    return out, (counters.experts_hit, visits, out)
                 return jax.lax.scan(layer, x, jnp.arange(layers))
 
             try:
                 t0 = time.time()
                 fn = jax.jit(all_layers).lower(*args).compile()
                 compile_s = time.time() - t0
-                out, hit = jax.block_until_ready(fn(*args))
+                out, (hit, visits, outs) = jax.block_until_ready(fn(*args))
                 t0 = time.time()
                 for _ in range(repeats):
                     res = fn(*args)
                 jax.block_until_ready(res)
                 ms = (time.time() - t0) * 1e3 / repeats / layers
+                ops_ms = _ops_ms(fn, args, layers) if profile else None
             finally:
-                moe._weight_block = rule
+                moe._weight_block = block_rule
+                moe._aligned_rows = layout_rule
             hit_mean = float(jnp.mean(hit))
+            aligned = by_rule[-1] if layout is None else layout == "aligned"
+            pairs = n * top_k
+            tm_line = tm_whole if aligned else tm
             line = {
                 "shape": name,
                 "platform": device.platform, "device": device.device_kind,
-                "pairs": n * top_k, "groups": layers * e, "tm": tm,
+                "pairs": pairs, "groups": layers * e, "tm": tm_line,
                 "gate_up_block": list(blocks[0]),
                 "down_block": list(blocks[1]),
                 "block_mb": [
                     round(tk * tn * size / 1e6, 3) for tk, tn in blocks
                 ],
                 "today": blocks == today, "chosen": blocks == chosen,
+                "layout": "aligned" if aligned else "packed",
+                "by_rule": "aligned" if by_rule[-1] else "packed",
+                "buffer_rows": (
+                    (-(-pairs // tm_whole) + e) * tm_whole if aligned
+                    else -(-pairs // tm) * tm if pairs >= tm else pairs
+                ),
+                "valid_rows": n if valid_rows is None else valid_rows,
                 "experts_hit_mean": hit_mean,
+                "rows_max": int(sizes.max()),
+                "weight_visits_mean": float(jnp.mean(visits)),
                 "grid_steps_per_layer": float(np.mean([
-                    _grid_steps(sizes[at], tm, d, f, blocks)
-                    for at in range(layers)
+                    _grid_steps(int(v), d, f, blocks) for v in visits
                 ])),
                 "ms_per_layer": ms, "compile_s": round(compile_s, 2),
                 "finite": bool(jnp.isfinite(out.astype(jnp.float32)).all()),
             }
+            if ops_ms is not None:
+                line["ops_ms_per_layer"] = ops_ms
+            out = outs[0].astype(jnp.float32)    # the first layer's: of x
+            if layout == "packed":
+                packed_out = out
+            elif layout == "aligned":
+                # the same rows through the same kernel, the routing
+                # weight applied one rounding later
+                line["max_abs_diff_from_packed"] = float(
+                    jnp.max(jnp.abs(out - packed_out))
+                )
+                line["max_abs"] = float(jnp.max(jnp.abs(packed_out)))
             if peak:
                 gbs = hit_mean * 3 * d * f * size / (ms * 1e-3) / 1e9
                 line["hit_weights_gb_per_s"] = gbs
@@ -296,11 +403,18 @@ def main(argv=None):
                     help="--serve: which of SERVE_SHAPES, comma-separated")
     ap.add_argument("--tiny", action="store_true",
                     help="--serve: toy widths, runs on a CPU")
+    ap.add_argument("--layouts", action="store_true",
+                    help="--serve: both row layouts under the rule's blocks")
+    ap.add_argument("--profile", action="store_true",
+                    help="--serve: ms a layer by device op on each line")
+    ap.add_argument("--valid-rows", type=int, default=None,
+                    help="--serve: tokens past this one repeat it")
     a = ap.parse_args(argv)
     if a.serve:
         lines = run_serve(
             ["tiny"] if a.tiny else a.shapes.split(","),
             repeats=a.repeats or 20, seed=a.seed, tiny=a.tiny,
+            layouts=a.layouts, valid_rows=a.valid_rows, profile=a.profile,
         )
         if lines is None:
             return 3
