@@ -56,6 +56,30 @@ Design rules, same discipline as :func:`dlrover_tpu.fault.fault_point`:
   plus one ring append, ~25 us; after warm-up nothing compiles, so a
   steady window holds none of them (PERF.md, PR 35: 0 records in the
   30 s window of every cell).
+- **An armed process watches its host.** ``arm()`` starts the armed
+  Tracer's :class:`~dlrover_tpu.observability.host_watch.HostWatch`:
+  a daemon thread that records a ``local`` ``host.pause`` span (attrs
+  ``late_s``, ``process_cpu_s``) for every 5 ms wait that woke 60 ms
+  late or more, a ``gc.callbacks`` hook that records a ``host.gc`` span
+  a generation-2 collection, and one ``host.watch`` span at its start
+  (all three in the ring and the sink; ``build_trees`` and
+  ``trace_query.py --summary`` leave them out).
+  ``disarm()`` / ``close()`` / an ``arm()`` of another Tracer end it;
+  disarmed there is no thread, no hook and no clock read.
+  ``observability/stalls.py`` lays them over the step spans
+  (``tools/trace_query.py --stalls``). What the watcher costs armed, on
+  the v5e (PERF.md, PR 53; traced runs, the armed 30 s window): on
+  ``nemo12b-serve-chat`` 967.3 tokens/s in a window without a pause
+  against 969.9 without the watcher (one completion of 230) with
+  ``step_host_serial_ms_p50`` 0.80-0.84 ms against 0.79-0.82, and a
+  timed run armed with a JSONL sink 851.74 where disarmed runs read
+  851.72; on ``lfm2-serve-sessions-8k``, whose benchmark runner runs a
+  5 ms watcher of its own, 1,250.5 against 1,243-1,244 (the parent's
+  windows held a 0.1 s pause each) but ``step_host_serial_ms_p50``
+  2.14-2.18 ms against 1.81-1.86 and ``step_account_ms_p50`` 0.94-0.98
+  against 0.88-0.89: two threads that ask for the interpreter 400 times
+  a second cost a 14 ms step ~0.3 ms of host time, all of it hidden
+  under the device.
 - **One clock with the device trace.** A record's ``ts`` is epoch
   seconds (``time.time()`` back-dated by the monotonic distance),
   ``mono`` is ``time.monotonic()``. The JAX profiler's host plane
@@ -81,6 +105,7 @@ from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional
 
 from dlrover_tpu.common.log import logger
+from dlrover_tpu.observability import host_watch
 
 TRACE_FILE_ENV = "DLROVER_TPU_TRACE_FILE"
 SCHEMA_VERSION = 1
@@ -258,6 +283,22 @@ class Tracer:
         self._exports: "deque[Dict]" = deque(maxlen=export_capacity)
         self._dropped = 0
         self._on_finish = on_finish
+        # The armed Tracer's watcher of host pauses and full
+        # collections (observability/host_watch.py); None unless armed.
+        self._host_watch: Optional[host_watch.HostWatch] = None
+
+    # ---- host watcher (its life is the armed Tracer's) ---------------------
+
+    def start_host_watch(self):
+        if self._host_watch is None:
+            self._host_watch = host_watch.HostWatch(self)
+            self._host_watch.start()
+
+    def stop_host_watch(self):
+        # Outside ``_lock``: the watcher's last record needs it.
+        watch, self._host_watch = self._host_watch, None
+        if watch is not None:
+            watch.stop()
 
     # ---- span creation -----------------------------------------------------
 
@@ -424,6 +465,7 @@ class Tracer:
         return out
 
     def close(self):
+        self.stop_host_watch()
         with self._lock:
             if self._sink_file is not None:
                 try:
@@ -442,9 +484,15 @@ _arm_lock = threading.Lock()
 
 
 def arm(tracer: Tracer) -> Tracer:
+    """Make ``tracer`` the process's Tracer and start its host watcher
+    (``host.pause`` / ``host.gc`` spans); a Tracer armed before it
+    loses its watcher, so a process never runs two."""
     global _tracer
     with _arm_lock:
+        if _tracer is not None and _tracer is not tracer:
+            _tracer.stop_host_watch()
         _tracer = tracer
+        tracer.start_host_watch()
     return tracer
 
 
@@ -640,9 +688,13 @@ class TraceAggregator:
 
 def build_trees(spans: List[Dict]) -> List[Dict]:
     """Nest a flat span list into parent->children trees (shared by the
-    aggregator, the query CLI, and the soak's trace invariant)."""
+    aggregator, the query CLI, and the soak's trace invariant). The
+    armed Tracer's own watcher spans (``host.pause`` / ``host.gc`` /
+    ``host.watch``) are left out: they belong to no request's tree."""
     by_id = {}
     for record in spans:
+        if record.get("name") in host_watch.NAMES:
+            continue
         node = dict(record)
         node["children"] = []
         by_id[node.get("span_id")] = node

@@ -667,7 +667,6 @@ class ServingEngine:
         if slot >= 0:
             self._release_slot(req, slot)
         self.metrics.requests.inc(outcome="cancelled")
-        self.metrics.annotate("serving_evict", rid=req.rid)
 
     def pending(self) -> int:
         """Requests step() has yet to return: queued, in a slot, out of
@@ -768,10 +767,6 @@ class ServingEngine:
                 # request: counting it again would skew done/admitted
                 # completion-rate dashboards.
                 self.metrics.requests.inc(outcome="admitted")
-            self.metrics.annotate(
-                "serving_admit", rid=req.rid, slot=req.slot,
-                prompt_len=req.prompt_len, requeues=req.requeues,
-            )
         for req in sch.drain_admission_shed():
             # Deadline lapsed while waiting for a free slot: shed at
             # the admission decision, same terminal surface.
@@ -909,10 +904,6 @@ class ServingEngine:
         self.metrics.shed.inc(reason="deadline", slo_class=req.slo_class)
         self.metrics.requests.inc(outcome="shed")
         self.metrics.failures.inc(reason="deadline")
-        self.metrics.annotate(
-            "serving_shed", rid=req.rid, reason="deadline",
-            slo_class=req.slo_class,
-        )
         self._emit_request_spans(req, status="error")
 
     # ---- internals ---------------------------------------------------------
@@ -1126,7 +1117,7 @@ class ServingEngine:
         self.metrics.tokens.inc(kind="decode")
         if end:
             req.truncated = end == "truncated"
-            self._finish(req, finished, slot)
+            self._finish(req, finished)
         else:
             self._tokens[slot] = tok
 
@@ -1295,22 +1286,15 @@ class ServingEngine:
         )
         return emitted, acc
 
-    def _finish(self, req: Request, finished: List[Request],
-                slot: Optional[int] = None):
-        """``slot``: the one the request held, if it left it at its
-        last launch (:meth:`_vacate`)."""
-        if self._leaving.pop(req.rid, None) is None:
-            slot = req.slot
-            if slot >= 0:
-                self._release_slot(req, slot)
+    def _finish(self, req: Request, finished: List[Request]):
+        # A request that left its slot at its last launch
+        # (:meth:`_vacate`) has none to release.
+        if self._leaving.pop(req.rid, None) is None and req.slot >= 0:
+            self._release_slot(req, req.slot)
         self.scheduler.finish(req)
         finished.append(req)
         self.metrics.requests.inc(
             outcome="truncated" if req.truncated else "finished"
-        )
-        self.metrics.annotate(
-            "serving_finish", rid=req.rid, slot=slot,
-            new_tokens=len(req.tokens), truncated=req.truncated,
         )
         self._emit_request_spans(req)
 

@@ -1448,9 +1448,6 @@ class PagedServingEngine(ServingEngine):
         self._temps[slot] = 0.0
         self.metrics.kv_preemptions.inc()
         self.metrics.requests.inc(outcome="preempted")
-        self.metrics.annotate(
-            "serving_preempt", rid=victim.rid, slot=slot,
-        )
         logger.info(
             "kvpool pressure: preempted rid %d (slot %d) to free "
             "blocks", victim.rid, slot,

@@ -40,6 +40,12 @@ def tracer(tmp_path):
     tracing.disarm()
 
 
+def _not_the_watchers(spans):
+    """Without the ``host.*`` spans of the armed Tracer's own watcher
+    (observability/host_watch.py; tests/test_host_watch.py)."""
+    return [s for s in spans if not s["name"].startswith("host.")]
+
+
 # ---------------------------------------------------------------------------
 # Span layer basics
 # ---------------------------------------------------------------------------
@@ -60,13 +66,14 @@ def test_span_nesting_propagation_and_sink(tracer, tmp_path):
     assert child.trace_id == outer.trace_id
     assert child.parent_id == outer.span_id
     records = load_spans([str(tmp_path / "spans.jsonl")])
-    by_name = {r["name"]: r for r in records}
+    assert records[0]["name"] == "host.watch"  # the Tracer is armed
+    by_name = {r["name"]: r for r in _not_the_watchers(records)}
     assert set(by_name) == {"outer", "inner", "remote"}
     assert by_name["remote"]["dur_s"] == pytest.approx(1.5)
     assert by_name["inner"]["attrs"]["bytes"] == 42
     assert by_name["outer"]["service"] == "test"
     # Ring + trees: one coherent trace.
-    trees = build_trees(tracer.finished())
+    trees = build_trees(tracer.finished())  # (leaves the watcher's out)
     assert len(trees) == 1
     root = trees[0]
     assert root["name"] == "outer"
@@ -90,7 +97,7 @@ def test_error_status_on_exception(tracer):
     with pytest.raises(RuntimeError):
         with tracing.span("boom"):
             raise RuntimeError("nope")
-    (record,) = tracer.finished()
+    (record,) = _not_the_watchers(tracer.finished())
     assert record["status"] == "error"
     assert record["attrs"]["error"] == "RuntimeError"
 
@@ -646,7 +653,7 @@ def test_trace_query_summary_and_critical_path(tmp_path, capsys):
         root.end(end_mono=root.start_mono + 2.0)
     finally:
         tracing.disarm()
-    spans = load_spans([str(sink)])
+    spans = _not_the_watchers(load_spans([str(sink)]))
     assert len(spans) == 4
 
     rows = trace_query.summarize(spans)
@@ -672,6 +679,7 @@ def test_trace_query_summary_and_critical_path(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "fleet.request" in out
+    assert "host.watch" not in out  # in the sink, and --stalls' to read
     rc = trace_query.main([str(sink), "--trace", trace_id])
     assert rc == 0
     out = capsys.readouterr().out

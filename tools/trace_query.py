@@ -31,6 +31,16 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # steps recompiled)
     python tools/trace_query.py --steps spans_engine.jsonl
 
+    # why an iteration took far longer than its like: every
+    # serving.step whose period (start to the next step's start)
+    # exceeds its kind's median by max(0.05 s, median), put down to
+    # the machine (a host.pause span with little CPU burned), the
+    # interpreter (about its length burned; "unattributed" between
+    # the two), a compile, a collection (host.gc), the device, the
+    # caller or a host phase, and the seconds lost by cause
+    # (observability/stalls.py; --step-name train.step for a trainer)
+    python tools/trace_query.py --stalls spans_engine.jsonl
+
     # a worker's or a replica's start: seconds compiling, loading
     # from the persistent cache and tracing + lowering by program (with
     # how the cache answered each compile), the cache directory as the
@@ -54,6 +64,7 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
+from dlrover_tpu.observability import host_watch, stalls  # noqa: E402
 from dlrover_tpu.observability.tracing import (  # noqa: E402
     build_trees,
     load_spans,
@@ -230,6 +241,18 @@ def _print_setup(table: Dict) -> None:
             print(f"  {seconds:>9.3f} s  {phase}")
 
 
+def _print_stalls(table: Dict, spans: List[Dict]) -> None:
+    watch = (
+        "host watcher on"
+        if any(s.get("name") == host_watch.WATCH for s in spans)
+        else "NO host.watch span: pauses and collections went "
+        "unrecorded and read as device_wait or a host phase"
+    )
+    print(f"{table['steps']} steps judged over {table['window_s']:.3f} s, "
+          f"{len(table['stalls'])} stalled; {watch}")
+    print("\n".join(stalls.render(table)))
+
+
 def _sparse_counts(attrs: List[Dict]) -> Dict:
     out = {}
     for name, count in (("selected_rows_mean", "selected_rows"),
@@ -353,6 +376,13 @@ def main(argv=None) -> int:
                     "trace seconds by program from compile.* spans, the "
                     "cache directory at start, engine build and "
                     "warm-up phases")
+    ap.add_argument("--stalls", action="store_true",
+                    help="the steps that took far longer than their "
+                    "like, each with its cause, and the seconds lost "
+                    "by cause")
+    ap.add_argument("--step-name", default=stalls.SERVING_STEP,
+                    help="the step span --stalls judges (train.step "
+                    "for a trainer's sink)")
     ap.add_argument("--trace",
                     help="print one trace's tree + critical path")
     ap.add_argument("--json", action="store_true",
@@ -381,6 +411,18 @@ def main(argv=None) -> int:
             )
         return 0
 
+    if ns.stalls:
+        table = stalls.summary(spans, step_name=ns.step_name)
+        if not table["steps"]:
+            print(f"no {ns.step_name} span has a period to judge",
+                  file=sys.stderr)
+            return 1
+        if ns.json:
+            print(json.dumps(table))
+        else:
+            _print_stalls(table, spans)
+        return 0
+
     if ns.setup:
         table = setup_summary(spans)
         if not table["programs"] and not table["engine"]:
@@ -403,7 +445,10 @@ def main(argv=None) -> int:
             table = step_summary(spans)
             rows, counts = table["phases"], table["counts"]
         else:
-            rows = summarize(spans)
+            # (the armed Tracer's own watcher spans are --stalls')
+            rows = summarize(
+                [s for s in spans if s.get("name") not in host_watch.NAMES]
+            )
         if ns.verbs and not rows:
             print("no master.<verb> server spans found", file=sys.stderr)
             return 1
