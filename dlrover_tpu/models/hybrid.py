@@ -63,7 +63,8 @@ from dlrover_tpu.ops.norms import rms_norm
 from dlrover_tpu.parallel.sharding import with_logical_constraint
 
 Pattern = Tuple[Tuple[str, str], ...]
-COUNTERS = ("moe_rows_held", "moe_rows_max", "moe_rows_dropped")
+COUNTERS = ("moe_rows_held", "moe_rows_max", "moe_rows_dropped",
+            "moe_rows_full_path")
 MTP_COUNTER = "mtp_moe_rows_held"    # the module's block, apart
 REMAT_KEEP = ("dots", "attention")
 
@@ -450,7 +451,9 @@ def _moe_apply(config, p, buffers, h):
     )
     with jax.named_scope("shared"):
         out = routed + _swiglu(p["shared"], h)
-    return out, jnp.stack([c.rows_held, c.rows_max, c.rows_dropped])
+    return out, jnp.stack(
+        [c.rows_held, c.rows_max, c.rows_dropped, c.rows_full_path]
+    )
 
 
 MIXERS: Dict[str, Kind] = {
@@ -624,16 +627,21 @@ def _block(config, kinds, positions):
     compiled step read 12,398 tokens/s where this reads 12,470 (my chip
     runs, PR 31; the layers' remat bodies are then laid out
     differently)."""
-    policies = jax.checkpoint_policies
-    if config.remat_keep == "attention":
-        keep = policies.save_only_these_names("flash_out", "flash_lse")
-    else:
-        keep = policies.save_from_both_policies(
-            policies.save_only_these_names("kda_out", "kda_states"),
-            policies.dots_with_no_batch_dims_saveable,
-        )
     return jax.checkpoint(
-        functools.partial(_layer, config, kinds, positions), policy=keep
+        functools.partial(_layer, config, kinds, positions),
+        policy=remat_policy(config.remat_keep),
+    )
+
+
+def remat_policy(remat_keep: str):
+    """What a layer keeps for its backward (:func:`_block`), a new
+    policy object a call."""
+    policies = jax.checkpoint_policies
+    if remat_keep == "attention":
+        return policies.save_only_these_names("flash_out", "flash_lse")
+    return policies.save_from_both_policies(
+        policies.save_only_these_names("kda_out", "kda_states"),
+        policies.dots_with_no_batch_dims_saveable,
     )
 
 

@@ -793,6 +793,10 @@ class ShareCounters(NamedTuple):
     # (expert, row tile) visits of each grouped matmul, where the rows
     # are expert-aligned (:func:`weight_visits`); None where they are not
     weight_visits: Optional[jnp.ndarray] = None
+    # ``rows_held`` of a call that went through the buffer that holds any
+    # routing, 0 of one that took a share's fast path
+    # (:func:`_share_rows_held`); ``moe_mlp_share`` alone counts it
+    rows_full_path: Optional[jnp.ndarray] = None
 
 
 def router_scores(x, router_w):
@@ -941,7 +945,7 @@ def moe_mlp_share(
         experts, weights = sigmoid_route(
             xf, router_w, router_bias, top_k, scaling
         )
-    out, group_sizes, n_held, visits = _routed_share(
+    out, group_sizes, n_held, visits, full = _routed_share(
         xf, (b, s, d), experts, weights, router_w.shape[-1], w_down,
         w_gate=w_gate, w_up=w_up, first=first, interpret=interpret,
     )
@@ -950,6 +954,7 @@ def moe_mlp_share(
         rows_max=jnp.max(group_sizes),
         rows_dropped=jnp.zeros((), jnp.int32),
         weight_visits=visits,
+        rows_full_path=full,
     )
 
 
@@ -968,7 +973,7 @@ def routed_experts(x, experts, weights, w_gu, w_down, n_experts: int,
     capacity in any of them, so a token's experts give it the same
     output whatever else the call carries."""
     b, s, d = x.shape
-    out, group_sizes, n_held, visits = _routed_share(
+    out, group_sizes, n_held, visits, _ = _routed_share(
         x.reshape(b * s, d), (b, s, d), experts, weights, n_experts,
         w_down, w_gu=w_gu, held=n_experts, group_offset=group_offset,
         interpret=interpret,
@@ -987,7 +992,8 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
                   group_offset=None, interpret=None):
     """The expert compute both entries share: ``xf [n, embed]`` and its
     routing -> (out ``shape``, rows a weight group got, their sum, the
-    matmuls' weight visits where the rows are expert-aligned or None).
+    matmuls' weight visits where the rows are expert-aligned or None,
+    the rows that went through the buffer that holds any routing).
     Experts ``first .. first + held - 1`` are computed (``held``: every
     group of ``w_down`` when None); expert ``first`` is weight group
     ``group_offset`` (0 when None)."""
@@ -1003,12 +1009,6 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
         if group_offset is not None:
             local = local + group_offset
         local = jnp.where(is_held, local, groups)      # absent: sorted last
-        order = jnp.argsort(local, stable=True)
-        inv_order = jnp.argsort(order)
-        group_sizes = jnp.bincount(local, length=groups + 1)[:groups].astype(
-            jnp.int32
-        )
-        n_held = jnp.sum(group_sizes)
         # A row tile no larger than the rows an evenly loaded expert
         # gets: a group pays for whole tiles.
         even = n * top_k // e_all
@@ -1018,38 +1018,84 @@ def _routed_share(xf, shape, experts, weights, e_all: int, w_down, *,
             return (rows + tm - 1) // tm * tm if rows >= tm else rows
 
         most = padded(n * min(top_k, held))
-        # one layout a call, by the buffer that holds any routing
-        aligned = _aligned_rows(
+        usual = padded(4 * even * held)
+        # A SHARE (few of the experts held) fills a fraction of ``most``:
+        # it asks whether its pairs fit four even shares' rows, and then
+        # works by row and by token, not by pair (:func:`_share_rows_held`).
+        share = 0 < usual < most   # (0: fewer pairs than experts)
+        # one layout a call, by the buffer that holds any routing (a
+        # share's rows are packed in both of its buffers)
+        aligned = not share and _aligned_rows(
             even, tm, most, held, n, xf.shape[1], w_down.shape[-2],
             jnp.dtype(xf.dtype).itemsize,
         )
 
-        def through(rows):
+        operands = (xf, weights, w_gate, w_up, w_down, w_gu)
+
+        def through_most(xf, weights, w_gate, w_up, w_down, w_gu,
+                         group_sizes=None, n_held=None):
+            order = jnp.argsort(local, stable=True)
+            inv_order = jnp.argsort(order)
+            if group_sizes is None:
+                group_sizes = jnp.bincount(
+                    local, length=groups + 1
+                )[:groups].astype(jnp.int32)
+                n_held = jnp.sum(group_sizes)
             if aligned:
-                return _share_rows_aligned(
+                out = _share_rows_aligned(
                     xf, weights, w_gate, w_up, w_down, order, inv_order,
                     is_held, local, group_sizes,
-                    (-(-rows // tm) + held) * tm, tm, interpret, w_gu, even,
+                    (-(-most // tm) + held) * tm, tm, interpret, w_gu, even,
                 )
-            return _share_rows(
-                xf, weights, w_gate, w_up, w_down, order, inv_order,
-                is_held, group_sizes, n_held, rows, tm, interpret, w_gu,
-                even,
-            )
+            else:
+                out = _share_rows(
+                    xf, weights, w_gate, w_up, w_down, order, inv_order,
+                    is_held, group_sizes, n_held, most, tm, interpret, w_gu,
+                    even,
+                )
+            return out, group_sizes, n_held
 
-        usual = padded(4 * even * held)
-        if 0 < usual < most:   # (0: fewer pairs than experts)
-            out = jax.lax.cond(
-                n_held <= usual, lambda: through(usual),
-                lambda: through(most),
+        if share:
+            group_of = local.reshape(n, top_k)
+            # (token, group): 1 where the token holds the group's expert.
+            # A token's experts differ, so a column sum is a group's rows.
+            member = jnp.sum(
+                group_of[:, :, None] == jnp.arange(groups), axis=1,
+                dtype=jnp.int32,
             )
+            group_sizes = jnp.sum(member, axis=0)
+            n_held = jnp.sum(group_sizes)
+            tail = min(TAIL_TOKENS, n)
+            fast = (n_held <= usual) & (jnp.sum(
+                jnp.sum(member, axis=1) > LEVELS
+            ) <= tail)
+            branches = (
+                lambda *operands: _share_rows_held(
+                    *operands, group_of, member, group_sizes, n_held, usual,
+                    tail, tm, interpret, even,
+                ),
+                lambda *operands: through_most(
+                    *operands, group_sizes, n_held
+                )[0],
+            )
+            if most >= RERUN_FROM * usual:
+                # Each branch keeps nothing for its backward but its
+                # operands and runs again there: what a branch keeps, a
+                # ``cond`` hands out of BOTH its branches, the other's as
+                # zeros, so the fast path would write the full path's
+                # residuals (2 GB a layer at 8,192 tokens x top-8 of 256:
+                # 2.5 ms of a 12.9 ms layer) every time it ran.
+                branches = tuple(jax.checkpoint(b) for b in branches)
+            out = jax.lax.cond(fast, *branches, *operands)
+            full = jnp.where(fast, 0, n_held)
         else:
-            out = through(most)
+            out, group_sizes, n_held = through_most(*operands)
+            full = n_held
         out = with_logical_constraint(
             out.reshape(shape), ("batch", "seq", "embed")
         )
         visits = weight_visits(group_sizes, tm, True) if aligned else None
-    return out, group_sizes, n_held, visits
+    return out, group_sizes, n_held, visits, full
 
 
 def _grouped_swiglu(xs, w_gate, w_up, w_gu, w_down, group_sizes, tm, even,
@@ -1115,6 +1161,212 @@ def _share_rows(xf, weights, w_gate, w_up, w_down, order, inv_order,
     return jnp.sum(
         per_pair.reshape(n, top_k, d).astype(jnp.float32), axis=1
     ).astype(cdt)                                      # back in token order
+
+
+# A share's fast path reads a token's first LEVELS held rows by one
+# gather of ``n`` indices a level, and the further rows of the few tokens
+# that hold more (by the binomial of an even router one token in 700 of
+# 8,192 x top-8 of 256 with 8 held, one in 140 of top-4 of 64) through a
+# buffer of TAIL_TOKENS tokens; more of them than that and the call takes
+# the full path.
+LEVELS = 2
+TAIL_TOKENS = 256
+# A share whose full buffer is at least this many usual ones runs its
+# ``cond``'s branches again in the backward (``_routed_share``). The zeros
+# a fast call writes for the full branch's residuals grow with the full
+# buffer; what running again costs is a second compiled copy of each
+# branch's forward, ~30 MB of executable a branch to load at every start
+# (+4.5 s of a 56 s set-up where the ratio is 2 and the zeros 1.8 ms a
+# layer; at 8 the zeros are 2.5 ms of a 12.9 ms layer: PERF.md, PR 54).
+RERUN_FROM = 4
+
+
+def _held_reads(at, tail_token, rows: int):
+    """How a token reads its rows of a ``rows``-row buffer, from ``at [n,
+    top_k]`` (the row of each of its pairs; ``rows``: an absent
+    expert's): a list of (``index [n]``, ``select [n, top_k]``) a
+    level, ``index`` the row (``rows``: none, read as zeros) and
+    ``select`` the one ``k`` it belongs to; and, where a token can hold
+    more than ``LEVELS`` rows, the same for ALL the later pairs of the
+    tail's tokens ``tail_token [tail]`` (``n``: a free slot) at once:
+    (``index [tail, top_k]``, ``select [tail, top_k]``), else None.
+    Elementwise over ``top_k`` but for the tail's ``tail`` indices."""
+    def nth(at):
+        hit = at < rows
+        return hit, jnp.cumsum(hit, axis=1, dtype=jnp.int32) - hit
+
+    hit, earlier = nth(at)
+    levels = []
+    for level in range(min(LEVELS, at.shape[1])):
+        select = hit & (earlier == level)
+        levels.append((jnp.min(jnp.where(select, at, rows), axis=1), select))
+    if tail_token is None:
+        return levels, None
+    at = jnp.take(at, tail_token, axis=0, mode="fill", fill_value=rows)
+    hit, earlier = nth(at)
+    later = hit & (earlier >= LEVELS)
+    return levels, (jnp.where(later, at, rows), later)
+
+
+def _tail_rows(buf, index):
+    """``buf[index]`` in float32, ``[tail, top_k, d]``, by one gather."""
+    return _take_or_zero(buf, index.reshape(-1)).astype(
+        jnp.float32
+    ).reshape(*index.shape, buf.shape[1])
+
+
+def _read_held(buf, weights, at, tail_token):
+    """``out[t] = sum over k of weights[t, k] * buf[at[t, k]]`` in
+    float32, ``[n, d]``: level by level, the tail's sum laid back over
+    the tokens by :func:`_lay_rows` (rounded to ``buf``'s dtype first:
+    one rounding of a partial sum of a token in hundreds). ``weights``
+    None: ones."""
+    n = at.shape[0]
+    levels, tail = _held_reads(at, tail_token, buf.shape[0])
+    out = 0.0
+    for index, select in levels:
+        got = _take_or_zero(buf, index).astype(jnp.float32)
+        if weights is not None:
+            got = got * jnp.sum(
+                jnp.where(select, weights, 0.0), axis=1, keepdims=True
+            )
+        out = out + got
+    if tail is not None:
+        index, select = tail
+        got = _tail_rows(buf, index)
+        if weights is not None:
+            got = got * jnp.where(select, jnp.take(
+                weights, tail_token, axis=0, mode="fill", fill_value=0
+            ), 0.0)[:, :, None]
+        out = out + _lay_rows(
+            jnp.sum(got, axis=1).astype(buf.dtype), tail_token[:, None], n
+        )
+    return out
+
+
+def _no_grad(*maps):
+    """The cotangents of ``int32`` maps (None: an operand left out)."""
+    return tuple(
+        None if m is None else np.zeros(m.shape, jax.dtypes.float0)
+        for m in maps
+    )
+
+
+@jax.custom_vjp
+def _dispatch_held(xf, token_of, at, tail_token):
+    """``_take_or_zero(xf, token_of)``: a buffer's rows, each the token
+    it computes (``n``: none). The transpose is the combine's read with
+    no weights: a token sums the cotangents of its rows."""
+    return _take_or_zero(xf, token_of)
+
+
+def _dispatch_held_fwd(xf, token_of, at, tail_token):
+    return _take_or_zero(xf, token_of), (token_of, at, tail_token)
+
+
+def _dispatch_held_bwd(res, g):
+    token_of, at, tail_token = res
+    dx = _read_held(g, None, at, tail_token).astype(g.dtype)
+    return (dx, *_no_grad(token_of, at, tail_token))
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(ys, weights, token_of, pair_of, at, tail_token):
+    """A token's weighted rows summed in float32 (:func:`_read_held`),
+    ``[n, d]`` in ``ys``'s dtype. The transposes: a row's cotangent is
+    its token's times its weight, one gather of ``rows`` indices out of
+    the ``[n, d]`` cotangent (``token_of``, ``pair_of [rows]``: the
+    token and the pair a row computes); a weight's is its row's dot with
+    the token's cotangent, level by level."""
+    return _read_held(ys, weights, at, tail_token).astype(ys.dtype)
+
+
+def _combine_held_fwd(ys, weights, token_of, pair_of, at, tail_token):
+    out = _read_held(ys, weights, at, tail_token).astype(ys.dtype)
+    return out, (ys, weights, token_of, pair_of, at, tail_token)
+
+
+def _combine_held_bwd(res, g):
+    ys, weights, token_of, pair_of, at, tail_token = res
+    n, top_k = at.shape
+    by_row = _take_or_zero(weights.reshape(n * top_k, 1), pair_of)
+    d_ys = (
+        _take_or_zero(g, token_of).astype(jnp.float32) * by_row
+    ).astype(ys.dtype)
+    levels, tail = _held_reads(at, tail_token, ys.shape[0])
+    g = g.astype(jnp.float32)
+    d_weights = sum(
+        jnp.where(select, jnp.sum(
+            _take_or_zero(ys, index).astype(jnp.float32) * g, axis=1,
+            keepdims=True,
+        ), 0.0)
+        for index, select in levels
+    )
+    if tail is not None:
+        index, select = tail
+        dots = jnp.sum(
+            _tail_rows(ys, index) * _take_or_zero(g, tail_token)[:, None, :],
+            axis=2,
+        )
+        d_weights = d_weights + _lay_rows(
+            jnp.where(select, dots, 0.0), tail_token[:, None], n
+        )
+    return (d_ys, d_weights.astype(weights.dtype),
+            *_no_grad(token_of, pair_of, at, tail_token))
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def _share_rows_held(xf, weights, w_gate, w_up, w_down, w_gu, group_of,
+                     member, group_sizes, n_held, rows, tail, tm, interpret,
+                     even):
+    """:func:`_share_rows` for a SHARE, whose ``rows`` (>= ``n_held``)
+    are a fraction of the ``n * top_k`` pairs: no map, gather or
+    transpose is sized by the pairs, and nothing scatters. ``group_of
+    [n, top_k]``: a pair's weight group (``groups``: absent), ``member
+    [n, groups]``: 1 where the token holds the group's expert.
+
+    * pair -> row by arithmetic: a group's first row plus the earlier
+      tokens that hold the group (a running sum down ``n`` a group), so
+      a group's rows are in token order as a stable sort leaves them;
+    * row -> pair by ONE sort, of ``group * pairs + pair`` (the payload
+      is in the key), cut to ``rows``;
+    * a token reads its rows level by level (:func:`_read_held`), the
+      routing weight multiplying in float32 at the token, as in
+      :func:`_share_rows_aligned`; ``tail`` tokens may hold more than
+      ``LEVELS`` rows (the caller has counted them);
+    * every transpose is the other direction's read.
+    """
+    n, top_k = group_of.shape
+    groups, pairs = group_sizes.shape[0], n * top_k
+    starts = jnp.cumsum(group_sizes) - group_sizes
+    place = starts + jnp.cumsum(member, axis=0) - member      # [n, groups]
+    at = jnp.sum(jnp.where(
+        group_of[:, :, None] == jnp.arange(groups), place[:, None, :], 0
+    ), axis=2)
+    at = jnp.where(group_of < groups, at, rows)
+    pair_of = jnp.sort(
+        group_of.reshape(pairs) * pairs + jnp.arange(pairs)
+    )[:rows] % pairs
+    pair_of = jnp.where(jnp.arange(rows) < n_held, pair_of, pairs)
+    token_of = pair_of // top_k
+    tail_token = None
+    if min(top_k, groups) > LEVELS:
+        more = jnp.sum(member, axis=1) > LEVELS                # [n]
+        slot = jnp.cumsum(more, dtype=jnp.int32) - more
+        tail_token = jnp.sum(jnp.where(
+            more & (slot == jnp.arange(tail)[:, None]), jnp.arange(n) - n, 0
+        ), axis=1) + n                                         # (n: free)
+    xs = _dispatch_held(xf, token_of, at, tail_token)      # [rows, d]
+    ys = _grouped_swiglu(
+        xs, w_gate, w_up, w_gu, w_down, group_sizes, _tile(rows, cap=tm),
+        even, interpret,
+    )                                  # (rows past the groups: unwritten)
+    return _combine_held(ys, weights, token_of, pair_of, at, tail_token)
 
 
 def _share_rows_aligned(xf, weights, w_gate, w_up, w_down, order, inv_order,

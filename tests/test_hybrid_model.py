@@ -317,12 +317,19 @@ def test_the_train_step_lowers_to_the_text_it_had_before_expert_serving(
     signature and, held here, its program: this step's lowered text is
     byte for byte what the commit before that PR lowers (sha256 of the
     text, taken there at this size). A PR that means to change the
-    hybrid train step replaces the digest."""
+    hybrid train step replaces the digest. PR 54 did (``8d3d698e...``
+    until then): the step's metrics carry ``moe_rows_full_path``, the
+    rows of the expert layers that left a share's fast path
+    (``moe._share_rows_held``). At this size no layer has one (160
+    tokens x top-4 of 16 experts, 4 held: four even shares' rows are all
+    640 pairs), so every layer takes the path it took and counts its
+    rows; ``tests/test_moe_share_rows.py`` holds the fast path, and
+    ``tools/program_hashes.py`` the cells' own programs."""
     import hashlib
 
     text = tiny_step[3].as_text()      # lowered for int32 [2, 81] tokens
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        "8d3d698e6e67c3b3"
+        "cf0cf544049b6625"
     out, counters = moe.moe_mlp_share(
         jnp.zeros((1, 8, 32)), jnp.zeros((32, 16)), jnp.zeros((16,)),
         *(jnp.zeros(s) for s in ((4, 32, 8), (4, 32, 8), (4, 8, 32))),

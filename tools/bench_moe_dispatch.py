@@ -52,6 +52,31 @@ widths and times nothing worth reading; without it, off a TPU, it exits
     chiprun -- python tools/bench_moe_dispatch.py --serve
     chiprun -- python tools/bench_moe_dispatch.py --serve --layouts \
         --shapes mellum2_chunk,lfm2_chunk --valid-rows 215
+
+``--share`` times the TRAINED share instead (``moe.moe_mlp_share``, what
+the ``mlp/router`` and ``mlp/experts`` scopes of a train cell's step
+hold): ONE layer's forward + backward (gradients of x, the router and
+the three expert weights) under ``jax.checkpoint`` with the cell's
+``remat_keep`` policy (``hybrid.remat_policy``), at the shapes of
+``SHARE_SHAPES``: ``kimi`` (``kimilinear-train-8k``: 8,192 tokens x
+2,304, top-8 of 256 experts, 8 held) and ``glm``
+(``glm47flash-train-8k``: 8,192 x 2,048, top-4 of 64, 8 held), float32
+weights cast a layer as the step casts them, a seeded router (even
+routing: a share gets ``held / all`` of the pairs, give or take) or
+with ``--skew`` a bias that sends every token's pairs to the held
+experts (more rows than the usual buffer: the full one). One JSON line a
+shape: ms a layer (host clock over ``--repeats`` calls in flight),
+``rows_held``, ``rows_max``, ``rows_full_path`` (the rows of a call that
+left the fast path; 0 where it ran, null at a parent that has no such
+counter), and with ``--profile`` ``ops_ms_per_layer``: the device's ms
+by op (each sort, gather, scatter, fusion and kernel under its own
+name). ``--tiny`` rehearses it on a CPU. (PR 54's readings of its parent:
+this file run against the parent's checkout, which has no
+``hybrid.remat_policy``, with ``_block``'s policies supplied under that
+name.)
+
+    chiprun -- python tools/bench_moe_dispatch.py --share --profile
+    chiprun -- python tools/bench_moe_dispatch.py --share --skew
 """
 
 import argparse
@@ -190,6 +215,20 @@ def _grid_steps(visits, d, f, blocks):
         -(-d // tk1) * -(-2 * f // tn1) + -(-f // tk2) * -(-d // tn2)
     )
     return visits * per_visit
+
+
+# The trained share's shapes (benchmark/configs/kimi-linear-48b-a3b.json,
+# glm-4.7-flash.json: hidden_size, moe_intermediate_size, the router's
+# width, experts a token, experts held, routed_scaling_factor, the
+# step's remat_keep; tokens: micro_batch x seq_len).
+SHARE_SHAPES = {
+    "kimi": dict(tokens=8192, d=2304, f=1024, experts=256, top_k=8, held=8,
+                 scaling=2.446, remat_keep="dots"),
+    "glm": dict(tokens=8192, d=2048, f=1536, experts=64, top_k=4, held=8,
+                scaling=1.8, remat_keep="attention"),
+}
+TINY_SHARE = dict(tokens=256, d=128, f=64, experts=32, top_k=4, held=4,
+                  scaling=2.0, remat_keep="dots")
 
 
 def _ops_ms(fn, args, layers, calls=3, top=12):
@@ -393,29 +432,125 @@ def run_serve(shapes, repeats=20, seed=0, tiny=False, layouts=False,
     return lines
 
 
+def run_share(shapes, repeats=20, seed=0, tiny=False, skew=False,
+              profile=False):
+    """One JSON-able dict a shape, printed as made (None: no TPU)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models import hybrid, moe
+
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not tiny:
+        print("bench_moe_dispatch --share: no TPU here (--tiny rehearses "
+              "on a CPU)", file=sys.stderr)
+        return None
+    lines = []
+    for name in shapes:
+        sh = TINY_SHARE if tiny else SHARE_SHAPES[name]
+        n, d, f, e, held = (
+            sh["tokens"], sh["d"], sh["f"], sh["experts"], sh["held"]
+        )
+        top_k, first = sh["top_k"], held    # the second share of e / held
+        keys = jax.random.split(jax.random.key(seed), 7)
+
+        def normal(key, shape, fan_in, dtype=jnp.float32):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    / fan_in ** 0.5).astype(dtype)
+
+        bias = 0.01 * jax.random.normal(keys[5], (e,), jnp.float32)
+        if skew:
+            bias = bias.at[first:first + held].set(100.0)
+        args = (
+            normal(keys[0], (1, n, d), 1, jnp.bfloat16),
+            normal(keys[1], (d, e), d), normal(keys[2], (held, d, f), d),
+            normal(keys[3], (held, d, f), d), normal(keys[4], (held, f, d), f),
+        )
+        cot = normal(keys[6], (1, n, d), 1)
+
+        @functools.partial(
+            jax.checkpoint, policy=hybrid.remat_policy(sh["remat_keep"])
+        )
+        def layer(x, router, w_gate, w_up, w_down):
+            return moe.moe_mlp_share(
+                x, router, bias, w_gate, w_up, w_down, first=first,
+                top_k=top_k, scaling=sh["scaling"],
+            )
+
+        def loss(*a):
+            out, counters = layer(*a)
+            return jnp.sum(out.astype(jnp.float32) * cot), counters
+
+        grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        t0 = time.time()
+        fn = jax.jit(grad).lower(*args).compile()
+        compile_s = time.time() - t0
+        (_, counters), grads = jax.block_until_ready(fn(*args))
+        t0 = time.time()
+        for _ in range(repeats):
+            res = fn(*args)
+        jax.block_until_ready(res)
+        full = getattr(counters, "rows_full_path", None)
+        line = {
+            "shape": name, "skew": skew,
+            "platform": device.platform, "device": device.device_kind,
+            "tokens": n, "pairs": n * top_k, "held": held,
+            "remat_keep": sh["remat_keep"],
+            "rows_held": int(counters.rows_held),
+            "rows_max": int(counters.rows_max),
+            "rows_dropped": int(counters.rows_dropped),
+            "rows_full_path": None if full is None else int(full),
+            "ms_per_layer": (time.time() - t0) * 1e3 / repeats,
+            "compile_s": round(compile_s, 2),
+            "finite": all(
+                bool(jnp.isfinite(g.astype(jnp.float32)).all()) for g in grads
+            ),
+        }
+        if profile:
+            line["ops_ms_per_layer"] = _ops_ms(fn, args, 1, top=48)
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--repeats", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--serve", action="store_true",
                     help="time the served expert layer over weight blocks")
-    ap.add_argument("--shapes", default=",".join(SERVE_SHAPES),
-                    help="--serve: which of SERVE_SHAPES, comma-separated")
+    ap.add_argument("--share", action="store_true",
+                    help="time the trained share's forward + backward")
+    ap.add_argument("--skew", action="store_true",
+                    help="--share: every token's pairs go to the held experts")
+    ap.add_argument("--shapes", default=None,
+                    help="which of SERVE_SHAPES (--serve) or SHARE_SHAPES "
+                         "(--share), comma-separated; default: all")
     ap.add_argument("--tiny", action="store_true",
-                    help="--serve: toy widths, runs on a CPU")
+                    help="--serve, --share: toy widths, runs on a CPU")
     ap.add_argument("--layouts", action="store_true",
                     help="--serve: both row layouts under the rule's blocks")
     ap.add_argument("--profile", action="store_true",
-                    help="--serve: ms a layer by device op on each line")
+                    help="--serve, --share: ms a layer by device op on "
+                         "each line")
     ap.add_argument("--valid-rows", type=int, default=None,
                     help="--serve: tokens past this one repeat it")
     a = ap.parse_args(argv)
-    if a.serve:
-        lines = run_serve(
-            ["tiny"] if a.tiny else a.shapes.split(","),
-            repeats=a.repeats or 20, seed=a.seed, tiny=a.tiny,
-            layouts=a.layouts, valid_rows=a.valid_rows, profile=a.profile,
-        )
+    if a.share or a.serve:
+        names = ["tiny"] if a.tiny else (a.shapes or ",".join(
+            SHARE_SHAPES if a.share else SERVE_SHAPES
+        )).split(",")
+        if a.share:
+            lines = run_share(
+                names, repeats=a.repeats or 20, seed=a.seed, tiny=a.tiny,
+                skew=a.skew, profile=a.profile,
+            )
+        else:
+            lines = run_serve(
+                names, repeats=a.repeats or 20, seed=a.seed, tiny=a.tiny,
+                layouts=a.layouts, valid_rows=a.valid_rows,
+                profile=a.profile,
+            )
         if lines is None:
             return 3
         return 0 if all(line["finite"] for line in lines) else 1
