@@ -614,7 +614,7 @@ class ServingEngine:
             self.config.n_layers, self.slots, self.max_len,
             self.config.n_kv_heads, self.config.head_dim,
         )
-        dtype = self.config.compute_dtype
+        dtype = _slab_dtype(self.config)
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
     # ---- public API --------------------------------------------------------
@@ -1408,3 +1408,19 @@ class ServingEngine:
             self.metrics.retraces.inc(delta)
             self._trace_snapshot = dict(now)
         return delta
+
+
+
+def _slab_dtype(config):
+    """The slot engine's K/V slabs' dtype; a model that keeps anything
+    else than K and V a token is refused by name (down here: the file is
+    line-neutral above)."""
+    if getattr(config, "state_rows", None):
+        raise ValueError(
+            "a model with per-slot state ("
+            + ", ".join(name for name, _ in config.state_rows)
+            + ") is served by PagedServingEngine alone: the slot engine "
+            "(models/generate.py's slabs) holds K and V a token and "
+            "nothing a slot"
+        )
+    return config.compute_dtype

@@ -943,6 +943,7 @@ class PagedServingEngine(ServingEngine):
         spec_drafter: str = "ngram",
         spec_draft_layers: int = 2,
         window_blocks: Optional[int] = None,
+        state_snapshots: Optional[int] = None,
     ):
         if kv_cache_dtype not in ("fp", "int8"):
             raise ValueError(
@@ -1032,8 +1033,10 @@ class PagedServingEngine(ServingEngine):
             )
         self.num_blocks = num_blocks
         self._allocator = BlockAllocator(num_blocks, reserved=1)
+        if _is_linear(config):
+            _linear_module().check_shapes(config, block_size, prefill_chunk)
         self.state_snapshots = (
-            pool_layout.default_snapshots(num_blocks, slots)
+            self._snapshot_budget(state_snapshots, slots)
             if self._state_layout and prefix_cache else 0
         )
         # Every group's table, stacked as the grouped programs take
@@ -1064,6 +1067,7 @@ class PagedServingEngine(ServingEngine):
         self._state_restores = 0
         self._state_restores_from_snapshot = 0
         self._state_snapshots_taken = 0
+        self._state_snapshots_denied = 0
         self._prefix_rounded_down_blocks = 0
         self._chunk_rows_launched = self._chunk_rows_scored = 0
         self._slot_snapshot = [0] * slots
@@ -1094,13 +1098,19 @@ class PagedServingEngine(ServingEngine):
             _grouped_steps(
                 config, slots, self.max_blocks, block_size, prefill_chunk,
                 tuple(g.num_blocks for g in self._reach_groups),
-            ) if self._reach_groups else _paged_steps(
+            ) if self._reach_groups else _linear_steps(
+                config, slots, self.max_blocks, block_size, prefill_chunk,
+            ) if _is_linear(config) else _paged_steps(
                 config, slots, self.num_blocks, self.max_blocks,
                 block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
             )
         )
         rows_by = (self.latent_decode_attention or self.conv_decode_attention
                    or self.window_decode_attention)
+        if self.linear_kinds:
+            rows_by = ", ".join(
+                f"{name} {kind}" for name, kind in self.linear_kinds.items()
+            )
         if self.conv_chunk_attention or self.window_chunk_attention:
             rows_by += ", the chunk's by " + (
                 self.conv_chunk_attention or self.window_chunk_attention
@@ -1313,8 +1323,8 @@ class PagedServingEngine(ServingEngine):
         # own dtype.
         rows = [
             jnp.zeros(
-                (pool_layout.pool_layers(self.config), self.block_size)
-                + a.row_shape,
+                (pool_layout.pool_layers(self.config),
+                 a.block_rows(self.block_size)) + a.row_shape,
                 a.import_dtype,
             )
             for a in self._layout
@@ -1356,7 +1366,11 @@ class PagedServingEngine(ServingEngine):
             )
             jax.block_until_ready(acc)
             marks.mark("verify")
+        # The warmed arrays go BEFORE the fresh ones are made: both at
+        # once were the process's peak (16.4 GB of the chip's 16.9 with
+        # 4.3 GB of pools and state: my chip runs, PR 55).
         del pools
+        self._arrays = {}
         self._alloc_pool()
         self._trace_snapshot = self._all_trace_counts()
         self._end_warmup(marks)
@@ -1572,6 +1586,10 @@ class PagedServingEngine(ServingEngine):
         self.metrics.kv_bytes_in_use.set(
             (stats["used"] + stats["cached"]) * self._block_bytes
         )
+        if self._state_layout:
+            entry = sum(a.entry_bytes() for a in self._state_layout)
+            live = self._cache.snapshots_live if self._cache else 0
+            self.metrics.state_bytes.set((self.slots + live) * entry)
         if self._released_this_step:
             self.metrics.kv_window_blocks_released.inc(
                 self._released_this_step
@@ -1766,6 +1784,10 @@ class PagedServingEngine(ServingEngine):
                 self._state_restores_from_snapshot
             )
             stats["state_snapshots"] = self._state_snapshots_taken
+            stats["state_snapshots_denied"] = self._state_snapshots_denied
+            stats["state_snapshots_given_up"] = (
+                self._cache.snapshots_given_up_total if self._cache else 0
+            )
             stats["prefix_rounded_down_blocks"] = (
                 self._prefix_rounded_down_blocks
             )
@@ -1795,6 +1817,15 @@ class PagedServingEngine(ServingEngine):
                 self._prefix_rounded_down_blocks
             )
             stats["moe_rows_dropped"] = self._moe_rows_dropped
+        if self.linear_kinds:
+            # The array at a stride: its share of the bytes above and
+            # the whole array's size; and what each of the five runs.
+            per_block = self._array_block_bytes["ckeys"]
+            stats["ckey_bytes_in_use"] = (
+                (stats["used"] + stats["cached"]) * per_block
+            )
+            stats["ckey_bytes"] = self.num_blocks * per_block
+            stats.update(self.linear_kinds)
         if self.conv_decode_attention:
             stats["conv_decode_attention"] = self.conv_decode_attention
             stats["conv_chunk_attention"] = self.conv_chunk_attention
@@ -1877,7 +1908,7 @@ class PagedServingEngine(ServingEngine):
             g.check(self._launch_position)
         for a, pool in zip(self._layout, self._pools()):
             want = (self._groups[a.group].layers, blocks[a.group],
-                    self.block_size)
+                    a.block_rows(self.block_size))
             if pool.shape[:3] != want:
                 raise AssertionError(
                     f"pool array {a.name} of {pool.shape[:3]} in a pool "
@@ -2047,6 +2078,10 @@ class PagedServingEngine(ServingEngine):
             self._step_trace.counts["window_rows"] = self._window_rows(
                 r.slot for r in decoding
             )
+        if self.linear_kinds and self._step_trace is not None:
+            self._step_trace.counts.update(_linear_module().decode_counts(
+                self.config, [int(self._lengths[r.slot]) for r in decoding]
+            ))
 
     # ---- per-slot state (kvpool/layout.py) ---------------------------------
 
@@ -2080,6 +2115,8 @@ class PagedServingEngine(ServingEngine):
                 ("state_restores", 1),
                 ("state_restores_from_snapshot", int(bool(snapshot))),
                 ("state_restore_s", time.monotonic() - t0),
+                ("prefix_rounded_down_blocks",
+                 req.prefix_rounded_down_blocks or 0),
             ):
                 counts[name] = counts.get(name, 0) + more
 
@@ -2111,6 +2148,35 @@ class PagedServingEngine(ServingEngine):
             *state, *(jnp.asarray(r) for r in rows), np.int32(slot)
         ) + snaps)
 
+    @property
+    def linear_kinds(self) -> Dict[str, str]:
+        """What each of a lightning / block-sparse model's five parts
+        runs (``linear.kinds``), by name; ``{}`` for any other model."""
+        return dict(getattr(self._steps, "linear_kinds", ()))
+
+    def _snapshot_budget(self, stated: Optional[int], slots: int) -> int:
+        """Snapshot ids this engine keeps (``kvpool/layout.py``): the
+        count its caller states; else one a cached block while a
+        snapshot is no larger than a block; else what a share of the
+        per-token pool's bytes pays for."""
+        if stated is not None:
+            if stated < 1:
+                raise ValueError(
+                    f"state_snapshots {stated}: a prefix cache over a "
+                    "model with per-slot state needs one at least"
+                )
+            return int(stated)
+        entry = sum(a.entry_bytes() for a in self._state_layout)
+        block = sum(
+            a.block_bytes(self._groups[a.group].layers, self.block_size)
+            for a in self._layout if not a.group
+        )
+        if entry <= block:
+            return pool_layout.default_snapshots(self.num_blocks, slots)
+        return pool_layout.budgeted_snapshots(
+            entry, self.num_blocks * block, slots
+        )
+
     def _chunk_state_args(self, req: Request, start: int, n_valid: int):
         """What a chunk launch of a model with per-slot state carries
         after the plain arguments: the slot, and where in the chunk to
@@ -2141,6 +2207,10 @@ class PagedServingEngine(ServingEngine):
                 self._state_snapshots_taken += 1
                 if self._step_trace is not None:
                     self._step_trace.counts["state_snapshots"] = 1
+            else:
+                # Every id is lent: this prompt runs without one.
+                self._state_snapshots_denied += 1
+                self.metrics.state_snapshots_denied.inc()
         return np.int32(req.slot), np.int32(snap_at), np.int32(snap_id)
 
 
@@ -2308,4 +2378,76 @@ def _grouped_steps_for(config, slots: int, max_blocks: int, block_size: int,
         None, None, counts,
         window_decode_attention=decode_kind,
         window_chunk_attention=chunk_kind,
+    )
+
+
+# ---- lightning / block-sparse layers (kvpool/linear.py) ---------------------
+#
+# Down here for :func:`_latent_decode_kind`'s reasons.
+
+
+def _is_linear(config) -> bool:
+    return getattr(config, "kind", "") == "linear_sparse_lm"
+
+
+def _linear_module():
+    # Imported here: the module builds on this one.
+    from dlrover_tpu.serving.kvpool import linear
+
+    return linear
+
+
+class _LinearSteps(NamedTuple):
+    """:class:`_PagedSteps` for a model of lightning and block-sparse
+    layers: the same fields (the engine reads both by name), and what
+    each of its five parts runs."""
+
+    prefill: object
+    decode: object
+    cow: object
+    imp: object
+    exp: object
+    trace_counts: Dict[str, int]
+    pool_attention: str = "linear_block_lists"
+    sparse_chunk_attention: str = ""
+    latent_decode_attention: str = ""
+    conv_decode_attention: str = ""
+    conv_chunk_attention: str = ""
+    linear_kinds: tuple = ()
+
+
+def _linear_steps(config, slots: int, max_blocks: int, block_size: int,
+                  chunk: int) -> _LinearSteps:
+    """The programs of ``kvpool/linear.py``, keyed like
+    :func:`_paged_steps` and by what their parts run."""
+    kinds = _linear_module().kinds(config, config.compute_dtype, block_size)
+    return _linear_steps_for(
+        config, slots, max_blocks, block_size, chunk,
+        tuple(sorted(kinds.items())),
+    )
+
+
+@functools.lru_cache(maxsize=8)
+def _linear_steps_for(config, slots: int, max_blocks: int, block_size: int,
+                      chunk: int, kinds) -> _LinearSteps:
+    linear = _linear_module()
+    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
+    n_pools = len(pool_layout.pool_arrays(config))
+    n_state = 2 * len(pool_layout.state_arrays(config))
+    pool_args = tuple(range(n_pools + n_state))
+    return _LinearSteps(
+        jax.jit(linear.build_prefill(
+            config, max_blocks, block_size, chunk, counts
+        ), donate_argnums=pool_args),
+        jax.jit(linear.build_decode(
+            config, slots, max_blocks, block_size, counts,
+            dict(kinds)["block_decode_attention"],
+            dict(kinds)["lightning_decode"],
+        ), donate_argnums=pool_args),
+        jax.jit(_build_cow_copy(counts, n_pools, n_state),
+                donate_argnums=pool_args),
+        jax.jit(_build_import_scatter(counts, n_pools, n_state),
+                donate_argnums=pool_args),
+        jax.jit(_build_export_gather(counts, n_pools, n_state)),
+        counts, linear_kinds=kinds,
     )

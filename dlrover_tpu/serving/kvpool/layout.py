@@ -24,12 +24,27 @@ a dense GQA model: ``k`` and ``v`` of ``[kv_heads, head_dim]``):
   one), over the ATTENTION layers alone (``config.cache_layers``: the
   pool's layer axis; every other config's is ``n_layers``).
 
+- lightning and block-sparse layers (``models/linear_sparse_lm.py``):
+  ``k_pages``, ``v_pages [head_dim]``, ONE KV head a pool layer
+  (``cache_layers`` = sparse layers x KV heads: a selected block of a
+  head is one whole page), and ``ckeys [head_dim]``, the compressed keys,
+  an array at a STRIDE (``config.cache_strides``: name -> tokens a row
+  stands for; ``PoolArray.stride``): ``[layers, num_blocks, block_size /
+  stride, *row_shape]``, ``block_size / stride`` rows a block. Place
+  ``p`` of a sequence's compressed keys (the mean of rows ``[stride (p -
+  1), stride (p + 1))``) is held in the block of its LAST row, block ``p
+  // r`` at offset ``p % r``: every array of a block is a function of
+  the tokens up to that block's end alone, so a shared block means the
+  same to every sequence that shares it.
+
 Everything that MOVES a block (copy-on-write, import, export, prefix
 sharing, preemption, release) moves every array of this tuple and never
-asks what they are; everything that SIZES a block sums over it. Only the
+asks what they are; everything that SIZES a block sums over it (an array
+at a stride counts ``block_size / stride`` rows: ``PoolArray
+.block_rows``). Only the
 programs that read and write rows (``kvpool/engine.py``'s dense ones,
-``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``) know the
-arrays by name.
+``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``,
+``kvpool/linear.py``) know the arrays by name.
 
 GROUPS (``config.cache_groups``: name -> (layers, ``"all"`` or a reach
 in rows); a config without it is ONE group that keeps all, and nothing
@@ -58,7 +73,16 @@ run of cached blocks can be continued only from a boundary that has one.
 The engine treats them as it treats the arrays above: every program
 takes and returns them after the pool's, admission restores or zeroes a
 slot's, a chunk writes a snapshot, migration carries a slot's raw, and
-``kv_stats()`` sizes them; only ``kvpool/conv.py`` knows what they mean.
+``kv_stats()`` sizes them; only ``kvpool/conv.py`` and
+``kvpool/linear.py`` know what they mean. A state array is made in the
+dtype its config states (a third item beside layers and shape:
+``models/linear_sparse_lm.py``'s running sum is float32) and in
+``compute_dtype`` where it states none. HOW MANY snapshots an engine
+keeps is a budget in BYTES wherever one snapshot outweighs one block
+(:func:`budgeted_snapshots`: 18.9 MB against 0.2 MB there), and one a
+cached block where it does not (:func:`default_snapshots`: 57 KB against
+0.26 MB for ``models/conv_lm.py``); when no id is free the prefix cache
+gives up its least recently used entry's SNAPSHOT before any block.
 """
 
 from typing import NamedTuple, Tuple
@@ -89,15 +113,26 @@ class PoolArray(NamedTuple):
     # model's K and V: the wire's int8 rows dequantized on the host).
     import_dtype: object
     group: int = 0                  # which of ``cache_groups`` holds it
+    stride: int = 1                 # tokens a row stands for
 
     @property
     def raw(self) -> bool:
         return self.name not in KV_WIRE
 
+    def block_rows(self, block_size: int) -> int:
+        """Rows a block of ``block_size`` tokens holds of this array."""
+        if block_size % self.stride:
+            raise ValueError(
+                f"block_size {block_size} is not whole strides of "
+                f"{self.stride} tokens ({self.name})"
+            )
+        return block_size // self.stride
+
     def block_bytes(self, n_layers: int, block_size: int) -> int:
         """Bytes of one block of this array, all layers."""
         return int(
-            n_layers * block_size * np.prod(self.row_shape, dtype=np.int64)
+            n_layers * self.block_rows(block_size)
+            * np.prod(self.row_shape, dtype=np.int64)
             * jnp.dtype(self.dtype).itemsize
         )
 
@@ -120,10 +155,12 @@ def pool_arrays(config, kv_cache_dtype: str = "fp") -> Tuple[PoolArray, ...]:
     returns them."""
     cdt = config.compute_dtype
     rows = cache_rows(config)
+    strides = getattr(config, "cache_strides", None) or {}
     if kv_cache_dtype != "int8":
         return tuple(
             PoolArray(name, tuple(shape), cdt,
-                      cdt if name not in KV_WIRE else jnp.float32)
+                      cdt if name not in KV_WIRE else jnp.float32,
+                      stride=int(strides.get(name, 1)))
             for name, shape in rows
         )
     if [name for name, _ in rows] != ["k", "v"]:
@@ -152,7 +189,8 @@ def fresh(array: PoolArray, n_layers: int, num_blocks: int,
             array.dtype,
         )
     return jnp.zeros(
-        (n_layers, num_blocks, block_size) + array.row_shape, array.dtype
+        (n_layers, num_blocks, array.block_rows(block_size))
+        + array.row_shape, array.dtype,
     )
 
 
@@ -190,12 +228,30 @@ def default_snapshots(num_blocks: int, slots: int) -> int:
     return num_blocks - 1 + slots
 
 
+# The share of the per-token pool's bytes an engine spends on snapshots
+# when one outweighs a block and nobody states a count.
+SNAPSHOT_POOL_SHARE = 0.5
+
+
+def budgeted_snapshots(entry_bytes: int, pool_bytes: int, slots: int) -> int:
+    """Snapshot ids an engine keeps when ONE snapshot outweighs a block:
+    as many as :data:`SNAPSHOT_POOL_SHARE` of the pool's bytes pay for,
+    and never fewer than one a slot (a prompt's own, lent while it
+    prefills) and one more."""
+    return max(
+        slots + 1, int(SNAPSHOT_POOL_SHARE * pool_bytes) // max(entry_bytes, 1)
+    )
+
+
 def state_arrays(config) -> Tuple[StateArray, ...]:
     """The per-slot arrays ``config`` states (none: every model whose
-    whole state is rows in pages)."""
+    whole state is rows in pages), each in the dtype stated beside its
+    layers and shape, ``compute_dtype`` where none is."""
     return tuple(
-        StateArray(name, int(layers), tuple(shape), config.compute_dtype)
-        for name, (layers, shape) in getattr(config, "state_rows", ())
+        StateArray(name, int(spec[0]), tuple(spec[1]),
+                   jnp.dtype(spec[2]) if len(spec) > 2
+                   else config.compute_dtype)
+        for name, spec in getattr(config, "state_rows", ())
     )
 
 

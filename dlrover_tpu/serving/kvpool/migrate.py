@@ -308,11 +308,12 @@ def import_request(engine, payload: bytes,
     else:
         L, n, bs = raw_rows[wants[0].name].shape[:3]
     for a in wants:
-        want = (pool_layers, n, bs) + a.row_shape
+        # (an array at a stride holds fewer rows a block)
+        want = (pool_layers, n, a.block_rows(bs)) + a.row_shape
         if raw_rows[a.name].shape != want:
             raise MigrationError(
                 f"{a.name} {raw_rows[a.name].shape} vs blocks "
-                f"{(pool_layers, n, bs)} x {a.row_shape}"
+                f"{(pool_layers, n, a.block_rows(bs))} x {a.row_shape}"
             )
     if bs != engine.block_size or bs != header["block_size"]:
         raise MigrationError(

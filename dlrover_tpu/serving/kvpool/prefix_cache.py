@@ -36,7 +36,11 @@ request, ``insert`` attaches it to the entry of the boundary it was
 taken at (or takes it back: the entry had one, or was never made),
 evicting an entry frees its id with its block. ``lookup_with_state``
 returns the chain only as far as the DEEPEST entry holding a snapshot,
-with that id and how many matched blocks lay beyond it.
+with that id and how many matched blocks lay beyond it. The ids are a
+BUDGET the engine sizes (one a block where a snapshot is small, so many
+bytes where one outweighs a block: ``kvpool/layout.py``): when none is
+free ``take_snapshot`` takes the least recently used entry's, and that
+entry stays.
 
 **Tails** (``tail_allocators``: a pool in groups, ``kvpool/layout.py``).
 The entries name blocks of the FIRST group, which keeps every row. A
@@ -111,6 +115,7 @@ class PrefixCache:
         self.snapshots = snapshots
         self._free_snapshots = list(range(snapshots, 0, -1))
         self.snapshots_live = 0       # entries that hold one
+        self.snapshots_given_up_total = 0   # taken from an entry that stayed
         # The reach groups' allocators, in the groups' order.
         self._tail_allocs = tuple(tail_allocators)
         self.tails_live = 0           # entries that own tails
@@ -237,9 +242,23 @@ class PrefixCache:
         return freed
 
     def take_snapshot(self) -> int:
-        """Lend a free snapshot id to a request that is about to write
-        the state at a boundary (0: none is free, write the sentinel).
-        It comes back through :meth:`insert` or :meth:`give_snapshot`."""
+        """Lend a snapshot id to a request that is about to write the
+        state at a boundary. With none free, the least recently used
+        entry that holds one gives up its SNAPSHOT (the entry and its
+        block stay: the chain is still a prefix, continued from then on
+        from a shallower boundary that has one, and the blocks beyond it
+        are what ``lookup_with_state`` reports as rounded down): a
+        snapshot is given up before any block. 0: every id is lent to a
+        prompt that is still prefilling; the caller writes the sentinel
+        and runs without. It comes back through :meth:`insert` or
+        :meth:`give_snapshot`."""
+        if not self._free_snapshots:
+            for entry in self._entries.values():
+                if entry.snapshot:
+                    self._free_entry_snapshot(entry)
+                    entry.snapshot = 0
+                    self.snapshots_given_up_total += 1
+                    break
         return self._free_snapshots.pop() if self._free_snapshots else 0
 
     def give_snapshot(self, snapshot: int) -> None:

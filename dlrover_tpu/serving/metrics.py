@@ -121,6 +121,16 @@ class ServingMetrics:
             "blocks of a group that keeps only the rows a query can "
             "still see, released by live slots as they slid out of reach",
         )
+        self.state_bytes = reg.gauge(
+            "serving_state_bytes",
+            "bytes of per-slot state and of its live snapshots (a model "
+            "whose layers keep a state whatever the length)",
+        )
+        self.state_snapshots_denied = reg.counter(
+            "serving_state_snapshots_denied_total",
+            "prompts that ran without a snapshot of their own: every "
+            "snapshot id was lent to a prompt still prefilling",
+        )
         self.kv_bytes_in_use = reg.gauge(
             "serving_kv_bytes_in_use",
             "bytes of KV pool HBM referenced by live slots or the "

@@ -1368,3 +1368,69 @@ def test_window_serving_programs_compile_at_the_cells_shape(topo):
     assert window.decode_attention_kind(
         cfg, jnp.float32, 64, 264, 32, 512
     ) == "gathered_view"
+
+
+def test_linear_sparse_serving_programs_compile_at_the_cells_shape(topo):
+    """``sala-serve-docs-64k``'s decode step and prefill chunk (one
+    period of its 12 layers: a block-sparse layer and three lightning
+    layers) at published widths, 48 slots x 66,560 rows over the cell's
+    10,560-block pool, lower for the described v5e under their trace
+    names from what the engine's constructor builds (``kvpool.engine.
+    _linear_steps``): all five arrays (K and V pages, the compressed keys
+    at their stride, the slots' float32 state, its snapshots) alias their
+    outputs and none is copied or re-laid; the decode step reads its
+    listed pages by the list kernel (``ops/block_sparse_attention.py``),
+    once a sparse layer, booked to ``attn/sparse``; and every scope the
+    cell's readers book device time to is there."""
+    from benchmark import common, rehearse_sala, sala_scopes, trace_reduce
+    from dlrover_tpu.serving.kvpool import linear
+
+    cfg_json = common.load_json("configs", "minicpm-sala-9b.json")
+    types = cfg_json["mixer_types"][:4]
+    assert types == ["minicpm4"] + ["lightning-attn"] * 3
+    programs, logical = rehearse_sala.lower_engine_programs(
+        cfg_json, topo.devices[0], probes=False, reference=False,
+        mixer_types=types,
+    )
+    assert logical == {
+        "k_pages": 2 * 10560 * 64 * 128 * 2,
+        "v_pages": 2 * 10560 * 64 * 128 * 2,
+        "ckeys": 2 * 10560 * 4 * 128 * 2,
+        "lightning": 3 * 48 * 32 * 128 * 128 * 4,
+        "lightning_snapshots": 3 * 65 * 32 * 128 * 128 * 4,
+    }
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        for i in range(5):
+            assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
+        scopes = trace_reduce.scopes_from_hlo(text)
+        calls = [
+            v for k, v in scopes.items()
+            if k.startswith("paged_block_list_decode_attention")
+        ]
+        assert len(calls) == (1 if name == "jit_step" else 0), calls
+        assert all(sala_scopes.scope_of(v) == "sparse" for v in calls)
+        copies = "".join(
+            line for line in text.splitlines() if " copy(" in line
+        )
+        assert "bf16[2,10560," not in copies
+        assert "f32[3,48,32,128,128]" not in copies
+        assert "f32[3,65,32,128,128]" not in copies
+        booked = {sala_scopes.scope_of(v) for v in scopes.values()}
+        assert booked >= {"lightning", "select", "sparse", "state"}
+        if name == "jit_prefill":
+            assert "snapshot" in booked
+        m = c.memory_analysis()
+        assert m.alias_size_in_bytes == sum(logical.values())
+        assert m.temp_size_in_bytes < 2.2e9
+    from benchmark.runners import serve_linear
+
+    cfg = serve_linear.linear_config(cfg_json)
+    assert linear.decode_attention_kind(cfg, cfg.compute_dtype, 64) == \
+        "pool_kernel"
+    assert linear.decode_attention_kind(cfg, jnp.float32, 64) == \
+        "gathered_pages"
+    assert linear.decode_attention_kind(cfg, cfg.compute_dtype, 8) == \
+        "gathered_pages"

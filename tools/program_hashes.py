@@ -6,8 +6,9 @@ accepted serve cell (``nemo12b-serve-chat``, built as
 ``benchmark/rehearse.py`` builds them, ``keye-serve-docqa-32k``, as
 ``rehearse_keye.py``, ``xing-serve-sessions-16k``, as
 ``rehearse_xing.py``, ``lfm2-serve-sessions-8k``, as
-``rehearse_lfm2.py``, and ``mellum2-serve-mixed-16k``, as
-``rehearse_mellum2.py``, where the checkout's manifest has it). Run it on
+``rehearse_lfm2.py``, ``mellum2-serve-mixed-16k``, as
+``rehearse_mellum2.py``, and ``sala-serve-docs-64k``, as
+``rehearse_sala.py``, each where the checkout's manifest has it). Run it on
 two checkouts and compare: the same hash is the same program, so the cell
 cannot move.
 
@@ -184,6 +185,14 @@ def main(argv):
         serve_cells["mellum2-serve-mixed-16k"] = lambda ctx: \
             rehearse_mellum2.lower_engine_programs(
                 ctx["config"], device, probes=False
+            )[0]
+    if any(w["name"] == "sala-serve-docs-64k"
+           for w in manifest["workloads"]):
+        from benchmark import rehearse_sala
+
+        serve_cells["sala-serve-docs-64k"] = lambda ctx: \
+            rehearse_sala.lower_engine_programs(
+                ctx["config"], device, probes=False, reference=False
             )[0]
     for cell, lower in serve_cells.items():
         programs = lower(context(cell))

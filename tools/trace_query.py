@@ -162,7 +162,11 @@ def step_summary(spans: List[Dict]) -> Dict:
     layer groups adds ``window_rows_mean`` (the rows a decode launch
     reads of a group that keeps only what a query can see) and
     ``window_blocks_released`` (blocks of it the steps' slots gave
-    back)."""
+    back). A model of lightning and block-sparse layers (PR 55) adds
+    ``ckey_rows_mean`` (compressed keys a decode launch scores) and
+    ``state_slots_mean`` (slots whose state it updates), and any model
+    with per-slot state the sums ``state_snapshots``,
+    ``state_restores_from_snapshot`` and ``prefix_rounded_down_blocks``."""
     steps = [
         s for s in spans
         if s.get("name") == "serving.step" and s.get("dur_s") is not None
@@ -257,7 +261,9 @@ def _sparse_counts(attrs: List[Dict]) -> Dict:
     out = {}
     for name, count in (("selected_rows_mean", "selected_rows"),
                         ("experts_hit_mean", "experts_hit"),
-                        ("window_rows_mean", "window_rows")):
+                        ("window_rows_mean", "window_rows"),
+                        ("ckey_rows_mean", "ckey_rows"),
+                        ("state_slots_mean", "state_slots")):
         values = [a[count] for a in attrs if count in a]
         if values:
             out[name] = sum(values) / len(values)
@@ -267,6 +273,13 @@ def _sparse_counts(attrs: List[Dict]) -> Dict:
         out["window_blocks_released"] = sum(
             a.get("window_blocks_released", 0) for a in attrs
         )
+    for name in ("state_snapshots", "state_restores_from_snapshot",
+                 "prefix_rounded_down_blocks"):
+        # a model with per-slot state (kvpool/layout.py): snapshots the
+        # steps' chunks wrote, admissions a snapshot restored, hit blocks
+        # given up for want of one
+        if any(name in a for a in attrs):
+            out[name] = sum(a.get(name, 0) for a in attrs)
     if any("prefix_hit_tokens" in a for a in attrs):
         out["prefix_hit_tokens"] = sum(
             a.get("prefix_hit_tokens", 0) for a in attrs
