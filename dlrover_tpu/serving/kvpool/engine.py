@@ -1826,6 +1826,12 @@ class PagedServingEngine(ServingEngine):
             )
             stats["ckey_bytes"] = self.num_blocks * per_block
             stats.update(self.linear_kinds)
+            # What the decode step's selection would copy for the slots
+            # now active, from the host's tables (never on the step path).
+            at = [r.slot for r in self.scheduler.active()]
+            stats.update(_linear_module().ckey_copy_stats(
+                self.config, self._tables[at], self._lengths[at]
+            ))
         if self.conv_decode_attention:
             stats["conv_decode_attention"] = self.conv_decode_attention
             stats["conv_chunk_attention"] = self.conv_chunk_attention
@@ -2420,7 +2426,9 @@ def _linear_steps(config, slots: int, max_blocks: int, block_size: int,
                   chunk: int) -> _LinearSteps:
     """The programs of ``kvpool/linear.py``, keyed like
     :func:`_paged_steps` and by what their parts run."""
-    kinds = _linear_module().kinds(config, config.compute_dtype, block_size)
+    kinds = _linear_module().kinds(
+        config, config.compute_dtype, block_size, slots, max_blocks
+    )
     return _linear_steps_for(
         config, slots, max_blocks, block_size, chunk,
         tuple(sorted(kinds.items())),
@@ -2443,6 +2451,7 @@ def _linear_steps_for(config, slots: int, max_blocks: int, block_size: int,
             config, slots, max_blocks, block_size, counts,
             dict(kinds)["block_decode_attention"],
             dict(kinds)["lightning_decode"],
+            dict(kinds)["block_select"],
         ), donate_argnums=pool_args),
         jax.jit(_build_cow_copy(counts, n_pools, n_state),
                 donate_argnums=pool_args),

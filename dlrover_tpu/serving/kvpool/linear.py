@@ -32,9 +32,11 @@ its slot's state, leaves the state after its last VALID row and writes
 the state as of row ``snap_at`` of the chunk into snapshot ``snap_id``
 (sentinel 0: none) — both are :func:`lightning_state_after` of the same
 operands. A sparse layer scores the slot's compressed keys through its
-table (scope ``attn/select``), makes the LIST of blocks of each (slot,
-KV head) and maps it to pages through the table: the decode step then
-attends over the listed pages (``attn/sparse``; in place where
+table (scope ``attn/select``; the decode step in place, a run of
+consecutive blocks a copy, where :func:`select_kind` answers
+``pool_kernel``, ``ops/block_select.py``), makes the LIST of blocks of
+each (slot, KV head) and maps it to pages through the table: the decode
+step then attends over the listed pages (``attn/sparse``; in place where
 :func:`decode_attention_kind` answers ``pool_kernel``,
 ``ops/block_sparse_attention.py``), a chunk over the slot's rows under
 the block mask in spans of :data:`CHUNK_SPAN_BLOCKS` blocks, a tile of
@@ -51,9 +53,11 @@ option for any of them.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import linear_sparse_lm as lsm
+from dlrover_tpu.ops import block_select
 from dlrover_tpu.ops import block_sparse_attention as block_ops
 from dlrover_tpu.serving.engine import _place_first
 from dlrover_tpu.serving.kvpool import engine as paged
@@ -119,11 +123,24 @@ def lightning_decode_kind(config, state_dtype=jnp.float32) -> str:
     return "jnp"
 
 
-def select_kind(config) -> str:
-    """What scores the compressed keys and lists the blocks: ``"jnp"``
-    (``linear_sparse_lm.block_scores`` over the slot's places gathered
-    through its table, ``lax.top_k`` / the threshold mask of
-    ``ops/sparse_attention.py``)."""
+def select_kind(config, pool_dtype=None, slots: int = 0,
+                max_blocks: int = 0) -> str:
+    """What scores the DECODE step's compressed keys: ``"pool_kernel"``
+    (``ops.block_select.pool_block_scores``: the pool in place, read
+    through the table in runs of blocks, scores and softmax in VMEM)
+    where that kernel lowers — a TPU, a bf16 ``ckeys`` of 128-wide rows
+    whose one list fits the kernel's VMEM and whose tables fit its
+    scalar memory — and ``"jnp"`` (``linear_sparse_lm.block_scores``
+    over the slot's places gathered through its table), the definition,
+    everywhere else. The blocks are listed by ``lax.top_k`` either way,
+    and a prefill chunk's selection (the threshold mask of
+    ``ops/sparse_attention.py`` over the gathered view) is ``jnp``
+    everywhere."""
+    if paged._on_tpu() and max_blocks and block_select.select_kernel_supported(
+        pool_dtype, config.ckeys_per_block, config.head_dim, config.group,
+        slots, max_blocks,
+    ):
+        return "pool_kernel"
     return "jnp"
 
 
@@ -150,12 +167,14 @@ def chunk_attention_kind(config) -> str:
     return "masked_blocks"
 
 
-def kinds(config, pool_dtype, block_size: int):
-    """The five, by name, for ``kv_stats()``."""
+def kinds(config, pool_dtype, block_size: int, slots: int = 0,
+          max_blocks: int = 0):
+    """The five, by name, for ``kv_stats()`` (``slots``, ``max_blocks``:
+    the decode step's tables; 0: no step is asked about)."""
     return {
         "lightning_chunk": lightning_chunk_kind(config),
         "lightning_decode": lightning_decode_kind(config),
-        "block_select": select_kind(config),
+        "block_select": select_kind(config, pool_dtype, slots, max_blocks),
         "block_decode_attention": decode_attention_kind(
             config, pool_dtype, block_size
         ),
@@ -178,22 +197,78 @@ def new_ckey_place(config, lengths):
     return (lengths + 1) // stride - 1, done
 
 
+def decode_block_scores(config, q, fresh, ck, layer: int, tables, lengths,
+                        active, select: str,
+                        group_blocks: int = block_select.GROUP_BLOCKS):
+    """The decode step's block scores ``[slots, kv_heads, max_blocks]``
+    float32 for sparse layer ``layer``: ``q [slots, heads, d]`` at rows
+    ``lengths`` over each slot's compressed keys through its table, the
+    step's own new keys ``fresh [slots, kv_heads, d]`` laid over the
+    place :func:`new_ckey_place` says (they land in the pool after the
+    layer loop). ``select``: :func:`select_kind`'s answer. Both forms
+    are ``linear_sparse_lm.block_scores``; the kernel's inactive slots
+    read nothing and score 0. ``group_blocks``: the kernel's copy group
+    (``tools/bench_sparse_attention.py`` sweeps it; nothing else passes
+    it)."""
+    c = config
+    slots, max_blocks = tables.shape
+    kh = c.n_kv_heads
+    place, done = new_ckey_place(c, lengths)
+    if select == "pool_kernel":
+        qg = q.reshape(slots, kh, c.group, c.head_dim)
+        own = jnp.einsum(
+            "skgd,skd->skg", qg, fresh, preferred_element_type=jnp.float32
+        ) * c.head_dim ** -0.5
+        return block_select.pool_block_scores(
+            qg, own, ck, c.pool_layer(layer, 0), tables,
+            jnp.where(active, (lengths + 1) // c.kernel_stride, 0),
+            jnp.where(done, place, -1), group_blocks=group_blocks,
+        )
+    here = (
+        jnp.arange(max_blocks * c.ckeys_per_block)[None, :] == place[:, None]
+    ) & done[:, None]
+    views = jnp.stack([
+        jnp.where(
+            here[..., None], fresh[:, j, None, :],
+            _places(ck, c.pool_layer(layer, j), tables),
+        ) for j in range(kh)
+    ], axis=2)                                    # [slots, P, kh, d]
+    return jax.vmap(
+        lambda q1, ck1, t: lsm.block_scores(c, q1[None], ck1, t[None])[:, 0]
+    )(q, views, lengths)
+
+
+def decode_block_lists(config, scores, lengths):
+    """``select_block_list`` a slot: ``scores [slots, kv_heads, blocks]``
+    of queries at rows ``lengths`` -> (``blocks [slots, kv_heads,
+    width]``, ``count [slots, kv_heads]``)."""
+    return jax.vmap(
+        lambda s, t: tuple(
+            x[:, 0] for x in lsm.select_block_list(
+                config, s[:, None], t[None]
+            )
+        )
+    )(scores, lengths)
+
+
 def decode_attend(config, kp, vp, ck, layer: int, tables, lengths,
                   block_size: int, kind=None, active=None, taps=None,
-                  left=None):
+                  left=None, select=None):
     """The decode step's ``attend`` for sparse layer ``layer``: one query
     a slot. ``left``: a dict the new compressed keys land in (``[slots,
     kv_heads, d]``, for the caller to write where
     :func:`new_ckey_place` says). ``taps``: the probes' (``scores``,
-    ``blocks``, ``count``)."""
+    ``blocks``, ``count``). ``kind``, ``select``:
+    :func:`decode_attention_kind`'s and :func:`select_kind`'s answers
+    (None: asked here)."""
     c = config
     slots, max_blocks = tables.shape
     n_pool = kp.shape[1]
     stride, kh = c.kernel_stride, c.n_kv_heads
     kind = kind or decode_attention_kind(c, kp.dtype, block_size)
+    select = select or select_kind(c, ck.dtype, slots, max_blocks)
     if active is None:
         active = jnp.ones((slots,), bool)
-    place, done = new_ckey_place(c, lengths)
     # The rows the completed place averages: the step's own and the 2 *
     # stride - 1 before it, through the table.
     rows = jnp.maximum(
@@ -206,37 +281,23 @@ def decode_attend(config, kp, vp, ck, layer: int, tables, lengths,
     def attend(q, k_new, v_new):
         q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
         with jax.named_scope("select"):
-            views, fresh = [], []
+            fresh = []
             for j in range(kh):
-                at = c.pool_layer(layer, j)
-                before = kp[at, row_blk, row_off].astype(jnp.float32)
+                before = kp[
+                    c.pool_layer(layer, j), row_blk, row_off
+                ].astype(jnp.float32)
                 mean = (
                     jnp.sum(before[:, :-1], axis=1)
                     + k_new[:, j].astype(jnp.float32)
                 ) / (2 * stride)
                 fresh.append(mean.astype(ck.dtype))
-                view = _places(ck, at, tables)        # [slots, P, d]
-                here = (
-                    jnp.arange(view.shape[1])[None, :] == place[:, None]
-                ) & done[:, None]
-                views.append(
-                    jnp.where(here[..., None], fresh[-1][:, None, :], view)
-                )
             fresh = jnp.stack(fresh, axis=1)          # [slots, kh, d]
             if left is not None:
                 left["ckeys"] = fresh
-            scores = jax.vmap(
-                lambda q1, ck1, t: lsm.block_scores(
-                    c, q1[None], ck1, t[None]
-                )[:, 0]
-            )(q, jnp.stack(views, axis=2), lengths)   # [slots, kh, blocks]
-            blocks, count = jax.vmap(
-                lambda s, t: tuple(
-                    x[:, 0] for x in lsm.select_block_list(
-                        c, s[:, None], t[None]
-                    )
-                )
-            )(scores, lengths)            # [slots, kh, width], [slots, kh]
+            scores = decode_block_scores(
+                c, q, fresh, ck, layer, tables, lengths, active, select
+            )                                     # [slots, kh, blocks]
+            blocks, count = decode_block_lists(c, scores, lengths)
             if taps is not None:
                 taps.update(scores=scores, blocks=blocks, count=count)
             # The list as pages of this layer's KV heads, all pool
@@ -412,7 +473,7 @@ def chunk_attend(config, kp, vp, ck, layer: int, table_row, start,
 
 def decode_forward(config, kp, vp, ck, state, params, tables, lengths,
                    tokens, block_size: int, taps=None, *, kind=None,
-                   active=None, state_kind=None):
+                   active=None, state_kind=None, select=None):
     """All layers for one token a slot: float32 ``logits [slots,
     vocab]``, the sparse layers' new rows ``(k, v, ckeys) [pool layers,
     slots, d]`` and the state with every lightning layer's update of the
@@ -421,9 +482,10 @@ def decode_forward(config, kp, vp, ck, state, params, tables, lengths,
     place as soon as it has read it (landed after the loop the array is
     copied whole, there and back: the later layers read what the earlier
     ones have not yet written). ``taps``: a dict a layer's ``{layer:
-    taps}`` land in (the checks' probes). ``kind``, ``state_kind``:
-    :func:`decode_attention_kind`'s and :func:`lightning_decode_kind`'s
-    answers (None: asked here)."""
+    taps}`` land in (the checks' probes). ``kind``, ``state_kind``,
+    ``select``: :func:`decode_attention_kind`'s,
+    :func:`lightning_decode_kind`'s and :func:`select_kind`'s answers
+    (None: asked here)."""
     c = config
     positions = lengths[:, None]
     slots = tokens.shape[0]
@@ -466,7 +528,7 @@ def decode_forward(config, kp, vp, ck, state, params, tables, lengths,
                 c, params, layer, x, positions,
                 decode_attend(
                     c, kp, vp, ck, layer, tables, lengths, block_size,
-                    kind, active, taps=seen, left=left,
+                    kind, active, taps=seen, left=left, select=select,
                 ),
                 taps=seen,
             )
@@ -526,10 +588,11 @@ def chunk_forward(config, kp, vp, ck, state, params, tokens, table_row,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts, kind=None, state_kind=None):
-    """``kind``, ``state_kind``: :func:`decode_attention_kind`'s and
-    :func:`lightning_decode_kind`'s answers for this shape (None: asked
-    when the step is traced)."""
+                 counts, kind=None, state_kind=None, select=None):
+    """``kind``, ``state_kind``, ``select``:
+    :func:`decode_attention_kind`'s, :func:`lightning_decode_kind`'s and
+    :func:`select_kind`'s answers for this shape (None: asked when the
+    step is traced)."""
     max_len = max_blocks * block_size
     per = config.ckeys_per_block
 
@@ -540,6 +603,7 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         logits, (k_new, v_new, c_new), state = decode_forward(
             config, kp, vp, ck, state, params, tables, lengths, tokens,
             block_size, kind=kind, active=active, state_kind=state_kind,
+            select=select,
         )
         write = jnp.minimum(lengths, max_len - 1)
         blk = jnp.take_along_axis(
@@ -626,6 +690,25 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         return kp, vp, ck, state, snaps, first
 
     return prefill
+
+
+def ckey_copy_stats(config, tables, fills):
+    """How often the selection's run copies engage (``kv_stats()``; the
+    host's ``tables [slots, max_blocks]`` and rows ``fills [slots]`` of
+    the active slots): ``ckey_copy_groups``, the groups of
+    ``ops.block_select.GROUP_BLOCKS`` table entries a decode step's
+    selection reads a (sparse layer, KV head), and
+    ``ckey_copy_groups_run_share``, the share of them that are
+    consecutive ids and so one copy each (0.0 with no group)."""
+    c = config
+    places = (np.asarray(fills, np.int64).reshape(-1) + 1) // c.kernel_stride
+    groups, runs = block_select.copy_groups(
+        np.asarray(tables), -(-places // c.ckeys_per_block)
+    )
+    return {
+        "ckey_copy_groups": groups,
+        "ckey_copy_groups_run_share": runs / groups if groups else 0.0,
+    }
 
 
 def rows_listed(config, fill: int) -> int:

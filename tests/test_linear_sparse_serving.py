@@ -367,6 +367,50 @@ def test_no_program_retraces_across_admissions(tiny, warm):
     assert dict(warm.trace_counts) == before
 
 
+def test_the_selection_says_what_it_runs_and_how_often_its_runs_engage(
+    tiny
+):
+    """Off a TPU the selection is its definition, and ``kv_stats()``
+    carries the run copies' two counters: a document prefilled alone is
+    all runs; one that takes blocks freed before it, out of order, is
+    not."""
+    from dlrover_tpu.ops import block_select
+    from dlrover_tpu.serving.kvpool import linear
+
+    cfg, params = tiny
+    assert linear.select_kind(cfg, cfg.compute_dtype, 48, 1040) == "jnp"
+    g = block_select.GROUP_BLOCKS
+    document = prompts(cfg, [2 * g * BS - 6], seed=21)[0]
+
+    def stats_at_the_documents_end(eng):
+        """``kv_stats()`` with the document's request on its first
+        decode rows: two whole groups of blocks visible."""
+        r = eng.submit(document, 4)
+        while eng._lengths[r.slot] < len(document) or r.slot < 0:
+            eng.step()
+        assert len(eng._slot_blocks[r.slot]) == 2 * g
+        stats, table = eng.kv_stats(), eng._tables[r.slot, :2 * g].copy()
+        while eng.pending():
+            eng.step()
+        return stats, table
+
+    kw = dict(prefix_cache=False, max_len=2 * g * BS + CHUNK,
+              num_blocks=2 * g + 8)
+    alone, table = stats_at_the_documents_end(engine(cfg, params, **kw))
+    assert alone["block_select"] == "jnp"
+    assert (np.diff(table) == 1).all()
+    assert alone["ckey_copy_groups"] == 2
+    assert alone["ckey_copy_groups_run_share"] == 1.0
+    eng = engine(cfg, params, **kw)
+    serve(eng, [(prompts(cfg, [60], seed=22)[0], 2)])   # blocks 1 .. 8
+    reused, table = stats_at_the_documents_end(eng)
+    assert (np.diff(table[:g]) == 1).all()
+    assert not (np.diff(table[g:]) == 1).all()      # ... 39, then 1 ...
+    assert reused["ckey_copy_groups"] == 2
+    assert reused["ckey_copy_groups_run_share"] == 0.5
+    assert engine(cfg, params).kv_stats()["ckey_copy_groups"] == 0
+
+
 def test_the_step_span_carries_the_new_counts(tiny):
     from dlrover_tpu.serving.kvpool import linear
 

@@ -1412,6 +1412,14 @@ def test_linear_sparse_serving_programs_compile_at_the_cells_shape(topo):
         ]
         assert len(calls) == (1 if name == "jit_step" else 0), calls
         assert all(sala_scopes.scope_of(v) == "sparse" for v in calls)
+        # ... and scores its compressed keys in place (PR 56), once a
+        # sparse layer, booked to ``attn/select``; the chunk does not.
+        calls = [
+            v for k, v in scopes.items()
+            if k.startswith("paged_block_select_scores")
+        ]
+        assert len(calls) == (1 if name == "jit_step" else 0), calls
+        assert all(sala_scopes.scope_of(v) == "select" for v in calls)
         copies = "".join(
             line for line in text.splitlines() if " copy(" in line
         )
@@ -1434,3 +1442,9 @@ def test_linear_sparse_serving_programs_compile_at_the_cells_shape(topo):
         "gathered_pages"
     assert linear.decode_attention_kind(cfg, cfg.compute_dtype, 8) == \
         "gathered_pages"
+    assert linear.select_kind(cfg, cfg.compute_dtype, 48, 1040) == \
+        "pool_kernel"
+    assert linear.select_kind(cfg, jnp.float32, 48, 1040) == "jnp"
+    # every slot's table rides in scalar memory: 768 KB of it at most
+    assert linear.select_kind(cfg, cfg.compute_dtype, 192, 1040) == "jnp"
+    assert linear.kinds(cfg, cfg.compute_dtype, 64)["block_select"] == "jnp"

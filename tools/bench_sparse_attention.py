@@ -188,6 +188,93 @@ def chunk_kernel_part(cfg, eng, report, rng, iters, tiny):
                 )
 
 
+def block_select_part(report, rng, iters, tiny):
+    """The block-sparse mixer's decode SELECTION at
+    ``sala-serve-docs-64k``'s shape (48 slots x 1,040 blocks, 2 KV heads
+    x 16 heads, ``ckeys [6, 10,560, 4, 128]``), one sparse layer: the
+    ``jax.numpy`` scores over the table-gathered view beside the kernel
+    that reads the pool in place (``ops/block_select.py``) at 8, 16 and
+    32 table entries a copy group, over a CONSECUTIVE table (eight
+    documents of 1,024 blocks and 16 blocks of a slot's own, as the cell
+    holds them) and a SHUFFLED one (no run: a copy a block); the share
+    of copy groups that are runs, the two forms' worst difference and
+    whether they list the same blocks; and the list's ``lax.top_k``,
+    which both forms share."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import common
+    from benchmark.runners import serve_linear
+    from dlrover_tpu.models import linear_sparse_lm as lsm
+    from dlrover_tpu.ops import block_select
+    from dlrover_tpu.serving.kvpool import linear
+
+    if tiny:
+        cfg = lsm.tiny_config()
+        slots, mb, nb, doc = 3, 12, 64, 8
+    else:
+        cfg_json = common.load_json("configs", "minicpm-sala-9b.json")
+        cfg, eng = serve_linear.linear_config(cfg_json), cfg_json["serve_engine"]
+        slots, nb = eng["slots"], eng["num_blocks"]
+        mb, doc = eng["max_len"] // eng["block_size"], 1024
+    c, cdt = cfg, cfg.compute_dtype
+    per, bs, layer = c.ckeys_per_block, c.sparse_block, c.sparse_layers[-1]
+    f = lambda *s: jnp.asarray(rng.normal(size=s), cdt)  # noqa: E731
+    ck = f(c.cache_layers, nb, per, c.head_dim)
+    q, fresh = f(slots, c.n_heads, c.head_dim), f(slots, c.n_kv_heads, c.head_dim)
+    lengths = jnp.asarray(
+        mb * bs - 1 - rng.integers(0, 6 * bs, slots), jnp.int32
+    )
+    active = jnp.ones((slots,), bool)
+    n_docs = (nb - 1 - slots * (mb - doc)) // doc
+    tables = {"consecutive": np.stack([np.concatenate([
+        1 + doc * (s % n_docs) + np.arange(doc),
+        1 + doc * n_docs + (mb - doc) * s + np.arange(mb - doc),
+    ]) for s in range(slots)]).astype(np.int32), "shuffled": np.stack([
+        1 + rng.permutation(nb - 1)[:mb] for _ in range(slots)
+    ]).astype(np.int32)}
+    blocks = -(-((np.asarray(lengths) + 1) // c.kernel_stride) // per)
+    report("block_select.shape", {
+        "slots": slots, "max_blocks": mb, "ckeys": list(ck.shape),
+        "kind_here": linear.select_kind(c, ck.dtype, slots, mb),
+    })
+    listed = jax.jit(lambda s: linear.decode_block_lists(c, s, lengths))
+
+    def scores_fn(select, group_blocks=block_select.GROUP_BLOCKS):
+        """``fn(table)``; the pool and the queries go in as arguments (a
+        closed-over array is a constant of the program)."""
+        fn = jax.jit(lambda t, q, fresh, ck: linear.decode_block_scores(
+            c, q, fresh, ck, layer, t, lengths, active, select, group_blocks
+        ))
+        return lambda t: fn(t, q, fresh, ck)
+
+    for name, table in tables.items():
+        t = jnp.asarray(table)
+        want = scores_fn("jnp")(t)
+        if not tiny:
+            report(f"block_select.{name}.jnp", timed(
+                scores_fn("jnp"), t, iters=iters))
+        for group_blocks in (8, 16, 32):
+            groups, runs = block_select.copy_groups(
+                table, blocks, group_blocks)
+            fn = scores_fn("pool_kernel", group_blocks)
+            got = fn(t)
+            line = {
+                "copy_groups": groups, "run_share": runs / groups,
+                "max_abs_diff_vs_jnp": float(jnp.max(jnp.abs(got - want))),
+                "same_lists": all(
+                    bool(jnp.array_equal(a, b))
+                    for a, b in zip(listed(got), listed(want))
+                ),
+            }
+            if not tiny:
+                line.update(timed(fn, t, iters=iters))
+            report(f"block_select.{name}.kernel_g{group_blocks}", line)
+    if not tiny:
+        report("block_select.top_k_list", timed(listed, want, iters=iters))
+
+
 def index_pool_part(cfg, eng, report, rng, iters, tiny):
     """The index-key pool's reads and landings, packed beside bare (see
     the module's docstring). The pool is donated and threaded from call
@@ -398,6 +485,8 @@ def main(argv=None):
         chunk_kernel_part(cfg, eng, report, rng, it, args.tiny)
     if "index_pool" in args.parts:
         index_pool_part(cfg, eng, report, rng, it, args.tiny)
+    if "block_select" in args.parts:
+        block_select_part(report, rng, it, args.tiny)
     if "experts" in args.parts or "programs" in args.parts:
         params = jax.jit(
             lambda key: sparse_lm.init_params(cfg, key, dtype=cdt)

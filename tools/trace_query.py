@@ -50,11 +50,20 @@ tracing`` (``DLROVER_TPU_TRACE_FILE``, the fleet soak's
     # one trace's tree + critical path (a serving.step: its phases)
     python tools/trace_query.py --trace 7f3a... spans_*.jsonl
 
+    # a traced benchmark run's DEVICE ops under one named scope of one
+    # program, by op (the file is the run's trace_dump.json, not a span
+    # sink): ms and ops a launch of every (op kind, what it ran under
+    # below the scope), largest first
+    python tools/trace_query.py --device-ops select --program jit_step \
+        chiprun_out/benchmark/sala-serve-docs-64k/trace_dump.json
+
 Plain stdlib + the tracing module's own loaders — usable on any box
 that has the repo, no collector service required.
 """
 
 import argparse
+import bisect
+import collections
 import json
 import os
 import sys
@@ -366,6 +375,41 @@ def render_tree(node: Dict, indent: int = 0) -> List[str]:
     return lines
 
 
+def device_ops_by_scope(dump, program: str, scope: str):
+    """A ``trace_dump.json``'s device ops that ran inside a launch of
+    ``program`` under the named scope ``scope`` (a component of the op's
+    ``op_name`` path), folded by (op kind, the path below the scope):
+    ``{"launches", "ms_per_launch", "rows": [{"kind", "under",
+    "ms_per_launch", "ops_per_launch"}]}``, largest first."""
+    launches, dur, count = 0, collections.Counter(), collections.Counter()
+    for plane in dump.get("planes", {}).values():
+        mods = sorted(
+            (start, start + length) for name, start, length
+            in plane.get("XLA Modules", ())
+            if name.split("(")[0] == program
+        )
+        launches += len(mods)
+        starts = [m[0] for m in mods]
+        for _name, start, length, path, kind in plane.get("XLA Ops", ()):
+            i = bisect.bisect_right(starts, start) - 1
+            parts = path.split("/")
+            if i < 0 or start >= mods[i][1] or scope not in parts:
+                continue
+            key = (kind, "/".join(parts[parts.index(scope) + 1:]))
+            dur[key] += length
+            count[key] += 1
+    per = 1e6 * max(launches, 1)          # ns -> ms a launch
+    return {
+        "launches": launches,
+        "ms_per_launch": sum(dur.values()) / per,
+        "rows": [
+            {"kind": kind, "under": under, "ms_per_launch": ns / per,
+             "ops_per_launch": count[kind, under] / max(launches, 1)}
+            for (kind, under), ns in dur.most_common()
+        ],
+    }
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("files", nargs="+", help="span JSONL files")
@@ -398,9 +442,31 @@ def main(argv=None) -> int:
                     "for a trainer's sink)")
     ap.add_argument("--trace",
                     help="print one trace's tree + critical path")
+    ap.add_argument("--device-ops", metavar="SCOPE",
+                    help="the files are trace_dump.json of traced "
+                    "benchmark runs: device ops under this named scope "
+                    "of --program, by op")
+    ap.add_argument("--program", default="jit_step",
+                    help="the program --device-ops reads (jit_step)")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable output")
     ns = ap.parse_args(argv)
+    if ns.device_ops:
+        for path in ns.files:
+            with open(path) as f:
+                table = device_ops_by_scope(
+                    json.load(f), ns.program, ns.device_ops
+                )
+            if ns.json:
+                print(json.dumps(table))
+                continue
+            print(f"{path}: {ns.device_ops} of {ns.program}, "
+                  f"{table['launches']} launches, "
+                  f"{table['ms_per_launch']:.3f} ms a launch")
+            for r in table["rows"][:ns.top]:
+                print(f"{r['ms_per_launch']:9.3f}ms {r['ops_per_launch']:7.1f}"
+                      f" ops  {r['kind']:<10} {r['under']}")
+        return 0
     spans = load_spans(ns.files)
     if not spans:
         print("no spans found", file=sys.stderr)
