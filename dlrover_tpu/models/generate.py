@@ -682,4 +682,6 @@ def generate(
 # The kinds of model whose tree is nested and stored as a server reads it
 # (``prepare_decode_params`` hands them to their own module). Down here:
 # the file is line-neutral above for the served programs' kernels.
-NESTED_TREE_KINDS = ("latent_lm", "conv_lm", "window_lm", "linear_sparse_lm")
+NESTED_TREE_KINDS = (
+    "latent_lm", "conv_lm", "window_lm", "linear_sparse_lm", "delta_lm",
+)

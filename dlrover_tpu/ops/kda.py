@@ -249,11 +249,11 @@ def decayed_products(q, k, g_cum, beta, sub):
 # layout as cheaply as any, and a chunk is then a free reshape).
 
 
-def kda_recurrent(q, k, v, g, beta):
-    """The recurrence, a token a step."""
+def kda_recurrent(q, k, v, g, beta, state=None):
+    """The recurrence, a token a step (``state``: the file's last note)."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
-    b, h, _, dk = k.shape
+    zeros = jnp.zeros(k.shape[:2] + (k.shape[-1], v.shape[-1]), f32)
 
     def step(state, x):
         q_t, k_t, v_t, g_t, b_t = x
@@ -263,10 +263,10 @@ def kda_recurrent(q, k, v, g, beta):
         return state, _mm("bhk,bhkv->bhv", q_t, state)
 
     xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
-    _, out = jax.lax.scan(
-        step, jnp.zeros((b, h, dk, v.shape[-1]), f32), xs
-    )
-    return jnp.moveaxis(out, 0, 2)
+    first = zeros if state is None else state.astype(f32)
+    last, out = jax.lax.scan(step, first, xs)
+    out = jnp.moveaxis(out, 0, 2)
+    return out if state is None else (out, last)
 
 
 def head_groups(heads: int, seq: int) -> int:
@@ -412,3 +412,13 @@ def _kda_chunked(q, k, v, g, beta, chunk, sub):
         "bhnij,bhnjv->bhniv", p_mat, u0
     )
     return out.reshape(b, h, n * chunk, dv)[:, :, :s]
+
+
+# :func:`kda_recurrent` is the ONE definition both delta rules are held
+# to: the trained one (this file's chunked forms) and the SERVED one,
+# whose gate is one number a head (``ops/gated_delta.py``: ``g``
+# broadcast over the channels). ``state [b, h, dk, dv]`` is what the run
+# enters with: None (zeros) hands back the outputs alone, as ever; given,
+# ``(outputs, the state after the last row)``. Written down here, below
+# every kernel's call site: a compiled kernel carries its callers' line
+# numbers, and no line above moves for this note.

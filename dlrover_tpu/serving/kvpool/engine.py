@@ -1100,7 +1100,9 @@ class PagedServingEngine(ServingEngine):
                 tuple(g.num_blocks for g in self._reach_groups),
             ) if self._reach_groups else _linear_steps(
                 config, slots, self.max_blocks, block_size, prefill_chunk,
-            ) if _is_linear(config) else _paged_steps(
+            ) if _is_linear(config) else _delta_steps(
+                config, slots, self.max_blocks, block_size, prefill_chunk,
+            ) if _is_delta(config) else _paged_steps(
                 config, slots, self.num_blocks, self.max_blocks,
                 block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
             )
@@ -1817,7 +1819,14 @@ class PagedServingEngine(ServingEngine):
                 self._prefix_rounded_down_blocks
             )
             stats["moe_rows_dropped"] = self._moe_rows_dropped
-        if self.linear_kinds:
+        if _is_delta(self.config):
+            # What each of the four parts runs, and each state array's
+            # bytes a slot (a snapshot): the pair is one state.
+            stats.update(self.linear_kinds)
+            stats["state_array_bytes"] = {
+                a.name: a.entry_bytes() for a in self._state_layout
+            }
+        elif self.linear_kinds:
             # The array at a stride: its share of the bytes above and
             # the whole array's size; and what each of the five runs.
             per_block = self._array_block_bytes["ckeys"]
@@ -2085,7 +2094,11 @@ class PagedServingEngine(ServingEngine):
                 r.slot for r in decoding
             )
         if self.linear_kinds and self._step_trace is not None:
-            self._step_trace.counts.update(_linear_module().decode_counts(
+            module = (
+                _delta_module() if _is_delta(self.config)
+                else _linear_module()
+            )
+            self._step_trace.counts.update(module.decode_counts(
                 self.config, [int(self._lengths[r.slot]) for r in decoding]
             ))
 
@@ -2121,6 +2134,8 @@ class PagedServingEngine(ServingEngine):
                 ("state_restores", 1),
                 ("state_restores_from_snapshot", int(bool(snapshot))),
                 ("state_restore_s", time.monotonic() - t0),
+                # rows of the prompt that the hit did not cover
+                ("prefill_rows_again", req.prompt_len - len(hit) * bs),
                 ("prefix_rounded_down_blocks",
                  req.prefix_rounded_down_blocks or 0),
             ):
@@ -2206,7 +2221,14 @@ class PagedServingEngine(ServingEngine):
         # is a chunk's first row was the END of the chunk before it, or
         # the hit's own boundary, which has its snapshot).
         if self._cache is not None and start < boundary <= start + n_valid:
+            given_up = self._cache.snapshots_given_up_total
             snap_id = self._cache.take_snapshot()
+            given_up = self._cache.snapshots_given_up_total - given_up
+            if given_up and self._step_trace is not None:
+                counts = self._step_trace.counts
+                counts["state_snapshots_given_up"] = given_up + counts.get(
+                    "state_snapshots_given_up", 0
+                )
             if snap_id:
                 snap_at = boundary - start
                 self._slot_snapshot[req.slot] = snap_id
@@ -2459,4 +2481,57 @@ def _linear_steps_for(config, slots: int, max_blocks: int, block_size: int,
                 donate_argnums=pool_args),
         jax.jit(_build_export_gather(counts, n_pools, n_state)),
         counts, linear_kinds=kinds,
+    )
+
+
+# ---- delta-rule / full-attention layers (kvpool/delta.py) -------------------
+
+
+def _is_delta(config) -> bool:
+    return getattr(config, "kind", "") == "delta_lm"
+
+
+def _delta_module():
+    # Imported here: the module builds on this one.
+    from dlrover_tpu.serving.kvpool import delta
+
+    return delta
+
+
+def _delta_steps(config, slots: int, max_blocks: int, block_size: int,
+                 chunk: int) -> _LinearSteps:
+    """The programs of ``kvpool/delta.py``, keyed like
+    :func:`_paged_steps` and by what their parts run; what they run is
+    reported through ``linear_kinds``, the field the steps tuples have
+    for it."""
+    kinds = _delta_module().kinds(
+        config, config.compute_dtype, block_size, chunk, slots, max_blocks
+    )
+    return _delta_steps_for(
+        config, slots, max_blocks, block_size, chunk,
+        tuple(sorted(kinds.items())),
+    )
+
+
+@functools.lru_cache(maxsize=8)
+def _delta_steps_for(config, slots: int, max_blocks: int, block_size: int,
+                     chunk: int, kinds) -> _LinearSteps:
+    delta = _delta_module()
+    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
+    n_pools = len(pool_layout.pool_arrays(config))
+    n_state = 2 * len(pool_layout.state_arrays(config))
+    pool_args = tuple(range(n_pools + n_state))
+    return _LinearSteps(
+        jax.jit(delta.build_prefill(
+            config, max_blocks, block_size, chunk, counts, kinds
+        ), donate_argnums=pool_args),
+        jax.jit(delta.build_decode(
+            config, slots, max_blocks, block_size, counts, kinds
+        ), donate_argnums=pool_args),
+        jax.jit(_build_cow_copy(counts, n_pools, n_state),
+                donate_argnums=pool_args),
+        jax.jit(_build_import_scatter(counts, n_pools, n_state),
+                donate_argnums=pool_args),
+        jax.jit(_build_export_gather(counts, n_pools, n_state)),
+        counts, pool_attention="delta_state_and_pages", linear_kinds=kinds,
     )

@@ -37,6 +37,12 @@ a dense GQA model: ``k`` and ``v`` of ``[kv_heads, head_dim]``):
   the tokens up to that block's end alone, so a shared block means the
   same to every sequence that shares it.
 
+- delta-rule and un-grouped full-attention layers
+  (``models/delta_lm.py``): ``k``, ``v [kv_heads_held, head_dim]`` over
+  the FULL layers alone (``cache_layers``), the 30 KV heads held as 32
+  (zero heads: what such a row pads to on the device anyway, declared so
+  the dense model's kernels apply as they are).
+
 Everything that MOVES a block (copy-on-write, import, export, prefix
 sharing, preemption, release) moves every array of this tuple and never
 asks what they are; everything that SIZES a block sums over it (an array
@@ -44,7 +50,7 @@ at a stride counts ``block_size / stride`` rows: ``PoolArray
 .block_rows``). Only the
 programs that read and write rows (``kvpool/engine.py``'s dense ones,
 ``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``,
-``kvpool/linear.py``) know the arrays by name.
+``kvpool/linear.py``, ``kvpool/delta.py``) know the arrays by name.
 
 GROUPS (``config.cache_groups``: name -> (layers, ``"all"`` or a reach
 in rows); a config without it is ONE group that keeps all, and nothing
@@ -64,8 +70,12 @@ moves or sizes a block walks the groups; the programs take the groups'
 arrays in order and the tables stacked (``kvpool/window.py``).
 
 A SECOND kind of array holds what a sequence keeps whatever its length
-(``config.state_rows``: name -> (layers, a slot's shape); no other
-config states any): per-SLOT state ``[layers, slots, *shape]``, which
+(``config.state_rows``: name -> (layers, a slot's shape[, dtype]); ONE
+array for ``models/conv_lm.py`` and ``models/linear_sparse_lm.py``, TWO
+of two dtypes for ``models/delta_lm.py``: the float32 matrix state and
+the compute-dtype convolution taps, which are one state and move
+together: every walk below is over :func:`state_arrays`, whatever their
+number): per-SLOT state ``[layers, slots, *shape]``, which
 no block table addresses, and beside each its SNAPSHOTS ``[layers,
 snapshots, *shape]``: the state as of a block boundary, owned by the
 prefix cache's entry for that boundary (``kvpool/prefix_cache.py``). A
@@ -73,8 +83,8 @@ run of cached blocks can be continued only from a boundary that has one.
 The engine treats them as it treats the arrays above: every program
 takes and returns them after the pool's, admission restores or zeroes a
 slot's, a chunk writes a snapshot, migration carries a slot's raw, and
-``kv_stats()`` sizes them; only ``kvpool/conv.py`` and
-``kvpool/linear.py`` know what they mean. A state array is made in the
+``kv_stats()`` sizes them; only ``kvpool/conv.py``,
+``kvpool/linear.py`` and ``kvpool/delta.py`` know what they mean. A state array is made in the
 dtype its config states (a third item beside layers and shape:
 ``models/linear_sparse_lm.py``'s running sum is float32) and in
 ``compute_dtype`` where it states none. HOW MANY snapshots an engine

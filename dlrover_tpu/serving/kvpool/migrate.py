@@ -292,16 +292,20 @@ def import_request(engine, payload: bytes,
             )
         state_rows.append(rows)
         at += m["nbytes"]
-    cfg = engine.config
     pool_layers = engine._pools()[0].shape[0]
     has_kv = any(a.name == "k" for a in engine._layout)
     if has_kv:
         kq, vq, ks, vs, _ = kv_from_wire(payload[at:])
         L, n, bs, kh, hd = kq.shape
-        if (L, kh, hd) != (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim):
+        # What the pool holds of K: its own layer axis and row (a model
+        # that keeps K/V in some layers, or pads its heads, states both).
+        held = (pool_layers,) + next(
+            a.row_shape for a in engine._layout if a.name == "k"
+        )
+        if (L, kh, hd) != held:
             raise MigrationError(
                 f"model shape mismatch: wire {(L, kh, hd)} vs engine "
-                f"{(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}"
+                f"{held}"
             )
     elif len(payload) != at or not wants:
         raise MigrationError("a kv wire for an engine that keeps no K/V")

@@ -39,8 +39,9 @@ returns the chain only as far as the DEEPEST entry holding a snapshot,
 with that id and how many matched blocks lay beyond it. The ids are a
 BUDGET the engine sizes (one a block where a snapshot is small, so many
 bytes where one outweighs a block: ``kvpool/layout.py``): when none is
-free ``take_snapshot`` takes the least recently used entry's, and that
-entry stays.
+free ``take_snapshot`` takes the snapshot least recently USED (written,
+or restored from by a hit: an order of the snapshots' own, not the
+entries', whose every ancestor a hit touches), and its entry stays.
 
 **Tails** (``tail_allocators``: a pool in groups, ``kvpool/layout.py``).
 The entries name blocks of the FIRST group, which keeps every row. A
@@ -116,6 +117,15 @@ class PrefixCache:
         self._free_snapshots = list(range(snapshots, 0, -1))
         self.snapshots_live = 0       # entries that hold one
         self.snapshots_given_up_total = 0   # taken from an entry that stayed
+        # Snapshot id -> the entry that holds it, least recently USED
+        # first: a snapshot is used when it is written and when a hit
+        # restores from it. Not the entries' own order: a hit touches its
+        # whole chain, so a growing session's superseded boundaries would
+        # stay as young as its newest one, and the snapshots given up
+        # would be those of the sessions that have waited longest, all
+        # of them, the one about to be needed last of all (my chip run,
+        # PR 57: 22 % of a grown session's turns found none).
+        self._snapshot_lru: "OrderedDict[int, _Entry]" = OrderedDict()
         # The reach groups' allocators, in the groups' order.
         self._tail_allocs = tuple(tail_allocators)
         self.tails_live = 0           # entries that own tails
@@ -185,6 +195,8 @@ class PrefixCache:
             prompt, max_blocks, lambda entry: entry.snapshot
         )
         snapshot = usable[-1].snapshot if usable else 0
+        if snapshot:
+            self._snapshot_lru.move_to_end(snapshot)
         return self._lend(usable), snapshot, rounded
 
     def _continuable(self, prompt, max_blocks, owns):
@@ -243,8 +255,9 @@ class PrefixCache:
 
     def take_snapshot(self) -> int:
         """Lend a snapshot id to a request that is about to write the
-        state at a boundary. With none free, the least recently used
-        entry that holds one gives up its SNAPSHOT (the entry and its
+        state at a boundary. With none free, the entry whose snapshot
+        was least recently used (written, or restored from by a hit)
+        gives up its SNAPSHOT (the entry and its
         block stay: the chain is still a prefix, continued from then on
         from a shallower boundary that has one, and the blocks beyond it
         are what ``lookup_with_state`` reports as rounded down): a
@@ -252,13 +265,11 @@ class PrefixCache:
         prompt that is still prefilling; the caller writes the sentinel
         and runs without. It comes back through :meth:`insert` or
         :meth:`give_snapshot`."""
-        if not self._free_snapshots:
-            for entry in self._entries.values():
-                if entry.snapshot:
-                    self._free_entry_snapshot(entry)
-                    entry.snapshot = 0
-                    self.snapshots_given_up_total += 1
-                    break
+        if not self._free_snapshots and self._snapshot_lru:
+            entry = next(iter(self._snapshot_lru.values()))
+            self._free_entry_snapshot(entry)
+            entry.snapshot = 0
+            self.snapshots_given_up_total += 1
         return self._free_snapshots.pop() if self._free_snapshots else 0
 
     def give_snapshot(self, snapshot: int) -> None:
@@ -268,6 +279,7 @@ class PrefixCache:
     def _free_entry_snapshot(self, entry: _Entry) -> None:
         if entry.snapshot:
             self.snapshots_live -= 1
+            self._snapshot_lru.pop(entry.snapshot, None)
             self.give_snapshot(entry.snapshot)
 
     def insert(self, prompt: Sequence[int], blocks: Sequence[int],
@@ -313,6 +325,7 @@ class PrefixCache:
             if lent and k == at - 1 and not entry.snapshot:
                 entry.snapshot, lent = lent, 0
                 self.snapshots_live += 1
+                self._snapshot_lru[entry.snapshot] = entry
             if (new_tails and k == tails_at - 1 and not entry.tails
                     and all(new_tails)):
                 entry.tails = tuple(tuple(ids) for ids in new_tails)

@@ -1448,3 +1448,80 @@ def test_linear_sparse_serving_programs_compile_at_the_cells_shape(topo):
     # every slot's table rides in scalar memory: 768 KB of it at most
     assert linear.select_kind(cfg, cfg.compute_dtype, 192, 1040) == "jnp"
     assert linear.kinds(cfg, cfg.compute_dtype, 64)["block_select"] == "jnp"
+
+
+def test_delta_serving_programs_compile_at_the_cells_shape(topo):
+    """``olmohybrid-serve-grow-6k``'s decode step and prefill chunk (one
+    period of its 8 layers: three delta-rule layers and a full layer) at
+    published widths, 32 slots x 6,656 rows over the cell's pool, lower
+    for the described v5e under their trace names from what the engine's
+    constructor builds (``kvpool.engine._delta_steps``): all six arrays
+    (K and V pages of 32 held heads, the slots' float32 delta state and
+    bfloat16 taps, the snapshots of each) alias their outputs and none is
+    copied or re-laid; the decode step holds ONE state step a delta
+    layer (``ops/gated_delta.py``), booked to ``attn/delta``, and reads
+    its full layer's rows by the dense model's pool kernel, booked to
+    ``attn/full``; the chunk reads them by the dense chunk kernel; and
+    every scope the cell's readers book device time to is there."""
+    from benchmark import common, delta_scopes, rehearse_olmo_hybrid
+    from benchmark import trace_reduce
+    from benchmark.runners import serve_delta
+    from dlrover_tpu.serving.kvpool import delta
+
+    cfg_json = common.load_json("configs", "olmo-hybrid-7b.json")
+    eng = cfg_json["serve_engine"]
+    blocks, snaps = eng["num_blocks"], eng["state_snapshots"] + 1
+    programs, logical = rehearse_olmo_hybrid.lower_engine_programs(
+        cfg_json, topo.devices[0], probes=False, reference=False, layers=4,
+    )
+    assert logical == {
+        "k": blocks * 64 * 32 * 128 * 2, "v": blocks * 64 * 32 * 128 * 2,
+        "delta": 3 * 32 * 30 * 96 * 192 * 4,
+        "taps": 3 * 32 * 3 * 11520 * 2,
+        "delta_snapshots": 3 * snaps * 30 * 96 * 192 * 4,
+        "taps_snapshots": 3 * snaps * 3 * 11520 * 2,
+    }
+    for name in ("jit_step", "jit_prefill"):
+        c = programs[name].compile()
+        text = c.as_text()
+        assert name + "," in text.splitlines()[0]
+        for i in range(6):
+            assert f"{{{i}}}: ({i}, {{}}, may-alias)" in text
+        scopes = trace_reduce.scopes_from_hlo(text)
+        steps = [
+            v for k, v in scopes.items() if k.startswith("delta_state_step")
+        ]
+        assert len(steps) == (3 if name == "jit_step" else 0), steps
+        assert all(delta_scopes.scope_of(v) == "delta" for v in steps)
+        kernel = ("paged_pool_decode_attention" if name == "jit_step"
+                  else "paged_pool_chunk_attention")
+        calls = [v for k, v in scopes.items() if k.startswith(kernel)]
+        assert len(calls) == 1, (kernel, sorted(scopes)[:40])
+        assert all(delta_scopes.scope_of(v) == "full" for v in calls)
+        copies = "".join(
+            line for line in text.splitlines() if " copy(" in line
+        )
+        assert f"bf16[1,{blocks}," not in copies
+        assert "f32[3,32,30,96,192]" not in copies
+        assert f"f32[3,{snaps},30,96,192]" not in copies
+        assert "bf16[3,32,3,11520]" not in copies
+        booked = {delta_scopes.scope_of(v) for v in scopes.values()}
+        assert booked >= {"delta", "conv", "full"}
+        if name == "jit_prefill":
+            assert booked >= {"state", "snapshot"}
+        m = c.memory_analysis()
+        # the float32 state's 192 lanes pad to 256 on the device
+        held = sum(logical.values()) + (
+            logical["delta"] + logical["delta_snapshots"]
+        ) // 3
+        assert abs(m.alias_size_in_bytes - held) < 0.01 * held
+        assert m.temp_size_in_bytes < 1.0e9
+    cfg = serve_delta.delta_config(cfg_json)
+    assert delta.kinds(cfg, cfg.compute_dtype, 64, 512, 32, 104) == {
+        "delta_chunk": "jnp", "delta_decode": "state_kernel",
+        "full_decode_attention": "pool_kernel",
+        "full_chunk_attention": "pool_kernel",
+    }
+    assert delta.kinds(cfg, jnp.float32, 64, 512, 32, 104)[
+        "full_decode_attention"
+    ] == "gathered_view"
