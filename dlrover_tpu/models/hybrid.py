@@ -57,6 +57,7 @@ from dlrover_tpu.common.log import logger
 from dlrover_tpu.models import llama
 from dlrover_tpu.models import moe as moe_lib
 from dlrover_tpu.ops import kda as kda_ops
+from dlrover_tpu.ops import kda_tail
 from dlrover_tpu.ops import rope
 from dlrover_tpu.ops.attention import dot_product_attention
 from dlrover_tpu.ops.norms import rms_norm
@@ -237,19 +238,41 @@ def _l2norm(x):
 
 def _kda_apply(config, p, h):
     """Heads-major ``[b, heads, s, head_dim]`` from the projections on:
-    a chunk of the scan is then a reshape, and no transpose is paid."""
+    a chunk of the scan is then a reshape, and no transpose is paid.
+    The per-token work on either side of the scan has two forms, as the
+    scan has, and ``kda_scan_kind`` chooses both: where the scan is the
+    Pallas kernels so is this (``ops/kda_tail.py``: one fused float32
+    pass a tensor forward and one backward); anywhere else the
+    ``jax.numpy`` lines below, which are the definition."""
     with jax.named_scope("kda"):
-        def branch(w, conv):
-            return jax.nn.silu(_short_conv(_proj("bsd,dhk->bhsk", h, w), conv))
+        hd = config.kda_head_dim
+        fused = kda_ops.kda_scan_kind(hd, hd) == "pallas"
 
-        q = _l2norm(branch(p["wq"], p["conv_q"])) * config.kda_head_dim ** -0.5
-        k = _l2norm(branch(p["wk"], p["conv_k"]))
-        v = branch(p["wv"], p["conv_v"])
+        def heads(w):
+            return _proj("bsd,dhk->bhsk", h, w)
+
+        def branch(w, conv):
+            return jax.nn.silu(_short_conv(heads(w), conv))
+
+        if fused:
+            q = kda_tail.branch(heads(p["wq"]), p["conv_q"], hd ** -0.5)
+            k = kda_tail.branch(heads(p["wk"]), p["conv_k"], 1.0)
+            v = kda_tail.branch(heads(p["wv"]), p["conv_v"])
+        else:
+            q = _l2norm(branch(p["wq"], p["conv_q"])) * hd ** -0.5
+            k = _l2norm(branch(p["wk"], p["conv_k"]))
+            v = branch(p["wv"], p["conv_v"])
         low = _proj("bsd,dr->bsr", h, p["w_a1"])
-        g = -jnp.exp(p["a_log"])[:, None, None] * jax.nn.softplus(
-            _proj("bsr,rhk->bhsk", low, p["w_a2"]).astype(jnp.float32)
-            + p["dt_bias"][:, None, :]
-        )
+        if fused:
+            g = kda_tail.decay_gate(
+                _proj("bsr,rhk->bhsk", low, p["w_a2"]),
+                p["a_log"], p["dt_bias"],
+            )
+        else:
+            g = -jnp.exp(p["a_log"])[:, None, None] * jax.nn.softplus(
+                _proj("bsr,rhk->bhsk", low, p["w_a2"]).astype(jnp.float32)
+                + p["dt_bias"][:, None, :]
+            )
         beta = jax.nn.sigmoid(
             _proj("bsd,dh->bhs", h, p["w_beta"]).astype(jnp.float32)
         )
@@ -261,10 +284,14 @@ def _kda_apply(config, p, h):
         # the kernels walk back over the kept ``kda_states``), and not a
         # second time for what follows it.
         o = checkpoint_name(o, "kda_out")
-        gate = jax.nn.sigmoid(_proj(
+        gate = _proj(
             "bsr,rhk->bhsk", _proj("bsd,dr->bsr", h, p["w_g1"]), p["w_g2"]
-        ).astype(jnp.float32))
-        o = (rms_norm(o, p["o_norm"]) * gate).astype(h.dtype)
+        )
+        if fused:
+            o = kda_tail.gated_norm(o, gate, p["o_norm"], h.dtype)
+        else:
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+            o = (rms_norm(o, p["o_norm"]) * gate).astype(h.dtype)
         return _proj("bhsk,hkd->bsd", o, p["wo"])
 
 

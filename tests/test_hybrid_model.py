@@ -84,6 +84,49 @@ def test_loss_and_gradients_match_the_reference(tiny):
         assert err <= 2e-4 * scale + 1e-7, (jax.tree_util.keystr(path), err)
 
 
+def test_the_fused_kda_layer_matches_its_jax_numpy_lines(monkeypatch):
+    """``_kda_apply`` with ``kda_scan_kind`` steered to ``"pallas"`` (every
+    kernel interpreted: the scan's and ``ops/kda_tail.py``'s around it)
+    against the ``jax.numpy`` form: loss and every gradient of
+    ``tiny_config`` at the published head size, two KDA layers."""
+    import functools
+
+    from dlrover_tpu.ops import kda_tail
+
+    cfg = hybrid.tiny_config(
+        kda_heads=2, kda_head_dim=128, period=(("kda", "dense"),)
+    )
+    params, _ = hybrid.init_params(cfg, jax.random.key(0))
+    buffers = hybrid.init_buffers(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 41), 0, cfg.vocab_size)
+
+    def run():
+        return jax.jit(jax.value_and_grad(lambda p: hybrid.loss_fn(
+            cfg, p, {"tokens": tokens}, buffers
+        )[0]))(params)
+
+    want_loss, want = run()
+    monkeypatch.setattr(kda, "kda_scan_kind", lambda dk, dv: "pallas")
+    for module, name in [(kda, "kda_chunked_kernels")] + [
+        (kda_tail, n) for n in ("branch", "decay_gate", "gated_norm")
+    ]:
+        monkeypatch.setattr(module, name, functools.partial(
+            getattr(module, name), interpret=True
+        ))
+    ran = []
+    monkeypatch.setattr(kda_tail, "_call", lambda *a, real=kda_tail._call,
+                        **kw: ran.append(a[1]) or real(*a, **kw))
+    loss, grads = run()
+    assert {"kda_branch_fwd", "kda_branch_bwd", "kda_gate_fwd",
+            "kda_gate_bwd", "kda_out_fwd", "kda_out_bwd"} == set(ran)
+    assert float(loss) == pytest.approx(float(want_loss), rel=2e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(r))) + 1e-8
+        err = float(jnp.max(jnp.abs(g - r)))
+        assert err <= 2e-4 * scale + 1e-7, (jax.tree_util.keystr(path), err)
+
+
 def _kda_inputs(key, b, h, s, dk, dv, decay):
     ks = jax.random.split(key, 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731

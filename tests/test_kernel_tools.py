@@ -18,6 +18,7 @@ TOOLS = os.path.join(
 @pytest.mark.parametrize("tool", [
     "bench_kda_scan.py", "bench_flash_blocks.py", "bench_sparse_attention.py",
     "bench_sparse_attention.py --parts block_select",
+    "bench_kda_layer.py",
 ])
 def test_a_kernel_tool_rehearses_off_a_tpu(tool):
     tool, *args = tool.split()

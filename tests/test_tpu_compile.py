@@ -207,6 +207,88 @@ def test_kda_scan_kernels_compile_at_the_cells_shape(one_chip):
     ]
 
 
+def _kernel_names(compiled):
+    """{scope: [kernel name, ...]} of a program's Pallas calls, by the
+    scope ``benchmark/hybrid_scopes`` books each to."""
+    from benchmark import hybrid_scopes
+
+    booked = {}
+    for line in compiled.as_text().splitlines():
+        if "tpu_custom_call" in line and 'op_name="' in line:
+            name = line.split('op_name="')[1].split('"')[0]
+            booked.setdefault(hybrid_scopes.scope_of(name), []).append(
+                name.split("/")[-2]
+            )
+    return booked
+
+
+def test_kda_layer_kernels_compile_at_the_cells_shape(one_chip):
+    """A KDA layer of ``kimilinear-train-8k`` (1 x 8,192 tokens, 32
+    heads x 128) forward and pullback under the cell's remat policy: on
+    a TPU the per-token work around the scan is ``ops/kda_tail.py``'s
+    kernels, which lower for the described v5e and carry ``kda`` and NOT
+    ``kda_scan`` in their ``op_name``, forward and backward, so
+    ``benchmark/hybrid_scopes`` books them to the layer and the scan's
+    readers keep reading the scan alone."""
+    import functools
+
+    from benchmark import common
+    from benchmark.runners import train_hybrid
+    from dlrover_tpu.models import hybrid
+
+    cfg_json = common.load_json("configs", "kimi-linear-48b-a3b.json")
+    traffic = common.load_json("traffic", "pretrain-8k.json")
+    cfg = train_hybrid.hybrid_config(cfg_json)
+    layer = jax.checkpoint(
+        functools.partial(hybrid._kda_apply, cfg),
+        policy=hybrid.remat_policy(cfg.remat_keep),
+    )
+
+    def pulled(p, h):
+        with jax.named_scope("attn"):
+            out, pull = jax.vjp(layer, p, h)
+        return out, pull(out)
+
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda k: hybrid.MIXERS["kda"].init(cfg, k), jax.random.key(0)
+        ),
+    )
+    h = jax.ShapeDtypeStruct(
+        (1, traffic["seq_len"], cfg.embed_dim), cfg.compute_dtype,
+        sharding=one_chip,
+    )
+    booked = _kernel_names(jax.jit(pulled).lower(params, h).compile())
+    # q, k, v forward, again in the re-forward, and their pullbacks; the
+    # scan's forward is not run again (its output and states are kept).
+    assert sorted(booked.pop("kda")) == sorted(
+        ["kda_branch_fwd"] * 6 + ["kda_branch_bwd"] * 3
+        + ["kda_gate_fwd"] * 2 + ["kda_gate_bwd"]
+        + ["kda_out_fwd"] * 2 + ["kda_out_bwd"]
+    )
+    assert sorted(booked.pop("kda_scan")) == ["kda_scan_bwd", "kda_scan_fwd"]
+    assert not booked
+
+
+@pytest.mark.slow
+def test_kimilinear_step_fits_the_chip_with_the_layer_kernels(topo):
+    """The cell's whole step, built with those kernels, still peaks
+    under the chip's 16 GB (13.94 GB at PR 58, 14.41 at its parent). Out
+    of the tier-1 run: the compile alone is ~80 s of a run that has ~150
+    to spare, ``benchmark/rehearse_kimi_linear.py`` makes it by hand and
+    the cell's ``memory_peak_bytes`` reads the same number on the chip."""
+    from benchmark import common, rehearse_kimi_linear
+
+    step = rehearse_kimi_linear.lower_step(
+        common.load_json("configs", "kimi-linear-48b-a3b.json"),
+        common.load_json("traffic", "pretrain-8k.json"), topo.devices[0],
+    ).compile()
+    booked = _kernel_names(step)
+    assert len(booked["kda"]) == 4 * 15 and len(booked["kda_scan"]) == 4 * 2
+    assert step.memory_analysis().peak_memory_in_bytes < 16e9
+
+
 def test_expert_share_compiles_fwd_bwd(one_chip):
     """``moe_mlp_share`` at the hybrid cell's shape (8 of 256 experts
     held, top-8, 8,192 tokens of width 2,304): both row buffers' grouped
