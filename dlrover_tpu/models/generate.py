@@ -568,11 +568,11 @@ def prepare_decode_params(config, params):
     the MoE router stay f32 (same precision rule as
     llama.run_layer_stack). Pure jnp: generate()'s jitted run calls it
     traced, the serving engine calls it eagerly once per engine."""
-    if getattr(config, "kind", "") in NESTED_TREE_KINDS:
-        # Its tree is nested and stored as a server reads it.
-        from dlrover_tpu.models import model_for
+    from dlrover_tpu.models import model_for
 
-        return model_for(config).prepare_decode_params(config, params)
+    own = getattr(model_for(config), "prepare_decode_params", None)
+    if own is not None:     # (a tree nested and stored as a server reads it)
+        return own(config, params)
     cdt = config.compute_dtype
     if cdt != jnp.float32:
         keep = {"attn_norm", "mlp_norm", "router", "q_norm", "k_norm",
@@ -677,11 +677,3 @@ def generate(
 
     tokens, cache = run(params, prompt, rng, np.float32(temperature))
     return GenerateResult(tokens=tokens, cache=cache)
-
-
-# The kinds of model whose tree is nested and stored as a server reads it
-# (``prepare_decode_params`` hands them to their own module). Down here:
-# the file is line-neutral above for the served programs' kernels.
-NESTED_TREE_KINDS = (
-    "latent_lm", "conv_lm", "window_lm", "linear_sparse_lm", "delta_lm",
-)

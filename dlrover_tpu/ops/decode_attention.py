@@ -27,9 +27,9 @@ lower:
   the reference for a T-query pool kernel over several slots
   (ROADMAP S4(c)).
 
-``serving/kvpool/engine.pool_attention_kind`` picks between the two
-dense kernels and the engine's XLA gather, and
-``sparse_chunk_attention_kind`` between the sparse one and
+``serving/kvpool/dense.pool_attention_kind`` picks between the two
+dense kernels and the XLA gather, and
+``kvpool/sparse.chunk_attention_kind`` between the sparse one and
 ``ops.sparse_attention.masked_attention``, from what they can see
 (platform, pool dtype, page and chunk shapes); nothing here reads the
 environment.

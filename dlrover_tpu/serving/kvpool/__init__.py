@@ -18,10 +18,8 @@ from dlrover_tpu.serving.kvpool.allocator import (
     BlockAllocator,
     BlockPoolExhausted,
 )
-from dlrover_tpu.serving.kvpool.engine import (
-    SENTINEL_BLOCK,
-    PagedServingEngine,
-)
+from dlrover_tpu.serving.kvpool.engine import PagedServingEngine
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.migrate import (
     MigrationError,
     MigrationRefused,

@@ -52,8 +52,8 @@ import jax.numpy as jnp
 from dlrover_tpu.models import conv_lm
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.latent import (
     _softmax_add,
     _softmax_finish,
@@ -123,7 +123,7 @@ def decode_attention_kind(config, pool_dtype, block_size: int,
     shape). ``kv_stats()["conv_decode_attention"]`` and the engine's
     construction log line say which. The prefill chunk is not its
     business but :func:`chunk_attention_kind`'s."""
-    if not paged._on_tpu():
+    if not families._on_tpu():
         return "gathered_view"
     # Pallas costs ~1.2 s to import: only a process that may run the
     # kernel pays it (the repo's idiom for ops/ kernels).
@@ -357,10 +357,10 @@ def chunk_forward(config, k_pool, v_pool, state, params, tokens, table_row,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts, kind=None):
-    """``kind``: :func:`decode_attention_kind`'s answer for this shape
-    (None: asked when the step is traced)."""
-    max_len = max_blocks * block_size
+                 counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: asked when
+    the step is traced)."""
+    max_len, kinds = max_blocks * block_size, kinds or {}
 
     def step(k, v, state, snaps, params, tables, lengths, tokens, active,
              temps, rng, step_idx, first=0, first_slot=-1):
@@ -368,7 +368,7 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         tokens = _place_first(tokens, first, first_slot)
         logits, (k_new, v_new), new_state, counters = decode_forward(
             config, k, v, state, params, tables, lengths, tokens, block_size,
-            kind=kind, active=active,
+            kind=kinds.get("conv_decode_attention"), active=active,
         )
         write = jnp.minimum(lengths, max_len - 1)
         blk = jnp.take_along_axis(
@@ -403,9 +403,10 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts, kind=None):
-    """``kind``: :func:`chunk_attention_kind`'s answer for this shape
-    (None: asked when the chunk is traced)."""
+                  counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: asked when
+    the chunk is traced)."""
+    kind = (kinds or {}).get("conv_chunk_attention")
     if chunk % block_size:
         raise ValueError(
             f"prefill_chunk {chunk} must be whole blocks of {block_size}: "
@@ -489,12 +490,12 @@ def chunk_token_tile(chunk: int) -> int:
     return chunk if chunk % CHUNK_TOKEN_TILE else CHUNK_TOKEN_TILE
 
 
-def chunk_rows_scored(n_valid, chunk: int, kind: str):
+def chunk_rows_scored(n_valid, chunk: int, kinds):
     """Query rows (tokens) a chunk of ``n_valid`` valid rows scores: all
     ``chunk`` of them under the gathered form, and under the kernel its
     tiles up to the last that holds a valid row (the kernel's own test,
-    tile by tile: ``first token < n_valid``)."""
-    if kind != "pool_kernel":
+    tile by tile: ``first token < n_valid``). ``kinds``: :func:`kinds`'."""
+    if kinds["conv_chunk_attention"] != "pool_kernel":
         return chunk
     tile = chunk_token_tile(chunk)
     return -(-n_valid // tile) * tile
@@ -518,7 +519,7 @@ def chunk_attention_kind(config, pool_dtype, block_size: int,
     nothing falls back after it, so what it admits has to compile
     (``tests/test_tpu_compile.py``). ``kv_stats()["conv_chunk_attention"]``
     and the engine's construction log line say which."""
-    if not paged._on_tpu():
+    if not families._on_tpu():
         return "gathered_view"
     from dlrover_tpu.ops.flat_decode_attention import (
         flat_chunk_kernel_supported,
@@ -560,3 +561,31 @@ def chunk_attend_in_place(config, k_pool, v_pool, at: int, table_row, start,
         return _own_lanes(config, out)[None].astype(q.dtype)
 
     return attend
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+# The name of the programs' definition (two accepted benchmark files pin
+# the string); what each reads the slot's rows with is :func:`kinds`'.
+POOL_ATTENTION = "conv_gathered_view"
+
+
+def kinds(config, pool_dtype, block_size: int, chunk: int, slots: int = 0,
+          max_blocks: int = 0):
+    return {
+        "conv_decode_attention": decode_attention_kind(
+            config, pool_dtype, block_size, max_blocks, slots
+        ),
+        "conv_chunk_attention": chunk_attention_kind(
+            config, pool_dtype, block_size, max_blocks, chunk
+        ),
+    }
+
+
+def pool_stats(engine):
+    """Token rows the chunks launched carried, and those their attention
+    scored (the kernel skips the tiles of padding)."""
+    return {
+        "conv_chunk_rows_launched": engine._chunk_rows_launched,
+        "conv_chunk_rows_scored": engine._chunk_rows_scored,
+    }

@@ -47,8 +47,8 @@ from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.ops import gated_delta
 from dlrover_tpu.ops.window_attention import SMEM_TABLE_BYTES, window_reference
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 
 
 def sub_chunk(chunk: int) -> int:
@@ -79,7 +79,7 @@ def kinds(config, pool_dtype, block_size: int, chunk: int, slots: int = 0,
     definition, everywhere else."""
     c = config
     full = "gathered_view"
-    if paged._on_tpu():
+    if families._on_tpu():
         from dlrover_tpu.ops.decode_attention import chunk_kernel_supported
 
         held = c.kv_heads_held
@@ -396,3 +396,16 @@ def decode_counts(config, fills):
     its ``serving.step`` span: ``state_slots``, the slots whose state (of
     every delta layer) the launch reads and writes."""
     return {"state_slots": len(fills)}
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+POOL_ATTENTION = "delta_state_and_pages"
+
+
+def pool_stats(engine):
+    """Each state array's bytes a slot (a snapshot): the pair is one
+    state."""
+    return {"state_array_bytes": {
+        a.name: a.entry_bytes() for a in engine._state_layout
+    }}

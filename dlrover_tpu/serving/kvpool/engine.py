@@ -15,31 +15,16 @@ slot's logical cache is the pool rows its BLOCK TABLE names:
   0 so their masked-garbage writes can never land in a block another
   slot shares (the flat engine's own-row trick does not survive
   sharing).
-- **Attention reads the pool in place** (``pool_attention``:
-  ``"paged_kernel"``), in the decode step and the prefill chunk alike.
-  On a TPU with a bf16 pool whose page tiles and fits a VMEM chunk
-  (``ops.decode_attention.chunk_kernel_supported``; anything else
-  takes the gather, nothing fails to build) each layer's attention is
-  one Pallas call over the WHOLE stacked pool: the layer index, the
-  tables and the fills pick the pages, only the filled pages are
-  copied (one contiguous DMA a page), and the new tokens' own K/V
-  come from the layer's hands, so both programs are append-free: the
-  decode step lands one row a slot after its layer scan, the prefill
-  chunk its ``[layers, chunk]`` rows (PERF.md §5, PR 28: the chunk
-  program used to gather one slot's whole ``[max_len]`` view, carry
-  it through the scan and score all of it). The alternative
-  (``"xla_gather"``: everywhere else, and the plain reference of
-  the parity tests) gathers each slot's
-  logical ``[max_len]`` view through the table and runs the flat
-  engine's ``models/generate._layer_decode_read_only`` on it. On the
-  chip that path moved the cache at its full CAPACITY four times a
-  layer — the scan's slice of the layer's pool, the gathered view,
-  and one read each for K and V — as many bytes as the weights at
-  ``nemo12b-serve-chat`` (PERF.md §5, PR 25). Which one an engine's
-  programs were built with follows from the platform and the pool,
-  not from a knob (:func:`pool_attention_kind`); the engine logs it
-  once at construction and reports it in ``kv_stats()``. The verify /
-  draft programs and int8 pools still gather (no cell runs them).
+- **This file is the HOST**: slots, blocks, tables, the prefix cache,
+  the step loop. What the two programs compute is a FAMILY module's
+  (``kvpool/families.py``: ``programs_for(config)`` names it, and it
+  states ``kinds`` / ``build_decode`` / ``build_prefill``); one cached
+  builder (:func:`_steps_for`) jits them for every family, and this file
+  names no family above the adapter block at its end. What each part of
+  an engine's programs runs (a kernel over the pool in place, the
+  gathered definition) follows from the platform and the pool, not from
+  a knob: :attr:`PagedServingEngine.kinds`, the construction log line and
+  ``kv_stats()`` say it.
 - **Visibility invariant, unchanged.** A logical row is read iff
   ``row < fill``; stale or foreign content beyond a slot's fill —
   including the longer tail of a SHARED prefix block — is masked out
@@ -71,40 +56,40 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.common.log import logger
-from dlrover_tpu.models import generate as gen_lib
-from dlrover_tpu.models import llama
-from dlrover_tpu.serving.engine import (
-    ServingEngine,
-    _PhaseMarks,
-    _h2d,
-    _place_first,
-)
+from dlrover_tpu.serving.engine import ServingEngine, _PhaseMarks, _h2d
 from dlrover_tpu.serving.kvpool.allocator import (
     BlockAllocator,
     BlockPoolExhausted,
 )
 from dlrover_tpu.serving.kvpool import layout as pool_layout
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK, programs_for
+from dlrover_tpu.serving.kvpool.groups import ReachGroup, band_blocks
 from dlrover_tpu.serving.kvpool.prefix_cache import PrefixCache
-from dlrover_tpu.serving import spec_decode as spec_lib
 from dlrover_tpu.serving.scheduler import DECODE, PREFILL, Request
-
-# Pool row 0 absorbs the masked-garbage appends of non-active slots;
-# never allocated, never read.
-SENTINEL_BLOCK = 0
 
 
 class _PagedSteps(NamedTuple):
+    """An engine's compiled programs, whatever its family, and what they
+    were built with."""
+
     prefill: object
     decode: object
     cow: object
     imp: object          # migration import: host block rows -> pool[dst]
     exp: object          # migration export: pool[src] -> one block's rows
     trace_counts: Dict[str, int]
-    pool_attention: str = "xla_gather"   # see pool_attention_kind
-    sparse_chunk_attention: str = ""     # sparse_chunk_attention_kind
-    latent_decode_attention: str = ""    # latent.decode_attention_kind
-    conv_decode_attention: str = ""      # conv.decode_attention_kind
-    conv_chunk_attention: str = ""       # conv.chunk_attention_kind
+    pool_attention: str  # the name of the programs' definition
+    kinds: tuple         # the family's ``kinds()``, its items sorted
+
+    # Read by name in benchmark/ (rehearse_mellum2.py, rehearse_olmo_hybrid
+    # .py): retired with the adapter block at this file's end.
+    window_decode_attention = property(
+        lambda self: dict(self.kinds).get("window_decode_attention", "")
+    )
+    window_chunk_attention = property(
+        lambda self: dict(self.kinds).get("window_chunk_attention", "")
+    )
+    linear_kinds = property(lambda self: self.kinds)
 
 
 class _PagedSpecSteps(NamedTuple):
@@ -116,409 +101,6 @@ class _PagedSpecSteps(NamedTuple):
     verify: object
     draft: object        # None for the host-side n-gram drafter
     trace_counts: Dict[str, int]
-
-
-def _on_tpu() -> bool:
-    """The platform probe of :func:`pool_attention_kind` (tests patch
-    it to take the kernel path in interpret mode)."""
-    return jax.default_backend() == "tpu"
-
-
-def pool_attention_kind(config, block_size: int, kv_dtype: str,
-                        chunk: int) -> str:
-    """Which attention the plain decode program AND the prefill program
-    are built with, one answer for both: ``"paged_kernel"`` (the pool
-    read in place, filled pages only) where both kernels lower — a TPU,
-    a bf16 pool, a page that tiles and fits a VMEM chunk, a prefill
-    chunk whose query tile and buffers fit the VMEM the kernel asks for
-    — and ``"xla_gather"`` otherwise. Decided by what the code can see;
-    there is no option for it, and nothing falls back after it, so what
-    it admits has to compile (``tests/test_tpu_compile.py`` holds it to
-    that over GQA, MHA, wide heads and short caches). The cache's size
-    is no part of it: on the v5e the decode kernel was ahead of the
-    gather down to 4 slots x 576 rows and 16 slots x 128 rows (PERF.md
-    §6, PR 25), the chunk kernel at every ``start`` (PR 28)."""
-    if getattr(config, "kind", "") == "latent_lm":
-        # One latent row a token (kvpool/latent.py): the decode step
-        # absorbs the up-projection and reads the rows themselves.
-        return "latent_absorbed"
-    if getattr(config, "kind", "") == "conv_lm":
-        # Attention layers among convolution layers (kvpool/conv.py):
-        # K and V held flat, 64-wide heads. The name is the programs'
-        # definition; what each reads the slot's rows with is for
-        # conv.decode_attention_kind / chunk_attention_kind to say.
-        return "conv_gathered_view"
-    if getattr(config, "index_topk", 0):
-        # A learned selection of the cache (kvpool/sparse.py): index
-        # keys scored through the table, the selected rows gathered;
-        # what the chunk attends with under its selection is
-        # sparse_chunk_attention_kind's to say.
-        return "sparse_gather"
-    if kv_dtype != "fp" or not _on_tpu():
-        return "xla_gather"
-    # Pallas costs ~1.2 s to import: only a process that may run the
-    # kernels pays it (the repo's idiom for ops/ kernels).
-    from dlrover_tpu.ops.decode_attention import chunk_kernel_supported
-
-    if chunk_kernel_supported(
-        config.compute_dtype, block_size, config.n_heads,
-        config.n_kv_heads, config.head_dim, chunk,
-    ):
-        return "paged_kernel"
-    return "xla_gather"
-
-
-def sparse_chunk_attention_kind(config, pool_dtype, block_size: int,
-                                chunk: int, max_blocks: int) -> str:
-    """What a sparse model's prefill chunk attends with under its
-    selection (``kvpool/sparse.chunk_attend``): ``"chunk_kernel"``
-    (``ops.decode_attention.sparse_chunk_attention``: K and V read from
-    the pool in place, the selection applied to the scores in VMEM)
-    where that kernel lowers — a TPU, a bf16 pool, a page that is one
-    DMA, a token tile of whole lane blocks, buffers inside the VMEM it
-    asks for — and ``"masked_attention"``, the definition, over the
-    slot's gathered views everywhere else. Decided by what the code can
-    see, like :func:`pool_attention_kind` and for its reasons: no
-    option, nothing falls back after it, so what it admits has to
-    compile (``tests/test_tpu_compile.py`` holds it to the cell's
-    shape). The decode step is not its business: that one gathers the
-    selected rows whatever this says."""
-    if not _on_tpu():
-        return "masked_attention"
-    from dlrover_tpu.ops.decode_attention import (
-        sparse_chunk_kernel_supported,
-    )
-
-    if sparse_chunk_kernel_supported(
-        pool_dtype, block_size, config.n_heads, config.n_kv_heads,
-        config.head_dim, chunk, max_blocks,
-    ):
-        return "chunk_kernel"
-    return "masked_attention"
-
-
-def _layer_over_pool(config, p, x, positions, attend):
-    """``generate._layer_decode_read_only`` with the cache behind
-    ``attend(q, k_new, v_new)`` (``[b, s, heads, d]`` each) in place of
-    a ``[b, max_len]`` slab: the paged programs' memory is a pool and
-    tables, so their attention shares no logic with the slab's. Serves
-    the decode step (``[slots, 1]``) and the prefill chunk (``[1,
-    chunk]``) alike; the caller lands ``k_new`` / ``v_new`` in their
-    pages after the layer scan."""
-    residual = x
-    if "wqkv" in p:
-        q, k, v = gen_lib._fused_qkv(config, p, x, positions)
-    else:
-        q, k, v = llama.attention_qkv(config, p, x, positions)
-    attn = attend(q, k, v)
-    x = llama.attention_out(config, p, attn, residual)
-    if "w_gu" in p:
-        x = gen_lib._fused_mlp(config, p, x)
-    else:
-        x, _ = llama.mlp_block(config, p, x)
-    return x, k, v
-
-
-def _build_paged_decode(config, slots: int, max_blocks: int,
-                        block_size: int, counts,
-                        quantized: bool = False,
-                        attn: str = "xla_gather"):
-    """[slots] tokens -> one decoded token per slot, ragged lengths;
-    ``first`` / ``first_slot`` as the flat decode step takes them
-    (``serving.engine._place_first``).
-    ``attn`` (:func:`pool_attention_kind`): ``"paged_kernel"`` reads
-    each layer's K/V straight from the stacked pool through the block
-    tables, filled pages only; ``"xla_gather"`` gathers the cache per
-    layer into a ``[slots, max_len]`` view. ``quantized``: int8 pools +
-    per-(row, head) scale pools — the gather streams half the KV bytes
-    and the append quantizes each new row (ops/kv_quant);
-    dequantization folds into the attention math."""
-    max_len = max_blocks * block_size
-    kh, hd = config.n_kv_heads, config.head_dim
-    def _append_coords(tables, lengths, active):
-        # Per-slot append through the table. Non-active slots are
-        # redirected to the sentinel block: their garbage must never
-        # land in a block another slot may SHARE (the flat engine's
-        # own-row invisibility does not survive sharing). Active slots
-        # write their privately-owned cursor block (host COW-ensured).
-        write = jnp.minimum(lengths, max_len - 1)
-        blk = jnp.take_along_axis(
-            tables, (write // block_size)[:, None], axis=1
-        )[:, 0]
-        blk = jnp.where(active, blk, SENTINEL_BLOCK)
-        off = jnp.where(active, write % block_size, 0)
-        return blk, off
-
-    def _finish(x, params, rng, step_idx, temps, active, tokens):
-        logits = llama.unembed(config, params, x)[:, 0]   # [slots, V]
-        sub = jax.random.fold_in(rng, step_idx * 2)
-        nxt = gen_lib.sample_token(logits, sub, temps)
-        return jnp.where(active, nxt, tokens)
-
-    def step(k, v, params, tables, lengths, tokens, active, temps,
-             rng, step_idx, first=0, first_slot=-1):
-        counts["decode"] += 1  # traces only
-        tokens = _place_first(tokens, first, first_slot)
-        positions = lengths[:, None]                     # [slots, 1]
-        x = llama.embed_tokens(config, params, tokens[:, None])
-
-        def body(carry, layer_in):
-            pl, k_c, v_c = layer_in                      # [nb, bs, kh, hd]
-            k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-            v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-            y, k_new, v_new = gen_lib._layer_decode_read_only(
-                config, pl, carry, positions, k_view, v_view, lengths
-            )
-            return y, (k_new, v_new)
-
-        def body_in_place(carry, layer_in):
-            # The pools are closed over WHOLE: as scanned inputs the
-            # loop would slice a layer's pool out (a copy of all of
-            # it) before the kernel could pick its pages.
-            from dlrover_tpu.ops.decode_attention import (
-                pool_decode_attention,
-            )
-
-            pl, layer = layer_in
-            y, k_new, v_new = _layer_over_pool(
-                config, pl, carry, positions,
-                lambda q, k_new, v_new: pool_decode_attention(
-                    q[:, 0], k_new[:, 0], v_new[:, 0], k, v, layer,
-                    tables, lengths, active,
-                )[:, None],
-            )
-            return y, (k_new, v_new)
-
-        if attn == "paged_kernel":
-            x, (k_news, v_news) = jax.lax.scan(
-                body_in_place, x,
-                (params["layers"],
-                 jnp.arange(config.n_layers, dtype=jnp.int32)),
-            )
-        else:
-            x, (k_news, v_news) = jax.lax.scan(
-                body, x, (params["layers"], k, v)
-            )
-        blk, off = _append_coords(tables, lengths, active)
-        k = k.at[:, blk, off].set(k_news[:, :, 0].astype(k.dtype))
-        v = v.at[:, blk, off].set(v_news[:, :, 0].astype(v.dtype))
-        nxt = _finish(x, params, rng, step_idx, temps, active, tokens)
-        return k, v, nxt
-
-    def step_q8(k, v, ks, vs, params, tables, lengths, tokens, active,
-                temps, rng, step_idx, first=0, first_slot=-1):
-        from dlrover_tpu.ops.kv_quant import quantize_kv
-
-        counts["decode"] += 1  # traces only
-        tokens = _place_first(tokens, first, first_slot)
-        positions = lengths[:, None]
-        x = llama.embed_tokens(config, params, tokens[:, None])
-
-        def body(carry, layer_in):
-            pl, k_c, v_c, ks_c, vs_c = layer_in
-            k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-            v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-            ks_view = ks_c[tables].reshape(slots, max_len, kh)
-            vs_view = vs_c[tables].reshape(slots, max_len, kh)
-            y, k_new, v_new = gen_lib._layer_decode_read_only(
-                config, pl, carry, positions, k_view, v_view, lengths,
-                k_scale=ks_view, v_scale=vs_view,
-            )
-            return y, (k_new, v_new)
-
-        x, (k_news, v_news) = jax.lax.scan(
-            body, x, (params["layers"], k, v, ks, vs)
-        )
-        blk, off = _append_coords(tables, lengths, active)
-        kq, ks_rows = quantize_kv(k_news[:, :, 0])   # [L, slots, kh, hd]
-        vq, vs_rows = quantize_kv(v_news[:, :, 0])
-        k = k.at[:, blk, off].set(kq)
-        v = v.at[:, blk, off].set(vq)
-        ks = ks.at[:, blk, off].set(ks_rows)
-        vs = vs.at[:, blk, off].set(vs_rows)
-        nxt = _finish(x, params, rng, step_idx, temps, active, tokens)
-        return k, v, ks, vs, nxt
-
-    return step_q8 if quantized else step
-
-
-def _build_paged_prefill(config, max_blocks: int, block_size: int,
-                         chunk: int, counts, quantized: bool = False,
-                         attn: str = "xla_gather"):
-    """One prompt chunk into ONE slot's blocks. ``attn``
-    (:func:`pool_attention_kind`):
-
-    - ``"paged_kernel"``: the chunk does only its chunk's work. Each
-      layer's attention reads the slot's rows below ``start`` straight
-      from the stacked pool through ``table_row``
-      (``ops.decode_attention.pool_chunk_attention``) and the chunk's
-      own K/V from the layer's hands; the pools are closed over whole,
-      the layer scan carries nothing of the cache, and after it the
-      chunk's rows of all layers land in their pages with one write.
-    - ``"xla_gather"`` (everywhere else, and the reference): gather the
-      slot's logical ``[max_len]`` cache through its table row, run the
-      flat prefill body over it, scatter back only the touched blocks.
-
-    Either way shared untouched blocks are never rewritten (the COW
-    invariant), rows at or past ``n_valid`` are written, invisible and
-    overwritten later, and the head runs under ``last`` only: the host
-    reads the sampled token on a prompt's LAST chunk alone, so every
-    other chunk skips the ``[d, vocab]`` matmul and returns token 0.
-    ``quantized``: the slot view is dequantized for the
-    (compute-bound) chunk forward and the touched span re-quantized on
-    the way out — per-(row, head) round-to-nearest is IDEMPOTENT (the
-    amax element always maps to ±127), so rows below the chunk inside a
-    touched block keep their exact stored values."""
-    L = config.n_layers
-    kh, hd = config.n_kv_heads, config.head_dim
-    max_len = max_blocks * block_size
-    # Blocks a chunk can touch: chunk//bs full blocks when chunks are
-    # block-multiples, else the single block containing the chunk
-    # (init enforces one of chunk % bs == 0 / bs % chunk == 0).
-    n_touch = max(chunk // block_size, 1)
-
-    def _positions(start):
-        return (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
-
-    def _run_chunk(k_slot, v_slot, params, tokens, start):
-        positions = _positions(start)
-        x = llama.embed_tokens(config, params, tokens)
-
-        def body(carry, layer_in):
-            pl, k_c, v_c = layer_in
-            y, k_c, v_c = gen_lib._layer_decode(
-                config, pl, carry, positions, k_c, v_c, start
-            )
-            return y, (k_c, v_c)
-
-        return jax.lax.scan(
-            body, x, (params["layers"], k_slot, v_slot)
-        )
-
-    def _touched(arr, start, head_shape):
-        # Slice the touched span [touched0*bs, +n_touch*bs) — it
-        # covers [start, start+chunk) exactly (chunk-aligned starts;
-        # see the divisibility contract), so shared UNtouched blocks
-        # are never rewritten.
-        touched0 = start // block_size
-        seg = jax.lax.dynamic_slice(
-            arr, (0, 0, touched0 * block_size) + (0,) * len(head_shape),
-            (L, 1, n_touch * block_size) + head_shape,
-        ).reshape((L, n_touch, block_size) + head_shape)
-        return seg, touched0
-
-    def _first_token(x, params, n_valid, temp, rng, step_idx, last):
-        def head():
-            h = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-            logits = llama.unembed(config, params, h)[0, 0]    # [V]
-            sub = jax.random.fold_in(rng, step_idx * 2 + 1)
-            return gen_lib.sample_token(logits, sub, temp)
-
-        return jax.lax.cond(last, head, lambda: jnp.zeros((), jnp.int32))
-
-    def _land_chunk(pool, rows, table_row, start):
-        # ``rows`` [L, chunk, kh, hd] into their pages: whole blocks
-        # when a chunk is a multiple of a block, else the chunk's span
-        # inside the one block that holds it.
-        rows = rows.astype(pool.dtype)
-        if chunk % block_size == 0:
-            ids = jax.lax.dynamic_slice(
-                table_row, (start // block_size,), (n_touch,)
-            )
-            return pool.at[:, ids].set(
-                rows.reshape(L, n_touch, block_size, kh, hd)
-            )
-        return jax.lax.dynamic_update_slice(
-            pool, rows[:, None],
-            (0, table_row[start // block_size], start % block_size, 0, 0),
-        )
-
-    def prefill_in_place(k, v, params, tokens, table_row, start, n_valid,
-                         temp, rng, step_idx, last=True):
-        from dlrover_tpu.ops.decode_attention import pool_chunk_attention
-
-        counts["prefill"] += 1  # traces only
-        positions = _positions(start)
-        x = llama.embed_tokens(config, params, tokens)
-
-        def body(carry, layer_in):
-            # The pools are closed over WHOLE (see the decode step's
-            # body_in_place); what the scan stacks is the chunk's own
-            # K/V, [chunk, kh, hd] a layer.
-            pl, layer = layer_in
-            y, k_new, v_new = _layer_over_pool(
-                config, pl, carry, positions,
-                lambda q, k_new, v_new: pool_chunk_attention(
-                    q[0], k_new[0], v_new[0], k, v, layer, table_row,
-                    start,
-                )[None],
-            )
-            return y, (k_new[0], v_new[0])
-
-        x, (k_news, v_news) = jax.lax.scan(
-            body, x,
-            (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-        )
-        k = _land_chunk(k, k_news, table_row, start)
-        v = _land_chunk(v, v_news, table_row, start)
-        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
-        return k, v, first
-
-    def prefill_gather(k, v, params, tokens, table_row, start, n_valid,
-                       temp, rng, step_idx, last=True):
-        counts["prefill"] += 1  # traces only
-        k_slot = k[:, table_row].reshape(L, 1, max_len, kh, hd)
-        v_slot = v[:, table_row].reshape(L, 1, max_len, kh, hd)
-        x, (k_slot, v_slot) = _run_chunk(
-            k_slot, v_slot, params, tokens, start
-        )
-        seg_k, touched0 = _touched(k_slot, start, (kh, hd))
-        seg_v, _ = _touched(v_slot, start, (kh, hd))
-        ids = jax.lax.dynamic_slice(table_row, (touched0,), (n_touch,))
-        k = k.at[:, ids].set(seg_k.astype(k.dtype))
-        v = v.at[:, ids].set(seg_v.astype(v.dtype))
-        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
-        return k, v, first
-
-    def prefill_q8(k, v, ks, vs, params, tokens, table_row, start,
-                   n_valid, temp, rng, step_idx, last=True):
-        from dlrover_tpu.ops.kv_quant import dequantize_kv, quantize_kv
-
-        counts["prefill"] += 1  # traces only
-        k_q = k[:, table_row].reshape(L, 1, max_len, kh, hd)
-        v_q = v[:, table_row].reshape(L, 1, max_len, kh, hd)
-        ks_slot = ks[:, table_row].reshape(L, 1, max_len, kh)
-        vs_slot = vs[:, table_row].reshape(L, 1, max_len, kh)
-        # f32 view, not compute_dtype: q*scale is exact in f32, so the
-        # round trip is idempotent and untouched rows inside touched
-        # blocks re-quantize to their exact stored (values, scale).
-        k_slot = dequantize_kv(k_q, ks_slot, jnp.float32)
-        v_slot = dequantize_kv(v_q, vs_slot, jnp.float32)
-        x, (k_slot, v_slot) = _run_chunk(
-            k_slot, v_slot, params, tokens, start
-        )
-        kq_new, ks_new = quantize_kv(k_slot)
-        vq_new, vs_new = quantize_kv(v_slot)
-        seg_k, touched0 = _touched(kq_new, start, (kh, hd))
-        seg_v, _ = _touched(vq_new, start, (kh, hd))
-        seg_ks, _ = _touched(ks_new, start, (kh,))
-        seg_vs, _ = _touched(vs_new, start, (kh,))
-        ids = jax.lax.dynamic_slice(table_row, (touched0,), (n_touch,))
-        k = k.at[:, ids].set(seg_k)
-        v = v.at[:, ids].set(seg_v)
-        ks = ks.at[:, ids].set(seg_ks)
-        vs = vs.at[:, ids].set(seg_vs)
-        first = _first_token(x, params, n_valid, temp, rng, step_idx, last)
-        return k, v, ks, vs, first
-
-    if quantized:
-        return prefill_q8
-    # Both plain programs go by ``prefill``: a trace names a device op
-    # by its program (``jit_prefill:...``), and readers of traces find
-    # the chunk program by that name whichever it is.
-    prefill = prefill_in_place if attn == "paged_kernel" else prefill_gather
-    prefill.__name__ = prefill.__qualname__ = "prefill"
-    return prefill
 
 
 def _build_cow_copy(counts, n_pools: int, n_state: int = 0):
@@ -579,232 +161,24 @@ def _build_export_gather(counts, n_pools: int, n_state: int = 0):
     return exp
 
 
-def _build_paged_verify(config, slots: int, max_blocks: int,
-                        block_size: int, K: int, counts,
-                        quantized: bool = False):
-    """Paged sibling of serving.engine._build_verify_step: the T = K+1
-    verification queries gather each slot's logical cache through its
-    block table and all T new rows land via one advanced-index scatter
-    at block coordinates. Invalid writes (inactive slot, or a row at or
-    past max_len) are redirected to the sentinel block — the paged
-    engine's version of ``mode="drop"``; the host guarantees the rows
-    that CAN become visible (fill..fill+accept) sit in allocated,
-    privately-owned blocks (_spec_prepare_rows). ``quantized``: the
-    layer quantizes its new rows IN-LAYER (per-row round-to-nearest, so
-    intra-draft reads see exactly the values a sequential step would
-    read back from the int8 cache — the bit-stability rule, §35) and
-    the scatter appends the quantized rows + scales directly."""
-    max_len = max_blocks * block_size
-    kh, hd = config.n_kv_heads, config.head_dim
-    T = K + 1
-
-    def _verify_coords(tables, lengths, active):
-        writes = (
-            lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        )                                                # [slots, T]
-        valid = active[:, None] & (writes < max_len)
-        w = jnp.minimum(writes, max_len - 1)
-        blk = jnp.take_along_axis(tables, w // block_size, axis=1)
-        blk = jnp.where(valid, blk, SENTINEL_BLOCK)
-        off = jnp.where(valid, w % block_size, 0)
-        # Several invalid columns may collapse onto sentinel (0, 0);
-        # duplicate scatter targets are fine — it is garbage writing
-        # over garbage in a block that is never read.
-        return blk, off
-
-    def verify(k, v, params, tables, lengths, tokens, drafts,
-               draft_len, active, temps, rng, step_idx):
-        counts["verify"] += 1  # traces only
-        toks = jnp.concatenate([tokens[:, None], drafts], axis=1)
-        positions = (
-            lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        )
-        x = llama.embed_tokens(config, params, toks)
-
-        def body(carry, layer_in):
-            pl, k_c, v_c = layer_in
-            k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-            v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-            y, k_new, v_new = gen_lib._layer_verify_read_only(
-                config, pl, carry, positions, k_view, v_view, lengths
-            )
-            return y, (k_new, v_new)
-
-        x, (k_news, v_news) = jax.lax.scan(
-            body, x, (params["layers"], k, v)
-        )
-        blk, off = _verify_coords(tables, lengths, active)
-        k = k.at[:, blk, off].set(k_news.astype(k.dtype))
-        v = v.at[:, blk, off].set(v_news.astype(v.dtype))
-        logits = llama.unembed(config, params, x)        # [slots, T, V]
-        emitted, acc = spec_lib.spec_accept(
-            logits, drafts, draft_len, temps, active, tokens,
-            rng, step_idx,
-        )
-        return k, v, emitted, acc
-
-    def verify_q8(k, v, ks, vs, params, tables, lengths, tokens,
-                  drafts, draft_len, active, temps, rng, step_idx):
-        counts["verify"] += 1  # traces only
-        toks = jnp.concatenate([tokens[:, None], drafts], axis=1)
-        positions = (
-            lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        )
-        x = llama.embed_tokens(config, params, toks)
-
-        def body(carry, layer_in):
-            pl, k_c, v_c, ks_c, vs_c = layer_in
-            k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-            v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-            ks_view = ks_c[tables].reshape(slots, max_len, kh)
-            vs_view = vs_c[tables].reshape(slots, max_len, kh)
-            y, kq, ks_rows, vq, vs_rows = (
-                gen_lib._layer_verify_read_only(
-                    config, pl, carry, positions, k_view, v_view,
-                    lengths, k_scale=ks_view, v_scale=vs_view,
-                )
-            )
-            return y, (kq, ks_rows, vq, vs_rows)
-
-        x, (kqs, ks_news, vqs, vs_news) = jax.lax.scan(
-            body, x, (params["layers"], k, v, ks, vs)
-        )
-        blk, off = _verify_coords(tables, lengths, active)
-        k = k.at[:, blk, off].set(kqs)
-        v = v.at[:, blk, off].set(vqs)
-        ks = ks.at[:, blk, off].set(ks_news)
-        vs = vs.at[:, blk, off].set(vs_news)
-        logits = llama.unembed(config, params, x)
-        emitted, acc = spec_lib.spec_accept(
-            logits, drafts, draft_len, temps, active, tokens,
-            rng, step_idx,
-        )
-        return k, v, ks, vs, emitted, acc
-
-    return verify_q8 if quantized else verify
-
-
-def _build_paged_draft(config, slots: int, max_blocks: int,
-                       block_size: int, K: int, draft_layers: int,
-                       counts, quantized: bool = False):
-    """Paged early-exit drafter: K sequential single-token partial
-    forwards (first ``draft_layers`` blocks) through the block-table
-    gather; each drafted row's partial-layer K/V is appended beyond
-    the fill (sentinel-redirected when invalid) so the next draft can
-    attend it. The verify pass rewrites all layers of those rows
-    before any can become visible."""
-    max_len = max_blocks * block_size
-    kh, hd = config.n_kv_heads, config.head_dim
-    d = draft_layers
-
-    def _coords(tables, lens_i, active):
-        valid = active & (lens_i < max_len)
-        w = jnp.minimum(lens_i, max_len - 1)
-        blk = jnp.take_along_axis(
-            tables, (w // block_size)[:, None], axis=1
-        )[:, 0]
-        blk = jnp.where(valid, blk, SENTINEL_BLOCK)
-        off = jnp.where(valid, w % block_size, 0)
-        return blk, off
-
-    def draft(k, v, params, tables, lengths, tokens, active):
-        counts["draft"] += 1  # traces only
-        layers_d = jax.tree_util.tree_map(
-            lambda a: a[:d], params["layers"]
-        )
-        cur = tokens
-        drafts = []
-        for i in range(K):
-            lens_i = lengths + i
-            positions = lens_i[:, None]
-            x = llama.embed_tokens(config, params, cur[:, None])
-
-            def body(carry, layer_in):
-                pl, k_c, v_c = layer_in
-                k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-                v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-                y, k_new, v_new = gen_lib._layer_decode_read_only(
-                    config, pl, carry, positions, k_view, v_view,
-                    lens_i,
-                )
-                return y, (k_new, v_new)
-
-            x, (k_news, v_news) = jax.lax.scan(
-                body, x, (layers_d, k[:d], v[:d])
-            )
-            blk, off = _coords(tables, lens_i, active)
-            k = k.at[:d, blk, off].set(k_news[:, :, 0].astype(k.dtype))
-            v = v.at[:d, blk, off].set(v_news[:, :, 0].astype(v.dtype))
-            logits = llama.unembed(config, params, x)[:, 0]
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            cur = jnp.where(active, nxt, cur)
-            drafts.append(cur)
-        return k, v, jnp.stack(drafts, axis=1)
-
-    def draft_q8(k, v, ks, vs, params, tables, lengths, tokens,
-                 active):
-        from dlrover_tpu.ops.kv_quant import quantize_kv
-
-        counts["draft"] += 1  # traces only
-        layers_d = jax.tree_util.tree_map(
-            lambda a: a[:d], params["layers"]
-        )
-        cur = tokens
-        drafts = []
-        for i in range(K):
-            lens_i = lengths + i
-            positions = lens_i[:, None]
-            x = llama.embed_tokens(config, params, cur[:, None])
-
-            def body(carry, layer_in):
-                pl, k_c, v_c, ks_c, vs_c = layer_in
-                k_view = k_c[tables].reshape(slots, max_len, kh, hd)
-                v_view = v_c[tables].reshape(slots, max_len, kh, hd)
-                ks_view = ks_c[tables].reshape(slots, max_len, kh)
-                vs_view = vs_c[tables].reshape(slots, max_len, kh)
-                y, k_new, v_new = gen_lib._layer_decode_read_only(
-                    config, pl, carry, positions, k_view, v_view,
-                    lens_i, k_scale=ks_view, v_scale=vs_view,
-                )
-                return y, (k_new, v_new)
-
-            x, (k_news, v_news) = jax.lax.scan(
-                body, x, (layers_d, k[:d], v[:d], ks[:d], vs[:d])
-            )
-            blk, off = _coords(tables, lens_i, active)
-            kq, ks_rows = quantize_kv(k_news[:, :, 0])
-            vq, vs_rows = quantize_kv(v_news[:, :, 0])
-            k = k.at[:d, blk, off].set(kq)
-            v = v.at[:d, blk, off].set(vq)
-            ks = ks.at[:d, blk, off].set(ks_rows)
-            vs = vs.at[:d, blk, off].set(vs_rows)
-            logits = llama.unembed(config, params, x)[:, 0]
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            cur = jnp.where(active, nxt, cur)
-            drafts.append(cur)
-        return k, v, ks, vs, jnp.stack(drafts, axis=1)
-
-    return draft_q8 if quantized else draft
-
-
 @functools.lru_cache(maxsize=16)
 def _paged_spec_steps(
-    config: llama.TpuLMConfig, slots: int, num_blocks: int,
-    max_blocks: int, block_size: int, spec_k: int, draft_layers: int,
-    kv_dtype: str = "fp",
+    config, slots: int, num_blocks: int, max_blocks: int, block_size: int,
+    spec_k: int, draft_layers: int, kv_dtype: str = "fp",
 ) -> _PagedSpecSteps:
+    family = programs_for(config)
     counts = {"verify": 0, "draft": 0}
     quantized = kv_dtype == "int8"
     pool_args = (0, 1, 2, 3) if quantized else (0, 1)
     verify = jax.jit(
-        _build_paged_verify(config, slots, max_blocks, block_size,
-                            spec_k, counts, quantized=quantized),
+        family.build_verify(config, slots, max_blocks, block_size, spec_k,
+                            counts, quantized=quantized),
         donate_argnums=pool_args,
     )
     draft = None
     if draft_layers > 0:
         draft = jax.jit(
-            _build_paged_draft(config, slots, max_blocks, block_size,
+            family.build_draft(config, slots, max_blocks, block_size,
                                spec_k, draft_layers, counts,
                                quantized=quantized),
             donate_argnums=pool_args,
@@ -813,98 +187,118 @@ def _paged_spec_steps(
                            trace_counts=counts)
 
 
-def _paged_steps(
-    config: llama.TpuLMConfig, slots: int, num_blocks: int,
-    max_blocks: int, block_size: int, chunk: int,
-    kv_dtype: str = "fp",
-) -> _PagedSteps:
-    """Compiled once per shape key, shared across engines (the flat
-    engine's lru_cache discipline). Pools donated; tables/lengths/ids
-    all plain traced arguments. ``kv_dtype`` "int8" programs also
-    donate the scale pools. The decode and prefill programs' attention
-    (:func:`pool_attention_kind`) is part of the key."""
-    attn = pool_attention_kind(config, block_size, kv_dtype, chunk)
-    return _paged_steps_for(
-        config, slots, num_blocks, max_blocks, block_size, chunk,
-        kv_dtype, attn,
-        sparse_chunk_attention_kind(
-            config, config.compute_dtype, block_size, chunk, max_blocks
-        ) if attn == "sparse_gather" else "",
-        _latent_decode_kind(config, attn, slots, block_size, max_blocks),
-        _conv_decode_kind(config, attn, slots, block_size, max_blocks),
-        _conv_chunk_kind(config, attn, block_size, max_blocks, chunk),
+def _steps(config, slots: int, num_blocks: int, max_blocks: int,
+           block_size: int, chunk: int, kv_dtype: str = "fp",
+           group_blocks: tuple = ()) -> _PagedSteps:
+    """The programs of ``config``'s family (``kvpool/families.py``),
+    compiled once per shape key and shared across engines (the flat
+    engine's lru_cache discipline). What each of their parts runs (the
+    family's ``kinds``) is asked here and is part of the key.
+    ``group_blocks``: the reach groups' block counts (a pool in groups)."""
+    kinds = programs_for(config).kinds(
+        config, pool_layout.pool_arrays(config, kv_dtype)[0].dtype,
+        block_size, chunk, slots, max_blocks,
+    )
+    return _steps_for(
+        config, slots, num_blocks, max_blocks, block_size, chunk, kv_dtype,
+        group_blocks, tuple(sorted(kinds.items())),
     )
 
 
-@functools.lru_cache(maxsize=16)
-def _paged_steps_for(
-    config: llama.TpuLMConfig, slots: int, num_blocks: int,
-    max_blocks: int, block_size: int, chunk: int, kv_dtype: str,
-    attn: str, sparse_chunk: str = "", latent_decode: str = "",
-    conv_decode: str = "", conv_chunk: str = "",
-) -> _PagedSteps:
+@functools.lru_cache(maxsize=64)
+def _steps_for(config, slots: int, num_blocks: int, max_blocks: int,
+               block_size: int, chunk: int, kv_dtype: str,
+               group_blocks: tuple, kinds: tuple) -> _PagedSteps:
+    family, named = programs_for(config), dict(kinds)
     counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
-    quantized = kv_dtype == "int8"
     # The pools every program leads with and hands back, donated: the
-    # model's statement of what a block holds (kvpool/layout.py).
-    n_pools = len(pool_layout.pool_arrays(config, kv_dtype))
+    # model's statement of what a block holds (kvpool/layout.py) ...
+    layout = pool_layout.grouped_pool_arrays(config, kv_dtype)
+    n_pools = len(layout)
     # ... and the per-slot arrays with their snapshots, after them.
     n_state = 2 * len(pool_layout.state_arrays(config))
     pool_args = tuple(range(n_pools + n_state))
-    if attn == "conv_gathered_view":
-        # Imported here: the module builds on this one.
-        from dlrover_tpu.serving.kvpool import conv
-
-        build_decode = conv.build_decode(
-            config, slots, max_blocks, block_size, counts, conv_decode
-        )
-        build_prefill = conv.build_prefill(
-            config, max_blocks, block_size, chunk, counts, conv_chunk
-        )
-    elif attn == "latent_absorbed":
-        # Imported here: the module builds on this one.
-        from dlrover_tpu.serving.kvpool import latent
-
-        build_decode = latent.build_decode(
-            config, slots, max_blocks, block_size, counts, latent_decode
-        )
-        build_prefill = latent.build_prefill(
-            config, max_blocks, block_size, chunk, counts
-        )
-    elif attn == "sparse_gather":
-        # Imported here: the module builds on this one.
-        from dlrover_tpu.serving.kvpool import sparse
-
-        build_decode = sparse.build_decode(
-            config, slots, max_blocks, block_size, counts
-        )
-        build_prefill = sparse.build_prefill(
-            config, max_blocks, block_size, chunk, counts,
-            kind=sparse_chunk,
-        )
+    # (an int8 pool: the family's programs' int8 twins, the scale pools
+    # donated with the rest)
+    q8 = {"quantized": True} if kv_dtype == "int8" else {}
+    decode = jax.jit(family.build_decode(
+        config, slots, max_blocks, block_size, counts, named, **q8
+    ), donate_argnums=pool_args)
+    prefill = jax.jit(family.build_prefill(
+        config, max_blocks, block_size, chunk, counts, named, **q8
+    ), donate_argnums=pool_args)
+    imp = exp = None
+    if len(pool_layout.cache_groups(config)) > 1:
+        # A pool in groups copies the first group's arrays alone (the one
+        # group a block of which more than one owner can hold while it is
+        # written; the others ride through as the state arrays do), and
+        # migrates none: refused by name (kvpool/migrate.py).
+        n_first = sum(1 for a in layout if not a.group)
+        cow = _build_cow_copy(counts, n_first, n_pools - n_first)
     else:
-        build_decode = _build_paged_decode(
-            config, slots, max_blocks, block_size, counts,
-            quantized=quantized, attn=attn,
+        cow = _build_cow_copy(counts, n_pools, n_state)
+        imp = jax.jit(
+            _build_import_scatter(counts, n_pools, n_state),
+            donate_argnums=pool_args,
         )
-        build_prefill = _build_paged_prefill(
-            config, max_blocks, block_size, chunk, counts,
-            quantized=quantized, attn=attn,
+        # No donation: export reads the pools and the source keeps
+        # serving from them until the importer acks.
+        exp = jax.jit(_build_export_gather(counts, n_pools, n_state))
+    return _PagedSteps(
+        prefill, decode, jax.jit(cow, donate_argnums=pool_args), imp, exp,
+        counts, named.get("pool_attention", family.POOL_ATTENTION),
+        kinds,
+    )
+
+
+class _StateSteps(NamedTuple):
+    restore: object
+    get: object
+    put: object
+    trace_counts: Dict[str, int]
+
+
+@functools.lru_cache(maxsize=4)
+def _state_steps(n_state: int) -> _StateSteps:
+    """The programs that move a SLOT's state, for ``n_state`` per-slot
+    arrays (``kvpool/layout.py``), whatever they hold: ``restore(*state,
+    *snapshots, slot, snapshot)`` sets the slot's state to a snapshot's
+    (snapshot 0, the sentinel: to zeros) and hands back both tuples;
+    ``get(*state, slot)`` / ``put(*state, *rows, slot)`` read and write
+    one slot's (migration). Slot and snapshot are traced scalars: no
+    admission retraces."""
+    counts = {"state_restore": 0, "state_get": 0, "state_put": 0}
+
+    def restore(*args):
+        counts["state_restore"] += 1  # traces only
+        state, snaps = args[:n_state], args[n_state:2 * n_state]
+        slot, snapshot = args[2 * n_state:]
+        with jax.named_scope("state"), jax.named_scope("restore"):
+            return tuple(
+                s.at[:, slot].set(
+                    jnp.where(snapshot > 0, p[:, snapshot], 0).astype(s.dtype)
+                )
+                for s, p in zip(state, snaps)
+            ) + tuple(snaps)
+
+    def get(*args):
+        counts["state_get"] += 1  # traces only
+        return tuple(s[:, args[n_state]] for s in args[:n_state])
+
+    def put(*args):
+        counts["state_put"] += 1  # traces only
+        state, rows = args[:n_state], args[n_state:2 * n_state]
+        return tuple(
+            s.at[:, args[2 * n_state]].set(r.astype(s.dtype))
+            for s, r in zip(state, rows)
         )
-    decode = jax.jit(build_decode, donate_argnums=pool_args)
-    prefill = jax.jit(build_prefill, donate_argnums=pool_args)
-    cow = jax.jit(
-        _build_cow_copy(counts, n_pools, n_state), donate_argnums=pool_args
+
+    return _StateSteps(
+        jax.jit(restore, donate_argnums=tuple(range(2 * n_state))),
+        jax.jit(get),
+        jax.jit(put, donate_argnums=tuple(range(n_state))),
+        counts,
     )
-    imp = jax.jit(
-        _build_import_scatter(counts, n_pools, n_state),
-        donate_argnums=pool_args,
-    )
-    # No donation: export reads the pools and the source keeps serving
-    # from them until the importer acks.
-    exp = jax.jit(_build_export_gather(counts, n_pools, n_state))
-    return _PagedSteps(prefill, decode, cow, imp, exp, counts, attn,
-                       sparse_chunk, latent_decode, conv_decode, conv_chunk)
 
 
 class PagedServingEngine(ServingEngine):
@@ -923,7 +317,7 @@ class PagedServingEngine(ServingEngine):
 
     def __init__(
         self,
-        config: llama.TpuLMConfig,
+        config,
         params,
         slots: int,
         max_len: int,
@@ -967,6 +361,8 @@ class PagedServingEngine(ServingEngine):
         # (the base constructor marks its own phases into it).
         build = _PhaseMarks(time.monotonic())
         self.kv_cache_dtype = kv_cache_dtype
+        # The module that holds this model's programs (kvpool/families.py).
+        self._family = programs_for(config)
         # What a block holds (kvpool/layout.py), and the device arrays
         # by name; ``_pools()`` is their tuple in the layout's order.
         # The pool's groups (one, unless the config states more): the
@@ -1033,8 +429,10 @@ class PagedServingEngine(ServingEngine):
             )
         self.num_blocks = num_blocks
         self._allocator = BlockAllocator(num_blocks, reserved=1)
-        if _is_linear(config):
-            _linear_module().check_shapes(config, block_size, prefill_chunk)
+        # What the family's programs are not built for, refused by name.
+        getattr(self._family, "check_shapes", lambda *a: None)(
+            config, block_size, prefill_chunk
+        )
         self.state_snapshots = (
             self._snapshot_budget(state_snapshots, slots)
             if self._state_layout and prefix_cache else 0
@@ -1094,41 +492,24 @@ class PagedServingEngine(ServingEngine):
         # The base __init__ bound the FLAT step programs (never traced
         # — jit is lazy); swap in the paged programs, keyed on the
         # paged shapes, and re-settle the retrace snapshot.
-        self._steps = (
-            _grouped_steps(
-                config, slots, self.max_blocks, block_size, prefill_chunk,
-                tuple(g.num_blocks for g in self._reach_groups),
-            ) if self._reach_groups else _linear_steps(
-                config, slots, self.max_blocks, block_size, prefill_chunk,
-            ) if _is_linear(config) else _delta_steps(
-                config, slots, self.max_blocks, block_size, prefill_chunk,
-            ) if _is_delta(config) else _paged_steps(
-                config, slots, self.num_blocks, self.max_blocks,
-                block_size, prefill_chunk, kv_dtype=kv_cache_dtype,
-            )
+        self._steps = _steps(
+            config, slots, self.num_blocks, self.max_blocks, block_size,
+            prefill_chunk, kv_cache_dtype,
+            tuple(g.num_blocks for g in self._reach_groups),
         )
-        rows_by = (self.latent_decode_attention or self.conv_decode_attention
-                   or self.window_decode_attention)
-        if self.linear_kinds:
-            rows_by = ", ".join(
-                f"{name} {kind}" for name, kind in self.linear_kinds.items()
-            )
-        if self.conv_chunk_attention or self.window_chunk_attention:
-            rows_by += ", the chunk's by " + (
-                self.conv_chunk_attention or self.window_chunk_attention
-            )
         logger.info(
             "paged engine: %d slots x %d rows, %d blocks of %d "
             "(%s KV%s), a block holds %s; decode and prefill attention "
-            "%s%s%s%s%s",
+            "%s%s%s%s",
             slots, max_len, self.num_blocks, block_size, kv_cache_dtype,
             f" + index keys [{self._index_dim}], "
             f"{self.index_tokens_per_row} to a row, top-"
             f"{config.index_topk}" if self._index_dim else "",
             self._block_holds(), self.pool_attention,
-            ", the chunk under its selection by "
-            f"{self.sparse_chunk_attention}" if self._index_dim else "",
-            f", the decode step's rows by {rows_by}" if rows_by else "",
+            "".join(
+                f", {name} {kind}" for name, kind in self.kinds.items()
+                if name != "pool_attention"
+            ),
             "; a slot holds " + ", ".join(
                 a.describe() for a in self._state_layout
             ) + f", {self.state_snapshots} snapshots"
@@ -1232,18 +613,25 @@ class PagedServingEngine(ServingEngine):
 
     @property
     def pool_attention(self) -> str:
-        """``"paged_kernel"`` or ``"xla_gather"``: what the plain decode
-        and prefill programs were built with
-        (:func:`pool_attention_kind`)."""
+        """The name of the programs' definition (the family's
+        ``POOL_ATTENTION``; a dense model's ``"paged_kernel"`` or
+        ``"xla_gather"``, by shape)."""
         return self._steps.pool_attention
 
     @property
-    def sparse_chunk_attention(self) -> str:
-        """``"chunk_kernel"`` or ``"masked_attention"``: what a sparse
-        model's prefill program attends with under its selection
-        (:func:`sparse_chunk_attention_kind`); ``""`` for a dense
-        model."""
-        return self._steps.sparse_chunk_attention
+    def kinds(self) -> Dict[str, str]:
+        """What each part of this engine's programs runs, by the names
+        ``kv_stats()`` prints (the family's ``kinds``: decided from the
+        platform, the pool's dtype and the shapes; part of the programs'
+        cache key)."""
+        return dict(self._steps.kinds)
+
+    # Read by name in benchmark/runners (serve_delta.py, serve_window.py):
+    # retired with the adapter block at this file's end.
+    linear_kinds = kinds
+    window_decode_attention = property(
+        lambda self: self.kinds.get("window_decode_attention", "")
+    )
 
     def _fresh_arrays(self) -> Dict[str, object]:
         """Every array of the pool, zeroed: ONE rebuild site for all of
@@ -1618,6 +1006,10 @@ class PagedServingEngine(ServingEngine):
         self._prepare_reach_groups(req, start, start + n_valid)
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
+        scored = getattr(self._family, "chunk_rows_scored", None)
+        if scored is not None:
+            self._chunk_rows_launched += c
+            self._chunk_rows_scored += scored(n_valid, c, self.kinds)
         self._mark_prefill_prep(n_valid, start + n_valid)
         *pools, first = self._steps.prefill(
             *self._pools(), self._params, jnp.asarray(chunk),
@@ -1737,32 +1129,6 @@ class PagedServingEngine(ServingEngine):
 
     # ---- observability -----------------------------------------------------
 
-    @property
-    def latent_decode_attention(self) -> str:
-        """``"pool_kernel"`` or ``"gathered_view"``: what a latent
-        model's decode program reads its cached rows with
-        (``latent.decode_attention_kind``); ``""`` for any other model.
-        (Not beside :attr:`pool_attention`: see
-        :func:`_latent_decode_kind`.)"""
-        return self._steps.latent_decode_attention
-
-    @property
-    def conv_decode_attention(self) -> str:
-        """The same for a convolution / attention pattern model's decode
-        program (``conv.decode_attention_kind``); ``""`` for any other
-        model. Its prefill chunk reads a gathered view either way, which
-        is what :attr:`pool_attention` (``conv_gathered_view``) names."""
-        return self._steps.conv_decode_attention
-
-    @property
-    def conv_chunk_attention(self) -> str:
-        """The same for that model's prefill chunk
-        (``conv.chunk_attention_kind``): ``pool_kernel`` where the chunk
-        reads the pool in place a tile of tokens at a time, scoring only
-        the tiles that hold a valid row, ``gathered_view`` where it runs
-        its definition; ``""`` for any other model."""
-        return self._steps.conv_chunk_attention
-
     def kv_stats(self) -> Dict[str, object]:
         """Allocator + prefix-cache accounting (heartbeats, SignalBus,
         bench, the chaos block-reclaim invariant)."""
@@ -1772,6 +1138,12 @@ class PagedServingEngine(ServingEngine):
         )
         stats["cow_copies"] = self._allocator.cow_copies_total
         stats["pool_attention"] = self.pool_attention
+        # What each part of the programs runs, and what this family alone
+        # reports (kvpool/families.py).
+        stats.update(self.kinds)
+        stats.update(
+            getattr(self._family, "pool_stats", lambda engine: {})(self)
+        )
         stats["kv_layers"] = pool_layout.pool_layers(self.config)
         stats["state_layers"] = sum(a.layers for a in self._state_layout)
         if self._state_layout:
@@ -1793,7 +1165,6 @@ class PagedServingEngine(ServingEngine):
             stats["prefix_rounded_down_blocks"] = (
                 self._prefix_rounded_down_blocks
             )
-            stats["moe_rows_dropped"] = self._moe_rows_dropped
         if self._reach_groups:
             stats["groups"] = {
                 name: dict(
@@ -1813,41 +1184,9 @@ class PagedServingEngine(ServingEngine):
             stats["window_blocks_released_total"] = sum(
                 g.released_total for g in self._reach_groups
             )
-            stats["window_decode_attention"] = self.window_decode_attention
-            stats["window_chunk_attention"] = self.window_chunk_attention
             stats["prefix_rounded_down_blocks"] = (
                 self._prefix_rounded_down_blocks
             )
-            stats["moe_rows_dropped"] = self._moe_rows_dropped
-        if _is_delta(self.config):
-            # What each of the four parts runs, and each state array's
-            # bytes a slot (a snapshot): the pair is one state.
-            stats.update(self.linear_kinds)
-            stats["state_array_bytes"] = {
-                a.name: a.entry_bytes() for a in self._state_layout
-            }
-        elif self.linear_kinds:
-            # The array at a stride: its share of the bytes above and
-            # the whole array's size; and what each of the five runs.
-            per_block = self._array_block_bytes["ckeys"]
-            stats["ckey_bytes_in_use"] = (
-                (stats["used"] + stats["cached"]) * per_block
-            )
-            stats["ckey_bytes"] = self.num_blocks * per_block
-            stats.update(self.linear_kinds)
-            # What the decode step's selection would copy for the slots
-            # now active, from the host's tables (never on the step path).
-            at = [r.slot for r in self.scheduler.active()]
-            stats.update(_linear_module().ckey_copy_stats(
-                self.config, self._tables[at], self._lengths[at]
-            ))
-        if self.conv_decode_attention:
-            stats["conv_decode_attention"] = self.conv_decode_attention
-            stats["conv_chunk_attention"] = self.conv_chunk_attention
-            # Token rows the chunks launched carried, and those their
-            # attention scored (the kernel skips the tiles of padding).
-            stats["conv_chunk_rows_launched"] = self._chunk_rows_launched
-            stats["conv_chunk_rows_scored"] = self._chunk_rows_scored
         # What a restart pays before the first request: construction
         # and warm-up as the engine timed them (0.0: not warmed up).
         stats["engine_build_s"] = self.engine_build_s
@@ -1861,28 +1200,19 @@ class PagedServingEngine(ServingEngine):
             )
             stats["index_pool_bytes"] = self.num_blocks * per_block
             stats["index_tokens_per_row"] = self.index_tokens_per_row
-            stats["moe_rows_dropped"] = self._moe_rows_dropped
-            stats["sparse_chunk_attention"] = self.sparse_chunk_attention
         if self._latent is not None:
-            # The one array of a latent model: its bytes in use, the
-            # whole array's size, and one token's row of one layer.
+            # The one array of a latent model: its bytes in use and the
+            # whole array's size.
             per_block = self._array_block_bytes["latent"]
             stats["latent_bytes_in_use"] = (
                 (stats["used"] + stats["cached"]) * per_block
             )
             stats["latent_pool_bytes"] = self.num_blocks * per_block
-            stats["latent_row_bytes"] = per_block // (
-                self.config.n_layers * self.block_size
-            )
+        if (self._state_layout or self._reach_groups or self._index_dim
+                or self._latent is not None):
+            # (every layout but K and V alone in one group: those models'
+            # decode steps hand back their expert counts)
             stats["moe_rows_dropped"] = self._moe_rows_dropped
-            # Imported here: the module builds on this one.
-            from dlrover_tpu.serving.kvpool import latent
-
-            stats["latent_chunk_attention"] = latent.CHUNK_ATTENTION
-            stats["latent_chunk_query_rows"] = latent.chunk_query_rows(
-                self.prefill_chunk
-            )
-            stats["latent_decode_attention"] = self.latent_decode_attention
         if self._cache is not None:
             for key, value in self._cache.stats().items():
                 stats[f"prefix_{key}"] = value
@@ -1944,18 +1274,6 @@ class PagedServingEngine(ServingEngine):
     # ---- layer groups (kvpool/layout.py, kvpool/groups.py) -----------------
 
     @property
-    def window_decode_attention(self) -> str:
-        """``"pool_kernel"`` or ``"gathered_view"``: what the decode
-        program of a model whose pool is in groups reads its rows with
-        (``window.decode_attention_kind``); ``""`` for any other."""
-        return getattr(self._steps, "window_decode_attention", "")
-
-    @property
-    def window_chunk_attention(self) -> str:
-        """The same for its prefill chunk."""
-        return getattr(self._steps, "window_chunk_attention", "")
-
-    @property
     def _program_tables(self) -> np.ndarray:
         """The host mirror of what the programs take as tables: the one
         ``[slots, max_blocks]`` table, or every group's stacked."""
@@ -1966,12 +1284,6 @@ class PagedServingEngine(ServingEngine):
         """One :class:`ReachGroup` a group of the pool after the first.
         ``window_blocks`` sizes each (None: every slot's band and chunk,
         and as much again for cached prompts' tails)."""
-        # Imported here: nothing else of this module needs it.
-        from dlrover_tpu.serving.kvpool.groups import (
-            ReachGroup,
-            band_blocks,
-        )
-
         out = []
         for i, spec in enumerate(self._groups[1:], start=1):
             per_slot = band_blocks(
@@ -2093,12 +1405,9 @@ class PagedServingEngine(ServingEngine):
             self._step_trace.counts["window_rows"] = self._window_rows(
                 r.slot for r in decoding
             )
-        if self.linear_kinds and self._step_trace is not None:
-            module = (
-                _delta_module() if _is_delta(self.config)
-                else _linear_module()
-            )
-            self._step_trace.counts.update(module.decode_counts(
+        counts = getattr(self._family, "decode_counts", None)
+        if counts is not None and self._step_trace is not None:
+            self._step_trace.counts.update(counts(
                 self.config, [int(self._lengths[r.slot]) for r in decoding]
             ))
 
@@ -2169,12 +1478,6 @@ class PagedServingEngine(ServingEngine):
             *state, *(jnp.asarray(r) for r in rows), np.int32(slot)
         ) + snaps)
 
-    @property
-    def linear_kinds(self) -> Dict[str, str]:
-        """What each of a lightning / block-sparse model's five parts
-        runs (``linear.kinds``), by name; ``{}`` for any other model."""
-        return dict(getattr(self._steps, "linear_kinds", ()))
-
     def _snapshot_budget(self, stated: Optional[int], slots: int) -> int:
         """Snapshot ids this engine keeps (``kvpool/layout.py``): the
         count its caller states; else one a cached block while a
@@ -2205,15 +1508,6 @@ class PagedServingEngine(ServingEngine):
         at its last whole-block boundary, in the chunk that holds it."""
         if not self._state_layout:
             return ()
-        if self.conv_chunk_attention:
-            # (counted here, the one thing every chunk launch of such a
-            # model calls below the programs' call sites: D15)
-            from dlrover_tpu.serving.kvpool import conv
-
-            self._chunk_rows_launched += self.prefill_chunk
-            self._chunk_rows_scored += conv.chunk_rows_scored(
-                n_valid, self.prefill_chunk, self.conv_chunk_attention
-            )
         boundary = req.prompt_len // self.block_size * self.block_size
         snap_at = snap_id = 0
         # Exactly one chunk of a prompt has the boundary past its first
@@ -2242,296 +1536,39 @@ class PagedServingEngine(ServingEngine):
         return np.int32(req.slot), np.int32(snap_at), np.int32(snap_id)
 
 
-def _latent_decode_kind(config, attn: str, slots: int, block_size: int,
-                        max_blocks: int) -> str:
-    """``latent.decode_attention_kind`` for a latent model's programs,
-    ``""`` for any other's: the part of :func:`_paged_steps`'s key that
-    says what the decode step reads its cached rows with. Down here, the
-    module imported here: ``kvpool/latent.py`` builds on this one, and
-    no line above, where the dense and the sparse programs' kernels are
-    called from, moves for it (their compiled kernels carry those line
-    numbers: PERF.md section 6, PRs 35 and 40)."""
-    if attn != "latent_absorbed":
-        return ""
-    from dlrover_tpu.serving.kvpool import latent
 
-    return latent.decode_attention_kind(
-        config, config.compute_dtype, block_size, max_blocks, slots
-    )
-
-
-def _conv_decode_kind(config, attn: str, slots: int, block_size: int,
-                      max_blocks: int) -> str:
-    """``conv.decode_attention_kind`` for a convolution / attention
-    pattern model's programs, ``""`` for any other's: its part of
-    :func:`_paged_steps`'s key, as :func:`_latent_decode_kind` is the
-    latent model's, down here for the same reasons."""
-    if attn != "conv_gathered_view":
-        return ""
-    from dlrover_tpu.serving.kvpool import conv
-
-    return conv.decode_attention_kind(
-        config, config.compute_dtype, block_size, max_blocks, slots
-    )
+# ---- adapters: the names benchmark/ and tests/benchmark/ pin ----------------
+#
+# Each is a line or two over :func:`_steps`; a ``benchmark`` PR that moves
+# those files onto ``_steps`` / ``engine.kinds`` retires the block (ROADMAP
+# D12), with the three properties of :class:`_PagedSteps` and the engine's
+# ``linear_kinds`` / ``window_decode_attention``. Who reads what:
+# ``_paged_steps`` rehearse.py, rehearse_keye.py, rehearse_xing.py,
+# rehearse_lfm2.py, tools/program_hashes.py; ``_grouped_steps``
+# rehearse_mellum2.py; ``_linear_steps`` rehearse_sala.py, controls_sala.py;
+# ``_delta_steps`` rehearse_olmo_hybrid.py; the ``_*_steps_for`` (their
+# ``.cache_clear()``) controls_sala.py and tests/benchmark/test_keye.py,
+# test_xing.py, test_lfm2.py, test_mellum2.py. (``_state_steps`` and
+# ``PagedServingEngine._chunk_state_args`` above are pinned too:
+# controls_lfm2.py, controls_sala.py, controls_olmo_hybrid.py.)
 
 
-def _conv_chunk_kind(config, attn: str, block_size: int, max_blocks: int,
-                     chunk: int) -> str:
-    """``conv.chunk_attention_kind`` likewise: what that model's prefill
-    chunk reads its slot's rows with."""
-    if attn != "conv_gathered_view":
-        return ""
-    from dlrover_tpu.serving.kvpool import conv
-
-    return conv.chunk_attention_kind(
-        config, config.compute_dtype, block_size, max_blocks, chunk
-    )
-
-
-class _StateSteps(NamedTuple):
-    restore: object
-    get: object
-    put: object
-    trace_counts: Dict[str, int]
-
-
-@functools.lru_cache(maxsize=4)
-def _state_steps(n_state: int) -> _StateSteps:
-    """The programs that move a SLOT's state, for ``n_state`` per-slot
-    arrays (``kvpool/layout.py``), whatever they hold: ``restore(*state,
-    *snapshots, slot, snapshot)`` sets the slot's state to a snapshot's
-    (snapshot 0, the sentinel: to zeros) and hands back both tuples;
-    ``get(*state, slot)`` / ``put(*state, *rows, slot)`` read and write
-    one slot's (migration). Slot and snapshot are traced scalars: no
-    admission retraces."""
-    counts = {"state_restore": 0, "state_get": 0, "state_put": 0}
-
-    def restore(*args):
-        counts["state_restore"] += 1  # traces only
-        state, snaps = args[:n_state], args[n_state:2 * n_state]
-        slot, snapshot = args[2 * n_state:]
-        with jax.named_scope("state"), jax.named_scope("restore"):
-            return tuple(
-                s.at[:, slot].set(
-                    jnp.where(snapshot > 0, p[:, snapshot], 0).astype(s.dtype)
-                )
-                for s, p in zip(state, snaps)
-            ) + tuple(snaps)
-
-    def get(*args):
-        counts["state_get"] += 1  # traces only
-        return tuple(s[:, args[n_state]] for s in args[:n_state])
-
-    def put(*args):
-        counts["state_put"] += 1  # traces only
-        state, rows = args[:n_state], args[n_state:2 * n_state]
-        return tuple(
-            s.at[:, args[2 * n_state]].set(r.astype(s.dtype))
-            for s, r in zip(state, rows)
-        )
-
-    return _StateSteps(
-        jax.jit(restore, donate_argnums=tuple(range(2 * n_state))),
-        jax.jit(get),
-        jax.jit(put, donate_argnums=tuple(range(n_state))),
-        counts,
-    )
-
-
-class _GroupedSteps(NamedTuple):
-    """:class:`_PagedSteps` for a pool in groups: the same fields (the
-    engine reads both by name), and what the two programs read their rows
-    with. ``imp`` / ``exp`` are None: migration is refused by name."""
-
-    prefill: object
-    decode: object
-    cow: object
-    imp: object
-    exp: object
-    trace_counts: Dict[str, int]
-    pool_attention: str = "window_groups"
-    sparse_chunk_attention: str = ""
-    latent_decode_attention: str = ""
-    conv_decode_attention: str = ""
-    conv_chunk_attention: str = ""
-    window_decode_attention: str = ""
-    window_chunk_attention: str = ""
+def _paged_steps(config, slots: int, num_blocks: int, max_blocks: int,
+                 block_size: int, chunk: int, kv_dtype: str = "fp"):
+    return _steps(config, slots, num_blocks, max_blocks, block_size, chunk,
+                  kv_dtype)
 
 
 def _grouped_steps(config, slots: int, max_blocks: int, block_size: int,
-                   chunk: int, group_blocks) -> _GroupedSteps:
-    """The programs of a model whose pool is in groups
-    (``kvpool/window.py``), keyed like :func:`_paged_steps` and by what
-    their attention reads its rows with. Down here for
-    :func:`_latent_decode_kind`'s reasons."""
-    from dlrover_tpu.serving.kvpool import window
-
-    args = (config, config.compute_dtype, block_size, max_blocks, slots,
-            chunk)
-    return _grouped_steps_for(
-        config, slots, max_blocks, block_size, chunk, group_blocks,
-        window.decode_attention_kind(*args),
-        window.chunk_attention_kind(*args),
-    )
-
-
-@functools.lru_cache(maxsize=8)
-def _grouped_steps_for(config, slots: int, max_blocks: int, block_size: int,
-                       chunk: int, group_blocks, decode_kind: str,
-                       chunk_kind: str) -> _GroupedSteps:
-    from dlrover_tpu.serving.kvpool import window
-
-    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
-    layout = pool_layout.grouped_pool_arrays(config)
-    n_first = sum(1 for a in layout if not a.group)
-    pool_args = tuple(range(len(layout)))
-
-    def cow(*args):
-        # The first group's arrays alone: the one group a block of which
-        # more than one owner can hold while it is written.
-        counts["cow"] += 1  # traces only
-        pools, (src, dst) = args[:len(layout)], args[len(layout):]
-        return tuple(
-            p.at[:, dst].set(p[:, src]) for p in pools[:n_first]
-        ) + tuple(pools[n_first:])
-
-    return _GroupedSteps(
-        jax.jit(window.build_prefill(
-            config, max_blocks, block_size, chunk, counts, chunk_kind
-        ), donate_argnums=pool_args),
-        jax.jit(window.build_decode(
-            config, slots, max_blocks, block_size, counts, decode_kind
-        ), donate_argnums=pool_args),
-        jax.jit(cow, donate_argnums=pool_args),
-        None, None, counts,
-        window_decode_attention=decode_kind,
-        window_chunk_attention=chunk_kind,
-    )
-
-
-# ---- lightning / block-sparse layers (kvpool/linear.py) ---------------------
-#
-# Down here for :func:`_latent_decode_kind`'s reasons.
-
-
-def _is_linear(config) -> bool:
-    return getattr(config, "kind", "") == "linear_sparse_lm"
-
-
-def _linear_module():
-    # Imported here: the module builds on this one.
-    from dlrover_tpu.serving.kvpool import linear
-
-    return linear
-
-
-class _LinearSteps(NamedTuple):
-    """:class:`_PagedSteps` for a model of lightning and block-sparse
-    layers: the same fields (the engine reads both by name), and what
-    each of its five parts runs."""
-
-    prefill: object
-    decode: object
-    cow: object
-    imp: object
-    exp: object
-    trace_counts: Dict[str, int]
-    pool_attention: str = "linear_block_lists"
-    sparse_chunk_attention: str = ""
-    latent_decode_attention: str = ""
-    conv_decode_attention: str = ""
-    conv_chunk_attention: str = ""
-    linear_kinds: tuple = ()
+                   chunk: int, group_blocks):
+    return _steps(config, slots, 0, max_blocks, block_size, chunk,
+                  group_blocks=tuple(group_blocks))
 
 
 def _linear_steps(config, slots: int, max_blocks: int, block_size: int,
-                  chunk: int) -> _LinearSteps:
-    """The programs of ``kvpool/linear.py``, keyed like
-    :func:`_paged_steps` and by what their parts run."""
-    kinds = _linear_module().kinds(
-        config, config.compute_dtype, block_size, slots, max_blocks
-    )
-    return _linear_steps_for(
-        config, slots, max_blocks, block_size, chunk,
-        tuple(sorted(kinds.items())),
-    )
+                  chunk: int):
+    return _steps(config, slots, 0, max_blocks, block_size, chunk)
 
 
-@functools.lru_cache(maxsize=8)
-def _linear_steps_for(config, slots: int, max_blocks: int, block_size: int,
-                      chunk: int, kinds) -> _LinearSteps:
-    linear = _linear_module()
-    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
-    n_pools = len(pool_layout.pool_arrays(config))
-    n_state = 2 * len(pool_layout.state_arrays(config))
-    pool_args = tuple(range(n_pools + n_state))
-    return _LinearSteps(
-        jax.jit(linear.build_prefill(
-            config, max_blocks, block_size, chunk, counts
-        ), donate_argnums=pool_args),
-        jax.jit(linear.build_decode(
-            config, slots, max_blocks, block_size, counts,
-            dict(kinds)["block_decode_attention"],
-            dict(kinds)["lightning_decode"],
-            dict(kinds)["block_select"],
-        ), donate_argnums=pool_args),
-        jax.jit(_build_cow_copy(counts, n_pools, n_state),
-                donate_argnums=pool_args),
-        jax.jit(_build_import_scatter(counts, n_pools, n_state),
-                donate_argnums=pool_args),
-        jax.jit(_build_export_gather(counts, n_pools, n_state)),
-        counts, linear_kinds=kinds,
-    )
-
-
-# ---- delta-rule / full-attention layers (kvpool/delta.py) -------------------
-
-
-def _is_delta(config) -> bool:
-    return getattr(config, "kind", "") == "delta_lm"
-
-
-def _delta_module():
-    # Imported here: the module builds on this one.
-    from dlrover_tpu.serving.kvpool import delta
-
-    return delta
-
-
-def _delta_steps(config, slots: int, max_blocks: int, block_size: int,
-                 chunk: int) -> _LinearSteps:
-    """The programs of ``kvpool/delta.py``, keyed like
-    :func:`_paged_steps` and by what their parts run; what they run is
-    reported through ``linear_kinds``, the field the steps tuples have
-    for it."""
-    kinds = _delta_module().kinds(
-        config, config.compute_dtype, block_size, chunk, slots, max_blocks
-    )
-    return _delta_steps_for(
-        config, slots, max_blocks, block_size, chunk,
-        tuple(sorted(kinds.items())),
-    )
-
-
-@functools.lru_cache(maxsize=8)
-def _delta_steps_for(config, slots: int, max_blocks: int, block_size: int,
-                     chunk: int, kinds) -> _LinearSteps:
-    delta = _delta_module()
-    counts = {"prefill": 0, "decode": 0, "cow": 0, "imp": 0, "exp": 0}
-    n_pools = len(pool_layout.pool_arrays(config))
-    n_state = 2 * len(pool_layout.state_arrays(config))
-    pool_args = tuple(range(n_pools + n_state))
-    return _LinearSteps(
-        jax.jit(delta.build_prefill(
-            config, max_blocks, block_size, chunk, counts, kinds
-        ), donate_argnums=pool_args),
-        jax.jit(delta.build_decode(
-            config, slots, max_blocks, block_size, counts, kinds
-        ), donate_argnums=pool_args),
-        jax.jit(_build_cow_copy(counts, n_pools, n_state),
-                donate_argnums=pool_args),
-        jax.jit(_build_import_scatter(counts, n_pools, n_state),
-                donate_argnums=pool_args),
-        jax.jit(_build_export_gather(counts, n_pools, n_state)),
-        counts, pool_attention="delta_state_and_pages", linear_kinds=kinds,
-    )
+_delta_steps = _linear_steps
+_paged_steps_for = _grouped_steps_for = _linear_steps_for = _steps_for

@@ -26,8 +26,7 @@ from typing import Dict, List, Set
 import numpy as np
 
 from dlrover_tpu.serving.kvpool.allocator import BlockAllocator
-
-SENTINEL_BLOCK = 0
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 
 
 def band_blocks(reach: int, block_size: int, rows: int, chunk: int) -> int:

@@ -51,8 +51,8 @@ import jax.numpy as jnp
 from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import latent_lm
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.index_pool import tokens_per_row
 
 # Prefix rows a prefill chunk scores at a time, and query rows a TILE of
@@ -97,13 +97,13 @@ def decode_attention_kind(config, pool_dtype, block_size: int,
     whole tiles, buffers inside the VMEM it asks for, the ``slots``
     tables inside the scalar memory — and ``"gathered_view"``, the
     definition, over every slot's whole table gathered, everywhere else.
-    Decided by what the code can see, like ``engine.pool_attention_kind``
+    Decided by what the code can see, like ``dense.pool_attention_kind``
     and for its reasons: no option, nothing falls back after it, so what
     it admits has to compile (``tests/test_tpu_compile.py`` holds it to
     the cell's shape). ``kv_stats()["latent_decode_attention"]`` and the
     engine's construction log line say which. The prefill chunk is not
     its business (:func:`chunk_attend` as it is)."""
-    if not paged._on_tpu():
+    if not families._on_tpu():
         return "gathered_view"
     # Pallas costs ~1.2 s to import: only a process that may run the
     # kernel pays it (the repo's idiom for ops/ kernels).
@@ -280,12 +280,13 @@ def chunk_query_rows(chunk: int) -> int:
     return chunk if chunk % CHUNK_QUERY_ROWS else CHUNK_QUERY_ROWS
 
 
-def chunk_rows_scored(n_valid, chunk: int):
+def chunk_rows_scored(n_valid, chunk: int, kinds=None):
     """Query rows a chunk of ``n_valid`` valid rows scores: its tiles up
     to the last that holds a valid row. The chunk program takes its trip
     count from this function and an account of a traced run takes its
     rows from it (over the step spans' ``prefill_tokens``), so the two
-    cannot disagree. ``n_valid``: an int, an array or a traced scalar."""
+    cannot disagree. ``n_valid``: an int, an array or a traced scalar
+    (``kinds``: the protocol's third argument; one form here)."""
     tile = chunk_query_rows(chunk)
     return -(-n_valid // tile) * tile
 
@@ -360,7 +361,7 @@ def chunk_forward(config, pool, params, tokens, table_row, start,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts, kind=None):
+                 counts, kinds=None):
     max_len = max_blocks * block_size
 
     def step(pool, params, tables, lengths, tokens, active, temps, rng,
@@ -369,7 +370,7 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
         tokens = _place_first(tokens, first, first_slot)
         logits, rows, moe = decode_forward(
             config, pool, params, tables, lengths, tokens, block_size,
-            kind=kind,
+            kind=(kinds or {}).get("latent_decode_attention"),
         )
         write = jnp.minimum(lengths, max_len - 1)
         blk = jnp.take_along_axis(
@@ -463,7 +464,7 @@ def chunk_attend(config, pool, layer, table_row, start, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts):
+                  counts, kinds=None):
     def prefill(pool, params, tokens, table_row, start, n_valid, temp,
                 rng, step_idx, last=True):
         counts["prefill"] += 1  # traces only
@@ -485,3 +486,29 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         return pool, first
 
     return prefill
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+POOL_ATTENTION = "latent_absorbed"
+
+
+def kinds(config, pool_dtype, block_size: int, chunk: int, slots: int = 0,
+          max_blocks: int = 0):
+    return {
+        "latent_decode_attention": decode_attention_kind(
+            config, pool_dtype, block_size, max_blocks, slots
+        ),
+        "latent_chunk_attention": CHUNK_ATTENTION,
+    }
+
+
+def pool_stats(engine):
+    """One token's row of one layer in bytes, and the tile of queries a
+    chunk scores by (``kv_stats()``)."""
+    return {
+        "latent_row_bytes": engine._array_block_bytes["latent"] // (
+            engine.config.n_layers * engine.block_size
+        ),
+        "latent_chunk_query_rows": chunk_query_rows(engine.prefill_chunk),
+    }

@@ -48,7 +48,7 @@ sharing, preemption, release) moves every array of this tuple and never
 asks what they are; everything that SIZES a block sums over it (an array
 at a stride counts ``block_size / stride`` rows: ``PoolArray
 .block_rows``). Only the
-programs that read and write rows (``kvpool/engine.py``'s dense ones,
+programs that read and write rows (``kvpool/dense.py``,
 ``kvpool/sparse.py``, ``kvpool/latent.py``, ``kvpool/conv.py``,
 ``kvpool/linear.py``, ``kvpool/delta.py``) know the arrays by name.
 

@@ -60,8 +60,8 @@ from dlrover_tpu.models import linear_sparse_lm as lsm
 from dlrover_tpu.ops import block_select
 from dlrover_tpu.ops import block_sparse_attention as block_ops
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.latent import (
     _softmax_add,
     _softmax_finish,
@@ -112,7 +112,7 @@ def lightning_decode_kind(config, state_dtype=jnp.float32) -> str:
     whose one slot fits the kernel's VMEM — and ``"jnp"``
     (``linear_sparse_lm.lightning_step``: an elementwise rank-1 update
     and a reduction over the state), the definition, everywhere else."""
-    if not paged._on_tpu():
+    if not families._on_tpu():
         return "jnp"
     from dlrover_tpu.ops.lightning_attention import state_kernel_supported
 
@@ -136,9 +136,11 @@ def select_kind(config, pool_dtype=None, slots: int = 0,
     and a prefill chunk's selection (the threshold mask of
     ``ops/sparse_attention.py`` over the gathered view) is ``jnp``
     everywhere."""
-    if paged._on_tpu() and max_blocks and block_select.select_kernel_supported(
-        pool_dtype, config.ckeys_per_block, config.head_dim, config.group,
-        slots, max_blocks,
+    if families._on_tpu() and max_blocks and (
+        block_select.select_kernel_supported(
+            pool_dtype, config.ckeys_per_block, config.head_dim,
+            config.group, slots, max_blocks,
+        )
     ):
         return "pool_kernel"
     return "jnp"
@@ -151,7 +153,7 @@ def decode_attention_kind(config, pool_dtype, block_size: int) -> str:
     where that kernel lowers — a TPU, a bf16 pool, a page of whole (16,
     128) tiles inside a VMEM chunk — and ``"gathered_pages"``, the
     definition, everywhere else."""
-    if not paged._on_tpu():
+    if not families._on_tpu():
         return "gathered_pages"
     if block_ops.list_kernel_supported(
         pool_dtype, block_size, config.head_dim
@@ -167,8 +169,8 @@ def chunk_attention_kind(config) -> str:
     return "masked_blocks"
 
 
-def kinds(config, pool_dtype, block_size: int, slots: int = 0,
-          max_blocks: int = 0):
+def kinds(config, pool_dtype, block_size: int, chunk: int = 0,
+          slots: int = 0, max_blocks: int = 0):
     """The five, by name, for ``kv_stats()`` (``slots``, ``max_blocks``:
     the decode step's tables; 0: no step is asked about)."""
     return {
@@ -588,12 +590,13 @@ def chunk_forward(config, kp, vp, ck, state, params, tokens, table_row,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts, kind=None, state_kind=None, select=None):
-    """``kind``, ``state_kind``, ``select``:
-    :func:`decode_attention_kind`'s, :func:`lightning_decode_kind`'s and
-    :func:`select_kind`'s answers for this shape (None: asked when the
-    step is traced)."""
-    max_len = max_blocks * block_size
+                 counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: asked when
+    the step is traced)."""
+    max_len, kinds = max_blocks * block_size, kinds or {}
+    kind, state_kind, select = (kinds.get(name) for name in (
+        "block_decode_attention", "lightning_decode", "block_select",
+    ))
     per = config.ckeys_per_block
 
     def step(kp, vp, ck, state, snaps, params, tables, lengths, tokens,
@@ -640,7 +643,7 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts):
+                  counts, kinds=None):
     check_shapes(config, block_size, chunk)
     n_touch = chunk // block_size
 
@@ -737,4 +740,25 @@ def decode_counts(config, fills):
         "ckey_rows": places * len(c.sparse_layers),
         "selected_rows": sum(rows_listed(c, f) for f in fills),
         "state_slots": len(fills),
+    }
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+POOL_ATTENTION = "linear_block_lists"
+
+
+def pool_stats(engine):
+    """The array at a stride: its share of the bytes in use and the whole
+    array's size; and what the decode step's selection would copy for the
+    slots now active, from the host's tables (never on the step path)."""
+    per_block = engine._array_block_bytes["ckeys"]
+    held = engine._allocator.stats(engine._live_block_ids())
+    at = [r.slot for r in engine.scheduler.active()]
+    return {
+        "ckey_bytes_in_use": (held["used"] + held["cached"]) * per_block,
+        "ckey_bytes": engine.num_blocks * per_block,
+        **ckey_copy_stats(
+            engine.config, engine._tables[at], engine._lengths[at]
+        ),
     }

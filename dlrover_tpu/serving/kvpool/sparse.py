@@ -20,7 +20,7 @@ reads only the selected K/V rows (``ops/sparse_attention.py``):
   sets: on a TPU in a Pallas kernel that reads K and V from the pool in
   place (``ops.decode_attention.sparse_chunk_attention``), elsewhere by
   ``masked_attention``, the definition, over the gathered views of K
-  and V (``engine.sparse_chunk_attention_kind`` says which).
+  and V (:func:`chunk_attention_kind` says which).
 
 Both are append-free like the dense in-place programs: the new rows of
 all layers land after the layer scan (one row a slot, or the chunk's
@@ -40,8 +40,8 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.models import sparse_lm
 from dlrover_tpu.ops import sparse_attention as sa
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 from dlrover_tpu.serving.kvpool.index_pool import (
     IndexKeyPool,
     gather_at_layer,
@@ -210,7 +210,7 @@ def chunk_attend(config, k, v, ki, layer, table_row, start,
     ``n_valid`` (None: none) are padding: their output is never read,
     and a block of nothing else selects nothing and is left at zero.
 
-    ``kind`` (``engine.sparse_chunk_attention_kind``; None: asked here,
+    ``kind`` (:func:`chunk_attention_kind`; None: asked here,
     of what this call can see) says what attends under the selection:
     ``"chunk_kernel"``, ``ops.decode_attention.sparse_chunk_attention``
     once for the chunk with K and V read from the pool in place, or
@@ -218,7 +218,7 @@ def chunk_attend(config, k, v, ki, layer, table_row, start,
     slot's gathered view."""
     def attend(q, k_new, v_new, q_idx, k_idx, w):
         chunk = q.shape[1]
-        how = kind or paged.sparse_chunk_attention_kind(
+        how = kind or chunk_attention_kind(
             config, k.dtype, block_size, chunk, table_row.shape[0]
         )
         sub = min(CHUNK_QUERY_BLOCK, chunk)
@@ -327,7 +327,7 @@ def chunk_forward(config, k, v, ki, params, tokens, table_row, start,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts):
+                 counts, kinds=None):
     max_len = max_blocks * block_size
 
     def step(k, v, ki, params, tables, lengths, tokens, active, temps,
@@ -360,7 +360,11 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts, kind=None):
+                  counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: asked when
+    the chunk is traced)."""
+    kind = (kinds or {}).get("sparse_chunk_attention")
+
     def land(pool, rows, table_row, start):
         # ``rows`` [L, chunk, ...] at the slot's logical rows start ...
         at = start + jnp.arange(chunk)
@@ -391,3 +395,44 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         return k, v, ki, first
 
     return prefill
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+POOL_ATTENTION = "sparse_gather"
+
+
+def chunk_attention_kind(config, pool_dtype, block_size: int, chunk: int,
+                         max_blocks: int) -> str:
+    """What the prefill chunk attends with under its selection
+    (:func:`chunk_attend`): ``"chunk_kernel"``
+    (``ops.decode_attention.sparse_chunk_attention``: K and V read from
+    the pool in place, the selection applied to the scores in VMEM)
+    where that kernel lowers — a TPU, a bf16 pool, a page that is one
+    DMA, a token tile of whole lane blocks, buffers inside the VMEM it
+    asks for — and ``"masked_attention"``, the definition, over the
+    slot's gathered views everywhere else. Decided by what the code can
+    see, like ``dense.pool_attention_kind`` and for its reasons: no
+    option, nothing falls back after it, so what it admits has to
+    compile (``tests/test_tpu_compile.py`` holds it to the cell's
+    shape). The decode step is not its business: that one gathers the
+    selected rows whatever this says."""
+    if not families._on_tpu():
+        return "masked_attention"
+    from dlrover_tpu.ops.decode_attention import (
+        sparse_chunk_kernel_supported,
+    )
+
+    if sparse_chunk_kernel_supported(
+        pool_dtype, block_size, config.n_heads, config.n_kv_heads,
+        config.head_dim, chunk, max_blocks,
+    ):
+        return "chunk_kernel"
+    return "masked_attention"
+
+
+def kinds(config, pool_dtype, block_size: int, chunk: int, slots: int = 0,
+          max_blocks: int = 0):
+    return {"sparse_chunk_attention": chunk_attention_kind(
+        config, pool_dtype, block_size, chunk, max_blocks
+    )}

@@ -19,10 +19,10 @@ window_attention.py``), so what a released entry names is never read.
 Both programs are append-free: a layer's attention reads the group's
 pool in place through the table and takes the new tokens' own K/V from
 the layer's hands; the new rows of every layer land in their group after
-the layer loop. Where :func:`decode_attention_kind` /
-:func:`chunk_attention_kind` answer ``pool_kernel`` (a TPU at the cell's
-shape) the full layers call ``ops.decode_attention``'s accepted kernels
-and the window layers ``ops.window_attention``'s; everywhere else both
+the layer loop. Where :func:`kinds` answers ``pool_kernel`` (a TPU at
+the cell's shape) the full layers call ``ops.decode_attention``'s
+accepted kernels and the window layers ``ops.window_attention``'s;
+everywhere else both
 run the gathered ``jax.numpy`` form (``window_attention.
 window_reference``), the definition. Chunk starts are BLOCK-aligned, not
 chunk-aligned: a prefix hit resumes at its boundary.
@@ -40,8 +40,8 @@ from dlrover_tpu.models import generate as gen_lib
 from dlrover_tpu.models import window_lm
 from dlrover_tpu.ops.window_attention import window_reference
 from dlrover_tpu.serving.engine import _place_first
-from dlrover_tpu.serving.kvpool import engine as paged
-from dlrover_tpu.serving.kvpool.engine import SENTINEL_BLOCK
+from dlrover_tpu.serving.kvpool import families
+from dlrover_tpu.serving.kvpool.families import SENTINEL_BLOCK
 
 
 def group_index(config, kind: str) -> int:
@@ -57,52 +57,6 @@ def reach_of(config, kind: str):
     if kind == window_lm.FULL:
         return None
     return config.sliding_window - 1
-
-
-def _attention_kind(config, pool_dtype, block_size: int, max_blocks: int,
-                    slots: int, chunk: int) -> str:
-    if not paged._on_tpu():
-        return "gathered_view"
-    # Pallas costs ~1.2 s to import: only a process that may run the
-    # kernels pays it (the repo's idiom for ops/ kernels).
-    from dlrover_tpu.ops.window_attention import window_kernels_supported
-
-    if window_kernels_supported(
-        pool_dtype, block_size, config.n_heads, config.n_kv_heads,
-        config.head_dim, chunk, slots, max_blocks,
-    ):
-        return "pool_kernel"
-    return "gathered_view"
-
-
-def decode_attention_kind(config, pool_dtype, block_size: int,
-                          max_blocks: int, slots: int, chunk: int) -> str:
-    """What the decode step reads its cached rows with, both reaches
-    alike: ``"pool_kernel"`` (the full layers
-    ``ops.decode_attention.pool_decode_attention``, the window layers
-    ``ops.window_attention.pool_window_decode_attention``: the group's
-    pool in place, only the pages that hold a visible row) where those
-    kernels lower (``window_attention.window_kernels_supported``: a TPU,
-    a bf16 pool of 4 or 8k KV heads x 128 whose page is one DMA, tables
-    inside the scalar memory) and ``"gathered_view"``, the definition,
-    everywhere else. Decided by what the code can see, like
-    ``conv.decode_attention_kind`` and for its reasons: no option,
-    nothing falls back after it, so what it admits has to compile
-    (``tests/test_tpu_compile.py`` holds it to the cell's shape).
-    ``kv_stats()["window_decode_attention"]`` and the engine's
-    construction log line say which."""
-    return _attention_kind(config, pool_dtype, block_size, max_blocks,
-                           slots, chunk)
-
-
-def chunk_attention_kind(config, pool_dtype, block_size: int,
-                         max_blocks: int, slots: int, chunk: int) -> str:
-    """The same for the prefill chunk (``pool_chunk_attention`` /
-    ``pool_window_chunk_attention``): one predicate admits both programs'
-    kernels, so the two answers agree today; they are asked apart because
-    they are reported apart (``kv_stats()["window_chunk_attention"]``)."""
-    return _attention_kind(config, pool_dtype, block_size, max_blocks,
-                           slots, chunk)
 
 
 def _group_pools(config, pools, kind: str):
@@ -259,9 +213,11 @@ def chunk_forward(config, pools, params, tokens, table_rows, start,
 
 
 def build_decode(config, slots: int, max_blocks: int, block_size: int,
-                 counts, kind: str = "gathered_view"):
-    """``kind``: :func:`decode_attention_kind`'s answer for this shape."""
+                 counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: the
+    definition's)."""
     max_len = max_blocks * block_size
+    kind = (kinds or {}).get("window_decode_attention", "gathered_view")
     n_pools = 2 * len(config.cache_groups)
 
     def step(*args):
@@ -301,8 +257,10 @@ def build_decode(config, slots: int, max_blocks: int, block_size: int,
 
 
 def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
-                  counts, kind: str = "gathered_view"):
-    """``kind``: :func:`chunk_attention_kind`'s answer for this shape."""
+                  counts, kinds=None):
+    """``kinds``: :func:`kinds`' answers for this shape (None: the
+    definition's)."""
+    kind = (kinds or {}).get("window_chunk_attention", "gathered_view")
     if chunk % block_size:
         raise ValueError(
             f"prefill_chunk {chunk} must be whole blocks of {block_size}: "
@@ -347,3 +305,39 @@ def build_prefill(config, max_blocks: int, block_size: int, chunk: int,
         return (*out, first)
 
     return prefill
+
+
+# ---- what the family states (kvpool/families.py) ----------------------------
+
+POOL_ATTENTION = "window_groups"
+
+
+def kinds(config, pool_dtype, block_size: int, chunk: int, slots: int = 0,
+          max_blocks: int = 0):
+    """What the decode step and the prefill chunk read their cached rows
+    with, both reaches alike: ``"pool_kernel"`` (the full layers
+    ``ops.decode_attention.pool_decode_attention`` /
+    ``pool_chunk_attention``, the window layers ``ops.window_attention
+    .pool_window_decode_attention`` / ``pool_window_chunk_attention``:
+    the group's pool in place, only the pages that hold a visible row)
+    where those kernels lower (``window_attention
+    .window_kernels_supported``: a TPU, a bf16 pool of 4 or 8k KV heads x
+    128 whose page is one DMA, tables inside the scalar memory) and
+    ``"gathered_view"``, the definition, everywhere else. One predicate
+    admits both programs' kernels, so the two answers agree today; they
+    are named apart because they are reported apart. Decided by what the
+    code can see: no option, nothing falls back after it, so what it
+    admits has to compile (``tests/test_tpu_compile.py`` holds it to the
+    cell's shape)."""
+    kind = "gathered_view"
+    if families._on_tpu():
+        # Pallas costs ~1.2 s to import: only a process that may run the
+        # kernels pays it (the repo's idiom for ops/ kernels).
+        from dlrover_tpu.ops.window_attention import window_kernels_supported
+
+        if window_kernels_supported(
+            pool_dtype, block_size, config.n_heads, config.n_kv_heads,
+            config.head_dim, chunk, slots, max_blocks,
+        ):
+            kind = "pool_kernel"
+    return {"window_decode_attention": kind, "window_chunk_attention": kind}

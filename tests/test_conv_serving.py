@@ -308,7 +308,7 @@ def test_the_chunk_through_the_pool_kernel_emits_the_same_tokens(monkeypatch):
     ``kv_stats()`` names the kind and counts the rows the chunks
     launched and those their attention scored."""
     from dlrover_tpu.ops import flat_decode_attention as fda
-    from dlrover_tpu.serving.kvpool import conv
+    from dlrover_tpu.serving.kvpool import conv, families
     from dlrover_tpu.serving.kvpool import engine as paged
 
     # two KV heads of 64 are ONE lane row, under two query heads each
@@ -327,15 +327,15 @@ def test_the_chunk_through_the_pool_kernel_emits_the_same_tokens(monkeypatch):
     # 14 -> 2 chunks, 27 -> 4, the hit's 9 rows after 12 -> 2
     assert stats["conv_chunk_rows_launched"] == 8 * CHUNK
     assert stats["conv_chunk_rows_scored"] == 8 * CHUNK
-    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(families, "_on_tpu", lambda: True)
     monkeypatch.setattr(fda, "flat_chunk_kernel_supported", lambda *a: True)
     monkeypatch.setattr(fda, "CHUNK_PREFIX_BYTES", 2 * BS * cfg.kv_width * 4)
     monkeypatch.setattr(conv, "CHUNK_TOKEN_TILE", 4)
     # tile and VMEM chunk are no part of a program's key: programs of
     # the test's own
-    monkeypatch.setattr(paged, "_paged_steps_for", functools.lru_cache(
+    monkeypatch.setattr(paged, "_steps_for", functools.lru_cache(
         maxsize=16
-    )(paged._paged_steps_for.__wrapped__))
+    )(paged._steps_for.__wrapped__))
     calls = []
     kernel = fda.pool_flat_chunk_attention
     monkeypatch.setattr(

@@ -16,7 +16,7 @@ import pytest
 from dlrover_tpu.models import conv_lm
 from dlrover_tpu.ops import flat_decode_attention as fda
 from dlrover_tpu.serving.kvpool import PagedServingEngine, conv
-from dlrover_tpu.serving.kvpool import engine as paged
+from dlrover_tpu.serving.kvpool import engine as paged, families
 from tests.test_conv_serving import prompts, seeded_params, serve
 
 BS, MB = 16, 6
@@ -97,7 +97,7 @@ def test_flat_pool_kernel_matches_the_gathered_form(
     unread = np.ones(nb, bool)
     for row, fill in zip(tables, FILLS):
         unread[row[:-(-fill // BS)]] = False
-        row[-(-fill // BS):] = paged.SENTINEL_BLOCK
+        row[-(-fill // BS):] = families.SENTINEL_BLOCK
     k_pool = k_pool.at[:, unread].set(jnp.nan)
     v_pool = v_pool.at[:, unread].set(jnp.nan)
     got = np.asarray(conv.decode_attend(
@@ -251,7 +251,7 @@ def test_flat_chunk_kernel_matches_the_gathered_form(
     ))
     for start in STARTS:
         pages = -(-start // BS)
-        table = np.full(MB + 2, paged.SENTINEL_BLOCK, np.int32)
+        table = np.full(MB + 2, families.SENTINEL_BLOCK, np.int32)
         table[:pages] = order[:pages]
         unread = np.ones(nb, bool)
         unread[order[:pages]] = False
@@ -268,7 +268,9 @@ def test_flat_chunk_kernel_matches_the_gathered_form(
         )
         for n_valid in VALID:
             got = np.asarray(kernel(*poisoned, jnp.int32(n_valid)))
-            scored = conv.chunk_rows_scored(n_valid, CHUNK, "pool_kernel")
+            scored = conv.chunk_rows_scored(
+                n_valid, CHUNK, {"conv_chunk_attention": "pool_kernel"}
+            )
             assert scored == -(-n_valid // TILE) * TILE
             assert np.isfinite(got).all()
             assert not got[0, scored:].any()
@@ -283,7 +285,9 @@ def test_flat_chunk_kernel_matches_the_gathered_form(
                 np.testing.assert_allclose(
                     got, want[0, :scored], rtol=2 ** -6, atol=2 ** -6
                 )
-    assert conv.chunk_rows_scored(3, CHUNK, "gathered_view") == CHUNK
+    assert conv.chunk_rows_scored(
+        3, CHUNK, {"conv_chunk_attention": "gathered_view"}
+    ) == CHUNK
     # another layer of the same pool is another answer (below start)
     other = np.asarray(form("pool_kernel", 0)(
         k_pool, v_pool, table, start, jnp.int32(CHUNK)
@@ -328,8 +332,8 @@ def _a_day_of_traffic(cfg, params):
     traced = dict(eng.trace_counts)
     stats = eng.kv_stats()
     assert stats["pool_attention"] == "conv_gathered_view"
-    assert stats["conv_decode_attention"] == eng.conv_decode_attention
-    assert eng.latent_decode_attention == ""
+    assert stats["conv_decode_attention"] == eng.kinds["conv_decode_attention"]
+    assert "latent_decode_attention" not in eng.kinds
     a, b = prompts(cfg, [13, 27], seed=10)
     ra, rb = eng.submit(a, 12), eng.submit(b, 12)
     for _ in range(8):
@@ -347,7 +351,7 @@ def _a_day_of_traffic(cfg, params):
     tokens += serve(eng, [(first[:12] + other, 6)])
     assert eng.kv_stats()["prefix_hit_tokens"] - hits == 12
     assert dict(eng.trace_counts) == traced      # no retrace after warm-up
-    return eng.conv_decode_attention, tokens
+    return eng.kinds["conv_decode_attention"], tokens
 
 
 def test_engine_tokens_are_the_same_through_the_flat_pool_kernel(
@@ -362,14 +366,14 @@ def test_engine_tokens_are_the_same_through_the_flat_pool_kernel(
     cfg, params = tiny
     kind, want = _a_day_of_traffic(cfg, params)
     assert kind == "gathered_view"
-    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(families, "_on_tpu", lambda: True)
     monkeypatch.setattr(fda, "flat_kernel_supported", lambda *a: True)
     monkeypatch.setattr(fda, "CHUNK_BYTES", 2 * 4 * cfg.kv_width * 2)
     # the chunk size is no part of a program's key: programs of the
     # test's own
-    monkeypatch.setattr(paged, "_paged_steps_for", functools.lru_cache(
+    monkeypatch.setattr(paged, "_steps_for", functools.lru_cache(
         maxsize=16
-    )(paged._paged_steps_for.__wrapped__))
+    )(paged._steps_for.__wrapped__))
     calls = []
     kernel = fda.pool_flat_decode_attention
     monkeypatch.setattr(

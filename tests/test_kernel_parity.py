@@ -333,7 +333,7 @@ def test_paged_int8_decode_step_parity_vs_flat(monkeypatch):
     over the unquantized slab."""
     from dlrover_tpu.models import generate as gen_lib
     from dlrover_tpu.ops.kv_quant import quantize_kv
-    from dlrover_tpu.serving.kvpool.engine import _build_paged_decode
+    from dlrover_tpu.serving.kvpool.dense import _build_paged_decode
 
     cfg = llama.tiny_config(n_layers=2)
     params, _ = llama.init_params(cfg, jax.random.key(0))

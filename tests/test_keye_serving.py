@@ -109,12 +109,32 @@ def _drive(eng):
 
 
 def _ref_logits(params, seq, positions, cfg_json=CFG_JSON, **kw):
-    pad = -len(seq) % 8
+    """The reference's logits at ``positions`` of ``seq``. The sequence
+    is padded to whole multiples of 32 rows and the positions to 8 (the
+    reference is causal: what follows a row touches none of its logits),
+    so that the reference compiles a few programs, not one a length."""
+    positions = list(positions)
+    pad, more = -len(seq) % 32, -len(positions) % 8
     logits, _ = ref.logits_at(
-        params, jnp.asarray(seq + [0] * pad), jnp.asarray(positions),
-        cfg_json, **kw,
+        params, jnp.asarray(seq + [0] * pad),
+        jnp.asarray(positions + positions[-1:] * more), cfg_json, **kw,
     )
-    return np.asarray(logits)
+    return np.asarray(logits)[:len(positions)]
+
+
+@functools.lru_cache(maxsize=None)
+def _forwards(cfg, bs, chunk_attention=None):
+    """``sparse.decode_forward``'s logits and ``chunk_forward``'s rows as
+    two programs a (model, block size, what the chunk attends with):
+    called bare, each call traced and compiled its layer scan anew."""
+    return (
+        jax.jit(lambda pools, *args: sparse.decode_forward(
+            cfg, *pools, *args, bs
+        )[0]),
+        jax.jit(lambda pools, *args: sparse.chunk_forward(
+            cfg, *pools, *args, bs, None, chunk_attention
+        )[0]),
+    )
 
 
 def _next_logits(eng, req):
@@ -123,9 +143,9 @@ def _next_logits(eng, req):
     eng._drain("test")
     tokens = np.zeros(eng.slots, np.int32)
     tokens[req.slot] = req.tokens[-1]
-    logits, *_ = sparse.decode_forward(
-        eng.config, *eng._pools(), eng._params, jnp.asarray(eng._tables),
-        jnp.asarray(eng._lengths), jnp.asarray(tokens), eng.block_size,
+    logits = _forwards(eng.config, eng.block_size)[0](
+        eng._pools(), eng._params, jnp.asarray(eng._tables),
+        jnp.asarray(eng._lengths), jnp.asarray(tokens),
     )
     return np.asarray(logits[req.slot])
 
@@ -141,9 +161,9 @@ def _take_the_chunk_kernel(monkeypatch):
     so (the kernel then runs in interpret mode) and the predicate admits
     the tiny model's float32 pool and narrow heads."""
     from dlrover_tpu.ops import decode_attention as da
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import families
 
-    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(families, "_on_tpu", lambda: True)
     monkeypatch.setattr(da, "sparse_chunk_kernel_supported", lambda *a: True)
 
 
@@ -183,9 +203,9 @@ def test_chunked_prefill_and_paged_decode_give_the_references_logits(
     start = (n_prompt - 1) // CHUNK * CHUNK
     chunk = np.zeros((1, CHUNK), np.int32)
     chunk[0, :n_prompt - start] = prompt[start:]
-    x, _ = sparse.chunk_forward(
-        eng.config, *eng._pools(), eng._params, jnp.asarray(chunk),
-        jnp.asarray(eng._tables[req.slot]), jnp.int32(start), bs,
+    x = _forwards(eng.config, bs, chunk_attention)[1](
+        eng._pools(), eng._params, jnp.asarray(chunk),
+        jnp.asarray(eng._tables[req.slot]), jnp.int32(start),
     )
     from dlrover_tpu.models import llama
 
@@ -455,11 +475,12 @@ def test_dense_programs_are_what_they_were(params):
     programs do not know of a third: their lowered text is the text the
     builders give when called as before this model existed."""
     from dlrover_tpu.models import llama
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import dense, engine as paged
 
     cfg = llama.tiny_config(dtype="float32")
     counts = {"prefill": 0, "decode": 0}
-    steps = paged._paged_steps_for(cfg, 2, 9, 4, 4, 8, "fp", "xla_gather")
+    steps = paged._paged_steps(cfg, 2, 9, 4, 4, 8)
+    assert steps.pool_attention == "xla_gather"
     eng = PagedServingEngine(
         cfg, llama.init_params(cfg, jax.random.key(0))[0], slots=2,
         max_len=16, prefill_chunk=8, block_size=4,
@@ -479,10 +500,10 @@ def test_dense_programs_are_what_they_were(params):
         jnp.bool_(True),
     )
     direct_decode = jax.jit(
-        paged._build_paged_decode(cfg, 2, 4, 4, counts), donate_argnums=(0, 1)
+        dense._build_paged_decode(cfg, 2, 4, 4, counts), donate_argnums=(0, 1)
     )
     direct_prefill = jax.jit(
-        paged._build_paged_prefill(cfg, 4, 4, 8, counts), donate_argnums=(0, 1)
+        dense._build_paged_prefill(cfg, 4, 4, 8, counts), donate_argnums=(0, 1)
     )
     assert steps.decode.lower(*dec_args).as_text() == \
         direct_decode.lower(*dec_args).as_text()
@@ -493,21 +514,20 @@ def test_dense_programs_are_what_they_were(params):
 
 def test_sparse_programs_are_what_the_sparse_builders_give(params):
     """The sibling of the dense case above, for this model: the decode
-    and prefill programs ``_paged_steps_for`` hands a sparse engine lower
-    to the text ``kvpool/sparse.py``'s builders give when called
-    directly, whatever else that function has learnt to build since (a
-    latent model's decode kind and a convolution / attention model's two
-    are further parts of its key, "" here)."""
+    and prefill programs the one builder hands a sparse engine lower to
+    the text ``kvpool/sparse.py``'s builders give when called directly,
+    whatever other families it builds (this family's ``kinds`` alone are
+    a part of its key)."""
     from dlrover_tpu.serving.kvpool import engine as paged
 
     slots, max_blocks = 3, MAX_LEN // BS
     eng = _engine(params)
     steps = eng._steps
-    assert steps is paged._paged_steps_for(
-        CFG, slots, eng.num_blocks, max_blocks, BS, CHUNK, "fp",
-        "sparse_gather", "masked_attention", "", "", "",
+    assert steps is paged._paged_steps(
+        CFG, slots, eng.num_blocks, max_blocks, BS, CHUNK
     )
-    assert steps.latent_decode_attention == ""
+    assert steps.pool_attention == "sparse_gather"
+    assert steps.kinds == (("sparse_chunk_attention", "masked_attention"),)
     counts = {"prefill": 0, "decode": 0}
     i32 = jnp.int32
     pools = eng._pools()
@@ -529,7 +549,8 @@ def test_sparse_programs_are_what_the_sparse_builders_give(params):
     )
     direct_prefill = jax.jit(
         sparse.build_prefill(
-            CFG, max_blocks, BS, CHUNK, counts, kind="masked_attention"
+            CFG, max_blocks, BS, CHUNK, counts,
+            {"sparse_chunk_attention": "masked_attention"},
         ),
         donate_argnums=(0, 1, 2),
     )
@@ -555,5 +576,5 @@ def test_no_other_models_engine_speaks_of_a_latent_pool(model, params):
     else:
         eng = _engine(params)
     assert not [k for k in eng.kv_stats() if k.startswith("latent")]
-    assert eng.latent_decode_attention == ""
+    assert "latent_decode_attention" not in eng.kinds
     assert eng._latent is None

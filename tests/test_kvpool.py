@@ -22,6 +22,7 @@ from dlrover_tpu.serving.kvpool import (
     PagedServingEngine,
     PrefixCache,
 )
+from tests.greedy_reference import naive_greedy
 
 pytestmark = pytest.mark.kvpool
 
@@ -31,17 +32,6 @@ def tiny():
     cfg = llama.tiny_config()
     params, _ = llama.init_params(cfg, jax.random.key(0))
     return cfg, params
-
-
-def naive_greedy(cfg, params, prompt, max_new):
-    seq = jnp.asarray(prompt, jnp.int32)[None, :]
-    out = []
-    for _ in range(max_new):
-        logits, _ = llama.forward(cfg, params, seq)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        out.append(int(nxt[0]))
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    return out
 
 
 def make_prompts(cfg, lens, seed=0):
@@ -714,9 +704,9 @@ def _admit_f32_pools(monkeypatch):
     admits the f32 pools of the tiny models here, where XLA's f32 is
     f32 and the two paths differ by the order of summation alone."""
     from dlrover_tpu.ops import decode_attention as da
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import families
 
-    monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+    monkeypatch.setattr(families, "_on_tpu", lambda: True)
     monkeypatch.setattr(da, "pool_kernel_supported", lambda *a: True)
 
 
@@ -827,8 +817,6 @@ def test_prefill_runs_its_head_on_a_last_chunk_only(
     ten arguments as before the flag (the head then always on); the
     first token of a last chunk is that call's, any other chunk's is
     the placeholder 0 — and one compiled program serves both."""
-    from dlrover_tpu.serving.kvpool import engine as paged
-
     cfg, params = tiny
     if in_place:
         cfg = llama.tiny_config(
@@ -898,12 +886,12 @@ _KIND_CASES = {
 
 @pytest.mark.parametrize("case", sorted(_KIND_CASES))
 def test_pool_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import dense, families
 
     seen, want = _KIND_CASES[case]
     seen = dict(seen)
     on_tpu = seen.pop("on_tpu", True)
-    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(families, "_on_tpu", lambda: on_tpu)
     block_size = seen.pop("block_size", 16)
     kv_dtype = seen.pop("kv_dtype", "fp")
     chunk = seen.pop("chunk", 256)
@@ -911,7 +899,7 @@ def test_pool_attention_kind_goes_by_what_it_can_see(case, monkeypatch):
         **dict(n_heads=32, n_kv_heads=8, head_dim=128, dtype="bfloat16"),
         **seen,
     })
-    assert paged.pool_attention_kind(
+    assert dense.pool_attention_kind(
         cfg, block_size, kv_dtype, chunk
     ) == want
 

@@ -220,9 +220,9 @@ def own_programs(monkeypatch):
 
     from dlrover_tpu.serving.kvpool import engine as paged
 
-    monkeypatch.setattr(paged, "_paged_steps_for", functools.lru_cache(
+    monkeypatch.setattr(paged, "_steps_for", functools.lru_cache(
         maxsize=16
-    )(paged._paged_steps_for.__wrapped__))
+    )(paged._steps_for.__wrapped__))
 
 
 @pytest.fixture
@@ -234,11 +234,11 @@ def take_the_pool_kernel(monkeypatch):
     so that a slot's pages are several tiles. The engines' programs
     built from here on are the test's own (``own_programs``)."""
     from dlrover_tpu.ops import latent_decode_attention as lda
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import families
 
     def take(tile_rows):
         own_programs(monkeypatch)
-        monkeypatch.setattr(paged, "_on_tpu", lambda: True)
+        monkeypatch.setattr(families, "_on_tpu", lambda: True)
         monkeypatch.setattr(lda, "latent_kernel_supported", lambda *a: True)
         monkeypatch.setattr(lda, "TILE_ROWS", tile_rows)
 
@@ -262,7 +262,7 @@ def test_served_tokens_are_the_full_forwards(
     eng = engine(cfg, params)
     stats = eng.kv_stats()
     assert stats["latent_decode_attention"] == kind
-    assert eng.latent_decode_attention == kind
+    assert eng.kinds["latent_decode_attention"] == kind
     assert stats["pool_attention"] == "latent_absorbed"
     # a chunk of 8 is not whole tiles of the module's size: one tile
     assert stats["latent_chunk_query_rows"] == CHUNK
@@ -650,8 +650,8 @@ def test_cow_and_preemption_keep_the_latent_rows_consistent(tiny):
     (context,) = prompts(cfg, (30,), 7)
     turns = prompts(cfg, (5, 9, 3, 6), 8)
     items = [(context + t, 12) for t in turns]
-    roomy = engine(cfg, params, slots=4, num_blocks=120)
-    want = serve(roomy, items)
+    # (the unpressed engine: the shape the other tests have compiled)
+    want = serve(engine(cfg, params), items)
     tight = engine(cfg, params, slots=4, num_blocks=24)
     serve(tight, [(context, 1)])
     got = serve(tight, items)

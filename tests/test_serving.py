@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import llama
 from dlrover_tpu.serving import DECODE, PREFILL, ServingEngine, Scheduler
+from tests.greedy_reference import naive_greedy
 
 
 @pytest.fixture(scope="module")
@@ -19,18 +20,6 @@ def tiny():
     cfg = llama.tiny_config()
     params, _ = llama.init_params(cfg, jax.random.key(0))
     return cfg, params
-
-
-def naive_greedy(cfg, params, prompt: np.ndarray, max_new: int):
-    """Teacher-forced reference: re-forward the growing sequence."""
-    seq = jnp.asarray(prompt, jnp.int32)[None, :]
-    out = []
-    for _ in range(max_new):
-        logits, _ = llama.forward(cfg, params, seq)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        out.append(int(nxt[0]))
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    return out
 
 
 def make_prompts(cfg, lens, seed=0):

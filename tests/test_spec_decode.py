@@ -19,6 +19,7 @@ from dlrover_tpu.serving import spec_decode as spec_lib
 from dlrover_tpu.serving.engine import ServingEngine
 from dlrover_tpu.serving.kvpool.engine import PagedServingEngine
 from dlrover_tpu.serving.scheduler import DECODE, Scheduler
+from tests.greedy_reference import naive_greedy
 
 pytestmark = pytest.mark.spec
 
@@ -28,17 +29,6 @@ def tiny():
     cfg = llama.tiny_config()
     params, _ = llama.init_params(cfg, jax.random.key(0))
     return cfg, params
-
-
-def naive_greedy(cfg, params, prompt, max_new):
-    seq = jnp.asarray(prompt, jnp.int32)[None, :]
-    out = []
-    for _ in range(max_new):
-        logits, _ = llama.forward(cfg, params, seq)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        out.append(int(nxt[0]))
-        seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-    return out
 
 
 def copy_last_token_params(params):

@@ -14,6 +14,7 @@ compiles in the test's own process; and all such tests live in this one
 file. Code keyed on ``jax.default_backend()`` is steered by monkeypatch.
 """
 
+import contextlib
 import os
 
 import jax
@@ -45,22 +46,32 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def _as_if_on_tpu(monkeypatch):
+@contextlib.contextmanager
+def _tpu_branches():
     """Take the TPU branches (flash kernel, non-interpret Pallas) and
     keep the persistent cache out: a described-device executable can be
     written to it but never read back."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(llama, "_ATTN_CACHE", {})
-    monkeypatch.delenv("DLROVER_TPU_MOE_DISPATCH", raising=False)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    patch.setattr(llama, "_ATTN_CACHE", {})
+    patch.delenv("DLROVER_TPU_MOE_DISPATCH", raising=False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        patch.undo()
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _as_if_on_tpu():
+    with _tpu_branches():
+        yield
 
 
 def _n_kernels(compiled) -> int:
@@ -555,7 +566,7 @@ def _decode_args(cfg, one_chip):
 def _paged_programs(case, one_chip):
     """The case's config and paged step programs, and the arguments
     both programs start with, as shapes on the described chip."""
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import dense, engine as paged
 
     spec = dict(_PAGED_CASES[case])
     want = spec.pop("want")
@@ -563,8 +574,8 @@ def _paged_programs(case, one_chip):
     bs, chunk = spec.pop("block_size", 16), spec.pop("chunk", 256)
     cfg = llama.TpuLMConfig(n_layers=2, dtype="bfloat16", **spec)
     num_blocks = slots * max_blocks + 1
-    assert paged.pool_attention_kind(cfg, bs, "fp", chunk) == want
-    assert paged.pool_attention_kind(cfg, bs, "int8", chunk) == "xla_gather"
+    assert dense.pool_attention_kind(cfg, bs, "fp", chunk) == want
+    assert dense.pool_attention_kind(cfg, bs, "int8", chunk) == "xla_gather"
     steps = paged._paged_steps(cfg, slots, num_blocks, max_blocks, bs, chunk)
     assert steps.pool_attention == want
     arr, key, params = _decode_args(cfg, one_chip)
@@ -722,7 +733,28 @@ def test_slab_decode_step_is_plain_xla_and_one_rolled_loop(one_chip, kind):
     assert mem.temp_size_in_bytes < cache_bytes
 
 
-def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
+@pytest.fixture(scope="module")
+def keye_cell_programs(topo):
+    """``keye-serve-docqa-32k``'s two engine programs (2 of its 5 layers)
+    as ``benchmark/rehearse_keye.py`` lowers them, over a BARE index-key
+    array, COMPILED for the described v5e once for the two tests that
+    read them (the same compile, 30-40 s of tier-1: ROADMAP D16)."""
+    from benchmark import common, rehearse_keye
+
+    with _tpu_branches():   # (a module's fixture is made before a test's)
+        programs = rehearse_keye.lower_engine_programs(
+            common.load_json("configs", "keye-vl2-30b-a3b.json"),
+            topo.devices[0], n_layers=2,
+        )
+        return {
+            name: programs[name].compile()
+            for name in ("jit_step", "jit_prefill")
+        }
+
+
+def test_sparse_expert_serving_programs_compile_at_the_cells_shape(
+    keye_cell_programs,
+):
     """``keye-serve-docqa-32k``'s decode step and prefill chunk (2 of
     its 5 layers: the scan's body is one layer either way) at published
     widths, 16 slots x 33,792 rows over the cell's 5,200-block pool,
@@ -730,19 +762,13 @@ def test_sparse_expert_serving_programs_compile_at_the_cells_shape(topo):
     arrays alias their outputs, the expert matmuls are the grouped
     kernel (two calls a layer body, no dense [tokens, experts, ...]
     product), and weights + pool + temporaries stay inside the chip."""
-    from benchmark import common, sparse_scopes, trace_reduce
-    from benchmark import rehearse_keye
+    from benchmark import sparse_scopes, trace_reduce
 
-    cfg_json = common.load_json("configs", "keye-vl2-30b-a3b.json")
-    programs = rehearse_keye.lower_engine_programs(
-        cfg_json, topo.devices[0], n_layers=2
-    )
     aliased = (
         "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias), "
         "{2}: (2, {}, may-alias)"
     )
-    for name in ("jit_step", "jit_prefill"):
-        c = programs[name].compile()
+    for name, c in keye_cell_programs.items():
         text = c.as_text()
         assert name + "," in text.splitlines()[0]
         assert aliased in text
@@ -889,7 +915,7 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
     ``pool_attention`` goes on naming the definition."""
     from benchmark import common, conv_scopes, trace_reduce
     from benchmark import rehearse_lfm2
-    from dlrover_tpu.serving.kvpool import conv, engine as paged
+    from dlrover_tpu.serving.kvpool import conv
 
     cfg_json = common.load_json("configs", "lfm2-24b-a2b.json")
     programs, logical = rehearse_lfm2.lower_engine_programs(
@@ -952,14 +978,11 @@ def test_conv_serving_programs_compile_at_the_cells_shape(topo):
 
     cfg = serve_conv.conv_config(cfg_json)
     assert conv.lane_pack(cfg) == 2
-    assert paged.pool_attention_kind(cfg, 64, "fp", 512) == \
-        "conv_gathered_view"
-    assert conv.decode_attention_kind(
-        cfg, cfg.compute_dtype, 64, 144, 32
-    ) == "pool_kernel"
-    assert conv.chunk_attention_kind(
-        cfg, cfg.compute_dtype, 64, 144, 512
-    ) == "pool_kernel"
+    assert conv.POOL_ATTENTION == "conv_gathered_view"
+    assert conv.kinds(cfg, cfg.compute_dtype, 64, 512, 32, 144) == {
+        "conv_decode_attention": "pool_kernel",
+        "conv_chunk_attention": "pool_kernel",
+    }
 
 
 # What ``conv.decode_attention_kind`` sees -> what it must answer;
@@ -996,10 +1019,10 @@ def _conv_kind_case(seen, monkeypatch):
     """What a case of the two tables above and below sees: the platform
     probe patched, and ``(config, block_size, max_blocks)``."""
     from dlrover_tpu.models import conv_lm
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import families
 
     on_tpu = seen.get("on_tpu", True)
-    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(families, "_on_tpu", lambda: on_tpu)
     cfg = conv_lm.tiny_config(
         n_heads=seen.get("n_heads", 32), n_kv_heads=seen.get("n_kv_heads", 8),
         head_dim=seen.get("head_dim", 64), dtype=seen.get("dtype", "bfloat16"),
@@ -1184,11 +1207,11 @@ def test_latent_decode_attention_kind_admits_only_what_compiles(
     scalar memory answers ``gathered_view``."""
     from dlrover_tpu.models import latent_lm
     from dlrover_tpu.ops import latent_decode_attention as lda
-    from dlrover_tpu.serving.kvpool import engine as paged, latent, layout
+    from dlrover_tpu.serving.kvpool import families, latent, layout
 
     seen, want = _LATENT_KIND_CASES[case]
     on_tpu = seen.get("on_tpu", True)
-    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(families, "_on_tpu", lambda: on_tpu)
     bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 272)
     slots = seen.get("slots", 32)
     cfg = latent_lm.tiny_config(
@@ -1228,7 +1251,9 @@ def test_latent_decode_attention_kind_admits_only_what_compiles(
 
 
 @pytest.mark.parametrize("pool", ["the_engines_pool", "a_bare_array"])
-def test_sparse_serving_programs_do_not_copy_the_index_key_pool(topo, pool):
+def test_sparse_serving_programs_do_not_copy_the_index_key_pool(
+    topo, pool, request,
+):
     """``keye-serve-docqa-32k``'s decode step and prefill chunk over the
     index-key pool AS THE ENGINE BUILDS IT (``IndexKeyPool.zeros``: two
     64-wide keys to a 128-lane row, ``[layers, 5200, 32, 128]``) hold no
@@ -1269,24 +1294,23 @@ def test_sparse_serving_programs_do_not_copy_the_index_key_pool(topo, pool):
         lambda: IndexKeyPool.zeros(L, nb, bs, cfg.index_dim, cdt)
     ))
     assert ki.pack == 2 and ki.rows.shape == (L, nb, bs // 2, 128)
-    if pool == "a_bare_array":
-        ki = arr(ki.shape, cdt)
     steps = paged._paged_steps(cfg, slots, nb, mb, bs, chunk)
     i32, f32 = jnp.int32, jnp.float32
-    programs = {
+    programs = request.getfixturevalue(
+        "keye_cell_programs"    # (the bare array: rehearse_keye.py's)
+    ) if pool == "a_bare_array" else {
         "jit_step": steps.decode.lower(
             kv, kv, ki, params, arr((slots, mb), i32), arr((slots,), i32),
             arr((slots,), i32), arr((slots,), bool), arr((slots,), f32),
             key, arr((), i32), arr((), i32), arr((), i32),
-        ),
+        ).compile(),
         "jit_prefill": steps.prefill.lower(
             kv, kv, ki, params, arr((1, chunk), i32), arr((mb,), i32),
             arr((), i32), arr((), i32), arr((), f32), key, arr((), i32),
             arr((), bool),
-        ),
+        ).compile(),
     }
-    for name, lowered in programs.items():
-        c = lowered.compile()
+    for name, c in programs.items():
         text = c.as_text()
         assert name + "," in text.splitlines()[0]
         copies = [
@@ -1336,19 +1360,19 @@ def test_sparse_chunk_attention_kind_admits_only_what_compiles(
     answers ``masked_attention``."""
     from dlrover_tpu.models import sparse_lm
     from dlrover_tpu.ops import decode_attention as da
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import families, sparse
 
     seen, want = _SPARSE_KIND_CASES[case]
     seen = dict(seen)
     on_tpu = seen.pop("on_tpu", True)
-    monkeypatch.setattr(paged, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(families, "_on_tpu", lambda: on_tpu)
     h, kh = seen.get("heads", 32), seen.get("kv_heads", 4)
     bs, mb = seen.get("block_size", 64), seen.get("max_blocks", 528)
     t, dtype = seen.get("chunk", 512), seen.get("dtype", "bfloat16")
     cfg = sparse_lm.tiny_config(
         n_heads=h, n_kv_heads=kh, head_dim=128, dtype=dtype
     )
-    assert paged.sparse_chunk_attention_kind(
+    assert sparse.chunk_attention_kind(
         cfg, cfg.compute_dtype, bs, t, mb
     ) == want
     if want != "chunk_kernel":
@@ -1444,12 +1468,12 @@ def test_window_serving_programs_compile_at_the_cells_shape(topo):
     from benchmark.runners import serve_window
 
     cfg = serve_window.window_config(cfg_json)
-    args = (cfg, cfg.compute_dtype, 64, 264, 32, 512)
-    assert window.decode_attention_kind(*args) == "pool_kernel"
-    assert window.chunk_attention_kind(*args) == "pool_kernel"
-    assert window.decode_attention_kind(
-        cfg, jnp.float32, 64, 264, 32, 512
-    ) == "gathered_view"
+    assert set(window.kinds(
+        cfg, cfg.compute_dtype, 64, 512, 32, 264
+    ).values()) == {"pool_kernel"}
+    assert set(window.kinds(
+        cfg, jnp.float32, 64, 512, 32, 264
+    ).values()) == {"gathered_view"}
 
 
 def test_linear_sparse_serving_programs_compile_at_the_cells_shape(topo):

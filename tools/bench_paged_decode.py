@@ -1,6 +1,6 @@
 """Paged attention A/B on the device at hand: pool kernels vs gather.
 
-``serving/kvpool/engine.py`` builds its plain decode program and its
+``serving/kvpool/dense.py`` builds the plain decode program and the
 prefill program with one of two attentions (``pool_attention_kind``):
 ``paged_kernel`` reads each layer's K/V from the stacked pool in place,
 filled pages only (``ops.decode_attention.pool_decode_attention`` for
@@ -706,7 +706,7 @@ def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
     from dlrover_tpu.models import generate as gen_lib
     from dlrover_tpu.models import llama
     from dlrover_tpu.ops import decode_attention as da
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import dense
 
     slots, max_blocks, layers = shape.slots, shape.max_blocks, shape.layers
     heads, kv_heads, head_dim = shape.heads, shape.kv_heads, shape.head_dim
@@ -728,7 +728,7 @@ def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
         "view_mb": round(
             2 * slots * max_len * kv_heads * head_dim * 2 / 1e6, 1
         ),
-        "engine_would_build": paged.pool_attention_kind(
+        "engine_would_build": dense.pool_attention_kind(
             cfg, BLOCK, "fp", shape.chunk
         ),
     }), flush=True)
@@ -850,7 +850,7 @@ def run(shape, parts, chunk_kb, repeats, seed, starts, query_rows):
         tokens = {}
         for attn in ("xla_gather", "paged_kernel"):
             step = jax.jit(
-                paged._build_paged_decode(
+                dense._build_paged_decode(
                     cfg, slots, max_blocks, BLOCK, {"decode": 0}, attn=attn
                 ),
                 donate_argnums=(0, 1),
@@ -917,7 +917,7 @@ def _prefill(cfg, shape, starts, query_rows, repeats, rng, keys, k_pool,
     import numpy as np
 
     from dlrover_tpu.ops import decode_attention as da
-    from dlrover_tpu.serving.kvpool import engine as paged
+    from dlrover_tpu.serving.kvpool import dense
 
     chunk, layers, max_blocks = shape.chunk, shape.layers, shape.max_blocks
     max_len = max_blocks * BLOCK
@@ -1008,7 +1008,7 @@ def _prefill(cfg, shape, starts, query_rows, repeats, rng, keys, k_pool,
     )
     programs = {
         attn: jax.jit(
-            paged._build_paged_prefill(
+            dense._build_paged_prefill(
                 cfg, max_blocks, BLOCK, chunk, {"prefill": 0}, attn=attn
             ),
             donate_argnums=(0, 1),

@@ -7,10 +7,11 @@ accepted serve cell (``nemo12b-serve-chat``, built as
 ``rehearse_keye.py``, ``xing-serve-sessions-16k``, as
 ``rehearse_xing.py``, ``lfm2-serve-sessions-8k``, as
 ``rehearse_lfm2.py``, ``mellum2-serve-mixed-16k``, as
-``rehearse_mellum2.py``, and ``sala-serve-docs-64k``, as
-``rehearse_sala.py``, each where the checkout's manifest has it). Run it on
-two checkouts and compare: the same hash is the same program, so the cell
-cannot move.
+``rehearse_mellum2.py``, ``sala-serve-docs-64k``, as
+``rehearse_sala.py``, and ``olmohybrid-serve-grow-6k``, as
+``rehearse_olmo_hybrid.py``, each where the checkout's manifest has
+it). Run it on two checkouts and compare: the same hash is the same
+program, so the cell cannot move.
 
     JAX_PLATFORMS=cpu python3 tools/program_hashes.py [ROOT] [--dump DIR]
 
@@ -192,6 +193,14 @@ def main(argv):
 
         serve_cells["sala-serve-docs-64k"] = lambda ctx: \
             rehearse_sala.lower_engine_programs(
+                ctx["config"], device, probes=False, reference=False
+            )[0]
+    if any(w["name"] == "olmohybrid-serve-grow-6k"
+           for w in manifest["workloads"]):
+        from benchmark import rehearse_olmo_hybrid
+
+        serve_cells["olmohybrid-serve-grow-6k"] = lambda ctx: \
+            rehearse_olmo_hybrid.lower_engine_programs(
                 ctx["config"], device, probes=False, reference=False
             )[0]
     for cell, lower in serve_cells.items():
