@@ -81,7 +81,8 @@ _NGRAM_WINDOW = 128
 # phase is named by the mark that CLOSES it, so the host time between
 # two marks always belongs to the later one and the phases tile the span
 # whatever path a step takes. A steady iteration passes them as admit,
-# prefill_prep, prefill_launch, decode_prep, decode_launch, decode_fetch,
+# prefill_prep, prefill_launch (both once a chunk: twice in a two-chunk
+# step), decode_prep, decode_launch, decode_fetch,
 # commit, account: it launches its own programs first and then fetches
 # and commits the tokens of the PREVIOUS iteration's (``prefill_fetch``
 # when that one launched a prompt's last chunk and no decode). A drain
@@ -150,6 +151,7 @@ class _StepTrace(_PhaseMarks):
     def __init__(self, t0: float):
         super().__init__(t0)
         self.counts = {"n_admitted": 0, "n_decoding": 0,
+                       "prefill_chunks": 0, "prefill_rows": 0,
                        "prefill_tokens": 0, "overlapped": 0}
 
 
@@ -449,8 +451,9 @@ class ServingEngine:
     """Single-host continuous-batching engine over a slot-pooled cache.
 
     Host bookkeeping (the Scheduler) is jax-free; each ``step()`` runs
-    at most one prefill chunk and one ragged decode iteration. The
-    engine is not thread-safe — drive it from one serving loop."""
+    at most two prefill chunks (``Scheduler.pick_prefills``) and one
+    ragged decode iteration. The engine is not thread-safe — drive it
+    from one serving loop."""
 
     def __init__(
         self,
@@ -734,11 +737,13 @@ class ServingEngine:
         self.warmup_s = marks.emit("serving.warmup")
 
     def step(self) -> List[Request]:
-        """One scheduler iteration: admissions, at most one prefill
-        chunk and one ragged decode step LAUNCHED, then the tokens of
-        what the previous iteration launched fetched and handed out, so
-        the device holds its next programs while the host commits,
-        accounts and prepares (one step in flight, docs/DESIGN.md §29).
+        """One scheduler iteration: admissions, the prefill chunks
+        the scheduler picks (none, one, or two of the oldest prompt
+        while another waits behind it: ``Scheduler.pick_prefills``) and
+        one ragged decode step LAUNCHED, then the tokens of what the
+        previous iteration launched fetched and handed out, so the
+        device holds its next programs while the host commits, accounts
+        and prepares (one step in flight, docs/DESIGN.md §29).
         Returns the requests whose last token arrived in THIS iteration
         (tokens fully populated).
 
@@ -775,9 +780,17 @@ class ServingEngine:
         status = "ok"
         try:
             fault_point("serving.step.error", step_idx=self._step_idx)
-            pf = sch.pick_prefill()
-            if pf is not None:
+            # Two chunk launches back to back are what one a step
+            # already is ACROSS steps (§29): host state (``prefill_pos``,
+            # ``_lengths``, the slot's blocks) advances at the launch,
+            # the pools chain the programs by data dependence, and a
+            # launch takes a copy of its table row. No launch follows a
+            # prompt's last chunk, so ``_cur`` holds one ``first``.
+            chunks = sch.pick_prefills()
+            for pf in chunks:
                 self._run_prefill_chunk(pf, finished)
+            if len(chunks) > 1:
+                self.metrics.two_chunk_steps.inc()
             decoding = sch.decoding()
             if decoding:
                 self._run_decode(decoding, finished)
@@ -839,10 +852,15 @@ class ServingEngine:
         its ``prefill_tokens`` and ``prefill_kv_rows``, the cache rows
         its attention has a use for (the slot's fill below the chunk
         plus the chunk; over ``max_len`` the share of the logical view,
-        as ``kv_rows`` is for the decode launch)."""
+        as ``kv_rows`` is for the decode launch). Both are those of ONE
+        launch, the step's last: their readers pair them with a
+        per-launch device time. What the step's ``prefill_chunks``
+        launches prefilled together is ``prefill_rows``."""
         st = self._step_trace
         if st is not None:
             st.mark("prefill_prep")
+            st.counts["prefill_chunks"] += 1
+            st.counts["prefill_rows"] += n_valid
             st.counts["prefill_tokens"] = n_valid
             st.counts["prefill_kv_rows"] = kv_rows
 

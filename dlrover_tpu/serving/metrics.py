@@ -51,6 +51,12 @@ class ServingMetrics:
             "tokens processed, prefill (prompt) vs decode (generated)",
             labelnames=("kind",),
         )
+        self.two_chunk_steps = reg.counter(
+            "serving_two_chunk_steps_total",
+            "engine iterations that launched two prefill chunks (the "
+            "oldest prompt's next two, while another prompt waited in "
+            "a slot behind it)",
+        )
         self.tokens_wasted = reg.counter(
             "serving_tokens_wasted_total",
             "computed tokens thrown away by progress resets (step-error "

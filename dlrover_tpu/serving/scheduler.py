@@ -8,12 +8,27 @@ Stale KV left in a recycled slot is harmless by the visibility
 invariant (rows >= length are never read; see docs/DESIGN.md §25), so
 "compaction" is pure bookkeeping: the free-list.
 
-Per-iteration token budget: one scheduler tick admits at most one
-prefill CHUNK (``prefill_chunk`` prompt tokens) alongside the decode
-step's one-token-per-active-slot, and the chunk only runs when
+Per-iteration token budget: one scheduler tick launches at most TWO
+prefill CHUNKS (``prefill_chunk`` prompt tokens each) alongside the
+decode step's one-token-per-active-slot (:meth:`Scheduler.pick_prefills`).
+The first is the oldest PREFILL slot's next chunk and runs when
 ``decoding + prefill_chunk <= token_budget`` (or nothing is decoding).
-Lowering the budget protects decode latency from prefill bursts;
-the default (prefill_chunk + slots) never blocks a chunk.
+The second is THE SAME request's chunk after it, and runs only while
+another prompt waits in a slot behind that one, the first chunk does not
+end its prompt, and ``decoding + 2 * prefill_chunk <= token_budget`` (or
+nothing is decoding). A decode launch costs what its weights cost
+whether 15 slots ride it or 30, so while prompts queue in PREFILL the
+iteration that carries one chunk more reaches a full decode batch in
+half the launches; with nobody waiting a second chunk would only delay
+this iteration's tokens. It is the same request's because FCFS is the
+order of service (after its first chunk the oldest is still the oldest)
+and a prompt is done soonest when its chunks run back to back. Nothing
+is launched after a prompt's LAST chunk: that chunk's first token rides
+into this iteration's decode launch, which takes one. Lowering the
+budget protects decode latency from prefill bursts: ``prefill_chunk +
+slots`` never allows the second chunk beside a decode batch, and the
+default (2 * prefill_chunk + slots) never blocks what the rule would
+launch.
 
 **SLO classes (§31).** Admission is no longer bare FCFS: requests
 carry a named :class:`SloClass` (e.g. ``interactive`` — TTFT-bound —
@@ -211,7 +226,7 @@ class Scheduler:
         self.decode_tokens_per_slot = decode_tokens_per_slot
         self.token_budget = (
             token_budget if token_budget is not None
-            else prefill_chunk + slots * decode_tokens_per_slot
+            else 2 * prefill_chunk + slots * decode_tokens_per_slot
         )
         # drain_mode is the NAIVE static baseline the serving bench A/Bs
         # against: admit a full batch, run it to completion, only then
@@ -517,20 +532,32 @@ class Scheduler:
     def active(self) -> List[Request]:
         return [r for r in self.by_slot if r is not None]
 
-    def pick_prefill(self) -> Optional[Request]:
-        """The prefill chunk to run this iteration, or None. FCFS among
-        PREFILL slots (lowest rid = longest waiting); gated by the
-        token budget so a prompt burst cannot starve decode."""
+    def pick_prefills(self) -> List[Request]:
+        """The prefill chunk launches of this iteration, in order: none,
+        one, or the same request twice (its next two chunks). FCFS among
+        PREFILL slots (lowest rid = longest waiting); gated by the token
+        budget so a prompt burst cannot starve decode. A function of the
+        slots' states and the budget alone (module docstring)."""
         cands = [
             r for r in self.by_slot
             if r is not None and r.state == PREFILL
         ]
         if not cands:
-            return None
+            return []
         n_decoding = len(self.decoding()) * self.decode_tokens_per_slot
-        if n_decoding and n_decoding + self.prefill_chunk > self.token_budget:
-            return None
-        return min(cands, key=lambda r: r.rid)
+        # Chunks the budget leaves room for beside the decode batch.
+        room = 2 if not n_decoding else (
+            (self.token_budget - n_decoding) // self.prefill_chunk
+        )
+        if room < 1:
+            return []
+        oldest = min(cands, key=lambda r: r.rid)
+        ends_prompt = (
+            oldest.prompt_len - oldest.prefill_pos <= self.prefill_chunk
+        )
+        if room > 1 and len(cands) > 1 and not ends_prompt:
+            return [oldest, oldest]
+        return [oldest]
 
     # ---- completion --------------------------------------------------------
 

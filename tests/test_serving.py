@@ -165,12 +165,12 @@ def test_scheduler_budget_gates_prefill():
     # Two slots decoding -> 2 + 8 <= 10 allows the chunk...
     reqs[0].state = DECODE
     reqs[1].state = DECODE
-    assert sch.pick_prefill() is reqs[2]
+    assert sch.pick_prefills() == [reqs[2]]
     # ...three decoding -> 3 + 8 > 10 defers it.
     reqs[2].state = DECODE
     sch.submit(np.zeros(4, np.int32), 4)
     sch.admit()
-    assert sch.pick_prefill() is None
+    assert sch.pick_prefills() == []
 
 
 def test_scheduler_drain_mode_admits_only_empty():

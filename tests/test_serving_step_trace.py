@@ -110,11 +110,45 @@ def test_phases_tile_every_step_span(kind, parts, tracer):
 
 
 @pytest.mark.parametrize("kind", KINDS)
+def test_a_two_chunk_step_tiles_and_counts_both_launches(
+    kind, parts, tracer
+):
+    """Two prompts in PREFILL slots and the older one three chunks long:
+    the first step launches its first two (``Scheduler.pick_prefills``).
+    ``prefill_prep`` / ``prefill_launch`` pass twice and still tile the
+    span; ``prefill_tokens`` / ``prefill_kv_rows`` are the LAST
+    launch's, ``prefill_rows`` both launches' rows."""
+    eng = build(kind, parts)
+    reqs = [eng.submit(list(range(1, n + 1)), 3) for n in (19, 11)]
+    eng.run_until_idle(max_iters=500)
+    assert all(r.state == "done" and len(r.tokens) == 3 for r in reqs)
+    first, *rest = [s["attrs"] for s in step_spans(tracer)]
+    names = [p[0] for p in first["phases"]]
+    assert names[:5] == ["admit", "prefill_prep", "prefill_launch",
+                         "prefill_prep", "prefill_launch"]
+    assert sum(p[2] for p in first["phases"]) == pytest.approx(
+        step_spans(tracer)[0]["dur_s"], abs=1e-6
+    )
+    assert (first["prefill_chunks"], first["prefill_rows"]) == (2, 16)
+    assert (first["prefill_tokens"], first["prefill_kv_rows"]) == (8, 16)
+    # Then the older prompt's last chunk alone (nothing follows a last
+    # chunk), and the younger one's two with nobody waiting behind it.
+    assert [(a["prefill_chunks"], a["prefill_rows"]) for a in rest[:3]] == [
+        (1, 3), (1, 8), (1, 3),
+    ]
+    assert sum(a["prefill_chunks"] for a in rest[3:]) == 0
+    assert eng.metrics.two_chunk_steps.value() == 1
+
+
+@pytest.mark.parametrize("kind", KINDS)
 def test_counts_add_up_to_the_work_done(kind, parts, tracer):
     reqs = serve(build(kind, parts))
     attrs = [s["attrs"] for s in step_spans(tracer)]
-    assert sum(a["prefill_tokens"] for a in attrs) == sum(
+    assert sum(a["prefill_rows"] for a in attrs) == sum(
         n for n, _ in PLAN
+    )
+    assert sum(a["prefill_chunks"] for a in attrs) == sum(
+        -(-n // 8) for n, _ in PLAN
     )
     assert sum(a["n_admitted"] for a in attrs) == len(PLAN)
     assert sum(a["n_finished"] for a in attrs) == len(PLAN)
@@ -128,7 +162,8 @@ def test_counts_add_up_to_the_work_done(kind, parts, tracer):
     assert all(
         set(a) - {"retraces", "kv_rows", "prefill_kv_rows"} == {
             "idx", "phases", "n_admitted", "n_decoding",
-            "prefill_tokens", "n_finished", "overlapped",
+            "prefill_chunks", "prefill_rows", "prefill_tokens",
+            "n_finished", "overlapped",
         } for a in attrs
     )
     assert all("retraces" not in a for a in attrs[3:])
@@ -301,7 +336,7 @@ def test_an_injected_step_error_yields_an_error_span(kind, parts, tracer):
         bad["dur_s"], abs=1e-6
     )
     # The requeued requests restart: their prompts are prefilled twice.
-    assert sum(s["attrs"]["prefill_tokens"] for s in spans) > sum(
+    assert sum(s["attrs"]["prefill_rows"] for s in spans) > sum(
         n for n, _ in PLAN
     )
     assert [len(r.tokens) for r in reqs] == [new for _, new in PLAN]
@@ -353,6 +388,10 @@ def test_trace_query_renders_a_step_span(parts, tracer, tmp_path, capsys):
         "retraced_steps": [
             s["attrs"]["idx"] for s in spans if "retraces" in s["attrs"]
         ],
+        # PLAN never meets the rule of the second chunk: its first
+        # prompt's first chunk is its last, and the third prompt gets
+        # its slot when the second one decodes.
+        "prefill_chunks_mean": 1.0, "two_chunk_steps_pct": 0.0,
     }
     assert 1.0 <= table["counts"]["decode_batch_mean"] <= 2.0
     # A decode launch after one found it in flight (not the first, nor

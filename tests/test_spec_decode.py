@@ -311,13 +311,14 @@ def test_scheduler_budget_counts_verification_tokens():
         pre = sch.submit(np.arange(4, dtype=np.int32), 4)
         sch.admit(0.0)
         dec.state = DECODE
-        return sch.pick_prefill() is None
+        return sch.pick_prefills() == []
 
     assert not gated(1)   # 1*1 + 8 = 9 <= 10: prefill proceeds
     assert gated(4)       # 1*4 + 8 = 12 > 10: decode reserves first
     eng_budget = Scheduler(slots=2, max_len=32, prefill_chunk=8,
                            decode_tokens_per_slot=4)
-    assert eng_budget.token_budget == 8 + 2 * 4
+    # The default never blocks what the rule would launch: two chunks.
+    assert eng_budget.token_budget == 2 * 8 + 2 * 4
 
 
 def test_engine_wires_spec_budget(tiny):

@@ -162,7 +162,13 @@ def step_summary(spans: List[Dict]) -> Dict:
     enqueued while the previous launch's tokens were still on the
     device (one step in flight: ~100 in steady state, 0 on a path that
     drains every step), ``retraced_steps`` names the iterations that
-    recompiled a program. A sparse-attention expert model's steps also
+    recompiled a program, ``prefill_chunks_mean`` is the chunk
+    launches of a step that carried any (1 or 2:
+    ``Scheduler.pick_prefills``) and ``two_chunk_steps_pct`` the share
+    of those steps that carried two (both absent from a sink older than
+    the count; ``prefill_tokens`` sums the steps' ``prefill_rows``
+    where they have it, every launch's rows, else the one launch's
+    ``prefill_tokens``). A sparse-attention expert model's steps also
     count ``selected_rows_mean`` (the rows a decode launch's attention
     reads, beside the ``kv_rows_mean`` it could), ``experts_hit_mean``
     (distinct experts a launch's tokens reach, mean over layers) and
@@ -199,7 +205,9 @@ def step_summary(spans: List[Dict]) -> Dict:
         "errors": sum(s.get("status") != "ok" for s in steps),
         "admitted": sum(a["n_admitted"] for a in attrs),
         "finished": sum(a["n_finished"] for a in attrs),
-        "prefill_tokens": sum(a["prefill_tokens"] for a in attrs),
+        "prefill_tokens": sum(
+            a.get("prefill_rows", a["prefill_tokens"]) for a in attrs
+        ),
         "decode_batch_mean": (
             sum(decoding) / len(decoding) if decoding else 0.0
         ),
@@ -212,7 +220,7 @@ def step_summary(spans: List[Dict]) -> Dict:
             / len(decoding) if decoding else 0.0
         ),
         "retraced_steps": [a["idx"] for a in attrs if a.get("retraces")],
-        **_sparse_counts(attrs),
+        **_optional_counts(attrs),
     }}
 
 
@@ -266,8 +274,14 @@ def _print_stalls(table: Dict, spans: List[Dict]) -> None:
     print("\n".join(stalls.render(table)))
 
 
-def _sparse_counts(attrs: List[Dict]) -> Dict:
+def _optional_counts(attrs: List[Dict]) -> Dict:
     out = {}
+    chunks = [a["prefill_chunks"] for a in attrs if a.get("prefill_chunks")]
+    if chunks:
+        out["prefill_chunks_mean"] = sum(chunks) / len(chunks)
+        out["two_chunk_steps_pct"] = (
+            100.0 * sum(c > 1 for c in chunks) / len(chunks)
+        )
     for name, count in (("selected_rows_mean", "selected_rows"),
                         ("experts_hit_mean", "experts_hit"),
                         ("window_rows_mean", "window_rows"),
